@@ -6,13 +6,17 @@ store/tcp_store (rendezvous), DataLoader shm transport [— verify].
 Compute stays with XLA; these are the host-side native subsystems a TPU
 framework still genuinely needs in C++.
 
-The shared library is compiled on demand with g++ (this image has no
-pybind11; bindings are ctypes over a C ABI). Pure-Python fallbacks keep
-every feature working when no compiler is available.
+The shared library is a build product, never committed: it is compiled
+from ``native/ptcore.cc`` with g++ on first use (this image has no
+pybind11; bindings are ctypes over a C ABI) and rebuilt when the sha256
+of the source differs from the one recorded beside the binary — mtimes
+mean nothing on a copied checkout. Pure-Python fallbacks keep every
+feature working when no compiler is available.
 """
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -20,24 +24,43 @@ import threading
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "native")
 _SRC = os.path.join(_NATIVE_DIR, "ptcore.cc")
 _LIB = os.path.join(_NATIVE_DIR, "libptcore.so")
+_STAMP = _LIB + ".src_sha256"     # hash of the ptcore.cc that built _LIB
 
 _lib = None
 _lib_lock = threading.Lock()
 _build_error = None
 
 
-def _build():
-    # per-pid temp name: concurrent first-use builds (launch with several
+def _src_hash() -> str:
+    with open(_SRC, "rb") as f:
+        return hashlib.sha256(f.read()).hexdigest()
+
+
+def _stale(src_hash: str) -> bool:
+    try:
+        with open(_STAMP) as f:
+            return f.read().strip() != src_hash
+    except OSError:
+        return True
+
+
+def _build(src_hash: str):
+    # per-pid temp names: concurrent first-use builds (launch with several
     # local workers) must not interleave writes into one temp file
     tmp = f"{_LIB}.{os.getpid()}.tmp"
+    stamp_tmp = f"{_STAMP}.{os.getpid()}.tmp"
     cmd = ["g++", "-std=c++17", "-O2", "-shared", "-fPIC", "-pthread",
            _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=180)
         os.replace(tmp, _LIB)   # atomic: losers just overwrite with same
+        with open(stamp_tmp, "w") as f:
+            f.write(src_hash + "\n")
+        os.replace(stamp_tmp, _STAMP)   # stamp lands only after the lib
     finally:
-        if os.path.exists(tmp):
-            os.unlink(tmp)
+        for t in (tmp, stamp_tmp):
+            if os.path.exists(t):
+                os.unlink(t)
 
 
 def load_native():
@@ -51,9 +74,9 @@ def load_native():
         if _build_error is not None:
             return None
         try:
-            if not os.path.exists(_LIB) or (
-                    os.path.getmtime(_LIB) < os.path.getmtime(_SRC)):
-                _build()
+            src_hash = _src_hash()
+            if not os.path.exists(_LIB) or _stale(src_hash):
+                _build(src_hash)
             lib = ctypes.CDLL(_LIB)
         except (OSError, subprocess.SubprocessError) as e:
             _build_error = e

@@ -249,8 +249,6 @@ def _compiled(op: str, mesh: Mesh, plan: HierarchyPlan,
     """Jitted shard_map program per (op, mesh, plan) — host-level
     wrappers would otherwise re-trace on every call, which both costs
     milliseconds and makes the microbench time tracing, not comms."""
-    from jax.experimental.shard_map import shard_map
-
     if op == "all_reduce":
         inner = lambda xl: hier_all_reduce(        # noqa: E731
             jnp.squeeze(xl, 0), plan)
@@ -270,9 +268,9 @@ def _compiled(op: str, mesh: Mesh, plan: HierarchyPlan,
         out_specs = P()
     else:  # pragma: no cover
         raise ValueError(op)
-    return jax.jit(shard_map(inner, mesh=mesh,
-                             in_specs=(P(plan.axes),),
-                             out_specs=out_specs, check_rep=False))
+    return jax.jit(jax.shard_map(inner, mesh=mesh,
+                                 in_specs=(P(plan.axes),),
+                                 out_specs=out_specs, check_vma=False))
 
 
 def all_reduce(x, axes: Optional[Axes] = None, mesh: Optional[Mesh] = None,

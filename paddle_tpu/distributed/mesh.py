@@ -52,11 +52,10 @@ def build_device_mesh(axis_dims: dict, devices=None,
             f"topology {dict(zip(AXIS_ORDER, dims))} needs {total} devices, "
             f"have {len(devices)} (pass allow_subset=True to use a prefix)")
     devices = devices[:total]
-    try:
-        from jax.experimental import mesh_utils
-        arr = mesh_utils.create_device_mesh(dims, devices=devices)
-    except Exception:
-        arr = np.array(devices).reshape(dims)
+    # a topology mesh_utils cannot lay out raises here: a silently
+    # reshaped device list would put mp/sep on whatever links came first
+    from jax.experimental import mesh_utils
+    arr = mesh_utils.create_device_mesh(dims, devices=devices)
     return Mesh(arr, AXIS_ORDER)
 
 

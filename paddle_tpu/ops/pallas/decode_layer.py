@@ -458,7 +458,7 @@ def decode_layer_paged_kernel(mode, x, cos, sin, eps1, eps2, pos, tbl,
             ff_chunk=_tuned_ff_chunk(d, ff)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, d), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_fused._FORCE_INTERPRET,
     )(*operands)
@@ -481,7 +481,7 @@ def build_fused_callable(mode, inner_closed, eps1, eps2, *,
     weight-quant engines, where the in-graph dequant must stay fused
     into the XLA gemm prologue) evaluate the captured original jaxpr,
     which is the unfused math bit-for-bit."""
-    import jax.core as jcore
+    from jax.extend.core import jaxpr_as_fun
 
     invars = inner_closed.jaxpr.invars
 
@@ -500,7 +500,7 @@ def build_fused_callable(mode, inner_closed, eps1, eps2, *,
             return decode_layer_paged_kernel(
                 mode, fixed[0], fixed[1], fixed[2], eps1, eps2,
                 fixed[5], fixed[6], *cache, *wts)
-        return tuple(jcore.jaxpr_as_fun(inner_closed)(*args))
+        return tuple(jaxpr_as_fun(inner_closed)(*args))
 
     pt_fused_decode_layer.uses_kernel = use_kernel
     return pt_fused_decode_layer

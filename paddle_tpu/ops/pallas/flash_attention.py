@@ -69,13 +69,8 @@ _FORCE_INTERPRET = False
 
 
 def _pallas_available() -> bool:
-    if _FORCE_INTERPRET:
-        return True
-    try:
-        import jax.experimental.pallas  # noqa: F401
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    from .fused import pallas_gate
+    return pallas_gate(_FORCE_INTERPRET)
 
 
 def _pick_block(s, pref=512):
@@ -380,8 +375,8 @@ def _block_pref(env_name: str, kernel: str, seq: int, dim: int,
                 default: int = 512):
     """Resolve a kernel's preferred block size: explicit env override
     (routed through utils/flags.env_int, 0 = kernel defaults) beats a
-    valid autotune-table entry beats the PROFILE_r03 default (512).
-    Returns (pref, source)."""
+    valid autotune-table entry beats the default (512, from the one v5e
+    profile on record, PROFILE_r03.json). Returns (pref, source)."""
     if env_set(env_name):     # presence check: NAME=0 still means "env"
         return env_int(env_name, default), "env"
     from .autotune import lookup
@@ -398,11 +393,11 @@ def _note_blocks(kernel, source, bq, bk):
 
 def _jax_flash_blocks(jfa, sq, sk, dim=128):
     """Block sizes for jax's TPU flash kernel. The kernel's built-in
-    default is 128 everywhere; PROFILE_r03 (v5e, b32 h16 s1024 d64)
+    default is 128 everywhere; PROFILE_r03.json (v5e, b32 h16 s1024 d64)
     measured the three 128-block kernels at 53% of device self-time for
     ~14% of step FLOPs. Bigger tiles amortize the HBM traffic per score
-    tile — FLASH_BLOCKS_r03.json records the on-chip sweep; 512 wins,
-    unless the autotune table holds a fresher per-device winner.
+    tile: 512 is the default, unless the autotune table holds a
+    per-device winner.
     Env overrides: PT_JAX_FLASH_BLOCK (kv block), PT_JAX_FLASH_BLOCK_Q.
     Returns None (= kernel default) when the sequence doesn't tile."""
     pref, source = _block_pref("PT_JAX_FLASH_BLOCK", "jax_flash", sk,
@@ -421,33 +416,27 @@ def _jax_flash_blocks(jfa, sq, sk, dim=128):
         block_q_dq=bq)
 
 
+_LANES = 128      # both jax kernels tile sequences and heads by lanes
+
+
 def _jax_tpu_flash(q, k, v, is_causal, scale):
     """jax's tuned Pallas TPU flash kernel (differentiable), bhsd layout.
-    Returns None if shapes are unsupported. Equal q/kv head counts only —
-    GQA takes the splash path (no K/V materialization)."""
+    Returns None — a routing decision, recorded by sdpa — for the shapes
+    the kernel does not take: unequal q/kv head counts (GQA takes the
+    splash path, no K/V materialization), sequences that are not whole
+    128-lane tiles, a head_dim above 128 that is not a multiple of it.
+    Anything the kernel raises past that gate propagates."""
     if _FORCE_INTERPRET:
         return None     # interpret-mode tests target OUR kernels
-    try:
-        from jax.experimental.pallas.ops.tpu import flash_attention as jfa
-    except ImportError:
+    sq, sk, d = q.shape[1], k.shape[1], q.shape[3]
+    if k.shape[2] != q.shape[2] or sq % _LANES or sk % _LANES \
+            or (d > _LANES and d % _LANES):
         return None
-    if k.shape[2] != q.shape[2]:
-        return None
-    blocks = _jax_flash_blocks(jfa, q.shape[1], k.shape[1], q.shape[3])
-    try:
-        out = jfa.flash_attention(
-            jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
-            jnp.moveaxis(v, 2, 1), causal=is_causal, sm_scale=scale,
-            block_sizes=blocks)
-    except (ValueError, NotImplementedError):
-        if blocks is None:
-            return None
-        try:  # tuned blocks rejected for this shape: kernel defaults
-            out = jfa.flash_attention(
-                jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
-                jnp.moveaxis(v, 2, 1), causal=is_causal, sm_scale=scale)
-        except (ValueError, NotImplementedError):
-            return None
+    from jax.experimental.pallas.ops.tpu import flash_attention as jfa
+    out = jfa.flash_attention(
+        jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1),
+        jnp.moveaxis(v, 2, 1), causal=is_causal, sm_scale=scale,
+        block_sizes=_jax_flash_blocks(jfa, sq, sk, d))
     return jnp.moveaxis(out, 1, 2)
 
 
@@ -455,20 +444,20 @@ def _splash_attention(q, k, v, is_causal, scale, window=None):
     """jax's splash-attention TPU kernel: native GQA (q heads grouped
     over kv heads — K/V never repeated) and native sliding-window via
     LocalMask (block-sparse: fully-masked tiles are SKIPPED, unlike the
-    banded-masking fallbacks). bshd layout. Returns None when shapes
-    don't fit the kernel.
+    banded-masking fallbacks). bshd layout. Returns None — a routing
+    decision — when the q heads do not group over the kv heads or (on
+    the chip) a sequence is not whole 128-lane tiles; what the kernel
+    raises past that gate propagates.
 
     Reference parity: the flash-attn CUDA wrapper's GQA/window args
     (paddle/phi/kernels/gpu/flash_attn_kernel.cu — verify)."""
-    try:
-        from jax.experimental.pallas.ops.tpu.splash_attention import (
-            splash_attention_kernel as sak,
-            splash_attention_mask as sam)
-    except ImportError:
-        return None
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sak,
+        splash_attention_mask as sam)
     b, sq, h, d = q.shape
     sk, hk = k.shape[1], k.shape[2]
-    if h % hk != 0:
+    if h % hk != 0 or (not _FORCE_INTERPRET
+                       and (sq % _LANES or sk % _LANES)):
         return None
     g = h // hk
     if window is not None:
@@ -479,8 +468,8 @@ def _splash_attention(q, k, v, is_causal, scale, window=None):
     else:
         m = sam.FullMask((sq, sk))
     # splash's built-in default is 128-tiles everywhere — the same
-    # tiling PROFILE_r03 measured at 53% of step time on the jax flash
-    # kernel; hand it 512-class tiles when the sequence tiles
+    # tiling PROFILE_r03.json measured at 53% of step time on the jax
+    # flash kernel; hand it 512-class tiles when the sequence tiles
     # (PT_SPLASH_BLOCK overrides via utils/flags.env_int, 0 = kernel
     # defaults; a valid autotune-table entry beats the 512 default)
     pref, source = _block_pref("PT_SPLASH_BLOCK", "splash", sk, d)
@@ -494,26 +483,23 @@ def _splash_attention(q, k, v, is_causal, scale, window=None):
             block_q=bq, block_kv=bk, block_kv_compute=bk,
             block_q_dkv=bq, block_kv_dkv=bk, block_kv_dkv_compute=bk,
             block_q_dq=bq, block_kv_dq=bk)
-    try:
-        kern = sak.make_splash_mqa_single_device(
-            sam.MultiHeadMask([m] * g), block_sizes=blocks,
-            interpret=_FORCE_INTERPRET)
-        qs = (q * jnp.asarray(scale, q.dtype))
-        # (b, s, h, d) -> (b, kvh, g, s, d); kv -> (b, kvh, s, d)
-        qq = jnp.moveaxis(qs, 2, 1).reshape(b, hk, g, sq, d)
-        kk = jnp.moveaxis(k, 2, 1)
-        vv = jnp.moveaxis(v, 2, 1)
-        out = jax.vmap(jax.vmap(kern))(qq, kk, vv)  # (b, kvh, g, sq, d)
-    except (ValueError, NotImplementedError):
-        return None
+    kern = sak.make_splash_mqa_single_device(
+        sam.MultiHeadMask([m] * g), block_sizes=blocks,
+        interpret=_FORCE_INTERPRET)
+    qs = (q * jnp.asarray(scale, q.dtype))
+    # (b, s, h, d) -> (b, kvh, g, s, d); kv -> (b, kvh, s, d)
+    qq = jnp.moveaxis(qs, 2, 1).reshape(b, hk, g, sq, d)
+    kk = jnp.moveaxis(k, 2, 1)
+    vv = jnp.moveaxis(v, 2, 1)
+    out = jax.vmap(jax.vmap(kern))(qq, kk, vv)      # (b, kvh, g, sq, d)
     return jnp.moveaxis(out.reshape(b, h, sq, d), 1, 2)
 
 
-# route taken by the most recent sdpa() trace: "jax_flash" | "fused_flash"
-# | "xla".  Inspectable by bench.py / on-hardware tests so a broken Pallas
-# kernel can never silently masquerade as the fast path (VERDICT r1 weak #2).
+# route taken by the most recent sdpa() trace: "splash" | "jax_flash" |
+# "fused_flash" | "xla". Inspectable by chip_smoke.py / bench.py / the
+# on-hardware tests so the O(s^2) XLA path can never masquerade as the
+# fast path (VERDICT r1 weak #2).
 LAST_DISPATCH = "none"
-_FALLBACK_WARNED = False
 
 
 def sdpa_last_dispatch() -> str:
@@ -526,42 +512,33 @@ def sdpa(q, k, v, mask=None, is_causal=False, dropout_p=0.0, scale=None,
     TPU dispatch order: splash kernel (GQA and/or sliding-window —
     block-sparse, no K/V repeat) -> jax's tuned flash kernel (equal
     heads) -> our fused flash kernel (GQA + window aware) -> XLA-fused
-    reference (O(s^2) scores)."""
-    global LAST_DISPATCH, _FALLBACK_WARNED
+    reference (O(s^2) scores).
+
+    A route that does not take the shape returns None and the next one
+    is tried: that is routing, and ``LAST_DISPATCH`` records where it
+    ended. A failure INSIDE a Pallas route is never caught here — on a
+    TPU it raises, it does not fall back to the XLA path."""
+    global LAST_DISPATCH
     scale = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
     if (mask is None and dropout_p == 0.0 and _pallas_available()):
-        # trace-time failures in any Pallas path fall back to XLA
-        # (compile-time Mosaic errors surface later and are covered by
-        # the on-hardware kernel tests)
         gqa = k.shape[2] != q.shape[2]
         # PT_SDPA_PREFER overrides the equal-heads route for on-chip
         # A/B ("splash" | "jax_flash" | "fused"); GQA/window always
         # prefer splash (the only kernel that avoids K/V repeat)
         prefer = env_str("PT_SDPA_PREFER")
-        try:
-            if gqa or window is not None or prefer == "splash":
-                out = _splash_attention(q, k, v, is_causal, scale, window)
-                if out is not None:
-                    LAST_DISPATCH = "splash"
-                    return out
-            elif prefer != "fused":
-                out = _jax_tpu_flash(q, k, v, is_causal, scale)
-                if out is not None:
-                    LAST_DISPATCH = "jax_flash"
-                    return out
-            out = flash_attention_fused(q, k, v, is_causal, scale,
-                                        window=window)
-            if out is not None:
-                LAST_DISPATCH = "fused_flash"
-                return out
-        except Exception as e:
-            if not _FALLBACK_WARNED:
-                _FALLBACK_WARNED = True
-                import warnings
-                warnings.warn(
-                    f"Pallas flash attention unavailable, falling back to "
-                    f"O(s^2) XLA attention: {type(e).__name__}: {e}",
-                    RuntimeWarning)
+        out = None
+        if gqa or window is not None or prefer == "splash":
+            out, route = _splash_attention(q, k, v, is_causal, scale,
+                                           window), "splash"
+        elif prefer != "fused":
+            out, route = _jax_tpu_flash(q, k, v, is_causal,
+                                        scale), "jax_flash"
+        if out is None:
+            out, route = flash_attention_fused(
+                q, k, v, is_causal, scale, window=window), "fused_flash"
+        if out is not None:
+            LAST_DISPATCH = route
+            return out
     LAST_DISPATCH = "xla"
     return _xla_sdpa(q, k, v, mask, is_causal, dropout_p, scale,
                      window=window)

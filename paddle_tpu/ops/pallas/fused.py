@@ -28,18 +28,57 @@ import jax.numpy as jnp
 _FORCE_INTERPRET = False
 
 
-def _pallas_ok() -> bool:
-    if _FORCE_INTERPRET:
-        return True
-    try:
-        import jax.experimental.pallas  # noqa: F401
-        return jax.default_backend() == "tpu"
-    except Exception:
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+def pallas_gate(force_interpret: bool = False) -> bool:
+    """THE dispatch gate of every Pallas kernel in this package: on a TPU
+    (or in a test's forced interpret mode), and not in a program GSPMD
+    will partition.
+
+    jax refuses to lower a Mosaic kernel into a multi-device program
+    outside a fully-manual ``shard_map`` — "NotImplementedError: Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call in
+    a shard_map." (compile-only rehearsal of the dp=2 x mp=2 train step
+    for a described v5e:2x2, PR 21). The kernels do not carry their own
+    shard_map yet, so the gate closes while the process-current mesh
+    (``distributed.mesh``) spans several devices and the trace is not
+    inside a fully-manual shard_map: under the dp x mp x pp trainer every
+    fused kernel takes its identical-math jnp twin and ``sdpa`` its XLA
+    route (``sdpa_last_dispatch()`` says so). Tensor-parallel serving
+    traces inside shard_map and keeps the kernels."""
+    if not (force_interpret or _on_tpu()):
         return False
+    from ...distributed.mesh import get_current_mesh
+    mesh = get_current_mesh()
+    return mesh is None or mesh.size == 1 \
+        or jax.sharding.get_abstract_mesh().are_all_axes_manual
+
+
+def _pallas_ok() -> bool:
+    return pallas_gate(_FORCE_INTERPRET)
 
 
 def _round_up(n, m):
     return (n + m - 1) // m * m
+
+
+# Mosaic double-buffers every pipelined block inside a 16 MiB scoped VMEM
+# limit (v5e) and the kernel bodies add a few block-sized temporaries, so
+# the row kernels keep their pipelined buffers to a quarter of it.
+_VMEM_BLOCK_BUDGET = 4 * 1024 * 1024
+
+
+def _block_rows(rows: int, row_bytes: int, n_blocked: int) -> int:
+    """Rows per grid step for a kernel that pipelines ``n_blocked``
+    row-blocked operands (inputs + outputs) of ``row_bytes`` padded VMEM
+    bytes per row: the largest multiple of 8 (at most 256) whose double
+    buffers fit ``_VMEM_BLOCK_BUDGET`` — sized from the width and dtype,
+    not a constant, so hidden 4096 / 32x128 heads compile where a fixed
+    256 rows asked the v5e for 16-22 MiB."""
+    fit = _VMEM_BLOCK_BUDGET // (2 * n_blocked * row_bytes)
+    return min(_round_up(rows, 8), max(8, min(256, fit // 8 * 8)))
 
 
 # ---------------------------------------------------------------------------
@@ -78,7 +117,8 @@ def _rms_pallas(x, weight, eps, residual):
     h = orig_shape[-1]
     rows = x.size // h
     x2 = x.reshape(rows, h)
-    block_rows = max(8, min(256, _round_up(rows, 8) // 8 * 8))
+    block_rows = _block_rows(rows, h * x.dtype.itemsize,
+                             2 if residual is None else 4)
     grid = (pl.cdiv(rows, block_rows),)
     row_spec = pl.BlockSpec((block_rows, h), lambda i: (i, 0))
     w_spec = pl.BlockSpec((h,), lambda i: (0,))
@@ -224,8 +264,11 @@ def _rope_pallas(q, k, cos, sin):
     # cannot shape-cast vectors), middle dim broadcasts over heads
     c2 = jnp.broadcast_to(cos[None], (b, sq, d)).reshape(rows, 1, d)
     s2 = jnp.broadcast_to(sin[None], (b, sq, d)).reshape(rows, 1, d)
-    # ~1MB blocks: 256 * h * d * 4B at (h=16, d=64); 4 tensors in flight
-    rb = rows if rows <= 256 else 256
+    # q, k and both outputs ride the pipeline as (rb, h, d) blocks; in
+    # VMEM the trailing (h, d) tile pads to the dtype's (sublane, 128)
+    sub = 32 // q.dtype.itemsize
+    row_bytes = _round_up(h, sub) * _round_up(d, 128) * q.dtype.itemsize
+    rb = min(rows, _block_rows(rows, row_bytes, 4))
     grid = (pl.cdiv(rows, rb),)
     qspec = pl.BlockSpec((rb, h, d), lambda i: (i, 0, 0))
     cspec = pl.BlockSpec((rb, 1, d), lambda i: (i, 0, 0))
@@ -303,22 +346,23 @@ def _adamw_kernel(p_ref, g_ref, m_ref, v_ref, sc_ref,
     bc2 = sc_ref[6]     # 1 - beta2**step
     p = p_ref[...].astype(jnp.float32)
     g = g_ref[...].astype(jnp.float32)
-    m = m_ref[...]
-    v = v_ref[...]
+    m = m_ref[...].astype(jnp.float32)
+    v = v_ref[...].astype(jnp.float32)
     m_new = beta1 * m + (1 - beta1) * g
     v_new = beta2 * v + (1 - beta2) * g * g
     mhat = m_new / bc1
     vhat = v_new / bc2
     p_new = p - lr * (mhat / (jnp.sqrt(vhat) + eps) + wd * p)
     po_ref[...] = p_new.astype(po_ref.dtype)
-    mo_ref[...] = m_new
-    vo_ref[...] = v_new
+    mo_ref[...] = m_new.astype(mo_ref.dtype)
+    vo_ref[...] = v_new.astype(vo_ref.dtype)
 
 
 def fused_adamw(p, g, m, v, lr, beta1=0.9, beta2=0.999, eps=1e-8,
                 weight_decay=0.01, step=1):
-    """One-pass AdamW: reads p/g/m/v once, writes p/m/v once.
-    m and v are float32 master moments; p may be bf16."""
+    """One-pass AdamW: reads p/g/m/v once, writes p/m/v once, every
+    tensor in its OWN dtype (fp32 master moments, or bf16 moments beside
+    bf16 params); the update itself runs in fp32 registers."""
     if not _pallas_ok() or p.size < 1024:
         return _adamw_ref(p, g, m, v, lr, beta1, beta2, eps,
                           weight_decay, step)
@@ -330,8 +374,8 @@ def fused_adamw(p, g, m, v, lr, beta1=0.9, beta2=0.999, eps=1e-8,
     rows = pl.cdiv(n, lanes)
     pad = rows * lanes - n
 
-    def flat(x, dt):
-        x = x.reshape(-1).astype(dt)
+    def flat(x):
+        x = x.reshape(-1)
         if pad:
             x = jnp.pad(x, (0, pad))
         return x.reshape(rows, lanes)
@@ -350,16 +394,13 @@ def fused_adamw(p, g, m, v, lr, beta1=0.9, beta2=0.999, eps=1e-8,
         out_specs=[spec, spec, spec],
         out_shape=[
             jax.ShapeDtypeStruct((rows, lanes), p.dtype),
-            jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
-            jax.ShapeDtypeStruct((rows, lanes), jnp.float32),
+            jax.ShapeDtypeStruct((rows, lanes), m.dtype),
+            jax.ShapeDtypeStruct((rows, lanes), v.dtype),
         ],
         interpret=_FORCE_INTERPRET,
-    )(flat(p, p.dtype), flat(g, jnp.float32), flat(m, jnp.float32),
-      flat(v, jnp.float32), scalars)
+    )(flat(p), flat(g), flat(m), flat(v), scalars)
 
-    def unflat(x, shape, dt):
-        return x.reshape(-1)[:n].reshape(shape).astype(dt)
+    def unflat(x, shape):
+        return x.reshape(-1)[:n].reshape(shape)
 
-    return (unflat(po, p.shape, p.dtype),
-            unflat(mo, m.shape, jnp.float32),
-            unflat(vo, v.shape, jnp.float32))
+    return unflat(po, p.shape), unflat(mo, m.shape), unflat(vo, v.shape)

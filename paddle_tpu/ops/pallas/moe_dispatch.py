@@ -22,7 +22,7 @@ cotangents) — is a row-GATHER with out-of-range masking. No scatter
 appears anywhere in the compiled step.
 
 The gather itself has two implementations, selectable via
-``PT_MOE_GATHER`` (jnp | pallas; A/B'd on chip by moe_breakdown.py):
+``PT_MOE_GATHER`` (jnp | pallas; no chip number on record for either):
   - "jnp":    clip-take-mask; XLA emits a dynamic-gather.
   - "pallas": scalar-prefetch kernel — the row index feeds the BlockSpec
     index_map, so each grid step DMAs exactly the source row HBM->VMEM
@@ -53,7 +53,7 @@ def build_index_maps(topi, num_expert: int, capacity: int):
       keep : (T*k,) bool — not capacity-dropped
     Pure integer jnp (argsort + searchsorted); call on detached/
     stop-gradient inputs. Single source of truth for the routing math —
-    MoELayer.forward and moe_breakdown.py both import it.
+    MoELayer.forward imports it.
     """
     t, k = topi.shape
     e, cap = num_expert, capacity
@@ -82,11 +82,8 @@ _FORCE_INTERPRET = False
 def _pallas_ok(d: int, dtype) -> bool:
     if _FORCE_INTERPRET:
         return True
-    try:
-        import jax.experimental.pallas  # noqa: F401
-    except Exception:
-        return False
-    return (jax.default_backend() == "tpu" and d % 128 == 0
+    from .fused import pallas_gate
+    return (pallas_gate() and d % 128 == 0
             and dtype in (jnp.float32, jnp.bfloat16))
 
 
@@ -145,7 +142,7 @@ def _gather_rows_pallas_mr(x, idx, rows_per_step: int = 8):
     (per-slot DMA semaphores), waits once, then zeroes the invalid
     rows — R× fewer grid steps and R DMAs in flight by construction.
     ``PT_MOE_GATHER=pallas_mr`` selects it; ``PT_MOE_GATHER_ROWS``
-    tunes R. A/B'd against jnp + (1, d) pallas by moe_breakdown.py.
+    tunes R. Never run outside interpret mode (ROADMAP D5).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu

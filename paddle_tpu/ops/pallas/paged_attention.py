@@ -285,7 +285,7 @@ def _grid_call(kernel, in_specs, operands, b, mb, h, d, kvh, out_dtype):
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=_fused._FORCE_INTERPRET,
     )(*operands)

@@ -37,6 +37,7 @@ from typing import Callable, Optional
 import jax
 import jax.core as jcore
 from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal
+from jax.extend.core.primitives import closed_call_p, jit_p
 
 from .patterns import AnyPat, Bind, EqnGraph, MatchState, Or, Prim
 
@@ -255,7 +256,7 @@ def make_decode_fusion_pass(allow_kernel: bool = True):
         stats = run.last_rewrites = {}
 
         def eqn_fn(eqn: JaxprEqn) -> JaxprEqn:
-            if eqn.primitive.name != "pjit":
+            if eqn.primitive is not jit_p:
                 return eqn
             parsed = _validate_marked(eqn)
             if parsed is None:
@@ -284,7 +285,7 @@ def make_decode_fusion_pass(allow_kernel: bool = True):
                 getattr(fn, "uses_kernel", False))
             _record(RULE_NAME)
             return jcore.new_jaxpr_eqn(
-                list(eqn.invars), list(eqn.outvars), jcore.closed_call_p,
+                list(eqn.invars), list(eqn.outvars), closed_call_p,
                 dict(call_jaxpr=traced), traced.effects)
 
         return rewrite_everywhere(closed, eqn_fn)

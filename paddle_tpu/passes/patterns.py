@@ -27,6 +27,7 @@ import numpy as np
 import jax
 import jax.core as jcore
 from jax.extend.core import ClosedJaxpr, Jaxpr, JaxprEqn, Literal, Var
+from jax.extend.core.primitives import closed_call_p, jit_p
 
 __all__ = ["EqnGraph", "MatchState", "Pat", "AnyPat", "Capture", "Bind",
            "Lit", "Prim", "Or", "maybe_cast", "RewriteRule",
@@ -395,7 +396,7 @@ def make_rewrite_pass(rules: Sequence[RewriteRule], pass_name: str = "fusion",
                 if inner is None:
                     continue
                 replacement[id(eqn)] = jcore.new_jaxpr_eqn(
-                    list(args), list(eqn.outvars), jcore.closed_call_p,
+                    list(args), list(eqn.outvars), closed_call_p,
                     dict(call_jaxpr=inner), inner.effects)
                 consumed.update(id(e) for e in st.eqns)
                 if on_rewrite is not None:
@@ -422,7 +423,7 @@ def inline_pjit(closed: ClosedJaxpr, max_rounds: int = 5) -> ClosedJaxpr:
     on flat primitive chains, so this runs FIRST in the pipeline.
     Effectful pjits are left in place."""
     for _ in range(max_rounds):
-        if not any(e.primitive.name == "pjit" and not e.effects
+        if not any(e.primitive is jit_p and not e.effects
                    for e in closed.jaxpr.eqns):
             break
         closed = _inline_one_level(closed)
@@ -437,7 +438,6 @@ def _inline_one_level(closed: ClosedJaxpr) -> ClosedJaxpr:
     # the same library fn must not append N copies of its closure const
     const_of: Dict[int, Var] = {id(c): v
                                 for v, c in zip(constvars, consts)}
-    newvar = jcore.gensym("_pi")
     subst: Dict[Var, Atom] = {}
 
     def res(atom: Atom) -> Atom:
@@ -448,7 +448,7 @@ def _inline_one_level(closed: ClosedJaxpr) -> ClosedJaxpr:
     out_eqns: List[JaxprEqn] = []
     for eqn in jaxpr.eqns:
         eqn = eqn.replace(invars=[res(i) for i in eqn.invars])
-        inner = eqn.params.get("jaxpr") if eqn.primitive.name == "pjit" \
+        inner = eqn.params.get("jaxpr") if eqn.primitive is jit_p \
             else None
         if inner is None or eqn.effects or not isinstance(inner, ClosedJaxpr):
             out_eqns.append(eqn)
@@ -458,7 +458,7 @@ def _inline_one_level(closed: ClosedJaxpr) -> ClosedJaxpr:
         for cv, cval in zip(ij.constvars, inner.consts):
             nv = const_of.get(id(cval))
             if nv is None:
-                nv = newvar(cv.aval)
+                nv = Var(cv.aval)
                 constvars.append(nv)
                 consts.append(cval)
                 const_of[id(cval)] = nv
@@ -468,7 +468,7 @@ def _inline_one_level(closed: ClosedJaxpr) -> ClosedJaxpr:
         for ie in ij.eqns:
             new_out = []
             for ov in ie.outvars:
-                nv = newvar(ov.aval)
+                nv = Var(ov.aval)
                 m[ov] = nv
                 new_out.append(nv)
             new_in = [m.get(i, i) if isinstance(i, Var) else i

@@ -68,17 +68,12 @@ def _transient_types() -> Tuple[type, ...]:
     faults always; the fleet transport's wire failure (a send that
     exhausted its reconnect budget — the network being down is
     operational, not a bug); XLA's runtime error (device-side failures
-    — e.g. a preempted or flaky accelerator) when the class is
-    importable. Programming errors (ValueError & friends) always
-    propagate."""
+    — e.g. a preempted or flaky accelerator). Programming errors
+    (ValueError & friends) always propagate."""
+    from jax.errors import JaxRuntimeError
+
     from .transport import TransportError
-    types = [InjectedFault, TransportError]
-    try:
-        from jax.errors import JaxRuntimeError
-        types.append(JaxRuntimeError)
-    except ImportError:
-        pass
-    return tuple(types)
+    return (InjectedFault, TransportError, JaxRuntimeError)
 
 
 @dataclass

@@ -340,16 +340,16 @@ class _TPBackendMixin:
 
     # -- shard_map plumbing -----------------------------------------------
     def _shard_jit(self, fn, in_specs, out_specs, donate=()):
-        from jax.experimental.shard_map import shard_map
         spec = self._tp_spec
 
         def tp_fn(*args):
             with tp_hooks.active(spec):
                 return fn(*args)
 
-        return jax.jit(shard_map(tp_fn, mesh=self.tp_mesh,
-                                 in_specs=in_specs,
-                                 out_specs=out_specs, check_rep=False),
+        return jax.jit(jax.shard_map(tp_fn, mesh=self.tp_mesh,
+                                     in_specs=in_specs,
+                                     out_specs=out_specs,
+                                     check_vma=False),
                        donate_argnums=donate)
 
     def _replicate(self, tree):

@@ -18,7 +18,7 @@ NAMES = ["resnet50", "bert_base", "ernie_moe", "sdxl_unet",
 def test_workload_tiny_all():
     """All four workloads in ONE subprocess: the per-name subprocesses
     each paid a ~10s cold jax import for no isolation benefit on CPU
-    (chip sessions keep per-point isolation via workloads_session.sh)."""
+    (on the chip keep one point per process)."""
     env = dict(os.environ, PT_WORKLOADS_TINY="1", JAX_PLATFORMS="cpu")
     # single fake device is enough, but KEEP the fast-compile flags —
     # dropping them made every tiny XLA compile pay the full LLVM

@@ -1,0 +1,310 @@
+"""Compile-only rehearsal for the v5e: every Pallas kernel the two main
+paths (paged serving, training) dispatch on a TPU, at Llama-2-7B widths
+(hidden 4096, 32 heads x 128, ff 11008), compiled for a DESCRIBED
+``v5e:2x2`` topology — no chip attached, nothing runs. This is what
+catches what interpret mode cannot: a block too large for the chip's
+16 MiB scoped VMEM, a slice not aligned to the tiling.
+
+A compile that passes is not a chip run; ``chip_smoke.py`` is.
+
+The topology is described inside a module-scoped fixture (only the
+xdist worker that is handed this file loads the TPU compiler), the
+backend gates are steered from here (never through a program option,
+never via interpret mode), and the persistent compile cache is off
+around the file: an entry compiled for a described chip cannot be read
+back without one.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import fused
+from paddle_tpu.ops.pallas import paged_attention as pa
+
+# Llama-2-7B widths (models/llama.py llama_7b_config)
+HIDDEN, HEADS, HEAD_DIM, FF = 4096, 32, 128, 11008
+SLOTS, MAX_LEN, KV_BLOCK = 8, 2048, 16
+MAX_BLOCKS = MAX_LEN // KV_BLOCK                 # 128-entry table
+NUM_BLOCKS = 1 + SLOTS * MAX_BLOCKS
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    cc.reset_cache()
+
+
+@pytest.fixture
+def on_tpu_gates(monkeypatch):
+    """Answer the one ``jax.default_backend() == "tpu"`` question every
+    dispatch gate asks: the process sees the CPU, the program is compiled
+    for the chip. Everything else the gates look at stays live."""
+    monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+    assert not fused._FORCE_INTERPRET and not fa._FORCE_INTERPRET
+    assert fused._pallas_ok() and fa._pallas_available()
+
+
+def _compile(fn, one_chip, *specs):
+    """Compile ``fn`` for the described chip; returns the program text
+    (raises whatever the chip's compiler would raise)."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in specs]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _paged_specs(arena_dtype):
+    q = ((SLOTS, HEADS, HEAD_DIM), BF16)
+    arena = ((NUM_BLOCKS, KV_BLOCK, HEADS, HEAD_DIM), arena_dtype)
+    table = ((SLOTS, MAX_BLOCKS), jnp.int32)
+    lens = ((SLOTS,), jnp.int32)
+    return q, arena, table, lens
+
+
+SCALE = 1.0 / np.sqrt(HEAD_DIM)
+
+
+def _case_paged_bf16(one_chip):
+    q, arena, table, lens = _paged_specs(BF16)
+    return _compile(
+        functools.partial(pa.paged_attention_decode, scale=SCALE),
+        one_chip, q, arena, arena, table, lens)
+
+
+def _case_paged_int8(one_chip):
+    q, arena, table, lens = _paged_specs(jnp.int8)
+    scales = (arena[0][:-1], jnp.float32)
+    return _compile(
+        functools.partial(pa.paged_attention_decode_int8, scale=SCALE),
+        one_chip, q, arena, arena, scales, scales, table, lens)
+
+
+_QKV = ((2, 2048, HEADS, HEAD_DIM), BF16)
+
+
+def _case_sdpa_train(one_chip):
+    """The route an equal-heads bf16 model takes in training: forward
+    and backward of causal sdpa at s=2048."""
+    def loss(q, k, v):
+        return fa.sdpa(q, k, v, is_causal=True).astype(jnp.float32).sum()
+
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip,
+                    _QKV, _QKV, _QKV)
+    assert fa.sdpa_last_dispatch() == "jax_flash"
+    return text
+
+
+def _case_flash_fused(one_chip):
+    return _compile(
+        lambda q, k, v: fa.flash_attention_fused(q, k, v, is_causal=True),
+        one_chip, _QKV, _QKV, _QKV)
+
+
+_ROWS = ((2, 2048, HIDDEN), BF16)
+_W = ((HIDDEN,), BF16)
+
+
+def _case_rms(one_chip):
+    return _compile(lambda x, w: fused.fused_rms_norm(x, w, 1e-5),
+                    one_chip, _ROWS, _W)
+
+
+def _case_rms_decode_rows(one_chip):
+    """The decode block's shape: one row per slot."""
+    return _compile(lambda x, w: fused.fused_rms_norm(x, w, 1e-5),
+                    one_chip, ((SLOTS, 1, HIDDEN), BF16), _W)
+
+
+def _case_rms_residual(one_chip):
+    return _compile(
+        lambda x, r, w: fused.fused_rms_norm(x, w, 1e-5, residual=r),
+        one_chip, _ROWS, _ROWS, _W)
+
+
+def _case_rope(one_chip):
+    cs = ((2048, HEAD_DIM), BF16)
+    return _compile(fused.fused_rope, one_chip, _QKV, _QKV, cs, cs)
+
+
+def _case_adamw(one_chip):
+    """One 4096 x 11008 leaf in the bf16 params + bf16 moments setting
+    chip_smoke.py trains in; the moments must keep their dtype."""
+    leaf = ((HIDDEN, FF), BF16)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+            for s, d in (leaf,) * 4]
+    lowered = jax.jit(lambda p, g, m, v: fused.fused_adamw(
+        p, g, m, v, 1e-4, weight_decay=0.01, step=3)).lower(*args)
+    assert [o.dtype for o in jax.tree.leaves(lowered.out_info)] \
+        == [BF16] * 3
+    return lowered.compile().as_text()
+
+
+CASES = {
+    "paged_decode_bf16": _case_paged_bf16,
+    "paged_decode_int8": _case_paged_int8,
+    "sdpa_jax_flash_fwd_bwd": _case_sdpa_train,
+    "flash_attention_fused_fwd": _case_flash_fused,
+    "rms_norm": _case_rms,
+    "rms_norm_decode_rows": _case_rms_decode_rows,
+    "rms_norm_residual": _case_rms_residual,
+    "rope": _case_rope,
+    "adamw_leaf": _case_adamw,
+}
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_kernel_compiles_for_v5e(case, one_chip, on_tpu_gates):
+    text = CASES[case](one_chip)
+    assert "tpu_custom_call" in text, f"{case}: no Pallas call in the program"
+
+
+def abstract_paged_decode_program(layers, one_chip, kv_int8=False):
+    """Lower the paged engine's ONE decode-block program at full width
+    without a weight in memory: the model is built abstractly
+    (utils/scale.abstract_init), the backend's own jitted block is
+    lowered on ``jax.eval_shape``-style specs placed on ``one_chip``."""
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_7b_config
+    from paddle_tpu.serving.paging import PagedModelStepBackend
+    from paddle_tpu.utils.scale import abstract_init
+
+    cfg = llama_7b_config(num_hidden_layers=layers, dtype="bfloat16",
+                          tensor_parallel=False)
+    with abstract_init("bfloat16"):
+        model = LlamaForCausalLM(cfg)
+    backend = PagedModelStepBackend(
+        model, SLOTS, MAX_LEN, decode_block=8, block_size=KV_BLOCK,
+        num_blocks=NUM_BLOCKS, kv_int8=kv_int8, prefill_chunk=128)
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    cache = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                  for shape, dtype in backend.pool_specs)
+    return backend._block_jit.lower(
+        [spec(v) for v in backend._pv], [spec(v) for v in backend._bv],
+        cache, jax.tree.map(spec, backend.init_state()))
+
+
+def hbm_bytes(compiled) -> int:
+    """Device bytes one program holds, from the compiler's own account
+    (donated arguments alias their outputs and count once)."""
+    ma = compiled.memory_analysis()
+    return (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            + ma.output_size_in_bytes - ma.alias_size_in_bytes)
+
+
+def abstract_train_step(layers, mesh_or_chip, batch=2, seq=2048,
+                        tensor_parallel=False):
+    """Lower one whole ``TrainStep`` (forward, backward, AdamW in the bf16
+    params + moments setting) at full width on abstract weights placed by
+    ``mesh_or_chip``: a one-device sharding, or a mesh the model's own
+    partition specs are attached for."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu import optimizer
+    from paddle_tpu.jit import TrainStep
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_7b_config
+    from paddle_tpu.utils.scale import (abstract_init, abstract_state_specs,
+                                        attach_shardings)
+
+    cfg = llama_7b_config(
+        num_hidden_layers=layers, dtype="bfloat16", recompute=True,
+        scan_layers=True, tensor_parallel=tensor_parallel,
+        max_position_embeddings=seq)
+    with abstract_init("bfloat16"):
+        model = LlamaForCausalLM(cfg)
+    if isinstance(mesh_or_chip, Mesh):
+        attach_shardings(model, mesh_or_chip)
+        replicated = NamedSharding(mesh_or_chip, P())
+        batch_sharding = NamedSharding(mesh_or_chip, P("dp", None))
+    else:
+        replicated = batch_sharding = mesh_or_chip
+        for _, p in model.named_parameters():
+            p._value = jax.ShapeDtypeStruct(
+                p._value.shape, p._value.dtype, sharding=replicated)
+    for _, b in model.named_buffers():
+        b._value = jax.ShapeDtypeStruct(b._value.shape, b._value.dtype,
+                                        sharding=replicated)
+    opt = optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
+                          parameters=model.parameters(),
+                          multi_precision=False)
+    step = TrainStep(model, lambda m, b: m(b[0], b[1])[0], opt)
+    step._build()
+    opt._slots = abstract_state_specs(
+        opt.functional_state(),
+        {n: t._value for n, t in step._ptensors.items()})["slots"]
+    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
+                               sharding=batch_sharding)
+    return step.lower((ids, ids))
+
+
+def test_train_step_compiles_for_v5e(one_chip, on_tpu_gates):
+    """The whole one-chip train step at 2 layers: attention on jax's
+    flash kernel, every fused kernel present, inside the chip's HBM."""
+    compiled = abstract_train_step(2, one_chip).compile()
+    assert fa.sdpa_last_dispatch() == "jax_flash"
+    assert "tpu_custom_call" in compiled.as_text()
+    assert hbm_bytes(compiled) < 12 * 2 ** 30
+
+
+def test_mesh_train_step_takes_the_jnp_routes(topo, on_tpu_gates):
+    """jax refuses a Mosaic kernel in a program GSPMD partitions, so under
+    a multi-device current mesh the gate closes outside shard_map (and
+    stays open inside a fully-manual one): the dp=2 x mp=2 train step
+    compiles for the four chips on the jnp routes, weights still split."""
+    from jax.sharding import PartitionSpec as P
+    from paddle_tpu.distributed import mesh as pmesh
+
+    hcg = pmesh.HybridCommunicateGroup(dp_degree=2, mp_degree=2,
+                                       devices=list(topo.devices))
+    try:
+        assert not fused.pallas_gate()
+        inside = []
+        jax.eval_shape(jax.shard_map(
+            lambda x: (inside.append(fused.pallas_gate()), x)[1],
+            mesh=hcg.jax_mesh, in_specs=P("mp"), out_specs=P("mp"),
+            check_vma=False), jax.ShapeDtypeStruct((8,), jnp.float32))
+        assert inside == [True]
+        compiled = abstract_train_step(2, hcg.jax_mesh,
+                                       tensor_parallel=True).compile()
+    finally:
+        pmesh.set_current_mesh(None)
+    assert fa.sdpa_last_dispatch() == "xla"
+    assert "tpu_custom_call" not in compiled.as_text()
+    specs = {str(s.spec) for s in jax.tree.leaves(
+        compiled.output_shardings[1])}
+    assert any("'mp'" in s for s in specs), specs
+
+
+def test_paged_decode_block_compiles_for_v5e(one_chip, on_tpu_gates):
+    """The engine's ONE decode program (lax.scan of the shared step over
+    the decode block) at full width and 2 layers holds the Pallas paged
+    read and fits the chip."""
+    compiled = abstract_paged_decode_program(2, one_chip).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert hbm_bytes(compiled) < 12 * 2 ** 30

@@ -20,6 +20,43 @@ needs_native = pytest.mark.skipif(not native_available(),
                                   reason="g++ unavailable")
 
 
+class TestNativeBuild:
+    """libptcore.so is a build product (never committed): built from
+    ptcore.cc on first use, rebuilt when the sha256 of the source differs
+    from the stamp beside the binary — not by mtime, which means nothing
+    on a copied checkout."""
+
+    @pytest.mark.parametrize("lib,stamp,lib_older,rebuilt", [
+        (False, None, False, True),        # first use: no binary
+        (True, "match", True, False),      # same source, older mtime
+        (True, "other", False, True),      # source changed
+        (True, None, False, True),         # binary without a stamp
+    ], ids=["first-use", "old-mtime-same-hash", "hash-differs",
+            "no-stamp"])
+    def test_staleness_is_decided_by_source_hash(
+            self, tmp_path, monkeypatch, lib, stamp, lib_older, rebuilt):
+        import paddle_tpu.core as core
+        src = tmp_path / "ptcore.cc"
+        src.write_text("// source\n")
+        libp = tmp_path / "libptcore.so"
+        monkeypatch.setattr(core, "_SRC", str(src))
+        monkeypatch.setattr(core, "_LIB", str(libp))
+        monkeypatch.setattr(core, "_STAMP", str(libp) + ".src_sha256")
+        monkeypatch.setattr(core, "_lib", None)
+        monkeypatch.setattr(core, "_build_error", None)
+        if lib:
+            libp.write_bytes(b"not a library")
+            if lib_older:
+                os.utime(libp, (1, 1))
+        if stamp is not None:
+            (tmp_path / "libptcore.so.src_sha256").write_text(
+                core._src_hash() if stamp == "match" else "0" * 64)
+        built = []
+        monkeypatch.setattr(core, "_build", built.append)
+        assert core.load_native() is None     # the fake binary won't load
+        assert built == ([core._src_hash()] if rebuilt else [])
+
+
 class TestTracer:
     def test_span_roundtrip(self, tmp_path):
         t = NativeTracer()

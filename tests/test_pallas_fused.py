@@ -125,6 +125,71 @@ class TestFusedRope:
                                    rtol=1e-5)
 
 
+class TestRowBlocks:
+    """Row blocks are sized from the width and dtype so the pipelined
+    buffers stay well under the v5e's 16 MiB scoped VMEM — a constant
+    256 rows asked for 16-22 MiB at hidden 4096 / 32x128 heads."""
+
+    @pytest.mark.parametrize("rows,row_bytes,n,want", [
+        (4096, 4096 * 2, 4, 64),     # rms+residual, rope: 7B widths, bf16
+        (4096, 4096 * 2, 2, 128),    # rms without residual
+        (4096, 4096 * 4, 4, 32),     # the same in fp32
+        (4096, 1024 * 4, 4, 128),    # h=16 d=64 fp32: the old "~1MB" case
+        (4096, 128 * 4, 2, 256),     # narrow rows: capped at 256
+        (5, 4096 * 2, 4, 8),         # fewer rows than a block
+        (4096, 1 << 20, 4, 8),       # absurdly wide: floor of 8
+    ])
+    def test_block_rows(self, rows, row_bytes, n, want):
+        got = fused._block_rows(rows, row_bytes, n)
+        assert got == want and got % 8 == 0
+        if want > 8:
+            assert 2 * n * got * row_bytes <= fused._VMEM_BLOCK_BUDGET
+
+    def test_rope_kernel_many_row_blocks(self, interpret):
+        """Several grid steps at a width where the block is < rows."""
+        b, s, h, d = 1, 160, 32, 128
+        q, k = jnp.asarray(rnd(b, s, h, d)), jnp.asarray(rnd(b, s, h, d))
+        ang = jnp.asarray(rnd(s, d))
+        oq, ok = fused.fused_rope(q, k, jnp.cos(ang), jnp.sin(ang))
+        rq, rk = fused._rope_ref(q, k, jnp.cos(ang), jnp.sin(ang))
+        np.testing.assert_allclose(np.asarray(oq), np.asarray(rq),
+                                   rtol=1e-6, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(ok), np.asarray(rk),
+                                   rtol=1e-6, atol=1e-6)
+
+
+class TestPallasGate:
+    """``fused.pallas_gate``: jax cannot partition a Mosaic kernel under
+    GSPMD, so the gate closes under a multi-device current mesh unless
+    the trace is inside a fully-manual shard_map."""
+
+    def test_closed_off_tpu(self):
+        assert not fused.pallas_gate()
+        assert fused.pallas_gate(force_interpret=True)
+
+    def test_mesh_closes_it_outside_shard_map_only(self):
+        from jax.sharding import PartitionSpec as P
+        from paddle_tpu.distributed import mesh as pmesh
+        mesh = pmesh.build_device_mesh({"dp": 2, "mp": 2},
+                                       jax.devices()[:4])
+        pmesh.set_current_mesh(mesh)
+        try:
+            assert not fused.pallas_gate(force_interpret=True)
+            seen = []
+            for names in (None, {"mp"}):        # fully / partly manual
+                kw = {} if names is None else {"axis_names": names}
+                jax.eval_shape(jax.shard_map(
+                    lambda x: (seen.append(
+                        fused.pallas_gate(force_interpret=True)), x)[1],
+                    mesh=mesh, in_specs=P("mp"), out_specs=P("mp"),
+                    check_vma=False, **kw),
+                    jax.ShapeDtypeStruct((8,), jnp.float32))
+            assert seen == [True, False]
+        finally:
+            pmesh.set_current_mesh(None)
+        assert fused.pallas_gate(force_interpret=True)
+
+
 class TestFusedAdamW:
     def test_kernel_matches_ref(self, interpret):
         shape = (33, 40)  # 1320 elements > 1024 triggers the kernel path
@@ -156,6 +221,26 @@ class TestFusedAdamW:
                                     0.01, 1)
         np.testing.assert_allclose(np.asarray(po, np.float32),
                                    np.asarray(rp, np.float32), rtol=2e-2)
+
+    def test_bf16_moments_keep_their_dtype(self, interpret):
+        """The bf16 params + bf16 moments setting: every tensor is read
+        and written in its own dtype (the kernel used to hand back fp32
+        moments, i.e. two fp32 copies of the model per step on a TPU);
+        the update itself runs in fp32."""
+        shape = (48, 64)
+        p = jnp.asarray(rnd(*shape) - 0.5, jnp.bfloat16)
+        g = jnp.asarray(rnd(*shape) - 0.5, jnp.bfloat16)
+        m = jnp.asarray(rnd(*shape) * 0.1, jnp.bfloat16)
+        v = jnp.asarray(rnd(*shape) * 0.01, jnp.bfloat16)
+        kw = dict(lr=1e-2, beta1=0.9, beta2=0.99, eps=1e-8,
+                  weight_decay=0.05, step=3)
+        outs = fused.fused_adamw(p, g, m, v, **kw)
+        assert [o.dtype for o in outs] == [jnp.bfloat16] * 3
+        f32 = [a.astype(jnp.float32) for a in (p, g, m, v)]
+        refs = fused._adamw_ref(*f32, **kw)
+        for o, r in zip(outs, refs):
+            np.testing.assert_allclose(np.asarray(o, np.float32),
+                                       np.asarray(r), rtol=1e-2, atol=1e-3)
 
     def test_optimizer_adamw_uses_fused_math(self):
         # AdamW.step must follow the fused_adamw trajectory exactly
@@ -256,6 +341,46 @@ class TestFlashAttention:
                            1.0 / np.sqrt(q.shape[-1]))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-5)
+
+    def test_failure_inside_a_pallas_route_raises(self, fa_interpret,
+                                                  monkeypatch):
+        """No fallback that hides the device: a route that does not take
+        the shape returns None (routing, recorded); a route that FAILS
+        propagates instead of dropping to the O(s^2) XLA path."""
+        fa = fa_interpret
+        q, k, v = self._qkv()
+
+        def boom(*a, **kw):
+            raise RuntimeError("Mosaic refused")
+        monkeypatch.setattr(fa, "flash_attention_fused", boom)
+        with pytest.raises(RuntimeError, match="Mosaic refused"):
+            fa.sdpa(q, k, v, is_causal=True)
+
+    def test_unsupported_shape_is_a_recorded_routing_decision(
+            self, fa_interpret):
+        fa = fa_interpret
+        q, k, v = self._qkv(s=60)            # 60 tiles by nothing >= 8
+        out = fa.sdpa(q, k, v, is_causal=True)
+        assert fa.sdpa_last_dispatch() == "xla"
+        ref = fa._xla_sdpa(q, k, v, None, True, 0.0,
+                           1.0 / np.sqrt(q.shape[-1]))
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   rtol=1e-5)
+        q, k, v = self._qkv(s=64)
+        fa.sdpa(q, k, v, is_causal=True)
+        assert fa.sdpa_last_dispatch() == "fused_flash"
+
+    def test_jax_flash_route_gate(self):
+        """jax's kernel takes equal heads, whole 128-lane sequences and a
+        head_dim <= 128 or a multiple of it; everything else is routed on
+        (None), never tried-and-caught."""
+        from paddle_tpu.ops.pallas import flash_attention as fa
+        for shape in ((100, 128, 4, 4, 64), (128, 128, 4, 2, 64),
+                      (128, 128, 4, 4, 192)):
+            sq, sk, h, hk, d = shape
+            q = jnp.zeros((1, sq, h, d))
+            k = jnp.zeros((1, sk, hk, d))
+            assert fa._jax_tpu_flash(q, k, k, True, 1.0) is None, shape
 
     def test_jax_flash_block_heuristic(self):
         # PROFILE_r03: the kernel's 128-block default was the MFU

@@ -179,9 +179,9 @@ class TestInlinePjit:
             return jax.nn.log_softmax(x, axis=-1) * 2.0
         x = jnp.asarray(np.random.RandomState(0).randn(4, 8), jnp.float32)
         closed = jax.make_jaxpr(f)(x)
-        assert any(e.primitive.name == "pjit" for e in closed.jaxpr.eqns)
+        assert any(e.primitive.name == "jit" for e in closed.jaxpr.eqns)
         inlined = inline_pjit(closed)
-        assert not any(e.primitive.name == "pjit"
+        assert not any(e.primitive.name == "jit"
                        for e in inlined.jaxpr.eqns)
         np.testing.assert_array_equal(np.asarray(_eval(inlined, x)),
                                       np.asarray(f(x)))
@@ -191,7 +191,7 @@ class TestInlinePjit:
             return jnp.var(x, axis=-1)     # pjit(_var) contains _where
         x = jnp.asarray(np.random.RandomState(0).randn(4, 8), jnp.float32)
         inlined = inline_pjit(jax.make_jaxpr(f)(x))
-        assert not any(e.primitive.name == "pjit"
+        assert not any(e.primitive.name == "jit"
                        for e in inlined.jaxpr.eqns)
         np.testing.assert_allclose(np.asarray(_eval(inlined, x)),
                                    np.asarray(f(x)), rtol=1e-6)

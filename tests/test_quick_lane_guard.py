@@ -1,6 +1,6 @@
-"""Lane guard for the paged-serving/pallas additions (same contract as
-test_session_tools: tooling breaks must surface as test failures, not
-as silently-skipped coverage). Pins that
+"""Lane guard for the paged-serving/pallas additions (tooling breaks
+must surface as test failures, not as silently-skipped coverage). Pins
+that
 
 - every serving + paged-pallas test is COLLECTED by the quick lane
   (``-m 'not slow'``) — a stray ``slow`` mark or import error would

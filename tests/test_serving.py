@@ -193,8 +193,6 @@ class TestScheduler:
         assert s.pending() == 3
 
 
-@pytest.mark.skipif(not hasattr(jax, "export"),
-                    reason="jax.export unavailable in this jax build")
 class TestArtifactServing:
     def test_exported_engine_serves_same_stream(self, serving_setup,
                                                 tmp_path):
@@ -249,9 +247,8 @@ class TestArtifactServing:
 class TestArtifactSnapshotIdentity:
     """PR 5 carried follow-up: engine snapshots record the backing AOT
     artifact's fingerprint, and a restore onto a DIFFERENT artifact is
-    refused. Pinned with a stub backend (this environment lacks
-    jax.export; the artifact-level fingerprint computation rides the
-    skipif-gated TestArtifactServing tests)."""
+    refused. Pinned with a stub backend; the artifact-level fingerprint
+    computation rides the TestArtifactServing tests."""
 
     class _FingerprintBackend:
         """Stub of an ArtifactStepBackend: proxies the live model

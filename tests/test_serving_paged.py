@@ -479,9 +479,8 @@ class TestPagedScheduling:
 class TestPagedArtifact:
     """PR 4 carried follow-up: export_decoder(engine_paged=True) ships
     the paged engine's TWO programs with recorded arities, and
-    PagedArtifactStepBackend serves them. The stub test runs in THIS
-    environment; the artifact-level test rides the jax.export skipif
-    (same split as the PR 7 block_outputs=5 pins)."""
+    PagedArtifactStepBackend serves them: a stub-backend test plus
+    the artifact-level test through jax.export."""
 
     class _PagedProxyBackend:
         """Stands in for a PagedArtifactStepBackend: proxies the live
@@ -516,8 +515,6 @@ class TestPagedArtifact:
             np.testing.assert_array_equal(
                 res[rid], _ref(model, p, 5, temperature=0.0))
 
-    @pytest.mark.skipif(not hasattr(jax, "export"),
-                        reason="jax.export unavailable in this build")
     def test_paged_artifact_arity_and_bit_identity(self, paged_setup,
                                                    tmp_path):
         """The exported paged artifact records both program arities

@@ -10,7 +10,7 @@
 #     double-free at exit; documented in tests/test_resilience.py).
 #     This invocation passes `-p no:xdist -p no:randomly` and is immune
 #     — re-check the landmine on every jaxlib upgrade.
-#   - a stale-cache guard: a tests/.jax_cache accumulated across MANY
+#   - a stale-cache guard: a .jax_cache accumulated across MANY
 #     sessions (~140 entries, PR 7 data point) reproducibly segfaults
 #     the full suite mid-GC at a later paged-backend jax.jit even with
 #     the plugins disabled. Entry-count/age heuristic below wipes it
@@ -25,7 +25,7 @@ cd "$(dirname "$0")/.."
 # landmine): wipe when the entry count says "many sessions" or the
 # oldest entry says "not from today's session". A fresh worktree starts
 # cache-empty, which is why seed-comparison runs never crash.
-CACHE="tests/.jax_cache"
+CACHE=".jax_cache"
 CACHE_MAX_ENTRIES="${TIER1_CACHE_MAX_ENTRIES:-100}"
 CACHE_MAX_AGE_H="${TIER1_CACHE_MAX_AGE_H:-24}"
 if [ -d "$CACHE" ]; then
@@ -63,8 +63,8 @@ echo "tier1: re-anchor check — re-verify the compile-cache landmine on" \
 echo "tier1: landmine note — persistent compile cache + xdist/randomly" \
      "corrupts the native heap on a 2nd in-process paged-backend" \
      "compile; this runner passes -p no:xdist -p no:randomly (immune)." \
-     "A STALE multi-session tests/.jax_cache can still segfault the" \
-     "full suite mid-GC: on a native crash, rm -rf tests/.jax_cache" \
+     "A STALE multi-session .jax_cache can still segfault the" \
+     "full suite mid-GC: on a native crash, rm -rf .jax_cache" \
      "and re-run before blaming the tree. Re-check on each jaxlib" \
      "upgrade (ROADMAP env note)."
 
