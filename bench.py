@@ -39,7 +39,6 @@ PEAK_FLOPS = {"TPU v5 lite": 197e12}
 TPU_DEADLINE_S = env_float("BENCH_TPU_DEADLINE_S", 1100)
 COMMS_DEADLINE_S = env_float("BENCH_COMMS_DEADLINE_S", 240)
 PASSES_DEADLINE_S = env_float("BENCH_PASSES_DEADLINE_S", 240)
-OBS_DEADLINE_S = env_float("BENCH_OBS_DEADLINE_S", 240)
 SERVING_SPEC_DEADLINE_S = env_float("BENCH_SERVING_SPEC_DEADLINE_S", 240)
 SERVING_TP_DEADLINE_S = env_float("BENCH_SERVING_TP_DEADLINE_S", 300)
 SERVING_QUANT_DEADLINE_S = env_float("BENCH_SERVING_QUANT_DEADLINE_S",
@@ -830,22 +829,6 @@ def _child_passes():
     print("BENCH_JSON " + json.dumps(out), flush=True)
 
 
-def _child_observability():
-    """observability stage: the serving stream with metrics + request
-    tracing + flight recorder fully armed vs disarmed
-    (observability/microbench.py, CPU lane). Pins the <2%-enabled /
-    ~0%-disabled overhead contract every round, plus proof the
-    artifacts exist: metric families sampled, request/host spans and
-    tick markers in one loadable merged chrome trace."""
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    from paddle_tpu.observability.microbench import run_observability_bench
-    out = run_observability_bench(
-        requests=env_int("BENCH_OBS_REQUESTS", 8),
-        max_new=env_int("BENCH_OBS_MAX_NEW", 24))
-    print("BENCH_JSON " + json.dumps(out), flush=True)
-
-
 def _child_serving_spec():
     """serving-spec stage: the draft-verify engine (serving/spec.py)
     A/B'd against the plain slot-pool engine on a repetitive-
@@ -1097,7 +1080,6 @@ def _emit_final(result):
 CPU_STAGES = (
     ("comms", "--child-comms", _child_comms, COMMS_DEADLINE_S),
     ("passes", "--child-passes", _child_passes, PASSES_DEADLINE_S),
-    ("observability", "--child-observability", _child_observability, OBS_DEADLINE_S),
     ("serving-tp", "--child-serving-tp", _child_serving_tp, SERVING_TP_DEADLINE_S),
     ("serving-spec", "--child-serving-spec", _child_serving_spec, SERVING_SPEC_DEADLINE_S),
     ("serving-quant", "--child-serving-quant", _child_serving_quant, SERVING_QUANT_DEADLINE_S),

@@ -1,8 +1,9 @@
-"""Native runtime core: C++ host tracer, TCPStore, shared-memory queue.
+"""Native runtime core: TCPStore, shared-memory queue.
 
 Reference parity: the reference's native host runtime —
-paddle/fluid/platform/profiler (host tracer), paddle/phi/core/distributed/
-store/tcp_store (rendezvous), DataLoader shm transport [— verify].
+paddle/phi/core/distributed/store/tcp_store (rendezvous), DataLoader shm
+transport [— verify]. (Host spans are Python's one recorder,
+``observability/tracing.py``: the C++ host tracer went with PR 25.)
 Compute stays with XLA; these are the host-side native subsystems a TPU
 framework still genuinely needs in C++.
 
@@ -81,11 +82,6 @@ def load_native():
         except (OSError, subprocess.SubprocessError) as e:
             _build_error = e
             return None
-        lib.pt_trace_begin.argtypes = [ctypes.c_char_p]
-        lib.pt_trace_instant.argtypes = [ctypes.c_char_p]
-        lib.pt_trace_counter.argtypes = [ctypes.c_char_p, ctypes.c_int64]
-        lib.pt_trace_dump.argtypes = [ctypes.c_char_p, ctypes.c_int]
-        lib.pt_trace_event_count.restype = ctypes.c_int64
         lib.pt_store_server_start.argtypes = [ctypes.c_int]
         lib.pt_store_server_start.restype = ctypes.c_void_p
         lib.pt_store_server_port.argtypes = [ctypes.c_void_p]
@@ -124,8 +120,7 @@ def native_available() -> bool:
     return load_native() is not None
 
 
-from .native_api import (NativeTracer, TCPStore, ShmQueue,  # noqa: E402
-                         MasterDaemon)
+from .native_api import TCPStore, ShmQueue, MasterDaemon  # noqa: E402
 
-__all__ = ["load_native", "native_available", "NativeTracer", "TCPStore",
-           "ShmQueue", "MasterDaemon"]
+__all__ = ["load_native", "native_available", "TCPStore", "ShmQueue",
+           "MasterDaemon"]

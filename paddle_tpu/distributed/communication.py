@@ -279,30 +279,25 @@ class Task:
         return self._thread is None or not self._thread.is_alive()
 
 
-def send(tensor, dst=0, group=None, sync_op=True):
-    """Host-level point-to-point send over the TCPStore transport.
-    ``dst`` is the GLOBAL rank (reference semantics, same convention as
-    broadcast/scatter); ``group`` only namespaces the exchange."""
+def _reserve(seqs: dict, src: int, dst: int, group) -> str:
+    """The store key of the next message ``src`` -> ``dst``. Taken in the
+    CALLER's thread: ``isend(a); isend(b)`` (and ``batch_isend_irecv``)
+    number their messages in call order, whichever worker thread runs
+    first."""
+    gid = group.id if group else 0
+    with _SEQ_LOCK:
+        seq = seqs.get((gid, src, dst), 0)
+        seqs[(gid, src, dst)] = seq + 1
+    return f"p2p/{gid}/{src}->{dst}/{seq}"
+
+
+def _put(key: str, tensor):
     _warn_if_bulk(_val(tensor), "send")
-    store = _get_store()
-    src = _my_rank()
-    gid = group.id if group else 0
-    with _SEQ_LOCK:
-        seq = _SEND_SEQ.get((gid, src, dst), 0)
-        _SEND_SEQ[(gid, src, dst)] = seq + 1
-    store.set(f"p2p/{gid}/{src}->{dst}/{seq}", _pack(_val(tensor)))
-    return None
+    _get_store().set(key, _pack(_val(tensor)))
 
 
-def recv(tensor, src=0, group=None, sync_op=True):
-    """Blocking receive matching :func:`send` from GLOBAL rank ``src``."""
+def _take(key: str, tensor):
     store = _get_store()
-    me = _my_rank()
-    gid = group.id if group else 0
-    with _SEQ_LOCK:
-        seq = _RECV_SEQ.get((gid, src, me), 0)
-        _RECV_SEQ[(gid, src, me)] = seq + 1
-    key = f"p2p/{gid}/{src}->{me}/{seq}"
     store.wait(key)
     v = _unpack(store.get(key))
     store.delete_key(key)
@@ -311,6 +306,18 @@ def recv(tensor, src=0, group=None, sync_op=True):
                              if v.dtype != _val(tensor).dtype else v)
         return tensor
     return Tensor(v)
+
+
+def send(tensor, dst=0, group=None, sync_op=True):
+    """Host-level point-to-point send over the TCPStore transport.
+    ``dst`` is the GLOBAL rank (reference semantics, same convention as
+    broadcast/scatter); ``group`` only namespaces the exchange."""
+    _put(_reserve(_SEND_SEQ, _my_rank(), dst, group), tensor)
+
+
+def recv(tensor, src=0, group=None, sync_op=True):
+    """Blocking receive matching :func:`send` from GLOBAL rank ``src``."""
+    return _take(_reserve(_RECV_SEQ, src, _my_rank(), group), tensor)
 
 
 def _async(fn, *args, **kw):
@@ -328,11 +335,12 @@ def _async(fn, *args, **kw):
 
 
 def isend(tensor, dst=0, group=None):
-    return _async(send, tensor, dst, group)
+    return _async(_put, _reserve(_SEND_SEQ, _my_rank(), dst, group), tensor)
 
 
 def irecv(tensor, src=0, group=None):
-    return _async(recv, tensor, src, group)
+    return _async(_take, _reserve(_RECV_SEQ, src, _my_rank(), group),
+                  tensor)
 
 
 @dataclasses.dataclass
@@ -355,10 +363,10 @@ def batch_isend_irecv(p2p_op_list):
     tasks = []
     for p in p2p_op_list:
         if p.op in (send, isend):
-            tasks.append(_async(send, p.tensor, p.peer, p.group))
+            tasks.append(isend(p.tensor, p.peer, p.group))
     for p in p2p_op_list:
         if p.op in (recv, irecv):
-            tasks.append(_async(recv, p.tensor, p.peer, p.group))
+            tasks.append(irecv(p.tensor, p.peer, p.group))
     return tasks
 
 

@@ -19,6 +19,7 @@ from typing import Iterable, Optional
 
 import numpy as np
 
+from ..observability.tracing import span as _span
 from ..tensor import Tensor, to_tensor
 
 __all__ = ["Dataset", "IterableDataset", "TensorDataset", "ChainDataset",
@@ -315,7 +316,9 @@ class DataLoader:
         return len(self.batch_sampler)
 
     def _fetch(self, indices):
-        return self.collate_fn([self.dataset[i] for i in indices])
+        data = [self.dataset[i] for i in indices]
+        with _span("io.loader_collate", batch=len(data)):
+            return self.collate_fn(data)
 
     def _iter_iterable(self):
         batch = []
@@ -416,8 +419,8 @@ class DataLoader:
                     slice_s = 5.0 if deadline is None \
                         else min(5.0, max(0.01, deadline - waited))
                     try:
-                        data = pickle.loads(
-                            queues[w].get(timeout=slice_s))
+                        with _span("io.loader_wait", worker=w):
+                            payload = queues[w].get(timeout=slice_s)
                         break
                     except TimeoutError:
                         waited += slice_s
@@ -428,9 +431,15 @@ class DataLoader:
                             ) from None
                         if deadline is not None and waited >= deadline:
                             raise
+                with _span("io.loader_unpickle"):
+                    data = pickle.loads(payload)
                 if isinstance(data, Exception):
                     raise data
-                yield self.collate_fn(data)
+                # np.stack + to_tensor: the host-to-device copy is here
+                with _span("io.loader_collate", batch=len(data),
+                           bytes=len(payload)):
+                    batch = self.collate_fn(data)
+                yield batch
         finally:
             for p in procs:
                 if p.is_alive():
@@ -475,7 +484,8 @@ class DataLoader:
                 if next_emit in buffered:
                     data = buffered.pop(next_emit)
                 else:
-                    i = q.get()
+                    with _span("io.loader_wait"):
+                        i = q.get()
                     data = results.pop(i)
                     if i != next_emit:
                         buffered[i] = data

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import framework
+from ..observability.tracing import named_program, span as _span
 from ..tensor import Tensor, Parameter, apply_op
 from ..nn.layer import Layer
 
@@ -427,6 +428,13 @@ def to_static(function=None, input_spec=None, build_strategy=None,
 # TrainStep: fused fwd+bwd+opt — the perf path
 # ---------------------------------------------------------------------------
 
+# The train step's name as a device trace shows it ("XLA Modules" line):
+# benchmark/kinds/train.py reads device time by it, so it is set on
+# purpose where the function is built (``tracing.named_program``) and
+# pinned in tests/test_trace_names.py.
+TRAIN_STEP_PROGRAM = "jit_step"
+
+
 class TrainStep:
     """Compile model+loss+optimizer into one donated XLA train step.
 
@@ -447,6 +455,7 @@ class TrainStep:
         self._donate = donate
         self._pnames = None
         self._compiled_info = None
+        self._steps = 0                 # calls so far: the span's ``step``
 
     def _build(self):
         model, loss_fn, opt = self.model, self.loss_fn, self.optimizer
@@ -496,7 +505,8 @@ class TrainStep:
 
         donate = (0, 1) if self._donate else ()
         self._step_fn = step            # uncompiled core (run_steps scans it)
-        self._jitted = jax.jit(step, donate_argnums=donate)
+        self._jitted = jax.jit(named_program(step, TRAIN_STEP_PROGRAM),
+                               donate_argnums=donate)
         self._ptensors, self._btensors, self._frozen = \
             ptensors, btensors, frozen
 
@@ -526,8 +536,12 @@ class TrainStep:
         """batch: pytree of Tensors/arrays. Returns loss Tensor (+aux)."""
         if self._jitted is None:
             self._build()
-        loss, new_params, new_opt_state, new_bufs, aux = self._jitted(
-            *self._step_args(batch))
+        # flattening the arguments and the enqueue; the device's time is
+        # the caller's (whoever fetches the loss waits for it)
+        with _span("train.step_dispatch", step=self._steps):
+            loss, new_params, new_opt_state, new_bufs, aux = self._jitted(
+                *self._step_args(batch))
+        self._steps += 1
         for n, v in new_params.items():
             self._ptensors[n]._update_value(v)
         for n, v in new_bufs.items():

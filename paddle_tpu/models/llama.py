@@ -306,8 +306,13 @@ class LlamaDecoderLayer(nn.Layer):
                                            cache, pos, pad, block_table)
             return self._decode_forward(x, cos, sin, attn_mask, cache,
                                         pos, pad, block_table)
-        h = x + self.self_attn(self.input_layernorm(x), cos, sin, attn_mask)
-        out = h + self.mlp(self.post_attention_layernorm(h))
+        # named scopes are metadata: the trace names a layer's halves,
+        # the compiled program is the same program (test-pinned)
+        with jax.named_scope("attn"):
+            h = x + self.self_attn(self.input_layernorm(x), cos, sin,
+                                   attn_mask)
+        with jax.named_scope("mlp"):
+            out = h + self.mlp(self.post_attention_layernorm(h))
         if self._seq_parallel:
             from ..distributed.fleet.meta_parallel import _constrain
             out = _constrain(out, P(None, "sep", None))
@@ -319,12 +324,15 @@ class LlamaDecoderLayer(nn.Layer):
         """The cache-path layer body — THE decode-layer math, whether
         traced inline (default) or inside a marked region (megakernel
         fusion)."""
-        a, new_cache = self.self_attn(self.input_layernorm(x), cos,
-                                      sin, attn_mask, cache=cache,
-                                      pos=pos, pad=pad,
-                                      block_table=block_table)
-        h = x + a
-        return h + self.mlp(self.post_attention_layernorm(h)), new_cache
+        with jax.named_scope("attn"):
+            a, new_cache = self.self_attn(self.input_layernorm(x), cos,
+                                          sin, attn_mask, cache=cache,
+                                          pos=pos, pad=pad,
+                                          block_table=block_table)
+            h = x + a
+        with jax.named_scope("mlp"):
+            out = h + self.mlp(self.post_attention_layernorm(h))
+        return out, new_cache
 
     def _markable(self, x, pos, pad, block_table) -> bool:
         """Whether this call is the slot-pool decode shape the megakernel
@@ -690,22 +698,24 @@ class LlamaForCausalLM(nn.Layer, GenerationMixin):
                 # nn.Linear stores (in, out); the kernel wants (V, D)
                 from ..ops.manipulation import transpose
                 w = transpose(w, (1, 0))
-            if c.tensor_parallel:
-                # resolves to the single-shard kernel when no mp mesh
-                # axis is active
-                loss = parallel_fused_linear_cross_entropy(
-                    h, w, labels, axis="mp",
-                    num_chunks=c.fused_head_ce_chunks)
-            else:
-                loss = fused_linear_cross_entropy(
-                    h, w, labels, num_chunks=c.fused_head_ce_chunks)
+            with jax.named_scope("lm_head"):
+                if c.tensor_parallel:
+                    # resolves to the single-shard kernel when no mp
+                    # mesh axis is active
+                    loss = parallel_fused_linear_cross_entropy(
+                        h, w, labels, axis="mp",
+                        num_chunks=c.fused_head_ce_chunks)
+                else:
+                    loss = fused_linear_cross_entropy(
+                        h, w, labels, num_chunks=c.fused_head_ce_chunks)
             return loss, None
-        if self.lm_head is not None:
-            logits = self.lm_head(h)
-        else:
-            from ..ops.math import matmul
-            logits = matmul(h, self.llama.embed_tokens.weight,
-                            transpose_y=True)
+        with jax.named_scope("lm_head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(h)
+            else:
+                from ..ops.math import matmul
+                logits = matmul(h, self.llama.embed_tokens.weight,
+                                transpose_y=True)
         if cache is not None:
             # tensor-parallel serving: the vocab-sharded lm_head shards
             # gather into full logits through the collectives all-gather

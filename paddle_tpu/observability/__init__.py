@@ -1,7 +1,6 @@
-"""Serving-grade observability: metrics registry, request tracing,
-crash flight recorder.
-
-Three independent planes, all host-side, all default-off or O(1):
+"""Observability: metrics registry, one host-span recorder, request
+tracing, crash flight recorder. All host-side; nothing ever enters a
+compiled program.
 
 - :mod:`.metrics` — process-global Counter/Gauge/Histogram registry
   with labels; lock-free no-op when disabled (``PT_METRICS=1`` /
@@ -10,12 +9,18 @@ Three independent planes, all host-side, all default-off or O(1):
   Server tick/queue/shed/deadline, engine decode/compile, BlockManager
   pool/prefix-hit, fault fires, resilience retries/breaker, collectives
   bytes + int8 error bound, pass rewrite counts.
-- :mod:`.tracing` — per-request lifecycle traces
-  (``PT_TRACE_REQUESTS=1``): queue-wait, prefill (chunk) spans, decode
-  residency, harvest, retries, exactly one terminal state per request;
-  exported as chrome-trace JSON on the SAME clock as the profiler's
-  ``RecordEvent`` ring so one Perfetto view shows ticks, host spans and
-  request rows aligned.
+- :mod:`.tracing` — THE host-span recorder of the program:
+  ``span(name, **ids)`` writes each span to a bounded in-memory ring
+  (always on, no switch) and into ``jax.profiler``'s own trace while a
+  session is open. The serving tick and its phases, the DataLoader's
+  wait / unpickle / collate, ``TrainStep``'s dispatch and every
+  ``profiler.RecordEvent`` site are spans of it; the chrome-trace
+  export, ``paddle_tpu.profiler`` and the benchmark's per-layer metric
+  readers all read that one ring. The same module holds the opt-in
+  per-request lifecycle traces (``PT_TRACE_REQUESTS=1``): queue-wait,
+  prefill (chunk) spans, decode residency, harvest, exactly one
+  terminal state per request, exported on the ring's clock so one
+  Perfetto view shows ticks, host spans and request rows aligned.
 - :mod:`.flight` — a bounded ring of recent structured events
   (``PT_FLIGHT_RECORDER_SIZE``) that auto-dumps on circuit-open,
   dumps + rides along with ``Server.snapshot()``, and restores with it.

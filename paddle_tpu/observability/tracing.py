@@ -1,52 +1,221 @@
-"""Per-request lifecycle traces for the serving stack.
+"""Host-side tracing: ONE span recorder for the whole program, plus
+per-request lifecycle traces for the serving stack.
 
-Every request the Server admits gets one :class:`RequestTrace`: a span
-for its queue wait, one span per prefill dispatch (whole-prompt on the
-dense engine, one per chunk on the paged engine), a decode-residency
-span covering its time live in the slot pool, harvest instants, and
-EXACTLY ONE terminal marker — ``terminal:completed`` or
-``terminal:<RequestFailure reason>`` (the chaos tests pin the
-exactly-one invariant: a request whose trace never terminates, or
-terminates twice, is a serving-loop bug).
+**The recorder.** ``span(name, **ids)`` (a context manager; ``begin`` /
+``end`` for a pair that cannot be a ``with`` block) stamps ``start`` and
+``dur`` on ``time.perf_counter_ns``, remembers the span open on the same
+thread when it began (``parent``, a per-thread stack) and carries the
+ids it was given (``tick=<Server._clock>``, ``rid=<request id>``,
+``step=<n>``). One call, two sinks:
 
-Clock discipline: spans are stamped with ``time.perf_counter_ns()/1e3``
-microseconds — the SAME clock and unit the profiler's ``RecordEvent``
-host ring uses — so :func:`export_chrome_trace` merges request spans,
-host spans, and the Server's tick markers into ONE chrome-trace JSON
-whose rows are already aligned in Perfetto (and sit on the same
-timeline as a concurrently-captured ``jax.profiler`` device trace,
-which also derives from the host monotonic clock).
+- a process-global ring, ``deque(maxlen=65536)`` of finished spans. It
+  is always on and bounded, as the flight recorder's ring is: there is
+  no switch, so the price (1.4 us a span on the chip's host, 2.8 us
+  inside a profiler session: PR 25's chip run, PERF.md section 5; at
+  most about 18 MB when the ring is full) is always paid, and spans are
+  recorded at tick / step / batch granularity only — never per token,
+  per slot or per layer, and never inside a compiled program;
+- a ``jax.profiler.TraceAnnotation`` entered and left with the span, so
+  while a ``jax.profiler`` session is open the same span lies in the
+  ``/host:CPU`` plane of the ``.xplane.pb``, on the device trace's
+  clock. With no session open that costs one ``is_enabled()`` check.
 
-Row layout in the exported trace: ``tid 0`` is the server row (tick
-spans, retry/breaker instants); each request renders on its own thread
-row named ``request <id>``.
+Readers of the ring: :func:`since` (spans wholly inside an interval),
+:func:`self_times` (duration minus the part the children cover, by
+name), :func:`chrome_events` / :func:`export_chrome_trace` (Perfetto),
+``paddle_tpu.profiler`` (``RecordEvent`` is a ring span; a ``Profiler``
+exports the spans of its recording intervals) and the benchmark's
+per-layer metric readers (``benchmark/layer_metrics``).
 
-Disabled (the default; arm with ``PT_TRACE_REQUESTS=1`` or
-``ObservabilityConfig(trace_requests=True)``) every method returns on a
-single bool check, and the Server leaves ``engine.tracer`` as None so
-the engine hot paths pay one ``is None`` test.
+Clocks: ring stamps are ``perf_counter_ns`` (CLOCK_MONOTONIC); the
+``.xplane.pb`` stamps its events in nanoseconds since the profiler
+session began. The two tick at the same rate but do NOT share an
+origin (the older claim here, that request spans "sit on the same
+timeline" as a ``jax.profiler`` trace, was wrong): a ring span and its
+twin in the xplane differ by one offset per trace — minus the
+``perf_counter`` reading at the session's start. Measured on the chip's
+host (PR 25; PERF.md section 5): over a traced interval that offset
+wandered by under 8 us, and a twin was 2.3-2.7 us longer than its ring
+span (at most 10.3 us), because the annotation opens before the ring's
+first stamp and closes after its second. Lay the two accounts side by
+side by that offset, not by equal stamps.
+
+**Request traces.** Every request the Server admits gets one
+:class:`RequestTrace`: a span for its queue wait, one span per prefill
+dispatch (whole-prompt on the dense engine, one per chunk on the paged
+engine), a decode-residency span covering its time live in the slot
+pool, harvest instants, and EXACTLY ONE terminal marker —
+``terminal:completed`` or ``terminal:<RequestFailure reason>`` (the
+chaos tests pin the exactly-one invariant: a request whose trace never
+terminates, or terminates twice, is a serving-loop bug). They allocate
+per request, so they stay opt-in (``PT_TRACE_REQUESTS=1`` or
+``ObservabilityConfig(trace_requests=True)``); disabled, every method
+returns on a single bool check and the Server leaves ``engine.tracer``
+as None so the engine hot paths pay one ``is None`` test. Request spans
+are stamped in ``perf_counter_ns()/1e3`` microseconds, the ring's clock
+and chrome-trace's unit.
+
+Row layout of :func:`export_chrome_trace`: ``tid 0`` is the server row
+(the ring's ``serving.*`` spans: ticks and their phases); other ring
+spans render on their own thread's row; each request renders on a row
+named ``request <id>``.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, Iterable, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from ..utils.flags import env_bool
 
-__all__ = ["RequestTracer", "RequestTrace", "export_chrome_trace",
-           "now_us"]
+__all__ = ["Span", "span", "begin", "end", "since", "self_times", "clear",
+           "chrome_events", "named_program", "RequestTracer",
+           "RequestTrace", "export_chrome_trace", "now_us", "RING_SIZE"]
 
 _SERVER_TID = 0
+_SERVER_PREFIX = "serving."
+
+RING_SIZE = 65536
+_RING: deque = deque(maxlen=RING_SIZE)     # finished spans, oldest first
+_STACKS = threading.local()                # .open: this thread's open spans
+_IDS = itertools.count(1)
+_now_ns = time.perf_counter_ns
+_thread_id = threading.get_ident
+_session_open = TraceAnnotation.is_enabled  # a jax.profiler session is open
 
 
 def now_us() -> float:
-    """Microseconds on the RecordEvent clock (perf_counter)."""
-    return time.perf_counter_ns() / 1000.0
+    """Microseconds on the ring's clock (perf_counter)."""
+    return _now_ns() / 1000.0
+
+
+class Span:
+    """One span, and — once it has ended — its own record in the ring.
+
+    ``name``; ``start`` and ``dur`` in ``perf_counter_ns`` nanoseconds
+    (``dur`` is None while the span is open); ``id`` (process-unique);
+    ``parent``, the id of the span open on the same thread when this one
+    began (None at the top); ``tid``, the thread; ``ids``, the keywords
+    it was given. Use as ``with span("serving.tick", tick=n):`` or as
+    ``s = begin(...)`` ... ``end(s)``; spans of one thread must end in
+    the reverse of the order they began."""
+
+    __slots__ = ("name", "ids", "id", "parent", "tid", "start", "dur",
+                 "_ta")
+
+    def __init__(self, name: str, **ids):
+        self.name = name
+        self.ids = ids
+        self.dur = None
+
+    def __enter__(self):
+        try:
+            stack = _STACKS.open
+        except AttributeError:
+            stack = _STACKS.open = []
+        self.parent = stack[-1].id if stack else None
+        self.id = next(_IDS)
+        self.tid = _thread_id()
+        stack.append(self)
+        if _session_open():
+            self._ta = TraceAnnotation(self.name, **self.ids)
+            self._ta.__enter__()
+        else:
+            self._ta = None
+        self.start = _now_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur = _now_ns() - self.start
+        if self._ta is not None:
+            self._ta.__exit__(None, None, None)
+            self._ta = None
+        stack = _STACKS.open
+        if stack and stack[-1] is self:
+            stack.pop()
+        elif self in stack:                 # ended out of order: keep the
+            stack.remove(self)              # other spans' parents intact
+        _RING.append(self)
+        return False
+
+    def __repr__(self):
+        return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"start={self.start}, dur={self.dur}, ids={self.ids})")
+
+
+span = Span
+
+
+def begin(name: str, **ids) -> Span:
+    """Open a span that :func:`end` closes — for a pair of calls that
+    cannot share a ``with`` block (``RecordEvent.begin`` / ``.end``)."""
+    return Span(name, **ids).__enter__()
+
+
+def end(s: Span):
+    s.__exit__(None, None, None)
+
+
+def since(t0_s: float, t1_s: Optional[float] = None) -> List[Span]:
+    """The ring's spans that lie WHOLLY inside ``[t0_s, t1_s]`` (seconds
+    on ``time.perf_counter``; no upper end when ``t1_s`` is None), in
+    the order they ended."""
+    lo = t0_s * 1e9
+    hi = float("inf") if t1_s is None else t1_s * 1e9
+    return [r for r in list(_RING)
+            if r.start >= lo and r.start + r.dur <= hi]
+
+
+def self_times(records: Iterable[Span]) -> Dict[str, List[int]]:
+    """``{name: [count, self_ns]}`` over ``records``: a span's self time
+    is its duration minus the durations of its children among the
+    records (children run on the parent's thread, one after another, so
+    they never overlap). Self times of a tree add up to its root."""
+    records = list(records)
+    own = {r.id: r.dur for r in records}
+    for r in records:
+        if r.parent in own:
+            own[r.parent] -= r.dur
+    out: Dict[str, List[int]] = {}
+    for r in records:
+        acc = out.setdefault(r.name, [0, 0])
+        acc[0] += 1
+        acc[1] += own[r.id]
+    return out
+
+
+def clear():
+    """Empty the ring (tests; a long-lived process never needs to)."""
+    _RING.clear()
+
+
+def named_program(fn, program: str):
+    """``fn``, renamed so that ``jax.jit(fn)`` compiles a module called
+    ``program`` (jax names it ``jit_<function name>``): the device
+    trace's "XLA Modules" line then shows a name chosen on purpose, which
+    renaming the Python function cannot move."""
+    fn.__name__ = fn.__qualname__ = program.removeprefix("jit_")
+    return fn
+
+
+def chrome_events(records: Iterable[Span], pid: Optional[int] = None
+                  ) -> List[dict]:
+    """Ring spans as chrome-trace "X" events in microseconds: the
+    ``serving.*`` spans on the server row (tid 0), every other span on
+    the row of the thread that recorded it."""
+    pid = os.getpid() if pid is None else pid
+    return [{"name": r.name, "ph": "X", "pid": pid,
+             "tid": _SERVER_TID if r.name.startswith(_SERVER_PREFIX)
+             else r.tid,
+             "ts": r.start / 1000.0, "dur": r.dur / 1000.0,
+             "args": dict(r.ids)} for r in records]
 
 
 @dataclass
@@ -67,23 +236,21 @@ class RequestTrace:
 
 
 class RequestTracer:
-    """Collects request traces + server-row events for one Server.
+    """Collects the request traces of one Server (the server's own row
+    — ticks and their phases — is in the module's span ring).
 
     Armed, retention is BOUNDED (a long-lived server must not grow
-    without limit): the server row is a ``deque(maxlen=
-    max_server_events)`` and, past ``max_requests`` retained traces,
-    each terminal evicts the oldest already-terminated trace —
-    still-open traces are never evicted, so an in-flight request
-    always reaches its terminal span."""
+    without limit): past ``max_requests`` retained traces, each
+    terminal evicts the oldest already-terminated trace — still-open
+    traces are never evicted, so an in-flight request always reaches
+    its terminal span."""
 
     def __init__(self, enabled: Optional[bool] = None,
-                 max_requests: int = 4096,
-                 max_server_events: int = 65536):
+                 max_requests: int = 4096):
         self.enabled = env_bool("PT_TRACE_REQUESTS") \
             if enabled is None else bool(enabled)
         self.max_requests = max_requests
         self.traces: Dict[int, RequestTrace] = {}
-        self._server_events: deque = deque(maxlen=max_server_events)
         self._lock = threading.Lock()
 
     # -- request lifecycle -------------------------------------------------
@@ -167,20 +334,6 @@ class RequestTracer:
                         if tr.terminals][:excess]:
                 del self.traces[rid]
 
-    # -- server row --------------------------------------------------------
-    def server_span_at(self, name: str, ts_begin_us: float, **args):
-        if not self.enabled:
-            return
-        self._server_events.append(
-            {"name": name, "ts": ts_begin_us,
-             "dur": now_us() - ts_begin_us, "args": args})
-
-    def server_instant(self, name: str, **args):
-        if not self.enabled:
-            return
-        self._server_events.append({"name": name, "ts": now_us(),
-                                    "dur": None, "args": args})
-
     # -- introspection -----------------------------------------------------
     def terminal_states(self) -> Dict[int, List[str]]:
         return {rid: list(tr.terminals)
@@ -189,16 +342,13 @@ class RequestTracer:
     def clear(self):
         with self._lock:
             self.traces.clear()
-            self._server_events.clear()
 
     # -- chrome-trace export -----------------------------------------------
     def chrome_events(self, pid: Optional[int] = None) -> List[dict]:
-        """The tracer's rows as chrome-trace events (metadata + X spans
-        + instants), ready to merge with a RecordEvent drain."""
+        """The request rows as chrome-trace events (metadata + X spans
+        + instants), ready to merge with the ring's spans."""
         pid = os.getpid() if pid is None else pid
-        ev: List[dict] = [
-            {"ph": "M", "name": "thread_name", "pid": pid,
-             "tid": _SERVER_TID, "args": {"name": "server"}}]
+        ev: List[dict] = []
 
         def emit(tid, rec):
             base = {"name": rec["name"], "pid": pid, "tid": tid,
@@ -208,8 +358,6 @@ class RequestTracer:
             else:
                 ev.append({**base, "ph": "X", "dur": rec["dur"]})
 
-        for rec in self._server_events:
-            emit(_SERVER_TID, rec)
         for rid, tr in sorted(self.traces.items()):
             tid = rid + 1                 # tid 0 is the server row
             ev.append({"ph": "M", "name": "thread_name", "pid": pid,
@@ -225,17 +373,20 @@ class RequestTracer:
 
 
 def export_chrome_trace(path: str, tracer: Optional[RequestTracer] = None,
-                        profiler=None, extra_events=()) -> str:
-    """Write ONE Perfetto-loadable chrome-trace JSON merging request
-    spans (``tracer``), the profiler's host-span ring (``profiler`` — a
-    ``paddle_tpu.profiler.Profiler``, drained destructively, exactly
-    what its own export would have written), and any extra pre-built
-    events. Parent directories are created. Returns ``path``."""
-    events: List[dict] = []
+                        since_s: float = 0.0, extra_events=()) -> str:
+    """Write ONE Perfetto-loadable chrome-trace JSON: the ring's spans
+    that began after ``since_s`` (``time.perf_counter`` seconds; the
+    whole ring by default) — the server row and every other host span,
+    ``RecordEvent`` sites included — merged with the request rows of
+    ``tracer`` and any extra pre-built events, all on the perf_counter
+    clock. Parent directories are created. Returns ``path``."""
+    pid = os.getpid()
+    events: List[dict] = [
+        {"ph": "M", "name": "thread_name", "pid": pid,
+         "tid": _SERVER_TID, "args": {"name": "server"}}]
+    events.extend(chrome_events(since(since_s), pid))
     if tracer is not None:
-        events.extend(tracer.chrome_events())
-    if profiler is not None:
-        events.extend(profiler._drain_events())
+        events.extend(tracer.chrome_events(pid))
     events.extend(extra_events)
     d = os.path.dirname(os.path.abspath(path))
     os.makedirs(d, exist_ok=True)

@@ -460,7 +460,7 @@ def decode_layer_paged_kernel(mode, x, cos, sin, eps1, eps2, pos, tbl,
         out_shape=jax.ShapeDtypeStruct((S, d), x.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_fused._FORCE_INTERPRET,
+        interpret=_fused._FORCE_INTERPRET, name="decode_layer",
     )(*operands)
     return (out[:, None, :],) + new_cache
 

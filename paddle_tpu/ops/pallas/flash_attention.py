@@ -206,11 +206,12 @@ def _flash_prep(q, k, v):
     return to3(q), to3(k), to3(v), d_pad
 
 
-def _flash_call(kernel, grid, arrs, out_specs, out_shapes, blocks):
+def _flash_call(name, kernel, grid, arrs, out_specs, out_shapes, blocks):
     from jax.experimental import pallas as pl
     return pl.pallas_call(
         kernel, grid=grid, in_specs=blocks, out_specs=out_specs,
-        out_shape=out_shapes, interpret=_FORCE_INTERPRET)(*arrs)
+        out_shape=out_shapes, interpret=_FORCE_INTERPRET,
+        name=name)(*arrs)
 
 
 def flash_attention_fused(q, k, v, is_causal=False, scale=None,
@@ -282,6 +283,7 @@ def flash_block(q, k, v, is_causal=False, scale=None, window=None):
         qh, kh, vh, d_pad = _flash_prep(q, k, v)
         bh = qh.shape[0]
         out, lse = _flash_call(
+            "flash_attention_fwd",
             ft.partial(_flash_fwd_kernel, scale=scale,
                        is_causal=is_causal, blk_q=blk_q, blk_k=blk_k,
                        sk=sk, d=d_pad, window=window),
@@ -313,6 +315,7 @@ def flash_block(q, k, v, is_causal=False, scale=None, window=None):
         delta = delta - dlse3.reshape(bh, sq).astype(jnp.float32)
         delta = jnp.broadcast_to(delta[..., None], (bh, sq, 128))
         dq = _flash_call(
+            "flash_attention_bwd_dq",
             ft.partial(_flash_bwd_dq_kernel, scale=scale,
                        is_causal=is_causal, blk_q=blk_q, blk_k=blk_k,
                        sk=sk, d=d_pad, window=window),
@@ -327,6 +330,7 @@ def flash_block(q, k, v, is_causal=False, scale=None, window=None):
              BlockSpec((None, blk_q, 128), lambda i, j: (i, j, 0)),
              BlockSpec((None, blk_q, 128), lambda i, j: (i, j, 0))])
         dk, dv = _flash_call(
+            "flash_attention_bwd_dkv",
             ft.partial(_flash_bwd_dkv_kernel, scale=scale,
                        is_causal=is_causal, blk_q=blk_q, blk_k=blk_k,
                        sq=sq, d=d_pad, window=window),

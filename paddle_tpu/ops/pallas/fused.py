@@ -129,7 +129,7 @@ def _rms_pallas(x, weight, eps, residual):
             in_specs=[row_spec, w_spec],
             out_specs=row_spec,
             out_shape=jax.ShapeDtypeStruct((rows, h), x.dtype),
-            interpret=_FORCE_INTERPRET,
+            interpret=_FORCE_INTERPRET, name="fused_rms_norm",
         )(x2, weight)
         return out.reshape(orig_shape)
     r2 = residual.reshape(rows, h)
@@ -140,7 +140,7 @@ def _rms_pallas(x, weight, eps, residual):
         out_specs=[row_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct((rows, h), x.dtype),
                    jax.ShapeDtypeStruct((rows, h), x.dtype)],
-        interpret=_FORCE_INTERPRET,
+        interpret=_FORCE_INTERPRET, name="fused_rms_norm_residual",
     )(x2, r2, weight)
     return out.reshape(orig_shape), s.reshape(orig_shape)
 
@@ -279,7 +279,7 @@ def _rope_pallas(q, k, cos, sin):
         out_specs=[qspec, qspec],
         out_shape=[jax.ShapeDtypeStruct((rows, h, d), q.dtype),
                    jax.ShapeDtypeStruct((rows, h, d), k.dtype)],
-        interpret=_FORCE_INTERPRET,
+        interpret=_FORCE_INTERPRET, name="fused_rope",
     )(q3, k3, c2, s2)
     return oq.reshape(q.shape), ok.reshape(k.shape)
 
@@ -397,7 +397,7 @@ def fused_adamw(p, g, m, v, lr, beta1=0.9, beta2=0.999, eps=1e-8,
             jax.ShapeDtypeStruct((rows, lanes), m.dtype),
             jax.ShapeDtypeStruct((rows, lanes), v.dtype),
         ],
-        interpret=_FORCE_INTERPRET,
+        interpret=_FORCE_INTERPRET, name="fused_adamw",
     )(flat(p), flat(g), flat(m), flat(v), scalars)
 
     def unflat(x, shape):

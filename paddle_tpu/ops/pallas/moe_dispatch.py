@@ -128,7 +128,7 @@ def _gather_rows_pallas(x, idx):
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, d), x.dtype),
-        interpret=_FORCE_INTERPRET,
+        interpret=_FORCE_INTERPRET, name="moe_gather_rows",
     )(idx.astype(jnp.int32), x)
 
 
@@ -191,7 +191,7 @@ def _gather_rows_pallas_mr(x, idx, rows_per_step: int = 8):
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, d), x.dtype),
-        interpret=_FORCE_INTERPRET,
+        interpret=_FORCE_INTERPRET, name="moe_gather_rows_mr",
     )(idx_p, x)
     return out[:m] if m_pad != m else out
 

@@ -266,7 +266,8 @@ def _kernel_ok_int8(k_codes) -> bool:
     return k_codes.dtype == jnp.int8 and _fused._pallas_ok()
 
 
-def _grid_call(kernel, in_specs, operands, b, mb, h, d, kvh, out_dtype):
+def _grid_call(name, kernel, in_specs, operands, b, mb, h, d, kvh,
+               out_dtype):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -287,7 +288,7 @@ def _grid_call(kernel, in_specs, operands, b, mb, h, d, kvh, out_dtype):
         out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
-        interpret=_fused._FORCE_INTERPRET,
+        interpret=_fused._FORCE_INTERPRET, name=name,
     )(*operands)
 
 
@@ -311,6 +312,7 @@ def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
                      lambda i, j, tbl, lens: (tbl[i, j], 0, 0, 0)),
     ]
     return _grid_call(
+        "paged_attention_decode",
         functools.partial(_decode_kernel, bs=bs, scale=scale,
                           nblocks=mb),
         in_specs, (block_table, lengths, q, k_arena, v_arena),
@@ -390,6 +392,7 @@ def paged_attention_decode_int8(q, k_codes, v_codes, k_scales, v_scales,
                      lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
     ]
     return _grid_call(
+        "paged_attention_decode_int8",
         functools.partial(_decode_kernel_int8, bs=bs, scale=scale,
                           nblocks=mb),
         in_specs, (block_table, lengths, q, k_codes, v_codes,

@@ -171,7 +171,7 @@ def _rows_pallas_fwd(x, labels):
         out_specs=[cspec, cspec],
         out_shape=[jax.ShapeDtypeStruct((n, 1), jnp.float32),
                    jax.ShapeDtypeStruct((n, 1), jnp.float32)],
-        interpret=_fused._FORCE_INTERPRET,
+        interpret=_fused._FORCE_INTERPRET, name="fused_xent_fwd",
     )(x, labels[:, None])
     return nll[:, 0], lse[:, 0]
 
@@ -190,7 +190,7 @@ def _rows_pallas_bwd(x, labels, lse, ga, gb):
         in_specs=[xspec, cspec, cspec, cspec, cspec],
         out_specs=xspec,
         out_shape=jax.ShapeDtypeStruct((n, v), x.dtype),
-        interpret=_fused._FORCE_INTERPRET,
+        interpret=_fused._FORCE_INTERPRET, name="fused_xent_bwd",
     )(x, labels[:, None], lse[:, None], ga[:, None], gb[:, None])
     return dx
 

@@ -2,17 +2,21 @@
 paddle/fluid/platform/profiler/ RecordEvent/CUPTI tracer — verify).
 
 TPU-native design: device tracing delegates to ``jax.profiler``
-(XProf/TensorBoard, perfetto); host spans are our own RecordEvent ring
-writing chrome-trace JSON, merged with the jax trace directory."""
+(XProf/TensorBoard, perfetto). A host span (``RecordEvent``) is a span of
+the program's one recorder (``observability/tracing.py``): it lands in
+the always-on ring and, while a ``jax.profiler`` session is open, in the
+``.xplane.pb`` beside the device's operations. A ``Profiler`` keeps no
+spans of its own: it remembers the intervals in which its scheduler said
+RECORD and exports the ring's spans that lie inside them."""
 from __future__ import annotations
 
-import contextlib
 import json
 import os
-import threading
 import time
 from enum import Enum
 from typing import Callable, Iterable, Optional
+
+from ..observability import tracing as _tracing
 
 __all__ = ["Profiler", "ProfilerTarget", "ProfilerState", "RecordEvent",
            "make_scheduler", "export_chrome_tracing", "load_profiler_result",
@@ -43,49 +47,23 @@ class SummaryView(Enum):
     MemoryView = 6
 
 
-_EVENTS: list = []
-_EVENTS_LOCK = threading.Lock()
-_ACTIVE = [False]
-
-
-def _tracer():
-    """The C++ host tracer (paddle_tpu.core libptcore); None when the
-    native library is unavailable — spans then use the Python path."""
-    from ..core.native_api import global_tracer
-    t = global_tracer()
-    return t if t.is_native else None
-
-
 class RecordEvent:
     """Host span (reference: paddle.profiler.RecordEvent / C++ RecordEvent
-    — verify). Usable as context manager or begin()/end()."""
+    — verify). Usable as context manager or begin()/end(). Always
+    recorded (the ring is bounded and has no switch); a ``Profiler``
+    decides only which spans its export shows."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._begin = None
+        self._span = None
 
     def begin(self):
-        t = _tracer()
-        if t is not None:
-            t.begin(self.name)
-            self._begin = "native"
-            return
-        self._begin = time.perf_counter_ns()
+        self._span = _tracing.begin(self.name)
 
     def end(self):
-        if self._begin == "native":
-            t = _tracer()
-            if t is not None:
-                t.end()
-            return
-        if self._begin is None or not _ACTIVE[0]:
-            return
-        now = time.perf_counter_ns()
-        with _EVENTS_LOCK:
-            _EVENTS.append({"name": self.name, "ph": "X", "pid": os.getpid(),
-                            "tid": threading.get_ident(),
-                            "ts": self._begin / 1000.0,
-                            "dur": (now - self._begin) / 1000.0})
+        if self._span is not None:
+            _tracing.end(self._span)
+            self._span = None
 
     def __enter__(self):
         self.begin()
@@ -152,6 +130,7 @@ class Profiler:
         self._jax_trace_dir = None
         self._jax_active = False
         self._last_export = None
+        self._intervals: list = []      # [t0_s, t1_s | None] while RECORD
 
     # -- device tracer ------------------------------------------------------
     def _start_device_trace(self):
@@ -179,23 +158,15 @@ class Profiler:
             self._jax_active = False
 
     def _drain_events(self):
-        with _EVENTS_LOCK:
-            ev = list(_EVENTS)
-            _EVENTS.clear()
-        t = _tracer()
-        if t is not None and t.event_count():
-            import tempfile
-            with tempfile.NamedTemporaryFile(suffix=".json",
-                                             delete=False) as f:
-                tmp = f.name
-            try:
-                t.dump(tmp, pid=os.getpid())
-                with open(tmp) as f:
-                    ev.extend(json.load(f).get("traceEvents", []))
-                t.clear()
-            finally:
-                os.unlink(tmp)
-        return ev
+        """The ring's spans that lie inside this profiler's recording
+        intervals, as chrome-trace events; each span is handed out once
+        (an interval still open restarts at now)."""
+        now = time.perf_counter()
+        spans = [r for t0, t1 in self._intervals
+                 for r in _tracing.since(t0, now if t1 is None else t1)]
+        self._intervals = [[now, None] for _, t1 in self._intervals
+                           if t1 is None]
+        return _tracing.chrome_events(spans)
 
     # -- lifecycle ----------------------------------------------------------
     @staticmethod
@@ -204,16 +175,16 @@ class Profiler:
                          ProfilerState.RECORD_AND_RETURN)
 
     def _arm_host_ring(self, on: bool):
-        """The host-span ring (and the native tracer) record iff the
-        scheduler state says so. ``start()`` used to set the ring
-        unconditionally — host spans recorded through CLOSED warmup
-        steps — and CLOSED→RECORD transitions in ``step()`` never
-        re-armed it; both directions are regression-pinned in
-        tests/test_observability.py."""
-        _ACTIVE[0] = on
-        t = _tracer()
-        if t is not None:
-            t.enable(on)
+        """Host spans count for this profiler iff the scheduler state
+        says so: ``start()`` must not record through CLOSED warmup
+        steps, and a CLOSED->RECORD transition in ``step()`` must open
+        an interval (both directions are regression-pinned in
+        tests/test_observability.py)."""
+        is_open = bool(self._intervals) and self._intervals[-1][1] is None
+        if on and not is_open:
+            self._intervals.append([time.perf_counter(), None])
+        elif not on and is_open:
+            self._intervals[-1][1] = time.perf_counter()
 
     def start(self):
         self._state = self.scheduler(self._step) if self.scheduler else \

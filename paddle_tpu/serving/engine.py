@@ -33,7 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
-from ..observability.tracing import now_us as _trace_now
+from ..observability.tracing import (named_program, now_us as _trace_now,
+                                     span as _span)
 from ..utils import faults
 
 # engine metric families (no-ops until metrics.enable()/PT_METRICS)
@@ -103,6 +104,14 @@ def slot_sample_logits(logits, keys, temperature, top_k, top_p):
     return jnp.where(greedy, greedy_tok, sampled.astype(jnp.int32))
 
 
+# The compiled programs' names as a device trace shows them ("XLA
+# Modules" line). benchmark/kinds/serve.py reads device time by these
+# names, so they are set on purpose where the functions are built
+# (``tracing.named_program``) and pinned in tests/test_trace_names.py.
+DECODE_PROGRAM = "jit_block_fn"
+PREFILL_CHUNK_PROGRAM = "jit_chunk_fn"
+
+
 def init_slot_state(num_slots: int) -> Dict[str, jnp.ndarray]:
     """Fresh all-slots-free in-graph state pytree."""
     S = num_slots
@@ -163,8 +172,9 @@ def build_slot_block_fn(pure, block: int, trace_counter=None,
             # poisoned row — numerically impossible from finite
             # weights/cache, so a False flag means corrupted state
             ok = ~jnp.any(jnp.isnan(logp), axis=-1)
-            nxt = slot_sample_logits(logp, sub, st["temp"], st["topk"],
-                                     st["topp"])
+            with jax.named_scope("sample"):
+                nxt = slot_sample_logits(logp, sub, st["temp"],
+                                         st["topk"], st["topp"])
             live = st["live"]
             hit = live & (st["eos"] >= 0) & (nxt == st["eos"])
             rem = jnp.where(live, st["remaining"] - 1, st["remaining"])
@@ -183,7 +193,7 @@ def build_slot_block_fn(pure, block: int, trace_counter=None,
             body, (cache_flat, state), None, length=block)
         return cache_flat, state, toks, lives, oks
 
-    return block_fn
+    return named_program(block_fn, DECODE_PROGRAM)
 
 
 def build_slot_prefill_fn(pure, row_specs):
@@ -224,11 +234,12 @@ def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
         logp, cache_flat = pure(
             pv, bv, ids, cache_flat, jnp.reshape(start_pos, (1,)),
             None, None, table, n_valid - 1)
-        tok0 = slot_sample_logits(logp, key[None], temp[None],
-                                  topk[None], topp[None])[0]
+        with jax.named_scope("sample"):
+            tok0 = slot_sample_logits(logp, key[None], temp[None],
+                                      topk[None], topp[None])[0]
         return tok0, cache_flat
 
-    return chunk_fn
+    return named_program(chunk_fn, PREFILL_CHUNK_PROGRAM)
 
 
 def _cancel_fn(state, slot):
@@ -312,7 +323,8 @@ class _FusedBlockJit:
                                       self._closed.consts, *flat)
             return jax.tree.unflatten(out_tree, out)
 
-        self._jit = jax.jit(run_block, donate_argnums=self._donate)
+        self._jit = jax.jit(named_program(run_block, DECODE_PROGRAM),
+                            donate_argnums=self._donate)
 
     def __call__(self, *args):
         if self._jit is None:
@@ -1033,11 +1045,19 @@ class ContinuousBatchingEngine:
             self._note_decode_bytes(self.decode_block)
         faults.fault_point("serving.harvest")
         toks, lives, oks = self._pending_block
-        toks_np = np.asarray(toks)                  # ONE host sync/block
-        lives_np = np.asarray(lives)                # (block, S)
-        oks_np = None if oks is None else np.asarray(oks)
-        rem_np = np.asarray(self._state["remaining"])
+        with _span("serving.decode_sync"):    # host blocked on the device
+            toks_np = np.asarray(toks)              # ONE host sync/block
+            lives_np = np.asarray(lives)            # (block, S)
+            oks_np = None if oks is None else np.asarray(oks)
+            rem_np = np.asarray(self._state["remaining"])
         self._pending_block = None
+        with _span("serving.harvest"):
+            self._credit_block(toks_np, lives_np, oks_np, rem_np)
+
+    def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
+        """The host half of a decode block: credit each live slot its
+        emitted tokens, quarantine poisoned rows, retire finished
+        slots."""
         emitted = int(lives_np.sum())
         self.decode_tokens += emitted
         self.tokens_emitted += emitted

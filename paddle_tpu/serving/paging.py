@@ -54,7 +54,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
-from ..observability.tracing import now_us as _trace_now
+from ..observability.tracing import now_us as _trace_now, span as _span
 from ..utils import faults
 from ..utils.flags import env_flag, env_int
 from .engine import (ContinuousBatchingEngine, ModelStepBackend, _SlotRun,
@@ -755,6 +755,13 @@ class PagedEngine(ContinuousBatchingEngine):
 
     # -- admission ---------------------------------------------------------
     def try_admit(self, request) -> bool:
+        """Block allocation, prefix lookup and slot arming for one
+        request (the chunks themselves run in :meth:`prefill_tick`);
+        False when the block pool cannot hold it yet."""
+        with _span("serving.admit", rid=request.request_id):
+            return self._try_admit(request)
+
+    def _try_admit(self, request) -> bool:
         prompt = np.asarray(request.prompt, np.int32).reshape(-1)
         resume = getattr(request, "resume", None)
         if resume is not None and resume.tokens:
@@ -838,7 +845,6 @@ class PagedEngine(ContinuousBatchingEngine):
         tokens (always at least one chunk when work is pending, so a
         tiny budget still progresses). Jobs run FIFO; a finished job
         arms its slot (or retires immediately on eos/max_new==1)."""
-        from ..profiler import RecordEvent
         spent = 0
         C = self.prefill_chunk_len
         while self._jobs and (token_budget is None or spent == 0
@@ -853,7 +859,8 @@ class PagedEngine(ContinuousBatchingEngine):
             ids[0, :n] = job.prompt[job.done:job.done + n]
             tr = self.tracer
             t_chunk = _trace_now() if tr is not None else 0.0
-            with RecordEvent("serving.prefill_chunk"):
+            with _span("serving.prefill_chunk",
+                       rid=job.run.request.request_id, tokens=n):
                 tok0_dev, self._cache = self.backend.prefill_chunk(
                     jnp.asarray(ids), self._cache,
                     jnp.asarray(job.table_row[None]),
@@ -889,7 +896,8 @@ class PagedEngine(ContinuousBatchingEngine):
                                     slot=job.slot,
                                     reused_tokens=len(job.run.tokens))
         else:
-            tok0 = int(tok0_dev)
+            with _span("serving.prefill_sync", rid=req.request_id):
+                tok0 = int(tok0_dev)        # host blocked on the last chunk
             job.run.tokens = [tok0]
             job.run.t_admit = now           # TTFT timestamp
             self.tokens_emitted += 1

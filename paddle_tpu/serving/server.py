@@ -4,10 +4,14 @@ resilience policies.
 One iteration of the loop = one tick of the engine-block clock: expire
 deadlined requests, admit whatever the scheduler releases into free
 slots, advance chunked prefills, run one compiled decode block, harvest
-retired requests. Per-request latency and engine-level tokens/s /
-slot-occupancy counters are emitted as profiler RecordEvent spans
-(chrome-trace) and summarized by ``stats()`` — the serving analogue of
-the training loop's MFU line.
+retired requests. Every tick is one ``serving.tick`` span of the
+program's span recorder (``observability/tracing.py``) and each of its
+phases a child span (``serving.expire`` / ``schedule`` / ``admit`` /
+``prefill_chunk`` / ``prefill_sync`` / ``decode_block`` / ``decode_sync``
+/ ``harvest`` / ``deliver``), always recorded in the bounded ring and,
+under a ``jax.profiler`` session, in the device trace; per-request
+latency and engine-level tokens/s / slot-occupancy are summarized by
+``stats()`` — the serving analogue of the training loop's MFU line.
 
 Failure paths are first-class (serving/resilience.py): every submitted
 request ends either in a completed output array or an explicit
@@ -28,7 +32,7 @@ import numpy as np
 from ..observability import (FlightRecorder, ObservabilityConfig,
                              RequestTracer)
 from ..observability import metrics as _om
-from ..observability.tracing import export_chrome_trace, now_us
+from ..observability.tracing import export_chrome_trace, span
 from ..utils import faults
 from .engine import ContinuousBatchingEngine
 from .resilience import (RequestFailure, ResilienceConfig,
@@ -193,6 +197,7 @@ class Server:
         self.latencies: Dict[int, float] = {}
         self.ttft: Dict[int, float] = {}       # submit -> first token
         self.tick_seconds: list = []           # per-tick wall times
+        self._t_built = time.perf_counter()    # export_trace's origin
         self._next_id = 0
         self._clock = 0
         self._wall = 0.0
@@ -357,8 +362,6 @@ class Server:
                     _M_BREAKER.set(1)
                     self.flight.record("breaker_open", clock=self._clock,
                                        after=res.consecutive_failures)
-                    self.tracer.server_instant(
-                        "breaker_open", clock=self._clock)
                     return False
                 if attempt < cfg.retry_attempts:
                     res.retries += 1
@@ -367,9 +370,8 @@ class Server:
                     self.flight.record("retry", attempt=attempt,
                                        backoff_s=round(backoff, 6),
                                        clock=self._clock)
-                    self.tracer.server_instant("retry", attempt=attempt,
-                                               clock=self._clock)
-                    time.sleep(backoff)
+                    with span("serving.retry", attempt=attempt):
+                        time.sleep(backoff)
         return False
 
     def _quarantine_all(self, reason: str):
@@ -467,14 +469,16 @@ class Server:
 
     # -- the tick ----------------------------------------------------------
     def _tick(self):
-        self._expire()
-        if self.preemption and not self.engine.has_pending_harvest():
-            # only at a clean block boundary — a dispatched block
-            # awaiting a harvest retry must land before any eviction
-            self._preempt_for_priority()
-        admitted = self.scheduler.pop_ready(
-            self._clock, self.engine.free_slot_count(),
-            engine_idle=not self.engine.has_live())
+        with span("serving.expire"):
+            self._expire()
+        with span("serving.schedule"):
+            if self.preemption and not self.engine.has_pending_harvest():
+                # only at a clean block boundary — a dispatched block
+                # awaiting a harvest retry must land before any eviction
+                self._preempt_for_priority()
+            admitted = self.scheduler.pop_ready(
+                self._clock, self.engine.free_slot_count(),
+                engine_idle=not self.engine.has_live())
         for i, req in enumerate(admitted):
             resumed = getattr(req, "resume", None) is not None
             ok = self.engine.try_admit(req)
@@ -564,7 +568,9 @@ class Server:
             self.tracer.terminal(req.request_id, "completed",
                                  tokens=len(run.tokens))
             if self.stream_sink is not None:
-                self.stream_sink(req.request_id, run.tokens, True, None)
+                with span("serving.deliver", rid=req.request_id):
+                    self.stream_sink(req.request_id, run.tokens, True,
+                                     None)
 
     def run_until_idle(self, max_ticks: Optional[int] = None
                        ) -> Dict[int, object]:
@@ -591,32 +597,31 @@ class Server:
             if self._res.breaker_open:   # incl. restored-open circuits
                 self._circuit_open_drain()
                 break
-            t_tick = time.perf_counter()
-            t_tick_us = now_us() if self.tracer.enabled else 0.0
-            try:
-                faults.fault_point("server.tick")
-                self._tick()
-            except faults.InjectedFault:
-                self._res.tick_faults += 1
-                self.flight.record("tick_fault", clock=self._clock)
-            self._clock += 1
-            ticks += 1
-            self._harvest()
-            self._drain_live_streams()
-            tick_s = time.perf_counter() - t_tick
-            self.tick_seconds.append(tick_s)
-            self.tracer.server_span_at("tick", t_tick_us,
-                                       clock=self._clock - 1)
-            _M_TICKS.inc()
-            _M_TICK_S.observe(tick_s)
-            _M_QUEUE.set(self.scheduler.pending())
-            _M_OCC.set(self.engine.occupancy())
-            self.flight.record(
-                "tick", clock=self._clock - 1,
-                queue=self.scheduler.pending(),
-                live=len(self.engine.live_runs()),
-                tokens=self.engine.tokens_emitted,
-                tick_ms=round(tick_s * 1000, 3))
+            with span("serving.tick", tick=self._clock):
+                t_tick = time.perf_counter()
+                try:
+                    faults.fault_point("server.tick")
+                    self._tick()
+                except faults.InjectedFault:
+                    self._res.tick_faults += 1
+                    self.flight.record("tick_fault", clock=self._clock)
+                self._clock += 1
+                ticks += 1
+                with span("serving.harvest"):
+                    self._harvest()
+                self._drain_live_streams()
+                tick_s = time.perf_counter() - t_tick
+                self.tick_seconds.append(tick_s)
+                _M_TICKS.inc()
+                _M_TICK_S.observe(tick_s)
+                _M_QUEUE.set(self.scheduler.pending())
+                _M_OCC.set(self.engine.occupancy())
+                self.flight.record(
+                    "tick", clock=self._clock - 1,
+                    queue=self.scheduler.pending(),
+                    live=len(self.engine.live_runs()),
+                    tokens=self.engine.tokens_emitted,
+                    tick_ms=round(tick_s * 1000, 3))
             if self._res.breaker_open:
                 self._circuit_open_drain()
                 break
@@ -631,10 +636,11 @@ class Server:
         the decode block — exactly when the host learns of them."""
         if self.stream_sink is None:
             return
-        for _slot, run in self.engine.live_runs():
-            if run.tokens:
-                self.stream_sink(run.request.request_id, run.tokens,
-                                 False, None)
+        with span("serving.deliver"):
+            for _slot, run in self.engine.live_runs():
+                if run.tokens:
+                    self.stream_sink(run.request.request_id, run.tokens,
+                                     False, None)
 
     def _circuit_open_drain(self):
         """Breaker-open endgame: auto-dump the flight recorder (the
@@ -703,14 +709,15 @@ class Server:
             out["kv_bytes_per_slot"] = eng.backend.kv_bytes_per_slot()
         return out
 
-    def export_trace(self, path: str, profiler=None) -> str:
+    def export_trace(self, path: str) -> str:
         """Write the served stream as ONE Perfetto-loadable chrome-trace
-        JSON: this server's request rows + tick markers, merged (on the
-        same perf_counter clock) with the profiler's ``RecordEvent``
-        host-span ring when a :class:`~paddle_tpu.profiler.Profiler` is
-        passed (drained destructively, like its own export)."""
+        JSON: this server's request rows merged (on the same
+        perf_counter clock) with the host spans the span ring has held
+        since this server was built — the server row (``serving.tick``
+        and its phases) and every other span, ``RecordEvent`` sites
+        included."""
         return export_chrome_trace(path, tracer=self.tracer,
-                                   profiler=profiler)
+                                   since_s=self._t_built)
 
     # -- crash-safe snapshot / restore -------------------------------------
     def snapshot(self, path: str):

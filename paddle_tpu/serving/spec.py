@@ -64,6 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
+from ..observability.tracing import span as _span
 from ..utils import faults
 from ..utils.flags import env_int
 from .engine import (ContinuousBatchingEngine, ModelStepBackend,
@@ -474,8 +475,9 @@ class _SpecEngineMixin:
         toks, counts, oks, n_draft = self._pending_block
         # ONE batched host sync per verify step (4 separate np.asarray
         # round-trips measurably tax the tick at CPU dispatch scale)
-        toks_np, counts_np, oks_np, rem_np = jax.device_get(
-            (toks, counts, oks, self._state["remaining"]))
+        with _span("serving.decode_sync"):
+            toks_np, counts_np, oks_np, rem_np = jax.device_get(
+                (toks, counts, oks, self._state["remaining"]))
         self._pending_block = None
         emitted = int(counts_np.sum())
         accepted = int(np.maximum(counts_np - 1, 0).sum())

@@ -180,11 +180,17 @@ class TestAmpDebugging:
 
     def test_tape_gc_single_call_cascade(self):
         from paddle_tpu.tensor import _tape
+        # nodes an EARLIER test file on this xdist worker left alive
+        # (tests/test_autograd.py keeps two) are not this test's: count
+        # from what is there, or the result depends on the files' order
+        _tape().gc()
+        before = len(_tape().nodes)
         x = paddle.to_tensor([1.0], stop_gradient=False)
         t = ((x * 2) * 3) * 4
+        assert len(_tape().nodes) == before + 3
         del t
         _tape().gc()
-        assert len(_tape().nodes) == 0
+        assert len(_tape().nodes) == before
 
 
 def test_autocast_casts_bmm_einsum_addmm():

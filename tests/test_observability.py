@@ -2,7 +2,8 @@
 registry semantics + disabled-path inertness, per-request lifecycle
 traces (exactly one terminal span per submitted request, pinned under a
 seeded chaos schedule), the merged Perfetto/chrome trace artifact
-(request rows + RecordEvent host spans + tick markers on one clock),
+(request rows + the span ring's server row and RecordEvent host spans on
+one clock; the recorder itself is pinned in tests/test_span_recorder.py),
 the crash flight recorder (bounded ring, circuit-open auto-dump,
 snapshot/restore round-trip), metrics exposition coverage across
 server/engine/paging/resilience/faults/collectives/passes, and the
@@ -230,21 +231,25 @@ class TestMergedChromeTrace:
                                                        tmp_path):
         """The acceptance artifact: ONE Perfetto-loadable chrome-trace
         JSON from one served batch containing request spans, RecordEvent
-        host spans, and tick markers — all on the perf_counter clock."""
+        host spans, and tick spans — all on the perf_counter clock, the
+        host spans read from the one span ring (no Profiler needed; one
+        armed over the same stream exports the same RecordEvent spans)."""
         model, cfg, dense, paged = setup
         dense.reset()
         srv = Server(dense, observability=ObservabilityConfig(
             trace_requests=True))
         prof = profiler.Profiler(targets=[profiler.ProfilerTarget.CPU],
                                  timer_only=True)
-        prof._drain_events()             # a clean host ring
         with prof:
             for p in _prompts(cfg, 5, [6, 11, 4]):
                 srv.submit(p, max_new_tokens=6)
             srv.run_until_idle()
         path = str(tmp_path / "nested" / "serve_trace.json")
-        srv.export_trace(path, profiler=prof)
+        srv.export_trace(path)
         events = json.load(open(path))["traceEvents"]
+        drained = {e["name"] for e in prof._drain_events()}
+        assert {"serving.decode_block", "serving.prefill",
+                "serving.tick"} <= drained
 
         req_rows = {e["tid"] for e in events
                     if e.get("ph") == "M" and
@@ -258,10 +263,14 @@ class TestMergedChromeTrace:
         # RecordEvent host spans from the SAME engine dispatches
         assert any(n == "serving.decode_block" for n in names)
         assert any(n == "serving.prefill" for n in names)
-        # tick markers on the server row
-        ticks = [e for e in events if e.get("name") == "tick"]
+        # tick spans (and the engine's dispatches) on the server row,
+        # each carrying its tick id; nothing from before this server
+        ticks = [e for e in events if e.get("name") == "serving.tick"]
         assert ticks and all(e["tid"] == 0 and e["ph"] == "X"
                              for e in ticks)
+        assert [e["args"]["tick"] for e in ticks] == list(range(len(ticks)))
+        assert all(e["tid"] == 0 for e in events
+                   if str(e.get("name", "")).startswith("serving."))
         # aligned clocks: every span timestamp sits in one monotonic
         # window (a wall-clock mixup would land µs-epoch outliers)
         ts = [e["ts"] for e in events if e.get("ph") == "X"]
