@@ -27,7 +27,7 @@ chains, this module applies it to the whole decode layer:
   once, the first grid step folds RMS-norm #1 + the q projection +
   RoPE into VMEM scratch, every step folds one arena block into the
   online softmax (int8 arenas dequantized in registers via the SAME
-  ``_deq_block`` as PR 10's paged-attention kernel), and the last step
+  ``_deq_block`` as the paged read's scan fallback), and the last step
   runs o_proj, the residual, RMS-norm #2 and the SwiGLU MLP entirely
   out of VMEM — the only HBM traffic per layer is the x row in, the
   out row back, the weights and the quantized KV blocks. The k/v
@@ -57,12 +57,31 @@ import jax
 import jax.numpy as jnp
 
 from . import fused as _fused
-from .paged_attention import (_deq_block, _online_update, quantize_kv,
-                              _NEG)
+from .paged_attention import _deq_block, quantize_kv, _NEG
 
 __all__ = ["marking", "marking_active", "ARG_LAYOUT", "N_CACHE",
            "N_WEIGHTS", "MODES", "build_fused_callable",
            "decode_layer_reference", "kernel_viable"]
+
+
+def _online_update(q, k, v, j, bs, length, scale, m_ref, l_ref, acc_ref):
+    """Fold one fp32 (bs, kvh, d) KV block into the running online
+    softmax (max / normalizer / accumulator scratch refs): the
+    megakernel's per-table-entry attention step."""
+    kvh = k.shape[1]
+    h, d = q.shape
+    qg = q.reshape(kvh, h // kvh, d)
+    s = jnp.einsum("kgd,tkd->kgt", qg, k) * scale   # (kvh, g, bs)
+    t = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+    s = jnp.where(t < length, s, _NEG)
+    m_prev, l_prev = m_ref[...], l_ref[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * corr + jnp.einsum("kgt,tkd->kgd", p, v)
+    m_ref[...] = m_new
+
 
 # ---------------------------------------------------------------------------
 # marking: the trace-time handshake between the serving engine and llama

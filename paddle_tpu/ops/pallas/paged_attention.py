@@ -7,14 +7,26 @@ block table maps the slot's timeline block j to an arena block id
 (vLLM's PagedAttention layout restated under the repo's static-shape
 rules — block 0 is the reserved trash block dead slots write into).
 
-TPU-native design: the kernel runs one grid step per (slot, table
-entry); the block table and per-slot lengths ride as SCALAR-PREFETCH
-operands so the k/v BlockSpec index_map can address the arena block
-directly — the gather IS the DMA schedule, no (S, max_len) dense view
-ever materializes. Attention over the blocks is an online softmax
-(running max / normalizer / accumulator in VMEM scratch, finalized on
-the last table entry), with table entries past the slot's length
-skipped via ``pl.when``. Off-TPU (and in the CPU quick lane) the SAME
+TPU-native design: the kernel walks a slot's LIVE pages, not its
+table. A grid step is a slot; its body is ONE loop over the slot's
+``cdiv(length, block_size)`` pages (clamped to the table's width), P
+pages to a chunk, whose trip count is read at run time from the
+scalar-prefetched ``lengths`` — so the engine's one compiled decode
+program serves every length, and a read costs what its live bytes cost.
+The arenas stay in HBM; the kernel copies each live page itself
+(``make_async_copy``, the page's arena id read from the scalar-prefetched
+block table) into one of two chunk buffers in VMEM, and starts the next
+chunk's copies — the next slot's first chunk, at a slot's end — before
+it folds the current chunk into the online softmax (running max /
+normalizer / accumulator as fp32 loop carries). Pages past the length
+are never copied; the tail of the last page is masked in the scores. A
+page is used as the ``(block_size * kv_heads, head_dim)`` matrix it is in
+memory, all query heads against all of its rows in one product with the
+other kv heads' rows masked (:func:`_walk_kernel`). P comes from shapes
+the call can see (:func:`_pages_per_chunk`); :func:`walk_counts` mirrors
+the loop bound on the host for the engine's ``kv_pages_live`` /
+``kv_pages_copied`` counters. No (S, max_len) dense view ever
+materializes. Off-TPU (and in the CPU quick lane) the SAME
 call falls back to :func:`paged_attention_reference` — a gather of the
 table into the dense layout followed by exactly the einsum/mask/softmax
 sequence of ``models.generation.cached_attention``, which is what keeps
@@ -30,10 +42,15 @@ it from ``int8_error_bound`` with n=1 and no phase-2 term).
 
 Bandwidth-true int8 decode (:func:`paged_attention_decode_int8`): the
 dequantization happens INSIDE the read, never ahead of it. On TPU the
-int8 kernel DMAs code blocks plus their ``(block_size, kv_heads)``
-scale blocks through the same scalar-prefetch index_map and dequantizes
-each block in registers — HBM sees ~(1 + 4/d)-byte/element traffic, the
-actual quantized footprint. Off-TPU the fallback is a ``lax.scan`` over
+int8 kernel is the same walk: the loop copies each live page's codes and
+its ``(block_size, kv_heads)`` scale page, and a row's ``absmax / 127``
+step multiplies that row's column of the scores (K) and of the
+probabilities (V) — HBM sees ~(1 + 4/d)-byte/element traffic, the actual
+quantized footprint, and no chunk-sized fp32 K or V exists even in VMEM.
+(The scale arenas are lane-sparse in HBM, 8 floats to a row: XLA re-lays
+them out as lane rows ahead of the call, arena-wide — the one part of
+the int8 read that does not scale with live KV; ROADMAP D2.) Off-TPU the
+fallback is a ``lax.scan`` over
 table entries that gathers ONE block of codes+scales at a time,
 dequantizes it, and folds it into the same online softmax — so even the
 CPU jaxpr holds no fp32 KV transient beyond a single
@@ -48,13 +65,14 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import fused as _fused
 
 __all__ = ["paged_attention_decode", "paged_attention_decode_int8",
            "paged_attention_reference", "paged_attention_int8_reference",
            "paged_gather", "quantize_kv", "dequantize_kv",
-           "kv_int8_error_bound"]
+           "kv_int8_error_bound", "walk_counts"]
 
 _NEG = -1e30
 
@@ -90,9 +108,10 @@ def dequantize_kv(codes, scales):
 def _deq_block(codes, scales):
     """Register-level EQuARX dequant of ONE block: codes (..., d) int8,
     scales (...,) fp32 -> fp32. THE collectives formula (±127 codes
-    reproduce ±absmax bit-exactly), not a restatement — the Pallas
-    kernel, the scan fallback and quantize_kv/dequantize_kv can never
-    drift apart."""
+    reproduce ±absmax bit-exactly), not a restatement — the megakernel,
+    the scan fallback and quantize_kv/dequantize_kv can never drift
+    apart. (The decode walk applies the same ``absmax / 127`` step to
+    score and probability columns instead: :func:`_chunk_dequantized`.)"""
     from ...distributed.collectives.quantized import _dequantize
     return _dequantize(codes, scales)
 
@@ -169,127 +188,311 @@ def paged_attention_int8_reference(q, k_codes, v_codes, k_scales,
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernel: decode (s=1), block-table scalar prefetch
+# Pallas kernel: decode (s=1), a walk over each slot's LIVE pages
 # ---------------------------------------------------------------------------
 
-def _online_update(q, k, v, j, bs, length, scale, m_ref, l_ref, acc_ref):
-    """Fold one fp32 (bs, kvh, d) KV block into the running online
-    softmax (max / normalizer / accumulator scratch refs). Shared by
-    the fp32 and int8 kernels — the int8 kernel differs ONLY in how k/v
-    reach fp32."""
-    kvh = k.shape[1]
-    h, d = q.shape
-    qg = q.reshape(kvh, h // kvh, d)
-    s = jnp.einsum("kgd,tkd->kgt", qg, k) * scale   # (kvh, g, bs)
-    t = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-    s = jnp.where(t < length, s, _NEG)
-    m_prev, l_prev = m_ref[...], l_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * corr + jnp.einsum("kgt,tkd->kgd", p, v)
-    m_ref[...] = m_new
+# Rows of K (tokens x kv heads) folded into the online softmax per loop
+# trip, and the VMEM the two chunk buffers of every stream may take
+# together. Chosen from chip measurements at the served shape (PR 26,
+# PERF.md section 6); the pages per chunk follow from shapes the call
+# can see (:func:`_pages_per_chunk`), never from an option.
+_CHUNK_ROWS = 2048
+_VMEM_BUDGET = 4 * 1024 * 1024
+_SCOPED_VMEM_LIMIT = 16 * 1024 * 1024      # Mosaic's default on a v5e
 
 
-def _decode_kernel_core(len_ref, q_ref, read_kv, o_ref, m_ref, l_ref,
-                        acc_ref, *, bs, scale, nblocks):
-    """ONE online-softmax scratch lifecycle (init at j==0, per-block
-    fold, finalize at the last table entry) shared by the fp32 and
-    int8 kernels — they differ ONLY in ``read_kv``, how the current
-    block's k/v reach fp32."""
+def _tile_pad(shape, dtype):
+    """Bytes a VMEM buffer of ``shape`` takes once its two minor
+    dimensions are padded to the (sublane, lane) tile of ``dtype``."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * (4 // item)
+    lead = 1
+    for n in shape[:-2]:
+        lead *= n
+    return (lead * _fused._round_up(shape[-2], sub)
+            * _fused._round_up(shape[-1], 128) * item)
+
+
+def _walk_vmem_bytes(ppc, pages):
+    """VMEM scratch of one walk: two chunk buffers of ``ppc`` pages for
+    every stream; ``pages`` is [(page_shape, dtype)]."""
+    return sum(_tile_pad((2, ppc) + tuple(shape), dtype)
+               for shape, dtype in pages)
+
+
+def _page_view(arena_shape):
+    """An arena's shape as the kernel views it, a page the matrix it is
+    in memory: ``(nb, bs * kvh, d)`` for K and V, one ``(1, bs * kvh)``
+    lane row a page for a scale arena ``(nb, bs, kvh)``."""
+    nb, bs, kvh = arena_shape[:3]
+    return (nb, bs * kvh) + tuple(arena_shape[3:]) \
+        if len(arena_shape) == 4 else (nb, 1, bs * kvh)
+
+
+def _pages_per_chunk(mb, pages):
+    """P, the pages one loop trip copies and folds: about
+    ``_CHUNK_ROWS`` rows of K, inside ``_VMEM_BUDGET``, at most the
+    table's width. ``pages[0]`` is the K page ``(bs * kvh, d)``."""
+    (page_rows, _), _ = pages[0]
+    ppc = max(1, min(_CHUNK_ROWS // page_rows, mb))
+    while ppc > 1 and _walk_vmem_bytes(ppc, pages) > _VMEM_BUDGET:
+        ppc -= 1
+    return ppc
+
+
+def walk_counts(lengths, mb, bs, ppc=1):
+    """What the decode kernel's walk does for these per-slot
+    ``lengths``, mirrored on the host: ``(live_pages, copied_pages,
+    chunks)``. A slot's live pages are ``cdiv(min(length, mb * bs),
+    bs)``; the kernel copies exactly those (at least one, so a length of
+    zero still reads a page) in ``cdiv(copied, ppc)`` loop trips.
+    ``live_pages / copied_pages`` is the share of the kernel's KV traffic
+    that is live KV."""
+    ln = np.minimum(np.asarray(lengths, np.int64).reshape(-1), mb * bs)
+    live = -(-np.maximum(ln, 0) // bs)
+    copied = np.maximum(live, 1)
+    return (int(live.sum()), int(copied.sum()),
+            int((-(-copied // ppc)).sum()))
+
+
+def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
+                 scale, bs, kvh, mb, ppc):
+    """ONE walk shared by the bf16/fp32 and the int8 kernels: a grid
+    step is a slot; its body loops over the slot's live pages, ``ppc``
+    to a chunk, copying each live page from the HBM arenas into one of
+    two chunk buffers (the next chunk's copies are in flight while the
+    current chunk is folded into the online softmax). The trip count is
+    read from the scalar-prefetched ``lengths``, so one program serves
+    every length. The kernels differ ONLY in ``to_chunk``: how a
+    buffered chunk becomes the ``(ppc * bs * kvh, d)`` K and V matrices
+    (and, for int8, the per-row scales that go with them).
+
+    A chunk is used whole, as ``(rows, d)`` with row ``t * kvh + k``
+    holding token t of kv head k — the arena page as it lies in memory,
+    no transpose. Scores of all h query heads against all rows are one
+    product; the rows of the other kv heads and the tokens past the
+    length are masked, and the masked probabilities give the output
+    from one ``p @ V`` (8x the MXU work of a grouped einsum at 8 kv
+    heads, and a sixth of its time on the chip: PERF.md section 6).
+    Running max, normalizer and accumulator are fp32 loop carries."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    hbm, o_ref = refs[:n_streams], refs[n_streams]
+    bufs = refs[n_streams + 1:2 * n_streams + 1]
+    sems, parity = refs[2 * n_streams + 1:]
     i = pl.program_id(0)
-    j = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    h, d = q_ref.shape[1:]
+    rows = ppc * bs * kvh
 
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def pages_of(slot):
+        ln = jnp.minimum(len_ref[slot], mb * bs)
+        return jnp.maximum(pl.cdiv(ln, bs), 1)
 
-    length = len_ref[i]
+    def each_live_page(slot, n_pages, c, buf, fn):
+        """``fn`` on the copy of every live page of chunk c of slot (a
+        run-time count: the slot's last chunk has fewer than ``ppc``);
+        returns that count."""
+        n_live = jnp.minimum(n_pages - c * ppc, ppc)
 
-    @pl.when(j * bs < length)
-    def _block():
-        k, v = read_kv()
-        _online_update(q_ref[0].astype(jnp.float32), k, v,
-                       j, bs, length, scale, m_ref, l_ref, acc_ref)
+        def page_copies(p, carry):
+            page = tbl_ref[slot, c * ppc + p]
+            for s in range(n_streams):
+                fn(pltpu.make_async_copy(
+                    hbm[s].at[page], bufs[s].at[buf, p], sems.at[s, buf]))
+            return carry
 
-    @pl.when(j == nblocks - 1)
-    def _finalize():
-        kvh, g, d = acc_ref.shape
-        o_ref[0] = (acc_ref[...] / l_ref[...]).reshape(
-            kvh * g, d).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, n_live, page_copies, 0)
+        return n_live
+
+    def start(slot, n_pages, c, buf):
+        """Start chunk c's copies. A page past the slot's last live one
+        is never copied; its place in the V buffer (and V's scales) is
+        zeroed instead: it meets a probability of exactly 0, but what an
+        older chunk left there may be another slot's NaN. (K's place
+        needs nothing: its scores are masked, not multiplied.)"""
+        n_live = each_live_page(slot, n_pages, c, buf,
+                                lambda cp: cp.start())
+
+        def clear(p, carry):
+            for b_ in bufs[1::2]:
+                b_[buf, p] = jnp.zeros(b_.shape[2:], b_.dtype)
+            return carry
+
+        jax.lax.fori_loop(n_live, ppc, clear, 0)
+
+    length = jnp.minimum(len_ref[i], mb * bs)
+    n_pages = pages_of(i)
+    n_chunks = pl.cdiv(n_pages, ppc)
+
+    @pl.when(i == 0)
+    def _first():
+        parity[0] = 0
+        start(i, n_pages, 0, 0)
+
+    base = parity[0]
+    q = q_ref[0]
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, rows), 1)
+    own_head = (col % kvh) == (
+        jax.lax.broadcasted_iota(jnp.int32, (h, rows), 0) // (h // kvh))
+    tok = col // kvh
+
+    def fold(c, carry):
+        m, l, acc = carry
+        buf = (base + c) % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next_chunk():
+            start(i, n_pages, c + 1, 1 - buf)
+
+        @pl.when((c + 1 == n_chunks) & (i + 1 < n_slots))
+        def _next_slot():
+            nxt = jnp.minimum(i + 1, n_slots - 1)
+            start(nxt, pages_of(nxt), 0, 1 - buf)
+
+        each_live_page(i, n_pages, c, buf, lambda cp: cp.wait())
+        k, v, k_scale, v_scale = to_chunk(bufs, buf, q.dtype)
+        s = jax.lax.dot_general(
+            q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        if k_scale is not None:
+            s = s * k_scale
+        s = jnp.where(own_head & (c * (ppc * bs) + tok < length),
+                      s * scale, _NEG)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m - m_new)
+        l = l * corr + jnp.sum(p, axis=-1, keepdims=True)
+        if v_scale is not None:
+            p = p * v_scale
+        acc = acc * corr + jnp.dot(p.astype(v.dtype), v,
+                                   preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, n_chunks, fold,
+        (jnp.full((h, 1), _NEG, jnp.float32),
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+    parity[0] = (base + n_chunks) % 2
 
 
-def _decode_kernel(tbl_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, bs, scale, nblocks):
-    _decode_kernel_core(
-        len_ref, q_ref,
-        lambda: (k_ref[0].astype(jnp.float32),
-                 v_ref[0].astype(jnp.float32)),
-        o_ref, m_ref, l_ref, acc_ref, bs=bs, scale=scale,
-        nblocks=nblocks)
+def _chunk_as_stored(bufs, buf, q_dtype):
+    """bf16/fp32 arenas: K and V enter the products as stored (in the
+    wider of their dtype and q's); no scales."""
+    k, v = bufs[0][buf], bufs[1][buf]
+    dt = jnp.promote_types(k.dtype, q_dtype)
+    d = k.shape[-1]
+    return k.reshape(-1, d).astype(dt), v.reshape(-1, d).astype(dt), \
+        None, None
 
 
-def _decode_kernel_int8(tbl_ref, len_ref, q_ref, k_ref, v_ref, sk_ref,
-                        sv_ref, o_ref, m_ref, l_ref, acc_ref, *, bs,
-                        scale, nblocks):
-    """int8 twin of :func:`_decode_kernel`: the k/v blocks arrive as
-    int8 codes plus their (bs, kvh) fp32 absmax scale blocks (same
-    scalar-prefetch index_map — the scale DMA rides the code DMA), and
-    the dequant happens in registers right before the block's einsum.
-    HBM traffic per table entry is the quantized footprint."""
-    _decode_kernel_core(
-        len_ref, q_ref,
-        lambda: (_deq_block(k_ref[0], sk_ref[0]),
-                 _deq_block(v_ref[0], sv_ref[0])),
-        o_ref, m_ref, l_ref, acc_ref, bs=bs, scale=scale,
-        nblocks=nblocks)
+def _chunk_dequantized(bufs, buf, q_dtype):
+    """int8 arenas: HBM traffic is the quantized footprint. The codes
+    enter the products as fp32 (exact) and each row's dequantization
+    step ``absmax / 127`` — the chunk's ``(bs, kvh)`` scale pages, copied
+    by the same loop as lane rows — multiplies its column of the scores
+    (K) and of the probabilities (V): ``q . (c * step) == (q . c) * step``
+    up to fp32 rounding, and no chunk-sized fp32 K or V is ever built."""
+    from ...distributed.collectives.quantized import _QMAX
+    d = bufs[0].shape[-1]
+
+    def steps(scale_buf):               # (ppc, 1, rows) -> (1, ppc * rows)
+        return jnp.concatenate(
+            [scale_buf[buf, p] for p in range(scale_buf.shape[1])],
+            axis=-1) * (1.0 / _QMAX)
+
+    return (bufs[0][buf].reshape(-1, d).astype(jnp.float32),
+            bufs[1][buf].reshape(-1, d).astype(jnp.float32),
+            steps(bufs[2]), steps(bufs[3]))
+
+
+def _tiles(arena_shape, dtype) -> bool:
+    """Shapes the walk's chunks can take on the chip. The kernel reads
+    a page as the ``(bs * kvh, d)`` matrix it is in memory: that view is
+    free (a bitcast) when the ``(kvh, d)`` planes are whole tiles — a
+    head_dim of whole 128-lane rows, kv heads in whole groups of 8
+    sublanes — and an int8 arena's scale page must be whole 128-lane
+    rows as well. Anything else would be re-laid out, arena-wide, on
+    every call. Interpret mode takes any shape."""
+    _, bs, kvh, d = arena_shape
+    return (d % 128 == 0 and kvh % 8 == 0
+            and (jnp.dtype(dtype) != jnp.int8 or (bs * kvh) % 128 == 0))
 
 
 def _kernel_ok(k_arena) -> bool:
     """Route the s=1 fp32/bf16 read through the Pallas kernel (real TPU
     or forced interpret mode); everything else takes the gathered-dense
     reference path — including the whole CPU quick lane, which is what
-    keeps paged streams bit-identical to the dense engine there."""
+    keeps paged streams bit-identical to the dense engine there — and so
+    does a shape whose pages do not tile (:func:`_tiles`)."""
     return (k_arena.dtype in (jnp.float32, jnp.bfloat16)
-            and _fused._pallas_ok())
+            and _fused._pallas_ok()
+            and (_fused._FORCE_INTERPRET
+                 or _tiles(k_arena.shape, k_arena.dtype)))
 
 
 def _kernel_ok_int8(k_codes) -> bool:
     """The int8 kernel's routing gate: code arenas only, TPU or forced
-    interpret mode. Off-TPU the int8 read takes the per-block scan
-    fallback (NOT the dense oracle — the no-fp32-KV-transient contract
-    holds on every backend)."""
-    return k_codes.dtype == jnp.int8 and _fused._pallas_ok()
+    interpret mode, pages that tile. Everything else takes the
+    per-block scan fallback (NOT the dense oracle — the
+    no-fp32-KV-transient contract holds on every backend)."""
+    return (k_codes.dtype == jnp.int8 and _fused._pallas_ok()
+            and (_fused._FORCE_INTERPRET
+                 or _tiles(k_codes.shape, k_codes.dtype)))
 
 
-def _grid_call(name, kernel, in_specs, operands, b, mb, h, d, kvh,
-               out_dtype):
+def _walk_call(name, to_chunk, q, arenas, block_table, lengths, scale):
+    """The walk over ``arenas`` (K, V and, for int8, their scale
+    arenas), each viewed page by page (:func:`_page_view`)."""
+    kvh = arenas[0].shape[2]
+    arenas = tuple(a.reshape(_page_view(a.shape)) for a in arenas)
+    ppc = _pages_per_chunk(block_table.shape[1],
+                           [(a.shape[1:], a.dtype) for a in arenas])
+    return _walk_pallas_call(q, arenas, block_table, lengths, name=name,
+                             to_chunk=to_chunk, scale=scale, kvh=kvh,
+                             ppc=ppc, interpret=_fused._FORCE_INTERPRET)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "name", "to_chunk", "scale", "kvh", "ppc", "interpret"))
+def _walk_pallas_call(q, arenas, block_table, lengths, *, name, to_chunk,
+                      scale, kvh, ppc, interpret):
+    """The ``pallas_call``: the arenas stay in HBM, the table and the
+    lengths are scalar-prefetched, q and the output are pipelined per
+    slot. The grid is sequential: the chunk buffers, their parity and
+    the first chunk of the next slot are handed from one slot to the
+    next.
+
+    Jitted on its own so that a model's layers, which all make this call
+    at one shape, share ONE trace and ONE lowering of the kernel inside
+    the decode program (a sixteenth of that part of set-up at 16 layers);
+    everything the trace depends on is an argument."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    b, h, d = q.shape
+    mb = block_table.shape[1]
+    bs = arenas[0].shape[1] // kvh
+    n = len(arenas)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, mb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d),
-                               lambda i, j, tbl, lens: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((kvh, h // kvh, 1), jnp.float32),
-            pltpu.VMEM((kvh, h // kvh, 1), jnp.float32),
-            pltpu.VMEM((kvh, h // kvh, d), jnp.float32),
-        ],
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, d), lambda i, tbl, lens: (i, 0, 0))]
+        + [pl.BlockSpec(memory_space=pltpu.HBM)] * n,
+        out_specs=pl.BlockSpec((1, h, d), lambda i, tbl, lens: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, ppc) + a.shape[1:], a.dtype)
+                        for a in arenas]
+        + [pltpu.SemaphoreType.DMA((n, 2)), pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
-        kernel,
+        functools.partial(_walk_kernel, n_streams=n, to_chunk=to_chunk,
+                          scale=scale, bs=bs, kvh=kvh, mb=mb, ppc=ppc),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=_fused._FORCE_INTERPRET, name=name,
-    )(*operands)
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name=name,
+    )(block_table, lengths, q, *arenas)
 
 
 def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
@@ -297,26 +500,11 @@ def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
     """One decode step of paged attention: q (b, h, d) against the
     arena through the block table; lengths (b,) = tokens valid per slot
     (the just-written current token included). Online softmax over the
-    table entries; entries past the length are skipped, entry 0 (trash)
-    is only ever touched by skipped/dead rows."""
-    from jax.experimental import pallas as pl
-
-    b, h, d = q.shape
-    nb, bs, kvh, _ = k_arena.shape
-    mb = block_table.shape[1]
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda i, j, tbl, lens: (i, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, d),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, d),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0, 0)),
-    ]
-    return _grid_call(
-        "paged_attention_decode",
-        functools.partial(_decode_kernel, bs=bs, scale=scale,
-                          nblocks=mb),
-        in_specs, (block_table, lengths, q, k_arena, v_arena),
-        b, mb, h, d, kvh, q.dtype)
+    slot's live pages only: a length beyond the table is clamped, and
+    the trash block 0 is only ever read through a dead slot's zeroed
+    table row."""
+    return _walk_call("paged_attention_decode", _chunk_as_stored, q,
+                      (k_arena, v_arena), block_table, lengths, scale)
 
 
 def _int8_decode_fallback(q, k_codes, v_codes, k_scales, v_scales,
@@ -367,8 +555,6 @@ def paged_attention_decode_int8(q, k_codes, v_codes, k_scales, v_scales,
     and fp32 accumulation as the dequant-then-dense oracle, reassociated
     by the online softmax — parity is pinned to ~1e-5, and greedy
     engine streams are pinned token-identical to the oracle route."""
-    from jax.experimental import pallas as pl
-
     if _FORCE_INT8_REFERENCE:
         return paged_attention_int8_reference(
             q[:, None], k_codes, v_codes, k_scales, v_scales,
@@ -377,24 +563,6 @@ def paged_attention_decode_int8(q, k_codes, v_codes, k_scales, v_scales,
         return _int8_decode_fallback(
             q, k_codes, v_codes, k_scales, v_scales, block_table,
             lengths, scale=scale)
-    b, h, d = q.shape
-    nb, bs, kvh, _ = k_codes.shape
-    mb = block_table.shape[1]
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda i, j, tbl, lens: (i, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, d),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0, 0)),
-        pl.BlockSpec((1, bs, kvh, d),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0, 0)),
-        pl.BlockSpec((1, bs, kvh),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-        pl.BlockSpec((1, bs, kvh),
-                     lambda i, j, tbl, lens: (tbl[i, j], 0, 0)),
-    ]
-    return _grid_call(
-        "paged_attention_decode_int8",
-        functools.partial(_decode_kernel_int8, bs=bs, scale=scale,
-                          nblocks=mb),
-        in_specs, (block_table, lengths, q, k_codes, v_codes,
-                   k_scales, v_scales),
-        b, mb, h, d, kvh, q.dtype)
+    return _walk_call("paged_attention_decode_int8", _chunk_dequantized,
+                      q, (k_codes, v_codes, k_scales, v_scales),
+                      block_table, lengths, scale)

@@ -1025,14 +1025,14 @@ class ContinuousBatchingEngine:
         decoded twice or dropped). A slot whose log-probs went NaN is
         quarantined alone via :meth:`cancel_slot` — the other rows'
         streams are untouched (bit-identical, pinned in tests)."""
-        from ..profiler import RecordEvent
         if self._pending_block is None:
             if not self.has_decoding():
                 return
             if faults.should_fire("serving.poison"):
                 self._poison_live_slot()
             faults.fault_point("serving.step_block")
-            with RecordEvent("serving.decode_block"):
+            with _span("serving.decode_block",
+                       **self._decode_block_counters()):
                 out = self.backend.decode_block(self._cache, self._state)
             self._cache, self._state = out[0], out[1]
             # old AOT artifacts predate the ok flags: pad with None
@@ -1053,6 +1053,12 @@ class ContinuousBatchingEngine:
         self._pending_block = None
         with _span("serving.harvest"):
             self._credit_block(toks_np, lives_np, oks_np, rem_np)
+
+    def _decode_block_counters(self) -> dict:
+        """Counters the ``serving.decode_block`` span carries besides the
+        engine's own (``steps``, ``slot_steps``): none on the dense
+        engine; the paged engine counts its kernel's page walk here."""
+        return {}
 
     def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
         """The host half of a decode block: credit each live slot its

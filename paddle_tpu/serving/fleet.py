@@ -893,17 +893,11 @@ class DecodeWorker:
         run = _SlotRun(req, tokens=toks,
                        t_admit=meta["t_admit"], block_ids=blocks)
         eng._slots[slot] = run
-        eos = req.eos_token_id
-        eng._state = eng._arm_jit(
-            eng._state, jnp.int32(slot), jnp.asarray(table_row),
-            jnp.int32(meta["tok0"]), jnp.int32(meta["pos0"]),
-            jnp.int32(meta["rem0"]),
-            jnp.int32(-1 if eos is None else eos),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jnp.float32(req.top_p),
-            jnp.asarray(np.asarray(h.arrays["key"], np.uint32)))
+        eng._arm(slot, table_row, meta["tok0"], meta["pos0"], meta["rem0"],
+                 req.eos_token_id, jnp.float32(req.temperature),
+                 jnp.int32(req.top_k), jnp.float32(req.top_p),
+                 jnp.asarray(np.asarray(h.arrays["key"], np.uint32)))
         self._commit()
-        eng._remaining_host[slot] = meta["rem0"]
         return True
 
     def _adopt_dense(self, h: KVHandoff, slot: int) -> bool:

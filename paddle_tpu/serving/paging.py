@@ -704,6 +704,11 @@ class PagedEngine(ContinuousBatchingEngine):
         self.prefilled_tokens = 0      # actually computed
         self.prefill_chunks = 0        # chunk programs dispatched
         self.fetched_tokens = 0        # of shared: remote-fetched KV
+        # the decode kernel's walk (per attention layer), and the host's
+        # mirror of the in-graph per-slot ``pos`` it is counted from
+        self.kv_pages_live = 0         # pages holding a slot's live KV
+        self.kv_pages_copied = 0       # pages the kernel copied for them
+        self._pos_host = np.zeros((self.num_slots,), np.int64)
 
     # -- introspection -----------------------------------------------------
     def prefix_cache_hit_rate(self) -> float:
@@ -912,16 +917,48 @@ class PagedEngine(ContinuousBatchingEngine):
         if rem0 <= 0:                # finished at admission
             self._retire(job.slot, job.run, now)
             return
-        L = len(job.prompt)
-        self._state = self._arm_jit(
-            self._state, jnp.int32(job.slot),
-            jnp.asarray(job.table_row), jnp.int32(tok0), jnp.int32(L),
-            jnp.int32(rem0), jnp.int32(-1 if eos is None else eos),
-            job.temp, job.topk, job.topp, job.key)
+        self._arm(job.slot, job.table_row, tok0, len(job.prompt), rem0,
+                  eos, job.temp, job.topk, job.topp, job.key)
         if self.tracer is not None:
             self.tracer.span_begin(req.request_id, "decode",
                                    slot=job.slot)
-        self._remaining_host[job.slot] = rem0
+
+    def _arm(self, slot, table_row, tok0, pos0, rem0, eos, temp, topk,
+             topp, key):
+        """Arm ``slot`` for decoding, in-graph and in the host's mirrors
+        of its remaining count and position (the one arm site: local
+        prefill completion and the fleet's adopted hand-offs)."""
+        self._state = self._arm_jit(
+            self._state, jnp.int32(slot), jnp.asarray(table_row),
+            jnp.int32(tok0), jnp.int32(pos0), jnp.int32(rem0),
+            jnp.int32(-1 if eos is None else eos), temp, topk, topp, key)
+        self._remaining_host[slot] = rem0
+        self._pos_host[slot] = pos0
+
+    # -- the decode kernel's walk, counted on the host ----------------------
+    def _decode_block_counters(self):
+        """Pages one layer's paged decode reads walk over the block about
+        to be dispatched, from the host's mirrors of ``pos`` and
+        ``remaining`` (no device fetch): at step k a slot's read is
+        ``pos + min(k, steps it stays live) + 1`` tokens long, and a dead
+        slot re-reads the length it was left at, through its zeroed table
+        row. ``ops.pallas.paged_attention.walk_counts`` mirrors the
+        kernel's loop bound. A slot that meets its EOS inside the block
+        is counted as if it ran to the block's end (at most one page a
+        block over)."""
+        from ..ops.pallas.paged_attention import walk_counts
+        k = np.arange(self.decode_block)[:, None]
+        stays = np.minimum(self._remaining_host, self.decode_block)
+        live, copied, _ = walk_counts(
+            self._pos_host[None, :] + np.minimum(k, stays[None, :]) + 1,
+            self.max_blocks, self.kv_block_size)
+        self.kv_pages_live += live
+        self.kv_pages_copied += copied
+        return {"kv_pages_live": live, "kv_pages_copied": copied}
+
+    def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
+        self._pos_host += lives_np.sum(axis=0)      # pos += live, per step
+        super()._credit_block(toks_np, lives_np, oks_np, rem_np)
 
     def _retire(self, slot, run, now):
         super()._retire(slot, run, now)
@@ -1017,7 +1054,9 @@ class PagedEngine(ContinuousBatchingEngine):
             "shared_tokens": self.shared_tokens,
             "prefilled_tokens": self.prefilled_tokens,
             "prefill_chunks": self.prefill_chunks,
-            "fetched_tokens": self.fetched_tokens}
+            "fetched_tokens": self.fetched_tokens,
+            "kv_pages_live": self.kv_pages_live,
+            "kv_pages_copied": self.kv_pages_copied}
         return meta, arrays
 
     def restore_state(self, meta, arrays):
@@ -1066,3 +1105,6 @@ class PagedEngine(ContinuousBatchingEngine):
         self.prefilled_tokens = pc["prefilled_tokens"]
         self.prefill_chunks = pc["prefill_chunks"]
         self.fetched_tokens = pc.get("fetched_tokens", 0)
+        self.kv_pages_live = pc.get("kv_pages_live", 0)
+        self.kv_pages_copied = pc.get("kv_pages_copied", 0)
+        self._pos_host = np.asarray(self._state["pos"]).astype(np.int64)

@@ -16,6 +16,7 @@ back without one.
 """
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -89,30 +90,59 @@ def _compile(fn, one_chip, *specs):
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
-def _paged_specs(arena_dtype):
-    q = ((SLOTS, HEADS, HEAD_DIM), BF16)
-    arena = ((NUM_BLOCKS, KV_BLOCK, HEADS, HEAD_DIM), arena_dtype)
-    table = ((SLOTS, MAX_BLOCKS), jnp.int32)
-    lens = ((SLOTS,), jnp.int32)
+# the paged read's two shapes: Llama-2-7B's (equal heads) and the one the
+# benchmark's mistral7b-decode-sat cell serves (32 slots, a 256-entry
+# table, 8 KV heads under 32 query heads, 5,121 blocks)
+PAGED_SHAPES = {
+    "llama7b": dict(slots=SLOTS, kv_heads=HEADS, max_blocks=MAX_BLOCKS,
+                    num_blocks=NUM_BLOCKS),
+    "served": dict(slots=32, kv_heads=8, max_blocks=256, num_blocks=5121),
+}
+
+
+def _paged_specs(arena_dtype, slots, kv_heads, max_blocks, num_blocks):
+    q = ((slots, HEADS, HEAD_DIM), BF16)
+    arena = ((num_blocks, KV_BLOCK, kv_heads, HEAD_DIM), arena_dtype)
+    table = ((slots, max_blocks), jnp.int32)
+    lens = ((slots,), jnp.int32)
     return q, arena, table, lens
 
 
 SCALE = 1.0 / np.sqrt(HEAD_DIM)
 
 
-def _case_paged_bf16(one_chip):
-    q, arena, table, lens = _paged_specs(BF16)
-    return _compile(
+def _walk_fits_vmem(max_blocks, *arenas):
+    """The walk's own account of its VMEM scratch is under Mosaic's
+    default scoped limit (the compile below is the chip's word on it)."""
+    pages = [(pa._page_view(shape)[1:], dtype) for shape, dtype in arenas]
+    ppc = pa._pages_per_chunk(max_blocks, pages)
+    assert pa._tiles(*arenas[0])
+    assert pa._walk_vmem_bytes(ppc, pages) <= pa._VMEM_BUDGET \
+        < pa._SCOPED_VMEM_LIMIT
+
+
+def _arenas_are_viewed_not_copied(text):
+    """The kernel's (bs * kvh, d) view of a K / V page is a bitcast: a
+    copy here would re-lay the whole arena out on every call."""
+    assert not re.search(r"= (bf16|s8)\[\d+,\d+,\d+\]\S* copy\(", text)
+    return text
+
+
+def _case_paged_bf16(one_chip, shape="llama7b"):
+    q, arena, table, lens = _paged_specs(BF16, **PAGED_SHAPES[shape])
+    _walk_fits_vmem(table[0][1], arena, arena)
+    return _arenas_are_viewed_not_copied(_compile(
         functools.partial(pa.paged_attention_decode, scale=SCALE),
-        one_chip, q, arena, arena, table, lens)
+        one_chip, q, arena, arena, table, lens))
 
 
-def _case_paged_int8(one_chip):
-    q, arena, table, lens = _paged_specs(jnp.int8)
+def _case_paged_int8(one_chip, shape="llama7b"):
+    q, arena, table, lens = _paged_specs(jnp.int8, **PAGED_SHAPES[shape])
     scales = (arena[0][:-1], jnp.float32)
-    return _compile(
+    _walk_fits_vmem(table[0][1], arena, arena, scales, scales)
+    return _arenas_are_viewed_not_copied(_compile(
         functools.partial(pa.paged_attention_decode_int8, scale=SCALE),
-        one_chip, q, arena, arena, scales, scales, table, lens)
+        one_chip, q, arena, arena, scales, scales, table, lens))
 
 
 _QKV = ((2, 2048, HEADS, HEAD_DIM), BF16)
@@ -178,6 +208,10 @@ def _case_adamw(one_chip):
 CASES = {
     "paged_decode_bf16": _case_paged_bf16,
     "paged_decode_int8": _case_paged_int8,
+    "paged_decode_bf16_served_shape":
+        functools.partial(_case_paged_bf16, shape="served"),
+    "paged_decode_int8_served_shape":
+        functools.partial(_case_paged_int8, shape="served"),
     "sdpa_jax_flash_fwd_bwd": _case_sdpa_train,
     "flash_attention_fused_fwd": _case_flash_fused,
     "rms_norm": _case_rms,

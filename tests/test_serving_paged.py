@@ -9,6 +9,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from paged_walk_cases import BS, WALK_CASES, chunk_rows, walk_inputs
+
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
@@ -385,27 +387,104 @@ class TestInt8KV:
 
 
 class TestPagedKernel:
-    def test_interpret_kernel_matches_reference(self, monkeypatch):
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_interpret_kernel_matches_reference(self, case, monkeypatch):
         """The Pallas paged-attention kernel (interpret mode on CPU)
-        matches the gathered-dense reference, GQA heads included."""
+        matches the gathered-dense reference at every edge of its walk
+        over a slot's live pages (tests/paged_walk_cases.py), GQA heads
+        and dead slots included."""
         pytest.importorskip("jax.experimental.pallas")
         import paddle_tpu.ops.pallas.fused as fused
         from paddle_tpu.ops.pallas import paged_attention as pa
         monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
-        rs = np.random.RandomState(0)
-        S, MB, BS, KVH, G, D, NB = 3, 4, 8, 2, 2, 16, 16
-        H = KVH * G
-        q = jnp.asarray(rs.randn(S, H, D).astype(np.float32))
-        ka = jnp.asarray(rs.randn(NB, BS, KVH, D).astype(np.float32))
-        va = jnp.asarray(rs.randn(NB, BS, KVH, D).astype(np.float32))
-        tbl = jnp.asarray(rs.randint(1, NB, (S, MB)).astype(np.int32))
-        lens = jnp.asarray([5, 17, 32], jnp.int32)
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", chunk_rows())
+        q, ka, va, tbl, lens = walk_inputs(case)
+        assert pa._kernel_ok(ka)
         out = pa.paged_attention_decode(q, ka, va, tbl, lens,
-                                        scale=D ** -0.5)
+                                        scale=q.shape[-1] ** -0.5)
         ref = pa.paged_attention_reference(
-            q[:, None], ka, va, tbl, lens, scale=D ** -0.5)[:, 0]
+            q[:, None], ka, va, tbl, lens, scale=q.shape[-1] ** -0.5)[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
+
+    def test_another_slots_nan_never_leaks_through_a_chunk_buffer(
+            self, monkeypatch):
+        """Pages past a slot's last live one are not copied, so their
+        place in the chunk buffer still holds what an earlier chunk left
+        there. A poisoned slot's NaN pages must not reach the next slot
+        through it (the quarantine's blast radius is one slot): slot 0's
+        five pages are NaN, and slot 1's one live page lands in the buffer
+        that last held two of them."""
+        import paddle_tpu.ops.pallas.fused as fused
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", chunk_rows())
+        q, ka, va, _, _ = walk_inputs("full_table_width_not_multiple_of_chunk")
+        tbl = jnp.asarray([[1, 2, 3, 4, 5], [6, 0, 0, 0, 0]], jnp.int32)
+        lens = jnp.asarray([5 * BS, 3], jnp.int32)
+        ka = ka.at[1:6].set(jnp.nan)
+        va = va.at[1:6].set(jnp.nan)
+        out = np.asarray(pa.paged_attention_decode(q, ka, va, tbl, lens,
+                                                   scale=0.25))
+        ref = np.asarray(pa.paged_attention_reference(
+            q[:, None], ka, va, tbl, lens, scale=0.25)[:, 0])
+        assert np.isnan(out[0]).all() and np.isfinite(out[1]).all()
+        np.testing.assert_allclose(out[1], ref[1], atol=1e-5)
+
+    def test_bf16_arena_is_read_as_stored(self, monkeypatch):
+        """A bf16 arena under a bf16 q: the products take bf16 operands
+        and accumulate in fp32, as the oracle's cast-then-multiply."""
+        import paddle_tpu.ops.pallas.fused as fused
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", chunk_rows())
+        q, ka, va, tbl, lens = (
+            a.astype(jnp.bfloat16) if a.dtype == jnp.float32 else a
+            for a in walk_inputs("all_edges_mixed_with_dead_slot"))
+        out = pa.paged_attention_decode(q, ka, va, tbl, lens, scale=0.25)
+        ref = pa.paged_attention_reference(
+            q[:, None], ka, va, tbl, lens, scale=0.25)[:, 0]
+        assert out.dtype == jnp.bfloat16
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32), atol=2e-2)
+
+    def test_walk_counts_matches_a_plain_loop(self):
+        from paddle_tpu.ops.pallas.paged_attention import walk_counts
+        rs = np.random.RandomState(5)
+        for mb, bs, ppc in ((5, 8, 2), (256, 16, 16), (3, 4, 1), (7, 2, 3)):
+            lengths = rs.randint(0, mb * bs + 2 * bs, (4, 9))
+            live = copied = chunks = 0
+            for n in lengths.reshape(-1):
+                pages = -(-min(int(n), mb * bs) // bs)
+                live += pages
+                copied += max(pages, 1)      # the walk never reads nothing
+                chunks += -(-max(pages, 1) // ppc)
+            assert walk_counts(lengths, mb, bs, ppc) \
+                == (live, copied, chunks)
+        assert walk_counts([563] * 32, 256, 16)[:2] == (36 * 32, 36 * 32)
+
+    def test_shapes_the_walk_cannot_tile_take_the_reference(self,
+                                                            monkeypatch):
+        """On a TPU the kernel reads a page as a (bs * kvh, d) matrix: a
+        head_dim that is not whole 128-lane rows, kv heads that do not
+        fill 8 sublanes, or an int8 scale page that is not whole lane
+        rows would be re-laid out arena-wide on every call, so they route
+        to the gathered reference (int8: the per-block scan) instead."""
+        import paddle_tpu.ops.pallas.fused as fused
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        monkeypatch.setattr(fused, "_on_tpu", lambda: True)
+        ok = lambda shape, dt=jnp.bfloat16: pa._kernel_ok(   # noqa: E731
+            jax.ShapeDtypeStruct(shape, dt))
+        assert ok((5121, 16, 8, 128))                  # the served shape
+        assert ok((1025, 16, 32, 128), jnp.float32)    # Llama-2-7B heads
+        assert not ok((64, 16, 8, 64))                 # head_dim 64
+        assert not ok((64, 16, 4, 128))                # 4 kv heads
+        assert not ok((64, 16, 8, 128), jnp.float16)   # dtype, as before
+        ok8 = lambda shape: pa._kernel_ok_int8(        # noqa: E731
+            jax.ShapeDtypeStruct(shape, jnp.int8))
+        assert ok8((5121, 16, 8, 128))
+        assert not ok8((64, 8, 8, 128))                # 64-wide scale page
+        assert not ok8((64, 16, 8, 64))
 
     def test_kernel_not_dispatched_on_cpu(self):
         """Without TPU or forced interpret, the paged read must take
@@ -414,6 +493,63 @@ class TestPagedKernel:
         from paddle_tpu.ops.pallas.paged_attention import _kernel_ok
         if jax.default_backend() == "cpu" and not fused._FORCE_INTERPRET:
             assert not _kernel_ok(jnp.zeros((2, 4, 2, 8), jnp.float32))
+
+
+class TestKvWalkCounters:
+    def test_decode_block_counts_the_walk_it_ran(self, paged_setup,
+                                                 monkeypatch):
+        """Every decode block adds to ``engine.kv_pages_live`` /
+        ``kv_pages_copied``, and carries on its ``serving.decode_block``
+        span, exactly what ``walk_counts`` gives for the lengths the
+        block's steps read — reckoned here from the DEVICE's pos /
+        remaining / live before each block, which the engine's host
+        mirrors must agree with (dead and mid-prefill slots included:
+        they re-read the length they were left at)."""
+        import time
+        from paddle_tpu.observability import tracing
+        from paddle_tpu.ops.pallas.paged_attention import walk_counts
+        model, cfg, engine = paged_setup
+        engine.reset()
+        want = [0, 0]
+        step_block = engine.step_block
+
+        def spying_step_block():
+            if engine._pending_block is None and engine.has_decoding():
+                st = {k: np.asarray(engine._state[k])
+                      for k in ("pos", "remaining", "live")}
+                np.testing.assert_array_equal(st["pos"], engine._pos_host)
+                stays = np.where(st["live"], np.minimum(
+                    st["remaining"], engine.decode_block), 0)
+                for k in range(engine.decode_block):
+                    live, copied, _ = walk_counts(
+                        st["pos"] + np.minimum(k, stays) + 1,
+                        engine.max_blocks, engine.kv_block_size)
+                    want[0] += live
+                    want[1] += copied
+            step_block()
+
+        monkeypatch.setattr(engine, "step_block", spying_step_block)
+        rs = np.random.RandomState(4)
+        srv = Server(engine)
+        # three requests on two slots: the third refills a freed slot, and
+        # the uneven budgets leave a dead slot beside a live one
+        for n_prompt, n_new in ((5, 7), (19, 12), (9, 5)):
+            srv.submit(rs.randint(0, cfg.vocab_size, (n_prompt,))
+                       .astype(np.int32), max_new_tokens=n_new)
+        t0 = time.perf_counter()
+        srv.run_until_idle()
+        spans = [sp for sp in tracing.since(t0)
+                 if sp.name == "serving.decode_block"]
+        assert spans and want[0] > 0
+        assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied"}
+        assert [engine.kv_pages_live, engine.kv_pages_copied] == want
+        assert [sum(sp.ids[k] for sp in spans)
+                for k in ("kv_pages_live", "kv_pages_copied")] == want
+        # copies are issued per live page: all of the kernel's KV traffic
+        # is live KV
+        assert engine.kv_pages_live == engine.kv_pages_copied
+        np.testing.assert_array_equal(np.asarray(engine._state["pos"]),
+                                      engine._pos_host)
 
 
 class TestPagedScheduling:

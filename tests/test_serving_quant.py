@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from paged_walk_cases import WALK_CASES, chunk_rows, walk_inputs
+
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.nn.quant import dequantize_array, quantize_array
@@ -109,19 +111,26 @@ class TestInt8KVInRead:
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
-    def test_interpret_kernel_matches_oracle(self, monkeypatch):
-        """The Pallas int8 kernel (interpret mode on CPU) dequantizes
-        code+scale blocks in registers and matches the oracle, GQA
-        heads included."""
+    @pytest.mark.parametrize("case", sorted(WALK_CASES))
+    def test_interpret_kernel_matches_oracle(self, case, monkeypatch):
+        """The Pallas int8 kernel (interpret mode on CPU) walks the same
+        live pages as the bf16 one, applies each row's absmax / 127 step
+        to its column of the scores and the probabilities, and matches
+        the dequant-then-dense oracle at every edge of the walk
+        (tests/paged_walk_cases.py), GQA heads and dead slots included."""
         pytest.importorskip("jax.experimental.pallas")
         import paddle_tpu.ops.pallas.fused as fused
         from paddle_tpu.ops.pallas import paged_attention as pa
         monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
-        q, kc, vc, ks, vs, tbl, lens, D = self._arena(1)
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", chunk_rows())
+        q, ka, va, tbl, lens = walk_inputs(case, seed=1)
+        (kc, ks), (vc, vs) = pa.quantize_kv(ka), pa.quantize_kv(va)
+        assert pa._kernel_ok_int8(kc)
+        scale = q.shape[-1] ** -0.5
         out = pa.paged_attention_decode_int8(q, kc, vc, ks, vs, tbl,
-                                             lens, scale=D ** -0.5)
+                                             lens, scale=scale)
         ref = pa.paged_attention_int8_reference(
-            q[:, None], kc, vc, ks, vs, tbl, lens, scale=D ** -0.5)[:, 0]
+            q[:, None], kc, vc, ks, vs, tbl, lens, scale=scale)[:, 0]
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
 
