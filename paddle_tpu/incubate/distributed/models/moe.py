@@ -9,7 +9,15 @@ TPU-native design: GShard-style *dense dispatch* — top-k gating builds a
 are einsums that XLA maps onto the MXU. Expert weights carry a partition
 spec over the expert mesh axis; under jit GSPMD turns the dispatch einsum
 into exactly the all-to-all the reference's global_scatter implements by
-hand. Capacity + GShard aux load-balance loss included."""
+hand. Capacity + GShard aux load-balance loss included.
+
+:class:`DroplessMoE` is the other kind of block: no capacity and no dropped
+token (sort the picks by expert, grouped matmuls over the experts HELD,
+weighted combine), SwiGLU experts, a sigmoid/softmax router with the
+``noaux_tc`` selection bias. It is told which experts it holds, routes over
+the published router width and computes its own experts' part — what expert
+parallelism asks of a layer, and what one chip of a cut deployment runs
+without the exchange. ``models/deepseek_v3.py`` is built on it."""
 from __future__ import annotations
 
 import math
@@ -23,7 +31,8 @@ from ....nn.common import Linear
 from ....nn.layer import Layer
 from ....tensor import Tensor, apply_op
 
-__all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate", "ExpertMLP"]
+__all__ = ["MoELayer", "NaiveGate", "GShardGate", "SwitchGate", "ExpertMLP",
+           "DroplessMoE", "route_topk", "dropless_expert_mix"]
 
 
 class BaseGate(Layer):
@@ -286,3 +295,128 @@ class MoELayer(Layer):
 
         out = apply_op(combine, comb, expert_out)
         return reshape(out, (b, s, d))
+
+
+# ---------------------------------------------------------------------------
+# dropless top-k SwiGLU experts (the servable block)
+# ---------------------------------------------------------------------------
+
+def route_topk(logits, bias, *, top_k, scoring="sigmoid", n_group=1,
+               topk_group=1, norm_topk_prob=True, scaling=1.0, forced=None):
+    """The published DeepSeek-V3 router on float32 ``logits (T, E)``:
+    ``s = sigmoid(logits)`` (or softmax); the CHOICE is the top-k of ``s +
+    bias`` (``bias`` = ``e_score_correction_bias``, ``noaux_tc``), limited
+    to the ``topk_group`` groups (of ``n_group``) whose two best biased
+    scores sum highest; the WEIGHTS are ``s`` itself at the chosen experts
+    (no bias), normalised over the k (``+ 1e-20``) and scaled. ``forced
+    (T, k)`` replaces the choice and leaves the weights' rule alone.
+    Returns ``(idx (T, k) int32, weights (T, k) float32)``."""
+    logits = logits.astype(jnp.float32)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        t, e = choice.shape
+        grouped = choice.reshape(t, n_group, e // n_group)
+        group_score = jnp.sum(jax.lax.top_k(grouped, 2)[0], axis=-1)
+        keep = jax.lax.top_k(group_score, topk_group)[1]        # (T, g)
+        mask = jnp.zeros((t, n_group), bool).at[
+            jnp.arange(t)[:, None], keep].set(True)
+        choice = jnp.where(jnp.repeat(mask, e // n_group, axis=1),
+                           choice, 0.0)
+    idx = jax.lax.top_k(choice, top_k)[1] if forced is None \
+        else forced.reshape(-1, top_k)
+    w = jnp.take_along_axis(scores, idx, axis=-1)
+    if norm_topk_prob:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), w * scaling
+
+
+def dropless_expert_mix(x, idx, weights, w_gate, w_up, w_down, first=0):
+    """``sum_k weights[t, k] * SwiGLU_{idx[t, k]}(x[t])`` over the experts
+    HELD: the stacked ``w_gate`` / ``w_up (E_held, h, ff)`` and ``w_down
+    (E_held, ff, h)`` are global experts ``first .. first + E_held - 1``;
+    a pick of any other expert adds nothing here (its holder adds it).
+    Exact and dropless for any routing: the ``T * k`` picks are sorted by
+    expert, each expert's rows form one group of three grouped matmuls
+    (``jax.lax.ragged_dot``: rows past the last group come out zero), and
+    the rows return to token order by the inverse permutation — gathers
+    only, no capacity, no scatter. Returns ``(y (T, h), stats)`` with
+    ``stats`` int32 ``[picks on held experts, held experts with at least
+    one token, largest count on one expert]``."""
+    t, k = idx.shape
+    e = w_gate.shape[0]
+    local = idx.reshape(-1) - first
+    held = (local >= 0) & (local < e)
+    key = jnp.where(held, local, e)              # not held: sorted last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((e + 1,), jnp.int32).at[key].add(1)[:e]
+    xs = x[order // k]                           # (T*k, h), expert-major
+    act = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, sizes)) \
+        * jax.lax.ragged_dot(xs, w_up, sizes)
+    ys = jax.lax.ragged_dot(act, w_down, sizes)  # rows not held: zeros
+    back = jnp.argsort(order)                    # token-major again
+    w = jnp.where(held, weights.reshape(-1), 0.0).astype(ys.dtype)
+    y = jnp.sum((ys[back] * w[:, None]).reshape(t, k, -1), axis=1)
+    stats = jnp.stack([jnp.sum(sizes), jnp.sum(sizes > 0),
+                       jnp.max(sizes)]).astype(jnp.int32)
+    return y, stats
+
+
+class DroplessMoE(Layer):
+    """Router + the routed experts this layer holds (+ nothing else: the
+    always-on shared experts are the model's, counted once across
+    holders). ``experts`` is ``(first, count)``: the global experts held,
+    all of them by default. ``forward(x (..., h))`` returns ``(y, idx,
+    stats)``; ``forced_idx`` replaces the router's choice (weights are
+    still the router's scores at those experts), which is how a reference
+    is held to the system's picks."""
+
+    def __init__(self, hidden, ffn, num_experts, top_k, *, experts=None,
+                 scoring="sigmoid", n_group=1, topk_group=1,
+                 norm_topk_prob=True, scaling=1.0):
+        super().__init__()
+        from ....nn import initializer as I
+        first, count = experts or (0, num_experts)
+        if not (0 <= first and count > 0
+                and first + count <= num_experts):
+            raise ValueError(f"experts held {(first, count)} lie outside "
+                             f"the router's {num_experts}")
+        self.first, self.count = first, count
+        self.route = dict(top_k=top_k, scoring=scoring, n_group=n_group,
+                          topk_group=topk_group,
+                          norm_topk_prob=norm_topk_prob, scaling=scaling)
+        self.gate = Linear(hidden, num_experts, bias_attr=False)
+        self.e_score_correction_bias = self.create_parameter(
+            (num_experts,), dtype="float32",
+            default_initializer=I.Constant(0.0))
+        up = I.XavierNormal(fan_in=hidden, fan_out=ffn)
+        self.gate_proj = self.create_parameter(
+            (count, hidden, ffn), default_initializer=up)
+        self.up_proj = self.create_parameter(
+            (count, hidden, ffn), default_initializer=up)
+        self.down_proj = self.create_parameter(
+            (count, ffn, hidden),
+            default_initializer=I.XavierNormal(fan_in=ffn, fan_out=hidden))
+
+    def forward(self, x, forced_idx=None):
+        def run(xv, gw, bias, wg, wu, wd, forced=None):
+            flat = xv.reshape(-1, xv.shape[-1])
+            with jax.named_scope("moe_router"):
+                # float32 in earnest: a TPU runs a float32 product in
+                # bf16 passes unless told otherwise, and a pick is a
+                # comparison of nearly equal scores
+                logits = jnp.dot(flat.astype(jnp.float32),
+                                 gw.astype(jnp.float32),
+                                 precision=jax.lax.Precision.HIGHEST)
+                idx, w = route_topk(logits, bias, forced=forced,
+                                    **self.route)
+            with jax.named_scope("moe_experts"):
+                y, stats = dropless_expert_mix(flat, idx, w, wg, wu, wd,
+                                               self.first)
+            return y.reshape(xv.shape), idx, stats
+        args = (x, self.gate.weight, self.e_score_correction_bias,
+                self.gate_proj, self.up_proj, self.down_proj)
+        if forced_idx is not None:
+            args += (forced_idx,)
+        return apply_op(run, *args)

@@ -1,4 +1,15 @@
-"""ERNIE-style Mixture-of-Experts LM — the EP (expert-parallel) baseline.
+"""ERNIE-style Mixture-of-Experts LM — a capacity-routed TOY, the EP
+(expert-parallel) training baseline. Not a servable block.
+
+What it is: a LayerNorm / GELU / learned-positions decoder where every
+`moe_every`-th layer's FFN is a GShard top-2-of-8 ``MoELayer`` that DROPS
+tokens past ``capacity_factor`` (1.25) and whose experts are biased GELU
+MLPs; training only, no cache path. It exercises the dispatch/combine
+einsums under an "ep" mesh axis and nothing a deployed sparse model needs.
+The dropless top-k SwiGLU expert layer (told which experts it holds, exact
+for any routing, inside the serving engine's two programs) is
+``incubate.distributed.models.moe.DroplessMoE``; ``models/deepseek_v3.py``
+is the servable model built on it (ROADMAP D5).
 
 Reference parity: ERNIE-MoE trained through
 paddle.incubate.distributed.models.moe.MoELayer with the expert comm group
@@ -6,11 +17,10 @@ from HybridCommunicateGroup (reference: python/paddle/incubate/distributed/
 models/moe/moe_layer.py — verify); the model itself lives in the ERNIE
 ecosystem repo, SURVEY §1 requires an in-repo equivalent.
 
-TPU-native design: transformer decoder where every `moe_every`-th layer's
-FFN is a GShard top-2 MoELayer whose stacked expert weights carry a
-partition spec over the "ep" mesh axis — the dispatch/combine einsums
-lower to exactly the all-to-all the reference's global_scatter /
-global_gather ops implement by hand (SURVEY §2.3 EP row)."""
+TPU-native design: the stacked expert weights carry a partition spec over
+the "ep" mesh axis — the dispatch/combine einsums lower to exactly the
+all-to-all the reference's global_scatter / global_gather ops implement by
+hand (SURVEY §2.3 EP row)."""
 from __future__ import annotations
 
 from dataclasses import dataclass

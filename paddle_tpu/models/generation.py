@@ -231,6 +231,50 @@ def cached_attention(qv, kv_, vv, ckv, cvv, posv, *, scale, cos=None,
     return out, ck, cv
 
 
+def latent_cached_attention(q_lat, latent, arena, posv, block_table, *,
+                            scale, rank):
+    """The latent-cache (MLA) step, beside :func:`cached_attention`: the
+    cache keeps ONE row a token a layer, ``[c_kv (rank) | k_pe]``, shared
+    by every head, and every read is the absorbed form.
+
+    ``q_lat (b, s, h, rank + rope)``: per head ``[q_nope W_kvb,k^T |
+    q_pe]`` (RoPE applied); ``latent (b, s, rank + rope)``: ``[c_kv
+    after its norm | k_pe after RoPE]``; ``arena (num_blocks, block_size,
+    w)`` with ``w`` the row padded to whole 128-lane tiles (the pad
+    columns hold zeros); ``posv (b,)`` per-row write offsets;
+    ``block_table (b, max_blocks)``. The write scatters the rows through
+    the table (positions past its width land in the trash block 0, as in
+    ``cached_attention``). The s = 1 read walks the slot's live pages in
+    the Pallas kernel where the arena tiles (TPU); every other read (a
+    prefill chunk, the CPU lane) gathers the table. Returns ``(o_latent
+    (b, s, h, rank), arena)``: the caller expands through ``W_kvb,v``."""
+    from ..ops.pallas import paged_attention as _pa
+    b, s, h, _ = q_lat.shape
+    w = arena.shape[-1]
+    posv = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (b,))
+    bs_blk, mb = arena.shape[1], block_table.shape[1]
+    tpos = posv[:, None] + jnp.arange(s)[None, :]            # (b, s)
+    blk_idx = tpos // bs_blk
+    oob = blk_idx >= mb
+    blk = jnp.where(oob, 0, jnp.take_along_axis(
+        block_table, jnp.clip(blk_idx, 0, mb - 1), axis=1))
+    off = jnp.where(oob, 0, tpos % bs_blk)
+
+    def widen(x):
+        return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, w - x.shape[-1])])
+
+    arena = arena.at[blk, off].set(widen(latent).astype(arena.dtype))
+    q_lat = widen(q_lat)
+    if s == 1 and _pa._kernel_ok(arena):
+        out = _pa.mla_paged_attention_decode(
+            q_lat[:, 0], arena, block_table, posv + 1, scale=scale,
+            rank=rank)[:, None]
+    else:
+        out = _pa.mla_paged_attention_reference(
+            q_lat, arena, block_table, posv + s, scale=scale, rank=rank)
+    return out, arena
+
+
 def forward_accepts_pad(cls) -> bool:
     """Whether ``cls.forward`` takes per-row ``pad`` counts (ragged /
     slot-pool decode). The inspect.signature probe is cached per class —

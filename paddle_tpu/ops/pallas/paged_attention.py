@@ -70,6 +70,7 @@ import numpy as np
 from . import fused as _fused
 
 __all__ = ["paged_attention_decode", "paged_attention_decode_int8",
+           "mla_paged_attention_decode", "mla_paged_attention_reference",
            "paged_attention_reference", "paged_attention_int8_reference",
            "paged_gather", "quantize_kv", "dequantize_kv",
            "kv_int8_error_bound", "walk_counts"]
@@ -223,7 +224,9 @@ def _walk_vmem_bytes(ppc, pages):
 def _page_view(arena_shape):
     """An arena's shape as the kernel views it, a page the matrix it is
     in memory: ``(nb, bs * kvh, d)`` for K and V, one ``(1, bs * kvh)``
-    lane row a page for a scale arena ``(nb, bs, kvh)``."""
+    lane row a page for a scale arena ``(nb, bs, kvh)``. (A latent arena
+    ``(nb, bs, w)`` is that view already: :func:`mla_paged_attention_decode`
+    passes it as it is.)"""
     nb, bs, kvh = arena_shape[:3]
     return (nb, bs * kvh) + tuple(arena_shape[3:]) \
         if len(arena_shape) == 4 else (nb, 1, bs * kvh)
@@ -256,7 +259,7 @@ def walk_counts(lengths, mb, bs, ppc=1):
 
 
 def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
-                 scale, bs, kvh, mb, ppc):
+                 v_streams, scale, bs, kvh, mb, ppc):
     """ONE walk shared by the bf16/fp32 and the int8 kernels: a grid
     step is a slot; its body loops over the slot's live pages, ``ppc``
     to a chunk, copying each live page from the HBM arenas into one of
@@ -265,7 +268,11 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
     read from the scalar-prefetched ``lengths``, so one program serves
     every length. The kernels differ ONLY in ``to_chunk``: how a
     buffered chunk becomes the ``(ppc * bs * kvh, d)`` K and V matrices
-    (and, for int8, the per-row scales that go with them).
+    (and, for int8, the per-row scales that go with them), and in
+    ``v_streams``, the streams whose buffers V is read from. V may be
+    narrower than K: it is then K's chunk cut to the output's width (the
+    latent arena, whose row is ``[c_kv | k_pe]`` and whose value is
+    ``c_kv``).
 
     A chunk is used whole, as ``(rows, d)`` with row ``t * kvh + k``
     holding token t of kv head k — the arena page as it lies in memory,
@@ -282,7 +289,8 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
     sems, parity = refs[2 * n_streams + 1:]
     i = pl.program_id(0)
     n_slots = pl.num_programs(0)
-    h, d = q_ref.shape[1:]
+    h = q_ref.shape[1]
+    dv = o_ref.shape[2]
     rows = ppc * bs * kvh
 
     def pages_of(slot):
@@ -315,8 +323,9 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
                                 lambda cp: cp.start())
 
         def clear(p, carry):
-            for b_ in bufs[1::2]:
-                b_[buf, p] = jnp.zeros(b_.shape[2:], b_.dtype)
+            for vs in v_streams:
+                bufs[vs][buf, p] = jnp.zeros(bufs[vs].shape[2:],
+                                             bufs[vs].dtype)
             return carry
 
         jax.lax.fori_loop(n_live, ppc, clear, 0)
@@ -352,6 +361,7 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
 
         each_live_page(i, n_pages, c, buf, lambda cp: cp.wait())
         k, v, k_scale, v_scale = to_chunk(bufs, buf, q.dtype)
+        v = v[:, :dv]
         s = jax.lax.dot_general(
             q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)
@@ -372,7 +382,7 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
     m, l, acc = jax.lax.fori_loop(
         0, n_chunks, fold,
         (jnp.full((h, 1), _NEG, jnp.float32),
-         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, d), jnp.float32)))
+         jnp.zeros((h, 1), jnp.float32), jnp.zeros((h, dv), jnp.float32)))
     o_ref[0] = (acc / l).astype(o_ref.dtype)
     parity[0] = (base + n_chunks) % 2
 
@@ -407,14 +417,31 @@ def _chunk_dequantized(bufs, buf, q_dtype):
             steps(bufs[2]), steps(bufs[3]))
 
 
+def _chunk_latent(bufs, buf, q_dtype):
+    """The latent arena: ONE stream, a row ``[c_kv | k_pe | pad]`` shared
+    by every query head. K is the whole row; V is its first ``rank``
+    columns, which the walk cuts to the output's width."""
+    k = bufs[0][buf]
+    k = k.reshape(-1, k.shape[-1]).astype(
+        jnp.promote_types(k.dtype, q_dtype))
+    return k, k, None, None
+
+
 def _tiles(arena_shape, dtype) -> bool:
     """Shapes the walk's chunks can take on the chip. The kernel reads
     a page as the ``(bs * kvh, d)`` matrix it is in memory: that view is
     free (a bitcast) when the ``(kvh, d)`` planes are whole tiles — a
     head_dim of whole 128-lane rows, kv heads in whole groups of 8
     sublanes — and an int8 arena's scale page must be whole 128-lane
-    rows as well. Anything else would be re-laid out, arena-wide, on
-    every call. Interpret mode takes any shape."""
+    rows as well. A latent arena ``(nb, bs, w)`` is its own page view:
+    it tiles when a page is whole tiles, ``w`` in 128-lane rows and the
+    block a whole number of sublane groups (16 rows of bf16). Anything
+    else would be re-laid out, arena-wide, on every call. Interpret mode
+    takes any shape."""
+    if len(arena_shape) == 3:
+        _, bs, w = arena_shape
+        return (w % 128 == 0
+                and bs % (8 * (4 // jnp.dtype(dtype).itemsize)) == 0)
     _, bs, kvh, d = arena_shape
     return (d % 128 == 0 and kvh % 8 == 0
             and (jnp.dtype(dtype) != jnp.int8 or (bs * kvh) % 128 == 0))
@@ -447,17 +474,29 @@ def _walk_call(name, to_chunk, q, arenas, block_table, lengths, scale):
     arenas), each viewed page by page (:func:`_page_view`)."""
     kvh = arenas[0].shape[2]
     arenas = tuple(a.reshape(_page_view(a.shape)) for a in arenas)
+    return _walk_pages(name, to_chunk, q, arenas, block_table, lengths,
+                       scale, kvh=kvh,
+                       v_streams=tuple(range(1, len(arenas), 2)),
+                       dv=q.shape[-1])
+
+
+def _walk_pages(name, to_chunk, q, arenas, block_table, lengths, scale, *,
+                kvh, v_streams, dv):
+    """The walk over arenas already in their page view; P from their
+    shapes."""
     ppc = _pages_per_chunk(block_table.shape[1],
                            [(a.shape[1:], a.dtype) for a in arenas])
     return _walk_pallas_call(q, arenas, block_table, lengths, name=name,
-                             to_chunk=to_chunk, scale=scale, kvh=kvh,
-                             ppc=ppc, interpret=_fused._FORCE_INTERPRET)
+                             to_chunk=to_chunk, v_streams=v_streams, dv=dv,
+                             scale=scale, kvh=kvh, ppc=ppc,
+                             interpret=_fused._FORCE_INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "name", "to_chunk", "scale", "kvh", "ppc", "interpret"))
+    "name", "to_chunk", "v_streams", "dv", "scale", "kvh", "ppc",
+    "interpret"))
 def _walk_pallas_call(q, arenas, block_table, lengths, *, name, to_chunk,
-                      scale, kvh, ppc, interpret):
+                      v_streams, dv, scale, kvh, ppc, interpret):
     """The ``pallas_call``: the arenas stay in HBM, the table and the
     lengths are scalar-prefetched, q and the output are pipelined per
     slot. The grid is sequential: the chunk buffers, their parity and
@@ -479,16 +518,17 @@ def _walk_pallas_call(q, arenas, block_table, lengths, *, name, to_chunk,
         grid=(b,),
         in_specs=[pl.BlockSpec((1, h, d), lambda i, tbl, lens: (i, 0, 0))]
         + [pl.BlockSpec(memory_space=pltpu.HBM)] * n,
-        out_specs=pl.BlockSpec((1, h, d), lambda i, tbl, lens: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dv), lambda i, tbl, lens: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2, ppc) + a.shape[1:], a.dtype)
                         for a in arenas]
         + [pltpu.SemaphoreType.DMA((n, 2)), pltpu.SMEM((1,), jnp.int32)],
     )
     return pl.pallas_call(
         functools.partial(_walk_kernel, n_streams=n, to_chunk=to_chunk,
-                          scale=scale, bs=bs, kvh=kvh, mb=mb, ppc=ppc),
+                          v_streams=v_streams, scale=scale, bs=bs, kvh=kvh,
+                          mb=mb, ppc=ppc),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
@@ -505,6 +545,40 @@ def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
     table row."""
     return _walk_call("paged_attention_decode", _chunk_as_stored, q,
                       (k_arena, v_arena), block_table, lengths, scale)
+
+
+def mla_paged_attention_reference(q, arena, block_table, lengths, *,
+                                  scale, rank):
+    """Gathered path of the latent read, any ``s``: the table gathered
+    into timeline order, every head's ``q (b, s, h, w)`` against the one
+    shared row (``w`` the arena's padded width), causal by ``lengths``
+    (row i of the ``s`` ends at ``lengths - s + i``), fp32 softmax, the
+    value the row's first ``rank`` columns. Returns ``(b, s, h, rank)``."""
+    b, s = q.shape[:2]
+    lat = arena[block_table]                       # (b, mb, bs, w)
+    lat = lat.reshape(b, -1, lat.shape[-1])
+    scores = jnp.einsum("bshw,btw->bhst", q.astype(jnp.float32),
+                        lat.astype(jnp.float32)) * scale
+    q_idx = (lengths - s)[:, None] + jnp.arange(s)[None, :]      # (b, s)
+    mask = jnp.arange(lat.shape[1])[None, None, :] <= q_idx[:, :, None]
+    scores = jnp.where(mask[:, None], scores, jnp.float32(_NEG))
+    probs = jax.nn.softmax(scores, axis=-1).astype(lat.dtype)
+    out = jnp.einsum("bhst,btr->bshr", probs, lat[..., :rank])
+    return out.astype(q.dtype)
+
+
+def mla_paged_attention_decode(q, arena, block_table, lengths, *, scale,
+                               rank):
+    """One decode step of latent (MLA, absorbed) attention: ``q (b, h,
+    w)`` holds per head ``[q_nope W_kvb,k^T | q_pe | 0]``, the arena
+    ``(nb, bs, w)`` one row ``[c_kv | k_pe | 0]`` a token, shared by all
+    heads. The same walk as :func:`paged_attention_decode` over ONE
+    stream: K is the whole row, V its first ``rank`` columns. Returns
+    ``o_latent (b, h, rank)``; the caller expands it through
+    ``W_kvb,v``."""
+    return _walk_pages("mla_paged_attention_decode", _chunk_latent, q,
+                       (arena,), block_table, lengths, scale, kvh=1,
+                       v_streams=(0,), dv=rank)
 
 
 def _int8_decode_fallback(q, k_codes, v_codes, k_scales, v_scales,

@@ -671,6 +671,7 @@ class ContinuousBatchingEngine:
         self._remaining_host = np.zeros((self.num_slots,), np.int64)
         self._finished: List[_SlotRun] = []
         self._pending_block = None     # dispatched, not yet harvested
+        self._block_span = None        # its ``serving.decode_block`` span
         self._bytes_step = None        # decode_bytes_per_step memo
         self.steps = 0                # engine decode steps executed
         self.tokens_emitted = 0       # useful tokens (incl. prefill's)
@@ -1032,7 +1033,7 @@ class ContinuousBatchingEngine:
                 self._poison_live_slot()
             faults.fault_point("serving.step_block")
             with _span("serving.decode_block",
-                       **self._decode_block_counters()):
+                       **self._decode_block_counters()) as self._block_span:
                 out = self.backend.decode_block(self._cache, self._state)
             self._cache, self._state = out[0], out[1]
             # old AOT artifacts predate the ok flags: pad with None
@@ -1050,15 +1051,24 @@ class ContinuousBatchingEngine:
             lives_np = np.asarray(lives)            # (block, S)
             oks_np = None if oks is None else np.asarray(oks)
             rem_np = np.asarray(self._state["remaining"])
+            counts_np = self._read_program_counters()
         self._pending_block = None
         with _span("serving.harvest"):
             self._credit_block(toks_np, lives_np, oks_np, rem_np)
+            if counts_np is not None:
+                self._credit_program_counters(counts_np)
 
     def _decode_block_counters(self) -> dict:
         """Counters the ``serving.decode_block`` span carries besides the
         engine's own (``steps``, ``slot_steps``): none on the dense
         engine; the paged engine counts its kernel's page walk here."""
         return {}
+
+    def _read_program_counters(self):
+        """What the model's programs counted into the cache (the paged
+        engine of a model that declares ``cache_counters``), fetched with
+        the block's other transfers; None where nothing is counted."""
+        return None
 
     def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
         """The host half of a decode block: credit each live slot its

@@ -440,6 +440,9 @@ class PagedModelStepBackend(ModelStepBackend):
         tree_holder["tree"] = tree
         self.pool_specs = tuple((c._value.shape, c._value.dtype)
                                 for c in flat)
+        # a model whose programs count (routed picks, experts hit) keeps
+        # the counts in the cache's LAST leaf and names them here
+        self.cache_counters = dict(getattr(model, "cache_counters", {}))
         self._pv = [p._value for _, p in model.named_parameters()]
         self._bv = [b._value for _, b in model.named_buffers()]
         # weight-only quant BEFORE the decode-block and chunk programs
@@ -709,6 +712,19 @@ class PagedEngine(ContinuousBatchingEngine):
         self.kv_pages_live = 0         # pages holding a slot's live KV
         self.kv_pages_copied = 0       # pages the kernel copied for them
         self._pos_host = np.zeros((self.num_slots,), np.int64)
+        # what the model's programs count into the cache's last leaf
+        # (``cache_counters``: name -> "sum" | "max"; a (2, n) int32 array,
+        # decode steps apart from prefill chunks): the decode row becomes
+        # an engine counter of that name, the chunk row ``prefill_<name>``
+        self._counter_names = tuple(
+            getattr(self.backend, "cache_counters", {}).items())
+        self._counts_seen = np.zeros((2, len(self._counter_names)),
+                                     np.int64)
+        for name, _ in self._counter_names:
+            setattr(self, name, 0)
+            setattr(self, "prefill_" + name, 0)
+        self._chunk_span = None        # the latest ``serving.prefill_chunk``
+        self._chunks_unread = 0        # chunks since the counters were read
 
     # -- introspection -----------------------------------------------------
     def prefix_cache_hit_rate(self) -> float:
@@ -865,7 +881,8 @@ class PagedEngine(ContinuousBatchingEngine):
             tr = self.tracer
             t_chunk = _trace_now() if tr is not None else 0.0
             with _span("serving.prefill_chunk",
-                       rid=job.run.request.request_id, tokens=n):
+                       rid=job.run.request.request_id,
+                       tokens=n) as self._chunk_span:
                 tok0_dev, self._cache = self.backend.prefill_chunk(
                     jnp.asarray(ids), self._cache,
                     jnp.asarray(job.table_row[None]),
@@ -875,6 +892,7 @@ class PagedEngine(ContinuousBatchingEngine):
             job.done += n
             spent += n
             self.prefill_chunks += 1
+            self._chunks_unread += 1
             self.prefilled_tokens += n
             _M_PREFILLS.inc()
             if tr is not None:
@@ -959,6 +977,35 @@ class PagedEngine(ContinuousBatchingEngine):
     def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
         self._pos_host += lives_np.sum(axis=0)      # pos += live, per step
         super()._credit_block(toks_np, lives_np, oks_np, rem_np)
+
+    def _read_program_counters(self):
+        return np.asarray(self._cache[-1]) if self._counter_names else None
+
+    def _credit_program_counters(self, counts_np):
+        """Fold the programs' counts into the engine's counters and onto
+        the spans of what ran: the decode row onto this block's
+        ``serving.decode_block``, the chunk row — every chunk since the
+        last read — onto the latest ``serving.prefill_chunk`` (with
+        ``chunks``, how many it speaks for). Sums are differences of
+        wrapping int32 totals; a maximum is the largest so far."""
+        spans = (("", self._block_span, {}),
+                 ("prefill_", self._chunk_span if self._chunks_unread
+                  else None, {"chunks": self._chunks_unread}))
+        now = counts_np.astype(np.int64)
+        for row, (prefix, span_, ids) in enumerate(spans):
+            for col, (name, fold) in enumerate(self._counter_names):
+                v = int(now[row, col])
+                if fold == "sum":
+                    v = (v - int(self._counts_seen[row, col])) % 2 ** 32
+                    setattr(self, prefix + name,
+                            getattr(self, prefix + name) + v)
+                else:
+                    setattr(self, prefix + name, v)
+                ids[name] = v
+            if span_ is not None:
+                span_.ids.update(ids)
+        self._counts_seen = now
+        self._chunks_unread = 0
 
     def _retire(self, slot, run, now):
         super()._retire(slot, run, now)

@@ -145,6 +145,25 @@ def _case_paged_int8(one_chip, shape="llama7b"):
         one_chip, q, arena, arena, scales, scales, table, lens))
 
 
+def _case_latent_decode(one_chip):
+    """The latent (MLA, absorbed) decode read at the shape the benchmark's
+    kanana2-docqa-decode cell serves: 64 slots, 32 heads against ONE
+    shared row a token (512 + 64 values, stored 640 wide), a 320-entry
+    table, 16,385 blocks; the value is the row's first 512 columns."""
+    arena = ((16385, KV_BLOCK, 640), BF16)
+    pages = [(arena[0][1:], BF16)]
+    assert pa._tiles(*arena)
+    assert pa._walk_vmem_bytes(pa._pages_per_chunk(320, pages), pages) \
+        <= pa._VMEM_BUDGET
+    text = _arenas_are_viewed_not_copied(_compile(
+        functools.partial(pa.mla_paged_attention_decode,
+                          scale=192 ** -0.5, rank=512),
+        one_chip, ((64, HEADS, 640), BF16), arena, ((64, 320), jnp.int32),
+        ((64,), jnp.int32)))
+    assert "%mla_paged_attention_decode" in text
+    return text
+
+
 _QKV = ((2, 2048, HEADS, HEAD_DIM), BF16)
 
 
@@ -212,6 +231,7 @@ CASES = {
         functools.partial(_case_paged_bf16, shape="served"),
     "paged_decode_int8_served_shape":
         functools.partial(_case_paged_int8, shape="served"),
+    "latent_decode_served_shape": _case_latent_decode,
     "sdpa_jax_flash_fwd_bwd": _case_sdpa_train,
     "flash_attention_fused_fwd": _case_flash_fused,
     "rms_norm": _case_rms,

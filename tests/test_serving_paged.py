@@ -467,9 +467,10 @@ class TestPagedKernel:
                                                             monkeypatch):
         """On a TPU the kernel reads a page as a (bs * kvh, d) matrix: a
         head_dim that is not whole 128-lane rows, kv heads that do not
-        fill 8 sublanes, or an int8 scale page that is not whole lane
-        rows would be re-laid out arena-wide on every call, so they route
-        to the gathered reference (int8: the per-block scan) instead."""
+        fill 8 sublanes, an int8 scale page that is not whole lane rows,
+        or a latent row that is not whole lane tiles would be re-laid out
+        arena-wide on every call, so they route to the gathered reference
+        (int8: the per-block scan) instead."""
         import paddle_tpu.ops.pallas.fused as fused
         from paddle_tpu.ops.pallas import paged_attention as pa
         monkeypatch.setattr(fused, "_on_tpu", lambda: True)
@@ -480,6 +481,11 @@ class TestPagedKernel:
         assert not ok((64, 16, 8, 64))                 # head_dim 64
         assert not ok((64, 16, 4, 128))                # 4 kv heads
         assert not ok((64, 16, 8, 128), jnp.float16)   # dtype, as before
+        # a latent arena (nb, bs, w): one row a token for all heads, its
+        # page the (bs, w) matrix it is in memory
+        assert ok((16385, 16, 640))                    # the served shape
+        assert not ok((64, 16, 576))                   # 4.5 lane tiles
+        assert not ok((64, 8, 640))                    # half a bf16 group
         ok8 = lambda shape: pa._kernel_ok_int8(        # noqa: E731
             jax.ShapeDtypeStruct(shape, jnp.int8))
         assert ok8((5121, 16, 8, 128))

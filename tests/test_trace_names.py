@@ -65,6 +65,12 @@ def _paged():
                         i32(2, 3), i32(2))
 
 
+def _latent():
+    return jax.make_jaxpr(lambda *a: pa.mla_paged_attention_decode(
+        *a, scale=0.1, rank=96))(f32(2, 4, 128), f32(5, 8, 128), i32(2, 3),
+                                 i32(2))
+
+
 def _paged_int8():
     codes = jnp.zeros((5, 8, 2, 32), jnp.int8)
     return jax.make_jaxpr(lambda *a: pa.paged_attention_decode_int8(
@@ -94,6 +100,7 @@ KERNELS = {
     "paged_attention_decode": (_paged, ["paged_attention_decode"]),
     "paged_attention_decode_int8":
         (_paged_int8, ["paged_attention_decode_int8"]),
+    "mla_paged_attention_decode": (_latent, ["mla_paged_attention_decode"]),
     "fused_rms_norm": (lambda: jax.make_jaxpr(
         lambda x, w: fused.fused_rms_norm(x, w, 1e-5))(
             f32(4, 128), f32(128)), ["fused_rms_norm"]),
@@ -189,6 +196,42 @@ def test_decode_and_prefill_programs_are_named_on_purpose(tiny):
     be = _paged_backend(tiny)
     assert _module_name(_lower_block(be)) == engine.DECODE_PROGRAM
     assert _module_name(_lower_chunk(be)) == engine.PREFILL_CHUNK_PROGRAM
+
+
+@pytest.fixture(scope="module")
+def tiny_latent_moe():
+    from paddle_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM,
+                                               deepseek_v3_tiny_config)
+    paddle.seed(0)
+    return DeepseekV3ForCausalLM(deepseek_v3_tiny_config())
+
+
+def test_latent_moe_model_keeps_the_program_names_and_names_its_parts(
+        tiny_latent_moe, interpret):
+    """A model of another class runs the SAME two programs (the benchmark
+    reads ``jit_block_fn`` / ``jit_chunk_fn`` whatever the model), its
+    s = 1 read is the named latent kernel, and its layer parts carry the
+    scopes a trace is read by."""
+    from paddle_tpu.serving import engine
+    be = _paged_backend(tiny_latent_moe)
+    block, chunk = _lower_block(be), _lower_chunk(be)
+    assert _module_name(block) == engine.DECODE_PROGRAM
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM
+    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
+    names = pallas_names(jax.make_jaxpr(be._block_jit)(
+        be._pv, be._bv, cache, be.init_state()))
+    assert "mla_paged_attention_decode" in names
+    assert "paged_attention_decode" not in names
+    text = block.as_text(debug_info=True)
+    for scope in ("attn", "mlp", "moe_router", "moe_experts", "moe_shared",
+                  "lm_head", "sample"):
+        assert f"{scope}/" in text, scope
+    # the s > 1 read gathers: no latent kernel in the chunk program
+    assert "mla_paged_attention_decode" not in pallas_names(
+        jax.make_jaxpr(be._chunk_jit)(
+            be._pv, be._bv, i32(1, 8), cache, i32(1, be.max_blocks),
+            jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
+            jnp.float32(0), jnp.int32(0), jnp.float32(1)))
 
 
 def test_train_step_program_is_named_on_purpose(tiny):
