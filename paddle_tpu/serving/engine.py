@@ -68,40 +68,58 @@ __all__ = ["ContinuousBatchingEngine", "ModelStepBackend",
            "build_paged_chunk_fn"]
 
 
-def slot_sample_logits(logits, keys, temperature, top_k, top_p):
+def slot_sample_logits(logits, keys, temperature, top_k, top_p, live=None):
     """Per-slot sampling over (S, V) logits (or log-probs — per-row
     shifts cancel in every branch): ``temperature``/``top_k``/``top_p``
     are (S,) arrays so one compiled program serves mixed greedy/sampled
     traffic. Greedy rows (temperature <= 0) take argmax; sampled rows
     share ONE descending sort for both the top-k threshold and the
-    top-p cutoff, then draw categorically with per-row keys."""
+    top-p cutoff, then draw categorically with per-row keys.
+
+    The sampled path (scaling, sort, softmax, cumsum, the two filters,
+    the draw) sits under a ``lax.cond`` on the program's own input: it
+    runs only in a call where some row that counts has ``temperature >
+    0``; otherwise the argmax is the whole answer. ``live`` is an
+    optional (S,) mask of the rows whose pick is used (default: all) —
+    a retired slot that still holds a sampled request's temperature
+    does not bring the sort back. Tokens are those of the unconditional
+    form bit for bit: a greedy row's pick is the same argmax of the
+    same fp32 values, a sampled row runs the same operations with the
+    same key (keys are split by the caller whether or not the branch
+    runs). A ``vmap`` over the predicate would turn the ``cond`` into a
+    ``select`` that runs both sides: keep this call un-vmapped."""
     S, V = logits.shape
     logits = logits.astype(jnp.float32)
     greedy = temperature <= 0.0
     greedy_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    t = jnp.where(greedy, jnp.float32(1.0),
-                  temperature.astype(jnp.float32))
-    scaled = logits / t[:, None]
-    sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
-    k = jnp.clip(top_k.astype(jnp.int32), 0, V)
-    use_k = (k > 0) & (k < V)
-    kth = jnp.take_along_axis(sorted_desc,
-                              jnp.maximum(k - 1, 0)[:, None], axis=-1)
-    kth = jnp.where(use_k[:, None], kth, -jnp.inf)
-    filt = jnp.where(scaled < kth, -jnp.inf, scaled)
-    # masking below-kth values inside the sorted array == re-sorting the
-    # filtered row (kept prefix unchanged, dropped tail -> -inf)
-    sorted_f = jnp.where(sorted_desc < kth, -jnp.inf, sorted_desc)
-    probs = jax.nn.softmax(sorted_f, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    cutoff_idx = jnp.clip(
-        jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True), 0, V - 1)
-    cutoff = jnp.take_along_axis(sorted_f, cutoff_idx, axis=-1)
-    cutoff = jnp.where((top_p < 1.0)[:, None], cutoff, -jnp.inf)
-    filt = jnp.where(filt < cutoff, -jnp.inf, filt)
-    sampled = jax.vmap(
-        lambda kk, row: jax.random.categorical(kk, row))(keys, filt)
-    return jnp.where(greedy, greedy_tok, sampled.astype(jnp.int32))
+
+    def sampled_path():
+        t = jnp.where(greedy, jnp.float32(1.0),
+                      temperature.astype(jnp.float32))
+        scaled = logits / t[:, None]
+        sorted_desc = jnp.sort(scaled, axis=-1)[:, ::-1]
+        k = jnp.clip(top_k.astype(jnp.int32), 0, V)
+        use_k = (k > 0) & (k < V)
+        kth = jnp.take_along_axis(sorted_desc,
+                                  jnp.maximum(k - 1, 0)[:, None], axis=-1)
+        kth = jnp.where(use_k[:, None], kth, -jnp.inf)
+        filt = jnp.where(scaled < kth, -jnp.inf, scaled)
+        # masking below-kth values inside the sorted array == re-sorting
+        # the filtered row (kept prefix unchanged, dropped tail -> -inf)
+        sorted_f = jnp.where(sorted_desc < kth, -jnp.inf, sorted_desc)
+        probs = jax.nn.softmax(sorted_f, axis=-1)
+        cum = jnp.cumsum(probs, axis=-1)
+        cutoff_idx = jnp.clip(
+            jnp.sum(cum < top_p[:, None], axis=-1, keepdims=True), 0, V - 1)
+        cutoff = jnp.take_along_axis(sorted_f, cutoff_idx, axis=-1)
+        cutoff = jnp.where((top_p < 1.0)[:, None], cutoff, -jnp.inf)
+        filt = jnp.where(filt < cutoff, -jnp.inf, filt)
+        sampled = jax.vmap(
+            lambda kk, row: jax.random.categorical(kk, row))(keys, filt)
+        return jnp.where(greedy, greedy_tok, sampled.astype(jnp.int32))
+
+    samples = ~greedy if live is None else ~greedy & live
+    return jax.lax.cond(jnp.any(samples), sampled_path, lambda: greedy_tok)
 
 
 # The compiled programs' names as a device trace shows them ("XLA
@@ -174,7 +192,8 @@ def build_slot_block_fn(pure, block: int, trace_counter=None,
             ok = ~jnp.any(jnp.isnan(logp), axis=-1)
             with jax.named_scope("sample"):
                 nxt = slot_sample_logits(logp, sub, st["temp"],
-                                         st["topk"], st["topp"])
+                                         st["topk"], st["topp"],
+                                         st["live"])
             live = st["live"]
             hit = live & (st["eos"] >= 0) & (nxt == st["eos"])
             rem = jnp.where(live, st["remaining"] - 1, st["remaining"])
@@ -674,6 +693,7 @@ class ContinuousBatchingEngine:
         self._block_span = None        # its ``serving.decode_block`` span
         self._bytes_step = None        # decode_bytes_per_step memo
         self.steps = 0                # engine decode steps executed
+        self.sampled_steps = 0        # of those, a live slot sampled
         self.tokens_emitted = 0       # useful tokens (incl. prefill's)
         self.decode_tokens = 0        # live-slot decode steps only
         self.slot_steps = 0           # S * steps (occupancy denominator)
@@ -1060,9 +1080,24 @@ class ContinuousBatchingEngine:
 
     def _decode_block_counters(self) -> dict:
         """Counters the ``serving.decode_block`` span carries besides the
-        engine's own (``steps``, ``slot_steps``): none on the dense
-        engine; the paged engine counts its kernel's page walk here."""
-        return {}
+        engine's own (``steps``, ``slot_steps``): ``sampled_steps``; the
+        paged engine adds its kernel's page walk."""
+        return {"sampled_steps":
+                self._count_sampled_steps(self.decode_block)}
+
+    def _count_sampled_steps(self, steps: int) -> int:
+        """Of the ``steps`` decode steps about to be dispatched, those in
+        which ``slot_sample_logits`` takes its sampled branch: a live
+        slot's request has ``temperature > 0``. From the host's mirrors
+        (each slot's request, ``_remaining_host``), no device fetch; a
+        slot stays live ``min(remaining, steps)`` steps, so one that
+        meets its EOS inside the block is counted to the block's end."""
+        n = max((min(int(self._remaining_host[slot]), steps)
+                 for slot, run in enumerate(self._slots)
+                 if run is not None and slot not in self._prefill_slots
+                 and run.request.temperature > 0), default=0)
+        self.sampled_steps += n
+        return n
 
     def _read_program_counters(self):
         """What the model's programs counted into the cache (the paged
@@ -1219,6 +1254,7 @@ class ContinuousBatchingEngine:
             "prefill_slots": sorted(self._prefill_slots),
             "slots": slots_meta, "finished": fin_meta,
             "counters": {"steps": self.steps,
+                         "sampled_steps": self.sampled_steps,
                          "tokens_emitted": self.tokens_emitted,
                          "decode_tokens": self.decode_tokens,
                          "slot_steps": self.slot_steps},
@@ -1271,6 +1307,7 @@ class ContinuousBatchingEngine:
         self._remaining_host = np.asarray(meta["remaining"], np.int64)
         c = meta["counters"]
         self.steps = c["steps"]
+        self.sampled_steps = c.get("sampled_steps", 0)
         self.tokens_emitted = c["tokens_emitted"]
         self.decode_tokens = c["decode_tokens"]
         self.slot_steps = c["slot_steps"]

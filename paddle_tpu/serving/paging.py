@@ -972,7 +972,8 @@ class PagedEngine(ContinuousBatchingEngine):
             self.max_blocks, self.kv_block_size)
         self.kv_pages_live += live
         self.kv_pages_copied += copied
-        return {"kv_pages_live": live, "kv_pages_copied": copied}
+        return dict(super()._decode_block_counters(),
+                    kv_pages_live=live, kv_pages_copied=copied)
 
     def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
         self._pos_host += lives_np.sum(axis=0)      # pos += live, per step

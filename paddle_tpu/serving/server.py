@@ -669,6 +669,7 @@ class Server:
             "requests_completed": completed,
             "tokens_emitted": eng.tokens_emitted,
             "decode_steps": eng.steps,
+            "sampled_steps": eng.sampled_steps,
             "slot_occupancy": round(eng.occupancy(), 4),
             "wall_s": round(self._wall, 4),
             "tokens_per_sec": round(eng.tokens_emitted / self._wall, 1)

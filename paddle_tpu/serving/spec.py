@@ -248,7 +248,7 @@ def build_spec_block_fn(pure, k: int, trace_counter=None,
         # position 0 through the sampling path: greedy rows get the
         # identical argmax, sampled rows the identical key schedule
         first = slot_sample_logits(logp[:, 0], sub, st["temp"],
-                                   st["topk"], st["topp"])
+                                   st["topk"], st["topp"], st["live"])
         t = t.at[:, 0].set(first)
         # sampled rows never accept drafts (k=0 fallback in-graph even
         # if the host proposed some)
@@ -461,6 +461,7 @@ class _SpecEngineMixin:
             self._cache, self._state = out[0], out[1]
             self._pending_block = (out[2], out[3], out[4], n_draft)
             self.steps += 1
+            self._count_sampled_steps(1)
             self.verify_steps += 1
             proposed = int(n_draft.sum())
             self.draft_proposed += proposed

@@ -547,7 +547,8 @@ class TestKvWalkCounters:
         spans = [sp for sp in tracing.since(t0)
                  if sp.name == "serving.decode_block"]
         assert spans and want[0] > 0
-        assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied"}
+        assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied",
+                                     "sampled_steps"}
         assert [engine.kv_pages_live, engine.kv_pages_copied] == want
         assert [sum(sp.ids[k] for sp in spans)
                 for k in ("kv_pages_live", "kv_pages_copied")] == want

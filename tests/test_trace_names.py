@@ -247,21 +247,23 @@ def test_train_step_program_is_named_on_purpose(tiny):
 # -- named scopes are metadata only -----------------------------------------
 
 def test_decode_block_span_counter_names(tiny):
-    """The two counters the ``serving.decode_block`` span carries, by the
-    names PERF.md's kernels row gives them; the same two are attributes
-    of the paged engine beside ``steps`` / ``slot_steps`` (the span itself
-    is checked on a served stream in tests/test_serving_paged.py). A fresh
-    engine's slots are all dead at pos 0: one page a slot a step."""
+    """The counters the ``serving.decode_block`` span carries, by the
+    names PERF.md's rows give them; the same are attributes of the
+    engine beside ``steps`` / ``slot_steps`` (the span itself is checked
+    on a served stream in tests/test_serving_paged.py and
+    tests/test_slot_sampler.py). A fresh engine's slots are all dead at
+    pos 0: one page a slot a step, and nothing samples."""
     from paddle_tpu.serving import ContinuousBatchingEngine
     eng = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
                                    decode_block=4, paged=True,
                                    block_size=8, prefill_chunk=8)
     assert eng._decode_block_counters() \
-        == {"kv_pages_live": 8, "kv_pages_copied": 8}
-    assert (eng.kv_pages_live, eng.kv_pages_copied) == (8, 8)
+        == {"sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8}
+    assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied) \
+        == (0, 8, 8)
     dense = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
                                      decode_block=4)
-    assert dense._decode_block_counters() == {}
+    assert dense._decode_block_counters() == {"sampled_steps": 0}
 
 
 def _op_histogram(text: str) -> dict:
