@@ -137,7 +137,7 @@ class CompileMeter:
 def build_model(sizes: Sizes, layers: int, seed: int, dtype="bfloat16",
                 **cfg_kw):
     """A bf16 Llama at ``sizes.widths`` and ``layers`` deep, random weights
-    from ``seed`` (bench.py's pure-bf16 build)."""
+    from ``seed``."""
     import paddle_tpu as paddle
     from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
     cfg_kw.setdefault("tensor_parallel", False)
@@ -227,8 +227,7 @@ def build_engine(model, sizes: Sizes, tp=False):
         f"prefill chunk {engine.prefill_chunk_len} (source: explicit "
         f"argument), {engine.num_kv_blocks} arena blocks, kv_int8 "
         f"{engine.kv_int8}, weight quant {engine.backend.quant_cfg}, "
-        f"megakernel {engine.megakernel()}, tp degree "
-        f"{engine.tp_degree()}")
+        f"tp degree {engine.tp_degree()}")
     return engine
 
 
@@ -409,8 +408,8 @@ def make_token_set(sizes: Sizes, seed: int, n: int):
 def build_trainer(model):
     from paddle_tpu import optimizer
     from paddle_tpu.jit import TrainStep
-    # bf16 params AND bf16 Adam moments (multi_precision off): the memory
-    # setting bench.py trains its largest one-chip config in
+    # bf16 params AND bf16 Adam moments (multi_precision off): what
+    # fits the largest one-chip config in 16 GB
     opt = optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
                           parameters=model.parameters(),
                           multi_precision=False)

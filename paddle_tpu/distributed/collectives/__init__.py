@@ -27,7 +27,7 @@ enable via :func:`configure` or ``PT_COLLECTIVES_BUCKETED_SYNC=1``.
 Everything here is **in-graph**: the ``*_collective`` primitives run
 inside ``shard_map`` where mesh axis names are bound; the module-level
 ``all_reduce``/``all_gather``/``reduce_scatter`` wrap them over a mesh
-for host-level use (tests, microbench, eager loops). The eager
+for host-level use (tests, eager loops). The eager
 control-plane API in :mod:`..communication` is unchanged and unrelated.
 """
 from __future__ import annotations
@@ -46,7 +46,6 @@ __all__ = [
     "quantized_all_reduce", "int8_error_bound",
     "build_buckets", "BucketedGradSync", "bucketed_allreduce_gradients",
     "attach_grad_sync",
-    "run_comms_bench",
 ]
 
 
@@ -132,4 +131,3 @@ from .hierarchical import (HierarchyPlan, plan_hierarchy,          # noqa: E402
 from .quantized import quantized_all_reduce, int8_error_bound      # noqa: E402
 from .bucketing import (build_buckets, BucketedGradSync,           # noqa: E402
                         bucketed_allreduce_gradients, attach_grad_sync)
-from .microbench import run_comms_bench                            # noqa: E402

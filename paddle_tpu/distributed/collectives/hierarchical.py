@@ -21,7 +21,7 @@ These primitives are IN-GRAPH: call them inside ``shard_map`` where the
 axis names are bound. The module-level :func:`all_reduce` /
 :func:`all_gather` / :func:`reduce_scatter` wrappers at the bottom run
 them over a mesh from host level (stacked per-device contributions in,
-global result out) — the form the tests and the comms microbench use.
+global result out) — the form the tests use.
 """
 from __future__ import annotations
 
@@ -172,7 +172,7 @@ def hier_all_gather(x, plan: HierarchyPlan):
 
 
 # --------------------------------------------------------------------------
-# host-level wrappers (tests / microbench / eager loops)
+# host-level wrappers (tests / eager loops)
 # --------------------------------------------------------------------------
 
 def _unwrap(x):
@@ -231,8 +231,7 @@ def _note_metrics(op: str, plan: HierarchyPlan, v, int8: bool = False):
                labels=("op", "mode")).inc(op=op, mode=mode)
     om.counter("pt_collectives_bytes_total",
                "payload bytes handed to collectives (stacked "
-               "contributions; algorithmic wire bytes are the comms "
-               "microbench's job)",
+               "contributions, not algorithmic wire bytes)",
                labels=("op", "mode")).inc(v.nbytes, op=op, mode=mode)
     if int8:
         from .quantized import int8_error_bound
@@ -247,8 +246,7 @@ def _note_metrics(op: str, plan: HierarchyPlan, v, int8: bool = False):
 def _compiled(op: str, mesh: Mesh, plan: HierarchyPlan,
               bucket_size: Optional[int]):
     """Jitted shard_map program per (op, mesh, plan) — host-level
-    wrappers would otherwise re-trace on every call, which both costs
-    milliseconds and makes the microbench time tracing, not comms."""
+    wrappers would otherwise re-trace on every call."""
     if op == "all_reduce":
         inner = lambda xl: hier_all_reduce(        # noqa: E731
             jnp.squeeze(xl, 0), plan)

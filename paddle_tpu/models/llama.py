@@ -298,16 +298,18 @@ class LlamaDecoderLayer(nn.Layer):
 
     def forward(self, x, cos, sin, attn_mask=None, cache=None, pos=None,
                 pad=None, block_table=None):
-        if cache is not None:
-            from ..ops.pallas import decode_layer as _dl
-            if _dl.marking_active() and attn_mask is None \
-                    and self._markable(x, pos, pad, block_table):
-                return self._marked_decode(x, cos, sin, attn_mask,
-                                           cache, pos, pad, block_table)
-            return self._decode_forward(x, cos, sin, attn_mask, cache,
-                                        pos, pad, block_table)
         # named scopes are metadata: the trace names a layer's halves,
         # the compiled program is the same program (test-pinned)
+        if cache is not None:
+            with jax.named_scope("attn"):
+                a, new_cache = self.self_attn(self.input_layernorm(x), cos,
+                                              sin, attn_mask, cache=cache,
+                                              pos=pos, pad=pad,
+                                              block_table=block_table)
+                h = x + a
+            with jax.named_scope("mlp"):
+                out = h + self.mlp(self.post_attention_layernorm(h))
+            return out, new_cache
         with jax.named_scope("attn"):
             h = x + self.self_attn(self.input_layernorm(x), cos, sin,
                                    attn_mask)
@@ -317,100 +319,6 @@ class LlamaDecoderLayer(nn.Layer):
             from ..distributed.fleet.meta_parallel import _constrain
             out = _constrain(out, P(None, "sep", None))
         return out
-
-    # -- decode path (KV cache) --------------------------------------------
-    def _decode_forward(self, x, cos, sin, attn_mask, cache, pos, pad,
-                        block_table):
-        """The cache-path layer body — THE decode-layer math, whether
-        traced inline (default) or inside a marked region (megakernel
-        fusion)."""
-        with jax.named_scope("attn"):
-            a, new_cache = self.self_attn(self.input_layernorm(x), cos,
-                                          sin, attn_mask, cache=cache,
-                                          pos=pos, pad=pad,
-                                          block_table=block_table)
-            h = x + a
-        with jax.named_scope("mlp"):
-            out = h + self.mlp(self.post_attention_layernorm(h))
-        return out, new_cache
-
-    def _markable(self, x, pos, pad, block_table) -> bool:
-        """Whether this call is the slot-pool decode shape the megakernel
-        fusion covers: s == 1, per-row (vector) positions, no sliding
-        window, and (dense mode) per-row pad counts present."""
-        if int(x.shape[1]) != 1 or pos is None:
-            return False
-        if len(getattr(pos, "shape", ())) != 1:
-            return False
-        if self.self_attn.config.sliding_window is not None:
-            return False
-        return block_table is not None or pad is not None
-
-    def _decode_layer_weights(self):
-        """The marked call's weight tuple, in the documented
-        ops.pallas.decode_layer ARG_LAYOUT order."""
-        a, m = self.self_attn, self.mlp
-        return (self.input_layernorm.weight, a.q_proj.weight,
-                a.k_proj.weight, a.v_proj.weight, a.o_proj.weight,
-                self.post_attention_layernorm.weight, m.gate_proj.weight,
-                m.up_proj.weight, m.down_proj.weight)
-
-    def _marked_decode(self, x, cos, sin, attn_mask, cache, pos, pad,
-                       block_table):
-        """Run the SAME decode-layer math inside a ``jax.jit``-marked
-        region so the serving engine's fused trace sees ONE
-        ``pt_decode_layer_<mode>`` pjit equation per layer (anchor for
-        passes/fusion_decode.py). Values are identical to the inline
-        path by construction — the marked pure function swaps the
-        weight values in and replays :meth:`_decode_forward`."""
-        from .. import framework
-        from ..tensor import Tensor as _T
-        mode = ("dense" if block_table is None else
-                "paged_int8" if len(cache) == 4 else "paged")
-        wts = self._decode_layer_weights()
-        fns = self.__dict__.setdefault("_marked_decode_fns", {})
-        fn = fns.get(mode)
-        if fn is None:
-            n_cache = len(cache)
-            layer = self
-
-            def pure(xv, cos_v, sin_v, eps1, eps2, posv, aux, *rest):
-                # eps ride as Literal args for the fusion pass; the
-                # body keeps its own static epsilons (same values)
-                del eps1, eps2
-                cache_vals = rest[:n_cache]
-                wvals = rest[n_cache:]
-                tensors = layer._decode_layer_weights()
-                saved = [(t, t._value) for t in tensors]
-                try:
-                    for t, v in zip(tensors, wvals):
-                        t._value = v
-                    pad_t = _T(aux) if mode == "dense" else None
-                    bt = None if mode == "dense" else _T(aux)
-                    # attn_mask is None by the marking condition (the
-                    # cache path refuses one anyway)
-                    with framework.functional_mode():
-                        out, new_cache = layer._decode_forward(
-                            _T(xv), cos_v, sin_v, None,
-                            tuple(_T(c) for c in cache_vals),
-                            _T(posv), pad_t, bt)
-                    return (out._value,) + tuple(c._value
-                                                 for c in new_cache)
-                finally:
-                    for t, v in saved:
-                        t._value = v
-
-            pure.__name__ = f"pt_decode_layer_{mode}"
-            pure.__qualname__ = pure.__name__
-            fn = jax.jit(pure)
-            fns[mode] = fn
-        aux = pad if block_table is None else block_table
-        out = fn(x._value, cos, sin,
-                 float(self.input_layernorm.epsilon),
-                 float(self.post_attention_layernorm.epsilon),
-                 pos._value, aux._value,
-                 *[c._value for c in cache], *[w._value for w in wts])
-        return _T(out[0]), tuple(_T(c) for c in out[1:])
 
 
 class LlamaDecoderStack(nn.Layer):

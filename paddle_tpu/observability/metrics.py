@@ -370,8 +370,8 @@ def reset():
 counter("pt_collectives_calls_total", "host-level collective dispatches",
         labels=("op", "mode"))
 counter("pt_collectives_bytes_total",
-        "payload bytes handed to collectives (stacked contributions; "
-        "algorithmic wire bytes are the comms microbench's job)",
+        "payload bytes handed to collectives (stacked contributions, "
+        "not algorithmic wire bytes)",
         labels=("op", "mode"))
 gauge("pt_collectives_int8_error_bound",
       "worst-case |dequant - fp32| of the most recent int8 all-reduce "

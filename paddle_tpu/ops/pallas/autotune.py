@@ -20,16 +20,14 @@ facts, not code facts — this module gives them a measured home:
 - **Consumers** (all at trace time, fallback defaults documented in
   each): ``xent._best_chunk`` (chunk cap), ``flash_attention``
   (splash/flash block preferences, with the effective choice
-  attributable via :func:`last_block_choice`), the paged engine's
-  default ``block_size``, and the decode megakernel's MLP
-  ``ff_chunk``.
+  attributable via :func:`last_block_choice`) and the paged engine's
+  default ``block_size``.
 - **Sweeps** (:func:`run_autotune`): xent vocab-chunk and the paged
   arena block size measure real work on ANY backend (the CPU lane's
-  numbers tune the CPU lane); the flash/splash block and megakernel
-  ff-chunk sweeps only run where the kernels do (TPU) and are recorded
-  as skipped elsewhere — a CPU-stamped table never smuggles CPU
-  timings into TPU kernels because the device-kind key and stamp both
-  change.
+  numbers tune the CPU lane); the flash/splash block sweep only runs
+  where the kernels do (TPU) and is recorded as skipped elsewhere — a
+  CPU-stamped table never smuggles CPU timings into TPU kernels because
+  the device-kind key and stamp both change.
 
 Lookups are counted (``pt_autotune_lookups_total{kernel,result}``) so
 a serving fleet can see tuner hit/miss/stale rates next to the pass
@@ -177,7 +175,7 @@ def lookup(kernel: str, key: Dict, path: Optional[str] = None
             warnings.warn(
                 f"autotune table {path} is STALE ({reason}) — kernels "
                 "fall back to their documented defaults; re-run the "
-                "autotune sweep (bench.py autotune stage) to refresh",
+                "autotune sweep (autotune.run_autotune) to refresh",
                 RuntimeWarning)
         _M_LOOKUPS.inc(kernel=kernel, result="stale")
         return None
@@ -366,11 +364,10 @@ def autotune_flash(seq: int = 1024, heads: int = 8, dim: int = 128,
 
 def run_autotune(path: Optional[str] = None, rows: int = 256,
                  vocab: int = 8192) -> dict:
-    """The bench 'autotune' stage: run every sweep that is honest on
-    this backend, persist the stamped table, and PROVE a kernel reads
-    it at trace time (the xent chunk cap is re-derived through the
-    production lookup path and compared against the recorded
-    winner)."""
+    """Run every sweep that is honest on this backend, persist the
+    stamped table, and PROVE a kernel reads it at trace time (the xent
+    chunk cap is re-derived through the production lookup path and
+    compared against the recorded winner)."""
     path = path or table_path()
     out = {"autotune_table": path}
     xent_res = autotune_xent(rows=rows, vocab=vocab, path=path)

@@ -109,8 +109,8 @@ def dequantize_kv(codes, scales):
 def _deq_block(codes, scales):
     """Register-level EQuARX dequant of ONE block: codes (..., d) int8,
     scales (...,) fp32 -> fp32. THE collectives formula (±127 codes
-    reproduce ±absmax bit-exactly), not a restatement — the megakernel,
-    the scan fallback and quantize_kv/dequantize_kv can never drift
+    reproduce ±absmax bit-exactly), not a restatement — the scan
+    fallback and quantize_kv/dequantize_kv can never drift
     apart. (The decode walk applies the same ``absmax / 127`` step to
     score and probability columns instead: :func:`_chunk_dequantized`.)"""
     from ...distributed.collectives.quantized import _dequantize

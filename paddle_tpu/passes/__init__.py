@@ -26,8 +26,7 @@ from jax.extend.core import (ClosedJaxpr, Jaxpr, JaxprEqn,
 
 __all__ = ["PassManager", "apply_passes", "dce_pass", "fold_constants",
            "program_stats", "fuse_conv_bn", "default_pipeline",
-           "cse_pass", "fusion_pass", "inline_pjit", "fusion_enabled",
-           "decode_fusion_pass", "make_decode_fusion_pass"]
+           "cse_pass", "fusion_pass", "inline_pjit", "fusion_enabled"]
 
 
 def fusion_enabled() -> bool:
@@ -266,6 +265,4 @@ def fuse_conv_bn(model):
 # which lazily imports this module's _rebuild)
 from .cse import cse_pass            # noqa: E402,F401
 from .fusion import fusion_pass      # noqa: E402,F401
-from .fusion_decode import (decode_fusion_pass,          # noqa: E402,F401
-                            make_decode_fusion_pass)     # noqa: E402,F401
 from .patterns import inline_pjit    # noqa: E402,F401

@@ -297,60 +297,6 @@ def _admit_fn(cache_flat, state, row_flat, slot, tok0, pos0, pad0, rem0,
     return new_cache, new_state
 
 
-class _FusedBlockJit:
-    """Megakernel decode-block program: traces the block fn under the
-    decode-layer marking context, runs the decode fusion pass
-    (passes/fusion_decode.py) over the jaxpr — splicing one fused
-    "decode layer" call per layer per scan step — and jits the
-    TRANSFORMED program. Built lazily on first call (same laziness as
-    ``jax.jit``); zero marked layers is a hard error, because a
-    requested megakernel that silently serves the unfused program
-    would be a misconfiguration, not a preference."""
-
-    def __init__(self, block_fn, donate=(2, 3), allow_kernel=True):
-        self._block_fn = block_fn
-        self._donate = donate
-        self._allow_kernel = allow_kernel
-        self._jit = None
-        self._closed = None
-        self.rewrites = 0       # fused decode-layer calls spliced
-        self.kernel_calls = 0   # of those, Pallas-megakernel-routed
-
-    def _build(self, args):
-        from ..ops.pallas import decode_layer as _dl
-        from ..passes.fusion_decode import make_decode_fusion_pass
-        with _dl.marking():
-            closed, out_shape = jax.make_jaxpr(
-                self._block_fn, return_shape=True)(*args)
-        run = make_decode_fusion_pass(allow_kernel=self._allow_kernel)
-        closed = run(closed)
-        stats = run.last_rewrites
-        self.rewrites = stats.get("decode_layer", 0)
-        self.kernel_calls = stats.get("kernel", 0)
-        if self.rewrites == 0:
-            raise RuntimeError(
-                "megakernel decode requested but no decode layer was "
-                f"fused (pass stats: {stats or 'no marked regions'}) — "
-                "the model must mark its decode layers (see "
-                "models/llama.py LlamaDecoderLayer._marked_decode)")
-        self._closed = closed
-        out_tree = jax.tree.structure(out_shape)
-
-        def run_block(*call_args):
-            flat = jax.tree.leaves(call_args)
-            out = jax.core.eval_jaxpr(self._closed.jaxpr,
-                                      self._closed.consts, *flat)
-            return jax.tree.unflatten(out_tree, out)
-
-        self._jit = jax.jit(named_program(run_block, DECODE_PROGRAM),
-                            donate_argnums=self._donate)
-
-    def __call__(self, *args):
-        if self._jit is None:
-            self._build(args)
-        return self._jit(*args)
-
-
 class _StepBackendCommon:
     """Shared slot-state/accounting helpers for every step backend
     (in-process, paged, AOT) — keyed off ``num_slots``/``pool_specs``
@@ -361,32 +307,6 @@ class _StepBackendCommon:
     quant_cfg = None
     _qmeta = None
     _weight_bound = 0.0
-    # megakernel decode (ops/pallas/decode_layer.py): resolved by the
-    # model backends' constructors, always False on AOT backends
-    fuse = False
-
-    def _resolve_fuse(self, fuse) -> bool:
-        """``fuse=None`` defers to the PT_SERVING_MEGAKERNEL env knob
-        (same contract as paged/kv_int8 resolution); explicit backends
-        are never rerouted by it because resolution only runs in the
-        model-backend constructors."""
-        if fuse is None:
-            from ..utils.flags import env_bool
-            fuse = env_bool("PT_SERVING_MEGAKERNEL")
-        self.fuse = bool(fuse)
-        return self.fuse
-
-    def _block_jit_for(self, block_fn, donate=(2, 3)):
-        """The decode-block program builder every model backend routes
-        through: plain ``jax.jit`` normally, the pass-transformed fused
-        program under megakernel mode. Weight-quant engines keep the
-        fused-call structure but pin the captured-jaxpr body
-        (allow_kernel=False) so XLA's dequant-into-gemm prologue fusion
-        is never traded for an HBM-materialized fp32 weight."""
-        if not self.fuse:
-            return jax.jit(block_fn, donate_argnums=donate)
-        return _FusedBlockJit(block_fn, donate=donate,
-                              allow_kernel=not self._qmeta)
 
     def init_state(self):
         return init_slot_state(self.num_slots)
@@ -431,7 +351,7 @@ class ModelStepBackend(_StepBackendCommon):
     over a live model (the same pure step ``generate()`` uses)."""
 
     def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, quant=None, fuse=None):
+                 decode_block: int, quant=None):
         from ..models.generation import (build_decode_step,
                                          forward_accepts_pad)
         from ..tensor import Tensor
@@ -459,11 +379,11 @@ class ModelStepBackend(_StepBackendCommon):
         # all trace against codes + in-graph dequant
         self._setup_weight_quant(model, quant)
         self._pure = self._maybe_quant_pure(self._pure)
-        self._resolve_fuse(fuse)
         self.decode_traces = [0]
-        self._block_jit = self._block_jit_for(
+        self._block_jit = jax.jit(
             build_slot_block_fn(self._pure, decode_block,
-                                self.decode_traces))
+                                self.decode_traces),
+            donate_argnums=(2, 3))
         self._prefill_jits: Dict[int, callable] = {}
 
     def pool_cache(self):
@@ -600,7 +520,7 @@ class ContinuousBatchingEngine:
                  decode_block: int = 8,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  backend=None, *, paged: Optional[bool] = None,
-                 spec=None, tp=None, quant=None, megakernel=None):
+                 spec=None, tp=None, quant=None):
         if backend is None:
             if model is None:
                 raise ValueError("pass a model or a step backend")
@@ -608,12 +528,6 @@ class ContinuousBatchingEngine:
             from .tp import resolve_tp_config
             tp_cfg = resolve_tp_config(tp)
             q_cfg = resolve_quant_config(quant)
-            if tp_cfg is not None and megakernel:
-                raise NotImplementedError(
-                    "megakernel decode is not yet composed with "
-                    "tensor-parallel serving (the sharded block builds "
-                    "its own shard_map programs) — drop megakernel= or "
-                    "tp= (ROADMAP follow-up)")
             if tp_cfg is not None:
                 # tensor-parallel serving: the SAME decode/prefill
                 # programs, sharded over a mesh (serving/tp.py). An
@@ -627,16 +541,7 @@ class ContinuousBatchingEngine:
                 # subclass hook: the speculative engine swaps in the
                 # verify-capable backend here (serving/spec.py)
                 backend = self._build_backend(model, num_slots, max_len,
-                                              decode_block, q_cfg,
-                                              fuse=megakernel)
-        elif megakernel is not None:
-            # same contract as quant=: the fused program is baked into
-            # the backend at construction, and the env knob never
-            # reroutes an explicit backend (resolution only runs above)
-            raise ValueError(
-                "megakernel= cannot be set alongside an explicit "
-                "backend — the fused decode program is baked into the "
-                "backend at construction")
+                                              decode_block, q_cfg)
         elif quant is not None:
             # same contract as kv_int8/num_blocks on the paged engine:
             # the quantization is baked into the backend at construction
@@ -675,9 +580,9 @@ class ContinuousBatchingEngine:
         self.reset()
 
     def _build_backend(self, model, num_slots, max_len, decode_block,
-                       quant=None, fuse=None):
+                       quant=None):
         return ModelStepBackend(model, num_slots, max_len, decode_block,
-                                quant=quant, fuse=fuse)
+                                quant=quant)
 
     # -- lifecycle ---------------------------------------------------------
     def reset(self):
@@ -722,25 +627,6 @@ class ContinuousBatchingEngine:
         """Number of times the decode-block program was traced/compiled
         — the static-shape invariant holds iff this stays 1."""
         return self.backend.decode_traces[0]
-
-    def megakernel(self) -> bool:
-        """Whether the decode block was built through the decode-layer
-        fusion pass (ops/pallas/decode_layer.py megakernel mode)."""
-        return bool(getattr(self.backend, "fuse", False))
-
-    def megakernel_rewrites(self) -> int:
-        """Fused decode-layer calls spliced into the ONE decode-block
-        program (layers × 1; 0 before the lazy first build or with
-        megakernel off)."""
-        return int(getattr(self.backend._block_jit, "rewrites", 0)) \
-            if hasattr(self.backend, "_block_jit") else 0
-
-    def megakernel_kernel_calls(self) -> int:
-        """Of the fused calls, how many routed to the Pallas megakernel
-        (0 off-TPU / under weight quant — those run the bit-exact
-        captured-jaxpr body)."""
-        return int(getattr(self.backend._block_jit, "kernel_calls", 0)) \
-            if hasattr(self.backend, "_block_jit") else 0
 
     def tp_degree(self) -> int:
         """Devices the decode block is sharded over (1 = TP off)."""

@@ -1,29 +1,12 @@
-"""Serving microbenches: tensor-parallel decode (serving/tp.py),
-speculative draft-verify decode (serving/spec.py), quantized and
-megakernel decode, the multi-tenant front door (serving/frontend.py),
-and the disaggregated prefill/decode fleet (serving/fleet.py) — each
-A/B'd against the plain engine.
-
-Tensor-parallel stage — the slot-pool decode block sharded
-over a device mesh (serving/tp.py) A/B'd against the 1-chip engine.
-
-What the stage pins every round:
-
-- **bit-identity**: the exact-mode sharded greedy stream must equal the
-  1-chip stream token-for-token (the TP correctness contract);
-- **tokens/s** for both engines — on the CPU lane the "mesh" is
-  ``--xla_force_host_platform_device_count`` simulated devices sharing
-  one socket, so the sharded number is a plumbing-overhead record, not
-  a speedup claim (the speedup exists where the shards are real chips);
-- **collective traffic**: logical payload bytes and collective calls
-  per decode step, read back from the ``pt_collectives_*`` metrics the
-  sharded backend notes per dispatched block;
-- **int8 hop**: the psum-mode hidden-state all-reduce compressed with
-  the EQuARX wire format, with its runtime-queryable error bound.
-
-Wired into bench.py as the ``serving-tp`` child stage (CPU lane,
-non-null on the fallback path like comms/passes/observability; the TPU
-child runs it too when its window owns more than one chip).
+"""Fleet soaks the ``tools/*.sh`` scripts drive on the CPU lane: seeded
+traffic through a paged prefill/decode fleet with workers killed
+(``tools/chaos.sh``), sized by the autoscaler
+(``tools/autoscale_soak.sh``), or crashed and recovered from the
+durable journal (``tools/recovery_soak.sh``). Each asserts what the
+recovery code promises (bit-identity to ``generate()``, zero block
+leaks, one decode compile per engine) and returns the counters the
+script prints. Device speed is the benchmark's to measure
+(``benchmark/run.py``), not theirs.
 """
 from __future__ import annotations
 
@@ -32,422 +15,7 @@ import time
 import numpy as np
 
 __all__ = ["run_fleet_kill_soak", "run_serving_autoscale_bench",
-           "run_serving_disagg_bench",
-           "run_serving_failover_bench", "run_serving_frontdoor_bench",
-           "run_serving_megakernel_bench",
-           "run_serving_prefixcache_bench", "run_serving_quant_bench",
-           "run_serving_recovery_bench", "run_serving_spec_bench",
-           "run_serving_tp_bench"]
-
-
-def run_serving_disagg_bench(requests_per_group: int = 6,
-                             groups: int = 3, max_new: int = 8,
-                             num_slots: int = 2) -> dict:
-    """Disaggregated prefill/decode fleet stage (serving/fleet.py +
-    handoff.py): a 2-prefill/2-decode paged fleet on a shared-system-
-    prompt workload, A/B'd against a single-replica Server and against
-    itself with affinity routing off.
-
-    What the stage pins every round:
-
-    - **handoff payload at wire size**: mean KV payload bytes per
-      request for the fp32 arena vs the int8 arena on the SAME
-      workload — the int8 payload must be ~3.6x smaller (codes +
-      scales ship quantized, never dequantized in transit);
-    - **fleet-wide prefix cache**: burst hit rate with affinity
-      routing (each group's warm system prompt lands where its
-      registered blocks live) vs the single-replica rate (gate: >=)
-      and vs the same fleet with affinity off (scattered groups pay
-      the prefix cold);
-    - **disagg-vs-unified TTFT p50 and decode tokens/s**: the
-      pipelining record on the CPU lane (the hardware-pool split is a
-      TPU-fleet claim; the CPU number tracks overhead);
-    - the compile-count pin: ONE decode block per decode worker, ONE
-      chunk program per prefill worker, and cross-worker streams
-      bit-identical to the unified server.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                         llama_tiny_config)
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    DecodeWorker, Fleet, PrefillWorker,
-                                    PrefillPagedEngine, Server)
-
-    paddle.seed(0)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    kw = dict(num_slots=num_slots, max_len=64, decode_block=4,
-              block_size=8, prefill_chunk=16)
-
-    # shared-system-prompt workload: each group shares a 16-token
-    # prefix (two full blocks); one warm request per group first, so
-    # the burst measures the hot-tenant steady state
-    sys_ps = [rs.randint(0, cfg.vocab_size, (16,)).astype(np.int32)
-              for _ in range(groups)]
-    warm = [np.concatenate([sp, rs.randint(0, cfg.vocab_size, (2,))
-                            .astype(np.int32)]) for sp in sys_ps]
-    burst = [np.concatenate([sys_ps[g], rs.randint(
-        0, cfg.vocab_size, (3 + k % 4,)).astype(np.int32)])
-        for g in range(groups) for k in range(requests_per_group)]
-
-    def drive(submit, run, engines):
-        for p in warm:
-            submit(p)
-        run()
-        pt0 = sum(e.prompt_tokens for e in engines)
-        st0 = sum(e.shared_tokens for e in engines)
-        rids = [submit(p) for p in burst]
-        t0 = time.perf_counter()
-        res = run()
-        dt = time.perf_counter() - t0
-        pt = sum(e.prompt_tokens for e in engines) - pt0
-        st = sum(e.shared_tokens for e in engines) - st0
-        return rids, res, dt, st / pt
-
-    pf_engines = [PrefillPagedEngine(model, **kw) for _ in range(2)]
-    dc_engines = [ContinuousBatchingEngine(model, paged=True, **kw)
-                  for _ in range(2)]
-
-    def mk_fleet(affinity, pf_list, dc_list):
-        for e in pf_list + dc_list:
-            e.reset()
-        return Fleet([PrefillWorker(e) for e in pf_list],
-                     [DecodeWorker(e) for e in dc_list],
-                     affinity=affinity, spill_depth=100)
-
-    # ---- unified single-replica baseline ---------------------------------
-    uni_eng = ContinuousBatchingEngine(model, paged=True, **kw)
-    uni = Server(uni_eng)
-    uni_rids, uni_res, dt_uni, uni_rate = drive(
-        lambda p: uni.submit(p, max_new_tokens=max_new),
-        lambda: uni.run_until_idle(), [uni_eng])
-    uni_ttft = [uni.ttft[r] * 1000 for r in uni_rids if r in uni.ttft]
-
-    # ---- fp32 fleet, affinity on -----------------------------------------
-    fleet = mk_fleet(True, pf_engines, dc_engines)
-    f_rids, f_res, dt_fleet, fleet_rate = drive(
-        lambda p: fleet.submit(p, max_new_tokens=max_new),
-        lambda: fleet.run_until_idle(max_ticks=2000),
-        [w.engine for w in fleet.prefill])
-    identical = all(np.array_equal(f_res[a], uni_res[b])
-                    for a, b in zip(f_rids, uni_rids))
-    # burst requests only, matching the unified sample (warm requests
-    # pay the cold prefix and would bias the fleet p50 upward)
-    ttft_ms = [d.server.ttft[r] * 1000 for d in fleet.decode
-               for r in f_rids if r in d.server.ttft]
-    fst = fleet.stats()
-    compiles = (max(d.engine.decode_compile_count()
-                    for d in fleet.decode),
-                max(w.engine.prefill_compile_count()
-                    for w in fleet.prefill))
-    kv_fp32 = fst["handoff_kv_bytes_mean"]
-    wire_fp32 = fst["handoff_wire_bytes_mean"]
-
-    # ---- same engines, affinity OFF (the A/B) ----------------------------
-    off = mk_fleet(False, pf_engines, dc_engines[:1])
-    *_, off_rate = drive(
-        lambda p: off.submit(p, max_new_tokens=max_new),
-        lambda: off.run_until_idle(max_ticks=2000),
-        [w.engine for w in off.prefill])
-
-    # ---- int8 fleet: same workload, quantized wire -----------------------
-    f8 = Fleet([PrefillWorker(PrefillPagedEngine(
-        model, kv_int8=True, **kw))],
-        [DecodeWorker(ContinuousBatchingEngine(
-            model, paged=True, kv_int8=True, **kw))],
-        affinity=True, spill_depth=100)
-    drive(lambda p: f8.submit(p, max_new_tokens=max_new),
-          lambda: f8.run_until_idle(max_ticks=2000),
-          [w.engine for w in f8.prefill])
-    kv_int8 = f8.stats()["handoff_kv_bytes_mean"]
-
-    useful = len(burst) * max_new
-    return {
-        "serving_disagg_workers": "2p+2d",
-        "serving_disagg_bit_identical": bool(identical),
-        "serving_disagg_handoffs": fst["handoffs"],
-        "serving_disagg_handoff_kv_bytes_fp32": kv_fp32,
-        "serving_disagg_handoff_kv_bytes_int8": kv_int8,
-        "serving_disagg_handoff_int8_ratio": round(
-            kv_fp32 / max(kv_int8, 1.0), 2),
-        "serving_disagg_handoff_wire_bytes": wire_fp32,
-        "serving_disagg_prefix_hit_rate_fleet": round(fleet_rate, 4),
-        "serving_disagg_prefix_hit_rate_noaffinity": round(off_rate,
-                                                           4),
-        "serving_disagg_prefix_hit_rate_single": round(uni_rate, 4),
-        "serving_disagg_affinity_ge_single": bool(
-            fleet_rate >= uni_rate - 1e-9),
-        "serving_disagg_tokens_per_sec": round(useful / dt_fleet, 1),
-        "serving_disagg_tokens_per_sec_unified": round(
-            useful / dt_uni, 1),
-        "serving_disagg_ttft_p50_ms": round(
-            float(np.percentile(ttft_ms, 50)), 2) if ttft_ms else None,
-        "serving_disagg_ttft_p50_ms_unified": round(
-            float(np.percentile(uni_ttft, 50)), 2) if uni_ttft
-        else None,
-        "serving_disagg_spillovers": fst["spillovers"],
-        "serving_disagg_decode_compiles": compiles[0],
-        "serving_disagg_prefill_compiles": compiles[1],
-    }
-
-
-def run_serving_prefixcache_bench(max_new: int = 8,
-                                  sys_len: int = 192,
-                                  tail_len: int = 7) -> dict:
-    """Fleet-wide KV prefix cache stage (serving/prefix_cache.py):
-    cold vs warm-local vs warm-remote TTFT on a shared-system-prompt
-    workload, plus the bytes-moved-vs-flops-saved accounting that IS
-    the feature's economic claim.
-
-    What the stage pins every round:
-
-    - **TTFT ladder**: the same system prompt served (a) cold — full
-      chunked prefill, (b) warm-LOCAL — the PR 4 index covers the
-      prefix on the admitting worker, (c) warm-REMOTE — another worker
-      holds the warm copy and the admitting worker fetches it over the
-      ``#fetch`` side channel, then prefills only the tail. Gate (in
-      bench.py): warm-remote strictly beats cold — a fetch must cost
-      less than the prefill it saves, or the tier is pointless;
-    - **bytes moved vs flops saved**: wire KV bytes per fetch against
-      ``~2 * n_params * covered_tokens`` of skipped prefill compute —
-      the trade the directory arbitrates;
-    - **counters from the metrics registry** (fetches / fetched blocks
-      / failures / duplicates / evictions) — the observability
-      satellite read back the way an operator would read it;
-    - the compile pin: decode and prefill compile counts stay 1 on
-      every worker — the fetch adopts through the shared scatter
-      program, never a new steady-path program.
-
-    A warm-up round on a DIFFERENT system prompt first compiles every
-    program (chunk prefill, decode block, adopt + fetch scatter), so
-    the measured TTFTs compare compute, not compilation. The default
-    system prefix is 24 blocks (192 tokens) — long enough that the
-    saved chunk dispatches dominate the fixed per-fetch cost
-    (serialize + CRC + one scatter) even on the CPU lane.
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                         llama_tiny_config)
-    from paddle_tpu.observability import metrics as om
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    DecodeWorker, Fleet, PrefillWorker,
-                                    PrefillPagedEngine)
-    from paddle_tpu.serving import prefix_cache as pc
-
-    paddle.seed(0)
-    om.reset()
-    om.enable(True)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    kw = dict(num_slots=2, max_len=256, decode_block=4, block_size=8,
-              prefill_chunk=8)
-    pf = [PrefillPagedEngine(model, **kw) for _ in range(2)]
-    dc = [ContinuousBatchingEngine(model, paged=True, **kw)
-          for _ in range(2)]
-    fleet = Fleet([PrefillWorker(e) for e in pf],
-                  [DecodeWorker(e) for e in dc])
-
-    def prompt(sys_p):
-        return np.concatenate(
-            [sys_p, rs.randint(0, cfg.vocab_size,
-                               (tail_len,)).astype(np.int32)])
-
-    def ttft_ms(rid):
-        for d in fleet.decode:
-            if rid in d.server.ttft:
-                return d.server.ttft[rid] * 1000.0
-        return None
-
-    def serve(p, worker):
-        rid = fleet.submit(p, max_new_tokens=max_new,
-                           prefill_worker=worker)
-        res = fleet.run_until_idle(max_ticks=2000)
-        return rid, res[rid], ttft_ms(rid)
-
-    # ---- warm-up: compile every program incl. the fetch scatter ----------
-    sys_w = rs.randint(0, cfg.vocab_size, (sys_len,)).astype(np.int32)
-    serve(prompt(sys_w), "prefill0")
-    serve(prompt(sys_w), "prefill1")        # first fetch: compiles
-    warmup_fetches = fleet.prefix_fetches
-
-    # ---- the measured ladder on a fresh system prompt --------------------
-    sys_m = rs.randint(0, cfg.vocab_size, (sys_len,)).astype(np.int32)
-    _, _, cold_ms = serve(prompt(sys_m), "prefill0")         # cold
-    _, _, local_ms = serve(prompt(sys_m), "prefill0")        # warm-local
-    p_rem = prompt(sys_m)
-    rr, out_r, remote_ms = serve(p_rem, "prefill1")          # warm-remote
-    ref = model.generate(paddle.to_tensor(p_rem[None, :]),
-                         max_new_tokens=max_new,
-                         temperature=0.0).numpy()[0]
-    identical = bool(np.array_equal(out_r, ref))
-
-    fetches = fleet.prefix_fetches - warmup_fetches
-    kv_bytes = fleet.prefix_fetch_kv_bytes[warmup_fetches:]
-    covered = sum(e.fetched_tokens for e in pf)
-    n_params = int(model.num_params())
-    flops_saved = 2 * n_params * covered
-    bytes_moved = int(np.sum(kv_bytes)) if kv_bytes else 0
-    fst = fleet.stats()
-    out = {
-        "serving_prefixcache_bit_identical": identical,
-        "serving_prefixcache_ttft_cold_ms": round(cold_ms, 2),
-        "serving_prefixcache_ttft_warm_local_ms": round(local_ms, 2),
-        "serving_prefixcache_ttft_warm_remote_ms": round(remote_ms, 2),
-        "serving_prefixcache_remote_vs_cold_speedup": round(
-            cold_ms / max(remote_ms, 1e-9), 2),
-        "serving_prefixcache_fetches": fetches,
-        "serving_prefixcache_fetch_kv_bytes_mean": round(
-            float(np.mean(kv_bytes)), 1) if kv_bytes else 0.0,
-        "serving_prefixcache_bytes_moved": bytes_moved,
-        "serving_prefixcache_covered_tokens": covered,
-        "serving_prefixcache_flops_saved": flops_saved,
-        "serving_prefixcache_flops_per_wire_byte": round(
-            flops_saved / bytes_moved, 1) if bytes_moved else None,
-        "serving_prefixcache_fetch_counter": int(
-            pc._M_FETCHES.value()),
-        "serving_prefixcache_fail_counters": {
-            k: int(v) for k, v in
-            fst["prefix_fetch_failures"].items()},
-        "serving_prefixcache_duplicates": fst[
-            "prefix_fetch_duplicates"],
-        "serving_prefixcache_evictions": fst["prefix_evictions"],
-        "serving_prefixcache_directory_entries": fst[
-            "prefix_directory"]["entries"],
-        "serving_prefixcache_decode_compiles": max(
-            e.decode_compile_count() for e in dc),
-        "serving_prefixcache_prefill_compiles": max(
-            e.prefill_compile_count() for e in pf),
-    }
-    om.reset()
-    om.enable(False)
-    return out
-
-
-def run_serving_failover_bench(requests: int = 6, max_new: int = 24,
-                               num_slots: int = 2,
-                               kill_after: int = 3) -> dict:
-    """Fleet failure-domain stage (serving/transport.py + fleet.py):
-    kill-one-decode-worker A/B on a paged 2-prefill/2-decode fleet
-    over the REAL localhost-TCP SocketTransport with ~1% wire faults
-    armed (partial_write/corrupt/disconnect).
-
-    What the stage pins every round:
-
-    - **recovered-stream bit-identity**: every stream of the killed
-      run — including the redriven ones, greedy AND seeded-sampled —
-      token-equal to the clean (unfailed) run of the same workload;
-    - **redrive latency p50/p95**: wall time from lease-expiry
-      detection to the redriven stream's terminal;
-    - **goodput with and without the mid-run kill**: completed useful
-      tokens/s A/B — the cost of losing (and re-homing) a failure
-      domain mid-traffic;
-    - **handoff retry/dedup counters from the metrics registry**:
-      transport resends/reconnects/CRC drops, fleet handoff retries,
-      and (rid, seq)-deduplicated adopts;
-    - the compile-count pin: the surviving decode worker's ONE block
-      (redrive arms through the existing programs, zero new compiles).
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                         llama_tiny_config)
-    from paddle_tpu.observability import metrics as om
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    DecodeWorker, Fleet, PrefillWorker,
-                                    PrefillPagedEngine, RequestFailure,
-                                    SocketTransport)
-    from paddle_tpu.serving import fleet as fleet_mod
-    from paddle_tpu.serving import transport as transport_mod
-    from paddle_tpu.utils import faults
-
-    paddle.seed(0)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    kw = dict(num_slots=num_slots, max_len=64, decode_block=4,
-              block_size=8, prefill_chunk=16)
-    prompts = [rs.randint(0, cfg.vocab_size,
-                          (int(rs.randint(5, 14)),)).astype(np.int32)
-               for _ in range(requests)]
-    news = [max_new - (i % 3) * 2 for i in range(requests)]
-    sampled = [i % 3 == 1 for i in range(requests)]
-
-    pf_engines = [PrefillPagedEngine(model, **kw) for _ in range(2)]
-    dc_engines = [ContinuousBatchingEngine(model, paged=True, **kw)
-                  for _ in range(2)]
-
-    def drive(kill: bool):
-        for e in pf_engines + dc_engines:
-            e.reset()
-        t = SocketTransport("fleet", retry_backoff_s=0.001)
-        fleet = Fleet([PrefillWorker(e) for e in pf_engines],
-                      [DecodeWorker(e) for e in dc_engines],
-                      transport=t, lease_misses=2, spill_depth=100)
-        rids = []
-        for i, (p, mn) in enumerate(zip(prompts, news)):
-            skw = dict(temperature=0.9, top_k=40, seed=100 + i) \
-                if sampled[i] else {}
-            rids.append(fleet.submit(p, max_new_tokens=mn, **skw))
-        t0 = time.perf_counter()
-        spec = ("transport.partial_write:p=0.01;transport.corrupt:"
-                "p=0.01;transport.disconnect:p=0.01")
-        with faults.injected(spec if kill else "", seed=7):
-            if kill:
-                for _ in range(kill_after):
-                    fleet.tick()
-                fleet.kill_decode_worker(1)
-            res = fleet.run_until_idle(max_ticks=2000)
-        dt = time.perf_counter() - t0
-        done = sum(news[i] for i, r in enumerate(rids)
-                   if not isinstance(res.get(r), RequestFailure))
-        out = ([res[r] if not isinstance(res[r], RequestFailure)
-                else None for r in rids], done / dt, fleet.stats())
-        t.close()
-        return out
-
-    drive(kill=False)                # warm-up: compiles land here, so
-    om.reset()                       # the A/B compares steady states
-    om.enable(True)
-    try:
-        clean_rows, clean_goodput, _ = drive(kill=False)
-        kill_rows, kill_goodput, kst = drive(kill=True)
-    finally:
-        om.enable(False)
-    identical = all(a is not None and b is not None
-                    and np.array_equal(a, b)
-                    for a, b in zip(clean_rows, kill_rows))
-    lat = kst["redrive_latency_p50_s"]
-    lat95 = kst["redrive_latency_p95_s"]
-    return {
-        "serving_failover_workers": "2p+2d",
-        "serving_failover_bit_identical": bool(identical),
-        "serving_failover_workers_lost": kst["workers_lost"],
-        "serving_failover_redrives": kst["redrives"],
-        "serving_failover_redrive_latency_p50_ms": round(
-            lat * 1000, 2) if lat is not None else 0.0,
-        "serving_failover_redrive_latency_p95_ms": round(
-            lat95 * 1000, 2) if lat95 is not None else 0.0,
-        "serving_failover_goodput_tokens_per_sec": round(
-            kill_goodput, 1),
-        "serving_failover_goodput_tokens_per_sec_clean": round(
-            clean_goodput, 1),
-        "serving_failover_goodput_ratio": round(
-            kill_goodput / clean_goodput, 3) if clean_goodput else 0.0,
-        # the registry's view (both runs; the kill run armed it)
-        "serving_failover_handoff_retries": int(
-            fleet_mod._M_FLEET_RETRIES.value()),
-        "serving_failover_duplicate_adopts": int(
-            fleet_mod._M_ADOPT_DUPS.value()),
-        "serving_failover_transport_resends": int(
-            transport_mod._M_RESENDS.value()),
-        "serving_failover_transport_crc_drops": int(
-            transport_mod._M_CRC_DROPS.value()),
-        "serving_failover_transport_reconnects": int(
-            transport_mod._M_RECONNECTS.value()),
-        "serving_failover_decode_compiles": max(
-            e.decode_compile_count() for e in dc_engines),
-    }
+           "run_serving_recovery_bench"]
 
 
 def run_fleet_kill_soak(seed: int = 0, kills: int = 2,
@@ -546,569 +114,6 @@ def run_fleet_kill_soak(seed: int = 0, kills: int = 2,
         "soak_duplicate_adopts": st["duplicate_adopts"],
         "soak_transport": st["transport"], "soak_ticks": st["ticks"],
         "soak_leaks": 0,
-    }
-
-
-def run_serving_frontdoor_bench(requests_per_tenant: int = 18,
-                                max_new: int = 8, num_slots: int = 4,
-                                decode_block: int = 4) -> dict:
-    """Multi-tenant front-door stage (serving/frontend.py): weighted-
-    fair shares, priority preemption, and per-priority TTFT on the
-    paged engine.
-
-    What the stage pins every round:
-
-    - **fairness**: a saturated 3-tenant workload (weights 1:2:3, equal
-      request shapes) measured via the streaming sink's per-tenant
-      token tallies while every tenant is still backlogged — measured
-      throughput shares must sit within 10% of the configured weights;
-    - **preemption**: a pool full of low-priority decodes evicted by a
-      high-priority burst — preemption count, the evicted requests
-      still completing (no starvation), and their outputs BIT-IDENTICAL
-      to an uninterrupted run (the resume-correctness contract);
-    - **TTFT p50/p95 split by priority**: the latency win preemption
-      buys the high tier while the low tier still finishes;
-    - the compile-count pin: ONE decode block + ONE chunk program
-      across fairness, evictions and resumes (no new compiled
-      programs).
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                         llama_tiny_config)
-    from paddle_tpu.serving import (ContinuousBatchingEngine, Frontend,
-                                    TenantConfig)
-
-    paddle.seed(0)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    weights = {"bronze": 1.0, "silver": 2.0, "gold": 3.0}
-    engine = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=64,
-        decode_block=decode_block, paged=True, block_size=8,
-        prefill_chunk=16)
-
-    # ---- phase 1: weighted-fair shares under saturation ------------------
-    fe = Frontend(engine, tenants={t: TenantConfig(weight=w)
-                                   for t, w in weights.items()},
-                  preemption=True)
-    for i in range(requests_per_tenant):
-        for t in weights:
-            p = rs.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
-            fe.submit(p, tenant=t, max_new_tokens=max_new)
-
-    def outstanding(t):
-        c = fe.server.tenant_counts.get(t, {})
-        return c.get("submitted", 0) - c.get("completed", 0) \
-            - c.get("failed", 0)
-
-    t0 = time.perf_counter()
-    # measure only while EVERY tenant is backlogged: the share claim is
-    # about contention, not about who finishes first
-    while all(outstanding(t) > 0 for t in weights) and fe.pump():
-        pass
-    dt_shares = time.perf_counter() - t0
-    streamed = dict(fe.tenant_tokens)
-    total = max(sum(streamed.values()), 1)
-    wsum = sum(weights.values())
-    shares = {t: streamed.get(t, 0) / total for t in weights}
-    expected = {t: w / wsum for t, w in weights.items()}
-    rel_err = max(abs(shares[t] - expected[t]) / expected[t]
-                  for t in weights)
-    fe.run_until_idle()                     # drain the tail
-
-    # ---- phase 2: priority preemption + per-priority TTFT ----------------
-    prompts = [rs.randint(0, cfg.vocab_size,
-                          (5 + (i % 3) * 4,)).astype(np.int32)
-               for i in range(num_slots)]
-    hi_prompts = [rs.randint(0, cfg.vocab_size, (6,)).astype(np.int32)
-                  for _ in range(2)]
-
-    def low_refs():
-        engine.reset()
-        ref_fe = Frontend(engine)
-        rids = [ref_fe.submit(p, max_new_tokens=24) for p in prompts]
-        res = ref_fe.run_until_idle()
-        return [res[r] for r in rids]
-
-    ref = low_refs()                        # uninterrupted twins
-
-    def burst(preempt):
-        engine.reset()
-        f = Frontend(engine, preemption=preempt)
-        lo = [f.submit(p, max_new_tokens=24, priority=0)
-              for p in prompts]
-        for _ in range(3):
-            f.pump()                        # pool fully decoding
-        hi_ = [f.submit(p, max_new_tokens=6, priority=5)
-               for p in hi_prompts]
-        return f, lo, hi_, f.run_until_idle()
-
-    # the A/B that makes the TTFT split meaningful: the same
-    # high-priority burst lands on the same busy pool, with and
-    # without the eviction policy. One warmup pass first — the first
-    # eviction ever compiles the (tiny) slot-cancel program, which
-    # would otherwise land inside the preemption side's TTFT
-    burst(True)
-    fe_off, _, hi_off, _ = burst(False)
-    fe2, low, hi, res = burst(True)
-    st = fe2.stats()
-    identical = all(np.array_equal(res[r], a)
-                    for r, a in zip(low, ref))
-
-    def ttft_ms(frontend, rids, q):
-        vals = [frontend.server.ttft[r] * 1000 for r in rids
-                if r in frontend.server.ttft]
-        return round(float(np.percentile(vals, q)), 2) if vals else None
-
-    return {
-        "serving_frontdoor_weights": {t: w for t, w in weights.items()},
-        "serving_frontdoor_share_bronze": round(shares["bronze"], 4),
-        "serving_frontdoor_share_silver": round(shares["silver"], 4),
-        "serving_frontdoor_share_gold": round(shares["gold"], 4),
-        "serving_frontdoor_share_max_rel_err": round(rel_err, 4),
-        "serving_frontdoor_shares_within_10pct": bool(rel_err <= 0.10),
-        "serving_frontdoor_fair_tokens_per_sec": round(
-            total / dt_shares, 1),
-        "serving_frontdoor_preemptions": st["preemptions"],
-        "serving_frontdoor_resumes": st["resumes"],
-        "serving_frontdoor_bit_identical": bool(identical),
-        "serving_frontdoor_ttft_p50_ms_high": ttft_ms(fe2, hi, 50),
-        "serving_frontdoor_ttft_p95_ms_high": ttft_ms(fe2, hi, 95),
-        "serving_frontdoor_ttft_p50_ms_high_nopreempt":
-            ttft_ms(fe_off, hi_off, 50),
-        "serving_frontdoor_ttft_p95_ms_high_nopreempt":
-            ttft_ms(fe_off, hi_off, 95),
-        "serving_frontdoor_ttft_p50_ms_low": ttft_ms(fe2, low, 50),
-        "serving_frontdoor_ttft_p95_ms_low": ttft_ms(fe2, low, 95),
-        "serving_frontdoor_decode_compiles":
-            engine.decode_compile_count(),
-        "serving_frontdoor_prefill_compiles":
-            engine.prefill_compile_count(),
-    }
-
-
-def run_serving_megakernel_bench(requests: int = 8, max_new: int = 32,
-                                 num_slots: int = 8,
-                                 decode_block: int = 8) -> dict:
-    """Fused decode-layer A/B: the megakernel engine (decode-fusion
-    pass + ops/pallas/decode_layer.py) against the plain paged+int8-KV
-    engine on the SAME greedy stream.
-
-    What the stage pins every round:
-
-    - **bit-identity**: fused greedy streams must equal the unfused
-      engine's token-for-token (on the CPU lane the fused call's body
-      IS the captured unfused jaxpr, so this pins the pass/splice
-      plumbing; on TPU the same gate pins the kernel's numerics
-      against greedy argmax);
-    - **decode tokens/s A/B** — an overhead record on the CPU lane
-      (same math, one extra call boundary); the HBM win belongs to the
-      TPU child, where the fused program stops round-tripping the
-      hidden state between attention/o_proj/MLP;
-    - **the no-transient jaxpr walk**: the transformed decode-block
-      program must hold NO fp32 hidden-state interior ((S, 1, ff) MLP
-      activation, (S, kvh, g, dh) attention internals) outside the
-      fused calls — the structural form of the VMEM-residency claim;
-    - rewrite/kernel-call counts from the pass, and the compile-count
-      pin (ONE decode program).
-    """
-    import jax.numpy as jnp
-
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.passes.fusion_decode import (fused_decode_calls,
-                                                 walk_outside_fused)
-    from paddle_tpu.serving import ContinuousBatchingEngine, Server
-
-    paddle.seed(0)
-    cfg = LlamaConfig(
-        vocab_size=512, hidden_size=256, intermediate_size=768,
-        num_hidden_layers=4, num_attention_heads=8,
-        num_key_value_heads=8, max_position_embeddings=256,
-        tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    prompts = [rs.randint(0, cfg.vocab_size,
-                          (8 + (i % 3) * 8,)).astype(np.int32)
-               for i in range(requests)]
-    max_len = -(-(32 + max_new) // 16) * 16
-    kw = dict(num_slots=num_slots, max_len=max_len,
-              decode_block=decode_block, paged=True, block_size=16,
-              prefill_chunk=32, kv_int8=True)
-    plain = ContinuousBatchingEngine(model, megakernel=False, **kw)
-    mega = ContinuousBatchingEngine(model, megakernel=True, **kw)
-
-    def run(engine):
-        engine.reset()
-        srv = Server(engine)
-        rids = [srv.submit(p, max_new_tokens=max_new) for p in prompts]
-        t0 = time.perf_counter()
-        res = srv.run_until_idle()
-        return [res[r] for r in rids], time.perf_counter() - t0
-
-    run(plain), run(mega)                   # compile warmup
-    ref, dt_plain = run(plain)
-    got, dt_mega = run(mega)
-    identical = all(np.array_equal(a, b) for a, b in zip(ref, got))
-
-    # the no-transient walk over the TRANSFORMED decode-block program
-    closed = mega.backend._block_jit._closed
-    S = num_slots
-    kvh = cfg.num_key_value_heads
-    g = cfg.num_attention_heads // kvh
-    dh = cfg.hidden_size // cfg.num_attention_heads
-    banned = {(S, 1, cfg.intermediate_size), (S, kvh, g, dh)}
-    outside = set()
-    for eqn in walk_outside_fused(closed):
-        for v in eqn.outvars:
-            aval = getattr(v, "aval", None)
-            if aval is not None and \
-                    getattr(aval, "dtype", None) == jnp.float32:
-                outside.add(tuple(aval.shape))
-    no_transient = not (outside & banned)
-
-    useful = requests * max_new
-    return {
-        "serving_megakernel_bit_identical": bool(identical),
-        "serving_megakernel_tokens_per_sec_unfused":
-            round(useful / dt_plain, 1),
-        "serving_megakernel_tokens_per_sec":
-            round(useful / dt_mega, 1),
-        "serving_megakernel_speedup": round(dt_plain / dt_mega, 3),
-        "serving_megakernel_rewrites": mega.megakernel_rewrites(),
-        "serving_megakernel_kernel_calls":
-            mega.megakernel_kernel_calls(),
-        "serving_megakernel_fused_calls_in_program":
-            len(fused_decode_calls(closed)),
-        "serving_megakernel_no_hidden_state_transient":
-            bool(no_transient),
-        "serving_megakernel_decode_compiles":
-            mega.decode_compile_count(),
-    }
-
-
-def run_serving_quant_bench(requests: int = 8, max_new: int = 48,
-                            num_slots: int = 8, decode_block: int = 8,
-                            weights: str = "int8") -> dict:
-    """Bandwidth-true quantized serving A/B: the fully quantized paged
-    engine (int8 KV arena + weight-only ``weights`` decode weights,
-    dequant inside the read/gemm) against the fp32 paged engine on the
-    SAME greedy stream.
-
-    What the stage pins every round:
-
-    - **decode tokens/s A/B** — the ROADMAP gate is that quantization
-      moves tokens/s, not just bytes/slot. On the CPU lane the arena is
-      host RAM and the dequant costs real VPU-less cycles, so the CPU
-      number is an overhead record (the speedup claim belongs to the
-      TPU child, where decode is HBM-bandwidth-bound and bytes ARE
-      time);
-    - **bytes-read/step accounting** from the metrics registry
-      (``pt_serving_decode_bytes_read_total`` per engine step): the
-      quant engine must read ~3-4x fewer bytes per decode step;
-    - **both error bounds** (``engine.quant_error_bound()``): the
-      runtime EQuARX KV bound and the build-time weight bound;
-    - **token agreement** with the fp32 stream (reported, not gated —
-      quantized logits legitimately diverge within the bounds);
-    - the compile-count pin (ONE decode program per engine).
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.observability import metrics
-    from paddle_tpu.serving import (ContinuousBatchingEngine,
-                                    QuantConfig, Scheduler, Server)
-
-    paddle.seed(0)
-    cfg = LlamaConfig(
-        vocab_size=512, hidden_size=256, intermediate_size=768,
-        num_hidden_layers=4, num_attention_heads=8,
-        num_key_value_heads=8, max_position_embeddings=256,
-        tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    prompts = [rs.randint(0, cfg.vocab_size,
-                          (8 + (i % 3) * 8,)).astype(np.int32)
-               for i in range(requests)]
-    max_len = -(-(32 + max_new) // 16) * 16      # block_size multiple
-
-    # the baseline pins BOTH halves fp32 explicitly — an armed
-    # PT_SERVING_QUANT_WEIGHTS / PT_SERVING_KV_INT8 in the operator's
-    # shell must not silently quantize it into a quant-vs-quant A/B
-    fp32 = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=max_len,
-        decode_block=decode_block, paged=True, block_size=16,
-        prefill_chunk=32, kv_int8=False, quant=False)
-    quant = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=max_len,
-        decode_block=decode_block, paged=True, block_size=16,
-        prefill_chunk=32, kv_int8=True,
-        quant=QuantConfig(weights=weights))
-
-    def run(engine):
-        engine.reset()
-        srv = Server(engine, Scheduler())
-        rids = [srv.submit(p, max_new_tokens=max_new) for p in prompts]
-        t0 = time.perf_counter()
-        res = srv.run_until_idle()
-        return [res[r] for r in rids], time.perf_counter() - t0
-
-    run(fp32), run(quant)                   # compile warmup
-
-    prev_enabled = metrics.enabled()
-    metrics.enable(True)
-    try:
-        # registered at serving import (engine.py) — fetch, don't
-        # re-declare (a drifting copy of the help string would be
-        # silently ignored by get-or-create)
-        bytes_c = metrics.REGISTRY.get(
-            "pt_serving_decode_bytes_read_total")
-        b0 = bytes_c.value()
-        ref, dt_fp32 = run(fp32)
-        bytes_fp32 = (bytes_c.value() - b0) / max(fp32.steps, 1)
-        b0 = bytes_c.value()
-        got, dt_quant = run(quant)
-        bytes_quant = (bytes_c.value() - b0) / max(quant.steps, 1)
-    finally:
-        metrics.enable(prev_enabled)
-    # GENERATED tokens only — results are prompt + generated rows, and
-    # counting the identical-by-construction prompt prefix inflates
-    # the agreement number
-    agree = float(np.mean([np.mean(a[len(p):] == b[len(p):])
-                           for a, b, p in zip(ref, got, prompts)]))
-    bounds = quant.quant_error_bound()
-
-    useful = requests * max_new
-    return {
-        "serving_quant_weights": weights,
-        "serving_quant_kv": "int8",
-        "serving_quant_tokens_per_sec_fp32": round(useful / dt_fp32, 1),
-        "serving_quant_tokens_per_sec": round(useful / dt_quant, 1),
-        "serving_quant_speedup": round(dt_fp32 / dt_quant, 3),
-        "serving_quant_bytes_per_step_fp32": int(bytes_fp32),
-        "serving_quant_bytes_per_step": int(bytes_quant),
-        "serving_quant_bytes_ratio": round(
-            bytes_fp32 / max(bytes_quant, 1), 2),
-        "serving_quant_kv_error_bound": round(bounds["kv"], 6),
-        "serving_quant_weight_error_bound": round(bounds["weights"], 6),
-        "serving_quant_token_agreement": round(agree, 4),
-        "serving_quant_decode_compiles": quant.decode_compile_count(),
-    }
-
-
-def run_serving_spec_bench(requests: int = 8, max_new: int = 64,
-                           num_slots: int = 8, k: int = 8,
-                           decode_block: int = 8,
-                           warm_tokens: int = 32,
-                           candidate_tokens: int = 96) -> dict:
-    """Speculative-decode A/B: the draft-verify engine
-    (``spec=SpecConfig(k=...)``) against the plain slot-pool engine on
-    the SAME stream of repetitive continuations — prompt-lookup's
-    target case (templated/self-repetitive text: code edits, RAG,
-    form letters). The workload is built from the model itself: one
-    batched generate scans ``candidate_tokens`` single-token prompts,
-    the ``requests`` most lookup-predictable streams are selected, and
-    each request's prompt carries the stream's first ``warm_tokens``
-    generated tokens so decoding resumes mid-cycle (the drafter locks
-    on immediately — acceptance is reported, not assumed).
-
-    What the stage pins every round:
-
-    - **bit-identity**: spec-mode greedy streams must equal the plain
-      engine's token-for-token (the correctness contract);
-    - **decode tokens/s A/B** + speedup (CPU-lane gate: >= 1.3x at
-      this config; the 2-3x target belongs to the TPU lane, where the
-      (S, k+1) verify forward re-reads weights once instead of k+1
-      times per emitted token);
-    - **acceptance rate** and **mean accepted draft tokens per verify
-      step** — the two knobs the speedup decomposes into;
-    - the compile-count pin (ONE verify program).
-    """
-    import paddle_tpu as paddle
-    from paddle_tpu.models.llama import (LlamaForCausalLM,
-                                         llama_tiny_config)
-    from paddle_tpu.serving import (ContinuousBatchingEngine, Server,
-                                    SpecConfig, ngram_propose)
-
-    paddle.seed(0)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
-
-    # ONE batched generate over the candidate streams; predictability
-    # is scored over exactly the window the bench will decode
-    ids = np.tile(np.arange(candidate_tokens, dtype=np.int32)[:, None],
-                  (1, 24))
-    full = model.generate(paddle.to_tensor(ids),
-                          max_new_tokens=warm_tokens + max_new).numpy()
-    cut = 24 + warm_tokens
-
-    def lookup_score(row) -> float:
-        hist, gen = list(row[:cut]), row[cut:]
-        acc = i = 0
-        while i < len(gen):
-            prop = ngram_propose(np.asarray(hist), k, 4, 1)
-            a = 0
-            for j in range(prop.size):
-                if i + j < len(gen) and prop[j] == gen[i + j]:
-                    a += 1
-                else:
-                    break
-            for j in range(min(a + 1, len(gen) - i)):
-                hist.append(int(gen[i + j]))
-            acc += a
-            i += a + 1
-        return acc / max(len(gen), 1)
-
-    scores = np.asarray([lookup_score(full[v])
-                         for v in range(candidate_tokens)])
-    top = np.argsort(scores, kind="stable")[::-1][:requests]
-    prompts = [full[t][:cut].astype(np.int32) for t in top]
-    max_len = cut + max_new + 8
-
-    base = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=max_len,
-        decode_block=decode_block, prompt_buckets=(cut,))
-    spec = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=max_len,
-        decode_block=decode_block, prompt_buckets=(cut,),
-        spec=SpecConfig(k=k, ngram_max=4))
-
-    def run(engine):
-        engine.reset()
-        srv = Server(engine)
-        rids = [srv.submit(p, max_new_tokens=max_new) for p in prompts]
-        t0 = time.perf_counter()
-        res = srv.run_until_idle()
-        return [res[r] for r in rids], time.perf_counter() - t0
-
-    run(base), run(spec)                    # compile warmup
-    ref, dt_base = run(base)
-    got, dt_spec = run(spec)
-    identical = all(np.array_equal(a, b) for a, b in zip(ref, got))
-
-    useful = requests * max_new
-    return {
-        "serving_spec_k": k,
-        "serving_spec_bit_identical": bool(identical),
-        "serving_spec_tokens_per_sec_baseline": round(useful / dt_base,
-                                                      1),
-        "serving_spec_tokens_per_sec": round(useful / dt_spec, 1),
-        "serving_spec_speedup": round(dt_base / dt_spec, 3),
-        "serving_spec_acceptance_rate": round(spec.acceptance_rate(),
-                                              4),
-        "serving_spec_mean_accepted_per_step": round(
-            spec.mean_accepted_per_step(), 3),
-        "serving_spec_tokens_per_step": round(
-            useful / max(spec.verify_steps, 1), 2),
-        "serving_spec_verify_steps": spec.verify_steps,
-        "serving_spec_workload_lookup_score": round(
-            float(scores[top].mean()), 3),
-        "serving_spec_decode_compiles": spec.decode_compile_count(),
-    }
-
-
-def run_serving_tp_bench(requests: int = 6, max_new: int = 16,
-                         num_slots: int = 2, decode_block: int = 4
-                         ) -> dict:
-    import jax
-
-    import paddle_tpu as paddle
-    from paddle_tpu.distributed.mesh import build_device_mesh
-    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
-    from paddle_tpu.observability import metrics
-    from paddle_tpu.serving import (ContinuousBatchingEngine, Server,
-                                    TPConfig)
-
-    n_dev = jax.device_count()
-    if n_dev < 2:
-        return {"serving_tp_devices": n_dev,
-                "serving_tp_skipped": "needs >= 2 devices "
-                "(simulated or real) to shard the decode block"}
-    # widest 2-level mesh the device count allows: 2 x (n/2) exercises
-    # the hierarchical inner/outer plan; an odd count falls back flat
-    if n_dev % 2 == 0:
-        mesh = build_device_mesh({"dp": 2, "mp": n_dev // 2})
-        axes = ("dp", "mp")
-    else:
-        mesh = build_device_mesh({"dp": 1, "mp": n_dev},
-                                 allow_subset=True)
-        axes = ("mp",)
-
-    paddle.seed(0)
-    cfg = LlamaConfig(
-        vocab_size=512, hidden_size=256, intermediate_size=768,
-        num_hidden_layers=4, num_attention_heads=8,
-        num_key_value_heads=8, max_position_embeddings=256)
-    model = LlamaForCausalLM(cfg)
-    rs = np.random.RandomState(0)
-    prompts = [rs.randint(0, cfg.vocab_size,
-                          (4 + (i % 3) * 6,)).astype(np.int32)
-               for i in range(requests)]
-
-    one = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=16 + max_new,
-        decode_block=decode_block, prompt_buckets=(16,))
-    tp = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=16 + max_new,
-        decode_block=decode_block, prompt_buckets=(16,),
-        tp=TPConfig(axes=axes, mesh=mesh))
-
-    def run(engine):
-        engine.reset()
-        srv = Server(engine)
-        rids = [srv.submit(p, max_new_tokens=max_new, arrival_step=i)
-                for i, p in enumerate(prompts)]
-        t0 = time.perf_counter()
-        res = srv.run_until_idle()
-        return [res[r] for r in rids], time.perf_counter() - t0
-
-    run(one), run(tp)                       # compile warmup
-    ref, dt_one = run(one)
-
-    prev_enabled = metrics.enabled()
-    metrics.enable(True)
-    try:
-        bytes_c = metrics.counter(
-            "pt_collectives_bytes_total",
-            "payload bytes handed to collectives",
-            labels=("op", "mode"))
-        calls_c = metrics.counter(
-            "pt_collectives_calls_total",
-            "host-level collective dispatches", labels=("op", "mode"))
-        b0 = bytes_c.value(op="tp_block", mode="tp_graph")
-        c0 = calls_c.value(op="tp_block", mode="tp_graph")
-        got, dt_tp = run(tp)
-        steps = tp.steps           # run() resets the engine counters
-        bytes_step = (bytes_c.value(op="tp_block", mode="tp_graph")
-                      - b0) / max(steps, 1)
-        calls_step = (calls_c.value(op="tp_block", mode="tp_graph")
-                      - c0) / max(steps, 1)
-    finally:
-        metrics.enable(prev_enabled)
-    identical = all(np.array_equal(a, b) for a, b in zip(ref, got))
-
-    # the int8 hop only exists in psum mode (exact mode has no
-    # reduction to compress) — one short stream + the runtime bound
-    p8 = ContinuousBatchingEngine(
-        model, num_slots=num_slots, max_len=16 + max_new,
-        decode_block=decode_block, prompt_buckets=(16,),
-        tp=TPConfig(axes=axes, mode="psum", int8=True, mesh=mesh))
-    s8 = Server(p8)
-    s8.submit(prompts[0], max_new_tokens=max_new)
-    s8.run_until_idle()
-    int8_bound = p8.tp_int8_error_bound()
-
-    useful = requests * max_new
-    return {
-        "serving_tp_devices": tp.tp_degree(),
-        "serving_tp_axes": "x".join(str(mesh.shape[a]) for a in axes),
-        "serving_tp_bit_identical": bool(identical),
-        "serving_tp_tokens_per_sec_1chip": round(useful / dt_one, 1),
-        "serving_tp_tokens_per_sec_mesh": round(useful / dt_tp, 1),
-        "serving_tp_collective_bytes_per_step": int(bytes_step),
-        "serving_tp_collective_calls_per_step": round(calls_step, 2),
-        "serving_tp_int8_error_bound": float(int8_bound),
-        "serving_tp_decode_compiles": tp.decode_compile_count(),
     }
 
 

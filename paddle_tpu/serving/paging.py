@@ -405,8 +405,7 @@ class PagedModelStepBackend(ModelStepBackend):
 
     def __init__(self, model, num_slots: int, max_len: int,
                  decode_block: int, block_size: int, num_blocks: int,
-                 kv_int8: bool, prefill_chunk: int, quant=None,
-                 fuse=None):
+                 kv_int8: bool, prefill_chunk: int, quant=None):
         from ..models.generation import (build_decode_step,
                                          forward_accepts_block_table,
                                          forward_accepts_pad)
@@ -449,15 +448,12 @@ class PagedModelStepBackend(ModelStepBackend):
         # are built (serving/quant.py)
         self._setup_weight_quant(model, quant)
         self._pure = self._maybe_quant_pure(self._pure)
-        self._resolve_fuse(fuse)
         self.decode_traces = [0]
         self.prefill_traces = [0]
-        # the decode block routes through the megakernel builder when
-        # armed; the chunked-prefill program stays unfused (s > 1 —
-        # compute-bound, outside the marked decode shape)
-        self._block_jit = self._block_jit_for(
+        self._block_jit = jax.jit(
             build_slot_block_fn(self._pure, decode_block,
-                                self.decode_traces, paged=True))
+                                self.decode_traces, paged=True),
+            donate_argnums=(2, 3))
         self._chunk_jit = jax.jit(
             build_paged_chunk_fn(self._pure, prefill_chunk,
                                  self.prefill_traces),
@@ -612,7 +608,7 @@ class PagedEngine(ContinuousBatchingEngine):
                  num_blocks: Optional[int] = None,
                  kv_int8: Optional[bool] = None,
                  prefill_chunk: Optional[int] = None,
-                 hash_fn=None, tp=None, quant=None, megakernel=None):
+                 hash_fn=None, tp=None, quant=None):
         if prompt_buckets is not None:
             raise ValueError(
                 "paged mode takes no prompt_buckets: prompts are "
@@ -625,8 +621,7 @@ class PagedEngine(ContinuousBatchingEngine):
                                        ("num_blocks", num_blocks),
                                        ("kv_int8", kv_int8),
                                        ("prefill_chunk", prefill_chunk),
-                                       ("quant", quant),
-                                       ("megakernel", megakernel))
+                                       ("quant", quant))
                      if v is not None}
             if given:
                 raise ValueError(
@@ -659,11 +654,6 @@ class PagedEngine(ContinuousBatchingEngine):
             tp_cfg = resolve_tp_config(tp)
             q_cfg = resolve_quant_config(quant)
             if tp_cfg is not None:
-                if megakernel:
-                    raise NotImplementedError(
-                        "megakernel decode is not yet composed with "
-                        "tensor-parallel serving — drop megakernel= or "
-                        "tp= (ROADMAP follow-up)")
                 # tensor-parallel paged serving: the shared KV arena
                 # shards its kv-head dim over the mesh (serving/tp.py);
                 # an explicit backend is never rerouted by the env flag
@@ -677,8 +667,7 @@ class PagedEngine(ContinuousBatchingEngine):
                 # verify-capable paged backend here (serving/spec.py)
                 backend = self._build_paged_backend(
                     model, num_slots, max_len, decode_block, block_size,
-                    num_blocks, bool(kv_int8), prefill_chunk, q_cfg,
-                    fuse=megakernel)
+                    num_blocks, bool(kv_int8), prefill_chunk, q_cfg)
         self.kv_block_size = backend.kv_block_size
         self.num_kv_blocks = backend.num_kv_blocks
         self.max_blocks = backend.max_blocks
@@ -691,11 +680,10 @@ class PagedEngine(ContinuousBatchingEngine):
 
     def _build_paged_backend(self, model, num_slots, max_len,
                              decode_block, block_size, num_blocks,
-                             kv_int8, prefill_chunk, quant=None,
-                             fuse=None):
+                             kv_int8, prefill_chunk, quant=None):
         return PagedModelStepBackend(
             model, num_slots, max_len, decode_block, block_size,
-            num_blocks, kv_int8, prefill_chunk, quant=quant, fuse=fuse)
+            num_blocks, kv_int8, prefill_chunk, quant=quant)
 
     # -- lifecycle ---------------------------------------------------------
     def reset(self):

@@ -64,12 +64,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
-from ..observability.tracing import span as _span
+from ..observability.tracing import named_program, span as _span
 from ..utils import faults
 from ..utils.flags import env_int
-from .engine import (ContinuousBatchingEngine, ModelStepBackend,
-                     _M_COMPILES, _M_DECODE_TOKENS, _M_STEPS, _M_TOKENS,
-                     slot_sample_logits)
+from .engine import (DECODE_PROGRAM, ContinuousBatchingEngine,
+                     ModelStepBackend, _M_COMPILES, _M_DECODE_TOKENS,
+                     _M_STEPS, _M_TOKENS, slot_sample_logits)
 from .paging import PagedEngine, PagedModelStepBackend
 
 __all__ = ["SpecConfig", "resolve_spec_config", "ngram_propose",
@@ -279,7 +279,8 @@ def build_spec_block_fn(pure, k: int, trace_counter=None,
                    live=live & (rem > 0))
         return cf, st2, t, n_emit, ok
 
-    return block_fn
+    # the verify step IS this engine's decode program: same trace name
+    return named_program(block_fn, DECODE_PROGRAM)
 
 
 # ---------------------------------------------------------------------------
@@ -317,10 +318,9 @@ class SpecModelStepBackend(_SpecBackendMixin, ModelStepBackend):
     """Dense slot-pool backend with the (S, k+1) verify program."""
 
     def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, spec: SpecConfig, quant=None,
-                 fuse=None):
+                 decode_block: int, spec: SpecConfig, quant=None):
         super().__init__(model, num_slots, max_len, decode_block,
-                         quant=quant, fuse=fuse)
+                         quant=quant)
         self._setup_spec(model, spec, paged=False)
 
 
@@ -331,10 +331,10 @@ class SpecPagedStepBackend(_SpecBackendMixin, PagedModelStepBackend):
     def __init__(self, model, num_slots: int, max_len: int,
                  decode_block: int, block_size: int, num_blocks: int,
                  kv_int8: bool, prefill_chunk: int, spec: SpecConfig,
-                 quant=None, fuse=None):
+                 quant=None):
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
-                         quant=quant, fuse=fuse)
+                         quant=quant)
         self._setup_spec(model, spec, paged=True)
 
 
@@ -538,7 +538,7 @@ class SpecEngine(_SpecEngineMixin, ContinuousBatchingEngine):
                  max_len: int = 256, decode_block: int = 8,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  backend=None, *, paged: Optional[bool] = None,
-                 spec=None, tp=None, quant=None, megakernel=None):
+                 spec=None, tp=None, quant=None):
         if paged:
             # same loud-refusal rule as spec= on a direct subclass
             # ctor: silently serving DENSE from a paged= request would
@@ -550,13 +550,12 @@ class SpecEngine(_SpecEngineMixin, ContinuousBatchingEngine):
         self._init_spec(spec, backend, tp)
         super().__init__(model, num_slots, max_len, decode_block,
                          prompt_buckets, backend, paged=False,
-                         quant=quant, megakernel=megakernel)
+                         quant=quant)
 
     def _build_backend(self, model, num_slots, max_len, decode_block,
-                       quant=None, fuse=None):
+                       quant=None):
         return SpecModelStepBackend(model, num_slots, max_len,
-                                    decode_block, self.spec,
-                                    quant=quant, fuse=fuse)
+                                    decode_block, self.spec, quant=quant)
 
 
 class SpecPagedEngine(_SpecEngineMixin, PagedEngine):
@@ -574,7 +573,7 @@ class SpecPagedEngine(_SpecEngineMixin, PagedEngine):
                  num_blocks: Optional[int] = None,
                  kv_int8: Optional[bool] = None,
                  prefill_chunk: Optional[int] = None,
-                 hash_fn=None, tp=None, quant=None, megakernel=None):
+                 hash_fn=None, tp=None, quant=None):
         if paged is not None and not paged:
             raise ValueError(
                 "SpecPagedEngine is the paged speculative engine — use "
@@ -585,14 +584,12 @@ class SpecPagedEngine(_SpecEngineMixin, PagedEngine):
                          prompt_buckets, backend, paged=True,
                          block_size=block_size, num_blocks=num_blocks,
                          kv_int8=kv_int8, prefill_chunk=prefill_chunk,
-                         hash_fn=hash_fn, quant=quant,
-                         megakernel=megakernel)
+                         hash_fn=hash_fn, quant=quant)
 
     def _build_paged_backend(self, model, num_slots, max_len,
                              decode_block, block_size, num_blocks,
-                             kv_int8, prefill_chunk, quant=None,
-                             fuse=None):
+                             kv_int8, prefill_chunk, quant=None):
         return SpecPagedStepBackend(model, num_slots, max_len,
                                     decode_block, block_size,
                                     num_blocks, kv_int8, prefill_chunk,
-                                    self.spec, quant=quant, fuse=fuse)
+                                    self.spec, quant=quant)

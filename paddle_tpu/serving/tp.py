@@ -50,6 +50,7 @@ backend is never rerouted by the env flags.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -342,6 +343,9 @@ class _TPBackendMixin:
     def _shard_jit(self, fn, in_specs, out_specs, donate=()):
         spec = self._tp_spec
 
+        # the sharded program keeps ``fn``'s name: the benchmark reads
+        # device time by ``engine.DECODE_PROGRAM`` whatever the backend
+        @functools.wraps(fn)
         def tp_fn(*args):
             with tp_hooks.active(spec):
                 return fn(*args)
@@ -450,12 +454,8 @@ class ShardedModelStepBackend(_TPBackendMixin, ModelStepBackend):
 
     def __init__(self, model, num_slots: int, max_len: int,
                  decode_block: int, tp: TPConfig, quant=None):
-        # fuse=False, not env-resolved: the sharded shard_map programs
-        # below replace the base decode block, and the megakernel pass
-        # is not yet composed with TP (the engine factory refuses
-        # megakernel= + tp= loudly; the env knob must not half-arm it)
         super().__init__(model, num_slots, max_len, decode_block,
-                         quant=quant, fuse=False)
+                         quant=quant)
         self._setup_tp(model, tp)
         # local-shape row specs: the prefill program zero-fills its
         # fresh cache row INSIDE shard_map, where shapes are per-device
@@ -511,10 +511,9 @@ class ShardedPagedStepBackend(_TPBackendMixin, PagedModelStepBackend):
                  kv_int8: bool, prefill_chunk: int, tp: TPConfig,
                  quant=None):
         from .engine import build_paged_chunk_fn
-        # fuse=False for the same reason as the dense sharded backend
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
-                         quant=quant, fuse=False)
+                         quant=quant)
         self._setup_tp(model, tp)
         self._block_jit = self._shard_jit(
             build_slot_block_fn(self._pure, self.block_size,

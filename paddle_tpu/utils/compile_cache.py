@@ -1,10 +1,10 @@
 """Where JAX's persistent compilation cache lives.
 
-One rule for every entry point (chip_smoke.py, bench.py, the measurement
-tools, tests/conftest.py): when ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
-reads it itself and nothing here sets a directory; otherwise the cache is
-one fixed git-ignored directory inside the checkout. The directory is part
-of the cache key, so it must not move between runs.
+One rule for every entry point (chip_smoke.py, benchmark/run.py, the
+measurement tools, tests/conftest.py): when ``JAX_COMPILATION_CACHE_DIR``
+is set, JAX reads it itself and nothing here sets a directory; otherwise
+the cache is one fixed git-ignored directory inside the checkout. The
+directory is part of the cache key, so it must not move between runs.
 """
 from __future__ import annotations
 

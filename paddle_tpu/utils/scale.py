@@ -9,8 +9,8 @@ with every Parameter backed by a ``jax.ShapeDtypeStruct`` (no host
 memory), attach NamedShardings for the target mesh, AOT-lower + compile
 the full fused train step over a virtual device mesh, and read
 ``memory_analysis()`` / ``cost_analysis()`` — the compiler's per-device
-peak-memory estimate for hardware we don't have attached. Used by
-``scale_check.py`` to validate Llama-13B TP×PP on a virtual v5p-32."""
+peak-memory estimate for hardware we don't have attached (an
+estimate: the chip has measured 1-2 GiB under it, PERF.md)."""
 from __future__ import annotations
 
 import contextlib

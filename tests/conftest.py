@@ -32,7 +32,7 @@ os.environ.setdefault("TRANSFORMERS_NO_ADVISORY_WARNINGS", "1")
 
 # autotune isolation: kernels consult the block-size tuning table at
 # trace time (ops/pallas/autotune.py), so ANY reachable table — the
-# default ~/.cache path (e.g. written by bench.py's autotune stage) OR
+# default ~/.cache path (e.g. written by autotune.run_autotune) OR
 # an inherited PT_TUNE_TABLE export — would make block choices, and
 # therefore compiled programs and timing-sensitive pins,
 # machine-dependent. Pin the suite unconditionally to a path that never
@@ -64,6 +64,41 @@ def _seed():
     paddle.seed(2024)
     np.random.seed(2024)
     yield
+
+
+_SOAK_CHILD = """
+import json, sys
+import jax
+jax.config.update("jax_enable_compilation_cache", False)
+from paddle_tpu.serving import microbench
+out = getattr(microbench, sys.argv[1])(**json.loads(sys.argv[2]))
+print("SOAK_JSON " + json.dumps(out))
+"""
+
+
+@pytest.fixture
+def run_soak():
+    """Run one entry point of ``serving/microbench.py`` the way the
+    ``tools/*.sh`` that drive it do: a plain child process with the
+    persistent compile cache off (the soaks build fresh paged backends
+    beside each other — tests/test_resilience.py ``_no_compile_cache``
+    says why that stays out of a pytest process). Returns its dict."""
+    import json
+    import subprocess
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def run(name, **kw):
+        p = subprocess.run(
+            [sys.executable, "-c", _SOAK_CHILD, name, json.dumps(kw)],
+            capture_output=True, text=True, timeout=600, cwd=root,
+            env=dict(os.environ, PYTHONPATH=root))
+        lines = [l for l in p.stdout.splitlines()
+                 if l.startswith("SOAK_JSON ")]
+        assert p.returncode == 0 and lines, p.stdout[-2000:] + \
+            p.stderr[-3000:]
+        return json.loads(lines[-1][len("SOAK_JSON "):])
+    return run
 
 
 def pytest_configure(config):

@@ -666,3 +666,20 @@ class TestAutoscaleKillBurst:
             lambda: ContinuousBatchingEngine(model, paged=True,
                                              kv_int8=True, **self.KW),
             seed=1, base_rate=0.5, burst_mult=6.0)
+
+
+def test_autoscale_soak_entry_point_runs_and_reports(run_soak):
+    """``tools/autoscale_soak.sh`` at its smallest: one burst with a kill
+    inside it over three fleets, and every key the script asserts on."""
+    out = run_soak("run_serving_autoscale_bench", horizon=12, max_new=4)
+    assert out["serving_autoscale_completed"] \
+        + out["serving_autoscale_failed"] \
+        == out["serving_autoscale_requests"] > 0
+    assert out["serving_autoscale_bit_identical_vs_peak"]
+    assert out["serving_autoscale_greedy_matches_generate"]
+    assert out["serving_autoscale_returned_to_min"]
+    assert out["serving_autoscale_decode_compiles"] == 1
+    assert out["serving_autoscale_leaks"] == 0
+    assert out["serving_autoscale_scale_ups"] >= 1
+    assert {"serving_autoscale_scale_downs", "serving_autoscale_peak_size",
+            "serving_autoscale_end_size"} <= set(out)

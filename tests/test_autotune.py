@@ -131,18 +131,6 @@ class TestConsumers:
         assert _block_pref("PT_SPLASH_BLOCK", "splash", 1024, 128) == \
             (0, "env")
 
-    def test_megakernel_ff_chunk_consults_table(self, table):
-        from paddle_tpu.ops.pallas.decode_layer import _tuned_ff_chunk
-        assert _tuned_ff_chunk(256, 768) == 768          # whole (default)
-        at.record("decode_layer", {"d": 256, "ff": 768},
-                  {"ff_chunk": 384}, 1.0)
-        # 384 is not 128-aligned-dividing? 768 % 384 == 0 and 384 % 128
-        # == 0 -> accepted
-        assert _tuned_ff_chunk(256, 768) == 384
-        at.record("decode_layer", {"d": 256, "ff": 768},
-                  {"ff_chunk": 200}, 1.0)     # misaligned: ignored
-        assert _tuned_ff_chunk(256, 768) == 768
-
 
 class TestSweep:
     def test_xent_sweep_records_and_is_consulted(self, table):

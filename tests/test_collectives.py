@@ -20,7 +20,7 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed import collectives as cc
 from paddle_tpu.distributed.collectives import (
     BucketedGradSync, CollectiveConfig, build_buckets, configure,
-    int8_error_bound, plan_hierarchy, run_comms_bench)
+    int8_error_bound, plan_hierarchy)
 from paddle_tpu.distributed.mesh import build_device_mesh
 
 pytestmark = pytest.mark.skipif(
@@ -425,21 +425,6 @@ class TestConfig:
         with configure(compress="int8"):
             assert cc.collective_config().compress == "int8"
         assert cc.collective_config().compress == base
-
-
-class TestMicrobench:
-    def test_reports_bytes_bandwidth_and_error(self, mesh):
-        out = run_comms_bench(size_mb=0.1, iters=1, mesh=mesh)
-        assert out["devices"] == 8 and out["mode"] == "hierarchical"
-        for op in ("all_reduce", "reduce_scatter", "all_gather",
-                   "all_reduce_int8"):
-            assert out[op]["bytes_moved"] > 0
-            assert out[op]["algbw_gbps"] > 0
-            assert out[op]["time_ms"] > 0
-        q = out["all_reduce_int8"]
-        assert q["within_bound"] and q["constant_exact"]
-        assert q["max_error"] == out["quant_vs_fp32_max_error"]
-        assert q["bytes_moved"] < out["all_reduce"]["bytes_moved"]
 
 
 class TestProfilerSpans:

@@ -653,3 +653,17 @@ class TestMetricsCatalog:
                      "pt_prefix_spill_hits_total",
                      "pt_prefix_spill_misses_total"):
             assert name in fams, name
+
+
+def test_recovery_soak_entry_point_runs_and_reports(run_soak):
+    """``tools/recovery_soak.sh`` at its smallest: checkpoint, crash,
+    ``Fleet.recover``, and every key the script asserts on or prints."""
+    out = run_soak("run_serving_recovery_bench", requests=2, max_new=4)
+    assert out["serving_recovery_completed"] \
+        == out["serving_recovery_requests"] == 2
+    assert out["serving_recovery_bit_identical"]
+    assert out["serving_recovery_decode_compiles"] == 1
+    assert out["serving_recovery_leaks"] == 0
+    assert out["serving_recovery_journal_replayed"] >= 1
+    assert {"serving_recovery_redriven",
+            "serving_recovery_recover_wall_s"} <= set(out)

@@ -556,3 +556,16 @@ class TestRedriveBitIdentity:
         res = fleet.run_until_idle(max_ticks=100)
         np.testing.assert_array_equal(
             res[rid], _ref(model, p, 6, temperature=0.0))
+
+
+def test_kill_soak_entry_point_runs_and_reports(run_soak):
+    """``tools/chaos.sh``'s first phase at its smallest: the entry point
+    imports, kills its worker, asserts bit-identity and zero leaks
+    inside, and returns the keys the script prints."""
+    out = run_soak("run_fleet_kill_soak", kills=1, requests=3, max_new=8)
+    assert out["soak_completed"] + out["soak_failed"] \
+        == out["soak_requests"] == 3
+    assert (out["soak_kills"], out["soak_workers_lost"],
+            out["soak_leaks"]) == (1, 1, 0)
+    assert {"soak_seed", "soak_redrives", "soak_duplicate_adopts",
+            "soak_transport", "soak_ticks"} <= set(out)

@@ -8,9 +8,17 @@ that
 - the interpret-mode pallas tests declare the pallas import guard so
   they SKIP (not error) on builds without Pallas;
 - on the CPU lane the paged read takes the bit-identical reference
-  path, never the kernel.
+  path, never the kernel;
+- the documents say what the tree holds: every ``PT_*`` name the program
+  reads from the environment has its row in ONE table of
+  ``docs/architecture.md``, and every repo path the documents write in
+  backticks exists.
 """
+import ast
+import fnmatch
+import glob
 import os
+import re
 import subprocess
 import sys
 
@@ -24,7 +32,7 @@ GUARDED_FILES = ["tests/test_serving_paged.py", "tests/test_serving.py",
                  "tests/test_serving_tp.py", "tests/test_serving_spec.py",
                  "tests/test_serving_quant.py",
                  "tests/test_sparse_quant.py",
-                 "tests/test_megakernel.py", "tests/test_autotune.py",
+                 "tests/test_autotune.py",
                  "tests/test_frontend.py", "tests/test_fleet.py",
                  "tests/test_fleet_failover.py",
                  "tests/test_prefix_cache.py",
@@ -115,20 +123,15 @@ REQUIRED_NODES = [
     "test_env_flag_never_reroutes_explicit_backend",
     "test_sparse_quant.py::TestWeightOnlyQuant::"
     "test_grouped_roundtrip_and_linear",
-    # PR 12 megakernel + autotuner pins: the fused-vs-unfused
-    # composition matrix (paged+kv_int8 is the flagship), the
-    # no-hidden-state-transient jaxpr walk, the interpret-mode
-    # megakernel parity, the impostor-marker soundness pin, and the
-    # autotune staleness/consumer contracts
-    "test_megakernel.py::TestFusedBitParity::test_paged_kv_int8",
-    "test_megakernel.py::TestFusedBitParity::test_quant_int8_paged",
-    "test_megakernel.py::TestFusedBitParity::test_spec_k8_paged",
-    "test_megakernel.py::TestNoTransientWalk::"
-    "test_fused_program_holds_no_hidden_state_interior",
-    "test_megakernel.py::TestMegaKernelInterpret::"
-    "test_kernel_matches_reference[paged_int8]",
-    "test_megakernel.py::TestDecodeFusionPass::"
-    "test_impostor_marker_left_unfused",
+    # the six compositions the engine factory builds, over one stream
+    # (PR 29: the safety net under the dense/paged/spec class fork)
+    "test_serving_quant.py::"
+    "test_every_composition_serves_the_same_stream[dense]",
+    "test_serving_quant.py::"
+    "test_every_composition_serves_the_same_stream[paged-kv_int8-w_int8]",
+    "test_serving_quant.py::"
+    "test_every_composition_serves_the_same_stream[paged-spec_k8]",
+    # PR 12 autotuner pins: the staleness/consumer contracts
     "test_autotune.py::TestTable::test_stale_stamp_refused_and_warned",
     "test_autotune.py::TestConsumers::"
     "test_xent_chunk_default_unchanged_without_table",
@@ -290,3 +293,95 @@ def test_cpu_lane_never_dispatches_paged_kernel():
         return                       # on-hardware lane: kernel allowed
     assert not fused._FORCE_INTERPRET     # test isolation sanity
     assert not _kernel_ok(jnp.zeros((4, 8, 2, 16), jnp.float32))
+
+
+# -- the documents against the tree ------------------------------------------
+
+def _read(*parts) -> str:
+    with open(os.path.join(ROOT, *parts)) as f:
+        return f.read()
+
+
+def test_every_env_knob_has_its_row_in_the_one_table():
+    """The ``PT_*`` names in ``paddle_tpu/``'s source (whole string
+    constants: what ``utils.flags.env_*`` and ``os.environ`` are handed,
+    directly or through a helper; metric and counter names are lower
+    case) are the first column of the table under "Environment knobs" in
+    docs/architecture.md, no more and no fewer."""
+    read = set()
+    for path in glob.glob(os.path.join(ROOT, "paddle_tpu", "**", "*.py"),
+                          recursive=True):
+        for node in ast.walk(ast.parse(_read(path))):
+            if isinstance(node, ast.Constant) and isinstance(node.value, str) \
+                    and re.fullmatch(r"PT_[A-Z0-9_]+", node.value):
+                read.add(node.value)
+    doc = _read("docs", "architecture.md")
+    section = doc.split("\n## Environment knobs\n")[1].split("\n## ")[0]
+    rows = re.findall(r"^\| `(PT_[A-Z0-9_]+)` \|", section, re.M)
+    assert len(rows) == len(set(rows)), "a knob has two rows"
+    assert set(rows) == read, (
+        f"read but not in the table: {sorted(read - set(rows))}; "
+        f"in the table but not read: {sorted(set(rows) - read)}")
+    assert len(read) > 40                  # the scan still finds them
+
+
+_DOC_FILE = (".py", ".md", ".sh", ".json", ".jsonl", ".txt", ".cc")
+
+
+def _tree_files() -> list:
+    """What git would commit, near enough: no hidden or cache directory,
+    and no top-level ``_scratch`` copy (.gitignore lists them)."""
+    out = []
+    for base, dirs, files in os.walk(ROOT):
+        top = base == ROOT
+        dirs[:] = [d for d in dirs
+                   if not d.startswith(".") and d != "__pycache__"
+                   and d != "chiprun_out" and not (top and d.startswith("_"))]
+        out += [os.path.relpath(os.path.join(base, f), ROOT) for f in files]
+    return out
+
+
+def _expand(word: str) -> list:
+    m = re.search(r"\{([^{}]*,[^{}]*)\}", word)
+    if not m:
+        return [word]
+    return [w for alt in m.group(1).split(",")
+            for w in _expand(word[:m.start()] + alt + word[m.end():])]
+
+
+def _doc_paths(text: str):
+    """File paths inside backticks: a word that ends in a source or
+    record suffix, line numbers stripped, brace lists expanded. Words
+    with a placeholder (``<cell>``, ``{epoch}``, ``...``), a home or
+    absolute path, or an option are not the tree's."""
+    for span in re.findall(r"`([^`\n]+)`", text):
+        for word in span.split():
+            word = re.sub(r":\d+(-\d+)?$", "", word.strip(".,;:()"))
+            if not word.endswith(_DOC_FILE) or "..." in word \
+                    or re.search(r"[<>$~=]|^[/-]", word) \
+                    or not re.fullmatch(r"[\w.{},*/-]+", word):
+                continue
+            yield from (w for w in _expand(word) if "{" not in w)
+
+
+def test_every_repo_path_the_documents_name_exists():
+    """README.md, docs/architecture.md and PERF.md section 3 (the layer
+    map): a path in backticks is a file of the tree, by its whole path or
+    by the tail the prose shortens it to (``serving/paging.py``,
+    ``test_sot.py``); a glob matches at least one."""
+    files = _tree_files()
+    perf = _read("PERF.md")
+    docs = {"README.md": _read("README.md"),
+            "docs/architecture.md": _read("docs", "architecture.md"),
+            "PERF.md section 3": perf[perf.index("\n## 3"):
+                                      perf.index("\n## 4")]}
+    missing, checked = [], 0
+    for name, text in docs.items():
+        for path in sorted(set(_doc_paths(text))):
+            checked += 1
+            if not any(fnmatch.fnmatchcase(f, path)
+                       or fnmatch.fnmatchcase(f, "*/" + path)
+                       for f in files):
+                missing.append(f"{name}: {path}")
+    assert not missing, missing
+    assert checked > 100                   # the scan still finds them

@@ -1,6 +1,6 @@
 """Abstract (weight-free) AOT scale-check machinery (VERDICT r1 #4:
-13B readiness without hardware). scale_check.py runs the real 13B
-config; here the same path is validated at tiny size on 8 devices."""
+13B readiness without hardware): utils/scale.py validated at tiny size
+on 8 devices."""
 import numpy as np
 import jax
 import jax.numpy as jnp

@@ -12,6 +12,9 @@ ops/pallas/paged_attention.py int8 in-read dequant):
   generate() on a host-dequantized twin model (the in-graph dequant is
   exact), composing with paged/kv_int8/spec, with the
   runtime-queryable error bounds and registry bytes accounting;
+- the six compositions the engine factory builds (dense, paged, int8
+  arena, int8/int4 weights, speculative) over ONE ragged stream, each
+  against its reference with one decode compile;
 - the routing matrix: explicit backends never rerouted by
   PT_SERVING_QUANT_WEIGHTS, quant= alongside an explicit backend /
   bogus configs / psum+quant refused loudly.
@@ -28,35 +31,42 @@ from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.nn.quant import dequantize_array, quantize_array
 from paddle_tpu.serving import (ContinuousBatchingEngine, PagedEngine,
                                 QuantConfig, Scheduler, Server,
-                                SpecConfig, SpecEngine)
+                                SpecConfig, SpecEngine, SpecPagedEngine)
 from paddle_tpu.serving.quant import resolve_quant_config
 
 _QUANT_PATTERNS = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj",
                    "up_proj", "down_proj", "lm_head")
 
 
-@pytest.fixture(scope="module")
-def setup():
-    """One model + its host-dequantized int8 twin for the whole file.
-    The twin is THE oracle: the engine's in-graph dequant must make
-    quantized serving bit-identical to generate() on the twin."""
-    paddle.seed(0)
-    cfg = llama_tiny_config(tensor_parallel=False)
-    model = LlamaForCausalLM(cfg)
+def _dequant_twin(model, cfg, bits, group_size=-1):
+    """``model`` with every served weight sent through the host's
+    quantize -> dequantize: THE oracle, since the engine's in-graph
+    dequant must make quantized serving bit-identical to generate() on
+    it."""
     twin = LlamaForCausalLM(cfg)
     for (n, p), (_, tp_) in zip(model.named_parameters(),
                                 twin.named_parameters()):
         v = p._value
         if v.ndim == 2 and any(s in n for s in _QUANT_PATTERNS):
-            codes, scales = quantize_array(v, 8, -1)
-            tp_._value = dequantize_array(codes, scales, 8,
+            codes, scales = quantize_array(v, bits, group_size)
+            tp_._value = dequantize_array(codes, scales, bits,
+                                          in_features=int(v.shape[0]),
                                           out_dtype=v.dtype)
         else:
             tp_._value = v
     for (_, b), (_, tb) in zip(model.named_buffers(),
                                twin.named_buffers()):
         tb._value = b._value
-    return model, twin, cfg
+    return twin
+
+
+@pytest.fixture(scope="module")
+def setup():
+    """One model + its host-dequantized int8 twin for the whole file."""
+    paddle.seed(0)
+    cfg = llama_tiny_config(tensor_parallel=False)
+    model = LlamaForCausalLM(cfg)
+    return model, _dequant_twin(model, cfg, 8), cfg
 
 
 def _ref(model, prompt, max_new, **kw):
@@ -300,20 +310,7 @@ class TestWeightOnlyServing:
         recipe, and the int4 bound is looser than int8's."""
         model, _, cfg = setup
         gcfg = QuantConfig(weights="int4", group_size=32)
-        twin4 = LlamaForCausalLM(cfg)
-        for (n, p), (_, t4) in zip(model.named_parameters(),
-                                   twin4.named_parameters()):
-            v = p._value
-            if v.ndim == 2 and any(s in n for s in _QUANT_PATTERNS):
-                c, s = quantize_array(v, 4, 32)
-                t4._value = dequantize_array(c, s, 4,
-                                             in_features=int(v.shape[0]),
-                                             out_dtype=v.dtype)
-            else:
-                t4._value = v
-        for (_, b), (_, tb) in zip(model.named_buffers(),
-                                   twin4.named_buffers()):
-            tb._value = b._value
+        twin4 = _dequant_twin(model, cfg, 4, 32)
         eng = ContinuousBatchingEngine(
             model, num_slots=2, max_len=64, decode_block=4, quant=gcfg)
         prompts = _prompts(cfg, 8, (5, 9))
@@ -398,6 +395,64 @@ class TestWeightOnlyServing:
                                    in_features=meta.in_features)
             err = float(jnp.max(jnp.abs(deq - named[i][1]._value)))
             assert err <= bound + 1e-7
+
+
+# ---------------------------------------------------------------------------
+# every composition of the engine factory over one stream
+# ---------------------------------------------------------------------------
+
+_DENSE = dict(num_slots=2, max_len=64, decode_block=4, prompt_buckets=(16,))
+_PAGED = dict(num_slots=2, max_len=64, decode_block=4, paged=True,
+              block_size=8, prefill_chunk=8)
+# name -> (factory arguments, the class the factory must build, bits of the
+# dequantized twin generate() runs on or None for the model itself)
+COMPOSITIONS = {
+    "dense": (_DENSE, ContinuousBatchingEngine, None),
+    "paged": (_PAGED, PagedEngine, None),
+    "paged-kv_int8": (dict(_PAGED, kv_int8=True), PagedEngine, None),
+    "paged-kv_int8-w_int8": (dict(_PAGED, kv_int8=True,
+                                  quant=QuantConfig(weights="int8")),
+                             PagedEngine, 8),
+    "dense-w_int4": (dict(_DENSE, quant=QuantConfig(weights="int4")),
+                     ContinuousBatchingEngine, 4),
+    "paged-spec_k8": (dict(_PAGED, max_len=96, spec=SpecConfig(k=8)),
+                      SpecPagedEngine, None),
+}
+
+
+@pytest.mark.parametrize("name", list(COMPOSITIONS))
+def test_every_composition_serves_the_same_stream(setup, name, monkeypatch):
+    """The ONE test that takes every composition the
+    ``ContinuousBatchingEngine`` factory builds over the same ragged
+    5 / 9 / 12 stream: the factory builds the class it should, the decode
+    program compiles once, and the tokens are the reference's. Lossless
+    compositions (and weight-only quant against its dequantized twin)
+    equal per-request ``generate()`` bit for bit; an int8 arena is lossy,
+    so its stream is held to the same composition read through the
+    dequant-then-dense oracle, token for token."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    model, twin8, cfg = setup
+    kw, cls, bits = COMPOSITIONS[name]
+    eng = ContinuousBatchingEngine(model, **kw)
+    assert type(eng) is cls
+    prompts = _prompts(cfg, 1, (5, 9, 12))
+    got = _stream(eng, prompts, max_new=5)
+    assert eng.decode_compile_count() == 1
+    if kw.get("kv_int8"):
+        assert eng.kv_error_bound() > 0.0
+        monkeypatch.setattr(pa, "_FORCE_INT8_REFERENCE", True)
+        ref = _stream(ContinuousBatchingEngine(model, **kw), prompts,
+                      max_new=5)
+    else:
+        oracle = model if bits is None else twin8 if bits == 8 \
+            else _dequant_twin(model, cfg, bits)
+        ref = [_ref(oracle, p, 5, temperature=0.0) for p in prompts]
+    for p, a, b in zip(prompts, got, ref):
+        assert a.shape == (len(p) + 5,)
+        np.testing.assert_array_equal(a, b)
+    if kw.get("paged"):
+        assert eng.prefill_compile_count() == 1
+        eng.manager.assert_consistent()
 
 
 # ---------------------------------------------------------------------------

@@ -21,6 +21,7 @@ from paddle_tpu.ops.pallas import fused
 from paddle_tpu.ops.pallas import moe_dispatch as md
 from paddle_tpu.ops.pallas import paged_attention as pa
 from paddle_tpu.ops.pallas import xent
+from paddle_tpu.serving.quant import QuantConfig
 
 
 @pytest.fixture
@@ -85,17 +86,6 @@ def _flash_fwd_bwd():
         argnums=(0, 1, 2)))(q, q, q)
 
 
-def _decode_layer():
-    import test_megakernel as mk
-    from paddle_tpu.ops.pallas import decode_layer as dl
-    x, cos, sin, eps1, eps2, *rest = \
-        mk.TestMegaKernelInterpret()._args("paged")
-    return jax.make_jaxpr(lambda x, cos, sin, *rest:
-                          dl.decode_layer_paged_kernel(
-                              "paged", x, cos, sin, eps1, eps2, *rest))(
-        x, cos, sin, *rest)
-
-
 KERNELS = {
     "paged_attention_decode": (_paged, ["paged_attention_decode"]),
     "paged_attention_decode_int8":
@@ -127,7 +117,6 @@ KERNELS = {
     "moe_gather_rows_mr": (lambda: jax.make_jaxpr(
         lambda x, i: md._gather_rows_pallas_mr(x, i, rows_per_step=4))(
             f32(16, 128), i32(8)), ["moe_gather_rows_mr"]),
-    "decode_layer": (_decode_layer, ["decode_layer"]),
 }
 
 
@@ -154,7 +143,7 @@ def test_every_pallas_call_site_passes_a_name():
             assert "name=" in src[m.end():i], \
                 f"{os.path.basename(path)}: pallas_call without name="
             sites += 1
-    assert sites >= 11
+    assert sites >= 10
 
 
 # -- the three program names the benchmark reads ---------------------------
@@ -169,33 +158,87 @@ def _module_name(lowered) -> str:
     return re.search(r"module @(\S+)", lowered.as_text()).group(1)
 
 
-def _paged_backend(model):
+def _paged_backend(model, kv_int8=False, **kw):
     from paddle_tpu.serving.paging import PagedModelStepBackend
     return PagedModelStepBackend(model, 2, 64, decode_block=4,
-                                 block_size=8, num_blocks=17, kv_int8=False,
-                                 prefill_chunk=8)
+                                 block_size=8, num_blocks=17,
+                                 kv_int8=kv_int8, prefill_chunk=8, **kw)
 
 
 def _lower_block(be):
-    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
-    return be._block_jit.lower(be._pv, be._bv, cache, be.init_state())
+    return be._block_jit.lower(be._pv, be._bv, be.pool_cache(),
+                               be.init_state())
 
 
 def _lower_chunk(be):
-    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
     return be._chunk_jit.lower(
-        be._pv, be._bv, i32(1, 8), cache, i32(1, be.max_blocks),
+        be._pv, be._bv, i32(1, 8), be.pool_cache(), i32(1, be.max_blocks),
         jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
         jnp.float32(0), jnp.int32(0), jnp.float32(1))
 
 
-def test_decode_and_prefill_programs_are_named_on_purpose(tiny):
+def _dense(model):
+    from paddle_tpu.serving.engine import ModelStepBackend
+    return _lower_block(ModelStepBackend(model, 2, 64, decode_block=4)), None
+
+
+def _paged_programs(**kw):
+    def build(model):
+        be = _paged_backend(model, **kw)
+        return _lower_block(be), _lower_chunk(be)
+    return build
+
+
+def _spec_verify(model):
+    from paddle_tpu.serving.spec import SpecConfig, SpecPagedStepBackend
+    be = SpecPagedStepBackend(model, 2, 64, 4, 8, 17, False, 8,
+                              SpecConfig(k=4))
+    return be._spec_jit.lower(be._pv, be._bv, be.pool_cache(),
+                              be.init_state(), i32(2, 4), i32(2)), \
+        _lower_chunk(be)
+
+
+def _tp_sharded(model):
+    """The shard_map programs over the suite's 8 virtual devices: their
+    own model (the mesh shards 8 kv heads)."""
+    from paddle_tpu.distributed.mesh import build_device_mesh
+    from paddle_tpu.serving.tp import ShardedPagedStepBackend, TPConfig
+    if jax.device_count() < 8:
+        pytest.skip("needs 8 (simulated) devices for the 2x4 mesh")
+    paddle.seed(0)
+    model = LlamaForCausalLM(llama_tiny_config(num_attention_heads=8,
+                                               num_key_value_heads=8))
+    be = ShardedPagedStepBackend(
+        model, 2, 64, 4, 8, 17, False, 8,
+        TPConfig(axes=("dp", "mp"),
+                 mesh=build_device_mesh({"dp": 2, "mp": 4})))
+    return _lower_block(be), _lower_chunk(be)
+
+
+DECODE_BUILDERS = {
+    "dense": _dense,
+    "paged": _paged_programs(),
+    "paged-kv_int8": _paged_programs(kv_int8=True),
+    "weight-quant": _paged_programs(quant=QuantConfig(weights="int8")),
+    "spec-verify": _spec_verify,
+    "tp-sharded": _tp_sharded,
+}
+
+
+@pytest.mark.parametrize("builder", list(DECODE_BUILDERS))
+def test_decode_and_prefill_programs_are_named_on_purpose(tiny, builder):
+    """EVERY backend that builds a decode-block program names it
+    ``engine.DECODE_PROGRAM`` (the speculative engine's verify step and
+    the sharded block included), and its chunk program
+    ``engine.PREFILL_CHUNK_PROGRAM``: the benchmark's ``decode_step_ms``
+    sums device time by that name, and reads 0 for any other."""
     from paddle_tpu.serving import engine
     assert (engine.DECODE_PROGRAM, engine.PREFILL_CHUNK_PROGRAM) \
         == ("jit_block_fn", "jit_chunk_fn")
-    be = _paged_backend(tiny)
-    assert _module_name(_lower_block(be)) == engine.DECODE_PROGRAM
-    assert _module_name(_lower_chunk(be)) == engine.PREFILL_CHUNK_PROGRAM
+    block, chunk = DECODE_BUILDERS[builder](tiny)
+    assert _module_name(block) == engine.DECODE_PROGRAM
+    if chunk is not None:
+        assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM
 
 
 @pytest.fixture(scope="module")

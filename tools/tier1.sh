@@ -91,11 +91,11 @@ else:
     else:
         print(f"tier1: WARNING autotune table {path} is STALE "
               f"({reason}) — kernels fall back to documented defaults; "
-              "re-run the bench autotune stage to refresh")
+              "re-run autotune.run_autotune to refresh")
 EOF
 else
   echo "tier1: no autotune table at $TUNE_TABLE (kernels use" \
-       "documented default block shapes; bench.py's autotune stage" \
+       "documented default block shapes; autotune.run_autotune" \
        "writes one)"
 fi
 
