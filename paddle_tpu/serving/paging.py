@@ -47,6 +47,7 @@ import hashlib
 import time
 from collections import OrderedDict
 from dataclasses import dataclass
+from itertools import islice
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -105,7 +106,12 @@ class BlockManager:
     """Host-side arena bookkeeping: free list, per-block refcounts,
     rolling-hash prefix index with LRU retention of released registered
     blocks. Pure python — it runs once per admission/retirement, never
-    inside the compiled stream."""
+    inside the compiled stream, but the device has nothing queued while
+    an admission runs, so its cost is idle time. A block handed out
+    costs O(1) from the free list and O(distinct hit tallies among the
+    retained blocks) by eviction — a handful — whatever the number of
+    retained blocks: the eviction order is kept as blocks park and
+    leave (``_by_hits``), never recomputed by a walk over them."""
 
     def __init__(self, num_blocks: int, block_size: int, hash_fn=None):
         if num_blocks < 2:
@@ -122,9 +128,15 @@ class BlockManager:
         self._index: Dict[bytes, Tuple[int, Tuple[int, ...]]] = {}
         self._digest_of: Dict[int, bytes] = {}  # registered blocks
         self._depth: Dict[bytes, int] = {}      # digest -> chain blocks
+        # retained blocks (registered, refcount 0) in LRU order
         self._cached: "OrderedDict[int, None]" = OrderedDict()
         # block id -> prefix-index hits observed (eviction cost signal)
         self._hits: Dict[int, int] = {}
+        # the eviction order: hit tally -> the retained blocks of that
+        # tally, LRU first. A tally only moves while its block is held
+        # (match_prefix acquires before it counts), so a retained block
+        # never changes bucket; an emptied bucket is dropped.
+        self._by_hits: Dict[int, "OrderedDict[int, None]"] = {}
         self.lookups = 0
         self.hit_blocks = 0
         self.evictions = 0
@@ -157,6 +169,36 @@ class BlockManager:
         the trade the fleet's watermark eviction arbitrates."""
         return 1.0 - len(self._free) / self.usable_blocks()
 
+    def _park(self, block_id: int):
+        """Retain a released registered block: youngest of the LRU
+        order and of its tally's bucket."""
+        self._cached[block_id] = None
+        self._by_hits.setdefault(self._hits.get(block_id, 0),
+                                 OrderedDict())[block_id] = None
+
+    def _unpark(self, block_id: int):
+        """Take a retained block out of the LRU order and its bucket
+        (resurrected by a prefix match, or evicted)."""
+        del self._cached[block_id]
+        tally = self._hits.get(block_id, 0)
+        bucket = self._by_hits[tally]
+        del bucket[block_id]
+        if not bucket:
+            del self._by_hits[tally]
+
+    def _reindex_cached(self):
+        """Rebuild the eviction order from ``_cached`` (LRU order) and
+        ``_hits`` — the two fields a snapshot carries."""
+        lru, self._cached, self._by_hits = list(self._cached), \
+            OrderedDict(), {}
+        for b in lru:
+            self._park(b)
+
+    def _next_victim(self) -> int:
+        """The retained block to evict next: the head of the lowest
+        tally's bucket — no walk over the retained blocks."""
+        return next(iter(self._by_hits[min(self._by_hits)]))
+
     def _evict_victim(self) -> int:
         """Pick and unregister the next cached block to evict. The
         score is COST-AWARE, not pure LRU: least observed prefix-index
@@ -164,12 +206,8 @@ class BlockManager:
         outlives a cold one-off chain of the same age), ties broken by
         LRU age. With no recorded hits anywhere this degrades to
         exactly the old LRU-first order."""
-        best, best_score = None, None
-        for pos, b in enumerate(self._cached):
-            score = (self._hits.get(b, 0), pos)
-            if best_score is None or score < best_score:
-                best, best_score = b, score
-        del self._cached[best]
+        best = self._next_victim()
+        self._unpark(best)
         digest = self._digest_of.pop(best)
         del self._index[digest]
         self._depth.pop(digest, None)
@@ -207,9 +245,12 @@ class BlockManager:
         tier reads this to copy exactly the chains about to die,
         WITHOUT perturbing hit counts or LRU order (a perturbed
         preview would desynchronize from the real eviction)."""
-        scored = sorted(((self._hits.get(b, 0), pos, b)
-                         for pos, b in enumerate(self._cached)))
-        return [b for _, _, b in scored[:n]]
+        out: List[int] = []
+        for tally in sorted(self._by_hits):
+            if len(out) >= n:
+                break
+            out.extend(islice(self._by_hits[tally], n - len(out)))
+        return out
 
     def chain_tokens_map(self) -> Dict[bytes, Tuple[int, ...]]:
         """Reconstruct full chain tokens for every registered digest
@@ -292,7 +333,7 @@ class BlockManager:
     def _acquire(self, block_id: int):
         r = self._ref.get(block_id, 0)
         if r == 0:                    # resurrect from the LRU cache
-            del self._cached[block_id]
+            self._unpark(block_id)
         self._ref[block_id] = r + 1
 
     def register_prefix(self, prompt, block_ids: Sequence[int],
@@ -343,7 +384,7 @@ class BlockManager:
             else:
                 del self._ref[bid]
                 if bid in self._digest_of:
-                    self._cached[bid] = None
+                    self._park(bid)
                 else:
                     self._free.append(bid)
         self._note_pool()
@@ -358,7 +399,9 @@ class BlockManager:
         - every refcount >= 1 (zeroes must leave the map);
         - the prefix index and the registered-block map are mutual
           inverses, retained blocks are all registered, and no free
-          block is still registered.
+          block is still registered;
+        - the eviction order holds exactly the retained blocks, each
+          under its current hit tally, in LRU order within a tally.
         """
         free, ref = set(self._free), set(self._ref)
         cached, reg = set(self._cached), set(self._digest_of)
@@ -393,6 +436,13 @@ class BlockManager:
         assert not stale_hits, \
             f"reuse tallies for unregistered blocks: " \
             f"{sorted(stale_hits)}"
+        want_order: Dict[int, List[int]] = {}
+        for b in self._cached:
+            want_order.setdefault(self._hits.get(b, 0), []).append(b)
+        have_order = {t: list(bk) for t, bk in self._by_hits.items()}
+        assert have_order == want_order, (
+            f"eviction order out of step with the retained blocks: "
+            f"have {have_order}, want {want_order}")
 
 
 class PagedModelStepBackend(ModelStepBackend):
@@ -766,11 +816,20 @@ class PagedEngine(ContinuousBatchingEngine):
     def try_admit(self, request) -> bool:
         """Block allocation, prefix lookup and slot arming for one
         request (the chunks themselves run in :meth:`prefill_tick`);
-        False when the block pool cannot hold it yet."""
-        with _span("serving.admit", rid=request.request_id):
-            return self._try_admit(request)
+        False when the block pool cannot hold it yet. The span says how
+        many blocks the admission allocated (``fresh_blocks``) and how
+        many of those it took by eviction (``evicted_blocks``)."""
+        with _span("serving.admit", rid=request.request_id) as sp:
+            evicted = self.manager.evictions
+            fresh = self._try_admit(request)
+            sp.ids.update(
+                fresh_blocks=fresh or 0,
+                evicted_blocks=self.manager.evictions - evicted)
+            return fresh is not None
 
-    def _try_admit(self, request) -> bool:
+    def _try_admit(self, request) -> Optional[int]:
+        """The admission proper: the number of blocks it allocated, or
+        None when the pool cannot hold the request yet."""
         prompt = np.asarray(request.prompt, np.int32).reshape(-1)
         resume = getattr(request, "resume", None)
         if resume is not None and resume.tokens:
@@ -796,7 +855,7 @@ class PagedEngine(ContinuousBatchingEngine):
         fresh = self.manager.allocate(total - len(shared))
         if fresh is None:            # pool exhausted: retry later
             self.manager.release(shared)
-            return False
+            return None
         block_ids = shared + fresh
         if self.tracer is not None:
             self.tracer.span_end(request.request_id, "queue_wait",
@@ -830,7 +889,7 @@ class PagedEngine(ContinuousBatchingEngine):
             temp=jnp.float32(request.temperature),
             topk=jnp.int32(request.top_k),
             topp=jnp.float32(request.top_p), resume_tok=resume_tok))
-        return True
+        return len(fresh)
 
     def _match_prefix_for_admission(self, full) -> List[int]:
         """Admission-time prefix match. The base engine consults only
@@ -1120,6 +1179,7 @@ class PagedEngine(ContinuousBatchingEngine):
         m.evictions = int(mm.get("evictions", 0))
         m._hits = {int(b): int(h) for b, h in mm.get("hits", [])
                    if int(b) in m._digest_of}
+        m._reindex_cached()
         m.assert_consistent()
         self._jobs = []
         for j, jm in enumerate(meta["jobs"]):

@@ -708,6 +708,10 @@ class Server:
         if hit_rate is not None:               # paged engine extras
             out["prefix_cache_hit_rate"] = round(hit_rate(), 4)
             out["kv_bytes_per_slot"] = eng.backend.kv_bytes_per_slot()
+            # retained prefix blocks evicted, by an allocation (each
+            # serving.admit span carries its own: evicted_blocks of its
+            # fresh_blocks) or by the fleet's watermark tier
+            out["block_evictions"] = eng.manager.evictions
         return out
 
     def export_trace(self, path: str) -> str:

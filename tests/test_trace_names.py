@@ -309,6 +309,41 @@ def test_decode_block_span_counter_names(tiny):
     assert dense._decode_block_counters() == {"sampled_steps": 0}
 
 
+def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
+    """``serving.admit`` carries ``fresh_blocks`` (allocated by this
+    admission) and ``evicted_blocks`` (of those, taken by evicting a
+    retained prefix block), and ``Server.stats()["block_evictions"]`` is
+    the engine's total: 0 until the arena has turned over."""
+    import time
+
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
+                                    Server)
+    eng = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
+                                   decode_block=4, paged=True,
+                                   block_size=8, prefill_chunk=8,
+                                   num_blocks=11)
+    srv = Server(eng, Scheduler())
+    rs = np.random.RandomState(0)
+    rids = [srv.submit(rs.randint(0, tiny.config.vocab_size, (19,))
+                       .astype(np.int32), max_new_tokens=6)
+            for _ in range(6)]
+    t0 = time.perf_counter()
+    srv.run_until_idle()
+    admits = [sp for sp in tracing.since(t0) if sp.name == "serving.admit"]
+    assert [sp.ids["rid"] for sp in admits] == rids
+    assert all(set(sp.ids) == {"rid", "fresh_blocks", "evicted_blocks"}
+               for sp in admits)
+    # distinct prompts: nothing shared, three blocks each
+    assert [sp.ids["fresh_blocks"] for sp in admits] == [3] * 6
+    evicted = [sp.ids["evicted_blocks"] for sp in admits]
+    assert evicted[:3] == [0, 0, 0] and evicted[-1] > 0
+    assert all(e <= 3 for e in evicted)
+    assert sum(evicted) == eng.manager.evictions \
+        == srv.stats()["block_evictions"] > 0
+    eng.manager.assert_consistent()
+
+
 def _op_histogram(text: str) -> dict:
     ops = re.findall(r"= \"?([a-z_]+\.[a-z_.]+)\"?[ (<]", text)
     return {op: ops.count(op) for op in set(ops)}
