@@ -286,10 +286,11 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
     the ONE chunked-prefill chunk program — ``engine_block_size`` /
     ``engine_num_blocks`` / ``engine_prefill_chunk`` mirror the
     ``PagedEngine`` knobs (defaults match: full dense capacity + trash
-    block, chunk = 2 blocks). The artifact records the program arities
-    (``block_outputs``/``chunk_outputs``) so a serving host can tell
-    what it loaded; ``serving.paging.PagedArtifactStepBackend`` is the
-    loader. The int8 KV arena is not exported (fp32 arena only)."""
+    block, chunk = ``serving.paging.default_prefill_chunk``). The
+    artifact records the program arities (``block_outputs``/
+    ``chunk_outputs``) so a serving host can tell what it loaded;
+    ``serving.paging.PagedArtifactStepBackend`` is the loader. The int8
+    KV arena is not exported (fp32 arena only)."""
     from ..models.generation import build_decode_step
     from ..tensor import Tensor
 
@@ -342,7 +343,9 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
         if engine_num_blocks is None:
             engine_num_blocks = 1 + engine_slots * max_blocks
         if engine_prefill_chunk is None:
-            engine_prefill_chunk = 2 * engine_block_size
+            from ..serving.paging import default_prefill_chunk
+            engine_prefill_chunk = default_prefill_chunk(
+                engine_block_size, max_len)
         pool0 = model.init_paged_kv_cache(engine_num_blocks,
                                           engine_block_size)
         pflat, ptree = jax.tree.flatten(

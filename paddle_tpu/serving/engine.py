@@ -244,7 +244,10 @@ def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
     The candidate first token is sampled in-graph from the last REAL
     column with the request's own params; the host uses it only on the
     final chunk. Unlike the dense engine's per-bucket prefill jits,
-    this compiles exactly once."""
+    this compiles exactly once. Every call streams all the weights
+    whatever ``chunk`` is, so the chunk a paged engine picks by itself
+    (``paging.default_prefill_chunk``) is the ridge where the chunk's
+    matmuls catch up with that read."""
 
     def chunk_fn(pv, bv, ids, cache_flat, table, start_pos, n_valid,
                  key, temp, topk, topp):

@@ -68,6 +68,25 @@ __all__ = ["BlockManager", "PagedArtifactStepBackend",
 
 TRASH_BLOCK = 0
 
+# The chunk program streams every weight once, however few tokens ride
+# on the read, so its matmuls are free up to the chip's ridge: peak
+# FLOP/s over peak bytes/s = 197e12 / 819e9 = 240 FLOP a byte on a TPU
+# v5e, and a bf16 weight gives 2 FLOP a token for its 2 bytes, i.e. 240
+# tokens a weight read. 256 is the multiple of the matrix unit's 128
+# rows next to that. On the chip (PERF.md section 6, PR 32; a 7B-class
+# model's 16 layers, 4,096-token tables) a chunk takes 11.6 ms at 32 and
+# at 64 tokens, 13.0 at 128, 22.9 at 256, 42.2 at 512; 128 and 256 serve
+# the same tokens a second, 512 pads more than it saves.
+PREFILL_CHUNK_RIDGE = 256
+
+
+def default_prefill_chunk(block_size: int, max_len: int) -> int:
+    """The chunk a paged engine prefills in when none is passed: the
+    chunk program's ridge (``PREFILL_CHUNK_RIDGE``), never longer than
+    the table (``max_len``), in whole KV blocks (at least one)."""
+    return max(min(PREFILL_CHUNK_RIDGE, max_len) // block_size, 1) \
+        * block_size
+
 # arena metric families (no-ops until metrics.enable()/PT_METRICS)
 _M_BLK_FREE = _om.gauge("pt_paging_blocks_free",
                         "arena blocks on the free list")
@@ -703,6 +722,12 @@ class PagedEngine(ContinuousBatchingEngine):
       Server re-queues and retries after retirements free blocks.
     - prompts are UNPADDED (no buckets): position 0 is token 0, which
       is what makes whole prefix blocks shareable across requests.
+    - ``prefill_chunk`` (else ``PT_SERVING_PREFILL_CHUNK``) is the chunk
+      program's length in tokens; when neither is given it is
+      :func:`default_prefill_chunk` — the program's ridge, where a
+      chunk's matmuls take as long as the weight read every chunk pays,
+      so a prompt re-reads the weights once per ~256 tokens.
+      :meth:`chunk_fill_share` says how much of the chunks was prompt.
     """
 
     def __init__(self, model=None, num_slots: int = 4,
@@ -749,8 +774,9 @@ class PagedEngine(ContinuousBatchingEngine):
         if kv_int8 is None:
             kv_int8 = env_flag("PT_SERVING_KV_INT8")
         if prefill_chunk is None:
-            prefill_chunk = env_int("PT_SERVING_PREFILL_CHUNK",
-                                    2 * block_size)
+            prefill_chunk = env_int(
+                "PT_SERVING_PREFILL_CHUNK",
+                default_prefill_chunk(block_size, max_len))
         if backend is None:
             if model is None:
                 raise ValueError("pass a model or a paged step backend")
@@ -825,6 +851,13 @@ class PagedEngine(ContinuousBatchingEngine):
         prefix blocks instead of recomputed."""
         return self.shared_tokens / self.prompt_tokens \
             if self.prompt_tokens else 0.0
+
+    def chunk_fill_share(self) -> float:
+        """Fraction of the chunk programs' columns that held a prompt
+        token (the rest was right-padding): a longer chunk re-reads the
+        weights less often and pads more."""
+        columns = self.prefill_chunks * self.prefill_chunk_len
+        return self.prefilled_tokens / columns if columns else 0.0
 
     def prefill_compile_count(self) -> int:
         return self.backend.prefill_traces[0]

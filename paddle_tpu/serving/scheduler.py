@@ -94,8 +94,14 @@ class Scheduler:
     paged engine prefills in chunks paced by this same budget
     (engine.prefill_tick), so one tick never steals more than ~budget
     tokens of prefill from the in-flight decode — that bounds the
-    decode-latency spike a long prompt used to cause. At least one
-    request always passes when the gate is open (no starvation)."""
+    decode-latency spike a long prompt used to cause. The chunk is the
+    budget's granule: a tick with prefill pending always runs at least
+    one chunk, which at the paged engine's default
+    (``paging.default_prefill_chunk``) is up to 256 prompt tokens —
+    about twice the device time of a 32-token chunk for eight times the
+    tokens — so a budget below the chunk length paces to one chunk a
+    tick, not to the budget. At least one request always passes when
+    the gate is open (no starvation)."""
 
     # strict FIFO pop: a slot freed by preemption would go back to the
     # front-inserted victim, so the Server's preemption policy refuses

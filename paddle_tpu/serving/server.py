@@ -707,6 +707,7 @@ class Server:
         hit_rate = getattr(eng, "prefix_cache_hit_rate", None)
         if hit_rate is not None:               # paged engine extras
             out["prefix_cache_hit_rate"] = round(hit_rate(), 4)
+            out["chunk_fill_share"] = round(eng.chunk_fill_share(), 4)
             out["kv_bytes_per_slot"] = eng.backend.kv_bytes_per_slot()
             # retained prefix blocks evicted, by an allocation (each
             # serving.admit span carries its own: evicted_blocks of its

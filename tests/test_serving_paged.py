@@ -619,6 +619,101 @@ class TestPagedScheduling:
                                  max_new_tokens=10)
 
 
+@pytest.fixture(scope="module")
+def chunk_streams():
+    """A 65- and a 300-token prompt served greedily by two engines that
+    differ in the chunk alone (32 passed, and none passed): ``(model,
+    prompts, {chunk: (engine, stats, streams)})``. The two engines' decode
+    blocks are one program compiled twice, which stays out of the
+    persistent compile cache (tests/test_resilience.py
+    ``_no_compile_cache``)."""
+    paddle.seed(0)
+    cfg = llama_tiny_config(tensor_parallel=False,
+                            max_position_embeddings=512)
+    model = LlamaForCausalLM(cfg)
+    rs = np.random.RandomState(32)
+    prompts = [rs.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
+               for L in (65, 300)]
+    jax.config.update("jax_enable_compilation_cache", False)
+    try:
+        runs = {}
+        for chunk in (32, None):
+            engine = ContinuousBatchingEngine(
+                model, num_slots=2, max_len=512, decode_block=4,
+                paged=True, block_size=16, prefill_chunk=chunk)
+            _LIVE_MANAGERS.append(engine.manager)
+            srv = Server(engine)
+            rids = [srv.submit(p, max_new_tokens=6) for p in prompts]
+            res = srv.run_until_idle()
+            runs[engine.prefill_chunk_len] = (
+                engine, srv.stats(), [res[r] for r in rids])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+    return model, prompts, runs
+
+
+# case -> (PT_SERVING_PREFILL_CHUNK, engine arguments, the chunk it gets)
+_CHUNK_CASES = {
+    "rule_at_block_16_max_len_4096":
+        (None, dict(block_size=16, max_len=4096), 256),
+    "whole_blocks_of_24": (None, dict(block_size=24, max_len=480), 240),
+    "max_len_64": (None, dict(block_size=16, max_len=64), 64),
+    "max_len_100": (None, dict(block_size=4, max_len=100), 100),
+    "argument_wins":
+        ("40", dict(block_size=8, max_len=64, prefill_chunk=24), 24),
+    "env_wins": ("40", dict(block_size=8, max_len=64), 40),
+}
+
+
+@pytest.mark.parametrize("case", [
+    *_CHUNK_CASES, "stream_65_tokens", "stream_300_tokens",
+    "chunk_fill_share"])
+def test_default_prefill_chunk(case, request, paged_setup, monkeypatch):
+    """The chunk a paged engine picks when none is passed
+    (``paging.default_prefill_chunk``): the chunk program's ridge, in
+    whole KV blocks, never wider than the table; what a caller passes
+    still wins; and a stream does not depend on it."""
+    from paddle_tpu.serving.paging import (PREFILL_CHUNK_RIDGE,
+                                           default_prefill_chunk)
+    if case in _CHUNK_CASES:
+        env, kw, want = _CHUNK_CASES[case]
+        if env is None:
+            monkeypatch.delenv("PT_SERVING_PREFILL_CHUNK", raising=False)
+        else:
+            monkeypatch.setenv("PT_SERVING_PREFILL_CHUNK", env)
+        engine = ContinuousBatchingEngine(   # compiles nothing until served
+            paged_setup[0], num_slots=2, decode_block=4, paged=True, **kw)
+        _LIVE_MANAGERS.append(engine.manager)
+        assert engine.prefill_chunk_len == want
+        # the rule alone, at this table and at KV blocks of 16
+        for bs in (kw["block_size"], 16):
+            chunk = default_prefill_chunk(bs, kw["max_len"])
+            assert bs <= chunk <= min(PREFILL_CHUNK_RIDGE, kw["max_len"])
+            assert chunk % bs == 0
+        if env is None:
+            assert want == default_prefill_chunk(kw["block_size"],
+                                                 kw["max_len"])
+        return
+    model, prompts, runs = request.getfixturevalue("chunk_streams")
+    assert sorted(runs) == [32, PREFILL_CHUNK_RIDGE]
+    if case == "chunk_fill_share":
+        for chunk, chunks in ((32, 3 + 10), (PREFILL_CHUNK_RIDGE, 1 + 2)):
+            engine, stats, _ = runs[chunk]
+            assert engine.prefill_chunks == chunks
+            assert engine.prefilled_tokens == 65 + 300
+            assert engine.prefill_compile_count() == 1
+            assert stats["chunk_fill_share"] == round(
+                engine.prefilled_tokens / (chunks * chunk), 4)
+        return
+    # the short prompt's table is 5 blocks wide: at the default its one
+    # chunk's pad columns past it land in the trash block
+    i = ("stream_65_tokens", "stream_300_tokens").index(case)
+    np.testing.assert_array_equal(runs[32][2][i],
+                                  runs[PREFILL_CHUNK_RIDGE][2][i])
+    np.testing.assert_array_equal(
+        runs[32][2][i], _ref(model, prompts[i], 6, temperature=0.0))
+
+
 class TestPagedArtifact:
     """PR 4 carried follow-up: export_decoder(engine_paged=True) ships
     the paged engine's TWO programs with recorded arities, and
