@@ -294,6 +294,9 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
     from ..models.generation import build_decode_step
     from ..tensor import Tensor
 
+    if engine_slots is not None and engine_paged:
+        from ..serving.paging import refuse_looped_cache
+        refuse_looped_cache(model, "the exported paged artifact")
     sample_kwargs = dict(temperature=temperature, top_k=top_k,
                          top_p=top_p)
     pvals = [p._value for _, p in model.named_parameters()]

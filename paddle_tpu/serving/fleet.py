@@ -101,7 +101,7 @@ from . import durability as _dur
 from .engine import (ContinuousBatchingEngine, _M_PREFILLS, _M_TOKENS,
                      _SlotRun)
 from .handoff import KVHandoff, decode_handoff, encode_handoff
-from .paging import PagedEngine, _sha1_chain
+from .paging import PagedEngine, _sha1_chain, refuse_looped_cache
 from . import prefix_cache as _pc
 from .prefix_cache import (PrefixCacheDirectory, _adopt_scatter,
                            adopt_prefix, extract_prefix)
@@ -638,6 +638,7 @@ class PrefillWorker:
                 "PrefillWorker needs a prefill-only engine "
                 "(PrefillDenseEngine / PrefillPagedEngine); got "
                 f"{type(engine).__name__}")
+        refuse_looped_cache(engine, "the fleet's hand-off and prefix tier")
         self.engine = engine
         self.name = name
         self.server = server or Server(engine, scheduler, resilience,
@@ -696,6 +697,7 @@ class DecodeWorker:
             raise NotImplementedError(
                 "the fleet's hand-off moves the (k, v) blocks of ONE pool: "
                 "a hybrid cache's two groups cannot be adopted yet")
+        refuse_looped_cache(engine, "the fleet's hand-off and prefix tier")
         self.engine = engine
         self.name = name
         self.server = server or Server(engine, resilience=resilience,

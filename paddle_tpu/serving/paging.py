@@ -498,6 +498,22 @@ class BlockManager:
             f"have {have_order}, want {want_order}")
 
 
+def refuse_looped_cache(holder, what: str):
+    """What moves or re-shapes ONE block of ``num_blocks`` (a hand-off, the
+    fleet's prefix tier, a sharded or verify-capable or exported backend)
+    cannot hold a looped model's cache yet, where a block id stands for
+    ``kv_cache_passes`` pages of every arena: refuse by name. ``holder`` is
+    the model (``kv_cache_passes``) or an engine built on it
+    (``cache_passes``)."""
+    passes = int(getattr(holder, "kv_cache_passes",
+                         getattr(holder, "cache_passes", 1)))
+    if passes > 1:
+        raise NotImplementedError(
+            f"{what} over a looped cache (kv_cache_passes = {passes}): a "
+            f"block id stands for {passes} pages of every arena there, "
+            "one a pass, and this path moves or shapes only one")
+
+
 class PagedModelStepBackend(ModelStepBackend):
     """Paged twin of ModelStepBackend: the pool cache is the shared
     block arena, the decode program threads the in-state block table
@@ -544,6 +560,11 @@ class PagedModelStepBackend(ModelStepBackend):
         # a model whose programs count (routed picks, experts hit) keeps
         # the counts in the cache's LAST leaf and names them here
         self.cache_counters = dict(getattr(model, "cache_counters", {}))
+        # a looped model runs each weight layer ``kv_cache_passes`` times a
+        # step, each pass over a slice of the layer's arenas of its own
+        self.cache_passes = int(getattr(model, "kv_cache_passes", 1))
+        self.attn_sites = self.cache_passes * int(getattr(
+            getattr(model, "config", None), "num_hidden_layers", 0))
         self._pv = [p._value for _, p in model.named_parameters()]
         self._bv = [b._value for _, b in model.named_buffers()]
         # weight-only quant BEFORE the decode-block and chunk programs
@@ -804,6 +825,11 @@ class PagedEngine(ContinuousBatchingEngine):
         self.max_blocks = backend.max_blocks
         self.kv_int8 = backend.kv_int8
         self.prefill_chunk_len = backend.prefill_chunk_len
+        # passes a step makes over the layers (1 unless the model loops)
+        # and the cached-attention sites a decode step holds: the page
+        # counters below stay ONE site's
+        self.cache_passes = getattr(backend, "cache_passes", 1)
+        self.attn_sites = getattr(backend, "attn_sites", 0)
         self.manager = BlockManager(self.num_kv_blocks,
                                     self.kv_block_size, hash_fn)
         self._arm_jit = jax.jit(_arm_fn, donate_argnums=(0,))
@@ -830,6 +856,7 @@ class PagedEngine(ContinuousBatchingEngine):
         # mirror of the in-graph per-slot ``pos`` it is counted from
         self.kv_pages_live = 0         # pages holding a slot's live KV
         self.kv_pages_copied = 0       # pages the kernel copied for them
+        self.ut_steps = 0              # looped model: passes run by decode
         self._pos_host = np.zeros((self.num_slots,), np.int64)
         # what the model's programs count into the cache's last leaf
         # (``cache_counters``: name -> "sum" | "max"; a (2, n) int32 array,
@@ -1119,8 +1146,12 @@ class PagedEngine(ContinuousBatchingEngine):
                                       self.max_blocks, self.kv_block_size)
         self.kv_pages_live += live
         self.kv_pages_copied += copied
-        return dict(super()._decode_block_counters(),
-                    kv_pages_live=live, kv_pages_copied=copied)
+        ids = dict(super()._decode_block_counters(),
+                   kv_pages_live=live, kv_pages_copied=copied)
+        if self.cache_passes > 1:
+            ids["ut_steps"] = self.decode_block * self.cache_passes
+            self.ut_steps += ids["ut_steps"]
+        return ids
 
     def _block_read_lengths(self):
         """``(lengths, decoding)``, each ``(decode_block, slots)``: the
@@ -1262,7 +1293,8 @@ class PagedEngine(ContinuousBatchingEngine):
             "prefill_chunks": self.prefill_chunks,
             "fetched_tokens": self.fetched_tokens,
             "kv_pages_live": self.kv_pages_live,
-            "kv_pages_copied": self.kv_pages_copied}
+            "kv_pages_copied": self.kv_pages_copied,
+            "ut_steps": self.ut_steps}
         return meta, arrays
 
     def restore_state(self, meta, arrays):
@@ -1314,4 +1346,5 @@ class PagedEngine(ContinuousBatchingEngine):
         self.fetched_tokens = pc.get("fetched_tokens", 0)
         self.kv_pages_live = pc.get("kv_pages_live", 0)
         self.kv_pages_copied = pc.get("kv_pages_copied", 0)
+        self.ut_steps = pc.get("ut_steps", 0)
         self._pos_host = np.asarray(self._state["pos"]).astype(np.int64)

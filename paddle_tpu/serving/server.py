@@ -713,6 +713,10 @@ class Server:
             # serving.admit span carries its own: evicted_blocks of its
             # fresh_blocks) or by the fleet's watermark tier
             out["block_evictions"] = eng.manager.evictions
+            out["attn_sites"] = eng.attn_sites
+            if eng.cache_passes > 1:           # a looped model's extras
+                out["ut_steps"] = eng.ut_steps
+                out["ut_exit_step_milli"] = eng.ut_exit_step_milli
             wm = getattr(eng, "window_manager", None)
             if wm is not None:                 # the hybrid cache's 2nd pool
                 out["window_block_evictions"] = wm.evictions

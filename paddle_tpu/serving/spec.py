@@ -70,7 +70,8 @@ from ..utils.flags import env_int
 from .engine import (DECODE_PROGRAM, ContinuousBatchingEngine,
                      ModelStepBackend, _M_COMPILES, _M_DECODE_TOKENS,
                      _M_STEPS, _M_TOKENS, slot_sample_logits)
-from .paging import PagedEngine, PagedModelStepBackend
+from .paging import (PagedEngine, PagedModelStepBackend,
+                     refuse_looped_cache)
 
 __all__ = ["SpecConfig", "resolve_spec_config", "ngram_propose",
            "build_spec_block_fn", "SpecModelStepBackend",
@@ -332,6 +333,7 @@ class SpecPagedStepBackend(_SpecBackendMixin, PagedModelStepBackend):
                  decode_block: int, block_size: int, num_blocks: int,
                  kv_int8: bool, prefill_chunk: int, spec: SpecConfig,
                  quant=None):
+        refuse_looped_cache(model, "speculative decoding")
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
                          quant=quant)

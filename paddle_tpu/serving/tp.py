@@ -71,7 +71,7 @@ from ..utils.tp_hooks import (current_tp, maybe_gather,  # noqa: F401
 from ..utils.flags import env_bool, env_str
 from .engine import (ModelStepBackend, build_slot_block_fn,
                      build_slot_prefill_fn)
-from .paging import PagedModelStepBackend
+from .paging import PagedModelStepBackend, refuse_looped_cache
 
 __all__ = ["TPConfig", "resolve_tp_config", "ShardedModelStepBackend",
            "ShardedPagedStepBackend"]
@@ -511,6 +511,7 @@ class ShardedPagedStepBackend(_TPBackendMixin, PagedModelStepBackend):
                  kv_int8: bool, prefill_chunk: int, tp: TPConfig,
                  quant=None):
         from .engine import build_paged_chunk_fn
+        refuse_looped_cache(model, "tensor-parallel serving")
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
                          quant=quant)

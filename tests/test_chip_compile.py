@@ -411,3 +411,71 @@ def test_paged_decode_block_compiles_for_v5e(one_chip, on_tpu_gates):
     assert "%paged_attention_decode." in text
     assert "%closed_call" not in text
     assert hbm_bytes(compiled) < 12 * 2 ** 30
+
+
+def abstract_looped_programs(layers, one_chip, slots=8):
+    """The paged engine's two programs for an Ouro-2.6B-class looped model
+    at full width and ``layers`` layers, lowered on abstract weights as
+    ``abstract_paged_decode_program`` lowers the dense model's: the cell's
+    deployment (``slots`` slots of 1,024 positions, 1 + 40 x slots blocks, a
+    256-token chunk). Returns ``(block, chunk, arena shape)``."""
+    from paddle_tpu.models.ouro import OuroConfig, OuroForCausalLM
+    from paddle_tpu.serving.paging import PagedModelStepBackend
+    from paddle_tpu.utils.scale import abstract_init
+
+    with abstract_init("bfloat16"):
+        model = OuroForCausalLM(OuroConfig(num_hidden_layers=layers,
+                                           dtype="bfloat16"))
+    num_blocks = 1 + 40 * slots
+    be = PagedModelStepBackend(
+        model, slots, 1024, decode_block=8, block_size=KV_BLOCK,
+        num_blocks=num_blocks, kv_int8=False, prefill_chunk=256)
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def scalar(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                  for shape, dtype in be.pool_specs)
+    pv, bv = [spec(v) for v in be._pv], [spec(v) for v in be._bv]
+    block = be._block_jit.lower(pv, bv, cache,
+                                jax.tree.map(spec, be.init_state()))
+    chunk = be._chunk_jit.lower(
+        pv, bv, scalar(jnp.int32, 1, 256), cache,
+        scalar(jnp.int32, 1, be.max_blocks), scalar(jnp.int32),
+        scalar(jnp.int32), scalar(jnp.uint32, 2), scalar(jnp.float32),
+        scalar(jnp.int32), scalar(jnp.float32))
+    return block, chunk, be.pool_specs[0][0]
+
+
+@pytest.mark.parametrize("program", ["block", "chunk"])
+def test_looped_programs_hold_each_layer_once_and_copy_no_arena(
+        program, one_chip, on_tpu_gates):
+    """A looped model's decode block and chunk program at full width and 2
+    layers of 4 passes each: the passes are a loop in the program, so the
+    compiled decode block calls ``paged_attention_decode`` at 2 sites, not
+    8; no instruction copies an arena (a weight layer's ``(4 x 321, 16, 16,
+    128)`` pair is carried through the loop and scattered into in place);
+    and what the program holds beyond its arguments, less the q / k / v
+    weights the compiler re-lays-out once before the loops (8 MiB each), is
+    under one arena."""
+    block, chunk, arena = abstract_looped_programs(2, one_chip)
+    assert arena == (4 * 321, KV_BLOCK, 16, 128)
+    compiled = (block if program == "block" else chunk).compile()
+    text = compiled.as_text()
+    sites = len(set(re.findall(r"%(paged_attention_decode\.\d+) = ", text)))
+    assert sites == (2 if program == "block" else 0)
+    shape = "bf16[%s]" % ",".join(map(str, arena))
+    made = [line for line in text.splitlines()
+            if re.search(r"= %s\S* (copy|dynamic-update-slice|"
+                         r"dynamic-slice|concatenate|pad)\(" % re.escape(shape),
+                         line)]
+    assert not made, made[:2]
+    ma = compiled.memory_analysis()
+    arena_bytes = int(np.prod(arena)) * 2
+    assert ma.alias_size_in_bytes >= 4 * arena_bytes      # donated in place
+    relaid = 2 * 3 * 2048 * 2048 * 2
+    assert ma.temp_size_in_bytes - relaid < arena_bytes
+    assert hbm_bytes(compiled) < 2 * 2 ** 30

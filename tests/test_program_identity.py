@@ -2,9 +2,11 @@
 cells come out as pinned (``tools/program_identity.py``: StableHLO with
 locations stripped, kernels in interpret mode). The pins are the programs
 of PR 30, which PR 31 (a third class of cache beside them) had to leave as
-they were. A change that means to alter one of them updates its pin here
+they were, as PR 33 did (a looped model's two programs are pinned beside
+them). A change that means to alter one of them updates its pin here
 and says so in CHANGES.md; one that does not has changed what
-``mistral7b-decode-sat`` or ``kanana2-docqa-decode`` runs."""
+``mistral7b-decode-sat``, ``kanana2-docqa-decode`` or
+``ouro-reason-decode`` runs."""
 import json
 import os
 import subprocess
@@ -20,6 +22,9 @@ PINNED = {
     "llama_int8.chunk": "315454823dde9af0dd1aac50adea43a61726c18c",
     "deepseek_v3.block": "a3c6cface75b212e43565830e8966dd03f9b533f",
     "deepseek_v3.chunk": "737a61e307860907e16b107ba441f39b755a2760",
+    # PR 33: a looped model's two programs (the passes a scan in each)
+    "ouro.block": "d3bf11f9184cf1aa62eee8e9e1495b7bfaee0f78",
+    "ouro.chunk": "07b38559f2adab2faa4491cf7dcc852151f6073c",
 }
 
 

@@ -360,6 +360,93 @@ def test_hybrid_engine_span_and_counter_names():
     assert stats["window_block_evictions"] == stats["block_evictions"] == 0
 
 
+def test_looped_model_keeps_the_program_names_and_holds_each_layer_once(
+        interpret):
+    """A looped model runs the SAME two programs; the passes are a loop IN
+    the program, so the decode block holds ``paged_attention_decode`` once
+    a weight layer (3), not once a cache layer (12); a pass sits under the
+    scope ``ut_step`` and the norm and gate that close it under
+    ``exit_gate``; the chunk program gathers."""
+    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
+    from paddle_tpu.serving import engine
+    paddle.seed(0)
+    be = _paged_backend(OuroForCausalLM(ouro_tiny_config()))
+    assert (be.cache_passes, be.attn_sites) == (4, 12)
+    block, chunk = _lower_block(be), _lower_chunk(be)
+    assert _module_name(block) == engine.DECODE_PROGRAM
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM
+    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
+    names = pallas_names(jax.make_jaxpr(be._block_jit)(
+        be._pv, be._bv, cache, be.init_state()))
+    assert names.count("paged_attention_decode") == 3
+    for text in (block.as_text(debug_info=True),
+                 chunk.as_text(debug_info=True)):
+        for scope in ("ut_step/attn", "ut_step/mlp", "exit_gate", "lm_head",
+                      "sample"):
+            assert f"{scope}/" in text, scope
+    assert "paged_attention_decode" not in pallas_names(
+        jax.make_jaxpr(be._chunk_jit)(
+            be._pv, be._bv, i32(1, 8), cache, i32(1, be.max_blocks),
+            jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
+            jnp.float32(0), jnp.int32(0), jnp.float32(1)))
+
+
+def test_looped_engine_span_and_counter_names():
+    """What a looped model adds to ``serving.decode_block`` (``ut_steps``,
+    and ``ut_exit_step_milli`` from the program's counters, which
+    ``serving.prefill_chunk`` carries too), each an attribute of the engine;
+    ``attn_sites`` beside one site's page counts; all three on
+    ``Server.stats()``."""
+    import time
+
+    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
+                                    Server)
+    paddle.seed(0)
+    eng = ContinuousBatchingEngine(
+        OuroForCausalLM(ouro_tiny_config()), num_slots=2, max_len=64,
+        decode_block=4, paged=True, block_size=8, prefill_chunk=8)
+    assert eng.attn_sites == 12
+    # a fresh engine's slots are all dead at pos 0: one page a slot a step
+    # at ONE site, and four passes a step
+    assert eng._decode_block_counters() == {
+        "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
+        "ut_steps": 16}
+    assert (eng.ut_steps, eng.ut_exit_step_milli,
+            eng.prefill_ut_exit_step_milli) == (16, 0, 0)
+    srv = Server(eng, Scheduler())
+    t0 = time.perf_counter()
+    srv.submit(np.arange(19, dtype=np.int32), max_new_tokens=6)
+    srv.run_until_idle()
+    spans = tracing.since(t0)
+    block = [sp for sp in spans if sp.name == "serving.decode_block"][-1]
+    assert {"ut_steps", "ut_exit_step_milli", "kv_pages_live"} \
+        <= set(block.ids)
+    chunk = [sp for sp in spans if sp.name == "serving.prefill_chunk"
+             and "chunks" in sp.ids][-1]
+    assert "ut_exit_step_milli" in chunk.ids
+    stats = srv.stats()
+    assert stats["attn_sites"] == 12
+    assert stats["ut_steps"] == eng.ut_steps == 16 + 4 * eng.steps
+    assert stats["ut_exit_step_milli"] == eng.ut_exit_step_milli > 0
+
+
+def test_a_model_that_does_not_loop_says_so_nowhere(tiny):
+    """``attn_sites`` is the layers of a model that runs each once, and
+    neither ``ut_steps`` nor ``ut_exit_step_milli`` is on its stats."""
+    from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
+                                    Server)
+    eng = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
+                                   decode_block=4, paged=True,
+                                   block_size=8, prefill_chunk=8)
+    assert (eng.cache_passes, eng.attn_sites) \
+        == (1, tiny.config.num_hidden_layers)
+    stats = Server(eng, Scheduler()).stats()
+    assert stats["attn_sites"] == tiny.config.num_hidden_layers
+    assert not {"ut_steps", "ut_exit_step_milli"} & set(stats)
+
+
 def test_train_step_program_is_named_on_purpose(tiny):
     from paddle_tpu import jit, optimizer
     assert jit.TRAIN_STEP_PROGRAM == "jit_step"

@@ -1,5 +1,6 @@
 """Fingerprints of the paged engine's two programs (decode block, prefill
-chunk) for the model classes the benchmark's accepted cells serve, at tiny
+chunk) for the model classes the benchmark's cells serve through
+``PagedModelStepBackend`` (dense, latent + experts, looped), at tiny
 sizes on the CPU: the StableHLO text with source locations stripped, the
 Pallas kernels in interpret mode so that their bodies are in the text.
 
@@ -25,6 +26,7 @@ def fingerprints() -> dict:
     from paddle_tpu.models.deepseek_v3 import (DeepseekV3ForCausalLM,
                                                deepseek_v3_tiny_config)
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
+    from paddle_tpu.models.ouro import OuroForCausalLM, ouro_tiny_config
     from paddle_tpu.ops.pallas import flash_attention, fused
     from paddle_tpu.serving.paging import PagedModelStepBackend
     fused._FORCE_INTERPRET = flash_attention._FORCE_INTERPRET = True
@@ -47,10 +49,12 @@ def fingerprints() -> dict:
     paddle.seed(0)
     llama = LlamaForCausalLM(llama_tiny_config(tensor_parallel=False))
     latent = DeepseekV3ForCausalLM(deepseek_v3_tiny_config())
+    looped = OuroForCausalLM(ouro_tiny_config())
     out = {}
     for name, programs in (("llama", texts(llama)),
                            ("llama_int8", texts(llama, kv_int8=True)),
-                           ("deepseek_v3", texts(latent))):
+                           ("deepseek_v3", texts(latent)),
+                           ("ouro", texts(looped))):
         for program, text in programs:
             text = re.sub(r"loc\(.*?\)", "", text)
             out[f"{name}.{program}"] = hashlib.sha1(text.encode()).hexdigest()
