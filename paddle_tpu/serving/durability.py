@@ -62,6 +62,7 @@ from ..observability import metrics as _om
 from ..utils import faults
 from .handoff import FETCH_FORMAT, KVHandoff, decode_handoff, \
     encode_handoff
+from .paging import walk_chain
 
 __all__ = ["JOURNAL_MAGIC", "MANIFEST_FORMAT", "PrefixSpillStore",
            "WriteAheadJournal", "extract_chain", "journal_path",
@@ -331,20 +332,15 @@ def _chain_block_ids(manager, tokens, n_blocks: int
     would change which block the eviction it precedes picks. Safe
     because the fleet tick is single-threaded — nothing can evict
     between this walk and the row copy."""
-    bs = manager.block_size
-    parent = b""
     out: List[int] = []
-    for j in range(n_blocks):
-        chunk = tuple(int(t) for t in tokens[j * bs:(j + 1) * bs])
-        if len(chunk) < bs:
-            return None
-        digest = manager.hash_fn(parent, chunk)
+    for digest, chunk in walk_chain(tokens, manager.block_size, n_blocks,
+                                    manager.hash_fn):
         entry = manager._index.get(digest)
         if entry is None or entry[1] != chunk:
             return None
         out.append(entry[0])
-        parent = digest
-    return out
+    # the walk stops at the last FULL block: a short chain is a miss
+    return out if len(out) == n_blocks else None
 
 
 def extract_chain(engine, tokens, n_blocks: int,
@@ -505,16 +501,12 @@ class PrefixSpillStore:
         mirrors ``PrefixCacheDirectory.deepest_covered`` (full blocks
         only, consecutive from the root)."""
         best: Tuple[int, Optional[bytes]] = (0, None)
-        parent = b""
-        for j in range((len(prompt) - 1) // block_size):
-            chunk = tuple(int(t)
-                          for t in prompt[j * block_size:
-                                          (j + 1) * block_size])
-            digest = hash_fn(parent, chunk)
+        for depth, (digest, _) in enumerate(walk_chain(
+                prompt, block_size, (len(prompt) - 1) // block_size,
+                hash_fn), 1):
             entry = self._index.get(digest.hex())
-            if entry is not None and entry[0] == j + 1:
-                best = (j + 1, digest)
-            parent = digest
+            if entry is not None and entry[0] == depth:
+                best = (depth, digest)
         return best
 
     def read(self, digest: bytes) -> KVHandoff:

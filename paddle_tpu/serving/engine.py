@@ -471,13 +471,17 @@ class _SlotRun:
     the moment the first token existed (prefill completion) — the TTFT
     timestamp. ``block_ids``: the paged engine's arena blocks to
     release at retirement (None on the dense engine);
-    ``window``: what the hybrid engine's run holds in its window pool."""
+    ``window``: what the hybrid engine's run holds in its window pool;
+    ``chain``: ``(digest, tokens)`` of the leading blocks of what the run
+    writes (prompt, then history) that the paged engine's block manager
+    has hashed so far — a cache it extends, never serialized."""
     request: object
     tokens: List[int] = field(default_factory=list)
     t_admit: float = 0.0
     t_done: float = 0.0
     block_ids: Optional[List[int]] = None
     window: Optional[object] = None
+    chain: list = field(default_factory=list)
     # set when the request was cancelled/quarantined instead of
     # completing ("timeout", "poisoned", "circuit_open", ...); the
     # Server records a RequestFailure in results instead of tokens

@@ -331,8 +331,8 @@ class PrefillPagedEngine(_PrefillEngineMixin, PagedEngine):
     #: local match (Fleet._fetch_prefix). None outside a fleet.
     prefix_fetcher = None
 
-    def _match_prefix_for_admission(self, full):
-        shared = self.manager.match_prefix(full)
+    def _match_prefix_for_admission(self, full, chain):
+        shared = self.manager.match_prefix(full, chain)
         if self.prefix_fetcher is not None:
             fetched = self.prefix_fetcher(full, shared)
             if fetched:
@@ -369,7 +369,7 @@ class PrefillPagedEngine(_PrefillEngineMixin, PagedEngine):
             if eos is not None and tok0 == eos:
                 rem0 = 0
             tokens, orig_len = None, None
-        self.manager.register_prefix(job.prompt, job.run.block_ids)
+        self._register_prompt(job)
         if rem0 <= 0:                       # finished at admission
             self._prefill_slots.discard(job.slot)
             self._retire(job.slot, job.run, now)

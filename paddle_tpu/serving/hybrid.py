@@ -86,11 +86,12 @@ class HybridPagedStepBackend(PagedModelStepBackend):
 @dataclass
 class _WindowHold:
     """What a run holds in the window pool: every block to release, the
-    window group's table row (column -> block), and the chain positions
-    ``(chain, first)`` to register once the prefill has written them."""
+    window group's table row (column -> block), and the positions
+    ``(first, stop)`` of the run's chain to register once the prefill has
+    written them."""
     block_ids: List[int]
     row: np.ndarray
-    plant: Optional[Tuple[list, int]] = None
+    plant: Optional[Tuple[int, int]] = None
 
 
 class HybridPagedEngine(PagedEngine):
@@ -174,7 +175,8 @@ class HybridPagedEngine(PagedEngine):
         The hit's cut is the longest prefix whose every block the full
         group holds AND whose tail the window group holds."""
         fm, wm, T = self.manager, self.window_manager, self.tail_blocks
-        found = fm.find_prefix(full)
+        chain = []
+        found = fm.find_prefix(full, chain)
         wm.lookups += 1
 
         def tail(n):
@@ -221,15 +223,14 @@ class HybridPagedEngine(PagedEngine):
         wrow[planted] = own_w[n_ring:]
         wrow[ring_cols] = np.asarray(own_w[:n_ring], np.int32)[
             np.arange(len(ring_cols)) % max(n_ring, 1)]
-        hold = _WindowHold(
-            shared_w + own_w, wrow,
-            ([(d, c) for d, c, _ in found], planted[0]) if planted else None)
+        hold = _WindowHold(shared_w + own_w, wrow,
+                           (planted[0], len(found)) if planted else None)
         return _Reservation(
             shared + fresh, row, cut,
             {"fresh_blocks": len(fresh), "window_blocks": len(own_w),
              "shared_window_blocks": len(shared_w),
              "window_evicted_blocks": wm.evictions - evicted_w},
-            window=hold)
+            chain, window=hold)
 
     def _try_admit(self, request):
         if getattr(request, "resume", None) is not None:
@@ -242,8 +243,9 @@ class HybridPagedEngine(PagedEngine):
         super()._register_prompt(job)
         hold = job.run.window
         if hold.plant is not None:
-            chain, first = hold.plant
-            self.window_manager.register_chain(chain, hold.row, first)
+            first, stop = hold.plant
+            self.window_manager.register_chain(job.run.chain[:stop],
+                                               hold.row, first)
 
     def _register_written(self, run, chain):
         """The full group indexes every written block; the window group

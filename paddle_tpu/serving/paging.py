@@ -121,12 +121,45 @@ def _sha1_chain(parent_digest: bytes, tokens: Tuple[int, ...]) -> bytes:
     return h.digest()
 
 
+def walk_chain(tokens, block_size: int, stop: int, hash_fn=None,
+               start: int = 0, parent: bytes = b""):
+    """``(digest, tokens)`` of the full blocks ``start .. stop - 1`` of a
+    sequence, one at a time as they are asked for; ``parent`` is block
+    ``start - 1``'s digest. THE walk over a digest chain: the prefix
+    index, the fleet's directory and the spill tier all take it. The
+    sequence is touched in bulk — one little-endian int32 copy of the
+    blocks walked, one ``tolist()`` for the token tuples the index
+    stores and compares, one ``tobytes()`` — and a block costs one sha1
+    over ``parent + its 4 * block_size bytes``: byte for byte
+    :func:`_sha1_chain`'s digest. A caller's own ``hash_fn`` is called
+    a block with ``(parent, tuple)`` instead. A trailing partial block
+    is never hashed. Tokens are int32 ids; a wider dtype is cast."""
+    bs = block_size
+    seq = np.ascontiguousarray(
+        np.asarray(tokens)[start * bs:stop * bs], dtype="<i4")
+    flat = seq.tolist()
+    n = len(flat) // bs
+    if hash_fn is None or hash_fn is _sha1_chain:
+        raw, width, sha1 = seq.tobytes(), 4 * bs, hashlib.sha1
+        for j in range(n):
+            parent = sha1(parent + raw[j * width:(j + 1) * width]).digest()
+            yield parent, tuple(flat[j * bs:(j + 1) * bs])
+    else:
+        for j in range(n):
+            chunk = tuple(flat[j * bs:(j + 1) * bs])
+            parent = hash_fn(parent, chunk)
+            yield parent, chunk
+
+
 class BlockManager:
     """Host-side arena bookkeeping: free list, per-block refcounts,
     rolling-hash prefix index with LRU retention of released registered
     blocks. Pure python — it runs once per admission/retirement, never
     inside the compiled stream, but the device has nothing queued while
-    an admission runs, so its cost is idle time. A block handed out
+    an admission runs, so its cost is idle time. A digest is computed by
+    :func:`walk_chain` and counted in ``hashed_blocks``; a caller that
+    keeps the chain it was given (:meth:`extend_chain`) has each of a
+    sequence's blocks hashed once. A block handed out
     costs O(1) from the free list and O(distinct hit tallies among the
     retained blocks) by eviction — a handful — whatever the number of
     retained blocks: the eviction order is kept as blocks park and
@@ -159,6 +192,7 @@ class BlockManager:
         self.lookups = 0
         self.hit_blocks = 0
         self.evictions = 0
+        self.hashed_blocks = 0      # digests computed, ever
         self._note_pool()
 
     def _note_pool(self):
@@ -321,26 +355,35 @@ class BlockManager:
         # logits exist
         return (len(prompt) - 1) // self.block_size
 
-    def find_prefix(self, prompt) -> List[Tuple[bytes, Tuple[int, ...],
-                                                 int]]:
+    def _walk(self, tokens, stop: int, chain: list):
+        """:func:`walk_chain` from where ``chain`` ends to block ``stop``,
+        each block counted and appended to ``chain`` as it is hashed."""
+        for entry in walk_chain(tokens, self.block_size, stop, self.hash_fn,
+                                len(chain), chain[-1][0] if chain else b""):
+            self.hashed_blocks += 1
+            chain.append(entry)
+            yield entry
+
+    def find_prefix(self, prompt, chain: Optional[list] = None
+                    ) -> List[Tuple[bytes, Tuple[int, ...], int]]:
         """Longest chain of indexed blocks matching the prompt's full
         prefix blocks, as ``(digest, tokens, block)`` a block; nothing is
         acquired. A digest hit whose stored tokens differ (hash
         collision) stops the chain — the caller just recomputes from
-        there."""
-        bs = self.block_size
+        there — and nothing past the first miss is hashed. ``chain``, an
+        empty list, receives ``(digest, tokens)`` of every block hashed
+        (the matches and the block the match stopped at) for the caller
+        to carry: :meth:`extend_chain`."""
         self.lookups += 1
         _M_PFX_LOOKUPS.inc()
         found = []
-        parent = b""
-        for j in range(self._shareable_blocks(prompt)):
-            chunk = tuple(int(t) for t in prompt[j * bs:(j + 1) * bs])
-            digest = self.hash_fn(parent, chunk)
+        for digest, chunk in self._walk(
+                prompt, self._shareable_blocks(prompt),
+                [] if chain is None else chain):
             entry = self._index.get(digest)
             if entry is None or entry[1] != chunk:
                 break
             found.append((digest, chunk, entry[0]))
-            parent = digest
         return found
 
     def block_of(self, digest: bytes, chunk) -> Optional[int]:
@@ -360,9 +403,10 @@ class BlockManager:
         _M_PFX_HITS.inc(len(blocks))
         self._note_pool()
 
-    def match_prefix(self, prompt) -> List[int]:
+    def match_prefix(self, prompt, chain: Optional[list] = None
+                     ) -> List[int]:
         """:meth:`find_prefix`, each match ref-acquired for the caller."""
-        blocks = [b for _, _, b in self.find_prefix(prompt)]
+        blocks = [b for _, _, b in self.find_prefix(prompt, chain)]
         self.acquire_hits(blocks)
         return blocks
 
@@ -372,17 +416,18 @@ class BlockManager:
             self._unpark(block_id)
         self._ref[block_id] = r + 1
 
+    def extend_chain(self, chain: list, tokens, n_blocks: int) -> list:
+        """Extend ``chain`` — ``(digest, tokens)`` of the first
+        ``len(chain)`` full blocks of ``tokens`` — in place to
+        ``n_blocks``, hashing only the blocks it lacks, and return it."""
+        for _ in self._walk(tokens, n_blocks, chain):
+            pass
+        return chain
+
     def chain(self, tokens, n_blocks: int) -> List[Tuple[bytes,
                                                           Tuple[int, ...]]]:
         """``(digest, tokens)`` of the first ``n_blocks`` full blocks."""
-        bs = self.block_size
-        out = []
-        parent = b""
-        for j in range(n_blocks):
-            chunk = tuple(int(t) for t in tokens[j * bs:(j + 1) * bs])
-            parent = self.hash_fn(parent, chunk)
-            out.append((parent, chunk))
-        return out
+        return self.extend_chain([], tokens, n_blocks)
 
     def register_chain(self, chain, block_ids: Sequence[int],
                        first: int = 0):
@@ -722,13 +767,15 @@ class _PrefillJob:
 class _Reservation:
     """What an admission holds once its blocks are reserved: the run's
     blocks (shared prefix first), its table row, how many leading prompt
-    blocks were served from the prefix index, and what the
-    ``serving.admit`` span says of it. An engine with a second pool adds
-    what the run holds there (``window``)."""
+    blocks were served from the prefix index, what the ``serving.admit``
+    span says of it, and the digests the lookup computed (``chain``, for
+    the run to carry). An engine with a second pool adds what the run
+    holds there (``window``)."""
     block_ids: List[int]
     table_row: np.ndarray
     shared_blocks: int
     span_ids: dict
+    chain: list
     window: Optional[object] = None
 
 
@@ -933,20 +980,24 @@ class PagedEngine(ContinuousBatchingEngine):
         request (the chunks themselves run in :meth:`prefill_tick`);
         False when the block pool cannot hold it yet. The span says how
         many blocks the admission allocated (``fresh_blocks``) and how
-        many of those it took by eviction (``evicted_blocks``)."""
+        many of those it took by eviction (``evicted_blocks``), and how
+        many block digests it computed (``hashed_blocks``)."""
         with _span("serving.admit", rid=request.request_id) as sp:
-            evicted = self.manager.evictions
+            m = self.manager
+            evicted, hashed = m.evictions, m.hashed_blocks
             ids = self._try_admit(request)
             sp.ids.update(
                 ids or {"fresh_blocks": 0},
-                evicted_blocks=self.manager.evictions - evicted)
+                evicted_blocks=m.evictions - evicted,
+                hashed_blocks=m.hashed_blocks - hashed)
             return ids is not None
 
     def _reserve(self, full, mnt) -> Optional[_Reservation]:
         """Blocks and table row for a request of prompt ``full`` and
         ``mnt`` new tokens: the matched prefix's blocks shared, the rest
         fresh; None when the pool cannot cover it yet (nothing held)."""
-        shared = self._match_prefix_for_admission(full)
+        chain = []
+        shared = self._match_prefix_for_admission(full, chain)
         total = self.blocks_needed(len(full), mnt)
         fresh = self.manager.allocate(total - len(shared))
         if fresh is None:            # pool exhausted: retry later
@@ -956,7 +1007,7 @@ class PagedEngine(ContinuousBatchingEngine):
         table_row = np.zeros((self.max_blocks,), np.int32)
         table_row[:len(block_ids)] = block_ids
         return _Reservation(block_ids, table_row, len(shared),
-                            {"fresh_blocks": len(fresh)})
+                            {"fresh_blocks": len(fresh)}, chain)
 
     def _try_admit(self, request) -> Optional[dict]:
         """The admission proper: what its span says of the blocks it
@@ -1004,7 +1055,7 @@ class PagedEngine(ContinuousBatchingEngine):
             run = _SlotRun(request, tokens=list(resume.tokens),
                            t_admit=resume.t_admit, block_ids=block_ids)
             resume_tok = int(resume.tokens[-1])
-        run.window = held.window
+        run.window, run.chain = held.window, held.chain
         self._slots[slot] = run
         self._prefill_slots.add(slot)
         n_shared = held.shared_blocks * self.kv_block_size
@@ -1018,14 +1069,14 @@ class PagedEngine(ContinuousBatchingEngine):
             topp=jnp.float32(request.top_p), resume_tok=resume_tok))
         return held.span_ids
 
-    def _match_prefix_for_admission(self, full) -> List[int]:
+    def _match_prefix_for_admission(self, full, chain: list) -> List[int]:
         """Admission-time prefix match. The base engine consults only
         its LOCAL index; the fleet's prefill engines override this to
         also fetch a longer chain another worker has registered
         (serving/prefix_cache.py) — either way the returned blocks are
         ref-acquired for the admitting request and ``done`` starts past
-        them."""
-        return self.manager.match_prefix(full)
+        them. ``chain`` receives the digests the local lookup computed."""
+        return self.manager.match_prefix(full, chain)
 
     def admit(self, request) -> bool:
         if not self.try_admit(request):
@@ -1116,7 +1167,14 @@ class PagedEngine(ContinuousBatchingEngine):
                                    slot=job.slot)
 
     def _register_prompt(self, job: _PrefillJob):
-        self.manager.register_prefix(job.prompt, job.run.block_ids)
+        """Index the prompt's shareable blocks (now filled) from the
+        chain the run carries since its admission, extended over the
+        blocks the lookup stopped short of."""
+        m, run = self.manager, job.run
+        m.register_chain(
+            m.extend_chain(run.chain, job.prompt,
+                           m._shareable_blocks(job.prompt)),
+            run.block_ids)
 
     def _arm(self, slot, table_row, tok0, pos0, rem0, eos, temp, topk,
              topp, key):
@@ -1205,12 +1263,14 @@ class PagedEngine(ContinuousBatchingEngine):
                 # later request continuing this conversation shares the
                 # decode-position KV too. Failed/poisoned runs register
                 # NOTHING (a poisoned block must never be matchable).
+                # The run's chain already holds the prompt's shareable
+                # blocks: only the blocks written since are hashed.
                 seq = np.concatenate([
                     np.asarray(run.request.prompt, np.int32).reshape(-1),
                     np.asarray(run.tokens[:-1], np.int32)])
                 self._register_written(
-                    run, self.manager.chain(
-                        seq, len(seq) // self.kv_block_size))
+                    run, self.manager.extend_chain(
+                        run.chain, seq, len(seq) // self.kv_block_size))
             self._release_slot_resources(run)
 
     def _register_written(self, run, chain):

@@ -52,6 +52,7 @@ import numpy as np
 
 from ..observability import metrics as _om
 from .handoff import FETCH_FORMAT, KVHandoff, reshard_kv_chunks
+from .paging import walk_chain
 
 __all__ = ["PrefixCacheDirectory", "adopt_prefix", "extract_prefix"]
 
@@ -141,21 +142,18 @@ class PrefixCacheDirectory:
         root, and the workers that do. A worker listing only a chain
         tail (its chain head was LRU-evicted) is not an owner — its
         own ``match_prefix`` could not serve the fetch."""
-        bs = block_size
         excl = set(exclude)
         best: Tuple[int, Tuple[str, ...]] = (0, ())
         alive: Optional[set] = None
-        parent = b""
-        for j in range((len(prompt) - 1) // bs):
-            chunk = tuple(int(t) for t in prompt[j * bs:(j + 1) * bs])
-            digest = hash_fn(parent, chunk)
+        for depth, (digest, _) in enumerate(walk_chain(
+                prompt, block_size, (len(prompt) - 1) // block_size,
+                hash_fn), 1):
             cand = {o for o in self._owners.get(digest, ())
                     if o not in excl}
             alive = cand if alive is None else (alive & cand)
             if not alive:
                 break
-            best = (j + 1, tuple(sorted(alive)))
-            parent = digest
+            best = (depth, tuple(sorted(alive)))
         return best
 
     def stats(self) -> dict:

@@ -713,6 +713,9 @@ class Server:
             # serving.admit span carries its own: evicted_blocks of its
             # fresh_blocks) or by the fleet's watermark tier
             out["block_evictions"] = eng.manager.evictions
+            # block digests the prefix index computed: once a block a
+            # request wrote or matched (serving.admit carries its own)
+            out["hashed_blocks"] = eng.manager.hashed_blocks
             out["attn_sites"] = eng.attn_sites
             if eng.cache_passes > 1:           # a looped model's extras
                 out["ut_steps"] = eng.ut_steps
@@ -720,6 +723,7 @@ class Server:
             wm = getattr(eng, "window_manager", None)
             if wm is not None:                 # the hybrid cache's 2nd pool
                 out["window_block_evictions"] = wm.evictions
+                out["hashed_blocks"] += wm.hashed_blocks
         return out
 
     def export_trace(self, path: str) -> str:

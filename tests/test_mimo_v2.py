@@ -557,6 +557,75 @@ def test_window_arenas_do_not_grow_with_max_len(f32_model):
                for shape, _ in shapes[64])
 
 
+@pytest.fixture(scope="module")
+def hybrid_lives(f32_model):
+    """Four requests behind one 64-token prefix, one at a time (cold; the
+    one that sets the tail aside; two hits), then one that continues the
+    last stream: what each life hashed and what it left in each index."""
+    from paddle_tpu.observability import tracing
+    shared = ids_of(64, seed=6)
+    prompts = [np.concatenate([shared, ids_of(n, seed=30 + n)])
+               for n in (9, 13, 11, 7)]
+    eng, srv = engine_of(f32_model)
+    fm, wm = eng.manager, eng.window_manager
+    lives = []
+    for i in range(5):
+        p = prompts[i] if i < 4 else np.concatenate(
+            [lives[3]["row"], ids_of(6, seed=77)])
+        hashed, matched = fm.hashed_blocks, eng.shared_tokens
+        rid = srv.submit(p, max_new_tokens=24)
+        srv.run_until_idle()
+        admit = [s.ids for s in tracing.since(0)
+                 if s.name == "serving.admit"][-1]
+        lives.append(dict(
+            prompt=p, row=np.asarray(srv.results[rid]),
+            hashed=fm.hashed_blocks - hashed, admit=admit,
+            matched=(eng.shared_tokens - matched) // 8,
+            stats=srv.stats()["hashed_blocks"], total=fm.hashed_blocks,
+            by_window=wm.hashed_blocks, index=dict(fm._index),
+            window_index=dict(wm._index)))
+    fm.assert_consistent(), wm.assert_consistent()
+    return lives
+
+
+@pytest.mark.parametrize("i,matched,hashed_at_admission", [
+    (0, 0, 1), (1, 0, 9), (2, 8, 9), (3, 8, 8), (4, 11, 12)],
+    ids=["cold", "sets_the_tail_aside", "hit", "hit_to_the_last_block",
+         "continues"])
+def test_hybrid_engine_hashes_a_block_once_for_both_groups(
+        hybrid_lives, i, matched, hashed_at_admission):
+    """``hashed_blocks`` rises by the blocks a request wrote or matched,
+    once each, and the window group registers (the tail set aside at the
+    end of prefill, the ring's tail at retirement) from the SAME list:
+    its manager hashes nothing, and every digest it holds is the full
+    group's digest of that block."""
+    from paddle_tpu.serving.paging import _sha1_chain
+    life = hybrid_lives[i]
+    row = life["row"]
+    assert life["matched"] == matched
+    assert life["hashed"] == (len(row) - 1) // 8
+    assert life["admit"]["hashed_blocks"] == hashed_at_admission
+    assert life["stats"] == life["total"] and life["by_window"] == 0
+    # the per-token fold of the written sequence, a reference kept here
+    want, parent = [], b""
+    for j in range((len(row) - 1) // 8):
+        chunk = tuple(int(t) for t in row[j * 8:(j + 1) * 8])
+        parent = _sha1_chain(parent, chunk)
+        want.append((parent, chunk))
+    index, window_index = life["index"], life["window_index"]
+    assert all(index[d][1] == chunk for d, chunk in want)
+    held = [j for j, (d, chunk) in enumerate(want)
+            if d in window_index and window_index[d][1] == chunk]
+    # the ring's tail before the last written block; from the request that
+    # set it aside on, the prefix's tail (blocks 6 and 7 of 8); and under
+    # the continuation, the tail the stream it continues left
+    tails = [8 if i else 0, len(want)]
+    if i == 4:
+        tails.insert(1, (len(hybrid_lives[3]["row"]) - 1) // 8)
+    assert held == [j for n in tails for j in range(max(n - 2, 0), n)]
+    assert set(window_index) <= set(index)
+
+
 def test_both_pools_are_consistent_after_a_churn(f32_model):
     """Admissions, retirements and evictions in BOTH pools: small pools,
     two prefixes, requests that hit, miss, set a tail aside and wait for

@@ -353,10 +353,14 @@ def test_hybrid_engine_span_and_counter_names():
     srv.run_until_idle()
     admit = [sp for sp in tracing.since(t0) if sp.name == "serving.admit"][0]
     assert set(admit.ids) == {"rid", "fresh_blocks", "evicted_blocks",
-                              "window_blocks", "shared_window_blocks",
+                              "hashed_blocks", "window_blocks",
+                              "shared_window_blocks",
                               "window_evicted_blocks"}
     assert (admit.ids["fresh_blocks"], admit.ids["window_blocks"]) == (3, 3)
     stats = srv.stats()
+    # a cold lookup hashes the block it misses on; the life, the three
+    # blocks it wrote, in the full group's manager for both groups
+    assert (admit.ids["hashed_blocks"], stats["hashed_blocks"]) == (1, 3)
     assert stats["window_block_evictions"] == stats["block_evictions"] == 0
 
 
@@ -481,7 +485,8 @@ def test_decode_block_span_counter_names(tiny):
 
 def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
     """``serving.admit`` carries ``fresh_blocks`` (allocated by this
-    admission) and ``evicted_blocks`` (of those, taken by evicting a
+    admission), ``hashed_blocks`` (block digests this admission computed)
+    and ``evicted_blocks`` (of the fresh ones, taken by evicting a
     retained prefix block), and ``Server.stats()["block_evictions"]`` is
     the engine's total: 0 until the arena has turned over."""
     import time
@@ -502,8 +507,12 @@ def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
     srv.run_until_idle()
     admits = [sp for sp in tracing.since(t0) if sp.name == "serving.admit"]
     assert [sp.ids["rid"] for sp in admits] == rids
-    assert all(set(sp.ids) == {"rid", "fresh_blocks", "evicted_blocks"}
-               for sp in admits)
+    assert all(set(sp.ids) == {"rid", "fresh_blocks", "evicted_blocks",
+                               "hashed_blocks"} for sp in admits)
+    # a cold lookup hashes the one block it misses on; a life, every
+    # block it wrote: (19 + 6 - 1) // 8
+    assert [sp.ids["hashed_blocks"] for sp in admits] == [1] * 6
+    assert srv.stats()["hashed_blocks"] == eng.manager.hashed_blocks == 18
     # distinct prompts: nothing shared, three blocks each
     assert [sp.ids["fresh_blocks"] for sp in admits] == [3] * 6
     evicted = [sp.ids["evicted_blocks"] for sp in admits]
