@@ -16,7 +16,12 @@ compiled program.
   wait / unpickle / collate, ``TrainStep``'s dispatch and every
   ``profiler.RecordEvent`` site are spans of it; the chrome-trace
   export, ``paddle_tpu.profiler`` and the benchmark's per-layer metric
-  readers all read that one ring. The same module holds the opt-in
+  readers all read that one ring. A point inside a span is a mark on it
+  (``Span.mark``: ``<name>_ns`` among its ids, ring-only), not a child
+  span; the serving engine's two device syncs are bracketed by a
+  ``StallWatch``, which writes on a sync that lasted far beyond its
+  kind's median what the process and the thread did meanwhile (the
+  Server's flight event ``sync_stall``). The same module holds the opt-in
   per-request lifecycle traces (``PT_TRACE_REQUESTS=1``): queue-wait,
   prefill (chunk) spans, decode residency, harvest, exactly one
   terminal state per request, exported on the ring's clock so one

@@ -2,7 +2,8 @@
 
 The serving loop records what a post-mortem needs — per-tick summaries,
 fault fires surfaced as step failures, retries, quarantines, load
-sheds, block-pool pressure, breaker transitions — into a fixed-size
+sheds, block-pool pressure, breaker transitions, device syncs that
+stalled (``sync_stall``: what the host did meanwhile) — into a fixed-size
 ring (``PT_FLIGHT_RECORDER_SIZE``, default 256 events). The ring is the
 black box: when the circuit breaker opens the Server auto-dumps it to a
 JSON file (atomic tmp+rename via the checkpoint helpers), and every

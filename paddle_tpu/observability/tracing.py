@@ -11,8 +11,11 @@ ids it was given (``tick=<Server._clock>``, ``rid=<request id>``,
 - a process-global ring, ``deque(maxlen=65536)`` of finished spans. It
   is always on and bounded, as the flight recorder's ring is: there is
   no switch, so the price (1.4 us a span on the chip's host, 2.8 us
-  inside a profiler session: PR 25's chip run, PERF.md section 5; at
-  most about 18 MB when the ring is full) is always paid, and spans are
+  inside a profiler session: PR 25's chip run; 0.3 us a mark, 7.4 us a
+  :class:`StallWatch` pair, 6.1 of them its one ``getrusage``, 17 us a
+  serving tick for all the marks and watches: PR 35's,
+  ``tools/sync_stamp_probe.py``; PERF.md section 3; at most about 18 MB
+  when the ring is full) is always paid, and spans are
   recorded at tick / step / batch granularity only — never per token,
   per slot or per layer, and never inside a compiled program;
 - a ``jax.profiler.TraceAnnotation`` entered and left with the span, so
@@ -20,12 +23,29 @@ ids it was given (``tick=<Server._clock>``, ``rid=<request id>``,
   ``/host:CPU`` plane of the ``.xplane.pb``, on the device trace's
   clock. With no session open that costs one ``is_enabled()`` check.
 
+**Marks.** ``Span.mark(name)`` on an OPEN span stores
+``ids[name + "_ns"]``, the nanoseconds since the span began: a point
+inside a span without a child span (a child with a new name would take
+its time out of the parent's self time, which metrics sum by name). An
+id or a mark set after ``__enter__`` is in the ring and NOT in the
+``TraceAnnotation`` twin, which took its ids when it opened: marks are
+ring-only.
+
+**Stalls.** :class:`StallWatch` brackets a recurring blocking span (the
+serving engine's two device syncs): it keeps the last durations a kind
+and, when one lasts far beyond their median, writes on that span what
+the process and the thread did meanwhile (``getrusage``, this thread's
+``schedstat``).
+
 Readers of the ring: :func:`since` (spans wholly inside an interval),
 :func:`self_times` (duration minus the part the children cover, by
 name), :func:`chrome_events` / :func:`export_chrome_trace` (Perfetto),
 ``paddle_tpu.profiler`` (``RecordEvent`` is a ring span; a ``Profiler``
 exports the spans of its recording intervals) and the benchmark's
-per-layer metric readers (``benchmark/layer_metrics``).
+per-layer metric readers (``benchmark/layer_metrics``: seven over the
+seconds under the profiler, ``benchmark/span_metrics.py``; five over the
+rest of the window, stamped with the profiler off,
+``benchmark/window_spans.py``).
 
 Clocks: ring stamps are ``perf_counter_ns`` (CLOCK_MONOTONIC); the
 ``.xplane.pb`` stamps its events in nanoseconds since the profiler
@@ -65,6 +85,7 @@ from __future__ import annotations
 import itertools
 import json
 import os
+import resource
 import threading
 import time
 from collections import deque
@@ -77,7 +98,8 @@ from ..utils.flags import env_bool
 
 __all__ = ["Span", "span", "begin", "end", "since", "self_times", "clear",
            "chrome_events", "named_program", "RequestTracer",
-           "RequestTrace", "export_chrome_trace", "now_us", "RING_SIZE"]
+           "RequestTrace", "export_chrome_trace", "now_us", "RING_SIZE",
+           "StallWatch"]
 
 _SERVER_TID = 0
 _SERVER_PREFIX = "serving."
@@ -103,9 +125,10 @@ class Span:
     (``dur`` is None while the span is open); ``id`` (process-unique);
     ``parent``, the id of the span open on the same thread when this one
     began (None at the top); ``tid``, the thread; ``ids``, the keywords
-    it was given. Use as ``with span("serving.tick", tick=n):`` or as
-    ``s = begin(...)`` ... ``end(s)``; spans of one thread must end in
-    the reverse of the order they began."""
+    it was given and what was stored since (:meth:`mark`). Use as
+    ``with span("serving.tick", tick=n):`` or as ``s = begin(...)`` ...
+    ``end(s)``; spans of one thread must end in the reverse of the order
+    they began."""
 
     __slots__ = ("name", "ids", "id", "parent", "tid", "start", "dur",
                  "_ta")
@@ -144,6 +167,15 @@ class Span:
             stack.remove(self)              # other spans' parents intact
         _RING.append(self)
         return False
+
+    def mark(self, name: str) -> int:
+        """Stamp a point inside this OPEN span: ``ids[name + "_ns"]`` is
+        the nanoseconds since the span began. Ring-only (the twin in a
+        profiler session took its ids at ``__enter__``). Returns the
+        stamp itself, on the ring's clock."""
+        now = _now_ns()
+        self.ids[name + "_ns"] = now - self.start
+        return now
 
     def __repr__(self):
         return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
@@ -194,6 +226,94 @@ def self_times(records: Iterable[Span]) -> Dict[str, List[int]]:
 def clear():
     """Empty the ring (tests; a long-lived process never needs to)."""
     _RING.clear()
+
+
+# A stall is a span that lasts more than STALL_FACTOR times the median of
+# the last STALL_HISTORY of its name AND STALL_EXCESS_NS more than it; no
+# verdict before STALL_MIN_HISTORY durations of the name are known.
+STALL_HISTORY = 32
+STALL_MIN_HISTORY = 8
+STALL_FACTOR = 3
+STALL_EXCESS_NS = 100_000_000
+_SCHEDSTAT = "/proc/thread-self/schedstat"
+_getrusage = resource.getrusage
+_RUSAGE_SELF = resource.RUSAGE_SELF
+
+
+class StallWatch:
+    """Says of a recurring blocking span (a device sync) whether this one
+    stalled, and what the host did meanwhile.
+
+    ``began = watch.begin()`` before the span opens, ``watch.end(sp,
+    began)`` after it closed. ``begin`` reads the process's ``getrusage``
+    and this thread's ``/proc/thread-self/schedstat`` (a descriptor kept
+    open, one a thread at a time; skipped where the kernel keeps no such
+    file); ``end`` compares ``sp.dur`` with the median of the last
+    durations of the spans of its name and, on a stall only, reads both
+    again and stores on the span (ring-only) and returns::
+
+        stall=1        over_ns       dur - median
+        nivcsw nvcsw   context switches of the PROCESS, forced / voluntary
+        majflt         its major page faults
+        cpu_ms         its user + system time: near 0 says every thread
+                       slept (driver, runtime, link); near dur x threads
+                       says the client's own threads were busy
+        run_delay_ms   this thread runnable and not run: the host's cores
+                       were taken away
+
+    None where the span is no stall."""
+
+    def __init__(self):
+        self._durs = {}                # span name -> its last durations
+        self._sched = (None, None)     # (thread, its schedstat's descriptor)
+
+    def _schedstat(self) -> Optional[bytes]:
+        """b"<on-cpu ns> <runnable, waiting ns> <slices>" of this thread."""
+        tid, fd = self._sched
+        if tid != _thread_id():
+            self.close()
+            try:
+                fd = os.open(_SCHEDSTAT, os.O_RDONLY)
+            except OSError:
+                fd = None
+            self._sched = (_thread_id(), fd)
+        return None if fd is None else os.pread(fd, 64, 0)
+
+    def begin(self):
+        return _getrusage(_RUSAGE_SELF), self._schedstat()
+
+    def end(self, sp: Span, began) -> Optional[dict]:
+        durs = self._durs.get(sp.name)
+        if durs is None:
+            durs = self._durs[sp.name] = deque(maxlen=STALL_HISTORY)
+        dur, fields = sp.dur, None
+        if len(durs) >= STALL_MIN_HISTORY:
+            median = sorted(durs)[len(durs) // 2]
+            over = dur - median
+            if dur > STALL_FACTOR * median and over > STALL_EXCESS_NS:
+                (ru0, sched0), (ru1, sched1) = began, self.begin()
+                fields = {
+                    "stall": 1, "over_ns": over,
+                    "nivcsw": ru1.ru_nivcsw - ru0.ru_nivcsw,
+                    "nvcsw": ru1.ru_nvcsw - ru0.ru_nvcsw,
+                    "majflt": ru1.ru_majflt - ru0.ru_majflt,
+                    "cpu_ms": round((ru1.ru_utime + ru1.ru_stime
+                                     - ru0.ru_utime - ru0.ru_stime) * 1e3, 3)}
+                if sched0 is not None and sched1 is not None:
+                    fields["run_delay_ms"] = round(
+                        (int(sched1.split()[1]) - int(sched0.split()[1]))
+                        / 1e6, 3)
+                sp.ids.update(fields)
+        durs.append(dur)
+        return fields
+
+    def close(self):
+        fd = self._sched[1]
+        if fd is not None:
+            os.close(fd)
+        self._sched = (None, None)
+
+    __del__ = close
 
 
 def named_program(fn, program: str):

@@ -33,8 +33,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
-from ..observability.tracing import (named_program, now_us as _trace_now,
-                                     span as _span)
+from ..observability.tracing import (StallWatch, named_program,
+                                     now_us as _trace_now, span as _span)
 from ..utils import faults
 
 # engine metric families (no-ops until metrics.enable()/PT_METRICS)
@@ -618,6 +618,56 @@ class ContinuousBatchingEngine:
         self.tokens_emitted = 0       # useful tokens (incl. prefill's)
         self.decode_tokens = 0        # live-slot decode steps only
         self.slot_steps = 0           # S * steps (occupancy denominator)
+        # time the chip was left with an empty queue while work was held
+        # (:meth:`_enqueued`), and when a sync last said it had drained
+        self.device_starved_ns = 0
+        self._drained_ns = None
+        # syncs that lasted far beyond their kind's median (S8)
+        self._stall_watch = StallWatch()
+        self.sync_stalls = 0
+        self.sync_stall_ns = 0        # what they lasted beyond it
+        self._stalls_untold: List[dict] = []   # for the Server's flight ring
+
+    # -- the chip's empty queue, and syncs that stall -------------------------
+    def _enqueued(self, sp):
+        """A chunk program or a decode block has just been enqueued
+        (``sp``: the open span around the enqueue). If a sync had said
+        that everything enqueued before it was finished, the chip sat
+        with an empty queue from then to now: that is counted in
+        ``device_starved_ns`` and written on ``sp`` as ``starved_ns``.
+        Measured from when the host LEARNS the device is drained to when
+        an enqueue RETURNS, so never more than the device's idle time; the
+        small programs in between (the admission's uploads, arming a
+        slot) neither start nor end an interval, and an engine left
+        without a live slot forgets the stamp (:meth:`_free_slot`): idle
+        for want of traffic is not starvation. The dense engine's
+        whole-prompt prefill ends an interval and, its first token being
+        fetched outside any sync span, starts none."""
+        if self._drained_ns is not None:
+            ns = time.perf_counter_ns() - self._drained_ns
+            self._drained_ns = None
+            self.device_starved_ns += ns
+            sp.ids["starved_ns"] = ns
+
+    def _free_slot(self, slot: int):
+        self._slots[slot] = None
+        if self._drained_ns is not None and not self.has_live():
+            self._drained_ns = None
+
+    def _sync_ended(self, sp, began):
+        """After a blocking sync's span ``sp`` closed (``began``: the stall
+        watch's reading from before it opened): count a stall."""
+        stall = self._stall_watch.end(sp, began)
+        if stall is not None:
+            self.sync_stalls += 1
+            self.sync_stall_ns += stall["over_ns"]
+            self._stalls_untold.append(dict(
+                stall, sync=sp.name, dur_ms=round(sp.dur / 1e6, 3)))
+
+    def take_sync_stalls(self) -> List[dict]:
+        """The stalls since the last call, for the caller's flight ring."""
+        told, self._stalls_untold = self._stalls_untold, []
+        return told
 
     # -- introspection -----------------------------------------------------
     def free_slot_count(self) -> int:
@@ -767,10 +817,11 @@ class ContinuousBatchingEngine:
         temp = jnp.float32(request.temperature)   # <= 0 means greedy
         topk = jnp.int32(request.top_k)
         topp = jnp.float32(request.top_p)
-        with RecordEvent("serving.prefill"):
+        with RecordEvent("serving.prefill") as ev:
             tok0_dev, row = self.backend.prefill(
                 Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
                 sub, temp, topk, topp)
+            self._enqueued(ev._span)
         tok0 = int(tok0_dev)
         if tr is not None:
             tr.span_at(request.request_id, "prefill", t_prefill,
@@ -831,11 +882,12 @@ class ContinuousBatchingEngine:
         ids = np.zeros((1, Lb), np.int32)
         ids[0, Lb - pl:] = full
         pad0 = Lb - pl
-        with RecordEvent("serving.prefill"):
+        with RecordEvent("serving.prefill") as ev:
             _discard, row = self.backend.prefill(
                 Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
                 jax.random.PRNGKey(0), jnp.float32(0.0), jnp.int32(0),
                 jnp.float32(1.0))
+            self._enqueued(ev._span)
         if tr is not None:
             tr.span_at(request.request_id, "prefill", t_prefill,
                        tokens=pl, bucket=Lb, resumed=True)
@@ -918,7 +970,7 @@ class ContinuousBatchingEngine:
             self.tracer.span_end(rid, "decode", preempted=True)
             self.tracer.instant(rid, "preempt", slot=slot,
                                 tokens=len(run.tokens))
-        self._slots[slot] = None
+        self._free_slot(slot)
         self._remaining_host[slot] = 0
         self._release_slot_resources(run)
         return run, key
@@ -957,6 +1009,7 @@ class ContinuousBatchingEngine:
             with _span("serving.decode_block",
                        **self._decode_block_counters()) as self._block_span:
                 out = self.backend.decode_block(self._cache, self._state)
+                self._enqueued(self._block_span)
             self._cache, self._state = out[0], out[1]
             # old AOT artifacts predate the ok flags: pad with None
             self._pending_block = tuple(out[2:]) \
@@ -968,12 +1021,19 @@ class ContinuousBatchingEngine:
             self._note_decode_bytes(self.decode_block)
         faults.fault_point("serving.harvest")
         toks, lives, oks = self._pending_block
-        with _span("serving.decode_sync"):    # host blocked on the device
+        began = self._stall_watch.begin()
+        with _span("serving.decode_sync") as sp:   # host blocked on the device
             toks_np = np.asarray(toks)              # ONE host sync/block
+            # the block is done and nothing else is queued: what follows
+            # in this span are round trips to a drained device
+            self._drained_ns = sp.mark("first")
             lives_np = np.asarray(lives)            # (block, S)
             oks_np = None if oks is None else np.asarray(oks)
             rem_np = np.asarray(self._state["remaining"])
             counts_np = self._read_program_counters()
+            sp.ids["fetches"] = 3 + (oks is not None) \
+                + (counts_np is not None)
+        self._sync_ended(sp, began)
         self._pending_block = None
         with _span("serving.harvest"):
             self._credit_block(toks_np, lives_np, oks_np, rem_np)
@@ -1085,7 +1145,7 @@ class ContinuousBatchingEngine:
             self.tracer.span_end(run.request.request_id, "decode",
                                  tokens=len(run.tokens))
         self._finished.append(run)
-        self._slots[slot] = None
+        self._free_slot(slot)
 
     def drain_finished(self) -> List[_SlotRun]:
         done, self._finished = self._finished, []

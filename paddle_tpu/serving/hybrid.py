@@ -232,12 +232,12 @@ class HybridPagedEngine(PagedEngine):
              "window_evicted_blocks": wm.evictions - evicted_w},
             chain, window=hold)
 
-    def _try_admit(self, request):
+    def _try_admit(self, request, sp):
         if getattr(request, "resume", None) is not None:
             raise NotImplementedError(
                 "preemption resume over the hybrid cache is not carried "
                 "over (can_resume is False)")
-        return super()._try_admit(request)
+        return super()._try_admit(request, sp)
 
     def _register_prompt(self, job):
         super()._register_prompt(job)

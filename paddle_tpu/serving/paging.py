@@ -918,6 +918,7 @@ class PagedEngine(ContinuousBatchingEngine):
             setattr(self, "prefill_" + name, 0)
         self._chunk_span = None        # the latest ``serving.prefill_chunk``
         self._chunks_unread = 0        # chunks since the counters were read
+        self._arm_ns = 0               # ends of prefill since the last block
 
     # -- introspection -----------------------------------------------------
     def prefix_cache_hit_rate(self) -> float:
@@ -981,11 +982,15 @@ class PagedEngine(ContinuousBatchingEngine):
         False when the block pool cannot hold it yet. The span says how
         many blocks the admission allocated (``fresh_blocks``) and how
         many of those it took by eviction (``evicted_blocks``), and how
-        many block digests it computed (``hashed_blocks``)."""
+        many block digests it computed (``hashed_blocks``); an admission
+        that went through also marks where its time went: ``reserved_ns``
+        when the lookup, the allocation and the table row were done,
+        ``keyed_ns`` when the request's key was made and split; the rest
+        to the span's end is the sampling scalars' uploads and the job."""
         with _span("serving.admit", rid=request.request_id) as sp:
             m = self.manager
             evicted, hashed = m.evictions, m.hashed_blocks
-            ids = self._try_admit(request)
+            ids = self._try_admit(request, sp)
             sp.ids.update(
                 ids or {"fresh_blocks": 0},
                 evicted_blocks=m.evictions - evicted,
@@ -1009,9 +1014,10 @@ class PagedEngine(ContinuousBatchingEngine):
         return _Reservation(block_ids, table_row, len(shared),
                             {"fresh_blocks": len(fresh)}, chain)
 
-    def _try_admit(self, request) -> Optional[dict]:
-        """The admission proper: what its span says of the blocks it
-        allocated, or None when a pool cannot hold the request yet."""
+    def _try_admit(self, request, sp) -> Optional[dict]:
+        """The admission proper, marking its span ``sp``: what the span
+        says of the blocks it allocated, or None when a pool cannot hold
+        the request yet."""
         prompt = np.asarray(request.prompt, np.int32).reshape(-1)
         resume = getattr(request, "resume", None)
         if resume is not None and resume.tokens:
@@ -1035,6 +1041,7 @@ class PagedEngine(ContinuousBatchingEngine):
         held = self._reserve(full, mnt)
         if held is None:
             return None
+        sp.mark("reserved")
         block_ids, table_row = held.block_ids, held.table_row
         if self.tracer is not None:
             self.tracer.span_end(request.request_id, "queue_wait",
@@ -1055,6 +1062,7 @@ class PagedEngine(ContinuousBatchingEngine):
             run = _SlotRun(request, tokens=list(resume.tokens),
                            t_admit=resume.t_admit, block_ids=block_ids)
             resume_tok = int(resume.tokens[-1])
+        sp.mark("keyed")
         run.window, run.chain = held.window, held.chain
         self._slots[slot] = run
         self._prefill_slots.add(slot)
@@ -1114,6 +1122,7 @@ class PagedEngine(ContinuousBatchingEngine):
                     jnp.asarray(job.done, jnp.int32),
                     jnp.asarray(n, jnp.int32),
                     job.sub, job.temp, job.topk, job.topp)
+                self._enqueued(self._chunk_span)
             job.done += n
             spent += n
             self.prefill_chunks += 1
@@ -1136,6 +1145,7 @@ class PagedEngine(ContinuousBatchingEngine):
             # preemption resume: the carried stream owns the next token
             # — the chunk's in-graph sample is discarded, tokens and the
             # TTFT timestamp ride over from the evicted run
+            t_synced = time.perf_counter_ns()       # nothing is fetched
             tok0 = job.resume_tok
             rem0 = req.max_new_tokens - len(job.run.tokens)
             req.resume = None
@@ -1144,8 +1154,13 @@ class PagedEngine(ContinuousBatchingEngine):
                                     slot=job.slot,
                                     reused_tokens=len(job.run.tokens))
         else:
-            with _span("serving.prefill_sync", rid=req.request_id):
+            began = self._stall_watch.begin()
+            with _span("serving.prefill_sync", rid=req.request_id) as sp:
                 tok0 = int(tok0_dev)        # host blocked on the last chunk
+            t_synced = sp.start + sp.dur
+            if self._pending_block is None:     # the last chunk was all
+                self._drained_ns = t_synced     # the device had queued
+            self._sync_ended(sp, began)
             job.run.tokens = [tok0]
             job.run.t_admit = now           # TTFT timestamp
             self.tokens_emitted += 1
@@ -1159,12 +1174,16 @@ class PagedEngine(ContinuousBatchingEngine):
         self._prefill_slots.discard(job.slot)
         if rem0 <= 0:                # finished at admission
             self._retire(job.slot, job.run, now)
-            return
-        self._arm(job.slot, job.table_row, tok0, len(job.prompt), rem0,
-                  eos, job.temp, job.topk, job.topp, job.key)
-        if self.tracer is not None:
-            self.tracer.span_begin(req.request_id, "decode",
-                                   slot=job.slot)
+        else:
+            self._arm(job.slot, job.table_row, tok0, len(job.prompt), rem0,
+                      eos, job.temp, job.topk, job.topp, job.key)
+            if self.tracer is not None:
+                self.tracer.span_begin(req.request_id, "decode",
+                                       slot=job.slot)
+        # host time between the last chunk's end and the next enqueue that
+        # no span covers (it is ``serving.tick``'s own): the next
+        # ``serving.decode_block`` carries the sum as ``arm_ns``
+        self._arm_ns += time.perf_counter_ns() - t_synced
 
     def _register_prompt(self, job: _PrefillJob):
         """Index the prompt's shareable blocks (now filled) from the
@@ -1205,7 +1224,9 @@ class PagedEngine(ContinuousBatchingEngine):
         self.kv_pages_live += live
         self.kv_pages_copied += copied
         ids = dict(super()._decode_block_counters(),
-                   kv_pages_live=live, kv_pages_copied=copied)
+                   kv_pages_live=live, kv_pages_copied=copied,
+                   arm_ns=self._arm_ns)
+        self._arm_ns = 0
         if self.cache_passes > 1:
             ids["ut_steps"] = self.decode_block * self.cache_passes
             self.ut_steps += ids["ut_steps"]

@@ -610,6 +610,9 @@ class Server:
                 with span("serving.harvest"):
                     self._harvest()
                 self._drain_live_streams()
+                for stall in self.engine.take_sync_stalls():
+                    self.flight.record("sync_stall", tick=self._clock - 1,
+                                       **stall)
                 tick_s = time.perf_counter() - t_tick
                 self.tick_seconds.append(tick_s)
                 _M_TICKS.inc()
@@ -687,6 +690,18 @@ class Server:
             if ticks else 0.0,
             "preemptions": self.preemptions,
             "resumes": self.resumes,
+            # the chip left with an empty queue while the engine held
+            # work (each enqueue's span carries its own ``starved_ns``):
+            # what host code can win of the device's idle time
+            "device_starved_s": round(eng.device_starved_ns / 1e9, 6),
+            "device_starved_share":
+            round(eng.device_starved_ns / 1e9 / self._wall, 4)
+            if self._wall else 0.0,
+            # syncs that lasted far beyond their median (the span says
+            # ``stall=1`` and what the host did; flight event
+            # ``sync_stall``), and what they lasted beyond it
+            "sync_stalls": eng.sync_stalls,
+            "sync_stall_s": round(eng.sync_stall_ns / 1e9, 6),
             # per-tenant breakdown (single-tenant traffic shows one
             # "default" row — the shape is stable either way)
             "tenants": {t: dict(c)

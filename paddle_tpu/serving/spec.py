@@ -456,10 +456,11 @@ class _SpecEngineMixin:
                 self._poison_live_slot()
             faults.fault_point("serving.step_block")
             draft, n_draft = self._propose()
-            with RecordEvent("serving.spec_verify"):
+            with RecordEvent("serving.spec_verify") as ev:
                 out = self.backend.spec_verify(
                     self._cache, self._state, jnp.asarray(draft),
                     jnp.asarray(n_draft))
+                self._enqueued(ev._span)
             self._cache, self._state = out[0], out[1]
             self._pending_block = (out[2], out[3], out[4], n_draft)
             self.steps += 1
@@ -478,9 +479,12 @@ class _SpecEngineMixin:
         toks, counts, oks, n_draft = self._pending_block
         # ONE batched host sync per verify step (4 separate np.asarray
         # round-trips measurably tax the tick at CPU dispatch scale)
-        with _span("serving.decode_sync"):
+        began = self._stall_watch.begin()
+        with _span("serving.decode_sync", fetches=1) as sp:
             toks_np, counts_np, oks_np, rem_np = jax.device_get(
                 (toks, counts, oks, self._state["remaining"]))
+            self._drained_ns = sp.mark("first")
+        self._sync_ended(sp, began)
         self._pending_block = None
         emitted = int(counts_np.sum())
         accepted = int(np.maximum(counts_np - 1, 0).sum())

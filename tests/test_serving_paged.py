@@ -548,7 +548,8 @@ class TestKvWalkCounters:
                  if sp.name == "serving.decode_block"]
         assert spans and want[0] > 0
         assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied",
-                                     "sampled_steps"}
+                                     "sampled_steps", "arm_ns",
+                                     "starved_ns"}
         assert [engine.kv_pages_live, engine.kv_pages_copied] == want
         assert [sum(sp.ids[k] for sp in spans)
                 for k in ("kv_pages_live", "kv_pages_copied")] == want

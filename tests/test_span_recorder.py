@@ -159,6 +159,47 @@ class TestRecorder:
         assert got["legacy.site"][0].parent == holder.id
         assert len(got["legacy.pair"]) == 1
 
+    def test_a_mark_is_the_time_since_the_span_began(self):
+        t0 = time.perf_counter()
+        with span("marked", tick=1) as s:
+            time.sleep(0.002)
+            at = s.mark("half")
+            time.sleep(0.001)
+        assert s.ids == {"tick": 1, "half_ns": at - s.start}
+        assert 0.002e9 <= s.ids["half_ns"] <= s.dur - 0.001e9
+        # the ring's record is the span itself: the mark is in it
+        assert tracing.since(t0)[-1].ids["half_ns"] == s.ids["half_ns"]
+
+    def test_a_stall_needs_history_and_both_thresholds(self):
+        def ended(ms, name="sync"):
+            sp = tracing.Span(name)
+            sp.start, sp.dur = 0, int(ms * 1e6)
+            return sp
+
+        watch = tracing.StallWatch()
+        slow = ended(900)
+        assert watch.end(slow, watch.begin()) is None     # no history
+        for _ in range(tracing.STALL_MIN_HISTORY - 2):
+            assert watch.end(ended(10), watch.begin()) is None
+        # seven known (one of them slow): still no verdict
+        assert watch.end(ended(900), watch.begin()) is None
+        # three medians and not 100 ms more; 100 ms more of a long median
+        # and not three of them
+        assert watch.end(ended(45), watch.begin()) is None
+        for _ in range(tracing.STALL_HISTORY):
+            watch.end(ended(200, "long"), watch.begin())
+        assert watch.end(ended(500, "long"), watch.begin()) is None
+        sp = ended(210)
+        got = watch.end(sp, watch.begin())
+        assert got["stall"] == 1 and got["over_ns"] == 200_000_000
+        assert {"nivcsw", "nvcsw", "majflt", "cpu_ms"} <= set(got)
+        assert {k: sp.ids[k] for k in got} == got and not slow.ids
+        # the history is bounded, a name's own, and a stall enters it
+        assert len(watch._durs["sync"]) == 10
+        assert len(watch._durs["long"]) == tracing.STALL_HISTORY
+        watch.close()
+        watch.close()                        # idempotent
+
     def test_a_span_costs_under_five_microseconds(self):
         """1e5 spans, ids included, no profiler session: the always-on
         price. Best of five batches, so a busy neighbour on the test
@@ -192,8 +233,9 @@ class TestProfilerTrace:
         try:
             for i in range(4):
                 with span("serving.tick", tick=i):
-                    with span("serving.decode_sync"):
+                    with span("serving.decode_sync") as sync:
                         time.sleep(0.002)
+                        sync.mark("first")
         finally:
             jax.profiler.stop_trace()
         ring = sorted(tracing.since(t0), key=lambda r: r.start)
@@ -209,6 +251,10 @@ class TestProfilerTrace:
         assert [t[0] for t in twins] == [r.name for r in ring]
         assert [t[3].get("tick") for t in twins if t[0] == "serving.tick"] \
             == [0, 1, 2, 3]
+        # a mark is ring-only: the twin took its ids when it opened
+        assert all("first_ns" in r.ids for r in ring
+                   if r.name == "serving.decode_sync")
+        assert not any("first_ns" in t[3] for t in twins)
         offsets = [t[1] - r.start for t, r in zip(twins, ring)]
         assert max(offsets) - min(offsets) < 1e6      # one offset, < 1 ms
         for t, r in zip(twins, ring):                 # same length, < 1 ms

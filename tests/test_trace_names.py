@@ -342,6 +342,7 @@ def test_hybrid_engine_span_and_counter_names():
     # in each group, and no live row
     assert eng._decode_block_counters() == {
         "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
+        "arm_ns": 0,
         "window_kv_pages_live": 8, "window_kv_pages_copied": 8,
         "kv_rows_live": 0, "window_kv_rows_live": 0}
     assert (eng.window_kv_pages_live, eng.window_kv_pages_copied,
@@ -353,7 +354,8 @@ def test_hybrid_engine_span_and_counter_names():
     srv.run_until_idle()
     admit = [sp for sp in tracing.since(t0) if sp.name == "serving.admit"][0]
     assert set(admit.ids) == {"rid", "fresh_blocks", "evicted_blocks",
-                              "hashed_blocks", "window_blocks",
+                              "hashed_blocks", "reserved_ns", "keyed_ns",
+                              "window_blocks",
                               "shared_window_blocks",
                               "window_evicted_blocks"}
     assert (admit.ids["fresh_blocks"], admit.ids["window_blocks"]) == (3, 3)
@@ -416,7 +418,7 @@ def test_looped_engine_span_and_counter_names():
     # at ONE site, and four passes a step
     assert eng._decode_block_counters() == {
         "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-        "ut_steps": 16}
+        "arm_ns": 0, "ut_steps": 16}
     assert (eng.ut_steps, eng.ut_exit_step_milli,
             eng.prefill_ut_exit_step_milli) == (16, 0, 0)
     srv = Server(eng, Scheduler())
@@ -475,7 +477,8 @@ def test_decode_block_span_counter_names(tiny):
                                    decode_block=4, paged=True,
                                    block_size=8, prefill_chunk=8)
     assert eng._decode_block_counters() \
-        == {"sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8}
+        == {"sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
+            "arm_ns": 0}
     assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied) \
         == (0, 8, 8)
     dense = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
@@ -508,7 +511,8 @@ def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
     admits = [sp for sp in tracing.since(t0) if sp.name == "serving.admit"]
     assert [sp.ids["rid"] for sp in admits] == rids
     assert all(set(sp.ids) == {"rid", "fresh_blocks", "evicted_blocks",
-                               "hashed_blocks"} for sp in admits)
+                               "hashed_blocks", "reserved_ns", "keyed_ns"}
+               for sp in admits)
     # a cold lookup hashes the one block it misses on; a life, every
     # block it wrote: (19 + 6 - 1) // 8
     assert [sp.ids["hashed_blocks"] for sp in admits] == [1] * 6
@@ -521,6 +525,60 @@ def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
     assert sum(evicted) == eng.manager.evictions \
         == srv.stats()["block_evictions"] > 0
     eng.manager.assert_consistent()
+
+
+def test_starvation_sync_and_stall_names(tiny):
+    """What PR 35 put on the spans a tick already had, by the names
+    ``benchmark/window_spans.py`` reads and PERF.md section 3 lists:
+    ``starved_ns`` on the two enqueue spans, ``arm_ns`` on
+    ``serving.decode_block``, ``first_ns`` / ``fetches`` on
+    ``serving.decode_sync`` (the speculative engine's too), the engine's
+    counters and the four ``Server.stats()`` keys."""
+    import time
+
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
+                                    Server)
+    from paddle_tpu.serving.spec import SpecConfig
+
+    def served(**kw):
+        eng = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
+                                       decode_block=4, paged=True,
+                                       block_size=8, prefill_chunk=8, **kw)
+        srv = Server(eng, Scheduler())
+        # the third refills a slot beside a live stream: its first chunk
+        # is enqueued on a drained device
+        for n, new in ((19, 5), (9, 13), (12, 5)):
+            srv.submit(np.arange(n, dtype=np.int32), max_new_tokens=new)
+        t0 = time.perf_counter()
+        srv.run_until_idle()
+        by = {}
+        for sp in tracing.since(t0):
+            by.setdefault(sp.name, []).append(sp)
+        return eng, srv, by
+
+    eng, srv, by = served()
+    assert all(set(sp.ids) == {"first_ns", "fetches"}
+               for sp in by["serving.decode_sync"])
+    assert all("arm_ns" in sp.ids for sp in by["serving.decode_block"])
+    starved = [sp for sps in by.values() for sp in sps
+               if "starved_ns" in sp.ids]
+    assert {sp.name for sp in starved} == {"serving.prefill_chunk",
+                                           "serving.decode_block"}
+    assert (eng.device_starved_ns, eng.sync_stalls, eng.sync_stall_ns) \
+        == (sum(sp.ids["starved_ns"] for sp in starved), 0, 0)
+    stats = srv.stats()
+    assert {"device_starved_s", "device_starved_share", "sync_stalls",
+            "sync_stall_s"} <= set(stats)
+    assert (stats["sync_stalls"], stats["sync_stall_s"]) == (0, 0.0)
+
+    eng, srv, by = served(spec=SpecConfig(k=3))
+    assert all(set(sp.ids) == {"first_ns", "fetches"}
+               and sp.ids["fetches"] == 1
+               for sp in by["serving.decode_sync"])
+    assert eng.device_starved_ns == sum(
+        sp.ids["starved_ns"] for sp in by["serving.spec_verify"]
+        + by["serving.prefill_chunk"] if "starved_ns" in sp.ids) > 0
 
 
 def _op_histogram(text: str) -> dict:
