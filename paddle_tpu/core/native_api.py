@@ -190,7 +190,7 @@ class TCPStore:
                 if n < 0:
                     raise ConnectionError("store get failed")
                 if n <= len(buf):
-                    return buf.raw[:n]
+                    return ctypes.string_at(buf, n)
                 # value larger than the buffer (and may grow between
                 # fetches — loop until a fetch fits)
                 size = n * 2
@@ -311,7 +311,8 @@ class ShmQueue:
                     "message exceeded this handle's capacity "
                     f"({self._capacity}B) and was dropped — open both ends "
                     "with the same capacity")
-            return buf.raw[:n]
+            # an owned copy of n bytes: .raw would copy the whole capacity
+            return ctypes.string_at(buf, n)
         import queue as _q
         try:
             return self._py.get(timeout=timeout)

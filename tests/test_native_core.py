@@ -70,6 +70,11 @@ class TestProfilerSpans:
         assert spans and spans[0]["dur"] >= 4000
 
 
+def _payload(nbytes, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, 256, nbytes, dtype=np.uint8).tobytes()
+
+
 def _store_worker(rank, port, results):
     store = TCPStore("127.0.0.1", port, world_size=2)
     store.set(f"key{rank}", f"val{rank}")
@@ -93,6 +98,42 @@ class TestTCPStore:
         assert not store.check("alpha")
         store.close()
         daemon.stop()
+
+    # the native get starts with a 64 KiB buffer and doubles it until the
+    # value fits: what comes back is the value, whole, at its own size
+    @pytest.mark.parametrize("nbytes", [0, 1, 64 << 10, (64 << 10) + 1,
+                                        300 << 10])
+    def test_get_returns_the_value_whole(self, nbytes):
+        daemon = MasterDaemon(0)
+        store = TCPStore("127.0.0.1", daemon.port)
+        value = _payload(nbytes, seed=nbytes)
+        store.set("v", value)
+        got = store.get("v")
+        assert type(got) is bytes and got == value
+        store.close()
+        daemon.stop()
+
+    def test_get_payload_is_owned(self):
+        daemon = MasterDaemon(0)
+        store = TCPStore("127.0.0.1", daemon.port)
+        first, second = _payload(4096, seed=1), _payload(4096, seed=2)
+        store.set("a", first)
+        store.set("b", second)
+        got = store.get("a")
+        assert store.get("b") == second
+        assert got == first
+        store.close()
+        daemon.stop()
+
+    @needs_native
+    def test_get_on_a_stopped_store_raises(self):
+        daemon = MasterDaemon(0)
+        store = TCPStore("127.0.0.1", daemon.port)
+        store.set("a", b"x")
+        daemon.stop()
+        with pytest.raises(ConnectionError, match="store get failed"):
+            store.get("a")
+        store.close()
 
     def test_get_blocks_until_set(self):
         daemon = MasterDaemon(0)
@@ -153,6 +194,80 @@ class TestShmQueue:
             q.get(timeout=0.1)
         q.close()
 
+    # the loader's handle: a 32 MiB receive buffer, reused by every pop
+    LOADER_CAPACITY = 32 << 20
+
+    @needs_native
+    @pytest.mark.parametrize("nbytes", [0, 1, 64 << 10,
+                                        LOADER_CAPACITY - 8])
+    def test_get_returns_what_was_put(self, nbytes):
+        q = ShmQueue(f"pt_sz_{os.getpid()}", capacity=self.LOADER_CAPACITY)
+        try:
+            msg = _payload(nbytes, seed=nbytes)
+            q.put(msg)
+            got = q.get(timeout=5)
+            assert type(got) is bytes and got == msg
+            assert q.qsize_bytes() == 0
+        finally:
+            q.close()
+
+    @needs_native
+    def test_get_payload_is_owned(self):
+        # the handle reuses its buffer for the next pop: no view of it may
+        # escape, or a caller that keeps a payload sees the next message
+        q = ShmQueue(f"pt_own_{os.getpid()}", capacity=self.LOADER_CAPACITY)
+        try:
+            first, second = _payload(64 << 10, seed=1), _payload(64 << 10,
+                                                                 seed=2)
+            q.put(first)
+            q.put(second)
+            got = q.get(timeout=5)
+            assert q.get(timeout=5) == second
+            assert got == first
+        finally:
+            q.close()
+
+    @needs_native
+    def test_get_cost_follows_the_message_not_the_capacity(self):
+        # a 64 KiB message out of a 32 MiB handle: copying the whole buffer
+        # read 17-20 ms on the sandbox, copying the message 0.04-0.1 ms
+        q = ShmQueue(f"pt_cost_{os.getpid()}", capacity=self.LOADER_CAPACITY)
+        try:
+            msg = _payload(64 << 10)
+            q.put(msg)
+            q.get(timeout=5)            # allocates the handle's buffer
+            took = []
+            for _ in range(9):
+                q.put(msg)
+                t0 = time.perf_counter()
+                got = q.get(timeout=5)
+                took.append(time.perf_counter() - t0)
+                assert got == msg
+            assert sorted(took)[4] < 5e-3, took
+        finally:
+            q.close()
+
+    @needs_native
+    def test_message_over_the_handles_capacity(self):
+        name = f"pt_big_{os.getpid()}"
+        q = ShmQueue(name, capacity=1 << 20)
+        small = ShmQueue(name, capacity=1024, create=False)
+        try:
+            with pytest.raises(ValueError, match="exceeds queue capacity"):
+                q.put(b"x" * (1 << 20))
+            q.put(b"x" * 4096)
+            q.put(b"fits")
+            with pytest.raises(ValueError,
+                               match="exceeded this handle's capacity"):
+                small.get(timeout=5)
+            # the message was dropped, the ring is intact
+            assert small.get(timeout=5) == b"fits"
+            with pytest.raises(TimeoutError):
+                small.get(timeout=0.05)
+        finally:
+            small.close()
+            q.close()
+
     @needs_native
     def test_cross_process(self):
         name = f"pt_xp_{os.getpid()}"
@@ -193,37 +308,62 @@ class _SquareDataset:
         return np.full((4,), i, np.float32), np.asarray([i * i], np.float32)
 
 
+class _TokenDataset:
+    """The train cell's samples scaled down: a row of int32 tokens, split
+    into inputs and labels (benchmark/kinds/train.py FixedTokens)."""
+
+    def __init__(self, boom_at=None):
+        self.rows = np.random.default_rng(0).integers(
+            0, 32000, (24, 129), dtype=np.int32)
+        self.boom_at = boom_at
+
+    def __len__(self):
+        return len(self.rows)
+
+    def __getitem__(self, i):
+        if i == self.boom_at:
+            raise RuntimeError(f"boom at {i}")
+        return self.rows[i, :-1], self.rows[i, 1:]
+
+
+class _BadDataset:
+    def __len__(self):
+        return 8
+
+    def __getitem__(self, i):
+        if i == 5:
+            raise RuntimeError("boom at 5")
+        return np.zeros(2, np.float32)
+
+
 class TestDataLoaderMultiprocess:
     @needs_native
-    def test_shared_memory_loader(self):
+    @pytest.mark.parametrize("dataset, batch, shape", [
+        (_SquareDataset(), 8, [8, 4]),
+        (_TokenDataset(), 2, [2, 128]),
+    ], ids=["square", "tokens"])
+    def test_shared_memory_loader(self, dataset, batch, shape):
+        # an epoch over the native rings is the in-process epoch, in order
         import paddle_tpu.io as io
-        dl = io.DataLoader(_SquareDataset(), batch_size=8, num_workers=2,
+        dl = io.DataLoader(dataset, batch_size=batch, num_workers=2,
                            use_shared_memory=True)
-        xs, ys = [], []
-        for x, y in dl:
-            assert x.shape == [8, 4]
-            xs.append(x.numpy())
-            ys.append(y.numpy())
-        allx = np.concatenate(xs)
-        assert allx.shape == (64, 4)
-        np.testing.assert_array_equal(allx[:, 0], np.arange(64))
-        np.testing.assert_array_equal(np.concatenate(ys)[:, 0],
-                                      np.arange(64) ** 2)
+        assert dl._shm_usable()
+        want = list(io.DataLoader(dataset, batch_size=batch, num_workers=0))
+        got = list(dl)
+        assert len(got) == len(want) == len(dataset) // batch
+        assert got[0][0].shape == shape
+        for g, w in zip(got, want):
+            for gt, wt in zip(g, w):
+                assert gt.numpy().dtype == wt.numpy().dtype
+                np.testing.assert_array_equal(gt.numpy(), wt.numpy())
 
     @needs_native
-    def test_worker_exception_propagates(self):
+    @pytest.mark.parametrize("dataset", [
+        _BadDataset(), _TokenDataset(boom_at=5)], ids=["floats", "tokens"])
+    def test_worker_exception_propagates(self, dataset):
         import paddle_tpu.io as io
 
-        class Bad:
-            def __len__(self):
-                return 8
-
-            def __getitem__(self, i):
-                if i == 5:
-                    raise RuntimeError("boom at 5")
-                return np.zeros(2, np.float32)
-
-        dl = io.DataLoader(Bad(), batch_size=2, num_workers=2,
+        dl = io.DataLoader(dataset, batch_size=2, num_workers=2,
                            use_shared_memory=True)
         with pytest.raises(RuntimeError, match="boom"):
             list(dl)
