@@ -48,7 +48,8 @@ from .. import nn
 from ..incubate.distributed.models.moe import DroplessMoE
 from ..nn import functional as F
 from ..tensor import Tensor, apply_op
-from .generation import GenerationMixin, latent_cached_attention
+from .generation import (GenerationMixin, latent_cached_attention,
+                         latent_index_scores)
 
 __all__ = ["DeepseekV3Config", "DeepseekV3Model", "DeepseekV3ForCausalLM",
            "deepseek_v3_tiny_config"]
@@ -157,87 +158,328 @@ class DeepseekV3MLP(nn.Layer):
         return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
 
 
+def _rope_tables(positions, theta: float, d: int):
+    """cos / sin ``(b, s, d)`` at ``positions (b, s)``, the angles in
+    float32 from the positions (no table of ``max_position_embeddings``
+    rows): for a model whose layers turn at different bases."""
+    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    ang = positions.astype(jnp.float32)[:, :, None] * inv[None, None]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang), jnp.sin(ang)
+
+
+def _rope_at(x, c, sn):
+    """rotate-half of ``x (b, s, ..., d)`` by cos / sin ``(b, s, d)``."""
+    shape = c.shape[:2] + (1,) * (x.ndim - 3) + (x.shape[-1],)
+    c, sn = c.reshape(shape).astype(x.dtype), sn.reshape(shape).astype(x.dtype)
+    x1, x2 = jnp.split(x, 2, axis=-1)
+    return x * c + jnp.concatenate([-x2, x1], axis=-1) * sn
+
+
 class DeepseekV3Attention(nn.Layer):
-    def __init__(self, config: DeepseekV3Config):
+    """Multi-head latent attention. Every size is an argument, the
+    config's value by default, so that one model may hold layers of
+    several geometries (``models/dots3_note.py``: full and sliding layers
+    at different head counts, ranks and head sizes). Beyond the published
+    DeepSeek-V3 block:
+
+    - ``rope_theta``: the angles are computed from the positions at this
+      base (half-split unless ``rope_interleave``), and ``forward``'s
+      ``cos`` / ``sin`` tables are not read;
+    - ``q_rescale`` / ``kv_rescale``: constants on the two rank norms'
+      outputs;
+    - ``gate``: a headwise sigmoid gate on the attention output, ``g =
+      sigmoid(x W_g) (heads,)``, ``o_h <- g_h o_h``;
+    - ``window``: key j is seen from query i iff ``0 <= i - j < window``;
+      the cached read goes through a ring table;
+    - ``indexer = {"n_heads", "head_dim", "topk"}``: learned sparse
+      attention. ``q^I = c_q W^I_q``, ``k^I = LayerNorm(x W^I_k)``, RoPE
+      on the first ``qk_rope_head_dim`` dims of each, ``w = x W^I_w /
+      sqrt(n_heads * head_dim)``, ``I(t, s) = sum_j w_j ReLU(q^I_j(t) .
+      k^I(s))``; a query attends to the ``topk`` keys ``s <= t`` of
+      largest ``I`` (all of them while ``t + 1 <= topk``), chosen by an
+      exact ``lax.top_k``. The cache is then the PAIR ``(latent arena,
+      index-key arena)`` under one block id, and ``forward`` returns a
+      third value: ``(ids (b, s, k), n_valid (b, s), counts (2,))`` with
+      the tokens scored and selected, summed over the live rows."""
+
+    def __init__(self, config: DeepseekV3Config, *, heads=None,
+                 q_lora_rank=-1, kv_lora_rank=None, qk_nope_head_dim=None,
+                 qk_rope_head_dim=None, v_head_dim=None, rope_theta=None,
+                 rope_interleave=None, q_rescale=1.0, kv_rescale=1.0,
+                 gate=False, window=None, indexer=None):
         super().__init__()
         c = self.config = config
-        h, heads = c.hidden_size, c.num_attention_heads
-        if c.q_lora_rank is None:
-            self.q_proj = nn.Linear(h, heads * c.qk_head_dim,
-                                    bias_attr=False)
-        else:
-            self.q_a_proj = nn.Linear(h, c.q_lora_rank, bias_attr=False)
-            self.q_a_layernorm = nn.RMSNorm(c.q_lora_rank, c.rms_norm_eps)
-            self.q_b_proj = nn.Linear(c.q_lora_rank, heads * c.qk_head_dim,
-                                      bias_attr=False)
-        self.kv_a_proj_with_mqa = nn.Linear(h, c.latent_width,
-                                            bias_attr=False)
-        self.kv_a_layernorm = nn.RMSNorm(c.kv_lora_rank, c.rms_norm_eps)
-        self.kv_b_proj = nn.Linear(
-            c.kv_lora_rank, heads * (c.qk_nope_head_dim + c.v_head_dim),
-            bias_attr=False)
-        self.o_proj = nn.Linear(heads * c.v_head_dim, h, bias_attr=False)
+        h = c.hidden_size
 
-    def forward(self, x, cos, sin, cache=None, pos=None, block_table=None):
-        """``cache`` is this layer's latent arena and ``pos (b,)`` the
-        per-row write offsets (the absorbed, cached read); without a
-        cache the whole sequence attends to itself, expanded."""
-        c = self.config
+        def given(v, default):
+            return default if v is None else v
+        self.heads = heads = given(heads, c.num_attention_heads)
+        self.q_rank = c.q_lora_rank if q_lora_rank == -1 else q_lora_rank
+        self.rank = given(kv_lora_rank, c.kv_lora_rank)
+        self.nope = given(qk_nope_head_dim, c.qk_nope_head_dim)
+        self.rope_dim = given(qk_rope_head_dim, c.qk_rope_head_dim)
+        self.v_dim = given(v_head_dim, c.v_head_dim)
+        self.rope_theta = rope_theta
+        self.interleave = given(rope_interleave,
+                                getattr(c, "rope_interleave", False))
+        self.q_rescale, self.kv_rescale = float(q_rescale), float(kv_rescale)
+        self.window, self.indexer = window, indexer
+        qk = self.nope + self.rope_dim
+        if self.q_rank is None:
+            self.q_proj = nn.Linear(h, heads * qk, bias_attr=False)
+        else:
+            self.q_a_proj = nn.Linear(h, self.q_rank, bias_attr=False)
+            self.q_a_layernorm = nn.RMSNorm(self.q_rank, c.rms_norm_eps)
+            self.q_b_proj = nn.Linear(self.q_rank, heads * qk,
+                                      bias_attr=False)
+        self.kv_a_proj_with_mqa = nn.Linear(h, self.rank + self.rope_dim,
+                                            bias_attr=False)
+        self.kv_a_layernorm = nn.RMSNorm(self.rank, c.rms_norm_eps)
+        self.kv_b_proj = nn.Linear(
+            self.rank, heads * (self.nope + self.v_dim), bias_attr=False)
+        self.o_proj = nn.Linear(heads * self.v_dim, h, bias_attr=False)
+        if gate:
+            self.head_gate = nn.Linear(h, heads, bias_attr=False)
+        self.has_gate = bool(gate)
+        if indexer is not None:
+            if self.q_rank is None:
+                raise ValueError("the indexer reads the query's low-rank "
+                                 "latent: it needs a q_lora_rank")
+            n, d = indexer["n_heads"], indexer["head_dim"]
+            self.idx_q_proj = nn.Linear(self.q_rank, n * d, bias_attr=False)
+            self.idx_k_proj = nn.Linear(h, d, bias_attr=False)
+            self.idx_k_norm = nn.LayerNorm(d)
+            self.idx_w_proj = nn.Linear(h, n, bias_attr=False)
+
+    def _positions(self, b, s, posv):
+        start = jnp.zeros((b,), jnp.int32) if posv is None else \
+            jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (b,))
+        return start[:, None] + jnp.arange(s)[None, :]
+
+    def _turn(self, x, cos, sin, positions):
+        """RoPE on ``x (b, s, ..., rope_dim)``: at the layer's own base
+        where it has one, from the model's tables otherwise."""
+        if self.rope_theta is None:
+            return _rope(x, cos, sin, positions, self.interleave)
+        if self.interleave:
+            x = jnp.concatenate([x[..., 0::2], x[..., 1::2]], axis=-1)
+        return _rope_at(x, *_rope_tables(positions, self.rope_theta,
+                                         self.rope_dim))
+
+    def _index(self, c_q, x):
+        """The indexer's three projections: ``(q (b, s, n, d), k (b, s,
+        d), w (b, s, n))`` before RoPE."""
+        n, d = self.indexer["n_heads"], self.indexer["head_dim"]
         b, s, _ = x.shape
-        heads, rank = c.num_attention_heads, c.kv_lora_rank
-        q = self.q_proj(x) if c.q_lora_rank is None else \
-            self.q_b_proj(self.q_a_layernorm(self.q_a_proj(x)))
+        q = self.idx_q_proj(c_q)
+        k = self.idx_k_norm(self.idx_k_proj(x))
+        w = self.idx_w_proj(x)
+
+        def shape(qv, wv):
+            return qv.reshape(b, s, n, d), \
+                wv.astype(jnp.float32) * (1.0 / math.sqrt(n * d))
+        q, w = apply_op(shape, q, w)
+        return q, k, w
+
+    def _turn_index(self, q, k, cos, sin, positions):
+        """RoPE on the first ``rope_dim`` dims of the indexer's q and k."""
+        r = self.rope_dim
+        q = jnp.concatenate([self._turn(q[..., :r], cos, sin, positions),
+                             q[..., r:]], axis=-1)
+        k = jnp.concatenate([self._turn(k[..., :r], cos, sin, positions),
+                             k[..., r:]], axis=-1)
+        return q, k
+
+    def forward(self, x, cos, sin, cache=None, pos=None, block_table=None,
+                valid_len=None):
+        """``cache`` is this layer's latent arena (with an indexer: the
+        pair ``(latent arena, index-key arena)``) and ``pos (b,)`` the
+        per-row write offsets (the absorbed, cached read); without a
+        cache the whole sequence attends to itself, expanded.
+        ``valid_len`` (a scalar; a right-padded prefill chunk's real
+        columns): the indexer's selection and selected read, a sort and a
+        gather a row, skip the blocks of 128 rows that are all padding."""
+        b, s, _ = x.shape
+        heads, rank, nope, vd = self.heads, self.rank, self.nope, self.v_dim
+        window, indexer = self.window, self.indexer
+        qk = nope + self.rope_dim
+        if self.q_rank is None:
+            c_q, q = None, self.q_proj(x)
+        else:
+            c_q = self.q_a_layernorm(self.q_a_proj(x))
+            if self.q_rescale != 1.0:
+                c_q = c_q * self.q_rescale
+            q = self.q_b_proj(c_q)
         kva = self.kv_a_proj_with_mqa(x)
         c_kv = self.kv_a_layernorm(kva[:, :, :rank])
-        scale = 1.0 / math.sqrt(c.qk_head_dim)
+        if self.kv_rescale != 1.0:
+            c_kv = c_kv * self.kv_rescale
+        scale = 1.0 / math.sqrt(qk)
+        extra = []
+        if self.has_gate:
+            with jax.named_scope("attn_gate"):
+                extra.append(F.sigmoid(self.head_gate(x)))
+        if indexer is not None:
+            extra.extend(self._index(c_q, x))
+
+        def take(rest):
+            """The optional operands, in the order they were added."""
+            rest = list(rest)
+            g = rest.pop(0) if self.has_gate else None
+            idx = tuple(rest[:3]) if indexer is not None else None
+            return g, idx
+
+        def gated(out, g):
+            if g is None:
+                return out
+            with jax.named_scope("attn_gate"):
+                return out * g[..., None].astype(out.dtype)
 
         def split(qv, kvav, posv):
-            qv = qv.reshape(b, s, heads, c.qk_head_dim)
-            start = jnp.zeros((b,), jnp.int32) if posv is None else \
-                jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (b,))
-            positions = start[:, None] + jnp.arange(s)[None, :]
-            q_pe = _rope(qv[..., c.qk_nope_head_dim:], cos, sin, positions,
-                         c.rope_interleave)
-            k_pe = _rope(kvav[:, :, rank:], cos, sin, positions,
-                         c.rope_interleave)
-            return qv[..., :c.qk_nope_head_dim], q_pe, k_pe
+            qv = qv.reshape(b, s, heads, qk)
+            positions = self._positions(b, s, posv)
+            q_pe = self._turn(qv[..., nope:], cos, sin, positions)
+            k_pe = self._turn(kvav[:, :, rank:], cos, sin, positions)
+            return qv[..., :nope], q_pe, k_pe, positions
 
         def w_kvb(wv):
-            w3 = wv.reshape(rank, heads, c.qk_nope_head_dim + c.v_head_dim)
-            return w3[..., :c.qk_nope_head_dim], w3[..., c.qk_nope_head_dim:]
+            w3 = wv.reshape(rank, heads, nope + vd)
+            return w3[..., :nope], w3[..., nope:]
+
+        def counts(n_valid, positions, live):
+            """[tokens scored, tokens selected] over the live rows: ``live
+            (b, 1 | s)``."""
+            live = live.astype(jnp.int32)
+            return jnp.stack([jnp.sum((positions + 1) * live),
+                              jnp.sum(n_valid * live)]).astype(jnp.int32)
 
         if cache is None:
-            def expanded(qv, kvav, ckv, wv):
-                q_nope, q_pe, k_pe = split(qv, kvav, None)
+            def expanded(qv, kvav, ckv, wv, *rest):
+                g, idx = take(rest)
+                q_nope, q_pe, k_pe, positions = split(qv, kvav, None)
                 wk, wvv = w_kvb(wv)
                 k_nope = jnp.einsum("btr,rhd->bthd", ckv, wk)
                 v = jnp.einsum("btr,rhd->bthd", ckv, wvv)
                 f32 = jnp.float32
-                scores = (jnp.einsum("bshd,bthd->bhst", q_nope.astype(f32),
-                                     k_nope.astype(f32))
-                          + jnp.einsum("bshd,btd->bhst", q_pe.astype(f32),
-                                       k_pe.astype(f32))) * scale
-                causal = jnp.tril(jnp.ones((s, s), bool))
-                probs = jax.nn.softmax(
-                    jnp.where(causal, scores, jnp.float32(-1e30)), axis=-1)
-                out = jnp.einsum("bhst,bthd->bshd", probs.astype(v.dtype), v)
-                return out.reshape(b, s, heads * c.v_head_dim)
-            return self.o_proj(apply_op(expanded, q, kva, c_kv,
-                                        self.kv_b_proj.weight)), None
+                seen = jnp.tril(jnp.ones((s, s), bool))
+                if window is not None:
+                    i, j = jnp.arange(s)[:, None], jnp.arange(s)[None, :]
+                    seen = seen & (i - j < window)
+                sel = None
+                if idx is not None:
+                    from ..ops.pallas.paged_attention import _index_scores
+                    with jax.named_scope("dsa_index"):
+                        qi, ki = self._turn_index(idx[0], idx[1], cos, sin,
+                                                  positions)
+                        score = _index_scores(qi, idx[2], ki, key_block=1024)
+                        score = jnp.where(seen[None], score, -jnp.inf)
+                    with jax.named_scope("dsa_select"):
+                        k = min(indexer["topk"], s)
+                        _, ids = jax.lax.top_k(score, k)
+                        n_valid = jnp.minimum(positions + 1, k)
+                        valid = jnp.arange(k)[None, None] < n_valid[..., None]
+                        picked = jnp.zeros((b, s, s), bool).at[
+                            jnp.arange(b)[:, None, None],
+                            jnp.arange(s)[None, :, None], ids].max(valid)
+                    seen = (seen[None] & picked)[:, None]       # (b,1,s,s)
+                    sel = (ids, n_valid,
+                           counts(n_valid, positions, jnp.ones((b, 1), bool)))
 
-        def absorbed(qv, kvav, ckv, wv, arena, posv, table):
-            q_nope, q_pe, k_pe = split(qv, kvav, posv)
+                def rows(lo, n):
+                    """Query rows lo..lo+n against every key."""
+                    qn = jax.lax.dynamic_slice_in_dim(q_nope, lo, n, 1)
+                    qp = jax.lax.dynamic_slice_in_dim(q_pe, lo, n, 1)
+                    scores = (jnp.einsum("bshd,bthd->bhst", qn.astype(f32),
+                                         k_nope.astype(f32))
+                              + jnp.einsum("bshd,btd->bhst", qp.astype(f32),
+                                           k_pe.astype(f32))) * scale
+                    vis = jax.lax.dynamic_slice_in_dim(seen, lo, n,
+                                                       seen.ndim - 2)
+                    probs = jax.nn.softmax(
+                        jnp.where(vis, scores, jnp.float32(-1e30)), axis=-1)
+                    return jnp.einsum("bhst,bthd->bshd",
+                                      probs.astype(v.dtype), v)
+
+                blk = 256
+                if s <= 2 * blk or s % blk:
+                    out = rows(0, s)
+                else:           # a long sequence: blocks of query rows
+                    out = jax.lax.map(lambda lo: rows(lo, blk),
+                                      jnp.arange(0, s, blk))
+                    out = jnp.moveaxis(out, 0, 1).reshape(b, s, heads, vd)
+                out = gated(out, g).reshape(b, s, heads * vd)
+                return out if sel is None else (out,) + sel
+            got = apply_op(expanded, q, kva, c_kv, self.kv_b_proj.weight,
+                           *extra)
+            if indexer is None:
+                return self.o_proj(got), None
+            return self.o_proj(got[0]), None, got[1:]
+
+        row_block = 128 if s > 128 and s % 128 == 0 else None
+
+        def select_rows(score, k, valid):
+            """The exact top-k of every row's scores; of a chunk's row
+            blocks, only those that hold a real column."""
+            if valid is None or row_block is None:
+                return jax.lax.top_k(score, k)[1]
+
+            def block(lo):
+                rows = jax.lax.dynamic_slice_in_dim(score, lo, row_block, 1)
+                return jax.lax.cond(
+                    lo < valid, lambda: jax.lax.top_k(rows, k)[1],
+                    lambda: jnp.zeros(rows.shape[:2] + (k,), jnp.int32))
+            ids = jax.lax.map(block, jnp.arange(0, s, row_block))
+            return jnp.moveaxis(ids, 0, 1).reshape(b, s, k)
+
+        def absorbed(qv, kvav, ckv, wv, arena, posv, table, *rest):
+            rest, valid = (rest[:-1], rest[-1]) if valid_len is not None \
+                else (rest, None)
+            g, idx = take(rest)
+            q_nope, q_pe, k_pe, positions = split(qv, kvav, posv)
             wk, wvv = w_kvb(wv)
             q_abs = jnp.einsum("bshd,rhd->bshr", q_nope, wk)
-            o_lat, arena = latent_cached_attention(
-                jnp.concatenate([q_abs, q_pe], axis=-1),
-                jnp.concatenate([ckv, k_pe], axis=-1), arena, posv, table,
-                scale=scale, rank=rank)
+            q_lat = jnp.concatenate([q_abs, q_pe], axis=-1)
+            lat = jnp.concatenate([ckv, k_pe], axis=-1)
+            sel = None
+            if idx is None:
+                kw = {} if window is None else {"window": window}
+                o_lat, arena = latent_cached_attention(
+                    q_lat, lat, arena, posv, table, scale=scale, rank=rank,
+                    **kw)
+            else:
+                keys = rest[-1]
+                with jax.named_scope("dsa_index"):
+                    qi, ki = self._turn_index(idx[0], idx[1], cos, sin,
+                                              positions)
+                    score, keys = latent_index_scores(qi, idx[2], ki, keys,
+                                                      posv, table)
+                with jax.named_scope("dsa_select"):
+                    k = min(indexer["topk"], score.shape[-1])
+                    ids = select_rows(score, k, valid)
+                    n_valid = jnp.minimum(positions + 1, k)
+                with jax.named_scope("dsa_read"):
+                    o_lat, arena = latent_cached_attention(
+                        q_lat, lat, arena, posv, table, scale=scale,
+                        rank=rank, select=(ids, n_valid), valid_len=valid)
+                live = (table[:, 0] > 0)[:, None]
+                if valid is not None:       # a chunk's real columns only
+                    live = live & (jnp.arange(s)[None, :] < valid)
+                sel = (keys, ids, n_valid, counts(n_valid, positions, live))
             out = jnp.einsum("bshr,rhd->bshd", o_lat, wvv)
-            return out.reshape(b, s, heads * c.v_head_dim), arena
-        out, arena = apply_op(absorbed, q, kva, c_kv, self.kv_b_proj.weight,
-                              cache, pos, block_table)
-        return self.o_proj(out), arena
+            out = gated(out, g).reshape(b, s, heads * vd)
+            return (out, arena) if sel is None else (out, arena) + sel
+        if indexer is None:
+            valid_len = None            # only the indexer's rows are dear
+            out, arena = apply_op(absorbed, q, kva, c_kv,
+                                  self.kv_b_proj.weight, cache, pos,
+                                  block_table, *extra)
+            return self.o_proj(out), arena
+        tail = (cache[1],) if valid_len is None else (cache[1], valid_len)
+        out, arena, keys, *sel = apply_op(
+            absorbed, q, kva, c_kv, self.kv_b_proj.weight, cache[0], pos,
+            block_table, *extra, *tail)
+        return self.o_proj(out), (arena, keys), tuple(sel)
 
 
 class DeepseekV3DecoderLayer(nn.Layer):

@@ -232,7 +232,8 @@ def cached_attention(qv, kv_, vv, ckv, cvv, posv, *, scale, cos=None,
 
 
 def latent_cached_attention(q_lat, latent, arena, posv, block_table, *,
-                            scale, rank):
+                            scale, rank, window=None, select=None,
+                            valid_len=None):
     """The latent-cache (MLA) step, beside :func:`cached_attention`: the
     cache keeps ONE row a token a layer, ``[c_kv (rank) | k_pe]``, shared
     by every head, and every read is the absorbed form.
@@ -246,8 +247,20 @@ def latent_cached_attention(q_lat, latent, arena, posv, block_table, *,
     the table (positions past its width land in the trash block 0, as in
     ``cached_attention``). The s = 1 read walks the slot's live pages in
     the Pallas kernel where the arena tiles (TPU); every other read (a
-    prefill chunk, the CPU lane) gathers the table. Returns ``(o_latent
-    (b, s, h, rank), arena)``: the caller expands through ``W_kvb,v``."""
+    prefill chunk, the CPU lane) gathers the table.
+
+    ``window``: the read covers the last ``window`` positions only, and
+    the table may cycle a slot's columns over a ring of blocks (the rules
+    of :func:`packed_cached_attention`: the walk starts at the window's
+    first page, the gathered read takes only the columns a window spans).
+    ``select = (ids (b, s, k), n_valid (b, s))``: the read attends to
+    those token ids of the slot's timeline and to no others, the first
+    ``n_valid`` of each row's ``k``; it gathers ROWS (``block_table[id //
+    bs] * bs + id % bs``), never the table; ``valid_len`` (a scalar: the
+    columns of a right-padded chunk that hold a prompt token) lets the s >
+    1 selected read skip the query blocks that are all padding. Returns
+    ``(o_latent (b, s, h, rank), arena)``: the caller expands through
+    ``W_kvb,v``."""
     from ..ops.pallas import paged_attention as _pa
     b, s, h, _ = q_lat.shape
     w = arena.shape[-1]
@@ -265,7 +278,33 @@ def latent_cached_attention(q_lat, latent, arena, posv, block_table, *,
 
     arena = arena.at[blk, off].set(widen(latent).astype(arena.dtype))
     q_lat = widen(q_lat)
-    if s == 1 and _pa._kernel_ok(arena):
+    kernel = s == 1 and _pa._kernel_ok(arena)
+    # a long chunk's scores, a query block at a time
+    q_block = 128 if s > 128 and s % 128 == 0 else None
+    if select is not None:
+        ids, n_valid = select
+        # an id past the valid ones names the slot's first row: masked,
+        # but read, and so it must not be another slot's (or a NaN)
+        ids = jnp.where(jnp.arange(ids.shape[-1]) < n_valid[..., None],
+                        ids, 0)
+        if kernel:
+            out = _pa.dsa_sparse_mla_decode(
+                q_lat[:, 0], arena, block_table, ids[:, 0], n_valid[:, 0],
+                scale=scale, rank=rank)[:, None]
+        else:
+            out = _pa.dsa_sparse_mla_reference(
+                q_lat, arena, block_table, ids, n_valid, scale=scale,
+                rank=rank, q_block=q_block, valid_len=valid_len)
+    elif window is not None:
+        if kernel:
+            out = _pa.swa_mla_paged_attention_decode(
+                q_lat[:, 0], arena, block_table, posv + 1, scale=scale,
+                rank=rank, window=window)[:, None]
+        else:
+            out = _pa.mla_paged_attention_reference(
+                q_lat, arena, block_table, posv + s, scale=scale, rank=rank,
+                window=window, q_block=q_block)
+    elif kernel:
         out = _pa.mla_paged_attention_decode(
             q_lat[:, 0], arena, block_table, posv + 1, scale=scale,
             rank=rank)[:, None]
@@ -273,6 +312,41 @@ def latent_cached_attention(q_lat, latent, arena, posv, block_table, *,
         out = _pa.mla_paged_attention_reference(
             q_lat, arena, block_table, posv + s, scale=scale, rank=rank)
     return out, arena
+
+
+def latent_index_scores(q_idx, w_idx, k_idx, key_arena, posv, block_table):
+    """The indexer of learned sparse attention over a paged cache: the new
+    tokens' index keys ``k_idx (b, s, d)`` are written into ``key_arena
+    (num_blocks, block_size, d)`` through the table (the latent rows'
+    block ids: a token's key lies under the block that holds its row), and
+    every query ``q_idx (b, s, H, d)`` with its head weights ``w_idx (b,
+    s, H)`` scores every cached key of its slot: ``I = sum_j w_j ReLU(q_j
+    . k)``, fp32 accumulation. The s = 1 read walks the live pages in the
+    Pallas kernel (``dsa_index_scores_decode``) where the arena tiles;
+    every other read gathers the table, a block of keys at a time.
+    Returns ``(I (b, s, max_blocks * block_size) float32, key_arena)``
+    with the positions past each row's own at ``-inf``."""
+    from ..ops.pallas import paged_attention as _pa
+    b, s = q_idx.shape[:2]
+    posv = jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (b,))
+    bs_blk, mb = key_arena.shape[1], block_table.shape[1]
+    tpos = posv[:, None] + jnp.arange(s)[None, :]            # (b, s)
+    blk_idx = tpos // bs_blk
+    oob = blk_idx >= mb
+    blk = jnp.where(oob, 0, jnp.take_along_axis(
+        block_table, jnp.clip(blk_idx, 0, mb - 1), axis=1))
+    off = jnp.where(oob, 0, tpos % bs_blk)
+    key_arena = key_arena.at[blk, off].set(k_idx.astype(key_arena.dtype))
+    q_idx = q_idx.astype(key_arena.dtype)
+    if s == 1 and _pa._kernel_ok(key_arena):
+        scores = _pa.dsa_index_scores_decode(
+            q_idx[:, 0], w_idx[:, 0], key_arena, block_table,
+            posv + 1)[:, None]
+    else:
+        scores = _pa.dsa_index_scores_reference(
+            q_idx, w_idx, key_arena, block_table, key_block=1024)
+    seen = jnp.arange(mb * bs_blk)[None, None, :] <= tpos[:, :, None]
+    return jnp.where(seen, scores, -jnp.inf), key_arena
 
 
 def packed_cached_attention(q, k, v, arena, posv, block_table, *, scale,
@@ -356,6 +430,19 @@ def forward_accepts_block_table(cls) -> bool:
     return cached
 
 
+def forward_accepts_valid_len(cls) -> bool:
+    """Whether ``cls.forward`` takes ``valid_len``: how many of a
+    right-padded chunk's columns hold a prompt token (a model whose
+    per-row work is dear, a sort and a gather a row, skips the pad
+    columns' blocks). Cached per class like :func:`forward_accepts_pad`."""
+    cached = cls.__dict__.get("_fwd_accepts_valid_len")
+    if cached is None:
+        import inspect
+        cached = "valid_len" in inspect.signature(cls.forward).parameters
+        cls._fwd_accepts_valid_len = cached
+    return cached
+
+
 def build_decode_step(model, sample_kwargs, tree_holder,
                       all_positions=False):
     """The shared pure step: (params, bufs, token_block, cache_flat,
@@ -375,6 +462,7 @@ def build_decode_step(model, sample_kwargs, tree_holder,
                          "pass sample_kwargs=None")
     ptensors = [p for _, p in model.named_parameters()]
     btensors = [b for _, b in model.named_buffers()]
+    takes_valid_len = forward_accepts_valid_len(type(model))
 
     def pure(pv, bv, token, cache_flat, pos, key=None, pad=None,
              block_table=None, last_index=None):
@@ -391,6 +479,10 @@ def build_decode_step(model, sample_kwargs, tree_holder,
             kw = {} if pad is None else {"pad": Tensor(pad)}
             if block_table is not None:     # paged-KV serving mode
                 kw["block_table"] = Tensor(block_table)
+            if last_index is not None and takes_valid_len:
+                # chunked prefill: the columns past the last real token
+                # are right-padding
+                kw["valid_len"] = Tensor(last_index + 1)
             with framework.functional_mode(), framework.no_grad_guard():
                 logits, new_cache = model.forward(
                     Tensor(token), cache=cache, pos=Tensor(pos), **kw)

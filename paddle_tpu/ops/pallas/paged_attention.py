@@ -73,6 +73,9 @@ __all__ = ["paged_attention_decode", "paged_attention_decode_int8",
            "mla_paged_attention_decode", "mla_paged_attention_reference",
            "packed_paged_attention_decode", "swa_paged_attention_decode",
            "packed_paged_attention_reference",
+           "swa_mla_paged_attention_decode", "dsa_index_scores_decode",
+           "dsa_index_scores_reference", "dsa_sparse_mla_decode",
+           "dsa_sparse_mla_reference",
            "paged_attention_reference", "paged_attention_int8_reference",
            "paged_gather", "quantize_kv", "dequantize_kv",
            "kv_int8_error_bound", "walk_counts"]
@@ -592,23 +595,81 @@ def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
 
 
 def mla_paged_attention_reference(q, arena, block_table, lengths, *,
-                                  scale, rank):
+                                  scale, rank, window=None, q_block=None):
     """Gathered path of the latent read, any ``s``: the table gathered
     into timeline order, every head's ``q (b, s, h, w)`` against the one
     shared row (``w`` the arena's padded width), causal by ``lengths``
     (row i of the ``s`` ends at ``lengths - s + i``), fp32 softmax, the
-    value the row's first ``rank`` columns. Returns ``(b, s, h, rank)``."""
+    value the row's first ``rank`` columns. Under a ``window`` key j is
+    seen from query i iff ``0 <= i - j < window`` and only the table
+    columns a window can span are gathered (the packed read's rule: the
+    table may cycle over a ring). ``q_block`` evaluates the query rows in
+    blocks of that many (s a multiple). Returns ``(b, s, h, rank)``."""
     b, s = q.shape[:2]
-    lat = arena[block_table]                       # (b, mb, bs, w)
-    lat = lat.reshape(b, -1, lat.shape[-1])
-    scores = jnp.einsum("bshw,btw->bhst", q.astype(jnp.float32),
-                        lat.astype(jnp.float32)) * scale
+    if window is None and q_block is None:
+        lat = arena[block_table]                       # (b, mb, bs, w)
+        lat = lat.reshape(b, -1, lat.shape[-1])
+        scores = jnp.einsum("bshw,btw->bhst", q.astype(jnp.float32),
+                            lat.astype(jnp.float32)) * scale
+        q_idx = (lengths - s)[:, None] + jnp.arange(s)[None, :]  # (b, s)
+        mask = jnp.arange(lat.shape[1])[None, None, :] <= q_idx[:, :, None]
+        scores = jnp.where(mask[:, None], scores, jnp.float32(_NEG))
+        probs = jax.nn.softmax(scores, axis=-1).astype(lat.dtype)
+        out = jnp.einsum("bhst,btr->bshr", probs, lat[..., :rank])
+        return out.astype(q.dtype)
+    bs, mb = arena.shape[1], block_table.shape[1]
+    if window is None:
+        tbl = block_table
+        t_idx = jnp.broadcast_to(jnp.arange(mb * bs)[None], (b, mb * bs))
+    else:
+        ncols = min(mb, -(-(window - 1 + s) // bs) + 1)
+        first = jnp.clip((lengths - s - (window - 1)) // bs, 0, mb - ncols)
+        tbl = jnp.take_along_axis(
+            block_table, first[:, None] + jnp.arange(ncols)[None], axis=1)
+        t_idx = first[:, None] * bs + jnp.arange(ncols * bs)[None]
+    lat = arena[tbl].reshape(b, -1, arena.shape[-1])        # (b, T, w)
+
+    def read(qb, q_idx):
+        """``qb (b, n, h, w)`` at positions ``q_idx (b, n)``."""
+        scores = jnp.einsum("bshw,btw->bhst", qb, lat,
+                            preferred_element_type=jnp.float32) * scale
+        mask = t_idx[:, None, :] <= q_idx[:, :, None]           # (b, n, T)
+        if window is not None:
+            mask = mask & (t_idx[:, None, :] > q_idx[:, :, None] - window)
+        scores = jnp.where(mask[:, None], scores, jnp.float32(_NEG))
+        probs = jax.nn.softmax(scores, axis=-1).astype(lat.dtype)
+        return jnp.einsum("bhst,btr->bshr", probs,
+                          lat[..., :rank]).astype(q.dtype)
+
     q_idx = (lengths - s)[:, None] + jnp.arange(s)[None, :]      # (b, s)
-    mask = jnp.arange(lat.shape[1])[None, None, :] <= q_idx[:, :, None]
-    scores = jnp.where(mask[:, None], scores, jnp.float32(_NEG))
-    probs = jax.nn.softmax(scores, axis=-1).astype(lat.dtype)
-    out = jnp.einsum("bhst,btr->bshr", probs, lat[..., :rank])
-    return out.astype(q.dtype)
+    return _in_query_blocks(read, q, q_idx, q_block)
+
+
+def _in_query_blocks(read, q, q_idx, q_block, *more, valid_len=None):
+    """``read(q (b, n, ...), q_idx (b, n), *more (b, n, ...))`` over the
+    ``s`` query rows in blocks of ``q_block`` (s a multiple), so that a
+    long chunk's scores fit; whole where ``q_block`` is None or covers s.
+    With ``valid_len`` (a scalar) a block whose first row is at or past it
+    is not read: its output is zeros."""
+    b, s = q.shape[:2]
+    if q_block is None or s <= q_block:
+        return read(q, q_idx, *more)
+    nb = s // q_block
+
+    def blocks(x):
+        return jnp.moveaxis(x.reshape((b, nb, q_block) + x.shape[2:]), 1, 0)
+
+    def one(a):
+        lo, args = a[0], a[1:]
+        if valid_len is None:
+            return read(*args)
+        shape = jax.eval_shape(read, *args)
+        return jax.lax.cond(lo < valid_len, lambda: read(*args),
+                            lambda: jnp.zeros(shape.shape, shape.dtype))
+    out = jax.lax.map(one, (jnp.arange(nb) * q_block,)
+                      + tuple(blocks(x) for x in (q, q_idx) + more))
+    out = jnp.moveaxis(out, 0, 1)
+    return out.reshape((b, s) + out.shape[3:])
 
 
 def mla_paged_attention_decode(q, arena, block_table, lengths, *, scale,
@@ -718,6 +779,337 @@ def swa_paged_attention_decode(q, arena, block_table, lengths, sinks, *,
     return _walk_pages("swa_paged_attention_decode", _chunk_latent, q,
                        (arena,), block_table, lengths, scale, kvh=kvh,
                        v_streams=(0,), dv=dv, window=window, sinks=sinks)
+
+
+def swa_mla_paged_attention_decode(q, arena, block_table, lengths, *,
+                                   scale, rank, window):
+    """One decode step of latent attention under a sliding ``window``
+    (:func:`mla_paged_attention_decode` with the window walk of
+    :func:`swa_paged_attention_decode`, no sink): the walk starts at the
+    first page that holds one of the slot's last ``window`` tokens, so the
+    table may cycle a slot's columns over a ring of blocks. Returns
+    ``o_latent (b, h, rank)``."""
+    return _walk_pages("swa_mla_paged_attention_decode", _chunk_latent, q,
+                       (arena,), block_table, lengths, scale, kvh=1,
+                       v_streams=(0,), dv=rank, window=window)
+
+
+# ---------------------------------------------------------------------------
+# learned sparse attention over a latent cache: the indexer's scores over a
+# second arena of the same blocks, and the read of the selected rows
+# ---------------------------------------------------------------------------
+
+def _index_scores(q, w, keys, key_block=None):
+    """``I = sum_j w_j ReLU(q_j . k)``: ``q (b, s, H, d)``, ``w (b, s,
+    H)`` float32, ``keys (b, T, d)`` -> ``(b, s, T)`` float32 (fp32
+    accumulation, operands as stored). ``key_block`` evaluates the keys in
+    blocks of that many, so that the per-head intermediate ``(b, s, H,
+    block)`` fits beside a long table."""
+    w = w.astype(jnp.float32)
+
+    def block(kb):
+        dots = jnp.einsum("bshd,btd->bsht", q, kb,
+                          preferred_element_type=jnp.float32)
+        return jnp.einsum("bsht,bsh->bst", jnp.maximum(dots, 0.0), w)
+
+    b, t, d = keys.shape
+    if key_block is None or t <= key_block:
+        return block(keys)
+    pad = -t % key_block                # zero keys: their scores are cut
+    keys = jnp.pad(keys, ((0, 0), (0, pad), (0, 0)))
+    out = jax.lax.map(block, jnp.moveaxis(
+        keys.reshape(b, (t + pad) // key_block, key_block, d), 1, 0))
+    return jnp.moveaxis(out, 0, 2).reshape(b, q.shape[1], t + pad)[..., :t]
+
+
+def dsa_index_scores_reference(q, w, key_arena, block_table, *,
+                               key_block=None):
+    """Gathered path of the indexer, any ``s``: the key arena ``(nb, bs,
+    d)`` gathered through the table into timeline order, ``q (b, s, H,
+    d)`` and ``w (b, s, H)`` against it. Returns ``(b, s, mb * bs)``
+    float32, unmasked."""
+    b = q.shape[0]
+    keys = key_arena[block_table].reshape(b, -1, key_arena.shape[-1])
+    return _index_scores(q, w, keys, key_block)
+
+
+# Pages one copy may take when the table names them in a row: a key page is
+# 4 KiB (16 tokens x 128 x 2 B) and a copy costs about 40 ns to issue
+# whatever its size, so page-by-page the indexer's walk is bound by issuing
+# copies at a ninth of the HBM roofline (my chip run, PR 37). A prefix
+# prefilled into a fresh pool lies in consecutive blocks, rising or (the
+# block manager pops its free list from the end) FALLING, and an aligned
+# run of _RUN of them moves as one copy; anything else moves page by page.
+# :func:`_run_directions` is the ONE place that says which groups those are:
+# the kernel reads its verdict a group (a scalar-prefetch operand) and the
+# caller turns a falling group's scores, which arrive in reverse, back.
+_RUN = 8
+
+
+def _run_directions(block_table, lengths, bs, run):
+    """``(b, groups)`` int32: +1 where an aligned group of ``run`` table
+    columns, wholly live, names rising consecutive blocks, -1 where falling
+    ones (the kernel copies either as one piece, lowest block first), 0
+    where its pages move one by one."""
+    b, mb = block_table.shape
+    groups = -(-mb // run)
+    tbl = jnp.pad(block_table, ((0, 0), (0, groups * run - mb)),
+                  constant_values=-1).reshape(b, groups, run)
+    step = jnp.arange(run)
+    rising = jnp.all(tbl == tbl[..., :1] + step, axis=-1)
+    falling = jnp.all(tbl == tbl[..., :1] - step, axis=-1)
+    n_pages = jnp.maximum(-(-jnp.minimum(lengths, mb * bs) // bs), 1)
+    live = (jnp.arange(groups) + 1) * run <= n_pages[:, None]
+    return jnp.where(live & (run > 1), rising.astype(jnp.int32)
+                     - falling.astype(jnp.int32), 0)
+
+
+def _index_kernel(tbl_ref, len_ref, dir_ref, q_ref, w_ref, hbm, o_ref, buf,
+                  sems, parity, *, bs, mb, ppc, run):
+    """The page walk of :func:`_walk_kernel` over ONE key arena that
+    returns scores and no softmax: a grid step is a slot; its live pages
+    are copied ``ppc`` to a chunk into one of two buffers (the next
+    chunk's, or the next slot's first, in flight meanwhile); a chunk is
+    the ``(rows, d)`` matrix it is in memory, ``q (H, d)`` against it in
+    one product, ReLU, the heads' weighted sum, and the chunk's ``(1,
+    rows)`` scores go to row c of the slot's output. Rows past the length
+    hold what the buffer held: the caller masks by position. Live pages
+    move ``run`` to a copy where :func:`_run_directions` says an aligned
+    group of table columns names consecutive blocks (``dir_ref``; a falling
+    group's pages, and so its scores, arrive in reverse)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    i = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+
+    def pages_of(slot):
+        return jnp.maximum(pl.cdiv(jnp.minimum(len_ref[slot], mb * bs), bs),
+                           1)
+
+    def each_live_page(slot, n_pages, c, b_, fn):
+        """``fn`` on every copy of chunk c's live pages: the same copies,
+        in the same order, whether they are started or waited for."""
+        n_live = jnp.minimum(n_pages - c * ppc, ppc)
+
+        def group(g, carry):
+            p0 = g * run
+            col = c * ppc + p0
+
+            def page_by_page():
+                def page_copy(p, carry):
+                    fn(pltpu.make_async_copy(
+                        hbm.at[tbl_ref[slot, col + p]], buf.at[b_, p0 + p],
+                        sems.at[b_]))
+                    return carry
+                jax.lax.fori_loop(0, jnp.minimum(n_live - p0, run),
+                                  page_copy, 0)
+
+            if run == 1:
+                page_by_page()
+                return carry
+            way = dir_ref[slot, col // run]
+
+            @pl.when(way != 0)
+            def _whole():
+                lowest = tbl_ref[slot, col] - jnp.where(way < 0, run - 1, 0)
+                fn(pltpu.make_async_copy(
+                    hbm.at[pl.ds(lowest, run)], buf.at[b_, pl.ds(p0, run)],
+                    sems.at[b_]))
+
+            pl.when(way == 0)(page_by_page)
+            return carry
+
+        jax.lax.fori_loop(0, pl.cdiv(n_live, run), group, 0)
+
+    def start(slot, n_pages, c, b_):
+        each_live_page(slot, n_pages, c, b_, lambda cp: cp.start())
+
+    n_pages = pages_of(i)
+    n_chunks = pl.cdiv(n_pages, ppc)
+
+    @pl.when(i == 0)
+    def _first():
+        parity[0] = 0
+        start(i, n_pages, 0, 0)
+
+    base = parity[0]
+    q = q_ref[0]
+    w = w_ref[0].astype(jnp.float32)                        # (H, 1)
+
+    def fold(c, carry):
+        b_ = (base + c) % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next_chunk():
+            start(i, n_pages, c + 1, 1 - b_)
+
+        @pl.when((c + 1 == n_chunks) & (i + 1 < n_slots))
+        def _next_slot():
+            nxt = jnp.minimum(i + 1, n_slots - 1)
+            start(nxt, pages_of(nxt), 0, 1 - b_)
+
+        each_live_page(i, n_pages, c, b_, lambda cp: cp.wait())
+        k = buf[b_]
+        k = k.reshape(-1, k.shape[-1])
+        dots = jax.lax.dot_general(
+            q.astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # (H, rows)
+        o_ref[0, pl.ds(c, 1), :] = jnp.sum(
+            jnp.maximum(dots, 0.0) * w, axis=0, keepdims=True)
+        return carry
+
+    jax.lax.fori_loop(0, n_chunks, fold, 0)
+    parity[0] = (base + n_chunks) % 2
+
+
+@functools.partial(jax.jit, static_argnames=("ppc", "run", "interpret"))
+def _index_pallas_call(q, w, key_arena, block_table, lengths, *, ppc, run,
+                       interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, d = q.shape
+    _, bs, _ = key_arena.shape
+    mb = block_table.shape[1]
+    chunks, rows = -(-mb // ppc), ppc * bs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec((1, h, 1), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pltpu.HBM)],
+        out_specs=pl.BlockSpec((1, chunks, rows), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, ppc) + key_arena.shape[1:],
+                                   key_arena.dtype),
+                        pltpu.SemaphoreType.DMA((2,)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    ways = _run_directions(block_table, lengths, bs, run) if run > 1 \
+        else jnp.zeros((b, 1), jnp.int32)
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, bs=bs, mb=mb, ppc=ppc, run=run),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, chunks, rows), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret, name="dsa_index_scores_decode",
+    )(block_table, lengths, ways, q, w.astype(jnp.float32)[:, :, None],
+      key_arena)
+    out = out.reshape(b, chunks * rows)[:, :-(-mb // run) * run * bs]
+    if run > 1:         # a falling run's pages came lowest block first
+        pages = out.reshape(b, -1, run, bs)
+        out = jnp.where((ways < 0)[:, :, None, None], pages[:, :, ::-1],
+                        pages).reshape(b, -1)
+    return out[:, :mb * bs]
+
+
+def dsa_index_scores_decode(q, w, key_arena, block_table, lengths):
+    """One decode step of the indexer: ``q (b, H, d)``, ``w (b, H)``
+    against every live key of the slot in the key arena ``(nb, bs, d)``,
+    walked page by page through the table (never gathered). Returns ``(b,
+    mb * bs)`` float32; entries at positions ``>= lengths`` are undefined
+    (the caller masks them)."""
+    mb = block_table.shape[1]
+    ppc = _pages_per_chunk(mb, [(key_arena.shape[1:], key_arena.dtype)])
+    run = _RUN if ppc % _RUN == 0 else 1     # groups must not straddle chunks
+    return _index_pallas_call(q, w, key_arena, block_table, lengths,
+                              ppc=ppc, run=run,
+                              interpret=_fused._FORCE_INTERPRET)
+
+
+def selected_rows(arena, block_table, ids):
+    """Token ids ``(b, ..., k)`` on each slot's timeline -> their rows of
+    the arena ``(nb, bs, w)`` through the table: ``(b, ..., k, w)``."""
+    bs = arena.shape[1]
+    shape = ids.shape
+    flat = ids.reshape(shape[0], -1)
+    blk = jnp.take_along_axis(block_table, flat // bs, axis=1)
+    rows = arena.reshape(-1, arena.shape[-1])[blk * bs + flat % bs]
+    return rows.reshape(shape + (arena.shape[-1],))
+
+
+def dsa_sparse_mla_reference(q, arena, block_table, ids, n_valid, *, scale,
+                             rank, q_block=None, valid_len=None):
+    """Gathered path of the selected latent read, any ``s``: ``q (b, s, h,
+    w)`` attends to the rows ``ids (b, s, k)`` of its slot's timeline and
+    to those only, the first ``n_valid (b, s)`` of them (the rest are
+    masked; an id past the valid ones must still name a row of the slot).
+    fp32 softmax, the value the row's first ``rank`` columns; ``q_block``
+    as in :func:`mla_paged_attention_reference`; with ``valid_len`` the
+    query blocks wholly at or past it are skipped (zeros). Returns ``(b, s,
+    h, rank)``."""
+    k = ids.shape[-1]
+
+    def read(qb, _, idb, nb_):
+        rows = selected_rows(arena, block_table, idb)        # (b, n, k, w)
+        scores = jnp.einsum("bshw,bskw->bhsk", qb, rows,
+                            preferred_element_type=jnp.float32) * scale
+        mask = jnp.arange(k)[None, None, :] < nb_[:, :, None]
+        scores = jnp.where(mask[:, None], scores, jnp.float32(_NEG))
+        probs = jax.nn.softmax(scores, axis=-1).astype(rows.dtype)
+        return jnp.einsum("bhsk,bskr->bshr", probs,
+                          rows[..., :rank]).astype(q.dtype)
+
+    return _in_query_blocks(read, q, n_valid, q_block, ids, n_valid,
+                            valid_len=valid_len)
+
+
+def _sparse_kernel(n_ref, q_ref, rows_ref, o_ref, *, scale, rank):
+    """One slot: ``q (h, w)`` against its ``k`` gathered rows ``(k, w)``,
+    the first ``n`` of them valid; softmax; the value the rows' first
+    ``rank`` columns."""
+    from jax.experimental import pallas as pl
+    n = n_ref[pl.program_id(0)]
+    q, rows = q_ref[0], rows_ref[0]
+    s = jax.lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32) * scale
+    seen = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) < n
+    s = jnp.where(seen, s, _NEG)
+    p = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    acc = jnp.dot(p.astype(rows.dtype), rows[:, :rank],
+                  preferred_element_type=jnp.float32)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "rank", "interpret"))
+def _sparse_pallas_call(q, rows, n_valid, *, scale, rank, interpret):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    b, h, w = q.shape
+    k = rows.shape[1]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(b,),
+        in_specs=[pl.BlockSpec((1, h, w), lambda i, n: (i, 0, 0)),
+                  pl.BlockSpec((1, k, w), lambda i, n: (i, 0, 0))],
+        out_specs=pl.BlockSpec((1, h, rank), lambda i, n: (i, 0, 0)))
+    return pl.pallas_call(
+        functools.partial(_sparse_kernel, scale=scale, rank=rank),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
+            vmem_limit_bytes=_SPARSE_VMEM_LIMIT),
+        interpret=interpret, name="dsa_sparse_mla_decode",
+    )(n_valid, q, rows.astype(q.dtype))
+
+
+# a slot's 2,048 gathered rows of 640 (2.6 MB) are pipelined whole, twice,
+# beside the (heads, 2048) fp32 scores: past Mosaic's default of 16 MiB
+_SPARSE_VMEM_LIMIT = 48 * 1024 * 1024
+
+
+def dsa_sparse_mla_decode(q, arena, block_table, ids, n_valid, *, scale,
+                          rank):
+    """One decode step of the selected latent read: ``q (b, h, w)``
+    attends to the rows ``ids (b, k)`` of its slot's timeline, the first
+    ``n_valid (b,)`` of them. The rows are gathered through the table
+    (``selected_rows``: k rows a slot, never the table) and the latent
+    step over them is ONE named Pallas call. Returns ``o_latent (b, h,
+    rank)``."""
+    rows = selected_rows(arena, block_table, ids)            # (b, k, w)
+    return _sparse_pallas_call(q, rows, n_valid.astype(jnp.int32),
+                               scale=scale, rank=rank,
+                               interpret=_fused._FORCE_INTERPRET)
 
 
 def _int8_decode_fallback(q, k_codes, v_codes, k_scales, v_scales,

@@ -1,7 +1,9 @@
 """The paged engine for a model whose cache keeps TWO GROUPS of layers:
 full-attention layers that hold every token, and sliding-window layers
-that ever read back only their last ``window`` tokens (``models/mimo_v2.py``;
-the model says so through ``kv_cache_groups``).
+that ever read back only their last ``window`` tokens (``models/mimo_v2.py``,
+packed grouped-query arenas; ``models/dots3_note.py``, latent arenas, a full
+layer's leaf a PAIR of arenas, latent rows and index keys, under one block
+id; the model says so through ``kv_cache_groups``).
 
 Each group has its own arena geometry (the model's), its own pool of
 blocks with its own :class:`~.paging.BlockManager`, and its own block
@@ -76,9 +78,19 @@ class HybridPagedStepBackend(PagedModelStepBackend):
                          quant=quant)
 
     def _new_cache(self, model):
-        return model.init_paged_kv_cache(
+        """The model's cache; ``leaf_group`` then names the group of every
+        ARENA: a layer's leaf may be a tuple of arenas of one group that
+        share a block id (latent rows and their index keys)."""
+        import jax
+        cache = model.init_paged_kv_cache(
             self.num_kv_blocks, self.kv_block_size,
             window_blocks=self.num_window_blocks)
+        groups, arenas = self.leaf_group, []
+        for leaf, group in zip(cache["layers"], groups):
+            arenas += [group] * len(jax.tree.leaves(
+                leaf, is_leaf=lambda x: not isinstance(x, (tuple, list))))
+        self.leaf_group = tuple(arenas) + groups[len(cache["layers"]):]
+        return cache
 
     table_width = property(lambda self: 2 * self.max_blocks)
 

@@ -735,6 +735,9 @@ class Server:
             if eng.cache_passes > 1:           # a looped model's extras
                 out["ut_steps"] = eng.ut_steps
                 out["ut_exit_step_milli"] = eng.ut_exit_step_milli
+            if hasattr(eng, "dsa_tokens_scored"):   # learned sparse reads
+                out["dsa_tokens_scored"] = eng.dsa_tokens_scored
+                out["dsa_tokens_selected"] = eng.dsa_tokens_selected
             wm = getattr(eng, "window_manager", None)
             if wm is not None:                 # the hybrid cache's 2nd pool
                 out["window_block_evictions"] = wm.evictions

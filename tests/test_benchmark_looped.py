@@ -142,11 +142,11 @@ def test_configuration_file_keeps_every_published_key(cfg, bench):
             "biases", "weights", "sandwich_output_norms", "sampling"} \
         <= set(cfg["assumed"])
     assert "overrides" not in cfg        # tunables at the program's defaults
-    entry = bench["configs"][-1]
+    entry = bench["configs"][4]          # the fifth; later PRs append
     assert (entry["name"], entry["reduced"], entry["file"]) \
         == (NAME, [], "benchmark/configs/Ouro-2.6B.json")
     assert entry["source"] == cfg["source"] and len(entry["why"]) <= 200
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][4]
     assert (cell["name"], cell["config"], cell["chips"]) == (CELL, NAME, 1)
     assert len(cell["why"]) <= 200
     reports = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]

@@ -479,3 +479,133 @@ def test_looped_programs_hold_each_layer_once_and_copy_no_arena(
     relaid = 2 * 3 * 2048 * 2048 * 2
     assert ma.temp_size_in_bytes - relaid < arena_bytes
     assert hbm_bytes(compiled) < 2 * 2 ** 30
+
+
+# -- learned sparse attention over a latent cache (dots3-longdoc-decode) ------
+
+DSA = dict(slots=64, mb=2304, blocks=49153, window_blocks=6401)
+
+
+def _case_dsa_index(one_chip):
+    """The indexer's decode walk at the shape dots3-longdoc-decode serves:
+    64 slots, 64 index heads of 128 against one 128-wide key a token, a
+    2,304-entry table, 49,153 blocks; scores out for all 36,864 positions."""
+    arena = ((DSA["blocks"], KV_BLOCK, 128), BF16)
+    assert pa._tiles(*arena)
+    text = _arenas_are_viewed_not_copied(_compile(
+        pa.dsa_index_scores_decode, one_chip, ((64, 64, 128), BF16),
+        ((64, 64), jnp.float32), arena, ((64, DSA["mb"]), jnp.int32),
+        ((64,), jnp.int32)))
+    assert "%dsa_index_scores_decode" in text
+    return text
+
+
+def _case_dsa_sparse(one_chip):
+    """The selected read: 2,048 row ids a slot gathered from the 640-wide
+    latent arena (never the table), 128 heads over them in one call."""
+    arena = ((DSA["blocks"], KV_BLOCK, 640), BF16)
+    text = _compile(
+        functools.partial(pa.dsa_sparse_mla_decode, scale=192 ** -0.5,
+                          rank=512),
+        one_chip, ((64, 128, 640), BF16), arena,
+        ((64, DSA["mb"]), jnp.int32), ((64, 2048), jnp.int32),
+        ((64,), jnp.int32))
+    assert "%dsa_sparse_mla_decode" in text
+    # nothing table-sized (64 x 36,864 rows) or arena-sized is made
+    assert not re.search(r"bf16\[64,36864,640\]|bf16\[49153,16,640\]\S* copy",
+                         text)
+    return text
+
+
+def _case_swa_mla(one_chip):
+    """The sliding layers' latent read: 64 heads against a 1,152-wide row
+    (rank 1024), a window of 513 over a ring, 6,401 blocks."""
+    arena = ((DSA["window_blocks"], KV_BLOCK, 1152), BF16)
+    pages = [(arena[0][1:], BF16)]
+    assert pa._tiles(*arena)
+    assert pa._walk_vmem_bytes(pa._pages_per_chunk(33, pages), pages) \
+        <= pa._VMEM_BUDGET
+    text = _arenas_are_viewed_not_copied(_compile(
+        functools.partial(pa.swa_mla_paged_attention_decode,
+                          scale=256 ** -0.5, rank=1024, window=513),
+        one_chip, ((64, 64, 1152), BF16), arena,
+        ((64, DSA["mb"]), jnp.int32), ((64,), jnp.int32)))
+    assert "%swa_mla_paged_attention_decode" in text
+    return text
+
+
+CASES.update({"dsa_index_scores_served_shape": _case_dsa_index,
+              "dsa_sparse_read_served_shape": _case_dsa_sparse,
+              "swa_mla_decode_served_shape": _case_swa_mla})
+
+
+def abstract_sparse_latent_programs(one_chip, layers=5):
+    """The hybrid engine's two programs for the dots3-note-prev
+    configuration as the benchmark serves it (published widths, 16 of 256
+    experts, 64 slots of 36,864, both pools), lowered on abstract weights."""
+    import json
+    from benchmark import weights_by_class
+    from paddle_tpu.models.dots3_note import Dots3NoteForCausalLM
+    from paddle_tpu.serving.hybrid import HybridPagedStepBackend
+    from paddle_tpu.utils.scale import abstract_init
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "dots3-note-prev.json")) as f:
+        c = json.load(f)
+    cfg = weights_by_class.model_config(
+        c, n_routed_experts=256, experts_held=(0, 16),
+        num_hidden_layers=layers)
+    with abstract_init("bfloat16"):
+        model = Dots3NoteForCausalLM(cfg)
+    dep = c["deployment"]
+    be = HybridPagedStepBackend(
+        model, dep["num_slots"], dep["max_len"], 8, KV_BLOCK,
+        dep["num_blocks"], dep["window_blocks"], 512)
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def scalar(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = tuple(jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+                  for shape, dtype in be.pool_specs)
+    pv, bv = [spec(v) for v in be._pv], [spec(v) for v in be._bv]
+    block = be._block_jit.lower(pv, bv, cache,
+                                jax.tree.map(spec, be.init_state()))
+    chunk = be._chunk_jit.lower(
+        pv, bv, scalar(jnp.int32, 1, 512), cache,
+        scalar(jnp.int32, 1, be.table_width), scalar(jnp.int32),
+        scalar(jnp.int32), scalar(jnp.uint32, 2), scalar(jnp.float32),
+        scalar(jnp.int32), scalar(jnp.float32))
+    return block, chunk, be
+
+
+@pytest.mark.parametrize("program", ["block", "chunk"])
+def test_sparse_latent_programs_fit_the_chip_and_copy_no_arena(
+        program, one_chip, on_tpu_gates):
+    """dots3-longdoc-decode's two programs at the served size: weights +
+    both pools + the program's temporaries fit the chip; the decode block
+    holds the three named Pallas calls (2 + 2 + 3 sites) and makes nothing
+    table-sized (64 x 36,864 rows of a latent or a key) or arena-sized; the
+    chunk program takes no fp32 scores over the whole table."""
+    block, chunk, be = abstract_sparse_latent_programs(one_chip)
+    assert be.leaf_group == (0, 0, 0, 0, 1, 1, 1, None)
+    compiled = (block if program == "block" else chunk).compile()
+    text = compiled.as_text()
+    assert hbm_bytes(compiled) < 14 * 2 ** 30, hbm_bytes(compiled) / 2 ** 30
+    ma = compiled.memory_analysis()
+    if program == "block":
+        for name, sites in (("dsa_index_scores_decode", 2),
+                            ("dsa_sparse_mla_decode", 2),
+                            ("swa_mla_paged_attention_decode", 3)):
+            assert len(set(re.findall(rf"%({name}\.\d+) = ", text))) \
+                == sites, name
+        # a table's worth of latent rows or index keys, or a copied arena
+        assert not re.search(
+            r"bf16\[64,36864,(640|128)\]|bf16\[64,2304,16,(640|128)\]|"
+            r"bf16\[(49153|6401),16,\d+\]\S* copy\(", text)
+        assert ma.temp_size_in_bytes < 1.5 * 2 ** 30
+    else:
+        assert not re.search(r"f32\[1,128,512,36864\]", text)
+        assert ma.temp_size_in_bytes < 3 * 2 ** 30

@@ -239,17 +239,28 @@ def latent_inputs(dtype=jnp.float32, w=128, rank=96):
     return q, arena, tbl, lens, rank
 
 
+# the second geometry: a sliding layer's of ``models/dots3_note.py``, whose
+# row is wider than its value by the same 64 (1,152 = 1,024 + 64 + pad there)
+@pytest.mark.parametrize("w,rank", [(128, 96), (384, 320)],
+                         ids=["row128", "row384"])
+@pytest.mark.parametrize("window", [None, 13], ids=["full", "window13"])
 @pytest.mark.parametrize("chunk_rows", [8, 16, 2048])
 def test_latent_kernel_in_interpret_mode_matches_the_gathered_read(
-        chunk_rows, monkeypatch):
+        chunk_rows, window, w, rank, monkeypatch):
     monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
     monkeypatch.setattr(pa, "_CHUNK_ROWS", chunk_rows)
-    q, arena, tbl, lens, rank = latent_inputs()
+    q, arena, tbl, lens, rank = latent_inputs(w=w, rank=rank)
     assert pa._kernel_ok(arena)
-    out = pa.mla_paged_attention_decode(q, arena, tbl, lens, scale=0.1,
-                                        rank=rank)
+    if window is None:
+        out = pa.mla_paged_attention_decode(q, arena, tbl, lens, scale=0.1,
+                                            rank=rank)
+        kw = {}
+    else:
+        out = pa.swa_mla_paged_attention_decode(
+            q, arena, tbl, lens, scale=0.1, rank=rank, window=window)
+        kw = {"window": window}
     want = pa.mla_paged_attention_reference(q[:, None], arena, tbl, lens,
-                                            scale=0.1, rank=rank)[:, 0]
+                                            scale=0.1, rank=rank, **kw)[:, 0]
     assert out.shape == (3, 4, rank)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=1e-5)
 

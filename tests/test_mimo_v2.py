@@ -519,10 +519,23 @@ def test_prefix_hit_streams_as_a_cold_prefill_and_leaves_only_the_tail(
     wm.assert_consistent()
 
 
-def test_a_continued_conversation_hits_on_the_retired_rings_tail(f32_model):
+@pytest.fixture(scope="module", params=["mimo_v2", "dots3_note"])
+def family(request, f32_model):
+    """The two model families the hybrid engine serves: packed
+    grouped-query arenas (this file's), and latent arenas whose full
+    layers' leaf is a pair under one block id (``models/dots3_note.py``).
+    ``(model, is the reference's argmax?)``."""
+    if request.param == "mimo_v2":
+        return f32_model, is_the_references_argmax
+    import test_dots3_note as other
+    return other.build(), other.is_the_references_argmax
+
+
+def test_a_continued_conversation_hits_on_the_retired_rings_tail(family):
     """A stream's last window is still in its ring when it retires: the
     tail before its last full block is registered as it is, so a request
     that continues the conversation hits in both groups."""
+    f32_model, is_the_references_argmax = family
     eng, srv = engine_of(f32_model)
     first = ids_of(45, seed=8)
     rid = srv.submit(first, max_new_tokens=28)
@@ -626,11 +639,12 @@ def test_hybrid_engine_hashes_a_block_once_for_both_groups(
     assert set(window_index) <= set(index)
 
 
-def test_both_pools_are_consistent_after_a_churn(f32_model):
+def test_both_pools_are_consistent_after_a_churn(family):
     """Admissions, retirements and evictions in BOTH pools: small pools,
     two prefixes, requests that hit, miss, set a tail aside and wait for
     blocks; after every tick both managers' accounting holds, every
     stream is the reference's argmax, and nothing is left held."""
+    f32_model, is_the_references_argmax = family
     eng, srv = engine_of(f32_model, num_blocks=40, window_blocks=20,
                          max_len=128)
     prefixes = [ids_of(32, seed=40), ids_of(32, seed=41)]

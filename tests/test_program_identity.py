@@ -3,7 +3,9 @@ cells come out as pinned (``tools/program_identity.py``: StableHLO with
 locations stripped, kernels in interpret mode). The pins are the programs
 of PR 30, which PR 31 (a third class of cache beside them) had to leave as
 they were, as PR 33 did (a looped model's two programs are pinned beside
-them). A change that means to alter one of them updates its pin here
+them), and PR 37 (``DeepseekV3Attention`` takes its sizes as arguments:
+kanana's two programs are byte for byte what they were; the new model's two
+are pinned beside them). A change that means to alter one of them updates its pin here
 and says so in CHANGES.md; one that does not has changed what
 ``mistral7b-decode-sat``, ``kanana2-docqa-decode`` or
 ``ouro-reason-decode`` runs."""
@@ -25,6 +27,10 @@ PINNED = {
     # PR 33: a looped model's two programs (the passes a scan in each)
     "ouro.block": "d3bf11f9184cf1aa62eee8e9e1495b7bfaee0f78",
     "ouro.chunk": "07b38559f2adab2faa4491cf7dcc852151f6073c",
+    # PR 37: learned sparse attention over a latent cache through the hybrid
+    # backend (the three kernels' bodies are in the text)
+    "dots3_note.block": "bfbdb67f80ad2d06dc8c54bf04b4b54cc89b95df",
+    "dots3_note.chunk": "3e8f4231eedd6cbfbe3b3e387e740fcd996cc219",
 }
 
 

@@ -84,6 +84,23 @@ def _swa():
             f32(2, 4, 128), f32(5, 16, 128), i32(2, 3), i32(2), f32(4))
 
 
+def _swa_mla():
+    return jax.make_jaxpr(lambda *a: pa.swa_mla_paged_attention_decode(
+        *a, scale=0.1, rank=96, window=12))(
+            f32(2, 4, 128), f32(5, 8, 128), i32(2, 3), i32(2))
+
+
+def _dsa_index():
+    return jax.make_jaxpr(pa.dsa_index_scores_decode)(
+        f32(2, 4, 16), f32(2, 4), f32(5, 8, 16), i32(2, 3), i32(2))
+
+
+def _dsa_sparse():
+    return jax.make_jaxpr(lambda *a: pa.dsa_sparse_mla_decode(
+        *a, scale=0.1, rank=96))(f32(2, 4, 128), f32(5, 8, 128), i32(2, 3),
+                                 i32(2, 6), i32(2))
+
+
 def _paged_int8():
     codes = jnp.zeros((5, 8, 2, 32), jnp.int8)
     return jax.make_jaxpr(lambda *a: pa.paged_attention_decode_int8(
@@ -107,6 +124,11 @@ KERNELS = {
     # window-layer read has a name of its own
     "paged_attention_decode-packed": (_packed, ["paged_attention_decode"]),
     "swa_paged_attention_decode": (_swa, ["swa_paged_attention_decode"]),
+    # PR 37: the three reads of learned sparse attention over a latent cache
+    "swa_mla_paged_attention_decode":
+        (_swa_mla, ["swa_mla_paged_attention_decode"]),
+    "dsa_index_scores_decode": (_dsa_index, ["dsa_index_scores_decode"]),
+    "dsa_sparse_mla_decode": (_dsa_sparse, ["dsa_sparse_mla_decode"]),
     "fused_rms_norm": (lambda: jax.make_jaxpr(
         lambda x, w: fused.fused_rms_norm(x, w, 1e-5))(
             f32(4, 128), f32(128)), ["fused_rms_norm"]),
@@ -322,6 +344,87 @@ def test_hybrid_model_keeps_the_program_names_and_names_both_reads(
                   "lm_head", "sample"):
         assert f"{scope}/" in text, scope
     assert "moe_shared/" not in text            # this family has none
+
+
+def test_sparse_latent_model_keeps_the_program_names_and_names_its_reads(
+        interpret):
+    """A model with learned sparse attention over a latent cache through
+    the hybrid backend: the two programs keep the names the trace is read
+    by; a full layer's decode calls ``dsa_index_scores_decode`` and
+    ``dsa_sparse_mla_decode``, a sliding layer's
+    ``swa_mla_paged_attention_decode``; the indexer, the selection, the
+    selected read and the gate sit under scopes of their own."""
+    from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
+                                              dots3_note_tiny_config)
+    from paddle_tpu.serving import engine
+    from paddle_tpu.serving.hybrid import HybridPagedStepBackend
+    paddle.seed(0)
+    be = HybridPagedStepBackend(
+        Dots3NoteForCausalLM(dots3_note_tiny_config()), 2, 64, 4, 8, 17, 11,
+        8)
+    assert be.leaf_group == (0, 0, 0, 0, 1, 1, 1, None)
+    block, chunk = _lower_block(be), be._chunk_jit.lower(
+        be._pv, be._bv, i32(1, 8), be.pool_cache(), i32(1, be.table_width),
+        jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
+        jnp.float32(0), jnp.int32(0), jnp.float32(1))
+    assert _module_name(block) == engine.DECODE_PROGRAM == "jit_block_fn"
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM == "jit_chunk_fn"
+    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
+    names = pallas_names(jax.make_jaxpr(be._block_jit)(
+        be._pv, be._bv, cache, be.init_state()))
+    assert names.count("dsa_index_scores_decode") == 2
+    assert names.count("dsa_sparse_mla_decode") == 2
+    assert names.count("swa_mla_paged_attention_decode") == 3
+    assert "mla_paged_attention_decode" not in names
+    text = block.as_text(debug_info=True)
+    for scope in ("attn", "attn_swa", "dsa_index", "dsa_select", "dsa_read",
+                  "attn_gate", "mlp", "moe_router", "moe_experts",
+                  "moe_shared", "lm_head", "sample"):
+        assert f"{scope}/" in text, scope
+    chunk_text = chunk.as_text(debug_info=True)
+    for scope in ("dsa_index", "dsa_select", "dsa_read", "attn_gate"):
+        assert f"{scope}/" in chunk_text, scope
+
+
+def test_sparse_latent_engine_span_and_counter_names():
+    """The indexers' counters by the names PERF.md's rows give them: on the
+    engine, on the spans ``serving.decode_block`` and
+    ``serving.prefill_chunk`` beside the expert counters, and totalled in
+    ``Server.stats()``."""
+    from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
+                                              dots3_note_tiny_config)
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
+                                    Server)
+    paddle.seed(0)
+    eng = ContinuousBatchingEngine(
+        Dots3NoteForCausalLM(dots3_note_tiny_config()), num_slots=2,
+        max_len=64, decode_block=4, paged=True, block_size=8,
+        prefill_chunk=8)
+    assert list(eng.backend.cache_counters) == [
+        "moe_picks", "moe_expert_hits", "moe_max_load", "dsa_tokens_scored",
+        "dsa_tokens_selected"]
+    srv = Server(eng, Scheduler())
+    before = len(tracing.since(0))
+    srv.submit(np.arange(20, dtype=np.int32), max_new_tokens=6)
+    srv.run_until_idle()
+    spans = tracing.since(0)[before:]
+    blocks = [s.ids for s in spans if s.name == "serving.decode_block"
+              and "dsa_tokens_scored" in s.ids]
+    chunks = [s.ids for s in spans if s.name == "serving.prefill_chunk"
+              and "dsa_tokens_scored" in s.ids]
+    assert blocks and chunks
+    assert sum(b["dsa_tokens_selected"] for b in blocks) \
+        == eng.dsa_tokens_selected == 2 * sum(
+            min(20 + j + 1, 16) for j in range(5))
+    assert sum(b["dsa_tokens_scored"] for b in blocks) \
+        == eng.dsa_tokens_scored == 2 * sum(20 + j + 1 for j in range(5))
+    assert all("moe_picks" in b for b in blocks)
+    stats = srv.stats()
+    assert stats["dsa_tokens_scored"] == eng.dsa_tokens_scored
+    assert stats["dsa_tokens_selected"] == eng.dsa_tokens_selected
+    assert eng.prefill_dsa_tokens_scored == sum(
+        c["dsa_tokens_scored"] for c in chunks) > 0
 
 
 def test_hybrid_engine_span_and_counter_names():
