@@ -48,6 +48,8 @@ from .. import nn
 from ..incubate.distributed.models.moe import DroplessMoE
 from ..nn import functional as F
 from ..tensor import Tensor, apply_op
+from ..ops.pallas.dsa_select import dsa_select_topk
+from ..ops.pallas.dsa_select import kernel_ok as select_kernel_ok
 from .generation import (GenerationMixin, latent_cached_attention,
                          latent_index_scores)
 
@@ -197,11 +199,14 @@ class DeepseekV3Attention(nn.Layer):
       on the first ``qk_rope_head_dim`` dims of each, ``w = x W^I_w /
       sqrt(n_heads * head_dim)``, ``I(t, s) = sum_j w_j ReLU(q^I_j(t) .
       k^I(s))``; a query attends to the ``topk`` keys ``s <= t`` of
-      largest ``I`` (all of them while ``t + 1 <= topk``), chosen by an
-      exact ``lax.top_k``. The cache is then the PAIR ``(latent arena,
+      largest ``I`` (all of them while ``t + 1 <= topk``), chosen
+      exactly: by ``lax.top_k`` without a cache, by the Pallas kernel
+      ``dsa_select_topk`` (no sort; the ids in ascending position order)
+      on the cached path. The cache is then the PAIR ``(latent arena,
       index-key arena)`` under one block id, and ``forward`` returns a
-      third value: ``(ids (b, s, k), n_valid (b, s), counts (2,))`` with
-      the tokens scored and selected, summed over the live rows."""
+      third value: ``(ids (b, s, k), n_valid (b, s), counts (4,))`` with
+      the tokens scored and selected and the rows selected, all and by
+      the kernel, summed over the live rows."""
 
     def __init__(self, config: DeepseekV3Config, *, heads=None,
                  q_lora_rank=-1, kv_lora_rank=None, qk_nope_head_dim=None,
@@ -298,8 +303,9 @@ class DeepseekV3Attention(nn.Layer):
         per-row write offsets (the absorbed, cached read); without a
         cache the whole sequence attends to itself, expanded.
         ``valid_len`` (a scalar; a right-padded prefill chunk's real
-        columns): the indexer's selection and selected read, a sort and a
-        gather a row, skip the blocks of 128 rows that are all padding."""
+        columns): the indexer's selection and selected read skip the rows
+        that are all padding (the selection a block of 8, the read a block
+        of 128)."""
         b, s, _ = x.shape
         heads, rank, nope, vd = self.heads, self.rank, self.nope, self.v_dim
         window, indexer = self.window, self.indexer
@@ -347,12 +353,14 @@ class DeepseekV3Attention(nn.Layer):
             w3 = wv.reshape(rank, heads, nope + vd)
             return w3[..., :nope], w3[..., nope:]
 
-        def counts(n_valid, positions, live):
-            """[tokens scored, tokens selected] over the live rows: ``live
-            (b, 1 | s)``."""
+        def counts(n_valid, positions, live, by_kernel=False):
+            """[tokens scored, tokens selected, rows selected, rows the
+            kernel selected] over the live rows: ``live (b, 1 | s)``."""
             live = live.astype(jnp.int32)
+            rows = jnp.sum(jnp.broadcast_to(live, positions.shape))
             return jnp.stack([jnp.sum((positions + 1) * live),
-                              jnp.sum(n_valid * live)]).astype(jnp.int32)
+                              jnp.sum(n_valid * live), rows,
+                              rows if by_kernel else 0]).astype(jnp.int32)
 
         if cache is None:
             def expanded(qv, kvav, ckv, wv, *rest):
@@ -416,21 +424,14 @@ class DeepseekV3Attention(nn.Layer):
                 return self.o_proj(got), None
             return self.o_proj(got[0]), None, got[1:]
 
-        row_block = 128 if s > 128 and s % 128 == 0 else None
-
         def select_rows(score, k, valid):
-            """The exact top-k of every row's scores; of a chunk's row
-            blocks, only those that hold a real column."""
-            if valid is None or row_block is None:
-                return jax.lax.top_k(score, k)[1]
-
-            def block(lo):
-                rows = jax.lax.dynamic_slice_in_dim(score, lo, row_block, 1)
-                return jax.lax.cond(
-                    lo < valid, lambda: jax.lax.top_k(rows, k)[1],
-                    lambda: jnp.zeros(rows.shape[:2] + (k,), jnp.int32))
-            ids = jax.lax.map(block, jnp.arange(0, s, row_block))
-            return jnp.moveaxis(ids, 0, 1).reshape(b, s, k)
+            """The exact top-k of every row's scores, the ids in ascending
+            position order (``dsa_select_topk``); of a chunk, the row
+            blocks that hold no real column are skipped."""
+            live = None if valid is None else jnp.broadcast_to(
+                jnp.arange(s)[None, :] < valid, (b, s)).reshape(-1)
+            return dsa_select_topk(score.reshape(b * s, -1), k,
+                                   live).reshape(b, s, k)
 
         def absorbed(qv, kvav, ckv, wv, arena, posv, table, *rest):
             rest, valid = (rest[:-1], rest[-1]) if valid_len is not None \
@@ -465,7 +466,8 @@ class DeepseekV3Attention(nn.Layer):
                 live = (table[:, 0] > 0)[:, None]
                 if valid is not None:       # a chunk's real columns only
                     live = live & (jnp.arange(s)[None, :] < valid)
-                sel = (keys, ids, n_valid, counts(n_valid, positions, live))
+                sel = (keys, ids, n_valid,
+                       counts(n_valid, positions, live, select_kernel_ok()))
             out = jnp.einsum("bshr,rhd->bshd", o_lat, wvv)
             out = gated(out, g).reshape(b, s, heads * vd)
             return (out, arena) if sel is None else (out, arena) + sel

@@ -40,9 +40,10 @@ whose table cycles over a ring (``serving.hybrid.HybridPagedEngine``);
 ``forward``'s ``block_table (b, 2 * max_blocks)`` is the full group's row
 followed by the window group's. ``experts_held`` tells the expert layers
 which global experts they hold. The paged cache's last leaf is the int32
-counter array of ``models.deepseek_v3`` with two more columns
-(``cache_counters``): the tokens the indexers scored and selected, summed
-over the live rows and the full layers.
+counter array of ``models.deepseek_v3`` with four more columns
+(``cache_counters``): the tokens the indexers scored and selected and the
+rows selected, all and by the selection kernel, summed over the live rows
+and the full layers.
 """
 from __future__ import annotations
 
@@ -280,8 +281,8 @@ class Dots3NoteModel(nn.Layer):
                 moe = jnp.stack(moe)                     # (moe_layers, 3)
                 counters = counters.at[row, :2].add(jnp.sum(moe[:, :2], 0))
                 counters = counters.at[row, 2].max(jnp.max(moe[:, 2]))
-            if dsa:                                      # (full layers, 2)
-                counters = counters.at[row, 3:5].add(
+            if dsa:                                      # (full layers, 4)
+                counters = counters.at[row, 3:7].add(
                     jnp.sum(jnp.stack(dsa), 0))
             return counters
         counters = apply_op(count, cache["moe_counters"], *stats,
@@ -292,13 +293,17 @@ class Dots3NoteModel(nn.Layer):
 
 class Dots3NoteForCausalLM(nn.Layer, GenerationMixin):
     # as ``DeepseekV3ForCausalLM``: what the programs count into the paged
-    # cache's last leaf, an int32 (2, 5) array, row 0 by s = 1 calls
-    # (decode steps), row 1 by s > 1 calls (prefill chunks). The two dsa
-    # columns: tokens the indexers scored (each live row's context) and
-    # tokens the reads attended to, summed over the full layers
+    # cache's last leaf, an int32 (2, 7) array, row 0 by s = 1 calls
+    # (decode steps), row 1 by s > 1 calls (prefill chunks). The dsa
+    # columns, summed over the full layers: tokens the indexers scored
+    # (each live row's context), tokens the reads attended to, live rows
+    # whose top-k was selected, and of those the rows the kernel
+    # ``dsa_select_topk`` selected (all of them on a TPU, none off it)
     cache_counters = {"moe_picks": "sum", "moe_expert_hits": "sum",
                       "moe_max_load": "max", "dsa_tokens_scored": "sum",
-                      "dsa_tokens_selected": "sum"}
+                      "dsa_tokens_selected": "sum",
+                      "dsa_rows_selected": "sum",
+                      "dsa_rows_kernel_selected": "sum"}
 
     def __init__(self, config: Dots3NoteConfig):
         super().__init__()

@@ -254,9 +254,12 @@ def test_configuration_file_keeps_every_published_key(cfg):
                 "mla_decode_kernel_roofline", "swa_decode_kernel_roofline",
                 "full_decode_kernel_roofline",
                 "swa_moe_decode_step_roofline"} & reports
-    assert [m["name"] for m in bench["per_layer"][-6:]] == NEW_METRICS
+    # PR 37's six in a row, then PR 38's selection kernel share at the end
+    ours = bench["per_layer"][-7:]
+    assert [m["name"] for m in ours] == NEW_METRICS + [
+        "dsa_select_kernel_share"]
     assert all(m["workloads"] == [CELL] and m["moves"] == "out_tokens_per_s"
-               for m in bench["per_layer"][-6:])
+               for m in ours)
     assert {k: v["value"] for k, v in cfg["overrides"].items()} \
         == {"prefill_chunk": 512}
     # both pools and the weights, as a deployment would hold them

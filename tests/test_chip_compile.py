@@ -24,6 +24,7 @@ import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from paddle_tpu.ops.pallas import dsa_select as ds
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import fused
 from paddle_tpu.ops.pallas import paged_attention as pa
@@ -534,9 +535,33 @@ def _case_swa_mla(one_chip):
     return text
 
 
-CASES.update({"dsa_index_scores_served_shape": _case_dsa_index,
-              "dsa_sparse_read_served_shape": _case_dsa_sparse,
-              "swa_mla_decode_served_shape": _case_swa_mla})
+def _case_dsa_select(one_chip, rows=64):
+    """The indexer's exact top-2,048 of 36,864 scores a row without a sort:
+    a decode step's 64 slots, or one 128-row block of a prefill chunk."""
+    text = _compile(lambda s: ds.dsa_select_topk(s, 2048), one_chip,
+                    ((rows, DSA["mb"] * KV_BLOCK), jnp.float32))
+    assert "%dsa_select_topk" in text
+    assert not re.search(r"= \(?f32\[%d,(1,)?36864\]\S* sort\(" % rows,
+                         text)
+    return text
+
+
+# (a dict of its own: ``test_kernel_compiles_for_v5e`` took its cases from
+# CASES where it is defined, above, so an update down here never ran)
+SPARSE_CASES = {
+    "dsa_index_scores_served_shape": _case_dsa_index,
+    "dsa_select_topk_served_shape": _case_dsa_select,
+    "dsa_select_topk_chunk_block": functools.partial(_case_dsa_select,
+                                                     rows=128),
+    "dsa_sparse_read_served_shape": _case_dsa_sparse,
+    "swa_mla_decode_served_shape": _case_swa_mla,
+}
+
+
+@pytest.mark.parametrize("case", sorted(SPARSE_CASES))
+def test_sparse_latent_kernel_compiles_for_v5e(case, one_chip, on_tpu_gates):
+    text = SPARSE_CASES[case](one_chip)
+    assert "tpu_custom_call" in text, f"{case}: no Pallas call in the program"
 
 
 def abstract_sparse_latent_programs(one_chip, layers=5):
@@ -586,9 +611,11 @@ def test_sparse_latent_programs_fit_the_chip_and_copy_no_arena(
         program, one_chip, on_tpu_gates):
     """dots3-longdoc-decode's two programs at the served size: weights +
     both pools + the program's temporaries fit the chip; the decode block
-    holds the three named Pallas calls (2 + 2 + 3 sites) and makes nothing
-    table-sized (64 x 36,864 rows of a latent or a key) or arena-sized; the
-    chunk program takes no fp32 scores over the whole table."""
+    holds the four named Pallas calls (2 + 2 + 2 + 3 sites) and makes
+    nothing table-sized (64 x 36,864 rows of a latent or a key) or
+    arena-sized; the chunk program takes no fp32 scores over the whole
+    table; neither sorts a row's 36,864 scores (the selection is the
+    kernel ``dsa_select_topk``, 2 sites in each)."""
     block, chunk, be = abstract_sparse_latent_programs(one_chip)
     assert be.leaf_group == (0, 0, 0, 0, 1, 1, 1, None)
     compiled = (block if program == "block" else chunk).compile()
@@ -597,6 +624,7 @@ def test_sparse_latent_programs_fit_the_chip_and_copy_no_arena(
     ma = compiled.memory_analysis()
     if program == "block":
         for name, sites in (("dsa_index_scores_decode", 2),
+                            ("dsa_select_topk", 2),
                             ("dsa_sparse_mla_decode", 2),
                             ("swa_mla_paged_attention_decode", 3)):
             assert len(set(re.findall(rf"%({name}\.\d+) = ", text))) \
@@ -608,4 +636,8 @@ def test_sparse_latent_programs_fit_the_chip_and_copy_no_arena(
         assert ma.temp_size_in_bytes < 1.5 * 2 ** 30
     else:
         assert not re.search(r"f32\[1,128,512,36864\]", text)
+        assert len(set(re.findall(r"%(dsa_select_topk\.\d+) = ", text))) \
+            == 2
         assert ma.temp_size_in_bytes < 3 * 2 ** 30
+    # the selection sorts nothing: no sort of a row's 36,864 scores
+    assert not re.search(r"\[[\d,]*36864\]\S* sort\(", text)

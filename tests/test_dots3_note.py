@@ -194,11 +194,18 @@ def test_the_shares_of_the_experts_add_up_to_the_uncut_layer(f32_model):
 
 # -- the cached path -----------------------------------------------------------
 
-def test_chunked_paged_prefill_then_decode_matches_the_reference(f32_model):
+@pytest.mark.parametrize("interpret", [False, True], ids=["gathered",
+                                                          "kernels"])
+def test_chunked_paged_prefill_then_decode_matches_the_reference(
+        interpret, f32_model, monkeypatch):
     """70 tokens in chunks of 16 through a fresh two-group cache (the ring
     of cdiv(8 + 16, 8) + 1 = 4 blocks wraps, the selection drops most of
     the context), then 6 decode steps, against the reference's full
-    forward."""
+    forward: on the CPU lane, and with the kernels in interpret mode (the
+    selection ``dsa_select_topk`` in the chunks and the steps, the three
+    reads in the steps). Either way each row's ids come in ascending
+    position order."""
+    monkeypatch.setattr(fused, "_FORCE_INTERPRET", interpret)
     ids = ids_of(70, seed=2)
     rows, got, picks, sel = ref.cached_outputs(f32_model, ids, chunk=16,
                                                decode=6, block=8)
@@ -208,6 +215,8 @@ def test_chunked_paged_prefill_then_decode_matches_the_reference(f32_model):
     assert r["picks_agree"] == 1.0 and r["selected_share"] == 1.0
     assert r["logits_err"] < 1e-5
     assert len(rows) == 2 * 4 + 6
+    for ids_, _ in sel:
+        assert (np.diff(ids_[:, :TOPK], axis=-1) > 0).all()
 
 
 def test_a_ring_too_short_fails_the_cached_comparison(f32_model, monkeypatch):
@@ -459,8 +468,30 @@ def test_the_cached_step_through_the_kernels_is_the_gathered_step(
     np.testing.assert_allclose(outs[0][0], outs[1][0], rtol=2e-4, atol=2e-4)
     for (a, an), (b, bn) in zip(outs[0][1], outs[1][1]):
         assert np.array_equal(an, bn)
-        for r in range(len(an)):
-            assert set(a[r, :an[r]].tolist()) == set(b[r, :bn[r]].tolist())
+        assert np.array_equal(a, b)       # one order on both lanes
+
+
+@pytest.mark.parametrize("interpret", [False, True], ids=["gathered",
+                                                          "kernels"])
+def test_a_decode_step_counts_its_live_rows_selected(interpret, f32_model,
+                                                     monkeypatch):
+    """A decode step of three slots, one dead (its table row all trash):
+    the step counts the 2 live rows in each of the 2 full layers as
+    ``dsa_rows_selected``, and as ``dsa_rows_kernel_selected`` where the
+    kernel made the selection."""
+    monkeypatch.setattr(fused, "_FORCE_INTERPRET", interpret)
+    row, fb, wb = ref.ring_tables(64, 8, WINDOW, 8)
+    rows = np.stack([row, row, np.zeros_like(row)])
+    rows[1, :len(row) // 2] += len(row) // 2        # another slot's blocks
+    cache = f32_model.init_paged_kv_cache(2 * fb, 8, window_blocks=3 * wb)
+    with paddle.no_grad():
+        _, cache = f32_model(
+            paddle.to_tensor(ids_of(3, seed=1)[:, None]), cache=cache,
+            pos=paddle.to_tensor(np.asarray([30, 21, 0], np.int32)),
+            block_table=paddle.to_tensor(rows))
+    counts = np.asarray(cache["moe_counters"]._value)
+    assert counts[0, 5:].tolist() == [4, 4 if interpret else 0]
+    assert counts[1, 3:].tolist() == [0, 0, 0, 0]
 
 
 # -- the hybrid engine over a leaf of two arenas -------------------------------
@@ -611,13 +642,18 @@ def test_what_the_sparse_latent_cache_cannot_do_refuses_by_name(f32_model,
             "n_heads": 2, "head_dim": 8, "topk": 4})
 
 
+@pytest.mark.parametrize("interpret", [False, True], ids=["gathered",
+                                                          "kernels"])
 def test_a_chunks_pad_blocks_are_skipped_and_its_real_rows_unchanged(
-        f32_model):
-    """``valid_len``: of a right-padded chunk's blocks of 128 rows, those
-    wholly past the real columns skip the selection and the selected read;
-    the real rows' logits, the cache they leave and the selections are
-    what they are without it, and the chunk's dsa counters count the real
-    columns only."""
+        interpret, f32_model, monkeypatch):
+    """``valid_len``: of a right-padded chunk's rows, the blocks wholly past
+    the real columns skip the selected read (blocks of 128) and, in the
+    kernel, the selection (blocks of 8: zeros there); the real rows'
+    logits, the cache they leave and the selections are what they are
+    without it, and the chunk's dsa counters count the real columns only:
+    each one's row selected in each full layer, by the kernel where it
+    runs."""
+    monkeypatch.setattr(fused, "_FORCE_INTERPRET", interpret)
     ids = np.zeros((1, 256), np.int32)
     ids[0, :100] = ids_of(100, seed=13)
     row, fb, wb = ref.ring_tables(256 + 64, 8, WINDOW, 256)
@@ -636,12 +672,16 @@ def test_a_chunks_pad_blocks_are_skipped_and_its_real_rows_unchanged(
     (whole, sel_w, count_w), (cut, sel_c, count_c) = outs
     np.testing.assert_allclose(cut[:100], whole[:100], rtol=1e-5, atol=1e-5)
     for a, c_ in zip(sel_w, sel_c):
-        assert np.array_equal(a[:128], c_[:128])      # the first block ran
-        assert not c_[128:].any() and a[128:].any()   # the second did not
+        assert np.array_equal(a[:100], c_[:100])      # the real rows
+        if interpret:                                 # blocks of 8 skipped
+            assert not c_[104:].any() and a[104:].any()
+
     # row 1 (chunks): scored = sum of contexts, selected = sum of min(., 16),
-    # over 256 columns without valid_len and over the 100 real ones with it
+    # rows selected, of them by the kernel; over 256 columns without
+    # valid_len and over the 100 real ones with it
     def want(n):
         return [2 * sum(range(1, n + 1)),
-                2 * sum(min(t, TOPK) for t in range(1, n + 1))]
+                2 * sum(min(t, TOPK) for t in range(1, n + 1)),
+                2 * n, 2 * n if interpret else 0]
     assert count_w[1, 3:].tolist() == want(256)
     assert count_c[1, 3:].tolist() == want(100)

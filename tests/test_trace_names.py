@@ -101,6 +101,11 @@ def _dsa_sparse():
                                  i32(2, 6), i32(2))
 
 
+def _dsa_select():
+    from paddle_tpu.ops.pallas.dsa_select import dsa_select_topk
+    return jax.make_jaxpr(lambda s: dsa_select_topk(s, 6))(f32(8, 256))
+
+
 def _paged_int8():
     codes = jnp.zeros((5, 8, 2, 32), jnp.int8)
     return jax.make_jaxpr(lambda *a: pa.paged_attention_decode_int8(
@@ -129,6 +134,9 @@ KERNELS = {
         (_swa_mla, ["swa_mla_paged_attention_decode"]),
     "dsa_index_scores_decode": (_dsa_index, ["dsa_index_scores_decode"]),
     "dsa_sparse_mla_decode": (_dsa_sparse, ["dsa_sparse_mla_decode"]),
+    # PR 38: the indexer's exact top-k, which dsa_select_ms_per_step reads
+    # under its scope
+    "dsa_select_topk": (_dsa_select, ["dsa_select_topk"]),
     "fused_rms_norm": (lambda: jax.make_jaxpr(
         lambda x, w: fused.fused_rms_norm(x, w, 1e-5))(
             f32(4, 128), f32(128)), ["fused_rms_norm"]),
@@ -350,8 +358,9 @@ def test_sparse_latent_model_keeps_the_program_names_and_names_its_reads(
         interpret):
     """A model with learned sparse attention over a latent cache through
     the hybrid backend: the two programs keep the names the trace is read
-    by; a full layer's decode calls ``dsa_index_scores_decode`` and
-    ``dsa_sparse_mla_decode``, a sliding layer's
+    by; a full layer's decode calls ``dsa_index_scores_decode``,
+    ``dsa_select_topk`` and ``dsa_sparse_mla_decode`` (its chunk
+    ``dsa_select_topk`` too), a sliding layer's
     ``swa_mla_paged_attention_decode``; the indexer, the selection, the
     selected read and the gate sit under scopes of their own."""
     from paddle_tpu.models.dots3_note import (Dots3NoteForCausalLM,
@@ -373,6 +382,7 @@ def test_sparse_latent_model_keeps_the_program_names_and_names_its_reads(
     names = pallas_names(jax.make_jaxpr(be._block_jit)(
         be._pv, be._bv, cache, be.init_state()))
     assert names.count("dsa_index_scores_decode") == 2
+    assert names.count("dsa_select_topk") == 2
     assert names.count("dsa_sparse_mla_decode") == 2
     assert names.count("swa_mla_paged_attention_decode") == 3
     assert "mla_paged_attention_decode" not in names
@@ -403,7 +413,8 @@ def test_sparse_latent_engine_span_and_counter_names():
         prefill_chunk=8)
     assert list(eng.backend.cache_counters) == [
         "moe_picks", "moe_expert_hits", "moe_max_load", "dsa_tokens_scored",
-        "dsa_tokens_selected"]
+        "dsa_tokens_selected", "dsa_rows_selected",
+        "dsa_rows_kernel_selected"]
     srv = Server(eng, Scheduler())
     before = len(tracing.since(0))
     srv.submit(np.arange(20, dtype=np.int32), max_new_tokens=6)
@@ -425,6 +436,13 @@ def test_sparse_latent_engine_span_and_counter_names():
     assert stats["dsa_tokens_selected"] == eng.dsa_tokens_selected
     assert eng.prefill_dsa_tokens_scored == sum(
         c["dsa_tokens_scored"] for c in chunks) > 0
+    # a live row a decode step, a real column a chunk, in each full layer;
+    # the CPU lane selects with lax.top_k, so the kernel made none of them
+    assert sum(b["dsa_rows_selected"] for b in blocks) \
+        == eng.dsa_rows_selected == 2 * 5
+    assert eng.prefill_dsa_rows_selected == 2 * 20
+    assert stats["dsa_rows_selected"] == 2 * (5 + 20)
+    assert stats["dsa_rows_kernel_selected"] == 0
 
 
 def test_hybrid_engine_span_and_counter_names():

@@ -5,7 +5,7 @@ serves (64 slots at about 33.5k of context, KV block 16, a 2,304-entry
 table, 49,153 blocks of latent rows 640 wide and index keys 128 wide; a ring
 of 1,152-wide rows under a window of 513). Needs a TPU.
 
-    python3 tools/sparse_latent_probe.py [--index-only]
+    python3 tools/sparse_latent_probe.py [--index-only | --select-only]
 
 Per call, median of 10 runs: the indexer's walk ``dsa_index_scores_decode``
 against its gathered read, the exact selection ``lax.top_k`` (64 x 36,864 ->
@@ -16,6 +16,14 @@ against its gathered read, the exact selection ``lax.top_k`` (64 x 36,864 ->
 Then a short profile of a program with the scopes ``dsa_select`` /
 ``dsa_read``, reduced by ``benchmark/trace_scopes.py`` with the program's
 compiled text: what the metrics that read those scopes will find. The last line is one JSON object.
+
+``--select-only``: the selection alone, ``lax.top_k`` against the kernel
+``dsa_select_topk`` (``ops/pallas/dsa_select.py``), ms a call at the decode
+step's shape (64 x 36,864 -> 2,048) and at a chunk's (one 128-row block, and
+the 512 rows of a chunk), with the kernel's ids checked against
+``lax.top_k``'s (sorted) on those scores and on rows of ties, signed zeros
+and ``-inf``; then what ``trace_scopes`` finds under ``dsa_select`` in a
+program that calls the kernel there.
 """
 import json
 import os
@@ -29,6 +37,7 @@ import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
+from paddle_tpu.ops.pallas import dsa_select as ds           # noqa: E402
 from paddle_tpu.ops.pallas import paged_attention as pa       # noqa: E402
 
 HBM = 819e9
@@ -51,7 +60,78 @@ def rel_err(got, want):
     return float(np.abs(got - want).max() / np.abs(want).max())
 
 
+def scope_seconds(fn, args, module, scopes):
+    """``trace_scopes.seconds_by_scope`` over three calls of ``fn``
+    (``jax.jit``'d under the name ``module``) in a short profile."""
+    from jax.profiler import ProfileData
+    from benchmark import trace_reduce, trace_scopes
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    fn = jax.jit(fn)
+    jax.block_until_ready(fn(*args))
+    d = os.path.join("chiprun_out", "probe-trace")
+    shutil.rmtree(d, ignore_errors=True)
+    jax.profiler.start_trace(d)
+    for _ in range(3):
+        jax.block_until_ready(fn(*args))
+    jax.profiler.stop_trace()
+    loaded = trace_reduce.load(ProfileData.from_file(
+        trace_reduce.find_xplane(d)))
+    shutil.rmtree(d, ignore_errors=True)
+    return (trace_scopes.seconds_by_scope(loaded, module, text, scopes),
+            trace_reduce.reduce(loaded).get("modules"))
+
+
+def select_only():
+    """``lax.top_k`` against ``dsa_select_topk`` at the served shapes."""
+    out = {"device": jax.devices()[0].device_kind}
+    rs = np.random.RandomState(5)
+    n = MB * BS
+    lens = jnp.asarray(rs.randint(32900, 34400, SLOTS), jnp.int32)
+    seen = jnp.arange(n)[None] < lens[:, None]
+    dec = jnp.where(seen, jax.random.normal(jax.random.PRNGKey(1),
+                                            (SLOTS, n)), -jnp.inf)
+    posn = 33000 + jnp.arange(512)
+    chunk = jnp.where(jnp.arange(n)[None] <= posn[:, None],
+                      jax.random.normal(jax.random.PRNGKey(2), (512, n)),
+                      -jnp.inf)
+    # plateaus across the k-th place, signed zeros, short and empty rows
+    odd = np.round(rs.randn(64, n) * 2).astype(np.float32)
+    odd[0] = 0.0
+    odd[0, ::3] = -0.0
+    odd[1] = -np.inf
+    odd[2, 1000:] = -np.inf
+    odd[3, TOPK:] = -np.inf
+    odd[4, TOPK + 1:] = -np.inf
+    odd = jnp.asarray(odd)
+    xla = jax.jit(lambda s: jnp.sort(jax.lax.top_k(s, TOPK)[1], axis=-1))
+    kern = jax.jit(lambda s: ds.dsa_select_topk(s, TOPK))
+    for name, s in (("decode", dec), ("chunk_block", chunk[:128]),
+                    ("chunk", chunk), ("ties", odd)):
+        want, got = np.asarray(xla(s)), np.asarray(kern(s))
+        out[name] = {"rows": int(s.shape[0]),
+                     "top_k_ms": median_ms(xla, s),
+                     "kernel_ms": median_ms(kern, s),
+                     "rows_exact": int((want == got).all(-1).sum())}
+        print(json.dumps({name: out[name]}), flush=True)
+    # a chunk whose last 2.5 row blocks are padding: the kernel skips them
+    live = jnp.arange(512) < 200
+    part = jax.jit(lambda s, l: ds.dsa_select_topk(s, TOPK, l))
+    got = np.asarray(part(chunk, live))[:200]
+    out["chunk_200_live"] = {"kernel_ms": median_ms(part, chunk, live),
+                             "rows_exact": int((np.asarray(xla(chunk))[:200]
+                                                == got).all(-1).sum())}
+
+    def step(s):
+        with jax.named_scope("dsa_select"):
+            return ds.dsa_select_topk(s, TOPK)
+    out["scopes"], out["modules"] = scope_seconds(step, (dec,), "jit_step",
+                                                  ("dsa_select",))
+    print(json.dumps(out), flush=True)
+
+
 def main():
+    if "--select-only" in sys.argv:
+        return select_only()
     out = {"device": jax.devices()[0].device_kind}
     rs = np.random.RandomState(3)
     bf = jnp.bfloat16
@@ -159,31 +239,15 @@ def main():
                     "sparse_read_ms": median_ms(cread, qq, rows, t1, cid, nv,
                                                 runs=5)}
     # -- what trace_scopes finds ------------------------------------------
-    from jax.profiler import ProfileData
-    from benchmark import trace_reduce, trace_scopes
-
     def step(q, a, t, s, n):
         with jax.named_scope("dsa_select"):
             i = jax.lax.top_k(s, TOPK)[1]
         with jax.named_scope("dsa_read"):
             return pa.dsa_sparse_mla_decode(q, a, t, i, n, scale=0.07,
                                             rank=512)
-    args = (q, rows, table, scores, n_valid)
-    text = jax.jit(step).lower(*args).compile().as_text()
-    step = jax.jit(step)
-    jax.block_until_ready(step(*args))
-    d = os.path.join("chiprun_out", "probe-trace")
-    shutil.rmtree(d, ignore_errors=True)
-    jax.profiler.start_trace(d)
-    for _ in range(3):
-        jax.block_until_ready(step(*args))
-    jax.profiler.stop_trace()
-    loaded = trace_reduce.load(ProfileData.from_file(
-        trace_reduce.find_xplane(d)))
-    out["scopes"] = trace_scopes.seconds_by_scope(
-        loaded, "jit_step", text, ("dsa_select", "dsa_read"))
-    out["modules"] = trace_reduce.reduce(loaded).get("modules")
-    shutil.rmtree(d, ignore_errors=True)
+    out["scopes"], out["modules"] = scope_seconds(
+        step, (q, rows, table, scores, n_valid), "jit_step",
+        ("dsa_select", "dsa_read"))
     print(json.dumps(out), flush=True)
 
 
