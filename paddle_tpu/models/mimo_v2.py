@@ -27,7 +27,9 @@ experts where it is 1.
   (sigmoid scores, the choice by ``score + e_score_correction_bias``,
   weights the scores at the chosen, normalised, times
   ``routed_scaling_factor`` or 1), ``y = sum_e w_e SwiGLU_e(x)``, NO shared
-  expert.
+  expert. (``shared_expert_intermediate_size``, None here, adds one SwiGLU
+  beside them under the scope ``moe_shared``: ``models/laguna.py``'s
+  layers are these, read through its configuration.)
 
 Departures from the published model, none in the equations above: the
 value scale multiplies V before the product (the same number as scaling
@@ -115,6 +117,8 @@ class MiMoV2Config:
     norm_topk_prob: bool = True
     scoring_func: str = "sigmoid"
     routed_scaling_factor: Optional[float] = None
+    # one shared SwiGLU beside the routed experts (None: none)
+    shared_expert_intermediate_size: Optional[int] = None
     layernorm_epsilon: float = 1e-5
     max_position_embeddings: int = 262144
     tie_word_embeddings: bool = False
@@ -134,12 +138,10 @@ class MiMoV2Config:
             setattr(self, name, value)
         if self.tie_word_embeddings:
             raise ValueError("the MiMo-V2 head is untied")
-        if (self.swa_head_dim, self.swa_v_head_dim,
-                self.swa_num_attention_heads) != (
-                self.head_dim, self.v_head_dim, self.num_attention_heads):
-            raise ValueError("window layers with other head sizes or "
-                             "another number of query heads than full "
-                             "layers are not built")
+        if (self.swa_head_dim, self.swa_v_head_dim) != (
+                self.head_dim, self.v_head_dim):
+            raise ValueError("window layers with other head sizes than "
+                             "full layers are not built")
 
     @property
     def rotary_dim(self) -> int:
@@ -156,6 +158,18 @@ class MiMoV2Config:
         return self.swa_num_key_value_heads if kind == WINDOW \
             else self.num_key_value_heads
 
+    def attention_sizes(self, layer_idx: int) -> dict:
+        """``MiMoV2Attention``'s arguments for layer ``layer_idx``."""
+        swa = self.hybrid_layer_pattern[layer_idx] == WINDOW
+        return dict(
+            heads=self.swa_num_attention_heads if swa
+            else self.num_attention_heads,
+            theta=float(self.swa_rope_theta if swa else self.rope_theta),
+            rotary_dim=self.rotary_dim,
+            sink=self.add_swa_attention_sink_bias if swa
+            else self.add_full_attention_sink_bias,
+            gate=False, value_scale=self.attention_value_scale)
+
 
 def mimo_v2_tiny_config(**kw):
     """All three forms of layer in seven: dense + full, experts + window,
@@ -171,16 +185,61 @@ def mimo_v2_tiny_config(**kw):
     return MiMoV2Config(**base)
 
 
-def _partial_rope(x, positions, theta: float, rot: int):
+@dataclass(frozen=True)
+class YaRN:
+    """``rope_type: yarn`` (``transformers``' ``_compute_yarn_parameters``
+    with ``truncate``): over the ``rot`` dims a RoPE turns, frequency i is
+    ``base^(-2i/rot)`` (extrapolated) blended with that over ``factor``
+    (interpolated) by a ramp between the correction dims of ``beta_fast``
+    and ``beta_slow`` rotations in ``original_max_position_embeddings``
+    positions; cos and sin are multiplied by ``attention_factor``."""
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+    def correction_range(self, theta: float, rot: int) -> Tuple[int, int]:
+        """``(low, high)``: the dims below ``low`` keep their published
+        frequency, those from ``high`` on are interpolated."""
+        def dim(rotations):
+            return rot * math.log(self.original_max_position_embeddings
+                                  / (rotations * 2 * math.pi)) \
+                / (2 * math.log(theta))
+        low = max(math.floor(dim(self.beta_fast)), 0)
+        high = min(math.ceil(dim(self.beta_slow)), rot - 1)
+        return low, high
+
+    def inv_freq(self, theta: float, rot: int):
+        """The ``rot // 2`` frequencies, float32."""
+        extra = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
+                                 / rot))
+        low, high = self.correction_range(theta, rot)
+        ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 0.001), 0.0, 1.0)
+        return extra / self.factor * ramp + extra * (1.0 - ramp)
+
+
+def _partial_rope(x, positions, theta: float, rot: int,
+                  yarn: Optional[YaRN] = None):
     """x (b, s, heads, d) at ``positions (b, s)``: rotate-half on the first
-    ``rot`` dims (angles in float32 from the positions), the rest pass."""
-    inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32) / rot))
+    ``rot`` dims (angles in float32 from the positions), the rest pass;
+    under ``yarn`` its frequencies and its factor on cos and sin."""
+    if yarn is None:
+        inv = 1.0 / (theta ** (jnp.arange(0, rot, 2, dtype=jnp.float32)
+                               / rot))
+    else:
+        inv = yarn.inv_freq(theta, rot)
     ang = positions.astype(jnp.float32)[:, :, None] * inv[None, None]
     ang = jnp.concatenate([ang, ang], axis=-1)[:, :, None, :]
+
+    def turn(f):
+        t = f(ang) if yarn is None else f(ang) * yarn.attention_factor
+        return t.astype(x.dtype)
     xr = x[..., :rot]
     x1, x2 = jnp.split(xr, 2, axis=-1)
-    xr = xr * jnp.cos(ang).astype(x.dtype) \
-        + jnp.concatenate([-x2, x1], axis=-1) * jnp.sin(ang).astype(x.dtype)
+    xr = xr * turn(jnp.cos) \
+        + jnp.concatenate([-x2, x1], axis=-1) * turn(jnp.sin)
     return jnp.concatenate([xr, x[..., rot:]], axis=-1)
 
 
@@ -196,25 +255,39 @@ class MiMoV2MLP(nn.Layer):
 
 
 class MiMoV2Attention(nn.Layer):
-    def __init__(self, config: MiMoV2Config, kind: int):
+    """Grouped-query attention of one kind of layer over a packed arena.
+    What differs by kind are arguments (``config.attention_sizes``): the
+    query ``heads``, the RoPE ``theta``, the dims it turns (``rotary_dim``)
+    and its ``yarn`` rule, a per-head ``sink``, a per-head output ``gate``
+    (``o_h <- sigmoid(y W_g)_h o_h``, ``W_g (hidden, heads)`` on the
+    layer's normed input) and the ``value_scale`` on V."""
+
+    def __init__(self, config: MiMoV2Config, kind: int, *, heads: int,
+                 theta: float, rotary_dim: int, sink: bool, gate: bool,
+                 value_scale: float, yarn: Optional[YaRN] = None):
         super().__init__()
         c = self.config = config
         self.kind = kind
+        self.heads = heads
         self.kvh = c.kv_heads(kind)
         self.window = c.sliding_window if kind == WINDOW else None
-        self.theta = float(c.swa_rope_theta if kind == WINDOW
-                           else c.rope_theta)
-        h, heads = c.hidden_size, c.num_attention_heads
+        self.theta = theta
+        self.rotary_dim = rotary_dim
+        self.yarn = yarn
+        self.value_scale = value_scale
+        h = c.hidden_size
         self.q_proj = nn.Linear(h, heads * c.head_dim, bias_attr=False)
         self.k_proj = nn.Linear(h, self.kvh * c.head_dim, bias_attr=False)
         self.v_proj = nn.Linear(h, self.kvh * c.v_head_dim, bias_attr=False)
         self.o_proj = nn.Linear(heads * c.v_head_dim, h, bias_attr=False)
-        self.has_sink = c.add_swa_attention_sink_bias if kind == WINDOW \
-            else c.add_full_attention_sink_bias
+        self.has_sink = sink
         if self.has_sink:
             self.attention_sink_bias = self.create_parameter(
                 (heads,), dtype="float32",
                 default_initializer=I.Constant(0.0))
+        self.has_gate = gate
+        if gate:
+            self.head_gate = nn.Linear(h, heads, bias_attr=False)
 
     def forward(self, x, cache=None, pos=None, block_table=None):
         """``cache`` is this layer's packed arena, ``pos (b,)`` the
@@ -222,7 +295,7 @@ class MiMoV2Attention(nn.Layer):
         without a cache the whole sequence attends to itself."""
         c = self.config
         b, s, _ = x.shape
-        heads, kvh, window = c.num_attention_heads, self.kvh, self.window
+        heads, kvh, window = self.heads, self.kvh, self.window
         dk, dv = c.head_dim, c.v_head_dim
         scale = 1.0 / math.sqrt(dk)
 
@@ -231,11 +304,12 @@ class MiMoV2Attention(nn.Layer):
                 jnp.broadcast_to(jnp.asarray(posv, jnp.int32), (b,))
             positions = start[:, None] + jnp.arange(s)[None, :]
             qv = _partial_rope(qv.reshape(b, s, heads, dk), positions,
-                               self.theta, c.rotary_dim)
+                               self.theta, self.rotary_dim, self.yarn)
             kv_ = _partial_rope(kv_.reshape(b, s, kvh, dk), positions,
-                                self.theta, c.rotary_dim)
-            vv = vv.reshape(b, s, kvh, dv) * jnp.asarray(
-                c.attention_value_scale, vv.dtype)
+                                self.theta, self.rotary_dim, self.yarn)
+            vv = vv.reshape(b, s, kvh, dv)
+            if self.value_scale != 1.0:
+                vv = vv * jnp.asarray(self.value_scale, vv.dtype)
             return qv, kv_, vv
 
         def plain(qv, kv_, vv, sink=None):
@@ -268,9 +342,16 @@ class MiMoV2Attention(nn.Layer):
         q, k, v = self.q_proj(x), self.k_proj(x), self.v_proj(x)
         sink = (self.attention_sink_bias,) if self.has_sink else ()
         if cache is None:
-            return self.o_proj(apply_op(plain, q, k, v, *sink)), None
-        out, arena = apply_op(cached, q, k, v, cache, pos, block_table,
-                              *sink)
+            out, arena = apply_op(plain, q, k, v, *sink), None
+        else:
+            out, arena = apply_op(cached, q, k, v, cache, pos, block_table,
+                                  *sink)
+        if self.has_gate:
+            with jax.named_scope("attn_gate"):
+                g = F.sigmoid(self.head_gate(x))
+                out = apply_op(lambda o, gv: (o.reshape(
+                    b, s, heads, dv) * gv[..., None].astype(o.dtype)
+                ).reshape(b, s, heads * dv), out, g)
         return self.o_proj(out), arena
 
 
@@ -280,7 +361,8 @@ class MiMoV2DecoderLayer(nn.Layer):
         c = config
         self.kind = c.hybrid_layer_pattern[layer_idx]
         self.input_layernorm = nn.RMSNorm(c.hidden_size, c.layernorm_epsilon)
-        self.self_attn = MiMoV2Attention(c, self.kind)
+        self.self_attn = MiMoV2Attention(c, self.kind,
+                                         **c.attention_sizes(layer_idx))
         self.post_attention_layernorm = nn.RMSNorm(c.hidden_size,
                                                    c.layernorm_epsilon)
         self.is_moe = bool(c.moe_layer_freq[layer_idx])
@@ -292,6 +374,10 @@ class MiMoV2DecoderLayer(nn.Layer):
                 topk_group=c.topk_group, norm_topk_prob=c.norm_topk_prob,
                 scaling=1.0 if c.routed_scaling_factor is None
                 else c.routed_scaling_factor)
+            self.shared_experts = None
+            if c.shared_expert_intermediate_size:
+                self.shared_experts = MiMoV2MLP(
+                    c.hidden_size, c.shared_expert_intermediate_size)
         else:
             self.mlp = MiMoV2MLP(c.hidden_size, c.intermediate_size)
 
@@ -308,7 +394,10 @@ class MiMoV2DecoderLayer(nn.Layer):
             with jax.named_scope("mlp"):
                 return h + self.mlp(y), arena, None, None
         routed, picks, stats = self.mlp(y, forced_idx)
-        return h + routed, arena, picks, stats
+        if self.shared_experts is None:
+            return h + routed, arena, picks, stats
+        with jax.named_scope("moe_shared"):
+            return h + routed + self.shared_experts(y), arena, picks, stats
 
 
 class MiMoV2Model(nn.Layer):

@@ -1,9 +1,10 @@
 """The paged engine for a model whose cache keeps TWO GROUPS of layers:
 full-attention layers that hold every token, and sliding-window layers
-that ever read back only their last ``window`` tokens (``models/mimo_v2.py``,
-packed grouped-query arenas; ``models/dots3_note.py``, latent arenas, a full
-layer's leaf a PAIR of arenas, latent rows and index keys, under one block
-id; the model says so through ``kv_cache_groups``).
+that ever read back only their last ``window`` tokens (``models/mimo_v2.py``
+and ``models/laguna.py``, packed grouped-query arenas;
+``models/dots3_note.py``, latent arenas, a full layer's leaf a PAIR of
+arenas, latent rows and index keys, under one block id; the model says so
+through ``kv_cache_groups``).
 
 Each group has its own arena geometry (the model's), its own pool of
 blocks with its own :class:`~.paging.BlockManager`, and its own block

@@ -254,8 +254,11 @@ def test_configuration_file_keeps_every_published_key(cfg):
                 "mla_decode_kernel_roofline", "swa_decode_kernel_roofline",
                 "full_decode_kernel_roofline",
                 "swa_moe_decode_step_roofline"} & reports
-    # PR 37's six in a row, then PR 38's selection kernel share at the end
-    ours = bench["per_layer"][-7:]
+    # PR 37's six in a row, then PR 38's selection kernel share (later
+    # PRs append after them)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(NEW_METRICS[0])
+    ours = bench["per_layer"][first:first + 7]
     assert [m["name"] for m in ours] == NEW_METRICS + [
         "dsa_select_kernel_share"]
     assert all(m["workloads"] == [CELL] and m["moves"] == "out_tokens_per_s"

@@ -354,6 +354,38 @@ def test_hybrid_model_keeps_the_program_names_and_names_both_reads(
     assert "moe_shared/" not in text            # this family has none
 
 
+def test_gated_hybrid_model_keeps_the_program_names_and_names_both_reads(
+        interpret):
+    """Laguna's layers through the hybrid backend: the SAME two programs;
+    its 2 full layers read through ``paged_attention_decode`` and its 3
+    sliding ones through ``swa_paged_attention_decode``; the per-head gate
+    sits under ``attn_gate`` and the shared expert under ``moe_shared``,
+    beside the scopes MiMo's layers have."""
+    from paddle_tpu.models.laguna import LagunaForCausalLM, laguna_tiny_config
+    from paddle_tpu.serving import engine
+    from paddle_tpu.serving.hybrid import HybridPagedStepBackend
+    paddle.seed(0)
+    be = HybridPagedStepBackend(LagunaForCausalLM(laguna_tiny_config()), 2,
+                                64, 4, 8, 17, 11, 8)
+    assert be.leaf_group == (0, 1, 1, 1, 0, None)
+    block, chunk = _lower_block(be), be._chunk_jit.lower(
+        be._pv, be._bv, i32(1, 8), be.pool_cache(), i32(1, be.table_width),
+        jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
+        jnp.float32(0), jnp.int32(0), jnp.float32(1))
+    assert _module_name(block) == engine.DECODE_PROGRAM == "jit_block_fn"
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM == "jit_chunk_fn"
+    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
+    names = pallas_names(jax.make_jaxpr(be._block_jit)(
+        be._pv, be._bv, cache, be.init_state()))
+    assert names.count("paged_attention_decode") == 2
+    assert names.count("swa_paged_attention_decode") == 3
+    text = block.as_text(debug_info=True)
+    for scope in ("attn", "attn_swa", "attn_gate", "mlp", "moe_router",
+                  "moe_experts", "moe_shared", "lm_head", "sample"):
+        assert f"{scope}/" in text, scope
+    assert "attn_gate/" in chunk.as_text(debug_info=True)
+
+
 def test_sparse_latent_model_keeps_the_program_names_and_names_its_reads(
         interpret):
     """A model with learned sparse attention over a latent cache through
