@@ -6,7 +6,9 @@ they were, as PR 33 did (a looped model's two programs are pinned beside
 them), and PR 37 (``DeepseekV3Attention`` takes its sizes as arguments:
 kanana's two programs are byte for byte what they were; the new model's two
 are pinned beside them; PR 38 changed those two: the selection kernel; PR 39 pins MiMo's two, which its generalized attention
-class left as they were, and Laguna's). A change that means to alter one of them updates its pin here
+class left as they were, and Laguna's). The three models whose cells hold
+a share of their experts are fingerprinted holding a share; kanana's two
+programs run the expert kernel. A change that means to alter one of them updates its pin here
 and says so in CHANGES.md; one that does not has changed what
 ``mistral7b-decode-sat``, ``kanana2-docqa-decode``, ``ouro-reason-decode``,
 ``mimo-v2-agent-decode`` or ``dots3-longdoc-decode`` runs."""
@@ -23,23 +25,27 @@ PINNED = {
     "llama.chunk": "db442fc2d52832c9bed72a9837dd5b2c6cbddd0f",
     "llama_int8.block": "a64744e0f925390575129e611b215b3085a9aa6a",
     "llama_int8.chunk": "315454823dde9af0dd1aac50adea43a61726c18c",
-    "deepseek_v3.block": "a3c6cface75b212e43565830e8966dd03f9b533f",
-    "deepseek_v3.chunk": "737a61e307860907e16b107ba441f39b755a2760",
+    # a decode step (and the tiny chunk, 8 rows) on all the experts runs
+    # the kernel moe_hit_experts_decode in place of the grouped matmuls
+    "deepseek_v3.block": "d441d491dea9e4eebd41bd30d51880b7f881a3c6",
+    "deepseek_v3.chunk": "345ec2f7a03d92158340f21cbde341a27726c183",
     # PR 33: a looped model's two programs (the passes a scan in each)
     "ouro.block": "d3bf11f9184cf1aa62eee8e9e1495b7bfaee0f78",
     "ouro.chunk": "07b38559f2adab2faa4491cf7dcc852151f6073c",
     # PR 37: learned sparse attention over a latent cache through the hybrid
     # backend (the kernels' bodies are in the text); PR 38: the selection
     # is the kernel dsa_select_topk, and the counters two columns wider
-    "dots3_note.block": "e0960eb532504207dd072e704bbae98afe441f64",
-    "dots3_note.chunk": "d5a2a97e39bb9430dd45a68341b3109e2f56a8cb",
+    # this model and the next two hold a SHARE of their experts, as their
+    # cells do: the same six programs as before the expert kernel
+    "dots3_note.block": "c7892f7fd9fa0f0d62ac24c54e3ced43497920f3",
+    "dots3_note.chunk": "1f0f71c0880f7d343abbec810cfa90398a7fb606",
     # PR 39: MiMo's two programs as the parent commit made them, before
     # MiMoV2Attention took its sizes per kind as arguments (query heads,
     # RoPE rule, sink, gate, value scale); Laguna's two beside them
-    "mimo_v2.block": "031fb6cf7e214bcb19f48d4533b5201cd73d3bbb",
-    "mimo_v2.chunk": "205ce1bfc804d9d291bc6f83e4a4d36a361eafce",
-    "laguna.block": "f42c2cdc71442ae1d7b88832dd1430115c5bce2e",
-    "laguna.chunk": "4f2ae6a706cedc94432afeceef8f91de45175308",
+    "mimo_v2.block": "f898be5d03cf75371e24ec5145c4c72b28847bd1",
+    "mimo_v2.chunk": "bcd4088c52475b3c2faefc7d0be78b9f98d8248c",
+    "laguna.block": "7daa49a782abf7a803f00dc29f82e439401a5269",
+    "laguna.chunk": "8c8ec8c793d0ea5b798dfdd5ac9da1c340e49c47",
 }
 
 

@@ -106,6 +106,13 @@ def _dsa_select():
     return jax.make_jaxpr(lambda s: dsa_select_topk(s, 6))(f32(8, 256))
 
 
+def _moe_hit_experts():
+    from paddle_tpu.ops.pallas.moe_experts import moe_hit_experts_decode
+    return jax.make_jaxpr(moe_hit_experts_decode)(
+        f32(4, 16), i32(4, 2), f32(4, 2), f32(3, 16, 8), f32(3, 16, 8),
+        f32(3, 8, 16))
+
+
 def _paged_int8():
     codes = jnp.zeros((5, 8, 2, 32), jnp.int8)
     return jax.make_jaxpr(lambda *a: pa.paged_attention_decode_int8(
@@ -137,6 +144,9 @@ KERNELS = {
     # PR 38: the indexer's exact top-k, which dsa_select_ms_per_step reads
     # under its scope
     "dsa_select_topk": (_dsa_select, ["dsa_select_topk"]),
+    # the routed experts of a decode step on all the experts held, under
+    # the scope moe_experts
+    "moe_hit_experts_decode": (_moe_hit_experts, ["moe_hit_experts_decode"]),
     "fused_rms_norm": (lambda: jax.make_jaxpr(
         lambda x, w: fused.fused_rms_norm(x, w, 1e-5))(
             f32(4, 128), f32(128)), ["fused_rms_norm"]),

@@ -3,16 +3,19 @@
 model, each alone, at the shape the ``kanana2-docqa-decode`` cell serves.
 Needs a TPU.
 
-    python3 tools/latent_moe_probe.py [--chunk-rows N ...]
+    python3 tools/latent_moe_probe.py [--chunk-rows N ...] [--experts-only]
 
 ``latent``: the s = 1 read ``mla_paged_attention_decode`` (64 slots, 32
 heads against one shared 640-wide row, KV block 16, a 320-entry table,
 16,385 blocks, bf16) against the gathered read, per call, for three length
 mixes; each timing is REPS calls chained inside one program (a call's
 output feeds the next call's q), median of 10 runs. ``experts``:
-``dropless_expert_mix`` over all 128 experts (2048 x 768 SwiGLU, top-6) for
-a decode step's 64 tokens and a prefill chunk's 32, against the bytes of
-the experts the routing hits. The last line is one JSON object.
+a decode step's 64 tokens through all 128 experts (2048 x 768 SwiGLU,
+top-6) in three forms: the kernel ``moe_hit_experts_decode``, XLA's grouped
+matmuls and every row on every expert, ms a layer and the share of the hit
+experts' bytes, on the cell's router and on pools of 64, 100 and 128
+experts; the grouped form at a chunk's 512 rows. ``--experts-only`` skips
+the latent read. The last line is one JSON object.
 """
 import argparse
 import functools
@@ -27,12 +30,14 @@ import jax                                                    # noqa: E402
 import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
-from paddle_tpu.incubate.distributed.models.moe import (      # noqa: E402
-    dropless_expert_mix)
+from paddle_tpu.incubate.distributed.models import moe       # noqa: E402
+from paddle_tpu.ops.pallas import moe_experts as me           # noqa: E402
 from paddle_tpu.ops.pallas import paged_attention as pa       # noqa: E402
 
 REPS = 32
 HBM = 819e9
+# the cell's expert layer: 128 experts of 2048 x 768, top-6; a chunk's rows
+EXPERTS, HIDDEN, FF, TOP_K, CHUNK = 128, 2048, 768, 6, 512
 
 
 def median_ms(j, *args, runs=10, reps=1):
@@ -113,28 +118,80 @@ def latent(a, out):
 
 
 def experts(a, out):
-    e, h, ff, k = 128, 2048, 768, 6
+    """A decode step's expert layer at the cell's shape, three forms on the
+    same routing: the kernel ``moe_hit_experts_decode`` (what a step on all
+    the experts takes on a TPU), XLA's grouped matmuls (``ragged-dot``, the
+    form before it; a prefill chunk's) and every row on every expert held
+    (a share's form); and the grouped form at a chunk's 512 rows."""
+    e, h, ff, k, t = EXPERTS, HIDDEN, FF, TOP_K, a.slots
+    expert_bytes = 3 * h * ff * 2
     ws = [jax.random.normal(jax.random.PRNGKey(10 + i), s, jnp.bfloat16)
           * 0.03 for i, s in enumerate(((e, h, ff), (e, h, ff), (e, ff, h)))]
-    for name, t in (("decode_64", 64), ("chunk_32", 32), ("rows_512", 512)):
-        x = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.bfloat16)
-        idx = jnp.asarray(np.stack([
-            np.random.RandomState(5 + i).permutation(e)[:k]
-            for i in range(t)]), jnp.int32)
-        w = jnp.full((t, k), 0.4, jnp.float32)
-        hit = len(np.unique(np.asarray(idx)))
+    x = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.bfloat16)
+    # the cell's router: a gate drawn as the model's (Xavier normal, 2048 ->
+    # 128), a selection bias drawn N(0, 0.05), sigmoid scores, top-6,
+    # normalised, x 2.448
+    gate = jax.random.normal(jax.random.PRNGKey(4), (h, e)) \
+        * np.sqrt(2.0 / (h + e))
+    bias = 0.05 * jax.random.normal(jax.random.PRNGKey(5), (e,))
+    idx, w = moe.route_topk(x.astype(jnp.float32) @ gate, bias, top_k=k,
+                            scaling=2.448)
+    routings = {"router": (idx, w)}
+    # the same weights over pools of experts: the cell's routers hit 0.78 of
+    # 128 (100 a layer), random rows through a fresh router more
+    for pool in sorted({e // 2, e * 25 // 32, e}):
+        rs = np.random.RandomState(5 + pool)
+        held = rs.permutation(e)[:pool]
+        routings[f"pool_{pool}"] = (jnp.asarray(np.stack(
+            [rs.permutation(held)[:k] for _ in range(t)]), jnp.int32), w)
 
+    def chained(fn):
         def prog(x0, idx, w, *ws):
             def body(_, xx):
-                y, _ = dropless_expert_mix(xx, idx, w, *ws)
+                y, _ = fn(xx, idx, w, *ws)
                 return (xx + 1e-3 * y).astype(xx.dtype)
             return jax.lax.fori_loop(0, 8, body, x0)
-        ms = median_ms(jax.jit(prog), x, idx, w, *ws, reps=8)
-        row = {"tokens": t, "experts_hit": hit, "ms": ms,
-               "hit_bytes_roofline_pct":
-               100 * hit * 3 * h * ff * 2 / HBM / (ms / 1e3)}
+        return jax.jit(prog)
+
+    forms = {
+        "kernel": me.moe_hit_experts_decode,
+        "grouped": lambda *v: moe._grouped_expert_mix(*v, 0, False),
+        "every_expert": lambda *v: moe._every_row_on_every_expert_held(*v,
+                                                                       0)}
+    for name, (idx, w) in routings.items():
+        hit = len(np.unique(np.asarray(idx)))
+        row = {"tokens": t, "experts_hit": hit}
+        want = np.asarray(forms["grouped"](x, idx, w, *ws)[0], np.float32)
+        got = np.asarray(forms["kernel"](x, idx, w, *ws)[0], np.float32)
+        row["kernel_vs_grouped_max_abs"] = float(np.abs(got - want).max())
+        row["grouped_max_abs"] = float(np.abs(want).max())
+        for form, fn in forms.items():
+            ms = median_ms(chained(fn), x, idx, w, *ws, reps=8)
+            row[f"{form}_ms"] = ms
+            row[f"{form}_hit_bytes_roofline_pct"] = \
+                100 * hit * expert_bytes / HBM / (ms / 1e3)
+        # the ff axis in tiles of half an expert (the budget at 384 lanes);
+        # the tile is the kernel call's static argument, so ``chained``'s
+        # fresh program traces and compiles the tiled kernel anew
+        keep, me._WEIGHT_VMEM = me._WEIGHT_VMEM, 6 * h * (ff // 2) * 2
+        row["half_expert_tile"] = me._ff_tile(h, ff, 2)
+        row["kernel_ms.half_expert_tiles"] = median_ms(
+            chained(me.moe_hit_experts_decode), x, idx, w, *ws, reps=8)
+        me._WEIGHT_VMEM = keep
         out["experts." + name] = row
         print("experts", name, json.dumps(row), flush=True)
+    t = CHUNK
+    x = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.bfloat16)
+    idx = jnp.asarray(np.stack([np.random.RandomState(5 + i).permutation(e)[:k]
+                                for i in range(t)]), jnp.int32)
+    w = jnp.full((t, k), 0.4, jnp.float32)
+    hit = len(np.unique(np.asarray(idx)))
+    ms = median_ms(chained(forms["grouped"]), x, idx, w, *ws, reps=8)
+    row = {"tokens": t, "experts_hit": hit, "grouped_ms": ms,
+           "grouped_hit_bytes_roofline_pct":
+           100 * hit * expert_bytes / HBM / (ms / 1e3)}
+    out[f"experts.rows_{t}"] = row
+    print(f"experts rows_{t}", json.dumps(row), flush=True)
 
 
 def main():
@@ -147,6 +204,7 @@ def main():
     ap.add_argument("--table", type=int, default=320)
     ap.add_argument("--blocks", type=int, default=16385)
     ap.add_argument("--chunk-rows", type=int, nargs="*", default=[])
+    ap.add_argument("--experts-only", action="store_true")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -154,7 +212,8 @@ def main():
                  f"{dev.platform}); nothing measured")
     out = {"device": dev.device_kind, "reps": REPS,
            "chunk_rows": pa._CHUNK_ROWS}
-    latent(a, out)
+    if not a.experts_only:
+        latent(a, out)
     experts(a, out)
     print(json.dumps(out))
 

@@ -62,9 +62,11 @@ def fingerprints() -> dict:
     llama = LlamaForCausalLM(llama_tiny_config(tensor_parallel=False))
     latent = DeepseekV3ForCausalLM(deepseek_v3_tiny_config())
     looped = OuroForCausalLM(ouro_tiny_config())
-    sparse = Dots3NoteForCausalLM(dots3_note_tiny_config())
-    hybrid = MiMoV2ForCausalLM(mimo_v2_tiny_config())
-    gated = LagunaForCausalLM(laguna_tiny_config())
+    # the expert layers as each cell holds them: kanana's all its experts,
+    # the other three a share (the routed experts' form follows from that)
+    sparse = Dots3NoteForCausalLM(dots3_note_tiny_config(experts_held=(0, 4)))
+    hybrid = MiMoV2ForCausalLM(mimo_v2_tiny_config(experts_held=(0, 4)))
+    gated = LagunaForCausalLM(laguna_tiny_config(experts_held=(0, 16)))
     out = {}
     for name, programs in (("llama", texts(llama)),
                            ("llama_int8", texts(llama, kv_int8=True)),
