@@ -26,7 +26,15 @@ other kv heads' rows masked (:func:`_walk_kernel`). P comes from shapes
 the call can see (:func:`_pages_per_chunk`); :func:`walk_counts` mirrors
 the loop bound on the host for the engine's ``kv_pages_live`` /
 ``kv_pages_copied`` counters. No (S, max_len) dense view ever
-materializes. Off-TPU (and in the CPU quick lane) the SAME
+materializes. A copy costs about as much to issue as a small page takes
+to move, so where the table names consecutive blocks the walk moves them
+together: an aligned group of 8 columns that names consecutive blocks,
+rising or falling, wholly below the length, is ONE copy
+(:func:`_run_directions`, the one rule, which the indexer's walk
+:func:`_index_kernel` follows too); any other group's pages are started as
+8 copies written out and waited for as one group. A walk under a window
+starts at a page that is not group-aligned and may cycle over a ring: it
+copies and waits page by page. Off-TPU (and in the CPU quick lane) the SAME
 call falls back to :func:`paged_attention_reference` — a gather of the
 table into the dense layout followed by exactly the einsum/mask/softmax
 sequence of ``models.generation.cached_attention``, which is what keeps
@@ -78,7 +86,8 @@ __all__ = ["paged_attention_decode", "paged_attention_decode_int8",
            "dsa_sparse_mla_reference",
            "paged_attention_reference", "paged_attention_int8_reference",
            "paged_gather", "quantize_kv", "dequantize_kv",
-           "kv_int8_error_bound", "walk_counts"]
+           "kv_int8_error_bound", "walk_counts", "run_groups",
+           "walk_run_pages"]
 
 _NEG = -1e30
 
@@ -266,9 +275,174 @@ def walk_counts(lengths, mb, bs, ppc=1, window=None):
             int((-(-copied // ppc)).sum()))
 
 
-def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
-                 v_streams, scale, bs, kvh, mb, ppc, window=None,
-                 sinks=False):
+# Pages one copy may take when the table names them in a row. A copy costs
+# about 40 ns to issue whatever its size, so a walk of small pages one by one
+# is bound by issuing copies: the indexer's 4 KiB key pages at a ninth of the
+# HBM roofline, the latent arena's 20 KiB pages at about two fifths (TPU
+# v5e). A prefix prefilled into a fresh pool lies in consecutive blocks,
+# rising or (the block manager pops its free list from the end) FALLING, and
+# an aligned group of _RUN of them moves as one copy; anything else moves
+# page by page. Both walks copy runs: the softmax walk (:func:`_walk_kernel`,
+# no window) and the indexer's (:func:`_index_kernel`); a windowed walk
+# starts at a page that is not group-aligned and may cycle over a ring, and
+# copies page by page. :func:`_run_directions` is the ONE place that says
+# which groups are runs: the kernels read its verdict a group (a
+# scalar-prefetch operand); a falling group's pages land in reverse, which
+# the indexer's caller turns back and the softmax walk, which masks rows by
+# their place in the buffer, makes harmless by taking a group as a run only
+# where no row of it is masked. The softmax walk also starts a group that is
+# no run as 8 copies written out and waits for every whole group at once
+# (:func:`_wait_groups`). :func:`run_groups` and :func:`walk_run_pages`
+# mirror the rule on the host for the engine's counter.
+_RUN = 8
+
+
+def _run_directions(block_table, lengths, bs, run, whole=False):
+    """``(b, groups)`` int32: +1 where an aligned group of ``run`` table
+    columns, wholly live, names rising consecutive blocks, -1 where falling
+    ones (the kernel copies either as one piece, lowest block first), 0
+    where its pages move one by one. With ``whole`` a group is live only
+    where every row of its pages lies below the length."""
+    b, mb = block_table.shape
+    groups = -(-mb // run)
+    tbl = jnp.pad(block_table, ((0, 0), (0, groups * run - mb)),
+                  constant_values=-1).reshape(b, groups, run)
+    step = jnp.arange(run)
+    rising = jnp.all(tbl == tbl[..., :1] + step, axis=-1)
+    falling = jnp.all(tbl == tbl[..., :1] - step, axis=-1)
+    if whole:
+        live = (jnp.arange(groups) + 1) * (run * bs) \
+            <= jnp.minimum(lengths, mb * bs)[:, None]
+    else:
+        n_pages = jnp.maximum(-(-jnp.minimum(lengths, mb * bs) // bs), 1)
+        live = (jnp.arange(groups) + 1) * run <= n_pages[:, None]
+    return jnp.where(live & (run > 1), rising.astype(jnp.int32)
+                     - falling.astype(jnp.int32), 0)
+
+
+def run_groups(table, run=_RUN):
+    """The table's half of :func:`_run_directions`, on the host: ``(rows,
+    groups + 1)`` int64 whose column g counts the row's first g aligned
+    groups of ``run`` columns that name consecutive blocks, rising or
+    falling. A row is fixed from its slot's arming, so this is computed once
+    a row."""
+    t = np.asarray(table, np.int64)
+    rows, mb = t.shape
+    t = t[:, :mb // run * run].reshape(rows, -1, run)
+    step = np.arange(run)
+    runs = (np.all(t == t[..., :1] + step, axis=-1)
+            | np.all(t == t[..., :1] - step, axis=-1)) & (run > 1)
+    return np.concatenate([np.zeros((rows, 1), np.int64),
+                           np.cumsum(runs, axis=1)], axis=1)
+
+
+def walk_run_pages(groups, lengths, bs, run=_RUN):
+    """Of the pages the softmax walk reads at ``lengths`` (``(..., rows)``,
+    0 for a row that reads nothing worth counting), those it moves in run
+    copies, given each row's :func:`run_groups`: ``run`` pages for every
+    run whose rows all lie below the length (``_run_directions(whole=
+    True)``). What the walk does wherever its chunk holds whole groups
+    (:func:`_walk_chunk`)."""
+    ln = np.asarray(lengths, np.int64).reshape(-1, groups.shape[0])
+    whole = np.clip(ln // (run * bs), 0, groups.shape[1] - 1)
+    return int(run * np.take_along_axis(groups, whole.T, axis=1).sum())
+
+
+def _walk_chunk(mb, pages, window=None):
+    """``(ppc, run)`` of a walk: the pages a loop trip copies
+    (:func:`_pages_per_chunk`) and the pages a run copy takes. Without a
+    ``window`` a chunk of at least ``_RUN`` pages is cut to whole groups of
+    ``_RUN``, so that no group straddles two chunks; a windowed walk, and a
+    chunk too small for a group, copy page by page (``run`` 1)."""
+    ppc = _pages_per_chunk(mb, pages)
+    run = _RUN if window is None and ppc >= _RUN else 1
+    return ppc - ppc % run, run
+
+
+def _copy_pages(tbl_ref, dir_ref, slot, column, n_live, run, copies, fn,
+                unroll=False):
+    """``fn`` on every copy that brings ``n_live`` live pages of ``slot``
+    into a chunk buffer, buffer page p from table column ``column(p)``: the
+    same copies, in the same order, whether they are started or waited on.
+    ``copies(block, page, n)`` are the copies (one a stream) of ``n`` pages
+    from arena block ``block`` on into buffer page ``page`` on. Where ``run``
+    > 1 the pages go in aligned groups of ``run``: a group that
+    :func:`_run_directions` (``dir_ref``) marks moves as ONE copy, lowest
+    block first (a falling group's pages land in reverse); any other page
+    moves alone. With ``unroll`` a whole group that is no run moves as
+    ``run`` copies written out, not as a loop of them (a fifth off a
+    page-by-page walk of 20 KiB pages on a TPU v5e)."""
+    from jax.experimental import pallas as pl
+
+    if run == 1:
+        def page_copy(p, carry):
+            for cp in copies(tbl_ref[slot, column(p)], p, 1):
+                fn(cp)
+            return carry
+
+        jax.lax.fori_loop(0, n_live, page_copy, 0)
+        return
+
+    def group(g, carry):
+        p0 = g * run
+        col = column(p0)
+
+        def page_by_page():
+            def page_copy(p, carry):
+                for cp in copies(tbl_ref[slot, col + p], p0 + p, 1):
+                    fn(cp)
+                return carry
+            jax.lax.fori_loop(0, jnp.minimum(n_live - p0, run), page_copy, 0)
+
+        way = dir_ref[slot, col // run]
+
+        @pl.when(way != 0)
+        def _whole():
+            lowest = tbl_ref[slot, col] - jnp.where(way < 0, run - 1, 0)
+            for cp in copies(lowest, p0, run):
+                fn(cp)
+
+        if not unroll:
+            pl.when(way == 0)(page_by_page)
+            return carry
+        whole = p0 + run <= n_live
+
+        @pl.when((way == 0) & whole)
+        def _written_out():
+            for p in range(run):
+                for cp in copies(tbl_ref[slot, col + p], p0 + p, 1):
+                    fn(cp)
+
+        pl.when((way == 0) & ~whole)(page_by_page)
+        return carry
+
+    jax.lax.fori_loop(0, pl.cdiv(n_live, run), group, 0)
+
+
+def _wait_groups(n_live, run, copies):
+    """Wait for the copies of ``n_live`` live pages, however they were
+    issued: a DMA semaphore counts bytes, so ONE wait of ``run`` pages
+    covers a whole group, whether one copy moved it or ``run`` did; the
+    pages past the last whole group take one wait each. (A wait a copy cost
+    a page-by-page walk of 20 KiB pages a sixth more on a TPU v5e.)"""
+    whole = n_live // run
+
+    def group(g, carry):
+        for cp in copies(0, g * run, run):
+            cp.wait()
+        return carry
+
+    def page(p, carry):
+        for cp in copies(0, p, 1):
+            cp.wait()
+        return carry
+
+    jax.lax.fori_loop(0, whole, group, 0)
+    jax.lax.fori_loop(whole * run, n_live, page, 0)
+
+
+def _walk_kernel(tbl_ref, len_ref, *refs, n_streams, to_chunk, v_streams,
+                 scale, bs, kvh, mb, ppc, run=1, window=None, sinks=False):
     """ONE walk shared by the bf16/fp32 and the int8 kernels: a grid
     step is a slot; its body loops over the slot's live pages, ``ppc``
     to a chunk, copying each live page from the HBM arenas into one of
@@ -291,6 +465,12 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
     softmax as one more column with no value: the online softmax opens
     at ``m = sink, l = 1, acc = 0``.
 
+    With ``run`` > 1 (no window; ``ppc`` a multiple of it) the refs open
+    with :func:`_run_directions`' verdict a group (``whole``), and a group
+    of ``run`` live pages that it marks moves as ONE copy of every stream
+    (:func:`_copy_pages`). A falling group's pages land in reverse; none of
+    its rows is masked, so the softmax does not see the order.
+
     A chunk is used whole, as ``(rows, d)`` with row ``t * kvh + k``
     holding token t of kv head k — the arena page as it lies in memory,
     no transpose. Scores of all h query heads against all rows are one
@@ -301,6 +481,8 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
     Running max, normalizer and accumulator are fp32 loop carries."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+    dir_ref, refs = (refs[0], refs[1:]) if run > 1 else (None, refs)
+    q_ref, refs = refs[0], refs[1:]
     if sinks:
         sink_ref, refs = refs[0], refs[1:]
     hbm, o_ref = refs[:n_streams], refs[n_streams]
@@ -325,23 +507,32 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
             return jnp.maximum(pl.cdiv(ln, bs), 1)
         return jnp.maximum(pl.cdiv(ln, bs) - first_page(slot), 1)
 
-    def each_live_page(slot, n_pages, c, buf, fn):
-        """``fn`` on the copy of every live page of chunk c of slot (a
-        run-time count: the slot's last chunk has fewer than ``ppc``);
-        returns that count."""
+    def each_live_page(slot, n_pages, c, buf, starting):
+        """Start (``starting``) or wait for the copies of every live page
+        of chunk c of slot (a run-time count: the slot's last chunk has
+        fewer than ``ppc``); returns that count. Where the walk takes runs
+        the copies are started by :func:`_copy_pages` and waited for a
+        group at a time (:func:`_wait_groups`)."""
         n_live = jnp.minimum(n_pages - c * ppc, ppc)
 
         first = first_page(slot)
 
-        def page_copies(p, carry):
-            page = tbl_ref[slot, c * ppc + p] if first is None \
-                else tbl_ref[slot, first + c * ppc + p]
-            for s in range(n_streams):
-                fn(pltpu.make_async_copy(
-                    hbm[s].at[page], bufs[s].at[buf, p], sems.at[s, buf]))
-            return carry
+        def column(p):
+            return c * ppc + p if first is None else first + c * ppc + p
 
-        jax.lax.fori_loop(0, n_live, page_copies, 0)
+        def copies(block, page, n):
+            src, dst = (block, page) if n == 1 else \
+                (pl.ds(block, n), pl.ds(page, n))
+            return [pltpu.make_async_copy(hbm[s].at[src], bufs[s].at[buf, dst],
+                                          sems.at[s, buf])
+                    for s in range(n_streams)]
+
+        if run > 1 and not starting:
+            _wait_groups(n_live, run, copies)
+        else:
+            _copy_pages(tbl_ref, dir_ref, slot, column, n_live, run, copies,
+                        (lambda cp: cp.start()) if starting
+                        else (lambda cp: cp.wait()), unroll=True)
         return n_live
 
     def start(slot, n_pages, c, buf):
@@ -350,8 +541,7 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
         zeroed instead: it meets a probability of exactly 0, but what an
         older chunk left there may be another slot's NaN. (K's place
         needs nothing: its scores are masked, not multiplied.)"""
-        n_live = each_live_page(slot, n_pages, c, buf,
-                                lambda cp: cp.start())
+        n_live = each_live_page(slot, n_pages, c, buf, True)
 
         def clear(p, carry):
             for vs in v_streams:
@@ -392,7 +582,7 @@ def _walk_kernel(tbl_ref, len_ref, q_ref, *refs, n_streams, to_chunk,
             nxt = jnp.minimum(i + 1, n_slots - 1)
             start(nxt, pages_of(nxt), 0, 1 - buf)
 
-        each_live_page(i, n_pages, c, buf, lambda cp: cp.wait())
+        each_live_page(i, n_pages, c, buf, False)
         k, v, k_scale, v_scale = to_chunk(bufs, buf, q.dtype)
         v = v[:, :dv]
         s = jax.lax.dot_general(
@@ -518,31 +708,35 @@ def _walk_call(name, to_chunk, q, arenas, block_table, lengths, scale):
 
 def _walk_pages(name, to_chunk, q, arenas, block_table, lengths, scale, *,
                 kvh, v_streams, dv, window=None, sinks=None):
-    """The walk over arenas already in their page view; P from their
-    shapes (under a ``window``, from the pages a window can span)."""
+    """The walk over arenas already in their page view; P and the run
+    copies from their shapes (:func:`_walk_chunk`; under a ``window``, from
+    the pages a window can span)."""
     mb = block_table.shape[1]
     if window is not None:
         bs = arenas[0].shape[1] // kvh
         mb = min(mb, -(-(window - 1) // bs) + 1)
-    ppc = _pages_per_chunk(mb, [(a.shape[1:], a.dtype) for a in arenas])
+    ppc, run = _walk_chunk(mb, [(a.shape[1:], a.dtype) for a in arenas],
+                           window)
     return _walk_pallas_call(q, arenas, block_table, lengths, sinks,
                              name=name, to_chunk=to_chunk,
                              v_streams=v_streams, dv=dv, scale=scale,
-                             kvh=kvh, ppc=ppc, window=window,
+                             kvh=kvh, ppc=ppc, run=run, window=window,
                              interpret=_fused._FORCE_INTERPRET)
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "name", "to_chunk", "v_streams", "dv", "scale", "kvh", "ppc", "window",
-    "interpret"))
+    "name", "to_chunk", "v_streams", "dv", "scale", "kvh", "ppc", "run",
+    "window", "interpret"))
 def _walk_pallas_call(q, arenas, block_table, lengths, sinks=None, *, name,
                       to_chunk, v_streams, dv, scale, kvh, ppc, interpret,
-                      window=None):
+                      run=1, window=None):
     """The ``pallas_call``: the arenas stay in HBM, the table and the
-    lengths are scalar-prefetched, q and the output are pipelined per
-    slot. The grid is sequential: the chunk buffers, their parity and
-    the first chunk of the next slot are handed from one slot to the
-    next.
+    lengths are scalar-prefetched (and, where ``run`` > 1, the run
+    directions: :func:`_run_directions`, computed here once a call, which
+    XLA shares between the layers that read one table), q and the output
+    are pipelined per slot. The grid is sequential: the chunk buffers,
+    their parity and the first chunk of the next slot are handed from one
+    slot to the next.
 
     Jitted on its own so that a model's layers, which all make this call
     at one shape, share ONE trace and ONE lowering of the kernel inside
@@ -557,14 +751,15 @@ def _walk_pallas_call(q, arenas, block_table, lengths, sinks=None, *, name,
     # the per-head sink, where there is one: whole in VMEM for every slot
     sink_in = [] if sinks is None else [
         sinks.astype(jnp.float32).reshape(h, 1)]
+    ways = [] if run == 1 else [
+        _run_directions(block_table, lengths, bs, run, whole=True)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=2 + len(ways),
         grid=(b,),
-        in_specs=[pl.BlockSpec((1, h, d), lambda i, tbl, lens: (i, 0, 0))]
-        + [pl.BlockSpec((h, 1), lambda i, tbl, lens: (0, 0))
-           for _ in sink_in]
+        in_specs=[pl.BlockSpec((1, h, d), lambda i, *_: (i, 0, 0))]
+        + [pl.BlockSpec((h, 1), lambda i, *_: (0, 0)) for _ in sink_in]
         + [pl.BlockSpec(memory_space=pltpu.HBM)] * n,
-        out_specs=pl.BlockSpec((1, h, dv), lambda i, tbl, lens: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, h, dv), lambda i, *_: (i, 0, 0)),
         scratch_shapes=[pltpu.VMEM((2, ppc) + a.shape[1:], a.dtype)
                         for a in arenas]
         + [pltpu.SemaphoreType.DMA((n, 2)), pltpu.SMEM((1,), jnp.int32)],
@@ -572,14 +767,14 @@ def _walk_pallas_call(q, arenas, block_table, lengths, sinks=None, *, name,
     return pl.pallas_call(
         functools.partial(_walk_kernel, n_streams=n, to_chunk=to_chunk,
                           v_streams=v_streams, scale=scale, bs=bs, kvh=kvh,
-                          mb=mb, ppc=ppc, window=window,
+                          mb=mb, ppc=ppc, run=run, window=window,
                           sinks=sinks is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, dv), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret, name=name,
-    )(block_table, lengths, q, *sink_in, *arenas)
+    )(block_table, lengths, *ways, q, *sink_in, *arenas)
 
 
 def paged_attention_decode(q, k_arena, v_arena, block_table, lengths,
@@ -833,37 +1028,6 @@ def dsa_index_scores_reference(q, w, key_arena, block_table, *,
     return _index_scores(q, w, keys, key_block)
 
 
-# Pages one copy may take when the table names them in a row: a key page is
-# 4 KiB (16 tokens x 128 x 2 B) and a copy costs about 40 ns to issue
-# whatever its size, so page-by-page the indexer's walk is bound by issuing
-# copies at a ninth of the HBM roofline (my chip run, PR 37). A prefix
-# prefilled into a fresh pool lies in consecutive blocks, rising or (the
-# block manager pops its free list from the end) FALLING, and an aligned
-# run of _RUN of them moves as one copy; anything else moves page by page.
-# :func:`_run_directions` is the ONE place that says which groups those are:
-# the kernel reads its verdict a group (a scalar-prefetch operand) and the
-# caller turns a falling group's scores, which arrive in reverse, back.
-_RUN = 8
-
-
-def _run_directions(block_table, lengths, bs, run):
-    """``(b, groups)`` int32: +1 where an aligned group of ``run`` table
-    columns, wholly live, names rising consecutive blocks, -1 where falling
-    ones (the kernel copies either as one piece, lowest block first), 0
-    where its pages move one by one."""
-    b, mb = block_table.shape
-    groups = -(-mb // run)
-    tbl = jnp.pad(block_table, ((0, 0), (0, groups * run - mb)),
-                  constant_values=-1).reshape(b, groups, run)
-    step = jnp.arange(run)
-    rising = jnp.all(tbl == tbl[..., :1] + step, axis=-1)
-    falling = jnp.all(tbl == tbl[..., :1] - step, axis=-1)
-    n_pages = jnp.maximum(-(-jnp.minimum(lengths, mb * bs) // bs), 1)
-    live = (jnp.arange(groups) + 1) * run <= n_pages[:, None]
-    return jnp.where(live & (run > 1), rising.astype(jnp.int32)
-                     - falling.astype(jnp.int32), 0)
-
-
 def _index_kernel(tbl_ref, len_ref, dir_ref, q_ref, w_ref, hbm, o_ref, buf,
                   sems, parity, *, bs, mb, ppc, run):
     """The page walk of :func:`_walk_kernel` over ONE key arena that
@@ -887,39 +1051,17 @@ def _index_kernel(tbl_ref, len_ref, dir_ref, q_ref, w_ref, hbm, o_ref, buf,
                            1)
 
     def each_live_page(slot, n_pages, c, b_, fn):
-        """``fn`` on every copy of chunk c's live pages: the same copies,
-        in the same order, whether they are started or waited for."""
+        """``fn`` on every copy of chunk c's live pages (:func:`_copy_pages`)."""
         n_live = jnp.minimum(n_pages - c * ppc, ppc)
 
-        def group(g, carry):
-            p0 = g * run
-            col = c * ppc + p0
+        def copies(block, page, n):
+            src, dst = (block, page) if n == 1 else \
+                (pl.ds(block, n), pl.ds(page, n))
+            return [pltpu.make_async_copy(hbm.at[src], buf.at[b_, dst],
+                                          sems.at[b_])]
 
-            def page_by_page():
-                def page_copy(p, carry):
-                    fn(pltpu.make_async_copy(
-                        hbm.at[tbl_ref[slot, col + p]], buf.at[b_, p0 + p],
-                        sems.at[b_]))
-                    return carry
-                jax.lax.fori_loop(0, jnp.minimum(n_live - p0, run),
-                                  page_copy, 0)
-
-            if run == 1:
-                page_by_page()
-                return carry
-            way = dir_ref[slot, col // run]
-
-            @pl.when(way != 0)
-            def _whole():
-                lowest = tbl_ref[slot, col] - jnp.where(way < 0, run - 1, 0)
-                fn(pltpu.make_async_copy(
-                    hbm.at[pl.ds(lowest, run)], buf.at[b_, pl.ds(p0, run)],
-                    sems.at[b_]))
-
-            pl.when(way == 0)(page_by_page)
-            return carry
-
-        jax.lax.fori_loop(0, pl.cdiv(n_live, run), group, 0)
+        _copy_pages(tbl_ref, dir_ref, slot, lambda p: c * ppc + p, n_live,
+                    run, copies, fn)
 
     def start(slot, n_pages, c, b_):
         each_live_page(slot, n_pages, c, b_, lambda cp: cp.start())

@@ -903,6 +903,11 @@ class PagedEngine(ContinuousBatchingEngine):
         # mirror of the in-graph per-slot ``pos`` it is counted from
         self.kv_pages_live = 0         # pages holding a slot's live KV
         self.kv_pages_copied = 0       # pages the kernel copied for them
+        self.kv_pages_in_runs = 0      # of the live, pages moved in runs
+        # each slot's table row as the walk's run copies see it
+        # (``paged_attention.run_groups``), from the row it was armed with
+        self._run_groups = self._table_run_groups(
+            np.zeros((self.num_slots, self.max_blocks), np.int32))
         self.ut_steps = 0              # looped model: passes run by decode
         self._pos_host = np.zeros((self.num_slots,), np.int64)
         # what the model's programs count into the cache's last leaf
@@ -1206,6 +1211,14 @@ class PagedEngine(ContinuousBatchingEngine):
             jnp.int32(-1 if eos is None else eos), temp, topk, topp, key)
         self._remaining_host[slot] = rem0
         self._pos_host[slot] = pos0
+        self._run_groups[slot] = self._table_run_groups(
+            np.asarray(table_row)[None])[0]
+
+    def _table_run_groups(self, rows):
+        """:func:`paged_attention.run_groups` of the full-length table's
+        columns of ``rows``."""
+        from ..ops.pallas.paged_attention import run_groups
+        return run_groups(rows[:, :self.max_blocks])
 
     # -- the decode kernel's walk, counted on the host ----------------------
     def _decode_block_counters(self):
@@ -1217,15 +1230,23 @@ class PagedEngine(ContinuousBatchingEngine):
         row. ``ops.pallas.paged_attention.walk_counts`` mirrors the
         kernel's loop bound. A slot that meets its EOS inside the block
         is counted as if it ran to the block's end (at most one page a
-        block over)."""
-        from ..ops.pallas.paged_attention import walk_counts
-        live, copied, _ = walk_counts(self._block_read_lengths()[0],
-                                      self.max_blocks, self.kv_block_size)
+        block over). ``kv_pages_in_runs``: of the live pages, those the
+        walk moves 8 to a copy (``paged_attention.walk_run_pages``), from
+        each decoding slot's row as it was armed; a dead slot reads
+        through a zeroed row, page by page."""
+        from ..ops.pallas.paged_attention import walk_counts, walk_run_pages
+        lengths, decoding = self._block_read_lengths()
+        live, copied, _ = walk_counts(lengths, self.max_blocks,
+                                      self.kv_block_size)
+        in_runs = walk_run_pages(self._run_groups,
+                                 np.where(decoding, lengths, 0),
+                                 self.kv_block_size)
         self.kv_pages_live += live
         self.kv_pages_copied += copied
+        self.kv_pages_in_runs += in_runs
         ids = dict(super()._decode_block_counters(),
                    kv_pages_live=live, kv_pages_copied=copied,
-                   arm_ns=self._arm_ns)
+                   kv_pages_in_runs=in_runs, arm_ns=self._arm_ns)
         self._arm_ns = 0
         if self.cache_passes > 1:
             ids["ut_steps"] = self.decode_block * self.cache_passes
@@ -1375,6 +1396,7 @@ class PagedEngine(ContinuousBatchingEngine):
             "fetched_tokens": self.fetched_tokens,
             "kv_pages_live": self.kv_pages_live,
             "kv_pages_copied": self.kv_pages_copied,
+            "kv_pages_in_runs": self.kv_pages_in_runs,
             "ut_steps": self.ut_steps}
         return meta, arrays
 
@@ -1427,5 +1449,8 @@ class PagedEngine(ContinuousBatchingEngine):
         self.fetched_tokens = pc.get("fetched_tokens", 0)
         self.kv_pages_live = pc.get("kv_pages_live", 0)
         self.kv_pages_copied = pc.get("kv_pages_copied", 0)
+        self.kv_pages_in_runs = pc.get("kv_pages_in_runs", 0)
         self.ut_steps = pc.get("ut_steps", 0)
         self._pos_host = np.asarray(self._state["pos"]).astype(np.int64)
+        self._run_groups = self._table_run_groups(
+            np.asarray(self._state["table"]))

@@ -489,7 +489,8 @@ def test_looped_programs_hold_each_layer_once_and_copy_no_arena(
     """A looped model's decode block and chunk program at full width and 2
     layers of 4 passes each: the passes are a loop in the program, so the
     compiled decode block calls ``paged_attention_decode`` at 2 sites, not
-    8; no instruction copies an arena (a weight layer's ``(4 x 321, 16, 16,
+    8, which share one table, one set of lengths and one set of run
+    directions (worked out once a pass); no instruction copies an arena (a weight layer's ``(4 x 321, 16, 16,
     128)`` pair is carried through the loop and scattered into in place);
     and what the program holds beyond its arguments, less the q / k / v
     weights the compiler re-lays-out once before the loops (8 MiB each), is
@@ -500,6 +501,12 @@ def test_looped_programs_hold_each_layer_once_and_copy_no_arena(
     text = compiled.as_text()
     sites = len(set(re.findall(r"%(paged_attention_decode\.\d+) = ", text)))
     assert sites == (2 if program == "block" else 0)
+    # the table, the lengths and the run directions a pass's layers read
+    # are worked out once a pass: every site takes the same three values
+    scalars = {m.group(1) for m in re.finditer(
+        r"%paged_attention_decode\.\d+ = .*? custom-call\(((?:%[^,]+, ){2}"
+        r"%[^,]+),", text)}
+    assert len(scalars) == (1 if program == "block" else 0), scalars
     shape = "bf16[%s]" % ",".join(map(str, arena))
     made = [line for line in text.splitlines()
             if re.search(r"= %s\S* (copy|dynamic-update-slice|"

@@ -4,6 +4,8 @@ equivalence, ref-counted prefix sharing (release on eos, no
 double-free, hash-collision fallback), int8 KV error inside the
 runtime-queryable bound, and the static-shape invariant (ONE decode
 program + ONE chunk-prefill program across everything)."""
+import functools
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -501,63 +503,300 @@ class TestPagedKernel:
             assert not _kernel_ok(jnp.zeros((2, 4, 2, 8), jnp.float32))
 
 
-class TestKvWalkCounters:
-    def test_decode_block_counts_the_walk_it_ran(self, paged_setup,
-                                                 monkeypatch):
-        """Every decode block adds to ``engine.kv_pages_live`` /
-        ``kv_pages_copied``, and carries on its ``serving.decode_block``
-        span, exactly what ``walk_counts`` gives for the lengths the
-        block's steps read — reckoned here from the DEVICE's pos /
-        remaining / live before each block, which the engine's host
-        mirrors must agree with (dead and mid-prefill slots included:
-        they re-read the length they were left at)."""
-        import time
-        from paddle_tpu.observability import tracing
-        from paddle_tpu.ops.pallas.paged_attention import walk_counts
-        model, cfg, engine = paged_setup
-        engine.reset()
-        want = [0, 0]
-        step_block = engine.step_block
+# The walk's run copies: an aligned group of 8 table columns that names
+# consecutive blocks, rising or falling, moves as one copy. Block size 4, a
+# 40-column table (five groups), 16 pages a chunk (two groups): each slot's
+# row holds runs, broken groups or both, and its length ends mid-page,
+# mid-group, on a group's end, past the table, or reads a dead slot's
+# zeroed row.
+RUN_BS, RUN_MB, RUN_NB = 4, 40, 260
 
-        def spying_step_block():
-            if engine._pending_block is None and engine.has_decoding():
-                st = {k: np.asarray(engine._state[k])
-                      for k in ("pos", "remaining", "live")}
-                np.testing.assert_array_equal(st["pos"], engine._pos_host)
-                stays = np.where(st["live"], np.minimum(
-                    st["remaining"], engine.decode_block), 0)
-                for k in range(engine.decode_block):
-                    live, copied, _ = walk_counts(
-                        st["pos"] + np.minimum(k, stays) + 1,
-                        engine.max_blocks, engine.kv_block_size)
-                    want[0] += live
-                    want[1] += copied
-            step_block()
 
-        monkeypatch.setattr(engine, "step_block", spying_step_block)
+def _run_table_and_lengths():
+    rising = np.arange(1, 41)
+    falling = np.arange(200, 160, -1)
+    broken = np.concatenate([
+        np.arange(41, 49),                       # rising
+        [49, 50, 52, 51, 53, 54, 55, 56],        # two swapped
+        np.arange(64, 56, -1),                   # falling
+        [90, 91, 92, 93, 80, 81, 82, 83],        # a run off the alignment
+        [84, 85, 86, 87, 88, 89, 94, 95]])
+    rows = [(rising, 3 * 32 + 2 * RUN_BS + 1),   # mid-page of group 3
+            (falling, 3 * 32),                   # on group 2's end
+            (falling - 50, 4 * 32 - 2),          # group 3's last page short
+            (broken, 4 * 32 + 5 * RUN_BS + 2),   # mid-group, mid-page
+            (np.zeros(RUN_MB), 37),              # a dead slot
+            (rising + 200, 1),
+            (falling, RUN_MB * RUN_BS + 9),      # past the table: clamped
+            (falling - 100, 32),                 # one group, exactly
+            (rising, 20)]                        # mid-group 0: no run
+    tbl = np.stack([r for r, _ in rows]).astype(np.int32)
+    return jnp.asarray(tbl), jnp.asarray([n for _, n in rows], jnp.int32)
+
+
+def _run_case(kind, rs):
+    """(kernel output, reference output, arena page shapes) on the run
+    tables, fp32 throughout."""
+    from paddle_tpu.ops.pallas import paged_attention as pa
+    tbl, lens = _run_table_and_lengths()
+    if kind.startswith("swa"):
+        # a windowed walk clamps a length past its table, as the engine
+        # never asks; its reference does not
+        lens = jnp.minimum(lens, RUN_MB * RUN_BS)
+    b, h, kvh, d, w, rank = tbl.shape[0], 4, 2, 16, 32, 16
+
+    def rnd(*shape):
+        return jnp.asarray(rs.randn(*shape).astype(np.float32))
+
+    if kind in ("mla", "swa_mla"):
+        q, arena = rnd(b, h, w), rnd(RUN_NB, RUN_BS, w)
+        window = 13 if kind == "swa_mla" else None
+        fn = pa.mla_paged_attention_decode if window is None else \
+            functools.partial(pa.swa_mla_paged_attention_decode,
+                              window=window)
+        out = fn(q, arena, tbl, lens, scale=0.2, rank=rank)
+        ref = pa.mla_paged_attention_reference(
+            q[:, None], arena, tbl, lens, scale=0.2, rank=rank,
+            window=window)[:, 0]
+        return out, ref, [(arena.shape[1:], arena.dtype)]
+    if kind in ("packed", "swa_packed"):
+        q, arena = rnd(b, h, w), rnd(RUN_NB, RUN_BS * kvh, w)
+        kw = dict(scale=0.2, kvh=kvh, dv=8)
+        if kind == "packed":
+            out = pa.packed_paged_attention_decode(q, arena, tbl, lens, **kw)
+            sinks = window = None
+        else:
+            sinks, window = rnd(h), 13
+            out = pa.swa_paged_attention_decode(q, arena, tbl, lens, sinks,
+                                                window=window, **kw)
+        ref = pa.packed_paged_attention_reference(
+            q[:, None], arena, tbl, lens, window=window, sinks=sinks,
+            **kw)[:, 0]
+        return out, ref, [(arena.shape[1:], arena.dtype)]
+    q = rnd(b, h, d)
+    ka, va = rnd(RUN_NB, RUN_BS, kvh, d), rnd(RUN_NB, RUN_BS, kvh, d)
+    if kind == "paged":
+        out = pa.paged_attention_decode(q, ka, va, tbl, lens, scale=0.25)
+        ref = pa.paged_attention_reference(q[:, None], ka, va, tbl, lens,
+                                           scale=0.25)[:, 0]
+        arenas = (ka, va)
+    else:
+        (kc, ks), (vc, vs) = pa.quantize_kv(ka), pa.quantize_kv(va)
+        out = pa.paged_attention_decode_int8(q, kc, vc, ks, vs, tbl, lens,
+                                             scale=0.25)
+        ref = pa.paged_attention_int8_reference(
+            q[:, None], kc, vc, ks, vs, tbl, lens, scale=0.25)[:, 0]
+        arenas = (kc, vc, ks, vs)
+    return out, ref, [(pa._page_view(a.shape)[1:], a.dtype) for a in arenas]
+
+
+class TestWalkRuns:
+    @pytest.mark.parametrize("kind", ["mla", "paged", "packed", "int8",
+                                      "swa_mla", "swa_packed"])
+    def test_walk_matches_reference_over_runs(self, kind, monkeypatch):
+        """Every walk (interpret mode) matches its gathered reference on
+        tables that hold rising, falling and broken groups: the four walks
+        without a window move each marked group as ONE copy (a falling
+        one lands in reverse), the windowed two page by page as before."""
+        pytest.importorskip("jax.experimental.pallas")
+        import paddle_tpu.ops.pallas.fused as fused
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        monkeypatch.setattr(fused, "_FORCE_INTERPRET", True)
+        # 16 pages a chunk: two groups, three chunks over the table
+        page_rows = RUN_BS * (1 if kind in ("mla", "swa_mla") else 2)
+        monkeypatch.setattr(pa, "_CHUNK_ROWS", 16 * page_rows)
+        out, ref, pages = _run_case(kind, np.random.RandomState(3))
+        windowed = kind.startswith("swa")
+        ppc, run = pa._walk_chunk(RUN_MB, pages,
+                                  13 if windowed else None)
+        assert run == (1 if windowed else pa._RUN)
+        if not windowed:
+            assert ppc == 16
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=1e-5)
+
+    def test_the_tables_hold_every_kind_of_group(self):
+        """The run tables above mark rising (+1), falling (-1) and
+        page-by-page (0) groups; a group ending in a partly written page
+        is no run under ``whole`` (the softmax walk's rule) and is one
+        under the indexer's."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        tbl, lens = _run_table_and_lengths()
+        ways = np.asarray(pa._run_directions(tbl, lens, RUN_BS, pa._RUN,
+                                              whole=True))
+        assert ways.tolist() == [
+            [1, 1, 1, 0, 0], [-1, -1, -1, 0, 0], [-1, -1, -1, 0, 0],
+            [1, 0, -1, 0, 0],
+            [0] * 5, [0] * 5, [-1] * 5, [-1, 0, 0, 0, 0], [0] * 5]
+        # 126 rows: 32 pages, the last of them two rows short
+        short = lens.at[0].set(4 * 32 - 2)
+        assert [np.asarray(pa._run_directions(tbl, short, RUN_BS, pa._RUN,
+                                              whole=whole))[0].tolist()
+                for whole in (True, False)] \
+            == [[1, 1, 1, 0, 0], [1, 1, 1, 1, 0]]
+
+    def test_run_pages_match_a_plain_loop(self):
+        """``run_groups`` + ``walk_run_pages`` (the engine's counter)
+        count what a plain loop over each row and length finds: ``_RUN``
+        pages for every aligned group of consecutive blocks whose rows all
+        lie below the length; and they agree with ``_run_directions``."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        rs = np.random.RandomState(11)
+        for mb, bs in ((40, 4), (37, 2), (7, 8), (320, 16)):
+            tbl = rs.randint(1, 500, (9, mb)).astype(np.int32)
+            for i in range(0, 9, 2):             # some rows in runs
+                start = rs.randint(1, 600)
+                tbl[i] = start + np.arange(mb) * (1 if i % 4 else -1) \
+                    + 1000
+            seg = tbl[3, 8:24]                   # two runs in a row
+            seg[:] = 5000 + np.arange(seg.size)
+            lengths = rs.randint(0, mb * bs + 3 * bs, (4, 9))
+            want = 0
+            for ln in lengths:
+                for row, n in zip(tbl, ln):
+                    for g in range(mb // pa._RUN):
+                        cols = row[g * pa._RUN:(g + 1) * pa._RUN]
+                        steps = np.diff(cols.astype(np.int64))
+                        if ((steps == 1).all() or (steps == -1).all()) \
+                                and (g + 1) * pa._RUN * bs <= min(n, mb * bs):
+                            want += pa._RUN
+            groups = pa.run_groups(tbl)
+            assert pa.walk_run_pages(groups, lengths, bs) == want
+            ways = np.stack([np.asarray(pa._run_directions(
+                jnp.asarray(tbl), jnp.asarray(ln, jnp.int32), bs, pa._RUN,
+                whole=True)) for ln in lengths])
+            assert pa._RUN * int((ways != 0).sum()) == want
+
+    def test_chunks_hold_whole_groups_where_a_walk_takes_runs(self):
+        """A walk without a window whose chunk holds at least ``_RUN``
+        pages cuts it to whole groups (no group straddles two chunks), at
+        every served page shape; a windowed walk, or a table under a
+        group, copies page by page at the chunk it had; ``walk_counts``
+        counts the chunks of that ``ppc``."""
+        from paddle_tpu.ops.pallas import paged_attention as pa
+        bf, i8, f32 = jnp.bfloat16, jnp.int8, jnp.float32
+        served = {                               # (mb, pages): ppc
+            (320, (((16, 640), bf),)): 96,       # the latent arena
+            (256, (((128, 128), bf),) * 2): 16,  # 8 KV heads, K and V
+            (256, (((128, 128), i8),) * 2
+             + (((1, 128), f32),) * 2): 16,      # the int8 walk
+            (64, (((256, 128), bf),) * 2): 8,    # 16 KV heads
+            (512, (((64, 384), bf),)): 32,       # a packed arena, 4 heads
+            (640, (((128, 256), bf),)): 16}      # a packed arena, 8 heads
+        for (mb, pages), ppc in served.items():
+            pages = list(pages)
+            assert pa._walk_chunk(mb, pages) == (ppc, pa._RUN)
+            assert ppc <= pa._pages_per_chunk(mb, pages)
+            assert pa._walk_chunk(mb, pages, window=128) \
+                == (pa._pages_per_chunk(mb, pages), 1)
+        for mb in range(1, 70):
+            ppc, run = pa._walk_chunk(mb, [((16, 640), bf)])
+            assert (run == 1) == (mb < pa._RUN)
+            assert ppc % run == 0 and 1 <= ppc <= mb
+        lengths = [1, 600, 5119, 3500]
+        assert pa.walk_counts(lengths, 320, 16, 96)[2] \
+            == sum(-(-(-(-n // 16)) // 96) for n in lengths)
+
+
+def _serve_counting_the_walk(engine, cfg, requests):
+    """Serve ``requests`` ((prompt, new tokens), two slots: a later one
+    refills a freed slot, uneven budgets leave a dead slot beside a live
+    one) and reckon, from the DEVICE's pos / remaining / live / table
+    before each block, what the walk does: live pages, copied pages, and
+    the live pages in aligned groups of 8 consecutive blocks (rising or
+    falling) whose rows all lie below the length — by a plain loop over
+    each live slot's table row. Returns the ``serving.decode_block``
+    spans and those three sums."""
+    import time
+    from paddle_tpu.observability import tracing
+    from paddle_tpu.ops.pallas.paged_attention import _RUN, walk_counts
+    engine.reset()
+    want = [0, 0, 0]
+    step_block = engine.step_block
+    bs, mb = engine.kv_block_size, engine.max_blocks
+
+    def spying_step_block():
+        if engine._pending_block is None and engine.has_decoding():
+            st = {k: np.asarray(engine._state[k])
+                  for k in ("pos", "remaining", "live", "table")}
+            np.testing.assert_array_equal(st["pos"], engine._pos_host)
+            stays = np.where(st["live"], np.minimum(
+                st["remaining"], engine.decode_block), 0)
+            for k in range(engine.decode_block):
+                lengths = st["pos"] + np.minimum(k, stays) + 1
+                live, copied, _ = walk_counts(lengths, mb, bs)
+                want[0] += live
+                want[1] += copied
+                for row, n, on in zip(st["table"], lengths, k < stays):
+                    for g in range(mb // _RUN if on else 0):
+                        d = np.diff(row[g * _RUN:(g + 1) * _RUN])
+                        if ((d == 1).all() or (d == -1).all()) \
+                                and (g + 1) * _RUN * bs <= min(n, mb * bs):
+                            want[2] += _RUN
+        step_block()
+
+    engine.step_block = spying_step_block
+    try:
         rs = np.random.RandomState(4)
         srv = Server(engine)
-        # three requests on two slots: the third refills a freed slot, and
-        # the uneven budgets leave a dead slot beside a live one
-        for n_prompt, n_new in ((5, 7), (19, 12), (9, 5)):
+        for n_prompt, n_new in requests:
             srv.submit(rs.randint(0, cfg.vocab_size, (n_prompt,))
                        .astype(np.int32), max_new_tokens=n_new)
         t0 = time.perf_counter()
         srv.run_until_idle()
-        spans = [sp for sp in tracing.since(t0)
-                 if sp.name == "serving.decode_block"]
+    finally:
+        del engine.step_block
+    return [sp for sp in tracing.since(t0)
+            if sp.name == "serving.decode_block"], want
+
+
+class TestKvWalkCounters:
+    def test_decode_block_counts_the_walk_it_ran(self, paged_setup):
+        """Every decode block adds to ``engine.kv_pages_live`` /
+        ``kv_pages_copied`` / ``kv_pages_in_runs``, and carries on its
+        ``serving.decode_block`` span, exactly what the walk does for the
+        lengths the block's steps read — reckoned here from the DEVICE's
+        pos / remaining / live / table before each block, which the
+        engine's host mirrors must agree with (dead and mid-prefill slots
+        included: they re-read the length they were left at)."""
+        model, cfg, engine = paged_setup
+        spans, want = _serve_counting_the_walk(
+            engine, cfg, ((5, 7), (19, 12), (9, 5)))
         assert spans and want[0] > 0
         assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied",
-                                     "sampled_steps", "arm_ns",
-                                     "starved_ns"}
-        assert [engine.kv_pages_live, engine.kv_pages_copied] == want
+                                     "kv_pages_in_runs", "sampled_steps",
+                                     "arm_ns", "starved_ns"}
+        assert [engine.kv_pages_live, engine.kv_pages_copied,
+                engine.kv_pages_in_runs] == want
         assert [sum(sp.ids[k] for sp in spans)
-                for k in ("kv_pages_live", "kv_pages_copied")] == want
+                for k in ("kv_pages_live", "kv_pages_copied",
+                          "kv_pages_in_runs")] == want
         # copies are issued per live page: all of the kernel's KV traffic
         # is live KV
         assert engine.kv_pages_live == engine.kv_pages_copied
         np.testing.assert_array_equal(np.asarray(engine._state["pos"]),
                                       engine._pos_host)
+
+    def test_pages_in_runs_are_counted_from_the_armed_rows(self,
+                                                             paged_setup):
+        """At two-token blocks a 32-column table holds four groups of 8:
+        a request's blocks come from ONE allocation out of a fresh pool,
+        falling and consecutive, so its whole groups move as runs, and
+        ``kv_pages_in_runs`` (span, engine total, snapshot) counts exactly
+        what a plain loop over the device's table finds; a refill into a
+        freed slot re-derives the slot's groups from its new row."""
+        model, cfg, _ = paged_setup
+        engine = ContinuousBatchingEngine(
+            model, num_slots=2, max_len=64, decode_block=4, paged=True,
+            block_size=2, prefill_chunk=8)
+        _LIVE_MANAGERS.append(engine.manager)
+        spans, want = _serve_counting_the_walk(
+            engine, cfg, ((21, 14), (35, 9), (17, 12)))
+        assert want[2] > 0 and want[2] < want[0]
+        assert engine.kv_pages_in_runs == want[2]
+        assert sum(sp.ids["kv_pages_in_runs"] for sp in spans) == want[2]
+        meta, _ = engine.snapshot_state()
+        assert meta["paged_counters"]["kv_pages_in_runs"] == want[2]
 
 
 class TestPagedScheduling:

@@ -505,7 +505,7 @@ def test_hybrid_engine_span_and_counter_names():
     # in each group, and no live row
     assert eng._decode_block_counters() == {
         "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-        "arm_ns": 0,
+        "kv_pages_in_runs": 0, "arm_ns": 0,
         "window_kv_pages_live": 8, "window_kv_pages_copied": 8,
         "kv_rows_live": 0, "window_kv_rows_live": 0}
     assert (eng.window_kv_pages_live, eng.window_kv_pages_copied,
@@ -581,7 +581,7 @@ def test_looped_engine_span_and_counter_names():
     # at ONE site, and four passes a step
     assert eng._decode_block_counters() == {
         "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-        "arm_ns": 0, "ut_steps": 16}
+        "kv_pages_in_runs": 0, "arm_ns": 0, "ut_steps": 16}
     assert (eng.ut_steps, eng.ut_exit_step_milli,
             eng.prefill_ut_exit_step_milli) == (16, 0, 0)
     srv = Server(eng, Scheduler())
@@ -641,9 +641,9 @@ def test_decode_block_span_counter_names(tiny):
                                    block_size=8, prefill_chunk=8)
     assert eng._decode_block_counters() \
         == {"sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-            "arm_ns": 0}
-    assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied) \
-        == (0, 8, 8)
+            "kv_pages_in_runs": 0, "arm_ns": 0}
+    assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied,
+            eng.kv_pages_in_runs) == (0, 8, 8, 0)
     dense = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
                                      decode_block=4)
     assert dense._decode_block_counters() == {"sampled_steps": 0}

@@ -3,19 +3,26 @@
 model, each alone, at the shape the ``kanana2-docqa-decode`` cell serves.
 Needs a TPU.
 
-    python3 tools/latent_moe_probe.py [--chunk-rows N ...] [--experts-only]
+    python3 tools/latent_moe_probe.py [--chunk-rows N ...]
+        [--orders shuffled falling rising] [--experts-only | --latent-only]
 
 ``latent``: the s = 1 read ``mla_paged_attention_decode`` (64 slots, 32
 heads against one shared 640-wide row, KV block 16, a 320-entry table,
 16,385 blocks, bf16) against the gathered read, per call, for three length
-mixes; each timing is REPS calls chained inside one program (a call's
-output feeds the next call's q), median of 10 runs. ``experts``:
+mixes and each table order of ``--orders``: ``shuffled`` (every slot's
+blocks drawn at random from the pool: every page its own copy), ``falling``
+(each slot's blocks consecutive and falling, as one allocation from a fresh
+pool hands them out: aligned groups of 8 move as one copy) or ``rising``;
+each row says what share of its live pages moved in run copies. Each timing
+is REPS calls chained inside one program (a call's output feeds the next
+call's q), median of 10 runs. ``experts``:
 a decode step's 64 tokens through all 128 experts (2048 x 768 SwiGLU,
 top-6) in three forms: the kernel ``moe_hit_experts_decode``, XLA's grouped
 matmuls and every row on every expert, ms a layer and the share of the hit
 experts' bytes, on the cell's router and on pools of 64, 100 and 128
 experts; the grouped form at a chunk's 512 rows. ``--experts-only`` skips
-the latent read. The last line is one JSON object.
+the latent read, ``--latent-only`` the experts. The last line is one JSON
+object.
 """
 import argparse
 import functools
@@ -80,22 +87,31 @@ def latent(a, out):
         return pa.mla_paged_attention_reference(
             q[:, None], ar, t, ln, scale=scale, rank=a.rank)[:, 0]
 
-    for name, lengths in mixes.items():
-        perm = 1 + rs.permutation(a.blocks - 1)
+    pages = [(arena.shape[1:], arena.dtype)]
+    # a checkout whose walk copies page by page has no run helpers: its
+    # chunk is _pages_per_chunk's and its run share None
+    ppc = pa._walk_chunk(a.table, pages)[0] if hasattr(pa, "_walk_chunk") \
+        else pa._pages_per_chunk(a.table, pages)
+    for (name, lengths), order in ((m, o) for m in mixes.items()
+                                   for o in a.orders):
+        blocks = {"shuffled": 1 + rs.permutation(a.blocks - 1),
+                  "falling": np.arange(a.blocks - 1, 0, -1),
+                  "rising": np.arange(1, a.blocks)}[order]
         tbl = np.zeros((a.slots, a.table), np.int32)
         o = 0
         for i, n_tok in enumerate(lengths):
-            n = -(-int(n_tok) // a.block)
-            tbl[i, :n] = perm[(o + np.arange(n)) % len(perm)]
+            n = min(-(-int(n_tok) // a.block), a.table)
+            tbl[i, :n] = blocks[(o + np.arange(n)) % len(blocks)]
             o += n
+        in_runs = pa.walk_run_pages(pa.run_groups(tbl), lengths, a.block) \
+            if hasattr(pa, "run_groups") else None
         tbl, lens = jnp.asarray(tbl), jnp.asarray(lengths, jnp.int32)
-        live, copied, chunks = pa.walk_counts(
-            lengths, a.table, a.block, pa._pages_per_chunk(
-                a.table, [(arena.shape[1:], arena.dtype)]))
+        live, copied, chunks = pa.walk_counts(lengths, a.table, a.block, ppc)
         want = np.asarray(jax.jit(ref)(q, arena, tbl, lens), np.float32)
         got = np.asarray(jax.jit(walk)(q, arena, tbl, lens), np.float32)
-        row = {"live_tokens": int(lengths.sum()), "live_pages": live,
-               "copied_pages": copied, "chunks": chunks,
+        row = {"order": order, "live_tokens": int(lengths.sum()),
+               "live_pages": live, "copied_pages": copied, "chunks": chunks,
+               "run_page_share": None if in_runs is None else in_runs / live,
                "err": float(np.abs(got - want).max()),
                "walk_ms": median_ms(chained(walk, a.rank), q, arena, tbl,
                                     lens, reps=REPS),
@@ -113,8 +129,8 @@ def latent(a, out):
                     pa.mla_paged_attention_decode, scale=scale,
                     rank=a.rank), a.rank), q, arena, tbl, lens, reps=REPS)
             pa._CHUNK_ROWS = keep
-        out["latent." + name] = row
-        print("latent", name, json.dumps(row), flush=True)
+        out[f"latent.{name}.{order}"] = row
+        print("latent", name, order, json.dumps(row), flush=True)
 
 
 def experts(a, out):
@@ -204,7 +220,11 @@ def main():
     ap.add_argument("--table", type=int, default=320)
     ap.add_argument("--blocks", type=int, default=16385)
     ap.add_argument("--chunk-rows", type=int, nargs="*", default=[])
-    ap.add_argument("--experts-only", action="store_true")
+    ap.add_argument("--orders", nargs="+", default=["shuffled"],
+                    choices=["shuffled", "falling", "rising"])
+    only = ap.add_mutually_exclusive_group()
+    only.add_argument("--experts-only", action="store_true")
+    only.add_argument("--latent-only", action="store_true")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -214,7 +234,8 @@ def main():
            "chunk_rows": pa._CHUNK_ROWS}
     if not a.experts_only:
         latent(a, out)
-    experts(a, out)
+    if not a.latent_only:
+        experts(a, out)
     print(json.dumps(out))
 
 
