@@ -53,12 +53,13 @@ def main():
     print("generated:", out.numpy()[:, -args.max_new:])
 
     if args.continuous:
-        # slot-pool continuous batching: 5 ragged requests through 2
-        # slots, one compiled decode program, greedy == generate()
+        # slot-pool continuous batching over the paged KV arena: 5
+        # ragged requests through 2 slots, one compiled decode program,
+        # greedy == generate(); max_len in whole 16-token KV blocks
         from paddle_tpu.serving import ContinuousBatchingEngine, Server
         engine = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=16 + args.max_new,
-            decode_block=4, prompt_buckets=(8, 16))
+            model, num_slots=2, max_len=16 * (2 + args.max_new // 16),
+            decode_block=4, block_size=16)
         server = Server(engine)
         rs = np.random.RandomState(1)
         reqs = [rs.randint(0, cfg.vocab_size, (l,)).astype(np.int32)

@@ -257,8 +257,6 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
                    top_k: int = 0, top_p: float = 1.0,
                    engine_slots: Optional[int] = None,
                    engine_decode_block: int = 8,
-                   engine_prompt_buckets: Sequence[int] = (16, 32),
-                   engine_paged: bool = False,
                    engine_block_size: int = 16,
                    engine_num_blocks: Optional[int] = None,
                    engine_prefill_chunk: Optional[int] = None):
@@ -276,25 +274,22 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
     implementation.
 
     ``engine_slots``: additionally export the continuous-batching
-    engine's programs (the slot-pool decode block over
-    ``engine_slots`` × ``max_len`` caches, plus one prefill per prompt
-    bucket) so ``GenerationPredictor.serve()`` runs the SAME serving
-    engine from the artifact alone — see ``paddle_tpu.serving``.
-
-    ``engine_paged=True`` exports the PAGED engine's two programs
-    instead: the block-arena decode block (in-state block tables) and
-    the ONE chunked-prefill chunk program — ``engine_block_size`` /
+    engine's two programs — the decode block over the block arena
+    (in-state block tables) and the ONE chunked-prefill chunk program —
+    so ``GenerationPredictor.serve()`` runs the SAME serving engine from
+    the artifact alone (``serving.paging.PagedArtifactStepBackend`` is
+    the loader; see ``paddle_tpu.serving``). ``engine_block_size`` /
     ``engine_num_blocks`` / ``engine_prefill_chunk`` mirror the
-    ``PagedEngine`` knobs (defaults match: full dense capacity + trash
-    block, chunk = ``serving.paging.default_prefill_chunk``). The
-    artifact records the program arities (``block_outputs``/
-    ``chunk_outputs``) so a serving host can tell what it loaded;
-    ``serving.paging.PagedArtifactStepBackend`` is the loader. The int8
-    KV arena is not exported (fp32 arena only)."""
+    engine's ``block_size`` / ``num_blocks`` / ``prefill_chunk``
+    (defaults match: every slot's whole table + trash block, chunk =
+    ``serving.paging.default_prefill_chunk``). The artifact records the
+    program arities (``block_outputs``/``chunk_outputs``) so a serving
+    host can tell what it loaded. The int8 KV arena is not exported
+    (fp32 arena only)."""
     from ..models.generation import build_decode_step
     from ..tensor import Tensor
 
-    if engine_slots is not None and engine_paged:
+    if engine_slots is not None:
         from ..serving.paging import refuse_looped_cache
         refuse_looped_cache(model, "the exported paged artifact")
     sample_kwargs = dict(temperature=temperature, top_k=top_k,
@@ -334,7 +329,7 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
         "gen_config": {"batch": batch, "prompt_len": prompt_len,
                        "max_len": max_len, **sample_kwargs},
     }
-    if engine_slots is not None and engine_paged:
+    if engine_slots is not None:
         from ..serving.engine import (build_paged_chunk_fn,
                                       build_slot_block_fn,
                                       init_slot_state)
@@ -363,8 +358,7 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
             init_slot_state(engine_slots))
         state_specs["table"] = jax.ShapeDtypeStruct(
             (engine_slots, max_blocks), jnp.int32)
-        block_fn = build_slot_block_fn(eng_pure, engine_decode_block,
-                                       paged=True)
+        block_fn = build_slot_block_fn(eng_pure, engine_decode_block)
         exp_block = jax.export.export(jax.jit(block_fn))(
             pspecs, bspecs, pool_specs, state_specs)
         chunk_fn = build_paged_chunk_fn(eng_pure, engine_prefill_chunk)
@@ -384,10 +378,10 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
             "chunk": exp_chunk.serialize(),
             "pool_specs": [(tuple(s.shape), str(np.dtype(s.dtype)))
                            for s in pool_specs],
-            # arities recorded like the dense engine's block_outputs:
-            # block emits (cache, state, toks, lives, oks), the chunk
-            # program (tok0, cache) — a serving host can tell what the
-            # artifact carries without deserializing anything
+            # arities recorded: the block emits (cache, state, toks,
+            # lives, oks), the chunk program (tok0, cache) — a serving
+            # host can tell what the artifact carries without
+            # deserializing anything
             "config": {"paged": True, "num_slots": engine_slots,
                        "max_len": max_len,
                        "decode_block": engine_decode_block,
@@ -396,55 +390,6 @@ def export_decoder(model, path: str, batch: int, prompt_len: int,
                        "prefill_chunk": engine_prefill_chunk,
                        "kv_int8": False,
                        "block_outputs": 5, "chunk_outputs": 2},
-        }
-    elif engine_slots is not None:
-        from ..serving.engine import (build_slot_block_fn,
-                                      build_slot_prefill_fn,
-                                      init_slot_state)
-        pool0 = model.init_kv_cache(engine_slots, max_len)
-        pflat, ptree = jax.tree.flatten(
-            pool0, is_leaf=lambda x: isinstance(x, Tensor))
-        eng_holder = {"tree": ptree}
-        # per-slot sampling rides the state arrays — the exported block
-        # serves every sampling config, so sample_kwargs=None here
-        eng_pure = build_decode_step(model, None, eng_holder)
-        pool_specs = tuple(jax.ShapeDtypeStruct(c._value.shape,
-                                                c._value.dtype)
-                           for c in pflat)
-        row_specs = tuple(((1,) + s.shape[1:], s.dtype)
-                          for s in pool_specs)
-        state_specs = jax.tree.map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
-            init_slot_state(engine_slots))
-        block_fn = build_slot_block_fn(eng_pure, engine_decode_block)
-        exp_block = jax.export.export(jax.jit(block_fn))(
-            pspecs, bspecs, pool_specs, state_specs)
-        prefills = {}
-        for lb in sorted(set(int(b) for b in engine_prompt_buckets)):
-            pre = build_slot_prefill_fn(eng_pure, row_specs)
-            prefills[lb] = jax.export.export(jax.jit(pre))(
-                pspecs, bspecs,
-                jax.ShapeDtypeStruct((1, lb), jnp.int32),
-                jax.ShapeDtypeStruct((1,), jnp.int32),
-                jax.ShapeDtypeStruct((2,), jnp.uint32),
-                jax.ShapeDtypeStruct((), jnp.float32),
-                jax.ShapeDtypeStruct((), jnp.int32),
-                jax.ShapeDtypeStruct((), jnp.float32)).serialize()
-        blob["engine"] = {
-            "block": exp_block.serialize(),
-            "prefill": prefills,
-            "pool_specs": [(tuple(s.shape), str(np.dtype(s.dtype)))
-                           for s in pool_specs],
-            # the decode block emits (cache, state, toks, lives, oks)
-            # since the NaN-sentinel — record the arity so a serving
-            # host can tell whether the artifact carries the flags
-            # (pre-sentinel 4-output artifacts load fine: the engine
-            # pads the missing flags with None)
-            "config": {"num_slots": engine_slots, "max_len": max_len,
-                       "decode_block": engine_decode_block,
-                       "prompt_buckets": sorted(
-                           int(b) for b in engine_prompt_buckets),
-                       "block_outputs": 5},
         }
     os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
     out = path + ".pdgen"
@@ -504,31 +449,20 @@ class GenerationPredictor:
 
     def serve(self, requests, run: bool = True):
         """Continuous-batching serving from the artifact alone: builds
-        the SAME ``serving.Server`` loop over the exported slot-pool
-        engine programs (requires ``export_decoder(...,
-        engine_slots=N)``). ``requests``: iterable of dicts with keys
-        matching :meth:`serving.Server.submit` (``prompt`` required).
+        the SAME ``serving.Server`` loop over the exported engine
+        programs (requires ``export_decoder(..., engine_slots=N)``).
+        ``requests``: iterable of dicts with keys matching
+        :meth:`serving.Server.submit` (``prompt`` required).
         Returns the Server (``run=False``) or its results dict."""
         if self._engine_blob is None:
             raise ValueError(
                 "this artifact has no engine programs; re-export with "
                 "export_decoder(..., engine_slots=N)")
         from ..serving import ContinuousBatchingEngine, Server
-        from ..serving.engine import ArtifactStepBackend
+        from ..serving.paging import PagedArtifactStepBackend
         if self._server is None:
-            cfgs = self._engine_blob["engine"]["config"]
-            if cfgs.get("paged"):
-                from ..serving.paging import PagedArtifactStepBackend
-                backend = PagedArtifactStepBackend(self._engine_blob)
-                # is_paged on the backend routes the factory to the
-                # PagedEngine (chunked prefill + block manager)
-                engine = ContinuousBatchingEngine(backend=backend)
-            else:
-                backend = ArtifactStepBackend(self._engine_blob)
-                engine = ContinuousBatchingEngine(
-                    backend=backend,
-                    prompt_buckets=cfgs["prompt_buckets"])
-            self._server = Server(engine)
+            self._server = Server(ContinuousBatchingEngine(
+                backend=PagedArtifactStepBackend(self._engine_blob)))
         server = self._server
         for req in requests:
             server.submit(**dict(req))

@@ -306,7 +306,7 @@ def autotune_paged_block(path: Optional[str] = None, num_slots: int = 4,
         if eng is None:
             eng = engines[bs] = ContinuousBatchingEngine(
                 model, num_slots=num_slots, max_len=64,
-                decode_block=4, paged=True, block_size=bs,
+                decode_block=4, block_size=bs,
                 prefill_chunk=bs)
         eng.reset()
         srv = Server(eng)

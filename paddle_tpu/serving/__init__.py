@@ -1,4 +1,5 @@
-"""Continuous-batching serving engine (slot-pool in-graph decode).
+"""Continuous-batching serving engine (slot-pool in-graph decode over a
+block-paged KV arena).
 
 ``ContinuousBatchingEngine`` keeps S sequence slots alive inside ONE
 jitted decode program; ``Scheduler`` admits ragged requests into free
@@ -11,18 +12,16 @@ serialized artifact alone."""
 from .autoscaler import (Autoscaler, AutoscalerConfig, DecisionKernel,
                          Observation)
 from .durability import PrefixSpillStore, WriteAheadJournal
-from .engine import (ArtifactStepBackend, ContinuousBatchingEngine,
-                     ModelStepBackend, slot_sample_logits)
+from .engine import ContinuousBatchingEngine, slot_sample_logits
 from .fleet import (DecodeWorker, Fleet, FleetRouter, InProcessTransport,
-                    PrefillDenseEngine, PrefillPagedEngine,
-                    PrefillWorker, SocketTransport, Transport,
-                    TransportError)
+                    PrefillPagedEngine, PrefillWorker, SocketTransport,
+                    Transport, TransportError)
 from .frontend import FairScheduler, Frontend, TenantConfig, TokenStream
 from .handoff import (KVHandoff, decode_handoff, encode_handoff,
                       reshard_kv_chunks)
 from .loadgen import Trace, TraceConfig, generate_trace, replay
 from .hybrid import HybridPagedEngine, HybridPagedStepBackend
-from .paging import (BlockManager, PagedArtifactStepBackend, PagedEngine,
+from .paging import (BlockManager, PagedArtifactStepBackend,
                      PagedModelStepBackend)
 from .prefix_cache import (PrefixCacheDirectory, adopt_prefix,
                            extract_prefix)
@@ -30,26 +29,22 @@ from .quant import QuantConfig
 from .resilience import RequestFailure, ResilienceConfig
 from .scheduler import Request, ResumeState, Scheduler
 from .server import Server
-from .spec import (SpecConfig, SpecEngine, SpecModelStepBackend,
-                   SpecPagedEngine, SpecPagedStepBackend, ngram_propose)
-from .tp import (ShardedModelStepBackend, ShardedPagedStepBackend,
-                 TPConfig)
+from .spec import (SpecConfig, SpecPagedEngine, SpecPagedStepBackend,
+                   ngram_propose)
+from .tp import ShardedPagedStepBackend, TPConfig
 
 __all__ = ["Autoscaler", "AutoscalerConfig", "ContinuousBatchingEngine",
-           "DecisionKernel", "ModelStepBackend", "Observation",
-           "ArtifactStepBackend", "BlockManager", "DecodeWorker",
+           "DecisionKernel", "Observation", "BlockManager", "DecodeWorker",
            "FairScheduler", "Fleet", "FleetRouter", "Frontend",
            "HybridPagedEngine", "HybridPagedStepBackend",
            "InProcessTransport", "KVHandoff",
-           "PagedArtifactStepBackend", "PagedEngine",
-           "PagedModelStepBackend", "PrefillDenseEngine",
+           "PagedArtifactStepBackend", "PagedModelStepBackend",
            "PrefillPagedEngine", "PrefillWorker",
            "PrefixCacheDirectory", "PrefixSpillStore", "QuantConfig",
            "Request", "RequestFailure", "ResilienceConfig",
            "ResumeState", "Scheduler", "Server", "SocketTransport",
-           "SpecConfig", "SpecEngine", "SpecModelStepBackend",
-           "SpecPagedEngine", "SpecPagedStepBackend",
-           "ShardedModelStepBackend", "ShardedPagedStepBackend",
+           "SpecConfig", "SpecPagedEngine", "SpecPagedStepBackend",
+           "ShardedPagedStepBackend",
            "TPConfig", "TenantConfig", "TokenStream", "Transport",
            "Trace", "TraceConfig", "TransportError",
            "WriteAheadJournal", "adopt_prefix",

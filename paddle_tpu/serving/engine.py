@@ -1,5 +1,5 @@
-"""Continuous-batching decode engine: a fixed pool of S sequence slots
-kept alive inside ONE jitted step.
+"""Continuous-batching decode engine over a block-paged KV arena: a fixed
+pool of S sequence slots kept alive inside ONE jitted decode program.
 
 Reference parity: the reference serving stack's fused_multi_transformer
 decode loop + PaddleNLP's dynamic-batching inference server (SURVEY §2.1
@@ -7,26 +7,29 @@ Inference, §3.5 AnalysisPredictor — verify); the design is the
 vLLM-style continuous batching discipline restated under the repo's
 static-shape rules.
 
-TPU-native design: the KV cache is preallocated at
-``(S, max_len, kv_heads, head_dim)`` and never reshapes — a retiring
-request frees its SLOT, not its memory. Per-slot ``pos``/``pad``/
-``live``/``eos``/``remaining``/rng-key/sampling-param state rides
-in-graph as (S,) arrays, so ONE compiled program (a ``lax.scan`` of the
-shared decode step over ``decode_block`` tokens) serves every mix of
-request depths, greedy/sampled traffic, and admission pattern — zero
-recompiles across the stream. Admission reuses the existing shared
-prefill/decode step from ``models/generation`` at batch 1 (prompt
-left-padded to a bucket length), then splices the prefilled row into
-the pool with ``lax.dynamic_update_slice`` on the batch dim while the
-other slots' cache rows stay untouched (prefill-insert). The defining
-invariant: a continuously-batched stream of ragged greedy requests is
-bit-identical to per-request ``generate()`` calls.
+TPU-native design: the KV cache is ONE ``(num_blocks, block_size, kvh,
+d)`` arena per layer (``serving/paging.py``: the block manager and the
+step backends) that never reshapes, read and written by every slot
+through its row of an in-graph ``(S, max_blocks)`` block table (vLLM's
+PagedAttention: the table is state, the arena a fixed shape). Per-slot
+``pos``/``live``/``eos``/``remaining``/rng-key/sampling-param state and
+the tables ride in-graph as (S,)-leading arrays, so ONE compiled program
+(a ``lax.scan`` of the shared decode step over ``decode_block`` tokens)
+serves every mix of request depths, greedy/sampled traffic, and
+admission pattern — zero recompiles across the stream. Prompts are
+unpadded and stream into the arena through ONE fixed-shape chunk
+program, interleaved with decode blocks and paced by the scheduler's
+per-tick prefill token budget; whole prompt blocks are shared across
+requests through the block manager's rolling-hash prefix index. The
+defining invariant: a continuously-batched stream of ragged greedy
+requests is bit-identical to per-request ``generate()`` calls.
 """
 from __future__ import annotations
 
 import time
+from collections import OrderedDict
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -62,10 +65,8 @@ _M_KV_BYTES = _om.gauge(
     "KV pool bytes resident per decode step (codes + scales under "
     "the int8 arena)")
 
-__all__ = ["ContinuousBatchingEngine", "ModelStepBackend",
-           "ArtifactStepBackend", "slot_sample_logits", "init_slot_state",
-           "build_slot_block_fn", "build_slot_prefill_fn",
-           "build_paged_chunk_fn"]
+__all__ = ["ContinuousBatchingEngine", "slot_sample_logits",
+           "init_slot_state", "build_slot_block_fn", "build_paged_chunk_fn"]
 
 
 def slot_sample_logits(logits, keys, temperature, top_k, top_p, live=None):
@@ -147,22 +148,21 @@ def init_slot_state(num_slots: int) -> Dict[str, jnp.ndarray]:
     }
 
 
-def build_slot_block_fn(pure, block: int, trace_counter=None,
-                        paged: bool = False):
+def build_slot_block_fn(pure, block: int, trace_counter=None):
     """The engine's ONE decode program: ``lax.scan`` of the shared step
     over ``block`` tokens with per-slot positions. Each scan iteration:
-    per-slot key split -> forward (vector ``pos``, per-slot ``pad``) ->
-    per-slot sampling -> in-graph eos/budget retirement (a finished
-    slot's ``live`` drops and its pos/tok freeze — it is masked junk
-    until the host refills it between blocks). Emits the (block, S)
+    per-slot key split -> forward (vector ``pos``, the slot's block
+    table) -> per-slot sampling -> in-graph eos/budget retirement (a
+    finished slot's ``live`` drops and its pos/tok freeze — it is masked
+    junk until the host refills it between blocks). Emits the (block, S)
     token matrix plus per-step live-slot counts (the occupancy/tok-s
     numerators), so the host syncs ONCE per block.
 
-    ``paged``: the state carries a per-slot block ``table`` and the
-    cache is the shared block arena; dead slots' tables are redirected
-    to the trash block 0 IN-GRAPH, so a retired slot whose blocks the
-    host has already handed to another request can never scatter junk
-    into them mid-block.
+    The state carries a per-slot block ``table`` and the cache is the
+    shared block arena; dead slots' tables are redirected to the trash
+    block 0 IN-GRAPH, so a retired slot whose blocks the host has
+    already handed to another request can never scatter junk into them
+    mid-block.
 
     Besides tokens and live masks the block also emits per-step (S,)
     ``ok`` flags — True iff the row's log-probs held no NaN (the logit
@@ -179,13 +179,9 @@ def build_slot_block_fn(pure, block: int, trace_counter=None,
             cf, st = carry
             sp = jax.vmap(jax.random.split)(st["key"])     # (S, 2, 2)
             new_key, sub = sp[:, 0], sp[:, 1]
-            if paged:
-                tbl = jnp.where(st["live"][:, None], st["table"], 0)
-                logp, cf = pure(pv, bv, st["tok"][:, None], cf,
-                                st["pos"], None, None, tbl)
-            else:
-                logp, cf = pure(pv, bv, st["tok"][:, None], cf,
-                                st["pos"], None, st["pad"])
+            tbl = jnp.where(st["live"][:, None], st["table"], 0)
+            logp, cf = pure(pv, bv, st["tok"][:, None], cf,
+                            st["pos"], None, None, tbl)
             # NaN (not -inf: log-probs legitimately underflow) marks a
             # poisoned row — numerically impossible from finite
             # weights/cache, so a False flag means corrupted state
@@ -215,26 +211,6 @@ def build_slot_block_fn(pure, block: int, trace_counter=None,
     return named_program(block_fn, DECODE_PROGRAM)
 
 
-def build_slot_prefill_fn(pure, row_specs):
-    """Batch-1 prefill of a prompt bucket into a fresh full-length cache
-    row (the row is spliced into the pool by the admit program). Reuses
-    the SAME shared step as ``generate()`` — prompt left-padded to the
-    bucket length, per-row pad counts mask the filler — so slot decode
-    is bit-identical to a standalone ``generate()`` call. The first
-    token is sampled in-graph with the request's own params (one
-    dispatch per admission, not two)."""
-
-    def prefill_fn(pv, bv, ids, pad, key, temp, topk, topp):
-        zero = tuple(jnp.zeros(shape, dtype) for shape, dtype in row_specs)
-        logp, row = pure(pv, bv, ids, zero, jnp.asarray(0, jnp.int32),
-                         None, pad)
-        tok0 = slot_sample_logits(logp, key[None], temp[None],
-                                  topk[None], topp[None])[0]
-        return tok0, row
-
-    return prefill_fn
-
-
 def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
     """ONE chunked-prefill program for every prompt of every length:
     a fixed ``(1, chunk)`` right-padded token window written straight
@@ -243,11 +219,10 @@ def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
     attended — writes past the table width land in the trash block).
     The candidate first token is sampled in-graph from the last REAL
     column with the request's own params; the host uses it only on the
-    final chunk. Unlike the dense engine's per-bucket prefill jits,
-    this compiles exactly once. Every call streams all the weights
-    whatever ``chunk`` is, so the chunk a paged engine picks by itself
-    (``paging.default_prefill_chunk``) is the ridge where the chunk's
-    matmuls catch up with that read."""
+    final chunk. It compiles exactly once. Every call streams all the
+    weights whatever ``chunk`` is, so the chunk an engine picks by
+    itself (``paging.default_prefill_chunk``) is the ridge where the
+    chunk's matmuls catch up with that read."""
 
     def chunk_fn(pv, bv, ids, cache_flat, table, start_pos, n_valid,
                  key, temp, topk, topp):
@@ -267,214 +242,46 @@ def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
 def _cancel_fn(state, slot):
     """Kill one slot in-graph (deadline/poison cancellation): ``live``
     drops and ``remaining`` zeroes, so the next decode block treats the
-    row as retired junk (and, paged, redirects its table to the trash
-    block). One compiled program serves every cancellation."""
+    row as retired junk and redirects its table to the trash block. One
+    compiled program serves every cancellation."""
     return dict(state,
                 live=state["live"].at[slot].set(False),
                 remaining=state["remaining"].at[slot].set(0))
 
 
-def _admit_fn(cache_flat, state, row_flat, slot, tok0, pos0, pad0, rem0,
-              eos0, temp0, topk0, topp0, key0):
-    """Splice a prefilled row into the pool (dynamic_update_slice on the
-    batch dim — other slots' rows untouched) and arm the slot's state.
-    ``slot`` is traced, so ONE compiled program serves every admission."""
-    new_cache = tuple(
-        jax.lax.dynamic_update_slice(c, r.astype(c.dtype),
-                                     (slot,) + (0,) * (c.ndim - 1))
-        for c, r in zip(cache_flat, row_flat))
+def _arm_fn(state, slot, table_row, tok0, pos0, rem0, eos0, temp0,
+            topk0, topp0, key0):
+    """Turn a slot live after its chunked prefill finished: the arena
+    already holds the prompt's K/V, so arming is a pure state update.
+    ``slot`` is traced — one compiled program serves every arming."""
 
     def set1(a, v):
         return a.at[slot].set(jnp.asarray(v, a.dtype))
 
-    new_state = dict(
+    return dict(
         state, tok=set1(state["tok"], tok0),
-        pos=set1(state["pos"], pos0), pad=set1(state["pad"], pad0),
+        pos=set1(state["pos"], pos0),
+        pad=set1(state["pad"], 0),        # prompts are unpadded
         live=set1(state["live"], rem0 > 0),
         eos=set1(state["eos"], eos0),
         remaining=set1(state["remaining"], rem0),
         key=state["key"].at[slot].set(key0),
         temp=set1(state["temp"], temp0),
         topk=set1(state["topk"], topk0),
-        topp=set1(state["topp"], topp0))
-    return new_cache, new_state
-
-
-class _StepBackendCommon:
-    """Shared slot-state/accounting helpers for every step backend
-    (in-process, paged, AOT) — keyed off ``num_slots``/``pool_specs``
-    which each backend sets up."""
-
-    # weight-only quantization state (serving/quant.py): None/empty on
-    # fp32 backends, so every hot path stays one falsy check
-    quant_cfg = None
-    _qmeta = None
-    _weight_bound = 0.0
-
-    def init_state(self):
-        return init_slot_state(self.num_slots)
-
-    def kv_bytes_per_slot(self) -> int:
-        """HBM bytes of KV cache per slot (the paged backend's arena is
-        shared, so its per-slot figure shrinks with block count)."""
-        total = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
-                    for shape, dtype in self.pool_specs)
-        return total // self.num_slots
-
-    def _setup_weight_quant(self, model, quant):
-        """Quantize the serving weight set in-place (model backends
-        call this between pv construction and program building; see
-        serving/quant.py). No-op when ``quant`` is None."""
-        if quant is None:
-            return
-        from .quant import quantize_backend_params
-        self.quant_cfg = quant
-        self._pv, self._qmeta, self._weight_bound = \
-            quantize_backend_params(model, self._pv, quant)
-
-    def _maybe_quant_pure(self, pure):
-        """Wrap a pure step with the in-graph dequant when this backend
-        holds quantized weights — EVERY program (decode block, prefill,
-        chunk, spec verify) must be built from the wrapped step."""
-        if not self._qmeta:
-            return pure
-        from .quant import wrap_pure_with_dequant
-        return wrap_pure_with_dequant(pure, self._qmeta)
-
-    def param_bytes(self) -> int:
-        """HBM bytes of weights + buffers one decode step reads (codes
-        AND scales under weight-only quant — the wire footprint, which
-        is the point)."""
-        return sum(int(v.nbytes) for v in jax.tree.leaves(self._pv)) \
-            + sum(int(v.nbytes) for v in jax.tree.leaves(self._bv))
-
-
-class ModelStepBackend(_StepBackendCommon):
-    """In-process backend: jits the slot block + per-bucket prefills
-    over a live model (the same pure step ``generate()`` uses)."""
-
-    def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, quant=None):
-        from ..models.generation import (build_decode_step,
-                                         forward_accepts_pad)
-        from ..tensor import Tensor
-        if not forward_accepts_pad(type(model)):
-            raise ValueError(
-                f"{type(model).__name__}.forward does not accept per-row "
-                "pad counts — the slot pool needs ragged decode support")
-        self.num_slots, self.max_len = num_slots, max_len
-        self.block_size = decode_block
-        tree_holder = {"tree": None}
-        self._tree_holder = tree_holder    # spec backends reuse it
-        self._pure = build_decode_step(model, None, tree_holder)
-        cache0 = model.init_kv_cache(num_slots, max_len)
-        flat, tree = jax.tree.flatten(
-            cache0, is_leaf=lambda x: isinstance(x, Tensor))
-        tree_holder["tree"] = tree
-        self.pool_specs = tuple((c._value.shape, c._value.dtype)
-                                for c in flat)
-        self.row_specs = tuple(((1,) + shape[1:], dtype)
-                               for shape, dtype in self.pool_specs)
-        self._pv = [p._value for _, p in model.named_parameters()]
-        self._bv = [b._value for _, b in model.named_buffers()]
-        # weight-only quant happens BEFORE any program is built so the
-        # decode block, prefills (and subclasses' chunk/verify programs)
-        # all trace against codes + in-graph dequant
-        self._setup_weight_quant(model, quant)
-        self._pure = self._maybe_quant_pure(self._pure)
-        self.decode_traces = [0]
-        self._block_jit = jax.jit(
-            build_slot_block_fn(self._pure, decode_block,
-                                self.decode_traces),
-            donate_argnums=(2, 3))
-        self._prefill_jits: Dict[int, callable] = {}
-
-    def pool_cache(self):
-        return tuple(jnp.zeros(shape, dtype)
-                     for shape, dtype in self.pool_specs)
-
-    def decode_block(self, cache_flat, state):
-        return self._block_jit(self._pv, self._bv, cache_flat, state)
-
-    def prefill(self, bucket_len, ids, pad, key, temp, topk, topp):
-        fn = self._prefill_jits.get(bucket_len)
-        if fn is None:
-            fn = jax.jit(build_slot_prefill_fn(self._pure, self.row_specs))
-            self._prefill_jits[bucket_len] = fn
-        return fn(self._pv, self._bv, ids, pad, key, temp, topk, topp)
-
-
-def artifact_fingerprint(cfgs: dict, *programs: bytes) -> str:
-    """Artifact identity: sha1 over the recorded config + the
-    serialized programs. Recorded into engine snapshots so a restore
-    onto a DIFFERENT artifact is refused — the ONE recipe shared by the
-    dense and paged artifact backends (changing it in one place cannot
-    silently de-gate the other)."""
-    import hashlib
-    h = hashlib.sha1(repr(sorted(
-        (k, str(v)) for k, v in cfgs.items())).encode())
-    for prog in programs:
-        h.update(prog)
-    return h.hexdigest()
-
-
-class ArtifactStepBackend(_StepBackendCommon):
-    """AOT backend: the SAME engine programs, deserialized from an
-    ``export_decoder(..., engine_slots=...)`` artifact — no model code
-    or tracing needed on the serving host (reference: AnalysisPredictor
-    serving from the saved program alone)."""
-
-    def __init__(self, blob):
-        eng = blob["engine"]
-        cfgs = eng["config"]
-        self.artifact_fingerprint = artifact_fingerprint(
-            cfgs, eng["block"],
-            *(eng["prefill"][lb] for lb in sorted(eng["prefill"])))
-        self.num_slots = cfgs["num_slots"]
-        self.max_len = cfgs["max_len"]
-        self.block_size = cfgs["decode_block"]
-        # pre-NaN-sentinel artifacts exported a 4-output decode block
-        # (no per-step ok flags); the engine pads the missing flags
-        # with None so both generations serve — new exports record
-        # block_outputs=5
-        self.carries_nan_flags = cfgs.get("block_outputs", 4) >= 5
-        self.pool_specs = tuple((tuple(shape), np.dtype(dtype))
-                                for shape, dtype in eng["pool_specs"])
-        self._block = jax.export.deserialize(eng["block"])
-        self._prefills = {int(k): jax.export.deserialize(v)
-                          for k, v in eng["prefill"].items()}
-        self._pv = [jnp.asarray(v) for v in blob["params"]]
-        self._bv = [jnp.asarray(v) for v in blob["buffers"]]
-        self.decode_traces = [1]     # one AOT-compiled decode program
-
-    def pool_cache(self):
-        return tuple(jnp.zeros(shape, dtype)
-                     for shape, dtype in self.pool_specs)
-
-    def decode_block(self, cache_flat, state):
-        return self._block.call(self._pv, self._bv, cache_flat, state)
-
-    def prefill(self, bucket_len, ids, pad, key, temp, topk, topp):
-        fn = self._prefills.get(int(bucket_len))
-        if fn is None:
-            raise ValueError(
-                f"prompt bucket {bucket_len} was not exported; available: "
-                f"{sorted(self._prefills)} — re-export with it in "
-                "engine_prompt_buckets")
-        return fn.call(self._pv, self._bv, ids, pad, key, temp, topk,
-                       topp)
+        topp=set1(state["topp"], topp0),
+        table=state["table"].at[slot].set(table_row))
 
 
 @dataclass
 class _SlotRun:
     """Host-side bookkeeping for one in-flight request. ``t_admit`` is
     the moment the first token existed (prefill completion) — the TTFT
-    timestamp. ``block_ids``: the paged engine's arena blocks to
-    release at retirement (None on the dense engine);
+    timestamp. ``block_ids``: the run's arena blocks to release at
+    retirement (None once released);
     ``window``: what the hybrid engine's run holds in its window pool;
     ``chain``: ``(digest, tokens)`` of the leading blocks of what the run
-    writes (prompt, then history) that the paged engine's block manager
-    has hashed so far — a cache it extends, never serialized."""
+    writes (prompt, then history) that the block manager has hashed so
+    far — a cache it extends, never serialized."""
     request: object
     tokens: List[int] = field(default_factory=list)
     t_admit: float = 0.0
@@ -488,104 +295,149 @@ class _SlotRun:
     failure: Optional[str] = None
 
 
+@dataclass
+class _PrefillJob:
+    """One admitted request still streaming its prompt into the arena
+    (``done`` counts tokens already resident, including the shared
+    prefix it skipped). For a preemption resume, ``prompt`` is the
+    original prompt plus the generated history being re-prefilled and
+    ``resume_tok`` is the carried in-hand next token — the chunk
+    programs' in-graph samples are discarded and the slot arms with it
+    instead."""
+    run: _SlotRun
+    slot: int
+    prompt: np.ndarray
+    done: int
+    table_row: np.ndarray          # (max_blocks,) int32
+    key: jnp.ndarray               # post-split state key
+    sub: jnp.ndarray               # prefill sampling key
+    temp: jnp.ndarray
+    topk: jnp.ndarray
+    topp: jnp.ndarray
+    tok0: Optional[int] = None
+    resume_tok: Optional[int] = None
+
+
+@dataclass
+class _Reservation:
+    """What an admission holds once its blocks are reserved: the run's
+    blocks (shared prefix first), its table row, how many leading prompt
+    blocks were served from the prefix index, what the ``serving.admit``
+    span says of it, and the digests the lookup computed (``chain``, for
+    the run to carry). An engine with a second pool adds what the run
+    holds there (``window``)."""
+    block_ids: List[int]
+    table_row: np.ndarray
+    shared_blocks: int
+    span_ids: dict
+    chain: list
+    window: Optional[object] = None
+
+
 class ContinuousBatchingEngine:
-    """Slot-pool decode engine over a step backend. The host syncs with
-    the device once per ``decode_block`` tokens: it reads the (block, S)
-    token matrix plus the post-block ``remaining`` counters, harvests
-    retired requests, and refills free slots — the decode program itself
-    is compiled exactly once for the engine's lifetime.
+    """Paged-KV continuous batching over a step backend. The host syncs
+    with the device once per ``decode_block`` tokens: it reads the
+    (block, S) token matrix plus the post-block ``remaining`` counters,
+    harvests retired requests, and refills free slots — the decode
+    program itself is compiled exactly once for the engine's lifetime.
 
-    ``paged=True`` (or ``PT_SERVING_PAGED=1``) constructs the
-    block-paged variant (``serving.paging.PagedEngine``): shared KV
-    arena + per-slot block tables, ref-counted prefix reuse, chunked
-    prefill — see that module for the paged-only knobs."""
+    - ``try_admit()`` reserves the request's blocks (sharing a matched
+      prefix) and queues a prefill job; it returns False when the block
+      pool cannot hold it yet — the Server re-queues and retries after
+      retirements free blocks. The prompt streams into the arena via
+      :meth:`prefill_tick` (chunk programs), and the slot arms when its
+      last chunk lands.
+    - prompts are UNPADDED: position 0 is token 0, which is what makes
+      whole prefix blocks shareable across requests.
+    - ``prefill_chunk`` (else ``PT_SERVING_PREFILL_CHUNK``) is the chunk
+      program's length in tokens; when neither is given it is
+      :func:`~.paging.default_prefill_chunk` — the program's ridge,
+      where a chunk's matmuls take as long as the weight read every
+      chunk pays, so a prompt re-reads the weights once per ~256 tokens.
+      :meth:`chunk_fill_share` says how much of the chunks was prompt.
+    - ``kv_int8=True`` (else ``PT_SERVING_KV_INT8``) stores the arena as
+      int8 codes + per-vector absmax scales; :meth:`kv_error_bound`
+      says the worst-case dequant error.
 
-    def __new__(cls, *args, **kw):
+    Constructing this class is the factory: a model whose cache keeps
+    two groups of layers (``kv_cache_groups``) gets
+    :class:`~.hybrid.HybridPagedEngine`, and ``spec=`` (or a
+    verify-capable backend) gets :class:`~.spec.SpecPagedEngine`."""
+
+    def __new__(cls, model=None, *args, backend=None, spec=None, **kw):
         if cls is ContinuousBatchingEngine:
-            paged = kw.get("paged")
-            backend = kw.get("backend") if len(args) < 6 else args[5]
-            if paged is None:
-                from ..utils.flags import env_flag
-                if getattr(backend, "is_paged", False):
-                    paged = True     # a paged backend IS the decision
-                elif backend is None:
-                    paged = env_flag("PT_SERVING_PAGED")
-                # an explicit non-paged backend (e.g. the AOT
-                # ArtifactStepBackend in GenerationPredictor) is never
-                # rerouted by the env flag
-            from .spec import spec_requested
-            spec = spec_requested(kw.get("spec"), backend)
-            if paged:
-                from .paging import PagedEngine
-                from .spec import SpecPagedEngine
-                model = args[0] if args else kw.get("model")
-                if backend is None and not spec and \
-                        getattr(model, "kv_cache_groups", None):
-                    # a model whose cache keeps two groups of layers
-                    # (full and sliding-window): its own engine
-                    from .hybrid import HybridPagedEngine
-                    return object.__new__(HybridPagedEngine)
-                return object.__new__(
-                    SpecPagedEngine if spec else PagedEngine)
-            if spec:
-                from .spec import SpecEngine
-                return object.__new__(SpecEngine)
+            if backend is None and getattr(model, "kv_cache_groups", None):
+                # a model whose cache keeps two groups of layers (full
+                # and sliding-window): its own engine
+                from .hybrid import HybridPagedEngine
+                return object.__new__(HybridPagedEngine)
+            from .spec import SpecPagedEngine, spec_requested
+            if spec_requested(spec, backend):
+                return object.__new__(SpecPagedEngine)
         return object.__new__(cls)
 
     def __init__(self, model=None, num_slots: int = 4, max_len: int = 256,
-                 decode_block: int = 8,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 backend=None, *, paged: Optional[bool] = None,
-                 spec=None, tp=None, quant=None):
-        if backend is None:
+                 decode_block: int = 8, *, backend=None, paged: bool = True,
+                 spec=None, block_size: Optional[int] = None,
+                 num_blocks: Optional[int] = None,
+                 kv_int8: Optional[bool] = None,
+                 prefill_chunk: Optional[int] = None,
+                 hash_fn=None, tp=None, quant=None):
+        from .paging import BlockManager
+        if not paged:
+            raise ValueError(
+                "paged=False: the dense slot-pool engine was removed — "
+                "the block-paged arena is the serving stack's one KV "
+                "layout (drop paged=)")
+        if backend is not None:
+            # the backend already baked these in — a silently ignored
+            # kv_int8=True (fp32 arena, bound 0.0), num_blocks or
+            # quant= (including quant=False against a quantized backend,
+            # which cannot be de-quantized) would be a misconfiguration,
+            # not a preference
+            given = {k: v for k, v in (("block_size", block_size),
+                                       ("num_blocks", num_blocks),
+                                       ("kv_int8", kv_int8),
+                                       ("prefill_chunk", prefill_chunk),
+                                       ("quant", quant))
+                     if v is not None}
+            if given:
+                raise ValueError(
+                    f"{sorted(given)} cannot be set alongside an "
+                    "explicit backend — they are baked into it at "
+                    "construction")
+        else:
             if model is None:
                 raise ValueError("pass a model or a step backend")
-            from .quant import resolve_quant_config
-            from .tp import resolve_tp_config
-            tp_cfg = resolve_tp_config(tp)
-            q_cfg = resolve_quant_config(quant)
-            if tp_cfg is not None:
-                # tensor-parallel serving: the SAME decode/prefill
-                # programs, sharded over a mesh (serving/tp.py). An
-                # explicitly passed backend is never rerouted by the
-                # PT_SERVING_TP env flag — same contract as paged.
-                from .tp import ShardedModelStepBackend
-                backend = ShardedModelStepBackend(
-                    model, num_slots, max_len, decode_block, tp_cfg,
-                    quant=q_cfg)
-            else:
-                # subclass hook: the speculative engine swaps in the
-                # verify-capable backend here (serving/spec.py)
-                backend = self._build_backend(model, num_slots, max_len,
-                                              decode_block, q_cfg)
-        elif quant is not None:
-            # same contract as kv_int8/num_blocks on the paged engine:
-            # the quantization is baked into the backend at construction
-            # — a silently ignored quant= (INCLUDING quant=False against
-            # a quantized backend, which cannot be de-quantized) would
-            # be a misconfiguration, not a preference (and the env knob
-            # never reroutes an explicit backend either: resolution
-            # only runs above)
-            raise ValueError(
-                "quant= cannot be set alongside an explicit backend — "
-                "weight-only quantization is baked into the backend at "
-                "construction")
+            backend = self._resolve_backend(
+                model, num_slots, max_len, decode_block, block_size,
+                num_blocks, kv_int8, prefill_chunk, tp, quant)
         if spec and not hasattr(self, "spec_k"):
             # only the factory (ContinuousBatchingEngine(...)) routes
-            # spec= to the speculative engine classes; a direct
-            # subclass constructor silently ignoring it would be a
+            # spec= to the speculative engine; a direct subclass
+            # constructor silently ignoring it would be a
             # misconfiguration, not a preference
             raise ValueError(
                 "spec= is only honored through the "
                 "ContinuousBatchingEngine factory (or construct "
-                "serving.spec.SpecEngine/SpecPagedEngine directly)")
+                "serving.spec.SpecPagedEngine directly)")
         self.backend = backend
         self.num_slots = backend.num_slots
         self.max_len = backend.max_len
         self.decode_block = backend.block_size
-        self.prompt_buckets = tuple(sorted(prompt_buckets)) \
-            if prompt_buckets else None
-        self._admit_jit = jax.jit(_admit_fn, donate_argnums=(0, 1))
+        self.kv_block_size = backend.kv_block_size
+        self.num_kv_blocks = backend.num_kv_blocks
+        self.max_blocks = backend.max_blocks
+        self.kv_int8 = backend.kv_int8
+        self.prefill_chunk_len = backend.prefill_chunk_len
+        # passes a step makes over the layers (1 unless the model loops)
+        # and the cached-attention sites a decode step holds: the page
+        # counters below stay ONE site's
+        self.cache_passes = getattr(backend, "cache_passes", 1)
+        self.attn_sites = getattr(backend, "attn_sites", 0)
+        self.manager = BlockManager(self.num_kv_blocks,
+                                    self.kv_block_size, hash_fn)
+        self._arm_jit = jax.jit(_arm_fn, donate_argnums=(0,))
         self._cancel_jit = jax.jit(_cancel_fn, donate_argnums=(0,))
         # host-side gate on the in-graph NaN flags (the flags are
         # always computed — same single compiled program either way)
@@ -595,10 +447,59 @@ class ContinuousBatchingEngine:
         self.tracer = None
         self.reset()
 
+    def _resolve_backend(self, model, num_slots, max_len, decode_block,
+                         block_size, num_blocks, kv_int8, prefill_chunk,
+                         tp, quant):
+        """The step backend for ``model``: unset knobs resolved (explicit
+        arg > env knob > default), tensor-parallel when ``tp`` (or
+        ``PT_SERVING_TP``) asks for it."""
+        from ..utils.flags import env_flag, env_int
+        from .paging import default_prefill_chunk
+        from .quant import resolve_quant_config
+        from .tp import resolve_tp_config
+        if block_size is None:
+            # resolution order: explicit arg > env knob > a valid
+            # (stamp-matching) autotune-table winner > the documented
+            # default 16 — a stale table never silently reshapes arenas
+            block_size = env_int("PT_SERVING_BLOCK_SIZE", 0)
+            if block_size <= 0:
+                from ..ops.pallas.autotune import tuned_paged_block_size
+                block_size = tuned_paged_block_size(16)
+        if num_blocks is None:
+            # every slot's whole table + trash by default — HBM savings
+            # come from passing a smaller pool (plus sharing);
+            # correctness never depends on the pool being oversized
+            num_blocks = 1 + num_slots * (max_len // block_size)
+        if kv_int8 is None:
+            kv_int8 = env_flag("PT_SERVING_KV_INT8")
+        if prefill_chunk is None:
+            prefill_chunk = env_int(
+                "PT_SERVING_PREFILL_CHUNK",
+                default_prefill_chunk(block_size, max_len))
+        tp_cfg = resolve_tp_config(tp)
+        q_cfg = resolve_quant_config(quant)
+        if tp_cfg is not None:
+            # tensor-parallel serving: the SAME decode/chunk programs,
+            # sharded over a mesh (serving/tp.py); an explicitly passed
+            # backend is never rerouted by the env flag
+            from .tp import ShardedPagedStepBackend
+            return ShardedPagedStepBackend(
+                model, num_slots, max_len, decode_block, block_size,
+                num_blocks, bool(kv_int8), prefill_chunk, tp_cfg,
+                quant=q_cfg)
+        return self._build_backend(
+            model, num_slots, max_len, decode_block, block_size,
+            num_blocks, bool(kv_int8), prefill_chunk, q_cfg)
+
     def _build_backend(self, model, num_slots, max_len, decode_block,
+                       block_size, num_blocks, kv_int8, prefill_chunk,
                        quant=None):
-        return ModelStepBackend(model, num_slots, max_len, decode_block,
-                                quant=quant)
+        """Subclass hook: the speculative and hybrid engines build their
+        own step backend here."""
+        from .paging import PagedModelStepBackend
+        return PagedModelStepBackend(
+            model, num_slots, max_len, decode_block, block_size,
+            num_blocks, kv_int8, prefill_chunk, quant=quant)
 
     # -- lifecycle ---------------------------------------------------------
     def reset(self):
@@ -607,7 +508,7 @@ class ContinuousBatchingEngine:
         self._cache = self.backend.pool_cache()
         self._state = self.backend.init_state()
         self._slots: List[Optional[_SlotRun]] = [None] * self.num_slots
-        self._prefill_slots: set = set()   # paged: mid-prefill slots
+        self._prefill_slots: set = set()   # mid-prefill slots
         self._remaining_host = np.zeros((self.num_slots,), np.int64)
         self._finished: List[_SlotRun] = []
         self._pending_block = None     # dispatched, not yet harvested
@@ -627,6 +528,38 @@ class ContinuousBatchingEngine:
         self.sync_stalls = 0
         self.sync_stall_ns = 0        # what they lasted beyond it
         self._stalls_untold: List[dict] = []   # for the Server's flight ring
+        self.manager.reset()
+        self._jobs: List[_PrefillJob] = []
+        self.prompt_tokens = 0         # all prompt tokens submitted
+        self.shared_tokens = 0         # skipped via prefix reuse
+        self.prefilled_tokens = 0      # actually computed
+        self.prefill_chunks = 0        # chunk programs dispatched
+        self.fetched_tokens = 0        # of shared: remote-fetched KV
+        # the decode kernel's walk (per attention layer), and the host's
+        # mirror of the in-graph per-slot ``pos`` it is counted from
+        self.kv_pages_live = 0         # pages holding a slot's live KV
+        self.kv_pages_copied = 0       # pages the kernel copied for them
+        self.kv_pages_in_runs = 0      # of the live, pages moved in runs
+        # each slot's table row as the walk's run copies see it
+        # (``paged_attention.run_groups``), from the row it was armed with
+        self._run_groups = self._table_run_groups(
+            np.zeros((self.num_slots, self.max_blocks), np.int32))
+        self.ut_steps = 0              # looped model: passes run by decode
+        self._pos_host = np.zeros((self.num_slots,), np.int64)
+        # what the model's programs count into the cache's last leaf
+        # (``cache_counters``: name -> "sum" | "max"; a (2, n) int32 array,
+        # decode steps apart from prefill chunks): the decode row becomes
+        # an engine counter of that name, the chunk row ``prefill_<name>``
+        self._counter_names = tuple(
+            getattr(self.backend, "cache_counters", {}).items())
+        self._counts_seen = np.zeros((2, len(self._counter_names)),
+                                     np.int64)
+        for name, _ in self._counter_names:
+            setattr(self, name, 0)
+            setattr(self, "prefill_" + name, 0)
+        self._chunk_span = None        # the latest ``serving.prefill_chunk``
+        self._chunks_unread = 0        # chunks since the counters were read
+        self._arm_ns = 0               # ends of prefill since the last block
 
     # -- the chip's empty queue, and syncs that stall -------------------------
     def _enqueued(self, sp):
@@ -640,9 +573,7 @@ class ContinuousBatchingEngine:
         small programs in between (the admission's uploads, arming a
         slot) neither start nor end an interval, and an engine left
         without a live slot forgets the stamp (:meth:`_free_slot`): idle
-        for want of traffic is not starvation. The dense engine's
-        whole-prompt prefill ends an interval and, its first token being
-        fetched outside any sync span, starts none."""
+        for want of traffic is not starvation."""
         if self._drained_ns is not None:
             ns = time.perf_counter_ns() - self._drained_ns
             self._drained_ns = None
@@ -678,10 +609,15 @@ class ContinuousBatchingEngine:
 
     def has_decoding(self) -> bool:
         """Any slot past prefill (worth running a decode block for) —
-        differs from :meth:`has_live` only on the paged engine, where a
-        slot can be occupied but still mid-chunked-prefill."""
+        differs from :meth:`has_live` where a slot is occupied but still
+        mid-chunked-prefill."""
         return any(s is not None and i not in self._prefill_slots
                    for i, s in enumerate(self._slots))
+
+    def has_prefill(self) -> bool:
+        """Any admitted prompt still streaming into the arena (a chunk
+        for :meth:`prefill_tick` to run)."""
+        return bool(self._jobs)
 
     def occupancy(self) -> float:
         """Fraction of decode-block slot-steps that emitted a token
@@ -708,17 +644,41 @@ class ContinuousBatchingEngine:
             return 0.0
         return fn(self._cache, self._state)
 
+    def prefix_cache_hit_rate(self) -> float:
+        """Fraction of submitted prompt tokens served from shared
+        prefix blocks instead of recomputed."""
+        return self.shared_tokens / self.prompt_tokens \
+            if self.prompt_tokens else 0.0
+
+    def chunk_fill_share(self) -> float:
+        """Fraction of the chunk programs' columns that held a prompt
+        token (the rest was right-padding): a longer chunk re-reads the
+        weights less often and pads more."""
+        columns = self.prefill_chunks * self.prefill_chunk_len
+        return self.prefilled_tokens / columns if columns else 0.0
+
+    def prefill_compile_count(self) -> int:
+        return self.backend.prefill_traces[0]
+
     def kv_error_bound(self) -> float:
-        """Runtime worst-case |dequant - fp32| over the KV cache — 0.0
-        on the dense engine (fp32 rows); the paged engine's int8 arena
-        overrides this with the EQuARX bound."""
-        return 0.0
+        """Runtime worst-case |dequantized - fp32| over the int8 arena
+        (0.0 in fp32 mode): the EQuARX single-quantization bound from
+        the largest live absmax scale."""
+        if not self.kv_int8:
+            return 0.0
+        from ..ops.pallas.paged_attention import kv_int8_error_bound
+        worst = 0.0
+        for (shape, dtype), buf in zip(self.backend.pool_specs,
+                                       self._cache):
+            if np.dtype(dtype) == np.float32 and len(shape) == 3:
+                worst = max(worst, float(jnp.max(buf)))
+        return float(kv_int8_error_bound(worst))
 
     def weight_error_bound(self) -> float:
         """Build-time worst-case elementwise |dequant - fp32| over the
         weight-only-quantized decode weights (half the largest
         quantization step; 0.0 when quant is off)."""
-        return float(getattr(self.backend, "_weight_bound", 0.0))
+        return float(self.backend._weight_bound)
 
     def quant_error_bound(self) -> dict:
         """Both quantization error components of the decode path, from
@@ -742,8 +702,7 @@ class ContinuousBatchingEngine:
         blocks, so the kv term is an upper bound — but it is the term
         quantization shrinks, which is what the A/B measures."""
         if self._bytes_step is None:
-            w = self.backend.param_bytes() \
-                if hasattr(self.backend, "param_bytes") else 0
+            w = self.backend.param_bytes()
             kv = sum(int(c.nbytes) for c in self._cache)
             self._bytes_step = {"weights": w, "kv": kv,
                                 "total": w + kv}
@@ -759,15 +718,11 @@ class ContinuousBatchingEngine:
         _M_W_BYTES.set(b["weights"])
         _M_KV_BYTES.set(b["kv"])
 
-    def bucket_len(self, prompt_len: int) -> int:
-        if self.prompt_buckets is None:
-            return prompt_len
-        for b in self.prompt_buckets:
-            if b >= prompt_len:
-                return b
-        raise ValueError(
-            f"prompt length {prompt_len} exceeds the largest bucket "
-            f"{self.prompt_buckets[-1]}")
+    def blocks_needed(self, prompt_len: int, max_new_tokens: int) -> int:
+        # positions written: prompt [0, L) plus generated tokens at
+        # [L, L+max_new-1) — the final sampled token is never written
+        return -(-(prompt_len + max(max_new_tokens - 1, 0))
+                 // self.kv_block_size)
 
     def validate_request(self, prompt_len: int, max_new_tokens: int):
         """Raise ValueError if the request can never fit a slot — run
@@ -778,156 +733,271 @@ class ContinuousBatchingEngine:
         if max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens={max_new_tokens}; must be >= 1")
-        lb = self.bucket_len(prompt_len)
-        if lb + max_new_tokens > self.max_len:
+        if prompt_len + max_new_tokens > self.max_len:
             raise ValueError(
-                f"prompt bucket ({lb}) + max_new_tokens "
+                f"prompt ({prompt_len}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the slot capacity "
                 f"({self.max_len}); raise max_len or shorten the request")
+        need = self.blocks_needed(prompt_len, max_new_tokens)
+        # the MANAGER is the source of truth, not the engine's
+        # num_kv_blocks attribute: allocate() draws from the manager,
+        # so validating against a stale attribute let an impossible
+        # request through the door and into run_until_idle's re-queue
+        # path forever (the PR-5 livelock fix; regression-pinned with a
+        # tiny pool in tests/test_resilience.py)
+        pool = self.manager.usable_blocks()
+        if need > pool:
+            raise ValueError(
+                f"request needs {need} KV blocks but the arena only "
+                f"has {pool}; raise num_blocks or shorten the request")
 
     # -- admission ---------------------------------------------------------
-    def admit(self, request) -> bool:
-        """Prefill the request's prompt (batch-1, left-padded to its
-        bucket) and splice the row into a free slot. Returns True if the
-        request already finished at admission (max_new==1 or eos on the
-        first token) — it then never occupies a slot. A request carrying
-        preemption ``resume`` state re-prefills its generated history
-        instead (see :meth:`_admit_resume`)."""
-        from ..profiler import RecordEvent
+    def try_admit(self, request) -> bool:
+        """Block allocation, prefix lookup and slot arming for one
+        request (the chunks themselves run in :meth:`prefill_tick`);
+        False when the block pool cannot hold it yet. The span says how
+        many blocks the admission allocated (``fresh_blocks``) and how
+        many of those it took by eviction (``evicted_blocks``), and how
+        many block digests it computed (``hashed_blocks``); an admission
+        that went through also marks where its time went: ``reserved_ns``
+        when the lookup, the allocation and the table row were done,
+        ``keyed_ns`` when the request's key was made and split; the rest
+        to the span's end is the sampling scalars' uploads and the job."""
+        with _span("serving.admit", rid=request.request_id) as sp:
+            m = self.manager
+            evicted, hashed = m.evictions, m.hashed_blocks
+            ids = self._try_admit(request, sp)
+            sp.ids.update(
+                ids or {"fresh_blocks": 0},
+                evicted_blocks=m.evictions - evicted,
+                hashed_blocks=m.hashed_blocks - hashed)
+            return ids is not None
+
+    def _reserve(self, full, mnt) -> Optional[_Reservation]:
+        """Blocks and table row for a request of prompt ``full`` and
+        ``mnt`` new tokens: the matched prefix's blocks shared, the rest
+        fresh; None when the pool cannot cover it yet (nothing held)."""
+        chain = []
+        shared = self._match_prefix_for_admission(full, chain)
+        total = self.blocks_needed(len(full), mnt)
+        fresh = self.manager.allocate(total - len(shared))
+        if fresh is None:            # pool exhausted: retry later
+            self.manager.release(shared)
+            return None
+        block_ids = shared + fresh
+        table_row = np.zeros((self.max_blocks,), np.int32)
+        table_row[:len(block_ids)] = block_ids
+        return _Reservation(block_ids, table_row, len(shared),
+                            {"fresh_blocks": len(fresh)}, chain)
+
+    def _try_admit(self, request, sp) -> Optional[dict]:
+        """The admission proper, marking its span ``sp``: what the span
+        says of the blocks it allocated, or None when a pool cannot hold
+        the request yet."""
+        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
         resume = getattr(request, "resume", None)
         if resume is not None and resume.tokens:
-            return self._admit_resume(request, resume)
-        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
-        L = int(prompt.shape[0])
-        self.validate_request(L, request.max_new_tokens)
-        Lb = self.bucket_len(L)
+            # preemption resume: the "prompt" to prefill is the original
+            # prompt plus the generated history minus the in-hand next
+            # token; the first full prompt blocks are usually still in
+            # the prefix index (eviction retained them), so most of this
+            # re-prefill is cache hits rather than recompute
+            full = np.concatenate([
+                prompt, np.asarray(resume.tokens[:-1], np.int32)])
+            mnt = request.max_new_tokens - len(resume.tokens) + 1
+        else:
+            resume = None
+            full, mnt = prompt, request.max_new_tokens
+        L = int(full.shape[0])
+        self.validate_request(L, mnt)
         slot = next((i for i, s in enumerate(self._slots) if s is None),
                     None)
         if slot is None:
             raise RuntimeError("no free slot (scheduler bug)")
-        tr = self.tracer
-        if tr is not None:
-            tr.span_end(request.request_id, "queue_wait")
-            t_prefill = _trace_now()
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, Lb - L:] = prompt
-        pad0 = Lb - L
-        key = jax.random.PRNGKey(request.seed)
-        key, sub = jax.random.split(key)      # generate()'s key schedule
-        temp = jnp.float32(request.temperature)   # <= 0 means greedy
-        topk = jnp.int32(request.top_k)
-        topp = jnp.float32(request.top_p)
-        with RecordEvent("serving.prefill") as ev:
-            tok0_dev, row = self.backend.prefill(
-                Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
-                sub, temp, topk, topp)
-            self._enqueued(ev._span)
-        tok0 = int(tok0_dev)
-        if tr is not None:
-            tr.span_at(request.request_id, "prefill", t_prefill,
-                       tokens=L, bucket=Lb)
-        _M_PREFILLS.inc()
-        _M_TOKENS.inc()
-        run = _SlotRun(request, tokens=[tok0], t_admit=time.perf_counter())
-        self.tokens_emitted += 1
-        eos = request.eos_token_id
-        rem0 = request.max_new_tokens - 1
-        if eos is not None and tok0 == eos:
-            rem0 = 0
-        if rem0 <= 0:
-            run.t_done = time.perf_counter()
-            self._finished.append(run)
-            return True
-        with RecordEvent("serving.admit"):
-            self._cache, self._state = self._admit_jit(
-                self._cache, self._state, row, jnp.int32(slot),
-                jnp.int32(tok0), jnp.int32(Lb), jnp.int32(pad0),
-                jnp.int32(rem0),
-                jnp.int32(-1 if eos is None else eos),
-                temp, topk, topp, key)
-        if tr is not None:
-            tr.span_begin(request.request_id, "decode", slot=slot)
+        held = self._reserve(full, mnt)
+        if held is None:
+            return None
+        sp.mark("reserved")
+        block_ids, table_row = held.block_ids, held.table_row
+        if self.tracer is not None:
+            self.tracer.span_end(request.request_id, "queue_wait",
+                                 shared_blocks=held.shared_blocks,
+                                 fresh_blocks=held.span_ids["fresh_blocks"],
+                                 resumed=resume is not None)
+        if resume is None:
+            key = jax.random.PRNGKey(request.seed)
+            key, sub = jax.random.split(key)  # generate()'s key schedule
+            run = _SlotRun(request, block_ids=block_ids)
+            resume_tok = None
+        else:
+            # the saved key IS the next step's split input — arming with
+            # it (and discarding the chunk programs' in-graph samples)
+            # keeps seeded-sampled resumes bit-identical
+            key = jnp.asarray(np.asarray(resume.key, np.uint32))
+            sub = jax.random.PRNGKey(0)            # discarded draw
+            run = _SlotRun(request, tokens=list(resume.tokens),
+                           t_admit=resume.t_admit, block_ids=block_ids)
+            resume_tok = int(resume.tokens[-1])
+        sp.mark("keyed")
+        run.window, run.chain = held.window, held.chain
         self._slots[slot] = run
-        self._remaining_host[slot] = rem0
+        self._prefill_slots.add(slot)
+        n_shared = held.shared_blocks * self.kv_block_size
+        self.prompt_tokens += L
+        self.shared_tokens += n_shared
+        self._jobs.append(_PrefillJob(
+            run=run, slot=slot, prompt=full, done=n_shared,
+            table_row=table_row, key=key, sub=sub,
+            temp=jnp.float32(request.temperature),
+            topk=jnp.int32(request.top_k),
+            topp=jnp.float32(request.top_p), resume_tok=resume_tok))
+        return held.span_ids
+
+    def _match_prefix_for_admission(self, full, chain: list) -> List[int]:
+        """Admission-time prefix match. The base engine consults only
+        its LOCAL index; the fleet's prefill engines override this to
+        also fetch a longer chain another worker has registered
+        (serving/prefix_cache.py) — either way the returned blocks are
+        ref-acquired for the admitting request and ``done`` starts past
+        them. ``chain`` receives the digests the local lookup computed."""
+        return self.manager.match_prefix(full, chain)
+
+    def admit(self, request) -> bool:
+        if not self.try_admit(request):
+            raise RuntimeError(
+                "KV block pool exhausted; use try_admit/Server (which "
+                "re-queue) or raise num_blocks")
         return False
 
-    def _admit_resume(self, request, resume) -> bool:
-        """Re-admit a preempted request: re-prefill prompt + generated
-        history — the KV the eviction dropped — into a fresh row, then
-        arm the slot with the CARRIED stream state (``tokens[-1]`` as
-        the in-hand next token, the saved rng key, the remaining token
-        budget). The re-prefill's in-graph sample is DISCARDED (the
-        stream already owns its next token, and the saved key must not
-        be advanced), so the resumed greedy AND seeded-sampled streams
-        are bit-identical to an uninterrupted run. Padding shifts are
-        invisible by construction: RoPE positions are pad-corrected and
-        masked slots contribute exact zeros, the same property that
-        makes bucket-padded serving equal generate()."""
-        from ..profiler import RecordEvent
-        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
-        toks = list(resume.tokens)
-        full = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
-        pl = int(full.shape[0])
-        rem0 = request.max_new_tokens - len(toks)
-        self.validate_request(pl, rem0 + 1)
-        Lb = self.bucket_len(pl)
-        slot = next((i for i, s in enumerate(self._slots) if s is None),
-                    None)
-        if slot is None:
-            raise RuntimeError("no free slot (scheduler bug)")
-        tr = self.tracer
-        if tr is not None:
-            tr.span_end(request.request_id, "queue_wait", resumed=True)
-            t_prefill = _trace_now()
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, Lb - pl:] = full
-        pad0 = Lb - pl
-        with RecordEvent("serving.prefill") as ev:
-            _discard, row = self.backend.prefill(
-                Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
-                jax.random.PRNGKey(0), jnp.float32(0.0), jnp.int32(0),
-                jnp.float32(1.0))
-            self._enqueued(ev._span)
-        if tr is not None:
-            tr.span_at(request.request_id, "prefill", t_prefill,
-                       tokens=pl, bucket=Lb, resumed=True)
-        _M_PREFILLS.inc()
-        # t_admit carries over: the first token existed before the
-        # eviction, so TTFT keeps measuring the first admission
-        run = _SlotRun(request, tokens=toks, t_admit=resume.t_admit)
-        eos = request.eos_token_id
-        with RecordEvent("serving.admit"):
-            self._cache, self._state = self._admit_jit(
-                self._cache, self._state, row, jnp.int32(slot),
-                jnp.int32(toks[-1]), jnp.int32(Lb), jnp.int32(pad0),
-                jnp.int32(rem0),
-                jnp.int32(-1 if eos is None else eos),
-                jnp.float32(request.temperature),
-                jnp.int32(request.top_k), jnp.float32(request.top_p),
-                jnp.asarray(np.asarray(resume.key, np.uint32)))
-        if tr is not None:
-            tr.instant(request.request_id, "resume", slot=slot,
-                       reused_tokens=len(toks))
-            tr.span_begin(request.request_id, "decode", slot=slot)
-        self._slots[slot] = run
-        self._remaining_host[slot] = rem0
-        request.resume = None       # consumed; a later preemption
-        return False                # rebuilds it from the live run
+    # -- chunked prefill ---------------------------------------------------
+    def prefill_tick(self, token_budget: Optional[int] = None) -> int:
+        """Advance pending prefill jobs by up to ``token_budget`` prompt
+        tokens (always at least one chunk when work is pending, so a
+        tiny budget still progresses). Jobs run FIFO; a finished job
+        arms its slot (or retires immediately on eos/max_new==1)."""
+        spent = 0
+        C = self.prefill_chunk_len
+        while self._jobs and (token_budget is None or spent == 0
+                              or spent < token_budget):
+            # fires BEFORE the chunk dispatch: the job's cursor hasn't
+            # advanced, so a retry re-dispatches the identical chunk
+            faults.fault_point("serving.prefill_tick")
+            job = self._jobs[0]
+            L = len(job.prompt)
+            n = min(C, L - job.done)
+            ids = np.zeros((1, C), np.int32)
+            ids[0, :n] = job.prompt[job.done:job.done + n]
+            tr = self.tracer
+            t_chunk = _trace_now() if tr is not None else 0.0
+            with _span("serving.prefill_chunk",
+                       rid=job.run.request.request_id,
+                       tokens=n) as self._chunk_span:
+                tok0_dev, self._cache = self.backend.prefill_chunk(
+                    jnp.asarray(ids), self._cache,
+                    jnp.asarray(job.table_row[None]),
+                    jnp.asarray(job.done, jnp.int32),
+                    jnp.asarray(n, jnp.int32),
+                    job.sub, job.temp, job.topk, job.topp)
+                self._enqueued(self._chunk_span)
+            job.done += n
+            spent += n
+            self.prefill_chunks += 1
+            self._chunks_unread += 1
+            self.prefilled_tokens += n
+            _M_PREFILLS.inc()
+            if tr is not None:
+                tr.span_at(job.run.request.request_id, "prefill_chunk",
+                           t_chunk, tokens=n, done=job.done, total=L)
+            if job.done >= L:
+                self._jobs.pop(0)
+                self._finish_prefill(job, tok0_dev)
+        return spent
 
-    def try_admit(self, request) -> bool:
-        """Admit if resources allow; False means "retry later" (the
-        paged engine's block pool can be exhausted even with a free
-        slot — the dense engine always admits into a free slot)."""
-        self.admit(request)
-        return True
+    def _finish_prefill(self, job: _PrefillJob, tok0_dev):
+        req = job.run.request
+        now = time.perf_counter()
+        eos = req.eos_token_id
+        if job.resume_tok is not None:
+            # preemption resume: the carried stream owns the next token
+            # — the chunk's in-graph sample is discarded, tokens and the
+            # TTFT timestamp ride over from the evicted run
+            t_synced = time.perf_counter_ns()       # nothing is fetched
+            tok0 = job.resume_tok
+            rem0 = req.max_new_tokens - len(job.run.tokens)
+            req.resume = None
+            if self.tracer is not None:
+                self.tracer.instant(req.request_id, "resume",
+                                    slot=job.slot,
+                                    reused_tokens=len(job.run.tokens))
+        else:
+            began = self._stall_watch.begin()
+            with _span("serving.prefill_sync", rid=req.request_id) as sp:
+                tok0 = int(tok0_dev)        # host blocked on the last chunk
+            t_synced = sp.start + sp.dur
+            if self._pending_block is None:     # the last chunk was all
+                self._drained_ns = t_synced     # the device had queued
+            self._sync_ended(sp, began)
+            job.run.tokens = [tok0]
+            job.run.t_admit = now           # TTFT timestamp
+            self.tokens_emitted += 1
+            _M_TOKENS.inc()
+            rem0 = req.max_new_tokens - 1
+            if eos is not None and tok0 == eos:
+                rem0 = 0
+        # the prompt's full blocks are resident now — index them so the
+        # NEXT request with this prefix skips the compute
+        self._register_prompt(job)
+        self._prefill_slots.discard(job.slot)
+        if rem0 <= 0:                # finished at admission
+            self._retire(job.slot, job.run, now)
+        else:
+            self._arm(job.slot, job.table_row, tok0, len(job.prompt), rem0,
+                      eos, job.temp, job.topk, job.topp, job.key)
+            if self.tracer is not None:
+                self.tracer.span_begin(req.request_id, "decode",
+                                       slot=job.slot)
+        # host time between the last chunk's end and the next enqueue that
+        # no span covers (it is ``serving.tick``'s own): the next
+        # ``serving.decode_block`` carries the sum as ``arm_ns``
+        self._arm_ns += time.perf_counter_ns() - t_synced
+
+    def _register_prompt(self, job: _PrefillJob):
+        """Index the prompt's shareable blocks (now filled) from the
+        chain the run carries since its admission, extended over the
+        blocks the lookup stopped short of."""
+        m, run = self.manager, job.run
+        m.register_chain(
+            m.extend_chain(run.chain, job.prompt,
+                           m._shareable_blocks(job.prompt)),
+            run.block_ids)
+
+    def _arm(self, slot, table_row, tok0, pos0, rem0, eos, temp, topk,
+             topp, key):
+        """Arm ``slot`` for decoding, in-graph and in the host's mirrors
+        of its remaining count and position (the one arm site: local
+        prefill completion and the fleet's adopted hand-offs)."""
+        self._state = self._arm_jit(
+            self._state, jnp.int32(slot), jnp.asarray(table_row),
+            jnp.int32(tok0), jnp.int32(pos0), jnp.int32(rem0),
+            jnp.int32(-1 if eos is None else eos), temp, topk, topp, key)
+        self._remaining_host[slot] = rem0
+        self._pos_host[slot] = pos0
+        self._run_groups[slot] = self._table_run_groups(
+            np.asarray(table_row)[None])[0]
+
+    def _table_run_groups(self, rows):
+        """:func:`paged_attention.run_groups` of the full-length table's
+        columns of ``rows``."""
+        from ..ops.pallas.paged_attention import run_groups
+        return run_groups(rows[:, :self.max_blocks])
 
     # -- preemption --------------------------------------------------------
     def can_resume(self, run: "_SlotRun") -> bool:
         """Whether a preempted ``run`` could later be re-admitted: its
-        prompt + generated history must still fit the engine (dense: a
-        prompt bucket; paged: the block pool). The preemption policy
-        checks this BEFORE evicting — a victim that could never come
-        back would be a silent kill, not a preemption."""
+        prompt + generated history must still fit the engine (its slot
+        capacity and the block pool). The preemption policy checks this
+        BEFORE evicting — a victim that could never come back would be a
+        silent kill, not a preemption."""
         if not run.tokens:
             return True          # mid-prefill: requeues as submitted
         req = run.request
@@ -975,10 +1045,15 @@ class ContinuousBatchingEngine:
         self._release_slot_resources(run)
         return run, key
 
-    def _release_slot_resources(self, run: "_SlotRun"):
-        """Free everything a preempted run held besides the slot
-        itself — dense rows are pool-owned, nothing to do (the paged
-        engine releases the run's arena blocks here)."""
+    def _release_slot_resources(self, run):
+        """Retirement and preemption release: the run's arena blocks
+        drop one ref — registered prompt-prefix blocks park in the LRU
+        cache (their prefix-index entries RETAINED, so a preempted run's
+        re-prefill is mostly cache hits), unregistered decode blocks
+        return to the free list."""
+        if run.block_ids is not None:
+            self.manager.release(run.block_ids)
+            run.block_ids = None
 
     # -- decode ------------------------------------------------------------
     def has_pending_harvest(self) -> bool:
@@ -1011,9 +1086,7 @@ class ContinuousBatchingEngine:
                 out = self.backend.decode_block(self._cache, self._state)
                 self._enqueued(self._block_span)
             self._cache, self._state = out[0], out[1]
-            # old AOT artifacts predate the ok flags: pad with None
-            self._pending_block = tuple(out[2:]) \
-                if len(out) > 4 else (out[2], out[3], None)
+            self._pending_block = tuple(out[2:])
             self.steps += self.decode_block
             self.slot_steps += self.decode_block * self.num_slots
             _M_STEPS.inc(self.decode_block)
@@ -1028,11 +1101,10 @@ class ContinuousBatchingEngine:
             # in this span are round trips to a drained device
             self._drained_ns = sp.mark("first")
             lives_np = np.asarray(lives)            # (block, S)
-            oks_np = None if oks is None else np.asarray(oks)
+            oks_np = np.asarray(oks)
             rem_np = np.asarray(self._state["remaining"])
             counts_np = self._read_program_counters()
-            sp.ids["fetches"] = 3 + (oks is not None) \
-                + (counts_np is not None)
+            sp.ids["fetches"] = 4 + (counts_np is not None)
         self._sync_ended(sp, began)
         self._pending_block = None
         with _span("serving.harvest"):
@@ -1042,10 +1114,46 @@ class ContinuousBatchingEngine:
 
     def _decode_block_counters(self) -> dict:
         """Counters the ``serving.decode_block`` span carries besides the
-        engine's own (``steps``, ``slot_steps``): ``sampled_steps``; the
-        paged engine adds its kernel's page walk."""
-        return {"sampled_steps":
-                self._count_sampled_steps(self.decode_block)}
+        engine's own (``steps``, ``slot_steps``): ``sampled_steps``, and
+        the pages one layer's paged decode reads walk over the block about
+        to be dispatched, from the host's mirrors of ``pos`` and
+        ``remaining`` (no device fetch): at step k a slot's read is
+        ``pos + min(k, steps it stays live) + 1`` tokens long, and a dead
+        slot re-reads the length it was left at, through its zeroed table
+        row. ``ops.pallas.paged_attention.walk_counts`` mirrors the
+        kernel's loop bound. A slot that meets its EOS inside the block
+        is counted as if it ran to the block's end (at most one page a
+        block over). ``kv_pages_in_runs``: of the live pages, those the
+        walk moves 8 to a copy (``paged_attention.walk_run_pages``), from
+        each decoding slot's row as it was armed; a dead slot reads
+        through a zeroed row, page by page."""
+        from ..ops.pallas.paged_attention import walk_counts, walk_run_pages
+        sampled = self._count_sampled_steps(self.decode_block)
+        lengths, decoding = self._block_read_lengths()
+        live, copied, _ = walk_counts(lengths, self.max_blocks,
+                                      self.kv_block_size)
+        in_runs = walk_run_pages(self._run_groups,
+                                 np.where(decoding, lengths, 0),
+                                 self.kv_block_size)
+        self.kv_pages_live += live
+        self.kv_pages_copied += copied
+        self.kv_pages_in_runs += in_runs
+        ids = dict(sampled_steps=sampled, kv_pages_live=live,
+                   kv_pages_copied=copied, kv_pages_in_runs=in_runs,
+                   arm_ns=self._arm_ns)
+        self._arm_ns = 0
+        if self.cache_passes > 1:
+            ids["ut_steps"] = self.decode_block * self.cache_passes
+            self.ut_steps += ids["ut_steps"]
+        return ids
+
+    def _block_read_lengths(self):
+        """``(lengths, decoding)``, each ``(decode_block, slots)``: the
+        length of every slot's read at every step of the block about to
+        be dispatched, and whether the slot is still decoding there."""
+        k = np.arange(self.decode_block)[:, None]
+        stays = np.minimum(self._remaining_host, self.decode_block)[None, :]
+        return self._pos_host[None, :] + np.minimum(k, stays) + 1, k < stays
 
     def _count_sampled_steps(self, steps: int) -> int:
         """Of the ``steps`` decode steps about to be dispatched, those in
@@ -1062,15 +1170,42 @@ class ContinuousBatchingEngine:
         return n
 
     def _read_program_counters(self):
-        """What the model's programs counted into the cache (the paged
-        engine of a model that declares ``cache_counters``), fetched with
-        the block's other transfers; None where nothing is counted."""
-        return None
+        """What the model's programs counted into the cache (a model that
+        declares ``cache_counters``), fetched with the block's other
+        transfers; None where nothing is counted."""
+        return np.asarray(self._cache[-1]) if self._counter_names else None
+
+    def _credit_program_counters(self, counts_np):
+        """Fold the programs' counts into the engine's counters and onto
+        the spans of what ran: the decode row onto this block's
+        ``serving.decode_block``, the chunk row — every chunk since the
+        last read — onto the latest ``serving.prefill_chunk`` (with
+        ``chunks``, how many it speaks for). Sums are differences of
+        wrapping int32 totals; a maximum is the largest so far."""
+        spans = (("", self._block_span, {}),
+                 ("prefill_", self._chunk_span if self._chunks_unread
+                  else None, {"chunks": self._chunks_unread}))
+        now = counts_np.astype(np.int64)
+        for row, (prefix, span_, ids) in enumerate(spans):
+            for col, (name, fold) in enumerate(self._counter_names):
+                v = int(now[row, col])
+                if fold == "sum":
+                    v = (v - int(self._counts_seen[row, col])) % 2 ** 32
+                    setattr(self, prefix + name,
+                            getattr(self, prefix + name) + v)
+                else:
+                    setattr(self, prefix + name, v)
+                ids[name] = v
+            if span_ is not None:
+                span_.ids.update(ids)
+        self._counts_seen = now
+        self._chunks_unread = 0
 
     def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
         """The host half of a decode block: credit each live slot its
         emitted tokens, quarantine poisoned rows, retire finished
         slots."""
+        self._pos_host += lives_np.sum(axis=0)      # pos += live, per step
         emitted = int(lives_np.sum())
         self.decode_tokens += emitted
         self.tokens_emitted += emitted
@@ -1084,7 +1219,7 @@ class ContinuousBatchingEngine:
             n = int(lives_np[:, slot].sum())
             if n > 0:
                 run.tokens.extend(int(t) for t in toks_np[:n, slot])
-            if self.nan_sentinel and oks_np is not None and n > 0 \
+            if self.nan_sentinel and n > 0 \
                     and not bool(oks_np[:n, slot].all()):
                 self.cancel_slot(slot, "poisoned")
                 continue
@@ -1103,17 +1238,17 @@ class ContinuousBatchingEngine:
     def cancel_slot(self, slot: int, reason: str) -> bool:
         """Cancel the request in ``slot`` mid-flight: kill the slot
         in-graph (live drops before the next decode block), release its
-        resources (paged: arena blocks at correct refcounts, pending
-        prefill job dropped), and surface the run through
-        ``drain_finished`` with ``failure=reason`` so the Server records
-        a RequestFailure instead of hanging the stream."""
+        resources (arena blocks at correct refcounts, pending prefill job
+        dropped), and surface the run through ``drain_finished`` with
+        ``failure=reason`` so the Server records a RequestFailure instead
+        of hanging the stream."""
         run = self._slots[slot]
         if run is None:
             return False
         run.failure = reason
         if slot in self._prefill_slots:
             self._prefill_slots.discard(slot)
-            self._abort_prefill(slot)   # paged: drop the pending job
+            self._abort_prefill(slot)   # drop the pending job
         else:
             self._state = self._cancel_jit(self._state, jnp.int32(slot))
         self._retire(slot, run, time.perf_counter())
@@ -1121,31 +1256,65 @@ class ContinuousBatchingEngine:
         return True
 
     def _abort_prefill(self, slot):
-        """Dense admission is synchronous — nothing to abort."""
+        """Cancel a mid-prefill request: drop its pending job (the
+        chunk loop never sees it again); its blocks release through the
+        shared ``_retire`` path. The slot never armed, so there is no
+        in-graph state to kill."""
+        self._jobs = [j for j in self._jobs if j.slot != slot]
 
     def _poison_live_slot(self):
-        """Fault action for the ``serving.poison`` site: corrupt the
-        FIRST decoding slot's KV cache row with NaN so its next logits
-        trip the sentinel. Only that slot's row is touched — the
-        quarantine-blast-radius invariant the chaos tests pin."""
+        """Fault action for the ``serving.poison`` site: NaN the arena
+        block holding the first decoding slot's
+        position ``pos-1``. That block is always (a) within the slot's
+        attended range, so the sentinel trips on the very next step,
+        and (b) a FRESH block owned only by this slot — its index
+        ``(pos-1)//bs >= (L-1)//bs`` sits past both the shared-prefix
+        and the registered range, so no other slot (and no future
+        prefix match) can ever read the poison."""
         for slot, run in enumerate(self._slots):
             if run is not None and slot not in self._prefill_slots:
+                L = int(np.asarray(run.request.prompt).reshape(-1)
+                        .shape[0])
+                pos = L + len(run.tokens) - 1
+                blk = run.block_ids[(pos - 1) // self.kv_block_size]
                 self._cache = tuple(
-                    c.at[slot].set(jnp.nan)
+                    c.at[blk].set(jnp.nan)
                     if jnp.issubdtype(c.dtype, jnp.floating) else c
                     for c in self._cache)
                 return slot
         return None
 
     def _retire(self, slot, run, now):
-        """Move a finished slot to the harvest list (the paged engine
-        also releases the slot's arena blocks here)."""
+        """Move a finished slot to the harvest list and release its arena
+        blocks."""
         run.t_done = now
         if self.tracer is not None:
             self.tracer.span_end(run.request.request_id, "decode",
                                  tokens=len(run.tokens))
         self._finished.append(run)
         self._free_slot(slot)
+        if run.block_ids is not None:
+            if run.failure is None and run.tokens:
+                # decode-time block sharing: every position the stream
+                # WROTE is resident — prompt plus generated history
+                # minus the final sampled token (never written). Extend
+                # the digest chain over the fully-written blocks so a
+                # later request continuing this conversation shares the
+                # decode-position KV too. Failed/poisoned runs register
+                # NOTHING (a poisoned block must never be matchable).
+                # The run's chain already holds the prompt's shareable
+                # blocks: only the blocks written since are hashed.
+                seq = np.concatenate([
+                    np.asarray(run.request.prompt, np.int32).reshape(-1),
+                    np.asarray(run.tokens[:-1], np.int32)])
+                self._register_written(
+                    run, self.manager.extend_chain(
+                        run.chain, seq, len(seq) // self.kv_block_size))
+            self._release_slot_resources(run)
+
+    def _register_written(self, run, chain):
+        """Index the fully-written blocks of a finished stream."""
+        self.manager.register_chain(chain, run.block_ids)
 
     def drain_finished(self) -> List[_SlotRun]:
         done, self._finished = self._finished, []
@@ -1173,8 +1342,8 @@ class ContinuousBatchingEngine:
     def snapshot_state(self):
         """(meta dict, host-array dict) capturing everything needed to
         resume every in-flight stream: the KV cache, the in-graph slot
-        state (positions, rng keys, sampling params — and the paged
-        block tables riding it), and the host bookkeeping. Taken at a
+        state (positions, rng keys, sampling params and the block
+        tables riding it), and the host bookkeeping. Taken at a
         tick boundary (the only host-consistent point); a restored
         engine finishes each stream bit-identical to an uninterrupted
         run because the decode program is a pure function of exactly
@@ -1221,6 +1390,43 @@ class ContinuousBatchingEngine:
                          "decode_tokens": self.decode_tokens,
                          "slot_steps": self.slot_steps},
         }
+        m = self.manager
+        meta["manager"] = {
+            "num_blocks": m.num_blocks, "block_size": m.block_size,
+            "free": list(m._free),
+            "ref": [[int(b), int(r)] for b, r in m._ref.items()],
+            "digest_of": [[int(b), d.hex()]
+                          for b, d in m._digest_of.items()],
+            "index": [[d.hex(), int(bid), [int(t) for t in chunk]]
+                      for d, (bid, chunk) in m._index.items()],
+            "cached": [int(b) for b in m._cached],   # LRU order
+            "lookups": m.lookups, "hit_blocks": m.hit_blocks,
+            "depth": [[d.hex(), int(n)] for d, n in m._depth.items()],
+            "evictions": m.evictions,
+            "hits": [[int(b), int(h)] for b, h in m._hits.items()],
+        }
+        jobs_meta = []
+        for j, job in enumerate(self._jobs):
+            arrays[f"job{j}_prompt"] = np.asarray(job.prompt, np.int32)
+            arrays[f"job{j}_table"] = np.asarray(job.table_row, np.int32)
+            arrays[f"job{j}_key"] = np.asarray(job.key)
+            arrays[f"job{j}_sub"] = np.asarray(job.sub)
+            jobs_meta.append({
+                "slot": job.slot, "done": job.done,
+                "temp": float(job.temp), "topk": int(job.topk),
+                "topp": float(job.topp), "tok0": job.tok0,
+                "resume_tok": job.resume_tok})
+        meta["jobs"] = jobs_meta
+        meta["paged_counters"] = {
+            "prompt_tokens": self.prompt_tokens,
+            "shared_tokens": self.shared_tokens,
+            "prefilled_tokens": self.prefilled_tokens,
+            "prefill_chunks": self.prefill_chunks,
+            "fetched_tokens": self.fetched_tokens,
+            "kv_pages_live": self.kv_pages_live,
+            "kv_pages_copied": self.kv_pages_copied,
+            "kv_pages_in_runs": self.kv_pages_in_runs,
+            "ut_steps": self.ut_steps}
         return meta, arrays
 
     def restore_state(self, meta: dict, arrays: dict):
@@ -1240,7 +1446,7 @@ class ContinuousBatchingEngine:
         if meta["engine_class"] != type(self).__name__:
             raise ValueError(
                 f"snapshot was taken by {meta['engine_class']}, this "
-                f"engine is {type(self).__name__} (dense/paged mismatch)")
+                f"engine is {type(self).__name__}")
         saved_fp = meta.get("backend_artifact")
         cur_fp = getattr(self.backend, "artifact_fingerprint", None)
         if saved_fp is not None and cur_fp is not None \
@@ -1273,6 +1479,58 @@ class ContinuousBatchingEngine:
         self.tokens_emitted = c["tokens_emitted"]
         self.decode_tokens = c["decode_tokens"]
         self.slot_steps = c["slot_steps"]
+        mm = meta["manager"]
+        m = self.manager
+        if (mm["num_blocks"], mm["block_size"]) != (m.num_blocks,
+                                                   m.block_size):
+            raise ValueError(
+                f"snapshot arena {mm['num_blocks']}x{mm['block_size']} "
+                f"does not match this engine's "
+                f"{m.num_blocks}x{m.block_size}")
+        m._free = list(mm["free"])
+        m._ref = {int(b): int(r) for b, r in mm["ref"]}
+        m._digest_of = {int(b): bytes.fromhex(d)
+                        for b, d in mm["digest_of"]}
+        m._index = {bytes.fromhex(d): (int(bid), tuple(chunk))
+                    for d, bid, chunk in mm["index"]}
+        m._cached = OrderedDict((int(b), None) for b in mm["cached"])
+        m.lookups, m.hit_blocks = mm["lookups"], mm["hit_blocks"]
+        m._depth = {bytes.fromhex(d): int(n)
+                    for d, n in mm.get("depth", [])}
+        m._depth = {d: n for d, n in m._depth.items()
+                    if d in m._index}
+        m.evictions = int(mm.get("evictions", 0))
+        m._hits = {int(b): int(h) for b, h in mm.get("hits", [])
+                   if int(b) in m._digest_of}
+        m._reindex_cached()
+        m.assert_consistent()
+        self._jobs = []
+        for j, jm in enumerate(meta["jobs"]):
+            run = self._slots[jm["slot"]]
+            self._jobs.append(_PrefillJob(
+                run=run, slot=jm["slot"],
+                prompt=np.asarray(arrays[f"job{j}_prompt"], np.int32),
+                done=jm["done"],
+                table_row=np.asarray(arrays[f"job{j}_table"], np.int32),
+                key=jnp.asarray(arrays[f"job{j}_key"]),
+                sub=jnp.asarray(arrays[f"job{j}_sub"]),
+                temp=jnp.float32(jm["temp"]),
+                topk=jnp.int32(jm["topk"]),
+                topp=jnp.float32(jm["topp"]), tok0=jm["tok0"],
+                resume_tok=jm.get("resume_tok")))
+        pc = meta["paged_counters"]
+        self.prompt_tokens = pc["prompt_tokens"]
+        self.shared_tokens = pc["shared_tokens"]
+        self.prefilled_tokens = pc["prefilled_tokens"]
+        self.prefill_chunks = pc["prefill_chunks"]
+        self.fetched_tokens = pc.get("fetched_tokens", 0)
+        self.kv_pages_live = pc.get("kv_pages_live", 0)
+        self.kv_pages_copied = pc.get("kv_pages_copied", 0)
+        self.kv_pages_in_runs = pc.get("kv_pages_in_runs", 0)
+        self.ut_steps = pc.get("ut_steps", 0)
+        self._pos_host = np.asarray(self._state["pos"]).astype(np.int64)
+        self._run_groups = self._table_run_groups(
+            np.asarray(self._state["table"]))
 
     def snapshot(self, path: str):
         """Write a crash-safe engine snapshot (single npz file, atomic

@@ -7,8 +7,8 @@ at production scale they want different hardware pools. This module
 splits the Server into replicas of two specialties and a router:
 
 - **PrefillWorker**: a Server over a prefill-only engine
-  (:class:`PrefillDenseEngine` / :class:`PrefillPagedEngine`). Prompts
-  admit, (chunked-)prefill and sample their first token exactly as on
+  (:class:`PrefillPagedEngine`). Prompts
+  admit, chunk-prefill and sample their first token exactly as on
   a unified server — same programs, same key schedule — but a finished
   prefill parks in a handoff **outbox** instead of arming the slot.
   The slot and its arena blocks stay held until the payload ships, so
@@ -22,12 +22,12 @@ splits the Server into replicas of two specialties and a router:
   refcounts, scatters the shipped rows into its arena through ONE
   fixed-shape jitted program (padded to ``max_blocks``; pad rows land
   in the trash block), registers the prompt prefix in its own index,
-  and arms the slot through the engine's EXISTING arm/admit program —
+  and arms the slot through the engine's EXISTING arm program —
   zero new compiled programs on the decode steady path, decode compile
   count stays 1. A request prefilled on worker A and decoded on worker
   B streams BIT-IDENTICAL to a single-replica Server (greedy and
-  seeded-sampled; dense, paged, paged+kv_int8) because the decode
-  block is a pure function of exactly the adopted state.
+  seeded-sampled; fp32 and kv_int8 arenas) because the decode block is
+  a pure function of exactly the adopted state.
 - **FleetRouter**: chained-SHA1 prefix-hash affinity — the digest of a
   prompt's first full block (the same key the BlockManager indexes it
   under) picks the prefill worker, so a tenant's system prompt lands
@@ -60,7 +60,7 @@ splits the Server into replicas of two specialties and a router:
   pool mid-stream; ``drain_prefill_worker`` stops routing to a worker
   so it can retire cleanly.
 
-- **Fleet-wide prefix cache** (PR 16, serving/prefix_cache.py): paged
+- **Fleet-wide prefix cache** (PR 16, serving/prefix_cache.py):
   workers publish their registered digest chains with each heartbeat
   into a :class:`~paddle_tpu.serving.prefix_cache.
   PrefixCacheDirectory`; on a prefill-admission miss where the
@@ -77,7 +77,7 @@ splits the Server into replicas of two specialties and a router:
 Knobs (utils/flags helpers): ``PT_SERVING_FLEET_AFFINITY`` (default
 on), ``PT_SERVING_FLEET_SPILL_DEPTH`` (default 8),
 ``PT_SERVING_FLEET_LEASE_MISSES`` (default 3 missed heartbeats),
-``PT_SERVING_FLEET_PREFIX_CACHE`` (default on, paged fleets) and the
+``PT_SERVING_FLEET_PREFIX_CACHE`` (default on) and the
 eviction watermarks ``PT_SERVING_FLEET_EVICT_HIGH`` / ``_LOW``
 (default 0.85 / 0.70 of fleet-global block pressure).
 """
@@ -86,7 +86,7 @@ from __future__ import annotations
 import os
 import time
 from collections import deque
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Optional
 
 import jax
@@ -98,10 +98,9 @@ from ..observability import metrics as _om
 from ..utils import faults
 from ..utils.flags import env_bool, env_float, env_int
 from . import durability as _dur
-from .engine import (ContinuousBatchingEngine, _M_PREFILLS, _M_TOKENS,
-                     _SlotRun)
+from .engine import ContinuousBatchingEngine, _M_TOKENS, _SlotRun
 from .handoff import KVHandoff, decode_handoff, encode_handoff
-from .paging import PagedEngine, _sha1_chain, refuse_looped_cache
+from .paging import _sha1_chain, refuse_looped_cache
 from . import prefix_cache as _pc
 from .prefix_cache import (PrefixCacheDirectory, _adopt_scatter,
                            adopt_prefix, extract_prefix)
@@ -114,8 +113,8 @@ from .transport import (InProcessTransport, SocketTransport, Transport,
                         TransportError, fetch_endpoint)
 
 __all__ = ["DecodeWorker", "Fleet", "FleetRouter", "InProcessTransport",
-           "PrefillDenseEngine", "PrefillPagedEngine", "PrefillWorker",
-           "SocketTransport", "Transport", "TransportError"]
+           "PrefillPagedEngine", "PrefillWorker", "SocketTransport",
+           "Transport", "TransportError"]
 
 # fleet metric families (registered at import so the catalog stays
 # complete at zero; no-ops until metrics.enable()/PT_METRICS)
@@ -203,7 +202,7 @@ def _stamp_resume_meta(meta: dict, ph: "_PendingHandoff"):
 @dataclass
 class _PendingHandoff:
     """One finished prefill waiting to ship. The slot stays occupied
-    (in ``_prefill_slots``, so it never decodes) and paged blocks stay
+    (in ``_prefill_slots``, so it never decodes) and its blocks stay
     referenced until the payload is on the wire — a serialize or
     transport fault retries against state that is still alive."""
     run: _SlotRun
@@ -212,9 +211,6 @@ class _PendingHandoff:
     tok0: int
     rem0: int
     key: np.ndarray                     # (2,) uint32 post-split key
-    row: Optional[tuple] = None         # dense: prefilled cache row
-    pad0: int = 0                       # dense: bucket pad count
-    bucket: int = 0                     # dense: bucket length Lb
     # redrive resume: the carried generated history (tokens[-1] ==
     # tok0) and the ORIGINAL prompt length — ``prompt`` above is then
     # prompt+tokens[:-1], the re-prefilled sequence
@@ -222,8 +218,13 @@ class _PendingHandoff:
     orig_len: Optional[int] = None
 
 
-class _PrefillEngineMixin:
-    """Outbox plumbing shared by the dense and paged prefill engines."""
+class PrefillPagedEngine(ContinuousBatchingEngine):
+    """The engine, prefilling but never decoding: chunked prefill,
+    prefix reuse and the block manager are inherited unchanged; a
+    finished prefill parks in the handoff outbox with its blocks still
+    referenced instead of arming the slot. Requests that finish AT
+    prefill (eos on the first token, max_new==1) complete here — no
+    decode worker ever sees them."""
 
     def reset(self):
         super().reset()
@@ -241,10 +242,10 @@ class _PrefillEngineMixin:
 
     def release_handoff(self, ph: _PendingHandoff):
         """Free everything a shipped (or permanently failed) handoff
-        held on this worker: the slot, and — paged — its arena blocks
-        at exact refcounts (registered prefix blocks park in the LRU
-        cache, which is what keeps the worker's prefix index hot for
-        the next same-prefix arrival)."""
+        held on this worker: the slot, and its arena blocks at exact
+        refcounts (registered prefix blocks park in the LRU cache, which
+        is what keeps the worker's prefix index hot for the next
+        same-prefix arrival)."""
         self._prefill_slots.discard(ph.slot)
         if self._slots[ph.slot] is ph.run:
             self._slots[ph.slot] = None
@@ -266,14 +267,9 @@ class _PrefillEngineMixin:
             k = len(ob_meta)
             arrays[f"ob{k}_prompt"] = np.asarray(ph.prompt, np.int32)
             arrays[f"ob{k}_key"] = np.asarray(ph.key, np.uint32)
-            if ph.row is not None:
-                for i, r in enumerate(ph.row):
-                    arrays[f"ob{k}_row{i}"] = np.asarray(r)
             ob_meta.append({
                 "slot": int(ph.slot), "tok0": int(ph.tok0),
-                "rem0": int(ph.rem0), "pad0": int(ph.pad0),
-                "bucket": int(ph.bucket),
-                "row": ph.row is not None,
+                "rem0": int(ph.rem0),
                 "tokens": None if ph.tokens is None
                 else [int(t) for t in ph.tokens],
                 "orig_len": None if ph.orig_len is None
@@ -284,32 +280,17 @@ class _PrefillEngineMixin:
     def restore_state(self, meta, arrays):
         super().restore_state(meta, arrays)
         self._outbox = []
-        n_leaves = len(self.backend.pool_specs)
         for k, e in enumerate(meta.get("outbox", ())):
             run = self._slots[e["slot"]]
             if run is None:
                 continue
-            row = None
-            if e["row"]:
-                row = tuple(np.asarray(arrays[f"ob{k}_row{i}"])
-                            for i in range(n_leaves))
             self._outbox.append(_PendingHandoff(
                 run=run, slot=int(e["slot"]),
                 prompt=np.asarray(arrays[f"ob{k}_prompt"], np.int32),
                 tok0=int(e["tok0"]), rem0=int(e["rem0"]),
                 key=np.asarray(arrays[f"ob{k}_key"], np.uint32),
-                row=row, pad0=int(e["pad0"]), bucket=int(e["bucket"]),
                 tokens=e["tokens"],
                 orig_len=e["orig_len"]))
-
-
-class PrefillPagedEngine(_PrefillEngineMixin, PagedEngine):
-    """Paged engine that prefills but never decodes: chunked prefill,
-    prefix reuse and the block manager are inherited unchanged; a
-    finished prefill parks in the handoff outbox with its blocks still
-    referenced instead of arming the slot. Requests that finish AT
-    prefill (eos on the first token, max_new==1) complete here — no
-    decode worker ever sees them."""
 
     def try_admit(self, request) -> bool:
         resume = getattr(request, "resume", None)
@@ -319,7 +300,7 @@ class PrefillPagedEngine(_PrefillEngineMixin, PagedEngine):
                 "prefill workers do not take preemption resumes — the "
                 "fleet never preempts (route resumes to a unified "
                 "Server)")
-        # a redrive resume rides the PR 13 paged resume branch
+        # a redrive resume rides the PR 13 resume branch
         # unchanged: chunked re-prefill of prompt+tokens[:-1] (mostly
         # prefix-index hits for shared prompts), carried key armed,
         # the chunk programs' in-graph samples discarded
@@ -402,142 +383,6 @@ class PrefillPagedEngine(_PrefillEngineMixin, PagedEngine):
             "tok0": ph.tok0, "pos0": L, "rem0": ph.rem0,
             "n_blocks": len(ph.run.block_ids), "n_ship": n_ship,
             "block_size": bs, "kv_int8": bool(self.kv_int8),
-            "leaf_specs": _leaf_specs(self.backend),
-            "t_admit": float(ph.run.t_admit),
-            "source": {"worker": source,
-                       "tp_degree": self.tp_degree()},
-        }
-        _stamp_resume_meta(meta, ph)
-        return KVHandoff(meta=meta, arrays=arrays)
-
-
-class PrefillDenseEngine(_PrefillEngineMixin, ContinuousBatchingEngine):
-    """Dense engine that prefills but never decodes. Admission runs the
-    SAME bucket prefill + key schedule as the unified dense engine
-    (``key = PRNGKey(seed); key, sub = split(key)``; ``sub`` samples
-    the first token, ``key`` arms the slot), but the prefilled row
-    parks in the outbox instead of splicing into the pool."""
-
-    def admit(self, request) -> bool:
-        from ..profiler import RecordEvent
-        resume = getattr(request, "resume", None)
-        if resume is not None and resume.tokens:
-            if not resume.redrive:
-                raise NotImplementedError(
-                    "prefill workers do not take preemption resumes — "
-                    "the fleet never preempts (route resumes to a "
-                    "unified Server)")
-            return self._admit_redrive(request, resume)
-        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
-        L = int(prompt.shape[0])
-        self.validate_request(L, request.max_new_tokens)
-        Lb = self.bucket_len(L)
-        slot = next((i for i, s in enumerate(self._slots) if s is None),
-                    None)
-        if slot is None:
-            raise RuntimeError("no free slot (scheduler bug)")
-        tr = self.tracer
-        if tr is not None:
-            tr.span_end(request.request_id, "queue_wait")
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, Lb - L:] = prompt
-        pad0 = Lb - L
-        key = jax.random.PRNGKey(request.seed)
-        key, sub = jax.random.split(key)     # generate()'s key schedule
-        with RecordEvent("serving.prefill"):
-            tok0_dev, row = self.backend.prefill(
-                Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
-                sub, jnp.float32(request.temperature),
-                jnp.int32(request.top_k), jnp.float32(request.top_p))
-        tok0 = int(tok0_dev)
-        _M_PREFILLS.inc()
-        _M_TOKENS.inc()
-        run = _SlotRun(request, tokens=[tok0],
-                       t_admit=time.perf_counter())
-        self.tokens_emitted += 1
-        eos = request.eos_token_id
-        rem0 = request.max_new_tokens - 1
-        if eos is not None and tok0 == eos:
-            rem0 = 0
-        if rem0 <= 0:                        # finished at admission
-            run.t_done = time.perf_counter()
-            self._finished.append(run)
-            return True
-        self._slots[slot] = run
-        self._prefill_slots.add(slot)        # occupied, never decoding
-        self._outbox.append(_PendingHandoff(
-            run=run, slot=slot, prompt=prompt, tok0=tok0, rem0=rem0,
-            key=np.asarray(key, np.uint32), row=row, pad0=pad0,
-            bucket=Lb))
-        return False
-
-    def _admit_redrive(self, request, resume) -> bool:
-        """Redrive re-prefill, dense flavour: prompt + tokens[:-1]
-        left-padded to its bucket, the in-graph sample DISCARDED (the
-        stream owns its next token and the carried key must not be
-        advanced), the prefilled row parked in the outbox with the
-        carried history — the mirror of the unified engine's
-        ``_admit_resume`` with the arm replaced by a handoff."""
-        from ..profiler import RecordEvent
-        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
-        toks = list(resume.tokens)
-        full = np.concatenate([prompt, np.asarray(toks[:-1], np.int32)])
-        pl = int(full.shape[0])
-        rem0 = request.max_new_tokens - len(toks)
-        self.validate_request(pl, rem0 + 1)
-        Lb = self.bucket_len(pl)
-        slot = next((i for i, s in enumerate(self._slots) if s is None),
-                    None)
-        if slot is None:
-            raise RuntimeError("no free slot (scheduler bug)")
-        if self.tracer is not None:
-            self.tracer.span_end(request.request_id, "queue_wait",
-                                 resumed=True, redrive=True)
-        ids = np.zeros((1, Lb), np.int32)
-        ids[0, Lb - pl:] = full
-        pad0 = Lb - pl
-        with RecordEvent("serving.prefill"):
-            _discard, row = self.backend.prefill(
-                Lb, jnp.asarray(ids), jnp.asarray([pad0], jnp.int32),
-                jax.random.PRNGKey(0), jnp.float32(0.0), jnp.int32(0),
-                jnp.float32(1.0))
-        _M_PREFILLS.inc()
-        run = _SlotRun(request, tokens=toks, t_admit=resume.t_admit)
-        request.resume = None
-        if rem0 <= 0:                        # defensive: already done
-            run.t_done = time.perf_counter()
-            self._finished.append(run)
-            return True
-        self._slots[slot] = run
-        self._prefill_slots.add(slot)
-        if self.tracer is not None:
-            self.tracer.instant(request.request_id, "resume",
-                                slot=slot, redrive=True,
-                                reused_tokens=len(toks))
-        self._outbox.append(_PendingHandoff(
-            run=run, slot=slot, prompt=full, tok0=int(toks[-1]),
-            rem0=rem0, key=np.asarray(resume.key, np.uint32), row=row,
-            pad0=pad0, bucket=Lb, tokens=toks,
-            orig_len=int(prompt.shape[0])))
-        return False
-
-    def extract_handoff(self, ph: _PendingHandoff,
-                        source: str = "") -> KVHandoff:
-        """Dense payload: the populated row prefix ``[:, :Lb]``. The
-        row beyond the bucket is zeros by construction (prefill starts
-        from a zero row), so shipping the prefix and zero-filling on
-        adopt reconstructs the row EXACTLY — bit-identity needs no
-        junk bytes on the wire."""
-        Lb = ph.bucket
-        arrays = {"prompt": np.asarray(ph.prompt, np.int32),
-                  "key": np.asarray(ph.key, np.uint32)}
-        for i, r in enumerate(ph.row):
-            arrays[f"kv_{i}"] = np.asarray(r[:, :Lb])
-        req = ph.run.request
-        meta = {
-            "kind": "dense", "request": request_to_meta(req),
-            "tok0": ph.tok0, "pos0": Lb, "pad0": ph.pad0,
-            "rem0": ph.rem0,
             "leaf_specs": _leaf_specs(self.backend),
             "t_admit": float(ph.run.t_admit),
             "source": {"worker": source,
@@ -632,12 +477,10 @@ class PrefillWorker:
     def __init__(self, engine, *, name: str = "",
                  scheduler=None, resilience=None, observability=None,
                  server: Optional[Server] = None):
-        if not isinstance(engine, (PrefillDenseEngine,
-                                   PrefillPagedEngine)):
+        if not isinstance(engine, PrefillPagedEngine):
             raise ValueError(
                 "PrefillWorker needs a prefill-only engine "
-                "(PrefillDenseEngine / PrefillPagedEngine); got "
-                f"{type(engine).__name__}")
+                f"(PrefillPagedEngine); got {type(engine).__name__}")
         refuse_looped_cache(engine, "the fleet's hand-off and prefix tier")
         self.engine = engine
         self.name = name
@@ -654,11 +497,10 @@ class PrefillWorker:
             return None
         hb = {"queue_depth": self.server.scheduler.pending(),
               "occupancy": self.engine.occupancy(),
-              "outbox": len(self.engine._outbox)}
-        if isinstance(self.engine, PagedEngine):
-            # the prefix-directory publish: heartbeat-shaped, so
-            # directory state rides the lease machinery for free
-            hb["prefixes"] = self.engine.manager.registered_chains()
+              "outbox": len(self.engine._outbox),
+              # the prefix-directory publish: heartbeat-shaped, so
+              # directory state rides the lease machinery for free
+              "prefixes": self.engine.manager.registered_chains()}
         return hb
 
     def queue_depth(self) -> int:
@@ -690,7 +532,7 @@ class DecodeWorker:
 
     def __init__(self, engine, *, name: str = "", resilience=None,
                  observability=None, server: Optional[Server] = None):
-        if isinstance(engine, (PrefillDenseEngine, PrefillPagedEngine)):
+        if isinstance(engine, PrefillPagedEngine):
             raise ValueError("DecodeWorker needs a decoding engine, "
                              "not a prefill-only one")
         if getattr(engine, "window_manager", None) is not None:
@@ -743,12 +585,11 @@ class DecodeWorker:
             "free_slots": self.engine.free_slot_count(),
             "progress": {run.request.request_id: list(run.tokens)
                          for _slot, run in self.engine.live_runs()},
-        }
-        if isinstance(self.engine, PagedEngine):
             # decode workers publish too: adopted prompts and
             # decode-time-shared completed sequences are fetchable
             # warm state like any prefill worker's
-            hb["prefixes"] = self.engine.manager.registered_chains()
+            "prefixes": self.engine.manager.registered_chains(),
+        }
         return hb
 
     # -- capacity ----------------------------------------------------------
@@ -766,12 +607,9 @@ class DecodeWorker:
     # -- adoption ----------------------------------------------------------
     def _validate(self, h: KVHandoff):
         eng = self.engine
-        paged = isinstance(eng, PagedEngine)
-        want_kind = "paged" if paged else "dense"
-        if h.kind != want_kind:
+        if h.kind != "paged":
             raise ValueError(
-                f"{h.kind} handoff cannot adopt into a {want_kind} "
-                "engine")
+                f"a {h.kind!r} payload cannot adopt as a stream")
         specs = _leaf_specs(eng.backend)
         if h.meta["leaf_specs"] != specs:
             raise ValueError(
@@ -779,8 +617,8 @@ class DecodeWorker:
                 f"(payload {h.meta['leaf_specs'][:2]}..., engine "
                 f"{specs[:2]}...) — same model config / paging layout "
                 "required")
-        if paged and (h.meta["block_size"] != eng.kv_block_size
-                      or bool(h.meta["kv_int8"]) != bool(eng.kv_int8)):
+        if h.meta["block_size"] != eng.kv_block_size \
+                or bool(h.meta["kv_int8"]) != bool(eng.kv_int8):
             raise ValueError(
                 "handoff arena geometry mismatch (block_size/kv_int8)")
         if h.meta["pos0"] + h.meta["rem0"] > eng.max_len:
@@ -827,11 +665,7 @@ class DecodeWorker:
                     None)
         if slot is None:
             return self.DEFER
-        if isinstance(eng, PagedEngine):
-            ok = self._adopt_paged(h, slot)
-        else:
-            ok = self._adopt_dense(h, slot)
-        if not ok:
+        if not self._adopt_blocks(h, slot):
             return self.DEFER
         if seq is not None:
             self._adopted.add((rid, seq))
@@ -867,7 +701,7 @@ class DecodeWorker:
         toks = [int(t) for t in meta.get("tokens", [meta["tok0"]])]
         return req, toks
 
-    def _adopt_paged(self, h: KVHandoff, slot: int) -> bool:
+    def _adopt_blocks(self, h: KVHandoff, slot: int) -> bool:
         eng = self.engine
         meta = h.meta
         prompt = h.arrays["prompt"]
@@ -904,33 +738,6 @@ class DecodeWorker:
                  jnp.int32(req.top_k), jnp.float32(req.top_p),
                  jnp.asarray(np.asarray(h.arrays["key"], np.uint32)))
         self._commit()
-        return True
-
-    def _adopt_dense(self, h: KVHandoff, slot: int) -> bool:
-        eng = self.engine
-        meta = h.meta
-        prompt = h.arrays["prompt"]
-        req, toks = self._carried(meta, prompt)
-        Lb = meta["pos0"]
-        row = []
-        for i, (shape, dtype) in enumerate(eng.backend.pool_specs):
-            r = np.zeros((1,) + tuple(shape[1:]), np.dtype(dtype))
-            r[:, :Lb] = h.arrays[f"kv_{i}"]
-            row.append(r)
-        eos = req.eos_token_id
-        # the stock admission program: zero new compiled programs
-        eng._cache, eng._state = eng._admit_jit(
-            eng._cache, eng._state, tuple(row), jnp.int32(slot),
-            jnp.int32(meta["tok0"]), jnp.int32(Lb),
-            jnp.int32(meta["pad0"]), jnp.int32(meta["rem0"]),
-            jnp.int32(-1 if eos is None else eos),
-            jnp.float32(req.temperature), jnp.int32(req.top_k),
-            jnp.float32(req.top_p),
-            jnp.asarray(np.asarray(h.arrays["key"], np.uint32)))
-        self._commit()
-        run = _SlotRun(req, tokens=toks, t_admit=meta["t_admit"])
-        eng._slots[slot] = run
-        eng._remaining_host[slot] = meta["rem0"]
         return True
 
 
@@ -1005,9 +812,8 @@ class Fleet:
                 "counters, so they must be unique")
         self._check_compat()
         self.transport = transport or InProcessTransport()
-        paged = isinstance(self.prefill[0].engine, PagedEngine)
         self.router = FleetRouter(
-            self.prefill[0].engine.kv_block_size if paged else 16,
+            self.prefill[0].engine.kv_block_size,
             affinity=affinity, spill_depth=spill_depth)
         self.resilience = resilience or ResilienceConfig()
         self._res = ResilienceState(self.resilience)
@@ -1050,7 +856,7 @@ class Fleet:
             raise ValueError(
                 f"eviction watermarks need 0 < low <= high <= 1; got "
                 f"low={evict_low}, high={evict_high}")
-        self.prefix_cache_enabled = bool(prefix_cache) and paged
+        self.prefix_cache_enabled = bool(prefix_cache)
         self.evict_high, self.evict_low = float(evict_high), \
             float(evict_low)
         self.directory = PrefixCacheDirectory()
@@ -1142,10 +948,6 @@ class Fleet:
 
     @staticmethod
     def _check_engine_compat(e, first):
-        paged0 = isinstance(first, PagedEngine)
-        if isinstance(e, PagedEngine) != paged0:
-            raise ValueError("mixed dense/paged fleet — every "
-                             "worker must share the engine kind")
         if e.max_len != first.max_len:
             raise ValueError(
                 f"max_len mismatch across the fleet "
@@ -1154,10 +956,10 @@ class Fleet:
             raise ValueError(
                 "KV leaf layout mismatch across the fleet — same "
                 "model config / paging layout required")
-        if paged0 and (e.kv_block_size != first.kv_block_size
-                       or bool(e.kv_int8) != bool(first.kv_int8)):
+        if e.kv_block_size != first.kv_block_size \
+                or bool(e.kv_int8) != bool(first.kv_int8):
             raise ValueError(
-                "paged arena geometry mismatch across the fleet "
+                "arena geometry mismatch across the fleet "
                 "(block_size/kv_int8)")
 
     # -- submission --------------------------------------------------------
@@ -1938,8 +1740,7 @@ class Fleet:
         wi = self.router.route(rec["prompt"], depths, eligible)
         w = self.prefill[wi]
         try:
-            # the re-prefill must fit the TARGET engine (dense: the
-            # history may outgrow the original bucket)
+            # the re-prefill must fit the TARGET engine
             w.engine.validate_request(pl, mnt)
         except ValueError as e:
             self._fail_handoff(rid, "worker_lost",
@@ -2030,12 +1831,9 @@ class Fleet:
 
     def prefix_hit_rate(self) -> float:
         """Fleet-wide prefix-cache hit rate: shared / submitted prompt
-        tokens summed over every prefill worker (0.0 on dense fleets,
-        which have no prefix index)."""
-        pt = sum(getattr(w.engine, "prompt_tokens", 0)
-                 for w in self.prefill)
-        st = sum(getattr(w.engine, "shared_tokens", 0)
-                 for w in self.prefill)
+        tokens summed over every prefill worker."""
+        pt = sum(w.engine.prompt_tokens for w in self.prefill)
+        st = sum(w.engine.shared_tokens for w in self.prefill)
         return st / pt if pt else 0.0
 
     def stats(self) -> dict:
@@ -2100,10 +1898,8 @@ class Fleet:
                  "queue": w.queue_depth(),
                  "tokens_emitted": w.engine.tokens_emitted,
                  "block_pressure": round(
-                     w.engine.manager.block_pressure(), 4)
-                 if hasattr(w.engine, "manager") else 0.0,
-                 "prefill_compiles": w.engine.prefill_compile_count()
-                 if hasattr(w.engine, "prefill_compile_count") else 1}
+                     w.engine.manager.block_pressure(), 4),
+                 "prefill_compiles": w.engine.prefill_compile_count()}
                 for w in self.prefill],
             "decode_workers": [
                 {"name": d.name, "state": self._health[d.name]["state"],
@@ -2111,8 +1907,7 @@ class Fleet:
                  "draining": d.name in self._draining_decode,
                  "tokens_emitted": d.engine.tokens_emitted,
                  "block_pressure": round(
-                     d.engine.manager.block_pressure(), 4)
-                 if hasattr(d.engine, "manager") else 0.0,
+                     d.engine.manager.block_pressure(), 4),
                  "decode_compiles": d.engine.decode_compile_count()}
                 for d in self.decode],
         }

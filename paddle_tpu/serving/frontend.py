@@ -25,20 +25,19 @@ layer in front of it for mixed production traffic:
   ``max_queued`` quotas shed at submit, composing with the PR 5
   bounded-queue/deadline machinery.
 - **Priority preemption** (policy in server.py, mechanism in
-  engine.py/paging.py): a strictly-higher-priority request that would
+  engine.py): a strictly-higher-priority request that would
   otherwise wait evicts a low-priority slot mid-decode — in-graph slot
   kill through the same ``_cancel_fn`` program deadlines use, paged
   blocks released at exact refcounts with their prefix-index entries
   RETAINED. The victim requeues carrying
   :class:`~.scheduler.ResumeState` (generated tokens + the slot's rng
   key + the original TTFT stamp) and later re-admits via chunked
-  re-prefill of its history — mostly prefix-cache hits on the paged
-  engine — arming with the carried key so the resumed greedy AND
+  re-prefill of its history — mostly prefix-cache hits — arming with the carried key so the resumed greedy AND
   seeded-sampled streams are bit-identical to an uninterrupted run.
   Preempt/resume are span events on the request trace, never
   terminals; decode/prefill compile counts stay pinned at 1 (resume
-  reuses the ONE decode block and the existing chunked-prefill/bucket
-  prefill programs — no new compiled programs).
+  reuses the ONE decode block and the existing chunked-prefill
+  program — no new compiled programs).
 """
 from __future__ import annotations
 

@@ -1,10 +1,10 @@
 """KV-block handoff wire format for the disaggregated serving fleet.
 
 A prefill worker that finishes a prompt owns exactly the state a decode
-worker needs to continue the stream: the slot's paged KV blocks (or the
-dense cache row), the in-hand first token, the post-split rng key, the
-remaining token budget and the request itself. This module defines that
-payload as a **versioned, bytes-true wire format**:
+worker needs to continue the stream: the slot's KV blocks, the in-hand
+first token, the post-split rng key, the remaining token budget and the
+request itself. This module defines that payload as a **versioned,
+bytes-true wire format**:
 
 - ``KVHandoff`` is the in-memory form: a JSON-safe ``meta`` dict plus a
   dict of numpy arrays (prompt, rng key, per-layer KV block data).
@@ -57,17 +57,17 @@ HANDOFF_FORMAT = "pt-kv-handoff"
 # never silently armed as a stream
 FETCH_FORMAT = "pt-kv-fetch"
 HANDOFF_VERSION = 1
-_KNOWN_FORMATS = frozenset({HANDOFF_FORMAT, FETCH_FORMAT})
+# the one payload kind each format carries
+_KIND_OF_FORMAT = {HANDOFF_FORMAT: "paged", FETCH_FORMAT: "prefix"}
 
 
 @dataclass
 class KVHandoff:
     """One slot's portable handoff payload.
 
-    ``meta`` (JSON-safe): ``format``/``version``, ``kind``
-    ("dense"|"paged"), the serialized request
-    (``resilience.request_to_meta``), the armed-slot scalars (``tok0``,
-    ``pos0``, ``rem0``, dense ``pad0``), the paged geometry
+    ``meta`` (JSON-safe): ``format``/``version``, ``kind`` ("paged"),
+    the serialized request (``resilience.request_to_meta``), the
+    armed-slot scalars (``tok0``, ``pos0``, ``rem0``), the arena geometry
     (``n_blocks`` total to allocate, ``n_ship`` actually shipped,
     ``block_size``, ``kv_int8``), per-leaf block specs for
     compatibility validation, the first-token timestamp ``t_admit``
@@ -76,9 +76,8 @@ class KVHandoff:
 
     ``arrays``: ``prompt`` (int32), ``key`` ((2,) uint32 — the
     post-split state key, i.e. the NEXT decode step's split input),
-    and ``kv_<i>`` per cache leaf — paged: ``(n_ship, bs, ...)`` block
-    rows at storage dtype; dense: the ``(1, pos0, ...)`` populated row
-    prefix.
+    and ``kv_<i>`` per cache leaf: ``(n_ship, bs, ...)`` block rows at
+    storage dtype.
     """
     meta: dict
     arrays: Dict[str, np.ndarray]
@@ -139,12 +138,17 @@ def encode_handoff(handoff: KVHandoff) -> bytes:
 
 def decode_handoff(data: bytes) -> KVHandoff:
     """Inverse of :func:`encode_handoff`; refuses foreign or future-versioned
-    payloads loudly instead of adopting garbage into an arena."""
+    payloads, and a payload whose kind is not its format's, loudly
+    instead of adopting garbage into an arena."""
     with np.load(io.BytesIO(data), allow_pickle=False) as z:
         meta = json.loads(str(z["__meta__"]))
         arrays = {k: z[k] for k in z.files if k != "__meta__"}
-    if meta.get("format") not in _KNOWN_FORMATS:
+    if meta.get("format") not in _KIND_OF_FORMAT:
         raise ValueError("payload is not a KV handoff")
+    if meta.get("kind") != _KIND_OF_FORMAT[meta["format"]]:
+        raise ValueError(
+            f"{meta.get('kind')!r} payload in a {meta['format']} — this "
+            f"build reads {_KIND_OF_FORMAT[meta['format']]!r} ones only")
     if meta.get("version") != HANDOFF_VERSION:
         raise ValueError(
             f"KV handoff version {meta.get('version')} unsupported "

@@ -53,8 +53,7 @@ def run_fleet_kill_soak(seed: int = 0, kills: int = 2,
     fleet = Fleet(
         [PrefillWorker(PrefillPagedEngine(model, **kw))
          for _ in range(2)],
-        [DecodeWorker(ContinuousBatchingEngine(model, paged=True,
-                                               **kw))
+        [DecodeWorker(ContinuousBatchingEngine(model, **kw))
          for _ in range(2)],
         transport=t, lease_misses=2, spill_depth=100)
     rids = [fleet.submit(p, max_new_tokens=mn, arrival_step=i % 4)
@@ -76,7 +75,7 @@ def run_fleet_kill_soak(seed: int = 0, kills: int = 2,
                 fleet.kill_decode_worker(vi)
                 killed += 1
                 fleet.add_decode_worker(DecodeWorker(
-                    ContinuousBatchingEngine(model, paged=True, **kw),
+                    ContinuousBatchingEngine(model, **kw),
                     name=f"decode{next_name}"))
                 next_name += 1
         res = fleet.results
@@ -186,13 +185,12 @@ def run_serving_autoscale_bench(seed: int = 0, horizon: int = 36,
         fleet = Fleet(
             [PrefillWorker(PrefillPagedEngine(model, **kw))
              for _ in range(2)],
-            [DecodeWorker(ContinuousBatchingEngine(model, paged=True,
-                                                   **kw))
+            [DecodeWorker(ContinuousBatchingEngine(model, **kw))
              for _ in range(n_decode)],
             lease_misses=2, spill_depth=100)
         scaler = Autoscaler(
             fleet,
-            lambda: ContinuousBatchingEngine(model, paged=True, **kw),
+            lambda: ContinuousBatchingEngine(model, **kw),
             config=scfg) if autoscale else None
         state = {"killed": False, "worker_ticks": 0,
                  "peak": n_decode, "clock": 0}
@@ -372,7 +370,7 @@ def run_serving_recovery_bench(seed: int = 0, requests: int = 6,
                  for i in range(requests)]
 
     pf = [PrefillPagedEngine(model, **kw) for _ in range(2)]
-    dc = [ContinuousBatchingEngine(model, paged=True, **kw)
+    dc = [ContinuousBatchingEngine(model, **kw)
           for _ in range(2)]
     by_name = {f"prefill{i}": e for i, e in enumerate(pf)}
     by_name.update({f"decode{i}": e for i, e in enumerate(dc)})

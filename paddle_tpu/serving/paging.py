@@ -1,13 +1,9 @@
-"""Block-paged KV cache for the continuous-batching engine: shared
-arena + per-slot block tables, ref-counted prefix reuse, chunked
-prefill.
+"""Block-paged KV cache for the continuous-batching engine: the shared
+arena's block manager and the step backends that hold the arena.
 
-The dense engine (engine.py) preallocates one ``(max_len, kvh, d)`` KV
-row per slot, so HBM is sized for the worst-case sequence and a shared
-system prompt is recomputed and stored per request. Paged mode replaces
-the per-slot rows with ONE ``(num_blocks, block_size, kvh, d)`` arena
-per layer plus an in-graph ``(S, max_blocks)`` block table riding the
-slot state (vLLM's PagedAttention restated under the repo's
+The engine (engine.py) keeps ONE ``(num_blocks, block_size, kvh, d)``
+arena per layer plus an in-graph ``(S, max_blocks)`` block table riding
+the slot state (vLLM's PagedAttention restated under the repo's
 static-shape rules — the table is state, the arena never reshapes):
 
 - ``BlockManager`` (host): free list + refcounts + a rolling-hash
@@ -25,28 +21,21 @@ static-shape rules — the table is state, the arena never reshapes):
   chunks interleaved with decode blocks, paced by the scheduler's
   per-tick prefill token budget — a long prompt no longer stalls every
   in-flight decode for its whole length, it steals at most
-  ``budget`` tokens of prefill per tick. The dense engine's per-bucket
-  prefill jits collapse to one program.
+  ``budget`` tokens of prefill per tick.
 - Attention runs the Pallas paged-attention kernel on TPU and the
   gathered-dense reference off-TPU (ops/pallas/paged_attention.py);
-  greedy paged streams are bit-identical to the dense fp32 engine and
-  to per-request ``generate()``.
+  greedy streams are bit-identical to per-request ``generate()``.
 - ``kv_int8=True`` stores the arena as int8 codes + per-vector fp32
   absmax scales (the EQuARX recipe from
   ``distributed/collectives/quantized.py``; ~3.9x less KV HBM); the
   worst-case dequant error is runtime-queryable via
-  :meth:`PagedEngine.kv_error_bound`.
-
-Everything is default-off: construct ``ContinuousBatchingEngine(...,
-paged=True)`` or set ``PT_SERVING_PAGED=1`` (``PT_SERVING_KV_INT8=1``
-for the int8 arena).
+  :meth:`ContinuousBatchingEngine.kv_error_bound`
+  (``PT_SERVING_KV_INT8=1`` turns it on when ``kv_int8`` is not passed).
 """
 from __future__ import annotations
 
 import hashlib
-import time
 from collections import OrderedDict
-from dataclasses import dataclass
 from itertools import islice
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -55,16 +44,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..observability import metrics as _om
-from ..observability.tracing import now_us as _trace_now, span as _span
 from ..utils import faults
-from ..utils.flags import env_flag, env_int
-from .engine import (ContinuousBatchingEngine, ModelStepBackend, _SlotRun,
-                     _M_PREFILLS, _M_TOKENS, _StepBackendCommon,
-                     artifact_fingerprint, build_paged_chunk_fn,
-                     build_slot_block_fn, init_slot_state)
+from .engine import build_paged_chunk_fn, build_slot_block_fn, init_slot_state
 
 __all__ = ["BlockManager", "PagedArtifactStepBackend",
-           "PagedModelStepBackend", "PagedEngine"]
+           "PagedModelStepBackend"]
+
 
 TRASH_BLOCK = 0
 
@@ -559,13 +544,51 @@ def refuse_looped_cache(holder, what: str):
             "one a pass, and this path moves or shapes only one")
 
 
-class PagedModelStepBackend(ModelStepBackend):
-    """Paged twin of ModelStepBackend: the pool cache is the shared
-    block arena, the decode program threads the in-state block table
-    through the forward, and prefill is ONE fixed-shape chunk program
-    instead of per-bucket jits."""
+class _StepBackendCommon:
+    """What the in-process and the exported step backends share: the
+    slot state with its block tables, a zeroed arena, and the byte
+    counts — keyed off ``num_slots``/``max_blocks``/``pool_specs``,
+    which each backend sets up."""
 
-    is_paged = True      # engine.__new__ routes on this, not isinstance
+    # weight-only quantization state (serving/quant.py): None/empty on
+    # fp32 backends, so every hot path stays one falsy check
+    quant_cfg = None
+    _qmeta = None
+    _weight_bound = 0.0
+
+    # columns of a slot's table row (one table of ``max_blocks``)
+    table_width = property(lambda self: self.max_blocks)
+
+    def init_state(self):
+        state = init_slot_state(self.num_slots)
+        state["table"] = jnp.zeros((self.num_slots, self.table_width),
+                                   jnp.int32)        # all-trash tables
+        return state
+
+    def pool_cache(self):
+        return tuple(jnp.zeros(shape, dtype)
+                     for shape, dtype in self.pool_specs)
+
+    def kv_bytes_per_slot(self) -> int:
+        """HBM bytes of KV cache per slot (the arena is shared, so the
+        per-slot figure shrinks with block count)."""
+        total = sum(int(np.prod(shape)) * np.dtype(dtype).itemsize
+                    for shape, dtype in self.pool_specs)
+        return total // self.num_slots
+
+    def param_bytes(self) -> int:
+        """HBM bytes of weights + buffers one decode step reads (codes
+        AND scales under weight-only quant — the wire footprint, which
+        is the point)."""
+        return sum(int(v.nbytes) for v in jax.tree.leaves(self._pv)) \
+            + sum(int(v.nbytes) for v in jax.tree.leaves(self._bv))
+
+
+class PagedModelStepBackend(_StepBackendCommon):
+    """In-process backend over a live model: the pool cache is the shared
+    block arena, the decode program threads the in-state block table
+    through the forward (the same pure step ``generate()`` uses), and
+    prefill is ONE fixed-shape chunk program."""
 
     def __init__(self, model, num_slots: int, max_len: int,
                  decode_block: int, block_size: int, num_blocks: int,
@@ -620,7 +643,7 @@ class PagedModelStepBackend(ModelStepBackend):
         self.prefill_traces = [0]
         self._block_jit = jax.jit(
             build_slot_block_fn(self._pure, decode_block,
-                                self.decode_traces, paged=True),
+                                self.decode_traces),
             donate_argnums=(2, 3))
         self._chunk_jit = jax.jit(
             build_paged_chunk_fn(self._pure, prefill_chunk,
@@ -632,14 +655,28 @@ class PagedModelStepBackend(ModelStepBackend):
                                          self.kv_block_size,
                                          kv_int8=self.kv_int8)
 
-    # columns of a slot's table row (one table of ``max_blocks``)
-    table_width = property(lambda self: self.max_blocks)
+    def _setup_weight_quant(self, model, quant):
+        """Quantize the serving weight set in-place (between pv
+        construction and program building; see serving/quant.py).
+        No-op when ``quant`` is None."""
+        if quant is None:
+            return
+        from .quant import quantize_backend_params
+        self.quant_cfg = quant
+        self._pv, self._qmeta, self._weight_bound = \
+            quantize_backend_params(model, self._pv, quant)
 
-    def init_state(self):
-        state = init_slot_state(self.num_slots)
-        state["table"] = jnp.zeros((self.num_slots, self.table_width),
-                                   jnp.int32)        # all-trash tables
-        return state
+    def _maybe_quant_pure(self, pure):
+        """Wrap a pure step with the in-graph dequant when this backend
+        holds quantized weights — EVERY program (decode block, chunk,
+        spec verify) must be built from the wrapped step."""
+        if not self._qmeta:
+            return pure
+        from .quant import wrap_pure_with_dequant
+        return wrap_pure_with_dequant(pure, self._qmeta)
+
+    def decode_block(self, cache_flat, state):
+        return self._block_jit(self._pv, self._bv, cache_flat, state)
 
     def prefill_chunk(self, ids, cache_flat, table_row, start_pos,
                       n_valid, key, temp, topk, topp):
@@ -647,31 +684,37 @@ class PagedModelStepBackend(ModelStepBackend):
                                table_row, start_pos, n_valid, key, temp,
                                topk, topp)
 
-    def prefill(self, *a, **kw):
-        raise RuntimeError("the paged backend prefills in chunks — use "
-                           "prefill_chunk (engine.admit drives it)")
+
+def artifact_fingerprint(cfgs: dict, *programs: bytes) -> str:
+    """Artifact identity: sha1 over the recorded config + the
+    serialized programs. Recorded into engine snapshots so a restore
+    onto a DIFFERENT artifact is refused."""
+    h = hashlib.sha1(repr(sorted(
+        (k, str(v)) for k, v in cfgs.items())).encode())
+    for prog in programs:
+        h.update(prog)
+    return h.hexdigest()
 
 
 class PagedArtifactStepBackend(_StepBackendCommon):
-    """AOT paged backend: the paged engine's TWO programs (ONE decode
-    block + ONE chunked-prefill chunk), deserialized from an
-    ``export_decoder(..., engine_slots=N, engine_paged=True)`` artifact
-    — no model code or tracing needed on the serving host. The
+    """AOT backend: the engine's TWO programs (ONE decode block + ONE
+    chunked-prefill chunk), deserialized from an
+    ``export_decoder(..., engine_slots=N)`` artifact — no model code or
+    tracing needed on the serving host (reference: AnalysisPredictor
+    serving from the saved program alone). The
     ``artifact_fingerprint`` (sha1 over the serialized programs +
     config) rides engine snapshots so a restore onto a DIFFERENT
     artifact is refused instead of silently resuming on other
     programs."""
-
-    is_paged = True
 
     def __init__(self, blob):
         eng = blob["engine"]
         cfgs = eng["config"]
         if not cfgs.get("paged"):
             raise ValueError(
-                "artifact holds the dense engine programs — load it "
-                "with ArtifactStepBackend, or re-export with "
-                "export_decoder(..., engine_paged=True)")
+                "artifact holds the removed dense slot-pool engine's "
+                "programs — re-export it with export_decoder(..., "
+                "engine_slots=N)")
         self.artifact_fingerprint = artifact_fingerprint(
             cfgs, eng["block"], eng["chunk"])
         self.num_slots = cfgs["num_slots"]
@@ -682,7 +725,6 @@ class PagedArtifactStepBackend(_StepBackendCommon):
         self.max_blocks = self.max_len // self.kv_block_size
         self.kv_int8 = bool(cfgs.get("kv_int8", False))
         self.prefill_chunk_len = cfgs["prefill_chunk"]
-        self.carries_nan_flags = cfgs.get("block_outputs", 4) >= 5
         self.pool_specs = tuple((tuple(shape), np.dtype(dtype))
                                 for shape, dtype in eng["pool_specs"])
         self._block = jax.export.deserialize(eng["block"])
@@ -692,16 +734,6 @@ class PagedArtifactStepBackend(_StepBackendCommon):
         self.decode_traces = [1]     # two AOT-compiled programs
         self.prefill_traces = [1]
 
-    def init_state(self):
-        state = init_slot_state(self.num_slots)
-        state["table"] = jnp.zeros((self.num_slots, self.max_blocks),
-                                   jnp.int32)
-        return state
-
-    def pool_cache(self):
-        return tuple(jnp.zeros(shape, dtype)
-                     for shape, dtype in self.pool_specs)
-
     def decode_block(self, cache_flat, state):
         return self._block.call(self._pv, self._bv, cache_flat, state)
 
@@ -710,747 +742,3 @@ class PagedArtifactStepBackend(_StepBackendCommon):
         return self._chunk.call(self._pv, self._bv, ids, cache_flat,
                                 table_row, start_pos, n_valid, key,
                                 temp, topk, topp)
-
-    def prefill(self, *a, **kw):
-        raise RuntimeError("the paged backend prefills in chunks — use "
-                           "prefill_chunk (engine.admit drives it)")
-
-
-def _arm_fn(state, slot, table_row, tok0, pos0, rem0, eos0, temp0,
-            topk0, topp0, key0):
-    """Turn a slot live after its chunked prefill finished: the arena
-    already holds the prompt's K/V, so arming is a pure state update
-    (the paged analogue of engine._admit_fn without the row splice).
-    ``slot`` is traced — one compiled program serves every arming."""
-
-    def set1(a, v):
-        return a.at[slot].set(jnp.asarray(v, a.dtype))
-
-    return dict(
-        state, tok=set1(state["tok"], tok0),
-        pos=set1(state["pos"], pos0),
-        pad=set1(state["pad"], 0),        # paged prompts are unpadded
-        live=set1(state["live"], rem0 > 0),
-        eos=set1(state["eos"], eos0),
-        remaining=set1(state["remaining"], rem0),
-        key=state["key"].at[slot].set(key0),
-        temp=set1(state["temp"], temp0),
-        topk=set1(state["topk"], topk0),
-        topp=set1(state["topp"], topp0),
-        table=state["table"].at[slot].set(table_row))
-
-
-@dataclass
-class _PrefillJob:
-    """One admitted request still streaming its prompt into the arena
-    (``done`` counts tokens already resident, including the shared
-    prefix it skipped). For a preemption resume, ``prompt`` is the
-    original prompt plus the generated history being re-prefilled and
-    ``resume_tok`` is the carried in-hand next token — the chunk
-    programs' in-graph samples are discarded and the slot arms with it
-    instead."""
-    run: _SlotRun
-    slot: int
-    prompt: np.ndarray
-    done: int
-    table_row: np.ndarray          # (max_blocks,) int32
-    key: jnp.ndarray               # post-split state key
-    sub: jnp.ndarray               # prefill sampling key
-    temp: jnp.ndarray
-    topk: jnp.ndarray
-    topp: jnp.ndarray
-    tok0: Optional[int] = None
-    resume_tok: Optional[int] = None
-
-
-@dataclass
-class _Reservation:
-    """What an admission holds once its blocks are reserved: the run's
-    blocks (shared prefix first), its table row, how many leading prompt
-    blocks were served from the prefix index, what the ``serving.admit``
-    span says of it, and the digests the lookup computed (``chain``, for
-    the run to carry). An engine with a second pool adds what the run
-    holds there (``window``)."""
-    block_ids: List[int]
-    table_row: np.ndarray
-    shared_blocks: int
-    span_ids: dict
-    chain: list
-    window: Optional[object] = None
-
-
-class PagedEngine(ContinuousBatchingEngine):
-    """Paged-KV continuous batching. Same Server/Scheduler contract as
-    the dense engine; differences:
-
-    - ``admit()`` only reserves blocks and queues a prefill job; the
-      prompt streams into the arena via :meth:`prefill_tick` (chunk
-      programs), and the slot arms when its last chunk lands.
-    - ``try_admit()`` can return False (block pool exhausted) — the
-      Server re-queues and retries after retirements free blocks.
-    - prompts are UNPADDED (no buckets): position 0 is token 0, which
-      is what makes whole prefix blocks shareable across requests.
-    - ``prefill_chunk`` (else ``PT_SERVING_PREFILL_CHUNK``) is the chunk
-      program's length in tokens; when neither is given it is
-      :func:`default_prefill_chunk` — the program's ridge, where a
-      chunk's matmuls take as long as the weight read every chunk pays,
-      so a prompt re-reads the weights once per ~256 tokens.
-      :meth:`chunk_fill_share` says how much of the chunks was prompt.
-    """
-
-    def __init__(self, model=None, num_slots: int = 4,
-                 max_len: int = 256, decode_block: int = 8,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 backend=None, *, paged: bool = True, spec=None,
-                 block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 kv_int8: Optional[bool] = None,
-                 prefill_chunk: Optional[int] = None,
-                 hash_fn=None, tp=None, quant=None):
-        if prompt_buckets is not None:
-            raise ValueError(
-                "paged mode takes no prompt_buckets: prompts are "
-                "unpadded and prefilled in fixed-size chunks")
-        if backend is not None:
-            # the backend already baked these in — a silently ignored
-            # kv_int8=True (fp32 arena, bound 0.0), num_blocks or
-            # quant= would be a misconfiguration, not a preference
-            given = {k: v for k, v in (("block_size", block_size),
-                                       ("num_blocks", num_blocks),
-                                       ("kv_int8", kv_int8),
-                                       ("prefill_chunk", prefill_chunk),
-                                       ("quant", quant))
-                     if v is not None}
-            if given:
-                raise ValueError(
-                    f"{sorted(given)} cannot be set alongside an "
-                    "explicit backend — they are baked into it at "
-                    "construction")
-        if block_size is None:
-            # resolution order: explicit arg > env knob > a valid
-            # (stamp-matching) autotune-table winner > the documented
-            # default 16 — a stale table never silently reshapes arenas
-            block_size = env_int("PT_SERVING_BLOCK_SIZE", 0)
-            if block_size <= 0:
-                from ..ops.pallas.autotune import tuned_paged_block_size
-                block_size = tuned_paged_block_size(16)
-        if num_blocks is None:
-            # full dense capacity + trash by default — HBM savings come
-            # from passing a smaller pool (plus sharing); correctness
-            # never depends on the pool being oversized
-            num_blocks = 1 + num_slots * (max_len // block_size)
-        if kv_int8 is None:
-            kv_int8 = env_flag("PT_SERVING_KV_INT8")
-        if prefill_chunk is None:
-            prefill_chunk = env_int(
-                "PT_SERVING_PREFILL_CHUNK",
-                default_prefill_chunk(block_size, max_len))
-        if backend is None:
-            if model is None:
-                raise ValueError("pass a model or a paged step backend")
-            from .quant import resolve_quant_config
-            from .tp import resolve_tp_config
-            tp_cfg = resolve_tp_config(tp)
-            q_cfg = resolve_quant_config(quant)
-            if tp_cfg is not None:
-                # tensor-parallel paged serving: the shared KV arena
-                # shards its kv-head dim over the mesh (serving/tp.py);
-                # an explicit backend is never rerouted by the env flag
-                from .tp import ShardedPagedStepBackend
-                backend = ShardedPagedStepBackend(
-                    model, num_slots, max_len, decode_block,
-                    block_size, num_blocks, bool(kv_int8),
-                    prefill_chunk, tp_cfg, quant=q_cfg)
-            else:
-                # subclass hook: the speculative engine swaps in the
-                # verify-capable paged backend here (serving/spec.py)
-                backend = self._build_paged_backend(
-                    model, num_slots, max_len, decode_block, block_size,
-                    num_blocks, bool(kv_int8), prefill_chunk, q_cfg)
-        self.kv_block_size = backend.kv_block_size
-        self.num_kv_blocks = backend.num_kv_blocks
-        self.max_blocks = backend.max_blocks
-        self.kv_int8 = backend.kv_int8
-        self.prefill_chunk_len = backend.prefill_chunk_len
-        # passes a step makes over the layers (1 unless the model loops)
-        # and the cached-attention sites a decode step holds: the page
-        # counters below stay ONE site's
-        self.cache_passes = getattr(backend, "cache_passes", 1)
-        self.attn_sites = getattr(backend, "attn_sites", 0)
-        self.manager = BlockManager(self.num_kv_blocks,
-                                    self.kv_block_size, hash_fn)
-        self._arm_jit = jax.jit(_arm_fn, donate_argnums=(0,))
-        super().__init__(backend=backend, spec=spec)
-
-    def _build_paged_backend(self, model, num_slots, max_len,
-                             decode_block, block_size, num_blocks,
-                             kv_int8, prefill_chunk, quant=None):
-        return PagedModelStepBackend(
-            model, num_slots, max_len, decode_block, block_size,
-            num_blocks, kv_int8, prefill_chunk, quant=quant)
-
-    # -- lifecycle ---------------------------------------------------------
-    def reset(self):
-        super().reset()
-        self.manager.reset()
-        self._jobs: List[_PrefillJob] = []
-        self.prompt_tokens = 0         # all prompt tokens submitted
-        self.shared_tokens = 0         # skipped via prefix reuse
-        self.prefilled_tokens = 0      # actually computed
-        self.prefill_chunks = 0        # chunk programs dispatched
-        self.fetched_tokens = 0        # of shared: remote-fetched KV
-        # the decode kernel's walk (per attention layer), and the host's
-        # mirror of the in-graph per-slot ``pos`` it is counted from
-        self.kv_pages_live = 0         # pages holding a slot's live KV
-        self.kv_pages_copied = 0       # pages the kernel copied for them
-        self.kv_pages_in_runs = 0      # of the live, pages moved in runs
-        # each slot's table row as the walk's run copies see it
-        # (``paged_attention.run_groups``), from the row it was armed with
-        self._run_groups = self._table_run_groups(
-            np.zeros((self.num_slots, self.max_blocks), np.int32))
-        self.ut_steps = 0              # looped model: passes run by decode
-        self._pos_host = np.zeros((self.num_slots,), np.int64)
-        # what the model's programs count into the cache's last leaf
-        # (``cache_counters``: name -> "sum" | "max"; a (2, n) int32 array,
-        # decode steps apart from prefill chunks): the decode row becomes
-        # an engine counter of that name, the chunk row ``prefill_<name>``
-        self._counter_names = tuple(
-            getattr(self.backend, "cache_counters", {}).items())
-        self._counts_seen = np.zeros((2, len(self._counter_names)),
-                                     np.int64)
-        for name, _ in self._counter_names:
-            setattr(self, name, 0)
-            setattr(self, "prefill_" + name, 0)
-        self._chunk_span = None        # the latest ``serving.prefill_chunk``
-        self._chunks_unread = 0        # chunks since the counters were read
-        self._arm_ns = 0               # ends of prefill since the last block
-
-    # -- introspection -----------------------------------------------------
-    def prefix_cache_hit_rate(self) -> float:
-        """Fraction of submitted prompt tokens served from shared
-        prefix blocks instead of recomputed."""
-        return self.shared_tokens / self.prompt_tokens \
-            if self.prompt_tokens else 0.0
-
-    def chunk_fill_share(self) -> float:
-        """Fraction of the chunk programs' columns that held a prompt
-        token (the rest was right-padding): a longer chunk re-reads the
-        weights less often and pads more."""
-        columns = self.prefill_chunks * self.prefill_chunk_len
-        return self.prefilled_tokens / columns if columns else 0.0
-
-    def prefill_compile_count(self) -> int:
-        return self.backend.prefill_traces[0]
-
-    def kv_error_bound(self) -> float:
-        """Runtime worst-case |dequantized - fp32| over the int8 arena
-        (0.0 in fp32 mode): the EQuARX single-quantization bound from
-        the largest live absmax scale."""
-        if not self.kv_int8:
-            return 0.0
-        from ..ops.pallas.paged_attention import kv_int8_error_bound
-        worst = 0.0
-        for (shape, dtype), buf in zip(self.backend.pool_specs,
-                                       self._cache):
-            if np.dtype(dtype) == np.float32 and len(shape) == 3:
-                worst = max(worst, float(jnp.max(buf)))
-        return float(kv_int8_error_bound(worst))
-
-    def blocks_needed(self, prompt_len: int, max_new_tokens: int) -> int:
-        # positions written: prompt [0, L) plus generated tokens at
-        # [L, L+max_new-1) — the final sampled token is never written
-        return -(-(prompt_len + max(max_new_tokens - 1, 0))
-                 // self.kv_block_size)
-
-    def bucket_len(self, prompt_len: int) -> int:
-        return prompt_len            # unpadded prompts, no buckets
-
-    def validate_request(self, prompt_len: int, max_new_tokens: int):
-        super().validate_request(prompt_len, max_new_tokens)
-        need = self.blocks_needed(prompt_len, max_new_tokens)
-        # the MANAGER is the source of truth, not the engine's
-        # num_kv_blocks attribute: allocate() draws from the manager,
-        # so validating against a stale attribute let an impossible
-        # request through the door and into run_until_idle's re-queue
-        # path forever (the PR-5 livelock fix; regression-pinned with a
-        # tiny pool in tests/test_resilience.py)
-        pool = self.manager.usable_blocks()
-        if need > pool:
-            raise ValueError(
-                f"request needs {need} KV blocks but the arena only "
-                f"has {pool}; raise num_blocks or shorten the request")
-
-    # -- admission ---------------------------------------------------------
-    def try_admit(self, request) -> bool:
-        """Block allocation, prefix lookup and slot arming for one
-        request (the chunks themselves run in :meth:`prefill_tick`);
-        False when the block pool cannot hold it yet. The span says how
-        many blocks the admission allocated (``fresh_blocks``) and how
-        many of those it took by eviction (``evicted_blocks``), and how
-        many block digests it computed (``hashed_blocks``); an admission
-        that went through also marks where its time went: ``reserved_ns``
-        when the lookup, the allocation and the table row were done,
-        ``keyed_ns`` when the request's key was made and split; the rest
-        to the span's end is the sampling scalars' uploads and the job."""
-        with _span("serving.admit", rid=request.request_id) as sp:
-            m = self.manager
-            evicted, hashed = m.evictions, m.hashed_blocks
-            ids = self._try_admit(request, sp)
-            sp.ids.update(
-                ids or {"fresh_blocks": 0},
-                evicted_blocks=m.evictions - evicted,
-                hashed_blocks=m.hashed_blocks - hashed)
-            return ids is not None
-
-    def _reserve(self, full, mnt) -> Optional[_Reservation]:
-        """Blocks and table row for a request of prompt ``full`` and
-        ``mnt`` new tokens: the matched prefix's blocks shared, the rest
-        fresh; None when the pool cannot cover it yet (nothing held)."""
-        chain = []
-        shared = self._match_prefix_for_admission(full, chain)
-        total = self.blocks_needed(len(full), mnt)
-        fresh = self.manager.allocate(total - len(shared))
-        if fresh is None:            # pool exhausted: retry later
-            self.manager.release(shared)
-            return None
-        block_ids = shared + fresh
-        table_row = np.zeros((self.max_blocks,), np.int32)
-        table_row[:len(block_ids)] = block_ids
-        return _Reservation(block_ids, table_row, len(shared),
-                            {"fresh_blocks": len(fresh)}, chain)
-
-    def _try_admit(self, request, sp) -> Optional[dict]:
-        """The admission proper, marking its span ``sp``: what the span
-        says of the blocks it allocated, or None when a pool cannot hold
-        the request yet."""
-        prompt = np.asarray(request.prompt, np.int32).reshape(-1)
-        resume = getattr(request, "resume", None)
-        if resume is not None and resume.tokens:
-            # preemption resume: the "prompt" to prefill is the original
-            # prompt plus the generated history minus the in-hand next
-            # token; the first full prompt blocks are usually still in
-            # the prefix index (eviction retained them), so most of this
-            # re-prefill is cache hits rather than recompute
-            full = np.concatenate([
-                prompt, np.asarray(resume.tokens[:-1], np.int32)])
-            mnt = request.max_new_tokens - len(resume.tokens) + 1
-        else:
-            resume = None
-            full, mnt = prompt, request.max_new_tokens
-        L = int(full.shape[0])
-        self.validate_request(L, mnt)
-        slot = next((i for i, s in enumerate(self._slots) if s is None),
-                    None)
-        if slot is None:
-            raise RuntimeError("no free slot (scheduler bug)")
-        held = self._reserve(full, mnt)
-        if held is None:
-            return None
-        sp.mark("reserved")
-        block_ids, table_row = held.block_ids, held.table_row
-        if self.tracer is not None:
-            self.tracer.span_end(request.request_id, "queue_wait",
-                                 shared_blocks=held.shared_blocks,
-                                 fresh_blocks=held.span_ids["fresh_blocks"],
-                                 resumed=resume is not None)
-        if resume is None:
-            key = jax.random.PRNGKey(request.seed)
-            key, sub = jax.random.split(key)  # generate()'s key schedule
-            run = _SlotRun(request, block_ids=block_ids)
-            resume_tok = None
-        else:
-            # the saved key IS the next step's split input — arming with
-            # it (and discarding the chunk programs' in-graph samples)
-            # keeps seeded-sampled resumes bit-identical
-            key = jnp.asarray(np.asarray(resume.key, np.uint32))
-            sub = jax.random.PRNGKey(0)            # discarded draw
-            run = _SlotRun(request, tokens=list(resume.tokens),
-                           t_admit=resume.t_admit, block_ids=block_ids)
-            resume_tok = int(resume.tokens[-1])
-        sp.mark("keyed")
-        run.window, run.chain = held.window, held.chain
-        self._slots[slot] = run
-        self._prefill_slots.add(slot)
-        n_shared = held.shared_blocks * self.kv_block_size
-        self.prompt_tokens += L
-        self.shared_tokens += n_shared
-        self._jobs.append(_PrefillJob(
-            run=run, slot=slot, prompt=full, done=n_shared,
-            table_row=table_row, key=key, sub=sub,
-            temp=jnp.float32(request.temperature),
-            topk=jnp.int32(request.top_k),
-            topp=jnp.float32(request.top_p), resume_tok=resume_tok))
-        return held.span_ids
-
-    def _match_prefix_for_admission(self, full, chain: list) -> List[int]:
-        """Admission-time prefix match. The base engine consults only
-        its LOCAL index; the fleet's prefill engines override this to
-        also fetch a longer chain another worker has registered
-        (serving/prefix_cache.py) — either way the returned blocks are
-        ref-acquired for the admitting request and ``done`` starts past
-        them. ``chain`` receives the digests the local lookup computed."""
-        return self.manager.match_prefix(full, chain)
-
-    def admit(self, request) -> bool:
-        if not self.try_admit(request):
-            raise RuntimeError(
-                "KV block pool exhausted; use try_admit/Server (which "
-                "re-queue) or raise num_blocks")
-        return False
-
-    # -- chunked prefill ---------------------------------------------------
-    def prefill_tick(self, token_budget: Optional[int] = None) -> int:
-        """Advance pending prefill jobs by up to ``token_budget`` prompt
-        tokens (always at least one chunk when work is pending, so a
-        tiny budget still progresses). Jobs run FIFO; a finished job
-        arms its slot (or retires immediately on eos/max_new==1)."""
-        spent = 0
-        C = self.prefill_chunk_len
-        while self._jobs and (token_budget is None or spent == 0
-                              or spent < token_budget):
-            # fires BEFORE the chunk dispatch: the job's cursor hasn't
-            # advanced, so a retry re-dispatches the identical chunk
-            faults.fault_point("serving.prefill_tick")
-            job = self._jobs[0]
-            L = len(job.prompt)
-            n = min(C, L - job.done)
-            ids = np.zeros((1, C), np.int32)
-            ids[0, :n] = job.prompt[job.done:job.done + n]
-            tr = self.tracer
-            t_chunk = _trace_now() if tr is not None else 0.0
-            with _span("serving.prefill_chunk",
-                       rid=job.run.request.request_id,
-                       tokens=n) as self._chunk_span:
-                tok0_dev, self._cache = self.backend.prefill_chunk(
-                    jnp.asarray(ids), self._cache,
-                    jnp.asarray(job.table_row[None]),
-                    jnp.asarray(job.done, jnp.int32),
-                    jnp.asarray(n, jnp.int32),
-                    job.sub, job.temp, job.topk, job.topp)
-                self._enqueued(self._chunk_span)
-            job.done += n
-            spent += n
-            self.prefill_chunks += 1
-            self._chunks_unread += 1
-            self.prefilled_tokens += n
-            _M_PREFILLS.inc()
-            if tr is not None:
-                tr.span_at(job.run.request.request_id, "prefill_chunk",
-                           t_chunk, tokens=n, done=job.done, total=L)
-            if job.done >= L:
-                self._jobs.pop(0)
-                self._finish_prefill(job, tok0_dev)
-        return spent
-
-    def _finish_prefill(self, job: _PrefillJob, tok0_dev):
-        req = job.run.request
-        now = time.perf_counter()
-        eos = req.eos_token_id
-        if job.resume_tok is not None:
-            # preemption resume: the carried stream owns the next token
-            # — the chunk's in-graph sample is discarded, tokens and the
-            # TTFT timestamp ride over from the evicted run
-            t_synced = time.perf_counter_ns()       # nothing is fetched
-            tok0 = job.resume_tok
-            rem0 = req.max_new_tokens - len(job.run.tokens)
-            req.resume = None
-            if self.tracer is not None:
-                self.tracer.instant(req.request_id, "resume",
-                                    slot=job.slot,
-                                    reused_tokens=len(job.run.tokens))
-        else:
-            began = self._stall_watch.begin()
-            with _span("serving.prefill_sync", rid=req.request_id) as sp:
-                tok0 = int(tok0_dev)        # host blocked on the last chunk
-            t_synced = sp.start + sp.dur
-            if self._pending_block is None:     # the last chunk was all
-                self._drained_ns = t_synced     # the device had queued
-            self._sync_ended(sp, began)
-            job.run.tokens = [tok0]
-            job.run.t_admit = now           # TTFT timestamp
-            self.tokens_emitted += 1
-            _M_TOKENS.inc()
-            rem0 = req.max_new_tokens - 1
-            if eos is not None and tok0 == eos:
-                rem0 = 0
-        # the prompt's full blocks are resident now — index them so the
-        # NEXT request with this prefix skips the compute
-        self._register_prompt(job)
-        self._prefill_slots.discard(job.slot)
-        if rem0 <= 0:                # finished at admission
-            self._retire(job.slot, job.run, now)
-        else:
-            self._arm(job.slot, job.table_row, tok0, len(job.prompt), rem0,
-                      eos, job.temp, job.topk, job.topp, job.key)
-            if self.tracer is not None:
-                self.tracer.span_begin(req.request_id, "decode",
-                                       slot=job.slot)
-        # host time between the last chunk's end and the next enqueue that
-        # no span covers (it is ``serving.tick``'s own): the next
-        # ``serving.decode_block`` carries the sum as ``arm_ns``
-        self._arm_ns += time.perf_counter_ns() - t_synced
-
-    def _register_prompt(self, job: _PrefillJob):
-        """Index the prompt's shareable blocks (now filled) from the
-        chain the run carries since its admission, extended over the
-        blocks the lookup stopped short of."""
-        m, run = self.manager, job.run
-        m.register_chain(
-            m.extend_chain(run.chain, job.prompt,
-                           m._shareable_blocks(job.prompt)),
-            run.block_ids)
-
-    def _arm(self, slot, table_row, tok0, pos0, rem0, eos, temp, topk,
-             topp, key):
-        """Arm ``slot`` for decoding, in-graph and in the host's mirrors
-        of its remaining count and position (the one arm site: local
-        prefill completion and the fleet's adopted hand-offs)."""
-        self._state = self._arm_jit(
-            self._state, jnp.int32(slot), jnp.asarray(table_row),
-            jnp.int32(tok0), jnp.int32(pos0), jnp.int32(rem0),
-            jnp.int32(-1 if eos is None else eos), temp, topk, topp, key)
-        self._remaining_host[slot] = rem0
-        self._pos_host[slot] = pos0
-        self._run_groups[slot] = self._table_run_groups(
-            np.asarray(table_row)[None])[0]
-
-    def _table_run_groups(self, rows):
-        """:func:`paged_attention.run_groups` of the full-length table's
-        columns of ``rows``."""
-        from ..ops.pallas.paged_attention import run_groups
-        return run_groups(rows[:, :self.max_blocks])
-
-    # -- the decode kernel's walk, counted on the host ----------------------
-    def _decode_block_counters(self):
-        """Pages one layer's paged decode reads walk over the block about
-        to be dispatched, from the host's mirrors of ``pos`` and
-        ``remaining`` (no device fetch): at step k a slot's read is
-        ``pos + min(k, steps it stays live) + 1`` tokens long, and a dead
-        slot re-reads the length it was left at, through its zeroed table
-        row. ``ops.pallas.paged_attention.walk_counts`` mirrors the
-        kernel's loop bound. A slot that meets its EOS inside the block
-        is counted as if it ran to the block's end (at most one page a
-        block over). ``kv_pages_in_runs``: of the live pages, those the
-        walk moves 8 to a copy (``paged_attention.walk_run_pages``), from
-        each decoding slot's row as it was armed; a dead slot reads
-        through a zeroed row, page by page."""
-        from ..ops.pallas.paged_attention import walk_counts, walk_run_pages
-        lengths, decoding = self._block_read_lengths()
-        live, copied, _ = walk_counts(lengths, self.max_blocks,
-                                      self.kv_block_size)
-        in_runs = walk_run_pages(self._run_groups,
-                                 np.where(decoding, lengths, 0),
-                                 self.kv_block_size)
-        self.kv_pages_live += live
-        self.kv_pages_copied += copied
-        self.kv_pages_in_runs += in_runs
-        ids = dict(super()._decode_block_counters(),
-                   kv_pages_live=live, kv_pages_copied=copied,
-                   kv_pages_in_runs=in_runs, arm_ns=self._arm_ns)
-        self._arm_ns = 0
-        if self.cache_passes > 1:
-            ids["ut_steps"] = self.decode_block * self.cache_passes
-            self.ut_steps += ids["ut_steps"]
-        return ids
-
-    def _block_read_lengths(self):
-        """``(lengths, decoding)``, each ``(decode_block, slots)``: the
-        length of every slot's read at every step of the block about to
-        be dispatched, and whether the slot is still decoding there."""
-        k = np.arange(self.decode_block)[:, None]
-        stays = np.minimum(self._remaining_host, self.decode_block)[None, :]
-        return self._pos_host[None, :] + np.minimum(k, stays) + 1, k < stays
-
-    def _credit_block(self, toks_np, lives_np, oks_np, rem_np):
-        self._pos_host += lives_np.sum(axis=0)      # pos += live, per step
-        super()._credit_block(toks_np, lives_np, oks_np, rem_np)
-
-    def _read_program_counters(self):
-        return np.asarray(self._cache[-1]) if self._counter_names else None
-
-    def _credit_program_counters(self, counts_np):
-        """Fold the programs' counts into the engine's counters and onto
-        the spans of what ran: the decode row onto this block's
-        ``serving.decode_block``, the chunk row — every chunk since the
-        last read — onto the latest ``serving.prefill_chunk`` (with
-        ``chunks``, how many it speaks for). Sums are differences of
-        wrapping int32 totals; a maximum is the largest so far."""
-        spans = (("", self._block_span, {}),
-                 ("prefill_", self._chunk_span if self._chunks_unread
-                  else None, {"chunks": self._chunks_unread}))
-        now = counts_np.astype(np.int64)
-        for row, (prefix, span_, ids) in enumerate(spans):
-            for col, (name, fold) in enumerate(self._counter_names):
-                v = int(now[row, col])
-                if fold == "sum":
-                    v = (v - int(self._counts_seen[row, col])) % 2 ** 32
-                    setattr(self, prefix + name,
-                            getattr(self, prefix + name) + v)
-                else:
-                    setattr(self, prefix + name, v)
-                ids[name] = v
-            if span_ is not None:
-                span_.ids.update(ids)
-        self._counts_seen = now
-        self._chunks_unread = 0
-
-    def _retire(self, slot, run, now):
-        super()._retire(slot, run, now)
-        if run.block_ids is not None:
-            if run.failure is None and run.tokens:
-                # decode-time block sharing: every position the stream
-                # WROTE is resident — prompt plus generated history
-                # minus the final sampled token (never written). Extend
-                # the digest chain over the fully-written blocks so a
-                # later request continuing this conversation shares the
-                # decode-position KV too. Failed/poisoned runs register
-                # NOTHING (a poisoned block must never be matchable).
-                # The run's chain already holds the prompt's shareable
-                # blocks: only the blocks written since are hashed.
-                seq = np.concatenate([
-                    np.asarray(run.request.prompt, np.int32).reshape(-1),
-                    np.asarray(run.tokens[:-1], np.int32)])
-                self._register_written(
-                    run, self.manager.extend_chain(
-                        run.chain, seq, len(seq) // self.kv_block_size))
-            self._release_slot_resources(run)
-
-    def _register_written(self, run, chain):
-        """Index the fully-written blocks of a finished stream."""
-        self.manager.register_chain(chain, run.block_ids)
-
-    # -- resilience hooks --------------------------------------------------
-    def _abort_prefill(self, slot):
-        """Cancel a mid-prefill request: drop its pending job (the
-        chunk loop never sees it again); its blocks release through the
-        shared ``_retire`` path. The slot never armed, so there is no
-        in-graph state to kill."""
-        self._jobs = [j for j in self._jobs if j.slot != slot]
-
-    def _release_slot_resources(self, run):
-        """Retirement and preemption release: the run's arena blocks
-        drop one ref — registered prompt-prefix blocks park in the LRU
-        cache (their prefix-index entries RETAINED, so a preempted run's
-        re-prefill is mostly cache hits), unregistered decode blocks
-        return to the free list."""
-        if run.block_ids is not None:
-            self.manager.release(run.block_ids)
-            run.block_ids = None
-
-    def _poison_live_slot(self):
-        """Paged poison: NaN the arena block holding the victim's
-        position ``pos-1``. That block is always (a) within the slot's
-        attended range, so the sentinel trips on the very next step,
-        and (b) a FRESH block owned only by this slot — its index
-        ``(pos-1)//bs >= (L-1)//bs`` sits past both the shared-prefix
-        and the registered range, so no other slot (and no future
-        prefix match) can ever read the poison."""
-        for slot, run in enumerate(self._slots):
-            if run is not None and slot not in self._prefill_slots:
-                L = int(np.asarray(run.request.prompt).reshape(-1)
-                        .shape[0])
-                pos = L + len(run.tokens) - 1
-                blk = run.block_ids[(pos - 1) // self.kv_block_size]
-                self._cache = tuple(
-                    c.at[blk].set(jnp.nan)
-                    if jnp.issubdtype(c.dtype, jnp.floating) else c
-                    for c in self._cache)
-                return slot
-        return None
-
-    # -- snapshot / restore ------------------------------------------------
-    def snapshot_state(self):
-        meta, arrays = super().snapshot_state()
-        m = self.manager
-        meta["manager"] = {
-            "num_blocks": m.num_blocks, "block_size": m.block_size,
-            "free": list(m._free),
-            "ref": [[int(b), int(r)] for b, r in m._ref.items()],
-            "digest_of": [[int(b), d.hex()]
-                          for b, d in m._digest_of.items()],
-            "index": [[d.hex(), int(bid), [int(t) for t in chunk]]
-                      for d, (bid, chunk) in m._index.items()],
-            "cached": [int(b) for b in m._cached],   # LRU order
-            "lookups": m.lookups, "hit_blocks": m.hit_blocks,
-            "depth": [[d.hex(), int(n)] for d, n in m._depth.items()],
-            "evictions": m.evictions,
-            "hits": [[int(b), int(h)] for b, h in m._hits.items()],
-        }
-        jobs_meta = []
-        for j, job in enumerate(self._jobs):
-            arrays[f"job{j}_prompt"] = np.asarray(job.prompt, np.int32)
-            arrays[f"job{j}_table"] = np.asarray(job.table_row, np.int32)
-            arrays[f"job{j}_key"] = np.asarray(job.key)
-            arrays[f"job{j}_sub"] = np.asarray(job.sub)
-            jobs_meta.append({
-                "slot": job.slot, "done": job.done,
-                "temp": float(job.temp), "topk": int(job.topk),
-                "topp": float(job.topp), "tok0": job.tok0,
-                "resume_tok": job.resume_tok})
-        meta["jobs"] = jobs_meta
-        meta["paged_counters"] = {
-            "prompt_tokens": self.prompt_tokens,
-            "shared_tokens": self.shared_tokens,
-            "prefilled_tokens": self.prefilled_tokens,
-            "prefill_chunks": self.prefill_chunks,
-            "fetched_tokens": self.fetched_tokens,
-            "kv_pages_live": self.kv_pages_live,
-            "kv_pages_copied": self.kv_pages_copied,
-            "kv_pages_in_runs": self.kv_pages_in_runs,
-            "ut_steps": self.ut_steps}
-        return meta, arrays
-
-    def restore_state(self, meta, arrays):
-        super().restore_state(meta, arrays)
-        mm = meta["manager"]
-        m = self.manager
-        if (mm["num_blocks"], mm["block_size"]) != (m.num_blocks,
-                                                   m.block_size):
-            raise ValueError(
-                f"snapshot arena {mm['num_blocks']}x{mm['block_size']} "
-                f"does not match this engine's "
-                f"{m.num_blocks}x{m.block_size}")
-        m._free = list(mm["free"])
-        m._ref = {int(b): int(r) for b, r in mm["ref"]}
-        m._digest_of = {int(b): bytes.fromhex(d)
-                        for b, d in mm["digest_of"]}
-        m._index = {bytes.fromhex(d): (int(bid), tuple(chunk))
-                    for d, bid, chunk in mm["index"]}
-        m._cached = OrderedDict((int(b), None) for b in mm["cached"])
-        m.lookups, m.hit_blocks = mm["lookups"], mm["hit_blocks"]
-        m._depth = {bytes.fromhex(d): int(n)
-                    for d, n in mm.get("depth", [])}
-        m._depth = {d: n for d, n in m._depth.items()
-                    if d in m._index}
-        m.evictions = int(mm.get("evictions", 0))
-        m._hits = {int(b): int(h) for b, h in mm.get("hits", [])
-                   if int(b) in m._digest_of}
-        m._reindex_cached()
-        m.assert_consistent()
-        self._jobs = []
-        for j, jm in enumerate(meta["jobs"]):
-            run = self._slots[jm["slot"]]
-            self._jobs.append(_PrefillJob(
-                run=run, slot=jm["slot"],
-                prompt=np.asarray(arrays[f"job{j}_prompt"], np.int32),
-                done=jm["done"],
-                table_row=np.asarray(arrays[f"job{j}_table"], np.int32),
-                key=jnp.asarray(arrays[f"job{j}_key"]),
-                sub=jnp.asarray(arrays[f"job{j}_sub"]),
-                temp=jnp.float32(jm["temp"]),
-                topk=jnp.int32(jm["topk"]),
-                topp=jnp.float32(jm["topp"]), tok0=jm["tok0"],
-                resume_tok=jm.get("resume_tok")))
-        pc = meta["paged_counters"]
-        self.prompt_tokens = pc["prompt_tokens"]
-        self.shared_tokens = pc["shared_tokens"]
-        self.prefilled_tokens = pc["prefilled_tokens"]
-        self.prefill_chunks = pc["prefill_chunks"]
-        self.fetched_tokens = pc.get("fetched_tokens", 0)
-        self.kv_pages_live = pc.get("kv_pages_live", 0)
-        self.kv_pages_copied = pc.get("kv_pages_copied", 0)
-        self.kv_pages_in_runs = pc.get("kv_pages_in_runs", 0)
-        self.ut_steps = pc.get("ut_steps", 0)
-        self._pos_host = np.asarray(self._state["pos"]).astype(np.int64)
-        self._run_groups = self._table_run_groups(
-            np.asarray(self._state["table"]))

@@ -13,7 +13,7 @@ right where it is consumed — XLA fuses the scale multiply into the gemm
 prologue, so HBM sees ~4x (int8) / ~8x (int4) fewer weight bytes per
 decode step with no separate dequant pass.
 
-Composition (the same contract as paged/spec/tp):
+Composition (the same contract as spec/tp):
 
 - everything is default-off: pass ``quant=QuantConfig(...)`` (or
   ``quant="int8"``/``"int4"``) to the ``ContinuousBatchingEngine``
@@ -22,7 +22,7 @@ Composition (the same contract as paged/spec/tp):
 - an explicitly passed backend is NEVER rerouted by the env knob, and
   ``quant=`` alongside an explicit backend is refused loudly (the
   quantization is baked into the backend at construction);
-- composes with ``paged=`` (int8 KV arena + int8 weights = the
+- composes with ``kv_int8=`` (int8 KV arena + int8 weights = the
   bandwidth-true stack), ``spec=`` (the verify program dequantizes the
   same codes), and ``tp=`` in mode="exact" (per-shard scales ride the
   SAME PartitionSpecs as their weights: a column-sharded weight's

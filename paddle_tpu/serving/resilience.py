@@ -29,7 +29,7 @@ Snapshots are single npz files written atomically (tmp + rename via
 state plus host metadata as an embedded JSON string — a ``Server``
 killed mid-stream restores in a fresh process and finishes every
 stream bit-identical to an uninterrupted run (pinned in
-tests/test_resilience.py for the dense AND paged engines).
+tests/test_resilience.py).
 """
 from __future__ import annotations
 

@@ -15,9 +15,9 @@ in a single forward:
   ``pos``, so row ``i`` of the window attends its own prefix *plus the
   drafts before it*, exactly the causal semantics verification needs;
 - the KV write is a masked ``k+1``-wide scatter: all ``k+1`` candidate
-  K/V entries land at ``pos .. pos+k`` up front (paged: through the
-  slot's block table, with positions past the table routed to the
-  trash block);
+  K/V entries land at ``pos .. pos+k`` up front, through the slot's
+  block table, with positions past the table routed to the trash
+  block;
 - **greedy acceptance** keeps the longest draft prefix matching the
   target model's own argmax, plus one bonus token: the emitted tokens
   of a step are ``t_0 .. t_a`` where ``t_i = argmax(logits at position
@@ -35,10 +35,10 @@ the NEXT step writes its own ``k+1`` window starting at ``pos+n`` —
 which covers every junk position — before attention can read them
 (row ``i`` masks ``t_idx <= pos+n+i``, and positions up to ``pos+n+i``
 are freshly written this step or emitted history). ``pos`` never
-reaches a rejected position, so no junk entry is ever attended, dense
-or paged. Paged slots already allocate blocks for the full request up
-front (``blocks_needed``), so the max advance is always covered; draft
-positions past the table width scatter into the reserved trash block.
+reaches a rejected position, so no junk entry is ever attended. Slots
+allocate blocks for the full request up front (``blocks_needed``), so
+the max advance is always covered; draft positions past the table width
+scatter into the reserved trash block.
 
 Seeded sampling initially falls back to ``k = 0``: a sampled slot's
 verify step emits exactly one token through the SAME per-slot
@@ -50,14 +50,14 @@ schedule — a follow-up, not a silent break).
 Everything is default-off: pass ``spec=SpecConfig(k=...)`` (or
 ``spec=True``) to ``ContinuousBatchingEngine``, or set
 ``PT_SERVING_SPEC=<k>`` (``PT_SERVING_SPEC_NGRAM`` bounds the drafter's
-n-gram length). Composes with ``paged=True``; tensor-parallel serving
-(``tp=``) is not yet composed with spec and is refused loudly.
+n-gram length). Tensor-parallel serving (``tp=``) is not yet composed
+with spec and is refused loudly.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Optional, Sequence, Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -68,14 +68,13 @@ from ..observability.tracing import named_program, span as _span
 from ..utils import faults
 from ..utils.flags import env_int
 from .engine import (DECODE_PROGRAM, ContinuousBatchingEngine,
-                     ModelStepBackend, _M_COMPILES, _M_DECODE_TOKENS,
-                     _M_STEPS, _M_TOKENS, slot_sample_logits)
-from .paging import (PagedEngine, PagedModelStepBackend,
-                     refuse_looped_cache)
+                     _M_COMPILES, _M_DECODE_TOKENS, _M_STEPS, _M_TOKENS,
+                     slot_sample_logits)
+from .paging import PagedModelStepBackend, refuse_looped_cache
 
 __all__ = ["SpecConfig", "resolve_spec_config", "ngram_propose",
-           "build_spec_block_fn", "SpecModelStepBackend",
-           "SpecPagedStepBackend", "SpecEngine", "SpecPagedEngine"]
+           "build_spec_block_fn", "SpecPagedStepBackend",
+           "SpecPagedEngine"]
 
 # speculative-decode metric families (no-ops until metrics.enable() /
 # PT_METRICS; registered at import so the catalog is complete at zero)
@@ -207,13 +206,12 @@ def ngram_propose(history, k: int, ngram_max: int = 3,
 # the ONE compiled verify program
 # ---------------------------------------------------------------------------
 
-def build_spec_block_fn(pure, k: int, trace_counter=None,
-                        paged: bool = False):
+def build_spec_block_fn(pure, k: int, trace_counter=None):
     """The spec engine's ONE decode program: a single draft-verify step
     over the slot pool. ``pure`` must be the all-positions verify head
     (``build_decode_step(..., all_positions=True)``) — it returns
     (S, k+1, V) log-probs for the window ``[tok, d_1 .. d_k]`` written
-    at per-row positions ``pos .. pos+k``.
+    at per-row positions ``pos .. pos+k`` through the slot's block table.
 
     In-graph per slot: targets ``t_i = argmax`` per position (row 0 of
     a sampled slot goes through the SAME key-split +
@@ -235,13 +233,9 @@ def build_spec_block_fn(pure, k: int, trace_counter=None,
         new_key, sub = sp[:, 0], sp[:, 1]
         toks_in = jnp.concatenate(
             [st["tok"][:, None], draft.astype(jnp.int32)], axis=1)
-        if paged:
-            tbl = jnp.where(st["live"][:, None], st["table"], 0)
-            logp, cf = pure(pv, bv, toks_in, cache_flat, st["pos"],
-                            None, None, tbl)
-        else:
-            logp, cf = pure(pv, bv, toks_in, cache_flat, st["pos"],
-                            None, st["pad"])
+        tbl = jnp.where(st["live"][:, None], st["table"], 0)
+        logp, cf = pure(pv, bv, toks_in, cache_flat, st["pos"],
+                        None, None, tbl)
         # (S, W, V) log-probs; NaN anywhere in the slot's window marks
         # a poisoned row (finite weights/cache cannot produce NaN)
         ok = ~jnp.any(jnp.isnan(logp), axis=(1, 2))
@@ -288,14 +282,22 @@ def build_spec_block_fn(pure, k: int, trace_counter=None,
 # backends
 # ---------------------------------------------------------------------------
 
-class _SpecBackendMixin:
-    """Adds the verify program to a model step backend. The plain
-    decode-block jit stays constructed (jax.jit wrapping is free until
-    traced) but the spec engine never calls it — ``decode_traces``
-    counts the verify block, so the compile-count pin stays == 1."""
+class SpecPagedStepBackend(PagedModelStepBackend):
+    """The model backend with the (S, k+1) verify program (chunked
+    prefill is inherited unchanged). The plain decode-block jit stays
+    constructed (jax.jit wrapping is free until traced) but the spec
+    engine never calls it — ``decode_traces`` counts the verify block,
+    so the compile-count pin stays == 1."""
 
-    def _setup_spec(self, model, spec: SpecConfig, paged: bool):
+    def __init__(self, model, num_slots: int, max_len: int,
+                 decode_block: int, block_size: int, num_blocks: int,
+                 kv_int8: bool, prefill_chunk: int, spec: SpecConfig,
+                 quant=None):
         from ..models.generation import build_decode_step
+        refuse_looped_cache(model, "speculative decoding")
+        super().__init__(model, num_slots, max_len, decode_block,
+                         block_size, num_blocks, kv_int8, prefill_chunk,
+                         quant=quant)
         self.spec = spec
         self.spec_k = spec.k
         # the verify head dequantizes the same weight codes as the
@@ -304,8 +306,7 @@ class _SpecBackendMixin:
             build_decode_step(model, None, self._tree_holder,
                               all_positions=True))
         self._spec_jit = jax.jit(
-            build_spec_block_fn(verify, spec.k, self.decode_traces,
-                                paged=paged),
+            build_spec_block_fn(verify, spec.k, self.decode_traces),
             donate_argnums=(2, 3))
         # one verify step per host round-trip (drafts are host inputs)
         self.block_size = 1
@@ -315,42 +316,23 @@ class _SpecBackendMixin:
                               draft, n_draft)
 
 
-class SpecModelStepBackend(_SpecBackendMixin, ModelStepBackend):
-    """Dense slot-pool backend with the (S, k+1) verify program."""
-
-    def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, spec: SpecConfig, quant=None):
-        super().__init__(model, num_slots, max_len, decode_block,
-                         quant=quant)
-        self._setup_spec(model, spec, paged=False)
-
-
-class SpecPagedStepBackend(_SpecBackendMixin, PagedModelStepBackend):
-    """Paged-arena backend with the (S, k+1) verify program (chunked
-    prefill is inherited unchanged)."""
-
-    def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, block_size: int, num_blocks: int,
-                 kv_int8: bool, prefill_chunk: int, spec: SpecConfig,
-                 quant=None):
-        refuse_looped_cache(model, "speculative decoding")
-        super().__init__(model, num_slots, max_len, decode_block,
-                         block_size, num_blocks, kv_int8, prefill_chunk,
-                         quant=quant)
-        self._setup_spec(model, spec, paged=True)
-
-
 # ---------------------------------------------------------------------------
 # engines
 # ---------------------------------------------------------------------------
 
-class _SpecEngineMixin:
-    """Draft-verify step loop + acceptance accounting shared by the
-    dense and paged speculative engines. Overrides ``step_block`` with
-    the verify dispatch; admission, cancellation, deadlines, the NaN
-    quarantine and snapshot/restore all ride the base machinery."""
+class SpecPagedEngine(ContinuousBatchingEngine):
+    """The engine with draft-verify decode, constructed via
+    ``ContinuousBatchingEngine(..., spec=SpecConfig(k=...))`` (or
+    ``PT_SERVING_SPEC=<k>``). Overrides ``step_block`` with the verify
+    dispatch; admission, chunked prefill, prefix reuse, cancellation,
+    deadlines, the NaN quarantine and snapshot/restore all ride the base
+    machinery — the verify window's junk writes past a slot's table land
+    in the trash block, and accepted positions are covered by the blocks
+    the request already allocated up front."""
 
-    def _init_spec(self, spec: Optional[SpecConfig], backend, tp=None):
+    def __init__(self, model=None, num_slots: int = 4,
+                 max_len: int = 256, decode_block: int = 8, *,
+                 backend=None, spec=None, tp=None, **kw):
         from .tp import resolve_tp_config
         if backend is None and resolve_tp_config(tp) is not None:
             raise NotImplementedError(
@@ -361,13 +343,22 @@ class _SpecEngineMixin:
             cfg = getattr(backend, "spec", None)
             if cfg is None:
                 raise ValueError(
-                    "speculative engines need a spec backend "
-                    "(SpecModelStepBackend / SpecPagedStepBackend); got "
-                    f"{type(backend).__name__}")
+                    "the speculative engine needs a spec backend "
+                    f"(SpecPagedStepBackend); got {type(backend).__name__}")
             self.spec = cfg
         else:
             self.spec = resolve_spec_config(spec) or SpecConfig()
         self.spec_k = self.spec.k
+        super().__init__(model, num_slots, max_len, decode_block,
+                         backend=backend, **kw)
+
+    def _build_backend(self, model, num_slots, max_len, decode_block,
+                       block_size, num_blocks, kv_int8, prefill_chunk,
+                       quant=None):
+        return SpecPagedStepBackend(model, num_slots, max_len,
+                                    decode_block, block_size, num_blocks,
+                                    kv_int8, prefill_chunk, self.spec,
+                                    quant=quant)
 
     # -- lifecycle ---------------------------------------------------------
     def reset(self):
@@ -527,75 +518,9 @@ class _SpecEngineMixin:
             raise ValueError(
                 f"snapshot was taken at spec k={sm['k']}, this engine "
                 f"runs k={self.spec.k} — the verify program shape (and "
-                "the paged write window) must match to resume")
+                "the write window) must match to resume")
         super().restore_state(meta, arrays)
         if sm is not None:
             self.verify_steps = sm["verify_steps"]
             self.draft_proposed = sm["draft_proposed"]
             self.draft_accepted = sm["draft_accepted"]
-
-
-class SpecEngine(_SpecEngineMixin, ContinuousBatchingEngine):
-    """Dense slot-pool engine with draft-verify decode. Constructed via
-    ``ContinuousBatchingEngine(..., spec=SpecConfig(k=...))`` (or
-    ``PT_SERVING_SPEC=<k>``)."""
-
-    def __init__(self, model=None, num_slots: int = 4,
-                 max_len: int = 256, decode_block: int = 8,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 backend=None, *, paged: Optional[bool] = None,
-                 spec=None, tp=None, quant=None):
-        if paged:
-            # same loud-refusal rule as spec= on a direct subclass
-            # ctor: silently serving DENSE from a paged= request would
-            # be a misconfiguration, not a preference
-            raise ValueError(
-                "SpecEngine is the dense speculative engine — use the "
-                "ContinuousBatchingEngine factory (paged=True, "
-                "spec=...) or SpecPagedEngine for the paged one")
-        self._init_spec(spec, backend, tp)
-        super().__init__(model, num_slots, max_len, decode_block,
-                         prompt_buckets, backend, paged=False,
-                         quant=quant)
-
-    def _build_backend(self, model, num_slots, max_len, decode_block,
-                       quant=None):
-        return SpecModelStepBackend(model, num_slots, max_len,
-                                    decode_block, self.spec, quant=quant)
-
-
-class SpecPagedEngine(_SpecEngineMixin, PagedEngine):
-    """Paged-arena engine with draft-verify decode (chunked prefill,
-    prefix reuse and the block manager are inherited unchanged — the
-    verify window's junk writes past a slot's table land in the trash
-    block, and accepted positions are covered by the blocks the
-    request already allocated up front)."""
-
-    def __init__(self, model=None, num_slots: int = 4,
-                 max_len: int = 256, decode_block: int = 8,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 backend=None, *, paged: bool = True, spec=None,
-                 block_size: Optional[int] = None,
-                 num_blocks: Optional[int] = None,
-                 kv_int8: Optional[bool] = None,
-                 prefill_chunk: Optional[int] = None,
-                 hash_fn=None, tp=None, quant=None):
-        if paged is not None and not paged:
-            raise ValueError(
-                "SpecPagedEngine is the paged speculative engine — use "
-                "the ContinuousBatchingEngine factory (spec=...) or "
-                "SpecEngine for the dense one")
-        self._init_spec(spec, backend, tp)
-        super().__init__(model, num_slots, max_len, decode_block,
-                         prompt_buckets, backend, paged=True,
-                         block_size=block_size, num_blocks=num_blocks,
-                         kv_int8=kv_int8, prefill_chunk=prefill_chunk,
-                         hash_fn=hash_fn, quant=quant)
-
-    def _build_paged_backend(self, model, num_slots, max_len,
-                             decode_block, block_size, num_blocks,
-                             kv_int8, prefill_chunk, quant=None):
-        return SpecPagedStepBackend(model, num_slots, max_len,
-                                    decode_block, block_size,
-                                    num_blocks, kv_int8, prefill_chunk,
-                                    self.spec, quant=quant)

@@ -1,16 +1,15 @@
 """Tensor-parallel serving: the ONE compiled decode block sharded over
 a TPU mesh.
 
-The slot-pool engine (engine.py / paging.py) runs its single compiled
-decode program on one chip, so the max servable model is one chip's HBM
+The engine (engine.py / paging.py) runs its single compiled decode
+program on one chip, so the max servable model is one chip's HBM
 and decode bandwidth is one chip's. This module shards that same
 program — and the chunked-prefill program — over a
 ``jax.sharding.Mesh`` via ``shard_map``:
 
-- the KV cache is sharded on the **kv-head axis**: the dense
-  ``(S, max_len, kvh, d)`` per-slot rows AND the paged
+- the KV cache is sharded on the **kv-head axis**: the
   ``(num_blocks, block_size, kvh, d)`` arena (plus its int8 scale
-  arrays) split dim 2 across the TP axes, so per-chip KV HBM shrinks by
+  arrays) splits dim 2 across the TP axes, so per-chip KV HBM shrinks by
   the TP degree — the single-chip ceiling the ROADMAP names;
 - attention weights are column-sharded (q/k/v out dims — each device
   owns a contiguous group of heads aligned with its kv-head shard),
@@ -41,7 +40,7 @@ Two weight layouts, selected by ``TPConfig.mode``:
   bound against the live cache/state and refuses to run over budget.
 
 Everything is default-off: pass ``tp=TPConfig(...)`` (or ``tp=True``)
-to ``ContinuousBatchingEngine`` / the paged engine, or set
+to ``ContinuousBatchingEngine``, or set
 ``PT_SERVING_TP=1`` (axes via ``PT_SERVING_TP_AXES``, comma-separated
 mesh axis names, default ``"mp"``; ``PT_SERVING_TP_MODE`` /
 ``PT_SERVING_TP_INT8`` select the layout). An explicitly passed
@@ -55,7 +54,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributed.collectives.hierarchical import plan_hierarchy
@@ -69,12 +67,10 @@ from ..utils import tp_hooks
 from ..utils.tp_hooks import (current_tp, maybe_gather,  # noqa: F401
                               maybe_gather_logits, maybe_reduce)
 from ..utils.flags import env_bool, env_str
-from .engine import (ModelStepBackend, build_slot_block_fn,
-                     build_slot_prefill_fn)
+from .engine import build_paged_chunk_fn, build_slot_block_fn
 from .paging import PagedModelStepBackend, refuse_looped_cache
 
-__all__ = ["TPConfig", "resolve_tp_config", "ShardedModelStepBackend",
-           "ShardedPagedStepBackend"]
+__all__ = ["TPConfig", "resolve_tp_config", "ShardedPagedStepBackend"]
 
 # mesh-shape gauges (no-ops until metrics.enable()/PT_METRICS): the
 # observability satellite — per-collective bytes/calls already ride
@@ -148,7 +144,7 @@ def resolve_tp_config(tp) -> Optional[TPConfig]:
 
 
 # ---------------------------------------------------------------------------
-# backend mixin: spec derivation + shard_map wrapping
+# spec derivation
 # ---------------------------------------------------------------------------
 
 def _param_pspec(name: str, sharding_spec, mode: str,
@@ -179,8 +175,58 @@ def _param_pspec(name: str, sharding_spec, mode: str,
     return P(*[axes if j == i else None for j in range(len(dims))])
 
 
-class _TPBackendMixin:
-    """Shared TP plumbing for the dense and paged sharded backends."""
+# ---------------------------------------------------------------------------
+# the sharded backend
+# ---------------------------------------------------------------------------
+
+class ShardedPagedStepBackend(PagedModelStepBackend):
+    """The model backend sharded over the TP mesh: the shared KV arena
+    (fp32 or int8 codes + scales) shards its kv-head dim, block tables
+    stay replicated in-state, and both the decode block and the ONE
+    chunked-prefill program run under ``shard_map``. Exact-mode streams
+    are bit-identical to the 1-chip engine (and therefore to
+    ``generate()``)."""
+
+    def __init__(self, model, num_slots: int, max_len: int,
+                 decode_block: int, block_size: int, num_blocks: int,
+                 kv_int8: bool, prefill_chunk: int, tp: TPConfig,
+                 quant=None):
+        refuse_looped_cache(model, "tensor-parallel serving")
+        super().__init__(model, num_slots, max_len, decode_block,
+                         block_size, num_blocks, kv_int8, prefill_chunk,
+                         quant=quant)
+        self._setup_tp(model, tp)
+        self._block_jit = self._shard_jit(
+            build_slot_block_fn(self._pure, self.block_size,
+                                self.decode_traces),
+            in_specs=(self._pv_pspecs, self._bv_pspecs,
+                      self._cache_pspecs, self._state_pspecs),
+            out_specs=(self._cache_pspecs, self._state_pspecs,
+                       P(), P(), P()),
+            donate=(2, 3))
+        self._chunk_jit = self._shard_jit(
+            build_paged_chunk_fn(self._pure, prefill_chunk,
+                                 self.prefill_traces),
+            in_specs=(self._pv_pspecs, self._bv_pspecs, P(),
+                      self._cache_pspecs, P(), P(), P(), P(), P(), P(),
+                      P()),
+            out_specs=(P(), self._cache_pspecs),
+            donate=(3,))
+
+    def decode_block(self, cache_flat, state):
+        self._check_int8_gate(cache_flat, state)
+        out = self._block_jit(self._pv, self._bv, cache_flat, state)
+        self._note_collectives("tp_block", self.block_size,
+                               self.block_size * self.num_slots)
+        return out
+
+    def prefill_chunk(self, ids, cache_flat, table_row, start_pos,
+                      n_valid, key, temp, topk, topp):
+        out = self._chunk_jit(self._pv, self._bv, ids, cache_flat,
+                              table_row, start_pos, n_valid, key, temp,
+                              topk, topp)
+        self._note_collectives("tp_prefill", 1, self.prefill_chunk_len)
+        return out
 
     def _setup_tp(self, model, tp: TPConfig):
         mesh = tp.mesh if tp.mesh is not None else get_current_mesh()
@@ -254,7 +300,7 @@ class _TPBackendMixin:
                                       scale_pspec(self._pv_pspecs[i],
                                                   scales))
         # the KV cache shards its kv-head dim (dim 2 of every pool leaf,
-        # 4D arenas/rows and 3D int8 scale arrays alike)
+        # 4D arenas and 3D int8 scale arrays alike)
         for shape, _ in self.pool_specs:
             if shape[2] % d:
                 raise ValueError(
@@ -299,8 +345,8 @@ class _TPBackendMixin:
         never cross the host-level ``collectives`` wrappers (where the
         pt_collectives_* families are normally noted), so the backend
         accounts them here, derived from the model dims: a decode step
-        moves S tokens (one per slot), a dense prefill bucket_len
-        tokens, a prefill chunk prefill_chunk tokens — each compiled
+        moves S tokens (one per slot), a prefill chunk prefill_chunk
+        tokens — each compiled
         dispatch fires 2L+1 collectives regardless of token count.
         Noted under mode="tp_graph" with op="tp_block" (decode) vs
         op="tp_prefill", so per-decode-step rates never mix in
@@ -385,24 +431,18 @@ class _TPBackendMixin:
         the live cache/state. A separate tiny program — the decode
         block itself stays unchanged and its compile count stays 1."""
         spec = self._tp_spec
-        pure, paged = self._pure, isinstance(self,
-                                             PagedModelStepBackend)
+        pure = self._pure
 
         def probe(pv, bv, cache_flat, state):
             sink: list = []
             tp_hooks._BOUND_SINK = sink
             try:
                 with tp_hooks.active(spec):
-                    if paged:
-                        tbl = jnp.where(state["live"][:, None],
-                                        state["table"], 0)
-                        logp, _ = pure(pv, bv, state["tok"][:, None],
-                                       cache_flat, state["pos"], None,
-                                       None, tbl)
-                    else:
-                        logp, _ = pure(pv, bv, state["tok"][:, None],
-                                       cache_flat, state["pos"], None,
-                                       state["pad"])
+                    tbl = jnp.where(state["live"][:, None],
+                                    state["table"], 0)
+                    logp, _ = pure(pv, bv, state["tok"][:, None],
+                                   cache_flat, state["pos"], None, None,
+                                   tbl)
             finally:
                 tp_hooks._BOUND_SINK = None
             del logp
@@ -441,109 +481,3 @@ class _TPBackendMixin:
                 f"exceeds TPConfig.int8_max_error={limit:.3e} — run "
                 "fp32 (int8=False) or raise the budget")
         self._int8_gate_pending = False
-
-
-# ---------------------------------------------------------------------------
-# sharded backends
-# ---------------------------------------------------------------------------
-
-class ShardedModelStepBackend(_TPBackendMixin, ModelStepBackend):
-    """Dense slot-pool backend with the decode block and per-bucket
-    prefills sharded over the TP mesh. Exact-mode streams are
-    bit-identical to :class:`ModelStepBackend` on one chip."""
-
-    def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, tp: TPConfig, quant=None):
-        super().__init__(model, num_slots, max_len, decode_block,
-                         quant=quant)
-        self._setup_tp(model, tp)
-        # local-shape row specs: the prefill program zero-fills its
-        # fresh cache row INSIDE shard_map, where shapes are per-device
-        d = self.tp_degree
-        self._row_specs_local = tuple(
-            (shape[:2] + (shape[2] // d,) + shape[3:], dtype)
-            for shape, dtype in self.row_specs)
-        self._row_out_pspecs = tuple(
-            P(None, None, self.tp_plan.axes) if len(shape) == 3
-            else P(None, None, self.tp_plan.axes, None)
-            for shape, _ in self.row_specs)
-        self._block_jit = self._shard_jit(
-            build_slot_block_fn(self._pure, self.block_size,
-                                self.decode_traces),
-            in_specs=(self._pv_pspecs, self._bv_pspecs,
-                      self._cache_pspecs, self._state_pspecs),
-            out_specs=(self._cache_pspecs, self._state_pspecs,
-                       P(), P(), P()),
-            donate=(2, 3))
-        self._prefill_jits = {}
-
-    def decode_block(self, cache_flat, state):
-        self._check_int8_gate(cache_flat, state)
-        out = self._block_jit(self._pv, self._bv, cache_flat, state)
-        self._note_collectives("tp_block", self.block_size,
-                               self.block_size * self.num_slots)
-        return out
-
-    def prefill(self, bucket_len, ids, pad, key, temp, topk, topp):
-        fn = self._prefill_jits.get(bucket_len)
-        if fn is None:
-            fn = self._shard_jit(
-                build_slot_prefill_fn(self._pure,
-                                      self._row_specs_local),
-                in_specs=(self._pv_pspecs, self._bv_pspecs,
-                          P(), P(), P(), P(), P(), P()),
-                out_specs=(P(), self._row_out_pspecs))
-            self._prefill_jits[bucket_len] = fn
-        out = fn(self._pv, self._bv, ids, pad, key, temp, topk, topp)
-        self._note_collectives("tp_prefill", 1, bucket_len)
-        return out
-
-
-class ShardedPagedStepBackend(_TPBackendMixin, PagedModelStepBackend):
-    """Paged twin: the shared KV arena (fp32 or int8 codes + scales)
-    shards its kv-head dim, block tables stay replicated in-state, and
-    both the decode block and the ONE chunked-prefill program run under
-    ``shard_map``. Exact-mode paged streams are bit-identical to the
-    1-chip paged engine (and therefore to dense / ``generate()``)."""
-
-    def __init__(self, model, num_slots: int, max_len: int,
-                 decode_block: int, block_size: int, num_blocks: int,
-                 kv_int8: bool, prefill_chunk: int, tp: TPConfig,
-                 quant=None):
-        from .engine import build_paged_chunk_fn
-        refuse_looped_cache(model, "tensor-parallel serving")
-        super().__init__(model, num_slots, max_len, decode_block,
-                         block_size, num_blocks, kv_int8, prefill_chunk,
-                         quant=quant)
-        self._setup_tp(model, tp)
-        self._block_jit = self._shard_jit(
-            build_slot_block_fn(self._pure, self.block_size,
-                                self.decode_traces, paged=True),
-            in_specs=(self._pv_pspecs, self._bv_pspecs,
-                      self._cache_pspecs, self._state_pspecs),
-            out_specs=(self._cache_pspecs, self._state_pspecs,
-                       P(), P(), P()),
-            donate=(2, 3))
-        self._chunk_jit = self._shard_jit(
-            build_paged_chunk_fn(self._pure, prefill_chunk,
-                                 self.prefill_traces),
-            in_specs=(self._pv_pspecs, self._bv_pspecs, P(),
-                      self._cache_pspecs, P(), P(), P(), P(), P(), P(),
-                      P()),
-            out_specs=(P(), self._cache_pspecs),
-            donate=(3,))
-
-    def decode_block(self, cache_flat, state):
-        self._check_int8_gate(cache_flat, state)
-        out = self._block_jit(self._pv, self._bv, cache_flat, state)
-        self._note_collectives("tp_block", self.block_size,
-                               self.block_size * self.num_slots)
-        return out
-
-    def prefill_chunk(self, ids, cache_flat, table_row, start_pos,
-                      n_valid, key, temp, topk, topp):
-        out = self._chunk_jit(self._pv, self._bv, ids, cache_flat,
-                              table_row, start_pos, n_valid, key, temp,
-                              topk, topp)
-        self._note_collectives("tp_prefill", 1, self.prefill_chunk_len)
-        return out

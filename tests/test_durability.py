@@ -6,8 +6,8 @@ a disk spill tier for watermark-evicted prefix chains, and the
 headline pin: a whole fleet killed MID-DECODE — streams queued,
 mid-chunked-prefill, shipped-in-transit, adopted-and-decoding —
 recovers via ``Fleet.recover`` with every completed stream
-BIT-IDENTICAL to an uncrashed run (greedy AND seeded-sampled; dense,
-paged, paged+kv_int8), compile counts still 1 on the reused arenas,
+BIT-IDENTICAL to an uncrashed run (greedy AND seeded-sampled; 16- and
+8-token blocks, kv_int8), compile counts still 1 on the reused arenas,
 zero block leaks, exactly one terminal per request across pre- and
 post-crash state, and a torn journal tail truncated LOUDLY."""
 import json
@@ -21,8 +21,7 @@ import paddle_tpu as paddle
 from paddle_tpu.distributed import checkpoint as _ckpt
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (ContinuousBatchingEngine, DecodeWorker,
-                                Fleet, PrefillDenseEngine,
-                                PrefillPagedEngine, PrefillWorker,
+                                Fleet, PrefillPagedEngine, PrefillWorker,
                                 PrefixSpillStore, RequestFailure,
                                 Server, WriteAheadJournal)
 from paddle_tpu.serving import durability as dur
@@ -31,8 +30,9 @@ from paddle_tpu.utils import faults
 
 @pytest.fixture(scope="module")
 def setup():
-    """One tiny model + paged 2-prefill/2-decode engines, plus dense
-    and kv_int8 single-prefill sets for the recovery matrix. reset()
+    """One tiny model + 2-prefill/2-decode engines at 8-token blocks,
+    plus single-prefill sets at the default 16-token geometry and with a
+    kv_int8 arena for the recovery matrix. reset()
     frees slots/blocks, never the compiled programs — so a 'crashed'
     fleet's engines stand in for a fresh process that re-traces once."""
     paddle.seed(0)
@@ -43,11 +43,10 @@ def setup():
     pf = [PrefillPagedEngine(model, **kw) for _ in range(2)]
     dc = [ContinuousBatchingEngine(model, paged=True, **kw)
           for _ in range(2)]
-    pf_d = PrefillDenseEngine(model, num_slots=2, max_len=64,
-                              decode_block=4, prompt_buckets=(8, 16, 32))
+    pf_d = PrefillPagedEngine(model, num_slots=2, max_len=64,
+                              decode_block=4)
     dc_d = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                    decode_block=4,
-                                    prompt_buckets=(8, 16, 32))
+                                    decode_block=4)
     pf_8 = PrefillPagedEngine(model, kv_int8=True, **kw)
     dc_8 = ContinuousBatchingEngine(model, paged=True, kv_int8=True,
                                     **kw)

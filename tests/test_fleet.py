@@ -1,7 +1,7 @@
 """Disaggregated prefill/decode fleet (serving/fleet.py + handoff.py):
 a request prefilled on worker A and decoded on worker B streams
 BIT-IDENTICAL to a single-replica Server (greedy AND seeded-sampled;
-dense, paged, paged+kv_int8) with decode/prefill compile counts pinned
+16- and 8-token blocks, kv_int8) with decode/prefill compile counts pinned
 at 1 and zero new compiled programs on the decode steady path. Plus:
 the versioned bytes-true wire format (int8 codes ship quantized, never
 dequantized in transit), chained-SHA1 prefix-affinity routing with
@@ -17,8 +17,8 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (ContinuousBatchingEngine, DecodeWorker,
                                 Fleet, FleetRouter, KVHandoff,
-                                PrefillDenseEngine, PrefillPagedEngine,
-                                PrefillWorker, RequestFailure,
+                                PrefillPagedEngine, PrefillWorker,
+                                RequestFailure,
                                 ResilienceConfig, Server, decode_handoff,
                                 encode_handoff, reshard_kv_chunks)
 from paddle_tpu.utils import faults
@@ -26,9 +26,10 @@ from paddle_tpu.utils import faults
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + the paged 2-prefill/2-decode engine set, the dense
-    1/1 pair and the int8 1/1 pair for the whole file (reset() frees
-    slots/blocks, never the compiled programs)."""
+    """One model + the 2-prefill/2-decode engine set at 8-token blocks,
+    a 1/1 pair at the default 16-token geometry and the int8 1/1 pair
+    for the whole file (reset() frees slots/blocks, never the compiled
+    programs)."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
@@ -37,11 +38,10 @@ def setup():
     pf = [PrefillPagedEngine(model, **kw) for _ in range(2)]
     dc = [ContinuousBatchingEngine(model, paged=True, **kw)
           for _ in range(2)]
-    pf_d = PrefillDenseEngine(model, num_slots=2, max_len=64,
-                              decode_block=4, prompt_buckets=(8, 16))
+    pf_d = PrefillPagedEngine(model, num_slots=2, max_len=64,
+                              decode_block=4)
     dc_d = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                    decode_block=4,
-                                    prompt_buckets=(8, 16))
+                                    decode_block=4)
     pf_8 = PrefillPagedEngine(model, kv_int8=True, **kw)
     dc_8 = ContinuousBatchingEngine(model, paged=True, kv_int8=True,
                                     **kw)
@@ -123,6 +123,20 @@ class TestWireFormat:
             decode_handoff(_corrupt())
         h.meta["version"] = 99       # meta keys override the stamp
         with pytest.raises(ValueError, match="version"):
+            decode_handoff(encode_handoff(h))
+
+    @pytest.mark.parametrize("kind", ["dense", "prefix", None])
+    def test_foreign_kind_refused_on_decode(self, kind):
+        """A hand-off payload is input from outside the process: one
+        whose kind is not "paged" (the removed engine's "dense", a
+        prefix-fetch kind, none) is refused when it is decoded."""
+        meta = {"request": {"request_id": 7}, "tok0": 3, "pos0": 5,
+                "rem0": 4}
+        if kind is not None:
+            meta["kind"] = kind
+        h = KVHandoff(meta=meta,
+                      arrays={"prompt": np.arange(5, dtype=np.int32)})
+        with pytest.raises(ValueError, match="payload in a"):
             decode_handoff(encode_handoff(h))
 
     def test_reshard_kv_chunks_identity(self):
@@ -612,8 +626,8 @@ class TestCompatAndRefusals:
     def test_mixed_fleet_and_geometry_refused(self, setup):
         model, cfg, pf, dc, (pf_d, dc_d), (pf_8, dc_8) = setup
         _reset(pf[0], dc_d, dc[0], dc_8)
-        with pytest.raises(ValueError, match="dense/paged"):
-            _fleet([pf[0]], [dc_d])
+        with pytest.raises(ValueError, match="mismatch"):
+            _fleet([pf[0]], [dc_d])   # 16-token blocks beside 8-token
         with pytest.raises(ValueError, match="layout mismatch"):
             _fleet([pf[0]], [dc_8])   # int8 arena: different leaves
 
@@ -624,7 +638,7 @@ class TestCompatAndRefusals:
         model, cfg, pf, dc, (pf_d, dc_d), _ = setup
         _reset(pf[0], dc[0], dc_d)
         fleet = _fleet([pf[0]], [dc[0]])
-        with pytest.raises(ValueError, match="dense/paged"):
+        with pytest.raises(ValueError, match="mismatch"):
             fleet.add_decode_worker(DecodeWorker(dc_d))
         with pytest.raises(ValueError, match="already in the fleet"):
             fleet.add_decode_worker(DecodeWorker(dc[1],

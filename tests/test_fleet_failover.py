@@ -8,7 +8,7 @@ transport with ~1% wire faults armed has every lost stream redriven —
 re-prefilled on a surviving prefill worker via a ``redrive``
 ResumeState with the heartbeat-carried tokens and the host-replayed rng
 key — and completes BIT-IDENTICAL to an unfailed run (greedy AND
-seeded-sampled; dense, paged, paged+kv_int8), compile counts still 1,
+seeded-sampled; 16- and 8-token blocks, kv_int8), compile counts still 1,
 zero block leaks on every surviving arena, and exactly one terminal per
 request across every worker's trace."""
 import os
@@ -21,8 +21,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.observability import ObservabilityConfig
 from paddle_tpu.serving import (ContinuousBatchingEngine, DecodeWorker,
-                                Fleet, PrefillDenseEngine,
-                                PrefillPagedEngine, PrefillWorker,
+                                Fleet, PrefillPagedEngine, PrefillWorker,
                                 Request, RequestFailure, ResumeState,
                                 Server, SocketTransport, TransportError,
                                 decode_handoff, encode_handoff)
@@ -37,10 +36,10 @@ WIRE_FAULTS = ("transport.partial_write:p=0.01;"
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + paged 2-prefill/2-decode engines, a dense
-    1-prefill/2-decode set and an int8 1-prefill/2-decode set (every
-    kill test needs a SURVIVING decode worker). reset() frees
-    slots/blocks, never the compiled programs."""
+    """One model + 2-prefill/2-decode engines at 8-token blocks, a
+    1-prefill/2-decode set at the default 16-token geometry and an int8
+    1-prefill/2-decode set (every kill test needs a SURVIVING decode
+    worker). reset() frees slots/blocks, never the compiled programs."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
@@ -49,11 +48,10 @@ def setup():
     pf = [PrefillPagedEngine(model, **kw) for _ in range(2)]
     dc = [ContinuousBatchingEngine(model, paged=True, **kw)
           for _ in range(2)]
-    pf_d = PrefillDenseEngine(model, num_slots=2, max_len=64,
-                              decode_block=4, prompt_buckets=(8, 16, 32))
+    pf_d = PrefillPagedEngine(model, num_slots=2, max_len=64,
+                              decode_block=4)
     dc_d = [ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                     decode_block=4,
-                                     prompt_buckets=(8, 16, 32))
+                                     decode_block=4)
             for _ in range(2)]
     pf_8 = PrefillPagedEngine(model, kv_int8=True, **kw)
     dc_8 = [ContinuousBatchingEngine(model, paged=True, kv_int8=True,
@@ -320,7 +318,7 @@ class TestAdoptIdempotency:
 class TestPrefillRedriveResume:
     def test_user_preemption_resume_still_refused(self, setup):
         """Regression pin: the PR 14 refusal (message and all)
-        survives for non-redrive resumes on BOTH prefill flavours."""
+        survives for non-redrive resumes at both geometries."""
         model, cfg, pf, dc, (pf_d, dc_d), _ = setup
         _reset(pf[0], pf_d)
         req = Request(request_id=1, prompt=np.ones((5,), np.int32),
@@ -334,11 +332,11 @@ class TestPrefillRedriveResume:
                            match="do not take preemption resumes"):
             pf_d.try_admit(req)
 
-    @pytest.mark.parametrize("flavour", ["paged", "dense"])
+    @pytest.mark.parametrize("flavour", ["paged"])
     def test_redrive_resume_parks_carried_history_in_outbox(
             self, setup, flavour):
-        model, cfg, pf, dc, (pf_d, dc_d), _ = setup
-        eng = pf[0] if flavour == "paged" else pf_d
+        model, cfg, pf, dc, _, _ = setup
+        eng = pf[0]
         _reset(eng)
         prompt = _prompts(cfg, 24, (9,))[0]
         toks = [7, 11, 13]
@@ -364,8 +362,7 @@ class TestPrefillRedriveResume:
             np.concatenate([prompt,
                             np.asarray(toks[:-1], np.int32)]))
         eng.release_handoff(ph)
-        if hasattr(eng, "manager"):
-            eng.manager.assert_consistent()
+        eng.manager.assert_consistent()
 
 
 # ---------------------------------------------------------------------------

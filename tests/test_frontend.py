@@ -5,8 +5,8 @@ per-tenant weighted-fair admission with quotas (FairScheduler deficit
 ledger layered on the bisect-FIFO scheduler), and priority preemption —
 a low-priority slot evicted mid-decode (paged blocks released at exact
 refcounts, prefix index retained) and resumed later via chunked
-re-prefill, bit-identical for greedy AND seeded-sampled traffic on the
-dense AND paged engines with decode/prefill compile counts pinned at 1.
+re-prefill, bit-identical for greedy AND seeded-sampled traffic with
+decode/prefill compile counts pinned at 1.
 Plus a seeded chaos schedule (~1% step faults) pinning the fairness +
 preemption invariants: exactly one terminal per request, zero
 slot/block leaks, arena consistent, completed greedy rows still
@@ -26,18 +26,19 @@ from paddle_tpu.utils import faults
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + one dense + one paged engine for the whole file
-    (reset() frees slots/blocks, never the compiled programs)."""
+    """One model + two engines for the whole file: one at the default
+    geometry (16-token blocks, a whole prompt in one chunk) and one at
+    8-token blocks and chunks (reset() frees slots/blocks, never the
+    compiled programs)."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
-    dense = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                     decode_block=4,
-                                     prompt_buckets=(8, 16, 32))
+    eng16 = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                     decode_block=4)
     paged = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
                                      decode_block=4, paged=True,
                                      block_size=8, prefill_chunk=8)
-    return model, cfg, dense, paged
+    return model, cfg, eng16, paged
 
 
 @pytest.fixture(autouse=True)
@@ -197,9 +198,9 @@ class TestFairScheduler:
             FairScheduler(tenants={"x": TenantConfig(weight=0.0)})
 
     def test_server_sheds_at_tenant_quota(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
-        fe = Frontend(dense,
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        fe = Frontend(eng16,
                       tenants={"a": TenantConfig(max_queued=1)})
         p = _prompts(cfg, 0, (5,))[0]
         ok = fe.submit(p, tenant="a", max_new_tokens=3)
@@ -216,14 +217,13 @@ class TestFairScheduler:
 
 
 class TestStreaming:
-    @pytest.mark.parametrize("which", ["dense", "paged"])
+    @pytest.mark.parametrize("which", ["paged"])
     def test_iterator_greedy_bit_identical(self, setup, which):
         """The headline pin: tokens consumed token-by-token off the
         iterator equal the generate() tail exactly, while run_until_idle
         results keep the full padded-array contract — one compiled
         decode program throughout."""
-        model, cfg, dense, paged = setup
-        engine = dense if which == "dense" else paged
+        model, cfg, _, engine = setup
         engine.reset()
         fe = Frontend(engine)
         prompts = _prompts(cfg, 1, (5, 9, 12))
@@ -242,9 +242,9 @@ class TestStreaming:
         assert engine.decode_compile_count() == 1
 
     def test_callback_api_under_run_until_idle(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
-        fe = Frontend(dense)
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        fe = Frontend(eng16)
         p = _prompts(cfg, 2, (5,))[0]
         got = []
         s = fe.submit(p, max_new_tokens=6, on_token=got.append)
@@ -257,9 +257,9 @@ class TestStreaming:
         """A consumer that never drains: the queue stays bounded at
         capacity, the oldest tokens are evicted and counted, and
         tokens_seen still tallies the full stream."""
-        model, cfg, dense, _ = setup
-        dense.reset()
-        fe = Frontend(dense, stream_capacity=4)
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        fe = Frontend(eng16, stream_capacity=4)
         p = _prompts(cfg, 3, (5,))[0]
         s = fe.submit(p, max_new_tokens=12, stream=True)
         fe.run_until_idle()
@@ -269,9 +269,9 @@ class TestStreaming:
         assert s.drain() == [int(t) for t in r[len(p) + 8:len(p) + 12]]
 
     def test_sampled_stream_matches_generate_seed(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
-        fe = Frontend(dense)
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        fe = Frontend(eng16)
         p = _prompts(cfg, 4, (9,))[0]
         s = fe.submit(p, max_new_tokens=6, temperature=1.0, top_k=40,
                       seed=7, stream=True)
@@ -281,9 +281,9 @@ class TestStreaming:
         assert got == [int(t) for t in r[len(p):len(p) + len(got)]]
 
     def test_shed_stream_terminates_immediately(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
-        fe = Frontend(dense,
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        fe = Frontend(eng16,
                       resilience=ResilienceConfig(max_queue_depth=1))
         p = _prompts(cfg, 5, (5,))[0]
         fe.submit(p, max_new_tokens=4)
@@ -294,14 +294,13 @@ class TestStreaming:
 
 
 class TestPreemption:
-    @pytest.mark.parametrize("which", ["dense", "paged"])
+    @pytest.mark.parametrize("which", ["paged"])
     def test_greedy_preempt_resume_bit_identical(self, setup, which):
         """The acceptance pin: low-priority requests evicted mid-decode
         by a high-priority arrival finish BIT-IDENTICAL to their
         uninterrupted generate() twins; the high-priority request got a
         slot while the pool was full; compile counts stay 1."""
-        model, cfg, dense, paged = setup
-        engine = dense if which == "dense" else paged
+        model, cfg, _, engine = setup
         engine.reset()
         prompts = _prompts(cfg, 6, (5, 9, 12))
         fe = Frontend(engine, preemption=True)
@@ -318,22 +317,20 @@ class TestPreemption:
                 res[rid], _ref(model, p, mn, temperature=0.0))
         assert engine.decode_compile_count() == 1
         assert all(s is None for s in engine._slots)
-        if which == "paged":
-            assert engine.prefill_compile_count() == 1
-            assert not engine.manager._ref
-            engine.manager.assert_consistent()
-            # the eviction retained the prompt's prefix-index entries,
-            # so the resume's re-prefill was mostly cache hits
-            assert engine.prefix_cache_hit_rate() > 0.0
+        assert engine.prefill_compile_count() == 1
+        assert not engine.manager._ref
+        engine.manager.assert_consistent()
+        # the eviction retained the prompt's prefix-index entries, so
+        # the resume's re-prefill was mostly cache hits
+        assert engine.prefix_cache_hit_rate() > 0.0
 
-    @pytest.mark.parametrize("which", ["dense", "paged"])
+    @pytest.mark.parametrize("which", ["paged"])
     def test_seeded_sampled_preempt_resume_bit_identical(self, setup,
                                                          which):
         """The rng key carried through ResumeState is the NEXT step's
         split input — a preempted seeded-sampled stream resumes on the
         exact key schedule generate(seed) uses."""
-        model, cfg, dense, paged = setup
-        engine = dense if which == "dense" else paged
+        model, cfg, _, engine = setup
         engine.reset()
         prompts = _prompts(cfg, 7, (5, 9, 12))
         fe = Frontend(engine, preemption=True)
@@ -361,16 +358,16 @@ class TestPreemption:
         explicit preemption=True on it is refused loudly; the env knob
         — weaker than explicit config, same contract as
         PT_SERVING_PAGED — resolves to off instead of forcing it."""
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         with pytest.raises(ValueError, match="priority-aware"):
-            Server(dense, Scheduler(), preemption=True)
+            Server(eng16, Scheduler(), preemption=True)
         with pytest.raises(ValueError, match="priority-aware"):
-            Frontend(dense, scheduler=Scheduler(), preemption=True)
+            Frontend(eng16, scheduler=Scheduler(), preemption=True)
         monkeypatch.setenv("PT_SERVING_PREEMPTION", "1")
-        srv = Server(dense, Scheduler())        # env-armed: degrades
+        srv = Server(eng16, Scheduler())        # env-armed: degrades
         assert not srv.preemption
-        srv2 = Server(dense, FairScheduler())   # env-armed: applies
+        srv2 = Server(eng16, FairScheduler())   # env-armed: applies
         assert srv2.preemption
 
     def test_queue_wait_measured_from_requeue_not_arrival(self, setup):
@@ -379,10 +376,10 @@ class TestPreemption:
         long-served victim is not killed the moment it re-enters the
         queue (deadlines stay end-to-end). Pinned directly against
         _expire with a crafted wait_from."""
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         p = _prompts(cfg, 20, (5,))[0]
-        srv = Server(dense, FairScheduler(), resilience=ResilienceConfig(
+        srv = Server(eng16, FairScheduler(), resilience=ResilienceConfig(
             max_queue_wait_ticks=15))
         rid = srv.submit(p, max_new_tokens=4)
         (req,) = srv.scheduler._queue
@@ -443,9 +440,9 @@ class TestPreemption:
         off when only the env knob armed it. (Spec engines compose
         since PR 14 — pinned in test_serving_spec.py's
         TestSpecPreemption.)"""
-        model, cfg, dense, _ = setup
-        dense.reset()
-        tp = self._TPLikeEngine(dense)
+        model, cfg, eng16, _ = setup
+        eng16.reset()
+        tp = self._TPLikeEngine(eng16)
         with pytest.raises(NotImplementedError, match="tensor-parallel"):
             Server(tp, FairScheduler(), preemption=True)
         monkeypatch.setenv("PT_SERVING_PREEMPTION", "1")

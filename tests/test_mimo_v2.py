@@ -677,7 +677,7 @@ def test_what_the_hybrid_cache_cannot_do_refuses_by_name(f32_model,
                                                          tmp_path):
     from paddle_tpu.serving import ContinuousBatchingEngine
     from paddle_tpu.serving.fleet import DecodeWorker, PrefillPagedEngine
-    from paddle_tpu.serving.paging import PagedEngine
+    from paddle_tpu.serving.paging import PagedModelStepBackend
     from paddle_tpu.serving.spec import SpecConfig
     from paddle_tpu.serving.tp import TPConfig
     kw = dict(paged=True, num_slots=2, max_len=64, block_size=8)
@@ -688,7 +688,8 @@ def test_what_the_hybrid_cache_cannot_do_refuses_by_name(f32_model,
         with pytest.raises(NotImplementedError, match=word):
             ContinuousBatchingEngine(f32_model, **extra, **kw)
     # a backend that knows one pool: the model itself says where to go
-    for build in (lambda: PagedEngine(f32_model, 2, 64, block_size=8),
+    for build in (lambda: PagedModelStepBackend(f32_model, 2, 64, 4, 8,
+                                                17, False, 16),
                   lambda: PrefillPagedEngine(f32_model, 2, 64,
                                              block_size=8)):
         with pytest.raises(NotImplementedError, match="HybridPagedEngine"):

@@ -27,19 +27,20 @@ from paddle_tpu.utils import faults
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + one dense + ONE paged engine for the whole file
-    (reset() frees state, never the compiled programs; a second paged
-    backend per process trips the documented compile-cache landmine)."""
+    """One model + two engines of different geometry for the whole file:
+    16-token blocks with a whole prompt in one chunk, and 8-token blocks
+    and chunks (reset() frees state, never the compiled programs; a
+    second backend of the SAME geometry per process trips the documented
+    compile-cache landmine)."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
-    dense = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                     decode_block=4,
-                                     prompt_buckets=(8, 16))
+    eng16 = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                     decode_block=4)
     paged = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
                                      decode_block=4, paged=True,
                                      block_size=8, prefill_chunk=8)
-    return model, cfg, dense, paged
+    return model, cfg, eng16, paged
 
 
 @pytest.fixture(autouse=True)
@@ -135,15 +136,15 @@ class TestDisabledPathInert:
         registry without a single sample, records no traces, and the
         engine carries no tracer (the hot paths pay one is-None
         check)."""
-        model, cfg, dense, paged = setup
+        model, cfg, eng16, paged = setup
         metrics.enable(False)
-        dense.reset()
-        srv = Server(dense, observability=ObservabilityConfig(
+        eng16.reset()
+        srv = Server(eng16, observability=ObservabilityConfig(
             trace_requests=False, flight_size=0))
         for p in _prompts(cfg, 1, [5, 9]):
             srv.submit(p, max_new_tokens=4)
         srv.run_until_idle()
-        assert dense.tracer is None
+        assert eng16.tracer is None
         assert srv.tracer.traces == {}
         assert srv.flight.events() == []
         sampled = [k for k, v in metrics.dump().items() if v["samples"]]
@@ -156,20 +157,20 @@ class TestDisabledPathInert:
 
 class TestRequestTraces:
     def test_completed_request_span_lifecycle(self, setup):
-        model, cfg, dense, paged = setup
-        dense.reset()
-        srv = Server(dense, observability=ObservabilityConfig(
+        model, cfg, eng16, paged = setup
+        eng16.reset()
+        srv = Server(eng16, observability=ObservabilityConfig(
             trace_requests=True))
         rid = srv.submit(_prompts(cfg, 2, [6])[0], max_new_tokens=5)
         srv.run_until_idle()
         tr = srv.tracer.traces[rid]
         names = tr.span_names()
-        # lifecycle order: queue wait -> prefill -> decode residency ->
-        # harvest -> exactly one terminal
-        for want in ("queue_wait", "prefill", "decode", "harvest",
+        # lifecycle order: queue wait -> prefill chunks -> decode
+        # residency -> harvest -> exactly one terminal
+        for want in ("queue_wait", "prefill_chunk", "decode", "harvest",
                      "terminal:completed"):
             assert want in names, (want, names)
-        assert names.index("queue_wait") < names.index("prefill")
+        assert names.index("queue_wait") < names.index("prefill_chunk")
         assert tr.terminals == ["completed"]
         assert tr.open == {}
 
@@ -177,7 +178,7 @@ class TestRequestTraces:
         """The acceptance invariant under injected chaos: every
         submitted request's trace reaches EXACTLY one terminal span,
         and the terminal agrees with what landed in results."""
-        model, cfg, dense, paged = setup
+        model, cfg, eng16, paged = setup
         paged.reset()
         res = ResilienceConfig(retry_attempts=2, retry_backoff_s=0.001,
                                breaker_threshold=64, max_queue_depth=4)
@@ -209,9 +210,9 @@ class TestRequestTraces:
         paged.manager.assert_consistent()
 
     def test_shed_request_still_terminates(self, setup):
-        model, cfg, dense, paged = setup
-        dense.reset()
-        srv = Server(dense,
+        model, cfg, eng16, paged = setup
+        eng16.reset()
+        srv = Server(eng16,
                      resilience=ResilienceConfig(max_queue_depth=1),
                      observability=ObservabilityConfig(
                          trace_requests=True))
@@ -234,9 +235,9 @@ class TestMergedChromeTrace:
         host spans, and tick spans — all on the perf_counter clock, the
         host spans read from the one span ring (no Profiler needed; one
         armed over the same stream exports the same RecordEvent spans)."""
-        model, cfg, dense, paged = setup
-        dense.reset()
-        srv = Server(dense, observability=ObservabilityConfig(
+        model, cfg, eng16, paged = setup
+        eng16.reset()
+        srv = Server(eng16, observability=ObservabilityConfig(
             trace_requests=True))
         prof = profiler.Profiler(targets=[profiler.ProfilerTarget.CPU],
                                  timer_only=True)
@@ -248,7 +249,7 @@ class TestMergedChromeTrace:
         srv.export_trace(path)
         events = json.load(open(path))["traceEvents"]
         drained = {e["name"] for e in prof._drain_events()}
-        assert {"serving.decode_block", "serving.prefill",
+        assert {"serving.decode_block", "serving.prefill_chunk",
                 "serving.tick"} <= drained
 
         req_rows = {e["tid"] for e in events
@@ -262,7 +263,7 @@ class TestMergedChromeTrace:
         assert "queue_wait" in names and "decode" in names
         # RecordEvent host spans from the SAME engine dispatches
         assert any(n == "serving.decode_block" for n in names)
-        assert any(n == "serving.prefill" for n in names)
+        assert any(n == "serving.prefill_chunk" for n in names)
         # tick spans (and the engine's dispatches) on the server row,
         # each carrying its tick id; nothing from before this server
         ticks = [e for e in events if e.get("name") == "serving.tick"]
@@ -307,9 +308,9 @@ class TestFlightRecorder:
     def test_dumps_on_circuit_open(self, setup, tmp_path):
         """Breaker opens -> the black box lands on disk before the
         drain, with the failure history inside."""
-        model, cfg, dense, paged = setup
-        dense.reset()
-        srv = Server(dense,
+        model, cfg, eng16, paged = setup
+        eng16.reset()
+        srv = Server(eng16,
                      resilience=ResilienceConfig(
                          retry_attempts=0, breaker_threshold=2),
                      observability=ObservabilityConfig(
@@ -332,9 +333,9 @@ class TestFlightRecorder:
     def test_snapshot_restore_roundtrip(self, setup, tmp_path):
         """The ring rides the snapshot: a restored server still holds
         the pre-kill events (and the snapshot dumped a sidecar file)."""
-        model, cfg, dense, paged = setup
-        dense.reset()
-        srv = Server(dense)
+        model, cfg, eng16, paged = setup
+        eng16.reset()
+        srv = Server(eng16)
         for p in _prompts(cfg, 7, [5, 9]):
             srv.submit(p, max_new_tokens=12)
         srv.run_until_idle(max_ticks=2)        # killed mid-stream
@@ -344,10 +345,9 @@ class TestFlightRecorder:
         srv.snapshot(snap)
         assert os.path.exists(snap + ".flight.json")
 
-        dense2 = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                          decode_block=4,
-                                          prompt_buckets=(8, 16))
-        srv2 = Server.restore(snap, dense2)
+        eng16b = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                          decode_block=4)
+        srv2 = Server.restore(snap, eng16b)
         ev = srv2.flight.events()
         kinds = [e["kind"] for e in ev]
         assert "restored" in kinds
@@ -372,7 +372,7 @@ class TestMetricsCoverage:
         from paddle_tpu.distributed import collectives as cc
         from paddle_tpu.passes import PassManager, default_pipeline
 
-        model, cfg, dense, paged = setup
+        model, cfg, eng16, paged = setup
         metrics.enable(True)
 
         # server + engine + paging + resilience (retry) + faults

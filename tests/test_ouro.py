@@ -250,9 +250,9 @@ def generate_row(model, prompt, new):
 
 def test_engine_streams_equal_generate_token_for_token(f32_model):
     from paddle_tpu.observability import tracing
-    from paddle_tpu.serving.paging import PagedEngine
+    from paddle_tpu.serving import ContinuousBatchingEngine
     eng, srv = engine_of(f32_model)
-    assert type(eng) is PagedEngine                  # no engine of its own
+    assert type(eng) is ContinuousBatchingEngine     # no engine of its own
     assert (eng.cache_passes, eng.attn_sites) == (PASSES, PASSES * 3)
     prompts = [ids_of(n, seed=20 + n) for n in (21, 40, 9, 33)]
     news = (30, 12, 25, 7)
@@ -378,10 +378,10 @@ def test_what_the_looped_cache_cannot_do_refuses_by_name(f32_model, tmp_path):
         PrefillWorker(PrefillPagedEngine(f32_model, 2, 64, block_size=8))
     with pytest.raises(NotImplementedError, match="exported paged artifact"):
         export_decoder(f32_model, str(tmp_path / "a"), 1, 8, 64,
-                       engine_slots=2, engine_paged=True)
-    # the dense slot pool pads its prompts on the left
+                       engine_slots=2)
+    # generate()'s ragged batch pads its prompts on the left
     with pytest.raises(NotImplementedError, match="left-padded"):
-        dense = ContinuousBatchingEngine(f32_model, num_slots=2, max_len=64)
-        dense.admit(type("R", (), dict(
-            prompt=ids_of(5), max_new_tokens=3, seed=0, temperature=0.0,
-            top_k=0, top_p=1.0, eos_token_id=None, request_id=0))())
+        f32_model.generate(paddle.to_tensor(np.stack([ids_of(5)] * 2)),
+                           max_new_tokens=3,
+                           attention_mask=np.array([[0, 1, 1, 1, 1],
+                                                    [1, 1, 1, 1, 1]]))

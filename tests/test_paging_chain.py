@@ -18,7 +18,7 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import (ContinuousBatchingEngine, Frontend,
-                                PagedEngine, Scheduler, Server)
+                                Scheduler, Server)
 from paddle_tpu.serving.durability import _chain_block_ids
 from paddle_tpu.serving.paging import BlockManager, _sha1_chain, walk_chain
 
@@ -254,7 +254,7 @@ def setup():
     engine = ContinuousBatchingEngine(
         model, num_slots=2, max_len=64, decode_block=4, paged=True,
         block_size=8, prefill_chunk=8, num_blocks=65)
-    assert isinstance(engine, PagedEngine)
+    assert type(engine) is ContinuousBatchingEngine
     return model, cfg, engine
 
 

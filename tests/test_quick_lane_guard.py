@@ -67,9 +67,9 @@ REQUIRED_NODES = [
     "test_dumps_on_circuit_open",
     "test_observability.py::TestProfilerSchedulerGating::"
     "test_closed_scheduler_keeps_host_ring_silent",
-    # PR 7 tensor-parallel pins: dense + paged sharded bit-identity on
-    # the simulated 2x4 mesh, the seeded-sampling parity, the int8-hop
-    # queryable bound, and the AOT 4/5-output arity compatibility
+    # PR 7 tensor-parallel pins: sharded bit-identity at two arena
+    # geometries on the simulated 2x4 mesh, the seeded-sampling parity,
+    # and the int8-hop queryable bound
     "test_serving_tp.py::TestDenseTPParity::"
     "test_greedy_staggered_bit_exact_one_compile",
     "test_serving_tp.py::TestDenseTPParity::"
@@ -78,9 +78,7 @@ REQUIRED_NODES = [
     "test_greedy_staggered_bit_exact_one_compile",
     "test_serving_tp.py::TestPsumInt8::"
     "test_int8_bound_queryable_from_live_state",
-    "test_serving.py::TestDecodeBlockArity::"
-    "test_legacy_four_output_stream_bit_identical",
-    # PR 8 speculative-decoding pins: dense + paged/chunked bit-identity
+    # PR 8 speculative-decoding pins: whole-prompt + chunked bit-identity
     # with the verify-block compile count, the eos-mid-span acceptance
     # cut, the k=0 degenerate window, the chaos schedule with spec
     # enabled, and the mid-stream kill/restore round trip
@@ -123,10 +121,10 @@ REQUIRED_NODES = [
     "test_env_flag_never_reroutes_explicit_backend",
     "test_sparse_quant.py::TestWeightOnlyQuant::"
     "test_grouped_roundtrip_and_linear",
-    # the six compositions the engine factory builds, over one stream
-    # (PR 29: the safety net under the dense/paged/spec class fork)
+    # the compositions the engine factory builds, over one stream
+    # (PR 29: the safety net under the engine's class tree)
     "test_serving_quant.py::"
-    "test_every_composition_serves_the_same_stream[dense]",
+    "test_every_composition_serves_the_same_stream[paged]",
     "test_serving_quant.py::"
     "test_every_composition_serves_the_same_stream[paged-kv_int8-w_int8]",
     "test_serving_quant.py::"
@@ -137,19 +135,15 @@ REQUIRED_NODES = [
     "test_xent_chunk_default_unchanged_without_table",
     "test_autotune.py::TestConsumers::"
     "test_flash_block_pref_resolution_order",
-    # PR 13 front-door pins: streaming bit-identity (dense + paged),
+    # PR 13 front-door pins: streaming bit-identity,
     # the preempt-resume bit-identity matrix (greedy AND seeded-
     # sampled), the span-events-never-terminals trace contract, the
     # resume-state snapshot round trip, WFQ shares, and the chaos
     # schedule with preemption + WFQ active
     "test_frontend.py::TestStreaming::"
-    "test_iterator_greedy_bit_identical[dense]",
-    "test_frontend.py::TestStreaming::"
     "test_iterator_greedy_bit_identical[paged]",
     "test_frontend.py::TestPreemption::"
     "test_greedy_preempt_resume_bit_identical[paged]",
-    "test_frontend.py::TestPreemption::"
-    "test_seeded_sampled_preempt_resume_bit_identical[dense]",
     "test_frontend.py::TestPreemption::"
     "test_seeded_sampled_preempt_resume_bit_identical[paged]",
     "test_frontend.py::TestPreemption::"
@@ -161,7 +155,7 @@ REQUIRED_NODES = [
     "test_frontend.py::TestFrontdoorChaos::"
     "test_chaos_with_preemption_and_wfq",
     # PR 14 disaggregated-fleet pins: cross-worker bit-identity
-    # (greedy + seeded-sampled, dense + paged + paged+kv_int8), the
+    # (greedy + seeded-sampled, 16- and 8-token blocks, kv_int8), the
     # bytes-true int8 wire format, the prefix-affinity fleet-wide
     # cache gate, live decode-worker migration, and the chaos schedule
     # over the handoff fault sites with zero leaks on both arenas
@@ -185,8 +179,6 @@ REQUIRED_NODES = [
     # (bit-identical resumes), and stream delivered-offsets ride
     # snapshots (kill/restore/re-attach sees only unseen tokens)
     "test_serving_spec.py::TestSpecPreemption::"
-    "test_greedy_preempt_resume_bit_identical[dense]",
-    "test_serving_spec.py::TestSpecPreemption::"
     "test_greedy_preempt_resume_bit_identical[paged]",
     "test_serving_spec.py::TestSpecPreemption::"
     "test_seeded_sampled_preempt_resume_bit_identical",
@@ -196,8 +188,8 @@ REQUIRED_NODES = [
     # duplicate delivery, adoption idempotency at exact refcounts, the
     # tampered-CRC pre-allocation refusal, the fault-site table guard,
     # and the headline kill-mid-decode redrive bit-identity matrix
-    # (paged under ~1% wire faults + one-terminal trace, dense,
-    # paged+kv_int8) plus the explicit worker_lost endgame
+    # (under ~1% wire faults + one-terminal trace, 16-token blocks,
+    # kv_int8) plus the explicit worker_lost endgame
     "test_fleet_failover.py::TestSocketTransport::"
     "test_disconnect_before_ack_delivers_duplicate",
     "test_fleet_failover.py::TestAdoptIdempotency::"

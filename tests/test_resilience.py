@@ -1,8 +1,9 @@
 """Serving resilience subsystem (serving/resilience.py +
 utils/faults.py): deterministic fault injection, deadlines +
 cancellation, load shedding, retry/backoff, circuit breaker, the
-NaN-logit quarantine, crash-safe snapshot/restore (dense AND paged,
-bit-identical resume), the paged-validation livelock regression, and a
+NaN-logit quarantine, crash-safe snapshot/restore (whole-prompt and
+mid-chunked-prefill kill points, bit-identical resume), the
+arena-validation livelock regression, and a
 chaos suite driving seeded randomized fault schedules against the
 accounting invariants (every request completed or explicitly failed,
 zero slot leaks, BlockManager.assert_consistent clean)."""
@@ -14,25 +15,26 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                PagedEngine, RequestFailure,
-                                ResilienceConfig, Scheduler, Server)
+                                RequestFailure, ResilienceConfig,
+                                Scheduler, Server)
 from paddle_tpu.utils import faults
 
 
 @pytest.fixture(scope="module")
 def setup():
-    """One model + one dense + one paged engine for the whole file
-    (reset() frees slots/blocks, never the compiled programs)."""
+    """One model + two engines for the whole file: one at the default
+    geometry (16-token blocks, a whole prompt in one chunk) and one at
+    8-token blocks and chunks (reset() frees slots/blocks, never the
+    compiled programs)."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
-    dense = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                     decode_block=4,
-                                     prompt_buckets=(8, 16))
+    eng16 = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                     decode_block=4)
     paged = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
                                      decode_block=4, paged=True,
                                      block_size=8, prefill_chunk=8)
-    return model, cfg, dense, paged
+    return model, cfg, eng16, paged
 
 
 @pytest.fixture(autouse=True)
@@ -152,10 +154,10 @@ class TestInertWhenDisabled:
         disarmed, both engines' greedy streams stay bit-identical to
         generate() and the decode/chunk compile counts stay 1 — the
         resilience wiring costs nothing on the clean path."""
-        model, cfg, dense, paged = setup
+        model, cfg, eng16, paged = setup
         prompts = _prompts(cfg, 0, (5, 9, 12, 5, 9))
         news = [6, 4, 7, 5, 6]
-        for engine in (dense, paged):
+        for engine in (eng16, paged):
             engine.reset()
             srv = Server(engine)
             rids = [srv.submit(p, max_new_tokens=mn)
@@ -177,10 +179,10 @@ class TestRetryAndBreaker:
         path with ZERO effect on outputs: the harvest fault parks the
         dispatched block so a retry never re-steps (no token decoded
         twice or dropped)."""
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 1, (5, 9, 12))
-        srv = Server(dense)
+        srv = Server(eng16)
         rids = [srv.submit(p, max_new_tokens=6) for p in prompts]
         with faults.injected(
                 "serving.step_block:every=3;serving.harvest:at=2"):
@@ -191,13 +193,13 @@ class TestRetryAndBreaker:
         st = srv.stats()
         assert st["retries"] > 0 and st["step_failures"] > 0
         assert st["requests_failed"] == 0
-        assert dense.decode_compile_count() == 1
+        assert eng16.decode_compile_count() == 1
 
     def test_tick_fault_skips_without_losing_requests(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         p = _prompts(cfg, 2, (5,))[0]
-        srv = Server(dense)
+        srv = Server(eng16)
         rid = srv.submit(p, max_new_tokens=5)
         with faults.injected("server.tick:at=1"):
             res = srv.run_until_idle()
@@ -206,10 +208,10 @@ class TestRetryAndBreaker:
         assert srv.stats()["tick_faults"] == 1
 
     def test_breaker_opens_and_drains_everything(self, setup, tmp_path):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 3, (5, 9, 6))
-        srv = Server(dense, resilience=ResilienceConfig(
+        srv = Server(eng16, resilience=ResilienceConfig(
             retry_attempts=1, retry_backoff_s=0.001,
             breaker_threshold=3))
         rids = [srv.submit(p, max_new_tokens=8) for p in prompts]
@@ -220,14 +222,14 @@ class TestRetryAndBreaker:
             assert res[rid].reason == "circuit_open"
         st = srv.stats()
         assert st["breaker_open"] and st["requests_failed"] == 3
-        assert all(s is None for s in dense._slots)   # no slot leak
+        assert all(s is None for s in eng16._slots)   # no slot leak
         # the OPEN circuit survives snapshot/restore — a restored
         # server must not silently re-close the breaker and resume
         # dispatching to a device the policy quarantined
         path = str(tmp_path / "breaker.npz")
         srv.snapshot(path)
-        dense.reset()
-        srv2 = Server.restore(path, dense)
+        eng16.reset()
+        srv2 = Server.restore(path, eng16)
         st2 = srv2.stats()
         assert st2["breaker_open"]
         assert st2["requests_failed"] == 3
@@ -259,10 +261,10 @@ class TestRetryAndBreaker:
 
 class TestDeadlinesAndShedding:
     def test_inflight_deadline_cancels_and_frees(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 4, (5, 9))
-        srv = Server(dense,
+        srv = Server(eng16,
                      resilience=ResilienceConfig(deadline_ticks=2))
         r0 = srv.submit(prompts[0], max_new_tokens=40)   # will expire
         r1 = srv.submit(prompts[1], max_new_tokens=4)    # finishes first
@@ -272,7 +274,7 @@ class TestDeadlinesAndShedding:
         assert res[r0].tokens_emitted > 0      # partial work accounted
         np.testing.assert_array_equal(
             res[r1], _ref(model, prompts[1], 4, temperature=0.0))
-        assert all(s is None for s in dense._slots)
+        assert all(s is None for s in eng16._slots)
         assert srv.stats()["timeouts"] == 1
 
     def test_paged_deadline_releases_blocks_exactly(self, setup):
@@ -291,10 +293,10 @@ class TestDeadlinesAndShedding:
         paged.manager.assert_consistent()
 
     def test_queue_wait_timeout_and_per_request_deadline(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 6, (5, 5, 5))
-        srv = Server(dense, resilience=ResilienceConfig(
+        srv = Server(eng16, resilience=ResilienceConfig(
             max_queue_wait_ticks=1))
         # two long-running requests occupy both slots; the third waits
         # in queue past the cap and times out without ever admitting
@@ -307,17 +309,17 @@ class TestDeadlinesAndShedding:
         for rid, mn in ((r0, 20), (r1, 20)):
             assert not isinstance(res[rid], RequestFailure)
         # per-request deadline overrides the (absent) config default
-        dense.reset()
-        srv2 = Server(dense)
+        eng16.reset()
+        srv2 = Server(eng16)
         ra = srv2.submit(prompts[0], max_new_tokens=40, deadline_ticks=2)
         res2 = srv2.run_until_idle()
         assert isinstance(res2[ra], RequestFailure)
 
     def test_load_shedding_at_queue_depth(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 7, (5, 5, 5, 5))
-        srv = Server(dense, resilience=ResilienceConfig(
+        srv = Server(eng16, resilience=ResilienceConfig(
             max_queue_depth=2))
         rids = [srv.submit(p, max_new_tokens=4) for p in prompts[:2]]
         shed = [srv.submit(p, max_new_tokens=4) for p in prompts[2:]]
@@ -332,14 +334,12 @@ class TestDeadlinesAndShedding:
 
 
 class TestNaNSentinel:
-    @pytest.mark.parametrize("which", ["dense", "paged"])
+    @pytest.mark.parametrize("which", ["paged"])
     def test_poison_quarantines_only_that_slot(self, setup, which):
         """The blast-radius pin: a poisoned slot fails as 'poisoned';
-        the OTHER slot's greedy stream stays bit-identical (dense rows
-        are independent; paged poison lands in a block only the victim
-        owns)."""
-        model, cfg, dense, paged = setup
-        engine = dense if which == "dense" else paged
+        the OTHER slot's greedy stream stays bit-identical (the poison
+        lands in a block only the victim owns)."""
+        model, cfg, _, engine = setup
         engine.reset()
         prompts = _prompts(cfg, 8, (5, 9))
         news = [6, 6]
@@ -355,17 +355,16 @@ class TestNaNSentinel:
         np.testing.assert_array_equal(
             res[ok], _ref(model, prompts[i], news[i], temperature=0.0))
         assert all(s is None for s in engine._slots)
-        if which == "paged":
-            engine.manager.assert_consistent()
+        engine.manager.assert_consistent()
 
     def test_sentinel_off_lets_the_stream_run(self, setup):
         """nan_sentinel=False: no quarantine — the poisoned slot runs
         its budget out and returns (garbage) tokens instead of a
         failure. Pins that the gate is the config, not the flags."""
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         p = _prompts(cfg, 9, (5,))[0]
-        srv = Server(dense, resilience=ResilienceConfig(
+        srv = Server(eng16, resilience=ResilienceConfig(
             nan_sentinel=False))
         rid = srv.submit(p, max_new_tokens=5)
         with faults.injected("serving.poison:at=1"):
@@ -376,19 +375,20 @@ class TestNaNSentinel:
 
 class TestSnapshotRestore:
     def _fresh_engine(self, cfg, paged):
+        """A new model and engine (a process-restart simulation): the
+        8-token geometry when ``paged``, else the default one."""
         paddle.seed(0)
-        model = LlamaForCausalLM(cfg)   # process-restart simulation
+        model = LlamaForCausalLM(cfg)
         if paged:
             return ContinuousBatchingEngine(
                 model, num_slots=2, max_len=64, decode_block=4,
                 paged=True, block_size=8, prefill_chunk=8)
         return ContinuousBatchingEngine(
-            model, num_slots=2, max_len=64, decode_block=4,
-            prompt_buckets=(8, 16))
+            model, num_slots=2, max_len=64, decode_block=4)
 
     def test_kill_restore_dense_bit_identical(self, setup, tmp_path,
                                               _no_compile_cache):
-        model, cfg, dense, _ = setup
+        model, cfg, eng16, _ = setup
         prompts = _prompts(cfg, 10, (5, 9, 12, 5))
         news = [8, 4, 7, 5]
 
@@ -396,17 +396,17 @@ class TestSnapshotRestore:
             return [srv.submit(p, max_new_tokens=mn, arrival_step=i)
                     for i, (p, mn) in enumerate(zip(prompts, news))]
 
-        dense.reset()                       # uninterrupted reference
-        srv_ref = Server(dense)
+        eng16.reset()                       # uninterrupted reference
+        srv_ref = Server(eng16)
         rids = submit_all(srv_ref)
         ref = srv_ref.run_until_idle()
 
-        dense.reset()                       # killed mid-stream
-        srv_kill = Server(dense)
+        eng16.reset()                       # killed mid-stream
+        srv_kill = Server(eng16)
         assert submit_all(srv_kill) == rids
         srv_kill.run_until_idle(max_ticks=3)
-        assert dense.has_live()             # genuinely mid-decode
-        path = str(tmp_path / "dense.npz")
+        assert eng16.has_live()             # genuinely mid-decode
+        path = str(tmp_path / "eng16.npz")
         srv_kill.snapshot(path)
 
         engine2 = self._fresh_engine(cfg, paged=False)
@@ -453,21 +453,21 @@ class TestSnapshotRestore:
         assert engine2.prefill_compile_count() == 1
 
     def test_restore_rejects_mismatched_engine(self, setup, tmp_path):
-        model, cfg, dense, paged = setup
+        model, cfg, eng16, paged = setup
         paged.reset()
         path = str(tmp_path / "p.npz")
         paged.snapshot(path)
-        dense.reset()
+        eng16.reset()
         with pytest.raises(ValueError, match="mismatch|pool_specs"):
-            dense.restore(path)
+            eng16.restore(path)
 
     def test_snapshot_is_atomic_no_tmp_litter(self, setup, tmp_path):
         from paddle_tpu.distributed.checkpoint import atomic_savez
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         path = str(tmp_path / "s.npz")
-        dense.snapshot(path)
-        dense.snapshot(path)                # overwrite goes via rename
+        eng16.snapshot(path)
+        eng16.snapshot(path)                # overwrite goes via rename
         assert [f for f in os.listdir(tmp_path)] == ["s.npz"]
 
         def boom(f):
@@ -504,7 +504,7 @@ class TestLivelockRegression:
         small.manager.assert_consistent()
         # (b) manager swapped without touching num_kv_blocks — the
         # exact desync that produced the livelock
-        stale = PagedEngine(backend=paged.backend)
+        stale = ContinuousBatchingEngine(backend=paged.backend)
         stale.manager = BlockManager(3, stale.kv_block_size)
         stale.reset()
         with pytest.raises(ValueError, match="KV blocks"):
@@ -571,10 +571,10 @@ class TestChaos:
         assert paged.prefill_compile_count() == 1
 
     def test_dense_chaos_schedule(self, setup):
-        model, cfg, dense, _ = setup
-        dense.reset()
+        model, cfg, eng16, _ = setup
+        eng16.reset()
         prompts = _prompts(cfg, 13, (5, 9, 12, 6))
-        srv = Server(dense, resilience=ResilienceConfig(
+        srv = Server(eng16, resilience=ResilienceConfig(
             retry_attempts=3, retry_backoff_s=0.001,
             breaker_threshold=12, deadline_ticks=60))
         rids = [srv.submit(p, max_new_tokens=5, arrival_step=i)
@@ -583,11 +583,11 @@ class TestChaos:
                 "server.tick:p=0.05;serving.poison:at=4,times=1")
         with faults.injected(spec, seed=42):
             res = srv.run_until_idle(max_ticks=300)
-        assert srv.scheduler.pending() == 0 and not dense.has_live()
+        assert srv.scheduler.pending() == 0 and not eng16.has_live()
         for rid, p in zip(rids, prompts):
             v = res[rid]
             if not isinstance(v, RequestFailure):
                 np.testing.assert_array_equal(
                     v, _ref(model, p, 5, temperature=0.0))
-        assert all(s is None for s in dense._slots)
-        assert dense.decode_compile_count() == 1
+        assert all(s is None for s in eng16._slots)
+        assert eng16.decode_compile_count() == 1

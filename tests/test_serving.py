@@ -22,8 +22,7 @@ def serving_setup():
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
     engine = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                      decode_block=4,
-                                      prompt_buckets=(8, 16))
+                                      decode_block=4)
     return model, cfg, engine
 
 
@@ -149,8 +148,10 @@ class TestContinuousBatching:
         with pytest.raises(ValueError, match="slot capacity"):
             srv.submit(np.ones((8,), np.int32), max_new_tokens=60)
             srv.run_until_idle()
-        with pytest.raises(ValueError, match="largest bucket"):
-            engine.bucket_len(17)
+        # prompts are unpadded: any length that fits the slot validates
+        engine.validate_request(17, 47)
+        with pytest.raises(ValueError, match="slot capacity"):
+            engine.validate_request(17, 48)
 
 
 class TestScheduler:
@@ -204,8 +205,7 @@ class TestArtifactServing:
         model, cfg, engine = serving_setup
         path = export_decoder(model, str(tmp_path / "srv"), batch=1,
                               prompt_len=8, max_len=64, engine_slots=2,
-                              engine_decode_block=4,
-                              engine_prompt_buckets=(8, 16))
+                              engine_decode_block=4)
         served = GenerationPredictor(path)
         rs = np.random.RandomState(3)
         prompts = [rs.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
@@ -218,30 +218,31 @@ class TestArtifactServing:
 
     def test_artifact_block_arity_both_directions(self, serving_setup,
                                                   tmp_path):
-        """New exports record block_outputs=5 so the serving host knows
-        the artifact carries the NaN-sentinel flags; an old artifact
-        (no arity key — simulated by stripping it) still loads, with
-        carries_nan_flags False."""
+        """Exports record the programs' arities (block_outputs=5: the
+        NaN-sentinel flags ride the block; chunk_outputs=2), the
+        artifact's identity is stable per blob and sensitive to its
+        config, and an artifact of the removed dense engine's programs
+        is refused by name."""
         import pickle
         from paddle_tpu.inference import export_decoder
-        from paddle_tpu.serving.engine import ArtifactStepBackend
+        from paddle_tpu.serving.paging import PagedArtifactStepBackend
         model, cfg, engine = serving_setup
         path = export_decoder(model, str(tmp_path / "arity"), batch=1,
                               prompt_len=8, max_len=64, engine_slots=2,
-                              engine_decode_block=4,
-                              engine_prompt_buckets=(8,))
+                              engine_decode_block=4)
         with open(path, "rb") as f:
             blob = pickle.load(f)
-        assert blob["engine"]["config"]["block_outputs"] == 5
-        back = ArtifactStepBackend(blob)
-        assert back.carries_nan_flags
+        conf = blob["engine"]["config"]
+        assert (conf["block_outputs"], conf["chunk_outputs"]) == (5, 2)
+        back = PagedArtifactStepBackend(blob)
         # artifact identity: stable per blob, sensitive to the config
         fp = back.artifact_fingerprint
-        assert fp == ArtifactStepBackend(blob).artifact_fingerprint
-        del blob["engine"]["config"]["block_outputs"]
-        legacy = ArtifactStepBackend(blob)
-        assert not legacy.carries_nan_flags
-        assert legacy.artifact_fingerprint != fp
+        assert fp == PagedArtifactStepBackend(blob).artifact_fingerprint
+        del conf["block_outputs"]
+        assert PagedArtifactStepBackend(blob).artifact_fingerprint != fp
+        del conf["paged"]
+        with pytest.raises(ValueError, match="dense slot-pool engine"):
+            PagedArtifactStepBackend(blob)
 
 
 class TestArtifactSnapshotIdentity:
@@ -273,8 +274,7 @@ class TestArtifactSnapshotIdentity:
 
         def build(fp):
             return ContinuousBatchingEngine(
-                backend=self._FingerprintBackend(engine.backend, fp),
-                prompt_buckets=(8, 16))
+                backend=self._FingerprintBackend(engine.backend, fp))
 
         def submit_all(srv):
             return [srv.submit(p, max_new_tokens=8, arrival_step=i)
@@ -303,13 +303,11 @@ class TestArtifactSnapshotIdentity:
                                                 tmp_path):
         model, cfg, engine = serving_setup
         art = ContinuousBatchingEngine(
-            backend=self._FingerprintBackend(engine.backend, "sha1:aaa"),
-            prompt_buckets=(8, 16))
+            backend=self._FingerprintBackend(engine.backend, "sha1:aaa"))
         path = str(tmp_path / "aaa.npz")
         art.snapshot(path)
         other = ContinuousBatchingEngine(
-            backend=self._FingerprintBackend(engine.backend, "sha1:bbb"),
-            prompt_buckets=(8, 16))
+            backend=self._FingerprintBackend(engine.backend, "sha1:bbb"))
         with pytest.raises(ValueError, match="different AOT artifact"):
             other.restore(path)
 
@@ -323,8 +321,7 @@ class TestArtifactSnapshotIdentity:
         path = str(tmp_path / "plain.npz")
         engine.snapshot(path)
         art = ContinuousBatchingEngine(
-            backend=self._FingerprintBackend(engine.backend, "sha1:xyz"),
-            prompt_buckets=(8, 16))
+            backend=self._FingerprintBackend(engine.backend, "sha1:xyz"))
         art.restore(path)                  # saved None, current set: ok
         art.reset()
         path2 = str(tmp_path / "art.npz")
@@ -334,24 +331,8 @@ class TestArtifactSnapshotIdentity:
 
 
 class TestDecodeBlockArity:
-    """The PR 5 NaN-sentinel grew the decode block from 4 outputs
-    (cache, state, toks, lives) to 5 (+ per-step (S,) ok flags).
-    Serving hosts meet BOTH generations: new programs carry the flags;
-    old 4-output AOT artifacts are padded with flags=None by
-    engine.step_block, which makes the sentinel inert for them without
-    touching the stream."""
-
-    class _LegacyBackend:
-        """A pre-sentinel artifact: its decode block returns 4 values."""
-        def __init__(self, inner):
-            self._inner = inner
-            self.carries_nan_flags = False
-
-        def __getattr__(self, name):
-            return getattr(self.__dict__["_inner"], name)
-
-        def decode_block(self, cache_flat, state):
-            return self._inner.decode_block(cache_flat, state)[:4]
+    """The decode block emits 5 outputs: (cache, state, toks, lives) and
+    the PR 5 NaN-sentinel's per-step (S,) ok flags."""
 
     def test_new_block_emits_five_outputs(self, serving_setup):
         model, cfg, engine = serving_setup
@@ -359,22 +340,3 @@ class TestDecodeBlockArity:
         out = engine.backend.decode_block(engine._cache, engine._state)
         assert len(out) == 5        # (cache, state, toks, lives, oks)
         engine.reset()              # the direct call donated cache/state
-
-    def test_legacy_four_output_stream_bit_identical(self,
-                                                     serving_setup):
-        """A 4-output backend serves the same greedy stream: the engine
-        pads the missing ok flags with None and the armed sentinel
-        (Server default) skips quarantine instead of crashing."""
-        model, cfg, engine = serving_setup
-        legacy = ContinuousBatchingEngine(
-            backend=self._LegacyBackend(engine.backend))
-        rs = np.random.RandomState(21)
-        prompts = [rs.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
-                   for L in (5, 9, 12)]
-        srv = Server(legacy)
-        assert legacy.nan_sentinel          # armed, inert on None flags
-        rids = [srv.submit(p, max_new_tokens=5) for p in prompts]
-        res = srv.run_until_idle()
-        for rid, p in zip(rids, prompts):
-            np.testing.assert_array_equal(
-                res[rid], _ref(model, p, 5, temperature=0.0))

@@ -1,5 +1,5 @@
 """Paged-KV serving engine (serving/paging.py): paged greedy streams
-bit-identical to dense/generate(), chunked-vs-whole prefill
+bit-identical to generate(), chunked-vs-whole prefill
 equivalence, ref-counted prefix sharing (release on eos, no
 double-free, hash-collision fallback), int8 KV error inside the
 runtime-queryable bound, and the static-shape invariant (ONE decode
@@ -16,7 +16,7 @@ from paged_walk_cases import BS, WALK_CASES, chunk_rows, walk_inputs
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (BlockManager, ContinuousBatchingEngine,
-                                PagedEngine, Request, Scheduler, Server)
+                                Request, Scheduler, Server)
 
 
 _LIVE_MANAGERS = []      # every BlockManager the module's tests built
@@ -26,15 +26,15 @@ _LIVE_MANAGERS = []      # every BlockManager the module's tests built
 def paged_setup():
     """One model + one paged engine for the whole file (reset() frees
     slots/blocks, never the two compiled programs). Constructed through
-    ContinuousBatchingEngine(paged=True) so the factory routing is on
-    the tested path."""
+    ContinuousBatchingEngine(paged=True), as the benchmark builds it, so
+    the factory routing is on the tested path."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
     engine = ContinuousBatchingEngine(
         model, num_slots=2, max_len=64, decode_block=4, paged=True,
         block_size=8, prefill_chunk=8)
-    assert isinstance(engine, PagedEngine)
+    assert type(engine) is ContinuousBatchingEngine
     _LIVE_MANAGERS.append(engine.manager)
     return model, cfg, engine
 
@@ -109,8 +109,7 @@ class TestPagedBitExactness:
 
     def test_sampled_row_matches_generate_seed(self, paged_setup):
         """Sampled traffic follows generate(seed)'s key schedule
-        through chunked prefill + paged decode (the dense engine's
-        parity invariant carries over)."""
+        through chunked prefill + paged decode."""
         model, cfg, engine = paged_setup
         engine.reset()
         rs = np.random.RandomState(2)
@@ -241,8 +240,8 @@ class TestPrefixSharing:
         and the stream stays bit-identical, with zero shared tokens."""
         model, cfg, engine = paged_setup
         backend = engine.backend
-        bad = PagedEngine(backend=backend,
-                          hash_fn=lambda parent, toks: b"collide")
+        bad = ContinuousBatchingEngine(
+            backend=backend, hash_fn=lambda parent, toks: b"collide")
         _LIVE_MANAGERS.append(bad.manager)
         rs = np.random.RandomState(5)
         pa = rs.randint(0, cfg.vocab_size, (17,)).astype(np.int32)
@@ -263,7 +262,7 @@ class TestPrefixSharing:
         — outputs still bit-identical, no corruption from the dead
         slot's trash-redirected writes."""
         model, cfg, engine = paged_setup
-        tight = PagedEngine(backend=engine.backend)
+        tight = ContinuousBatchingEngine(backend=engine.backend)
         # shrink the usable pool via a fresh manager over fewer blocks
         tight.manager = BlockManager(6, tight.kv_block_size)
         tight.num_kv_blocks = 6
@@ -827,36 +826,74 @@ class TestPagedScheduling:
         assert [r.request_id for r in
                 s.pop_ready(0, 2, True)] == [0, 1]
 
-    def test_env_flag_never_reroutes_explicit_dense_backend(
-            self, paged_setup, monkeypatch):
-        """PT_SERVING_PAGED=1 opts IN new engine builds only: a caller
-        holding a non-paged step backend (the AOT GenerationPredictor
-        path) must keep getting the dense engine, and a paged backend
-        routes paged even without the flag."""
-        from paddle_tpu.serving import ModelStepBackend
-        model, cfg, engine = paged_setup
-        monkeypatch.setenv("PT_SERVING_PAGED", "1")
-        dense_backend = ModelStepBackend(model, num_slots=2, max_len=64,
-                                         decode_block=4)
-        e = ContinuousBatchingEngine(backend=dense_backend,
-                                     prompt_buckets=(8, 16))
-        assert type(e) is ContinuousBatchingEngine
-        monkeypatch.delenv("PT_SERVING_PAGED")
-        e2 = ContinuousBatchingEngine(backend=engine.backend)
-        assert isinstance(e2, PagedEngine)
-
     def test_validate_rejects_oversized_at_the_door(self, paged_setup):
         model, cfg, engine = paged_setup
         engine.reset()
         srv = Server(engine)
         with pytest.raises(ValueError, match="slot capacity"):
             srv.submit(np.ones((8,), np.int32), max_new_tokens=60)
-        small = PagedEngine(backend=engine.backend)
+        small = ContinuousBatchingEngine(backend=engine.backend)
         small.manager = BlockManager(3, small.kv_block_size)
         small.num_kv_blocks = 3
         with pytest.raises(ValueError, match="KV blocks"):
             Server(small).submit(np.ones((30,), np.int32),
                                  max_new_tokens=10)
+
+
+def _factory_case(case, model):
+    """The engine ``ContinuousBatchingEngine`` builds for ``case``, and
+    the class it must be."""
+    from paddle_tpu.models.mimo_v2 import (MiMoV2ForCausalLM,
+                                           mimo_v2_tiny_config)
+    from paddle_tpu.serving import HybridPagedEngine, SpecPagedEngine
+    from paddle_tpu.serving.spec import SpecConfig
+    kw = dict(num_slots=2, max_len=64, decode_block=4, block_size=8,
+              prefill_chunk=8)
+    if case == "plain":
+        return ContinuousBatchingEngine(model, **kw), \
+            ContinuousBatchingEngine
+    if case == "two-groups":
+        two = MiMoV2ForCausalLM(mimo_v2_tiny_config())
+        return ContinuousBatchingEngine(two, **kw), HybridPagedEngine
+    if case == "spec":
+        return ContinuousBatchingEngine(
+            model, spec=SpecConfig(k=2), **kw), SpecPagedEngine
+    spec = ContinuousBatchingEngine(model, spec=SpecConfig(k=2), **kw)
+    return ContinuousBatchingEngine(backend=spec.backend), SpecPagedEngine
+
+
+@pytest.mark.parametrize("case", ["plain", "two-groups", "spec",
+                                  "spec-backend"])
+def test_the_factory_picks_from_what_it_observes(case, paged_setup,
+                                                 monkeypatch):
+    """ContinuousBatchingEngine(...) is the factory: the hybrid engine
+    for a model whose cache keeps two groups of layers, the speculative
+    engine under spec= (or over a verify-capable backend), the engine
+    itself otherwise. paged=True and the environment decide nothing."""
+    monkeypatch.setenv("PT_SERVING_PAGED", "0")
+    monkeypatch.delenv("PT_SERVING_SPEC", raising=False)
+    eng, want = _factory_case(case, paged_setup[0])
+    assert type(eng) is want
+    if case != "spec-backend":
+        monkeypatch.setenv("PT_SERVING_PAGED", "1")
+        again, _ = _factory_case(case, paged_setup[0])
+        assert type(again) is want
+
+
+@pytest.mark.parametrize("case", ["plain", "two-groups", "spec"])
+def test_paged_false_is_refused(case, paged_setup):
+    """The dense slot-pool engine was removed: paged=False raises, by
+    name, whichever engine the factory would have built."""
+    from paddle_tpu.models.mimo_v2 import (MiMoV2ForCausalLM,
+                                           mimo_v2_tiny_config)
+    from paddle_tpu.serving.spec import SpecConfig
+    model = paged_setup[0] if case != "two-groups" else \
+        MiMoV2ForCausalLM(mimo_v2_tiny_config())
+    extra = dict(spec=SpecConfig(k=2)) if case == "spec" else {}
+    with pytest.raises(ValueError, match="dense slot-pool engine was "
+                                         "removed"):
+        ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                 block_size=8, paged=False, **extra)
 
 
 @pytest.fixture(scope="module")
@@ -955,33 +992,30 @@ def test_default_prefill_chunk(case, request, paged_setup, monkeypatch):
 
 
 class TestPagedArtifact:
-    """PR 4 carried follow-up: export_decoder(engine_paged=True) ships
-    the paged engine's TWO programs with recorded arities, and
+    """PR 4 carried follow-up: export_decoder(engine_slots=N) ships
+    the engine's TWO programs with recorded arities, and
     PagedArtifactStepBackend serves them: a stub-backend test plus
     the artifact-level test through jax.export."""
 
     class _PagedProxyBackend:
         """Stands in for a PagedArtifactStepBackend: proxies the live
-        paged backend and carries the artifact markers (is_paged routes
-        the factory; the arity flag mirrors the recorded config)."""
-        is_paged = True
+        backend and carries the artifact's fingerprint."""
 
         def __init__(self, inner):
             self._inner = inner
-            self.carries_nan_flags = True
             self.artifact_fingerprint = "sha1:paged-stub"
 
         def __getattr__(self, name):
             return getattr(self.__dict__["_inner"], name)
 
     def test_stub_paged_backend_routes_and_serves(self, paged_setup):
-        """A backend advertising is_paged routes the factory to the
-        PagedEngine WITHOUT the paged= keyword (how the AOT serve()
-        path constructs it) and serves a bit-identical stream."""
+        """An explicit backend builds the engine itself, with no other
+        keyword (how the AOT serve() path constructs it), and serves a
+        bit-identical stream."""
         model, cfg, engine = paged_setup
         eng = ContinuousBatchingEngine(
             backend=self._PagedProxyBackend(engine.backend))
-        assert isinstance(eng, PagedEngine)
+        assert type(eng) is ContinuousBatchingEngine
         _LIVE_MANAGERS.append(eng.manager)
         rs = np.random.RandomState(41)
         prompts = [rs.randint(0, cfg.vocab_size, (L,)).astype(np.int32)
@@ -995,10 +1029,10 @@ class TestPagedArtifact:
 
     def test_paged_artifact_arity_and_bit_identity(self, paged_setup,
                                                    tmp_path):
-        """The exported paged artifact records both program arities
+        """The exported artifact records both program arities
         (block_outputs=5, chunk_outputs=2), loads through
-        PagedArtifactStepBackend, and GenerationPredictor.serve routes
-        it to the paged engine with bit-identical greedy results."""
+        PagedArtifactStepBackend, and GenerationPredictor.serve builds
+        the engine over it with bit-identical greedy results."""
         import pickle
         from paddle_tpu.inference import (GenerationPredictor,
                                           export_decoder)
@@ -1007,7 +1041,7 @@ class TestPagedArtifact:
         path = export_decoder(model, str(tmp_path / "paged"), batch=1,
                               prompt_len=8, max_len=64, engine_slots=2,
                               engine_decode_block=4,
-                              engine_paged=True, engine_block_size=8,
+                              engine_block_size=8,
                               engine_prefill_chunk=8)
         with open(path, "rb") as f:
             blob = pickle.load(f)
@@ -1016,7 +1050,6 @@ class TestPagedArtifact:
         assert cfgs["block_outputs"] == 5
         assert cfgs["chunk_outputs"] == 2
         back = PagedArtifactStepBackend(blob)
-        assert back.carries_nan_flags
         assert back.kv_block_size == 8
         served = GenerationPredictor(path)
         rs = np.random.RandomState(43)
@@ -1024,12 +1057,8 @@ class TestPagedArtifact:
                    for L in (5, 9, 12)]
         srv = served.serve([{"prompt": p, "max_new_tokens": 5}
                             for p in prompts], run=False)
-        assert isinstance(srv.engine, PagedEngine)
+        assert type(srv.engine) is ContinuousBatchingEngine
         res = srv.run_until_idle()
         for rid, p in enumerate(prompts):
             np.testing.assert_array_equal(
                 res[rid], _ref(model, p, 5, temperature=0.0))
-        # a dense loader on a paged artifact must refuse loudly
-        from paddle_tpu.serving import ArtifactStepBackend
-        with pytest.raises(KeyError):
-            ArtifactStepBackend(blob)

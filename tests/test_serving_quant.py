@@ -10,10 +10,10 @@ ops/pallas/paged_attention.py int8 in-read dequant):
   per-slot dense shape);
 - weight-only int8/int4 serving: engine streams BIT-IDENTICAL to
   generate() on a host-dequantized twin model (the in-graph dequant is
-  exact), composing with paged/kv_int8/spec, with the
+  exact), composing with kv_int8/spec, with the
   runtime-queryable error bounds and registry bytes accounting;
-- the six compositions the engine factory builds (dense, paged, int8
-  arena, int8/int4 weights, speculative) over ONE ragged stream, each
+- the five compositions the engine factory builds (fp32, int8 arena,
+  int8/int4 weights, speculative) over ONE ragged stream, each
   against its reference with one decode compile;
 - the routing matrix: explicit backends never rerouted by
   PT_SERVING_QUANT_WEIGHTS, quant= alongside an explicit backend /
@@ -29,9 +29,9 @@ from paged_walk_cases import WALK_CASES, chunk_rows, walk_inputs
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.nn.quant import dequantize_array, quantize_array
-from paddle_tpu.serving import (ContinuousBatchingEngine, PagedEngine,
-                                QuantConfig, Scheduler, Server,
-                                SpecConfig, SpecEngine, SpecPagedEngine)
+from paddle_tpu.serving import (ContinuousBatchingEngine, QuantConfig,
+                                Scheduler, Server, SpecConfig,
+                                SpecPagedEngine)
 from paddle_tpu.serving.quant import resolve_quant_config
 
 _QUANT_PATTERNS = ("q_proj", "k_proj", "v_proj", "o_proj", "gate_proj",
@@ -199,8 +199,7 @@ class TestInt8KVInRead:
         def fp32_shapes(engine):
             back = engine.backend
             from paddle_tpu.serving.engine import build_slot_block_fn
-            fn = build_slot_block_fn(back._pure, engine.decode_block,
-                                     paged=True)
+            fn = build_slot_block_fn(back._pure, engine.decode_block)
             closed = jax.make_jaxpr(fn)(
                 back._pv, back._bv, engine._cache, engine._state)
             shapes = set()
@@ -331,7 +330,7 @@ class TestWeightOnlyServing:
         spec = ContinuousBatchingEngine(
             model, num_slots=2, max_len=64, decode_block=4,
             spec=SpecConfig(k=4), quant="int8")
-        assert isinstance(spec, SpecEngine)
+        assert isinstance(spec, SpecPagedEngine)
         prompts = _prompts(cfg, 9, (5, 9))
         a = _stream(plain, prompts, max_new=8)
         b = _stream(spec, prompts, max_new=8)
@@ -401,19 +400,18 @@ class TestWeightOnlyServing:
 # every composition of the engine factory over one stream
 # ---------------------------------------------------------------------------
 
-_DENSE = dict(num_slots=2, max_len=64, decode_block=4, prompt_buckets=(16,))
-_PAGED = dict(num_slots=2, max_len=64, decode_block=4, paged=True,
-              block_size=8, prefill_chunk=8)
+_PAGED = dict(num_slots=2, max_len=64, decode_block=4, block_size=8,
+              prefill_chunk=8)
 # name -> (factory arguments, the class the factory must build, bits of the
 # dequantized twin generate() runs on or None for the model itself)
 COMPOSITIONS = {
-    "dense": (_DENSE, ContinuousBatchingEngine, None),
-    "paged": (_PAGED, PagedEngine, None),
-    "paged-kv_int8": (dict(_PAGED, kv_int8=True), PagedEngine, None),
+    "paged": (_PAGED, ContinuousBatchingEngine, None),
+    "paged-kv_int8": (dict(_PAGED, kv_int8=True), ContinuousBatchingEngine,
+                      None),
     "paged-kv_int8-w_int8": (dict(_PAGED, kv_int8=True,
                                   quant=QuantConfig(weights="int8")),
-                             PagedEngine, 8),
-    "dense-w_int4": (dict(_DENSE, quant=QuantConfig(weights="int4")),
+                             ContinuousBatchingEngine, 8),
+    "paged-w_int4": (dict(_PAGED, quant=QuantConfig(weights="int4")),
                      ContinuousBatchingEngine, 4),
     "paged-spec_k8": (dict(_PAGED, max_len=96, spec=SpecConfig(k=8)),
                       SpecPagedEngine, None),
@@ -450,9 +448,8 @@ def test_every_composition_serves_the_same_stream(setup, name, monkeypatch):
     for p, a, b in zip(prompts, got, ref):
         assert a.shape == (len(p) + 5,)
         np.testing.assert_array_equal(a, b)
-    if kw.get("paged"):
-        assert eng.prefill_compile_count() == 1
-        eng.manager.assert_consistent()
+    assert eng.prefill_compile_count() == 1
+    eng.manager.assert_consistent()
 
 
 # ---------------------------------------------------------------------------
@@ -476,11 +473,9 @@ class TestTPQuant:
         model = LlamaForCausalLM(cfg)
         mesh = build_device_mesh({"dp": 2, "mp": 4})
         one = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=64, decode_block=4,
-            prompt_buckets=(16,), quant="int8")
+            model, num_slots=2, max_len=64, decode_block=4, quant="int8")
         tp = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=64, decode_block=4,
-            prompt_buckets=(16,), quant="int8",
+            model, num_slots=2, max_len=64, decode_block=4, quant="int8",
             tp=TPConfig(axes=("dp", "mp"), mesh=mesh))
         prompts = _prompts(cfg, 12, (5, 9))
         a, b = _stream(one, prompts), _stream(tp, prompts)
@@ -504,10 +499,9 @@ class TestQuantRouting:
                                                       monkeypatch):
         """PT_SERVING_QUANT_WEIGHTS opts IN new engine builds only: a
         caller holding an explicit backend keeps its fp32 weights."""
-        from paddle_tpu.serving import ModelStepBackend
         model, _, cfg = setup
-        backend = ModelStepBackend(model, num_slots=2, max_len=64,
-                                   decode_block=4)
+        backend = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
+                                           decode_block=4).backend
         monkeypatch.setenv("PT_SERVING_QUANT_WEIGHTS", "int8")
         eng = ContinuousBatchingEngine(backend=backend)
         assert eng.backend.quant_cfg is None
@@ -519,18 +513,14 @@ class TestQuantRouting:
         assert eng2.weight_error_bound() > 0.0
 
     def test_quant_with_explicit_backend_refused(self, setup):
-        from paddle_tpu.serving import ModelStepBackend
         model, _, cfg = setup
-        backend = ModelStepBackend(model, num_slots=2, max_len=64,
-                                   decode_block=4)
-        with pytest.raises(ValueError, match="explicit backend"):
-            ContinuousBatchingEngine(backend=backend, quant="int8")
-        paged = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=64, decode_block=4, paged=True,
+        plain = ContinuousBatchingEngine(
+            model, num_slots=2, max_len=64, decode_block=4,
             block_size=8, prefill_chunk=8)
-        with pytest.raises(ValueError, match="explicit backend"):
-            ContinuousBatchingEngine(backend=paged.backend,
-                                     quant=QuantConfig())
+        for quant in ("int8", QuantConfig()):
+            with pytest.raises(ValueError, match="explicit backend"):
+                ContinuousBatchingEngine(backend=plain.backend,
+                                         quant=quant)
         # quant=False against a QUANTIZED backend refuses too: the
         # codes are baked in — silently serving quantized weights to a
         # caller who pinned fp32 would be the inverse misconfiguration
@@ -566,13 +556,14 @@ class TestQuantRouting:
         assert resolve_quant_config(False) is None
 
     def test_direct_paged_ctor_honors_quant(self, setup):
-        """PagedEngine(model, ..., quant=...) — the direct-constructor
-        route — quantizes like the factory (same contract as
-        kv_int8)."""
+        """SpecPagedEngine(model, ..., quant=...) — a subclass's direct
+        constructor, which the factory does not route — quantizes like
+        the factory (same contract as kv_int8)."""
         model, _, cfg = setup
-        eng = PagedEngine(model, num_slots=2, max_len=64,
-                          decode_block=4, block_size=8, prefill_chunk=8,
-                          quant="int8")
+        eng = SpecPagedEngine(model, num_slots=2, max_len=64,
+                              decode_block=4, block_size=8,
+                              prefill_chunk=8, spec=SpecConfig(k=2),
+                              quant="int8")
         assert eng.weight_error_bound() > 0.0
         prompts = _prompts(cfg, 11, (5,))
         assert _stream(eng, prompts)[0].shape == (11,)

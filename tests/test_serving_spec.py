@@ -1,6 +1,6 @@
 """Speculative draft-verify serving (paddle_tpu/serving/spec.py):
 greedy spec-mode streams bit-identical to non-speculative decode and
-per-request generate() (dense, paged, chunked prefill, eos inside an
+per-request generate() (a whole prompt in one chunk or in many, eos inside an
 accepted span), acceptance edge cases (k=0, all-k-accepted via an
 oracle drafter), the sampled-traffic k=0 key-schedule fallback, the
 compile-count pin (ONE verify program), chaos schedules with spec
@@ -10,38 +10,40 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
-from paddle_tpu.serving import (ContinuousBatchingEngine, PagedEngine,
-                                RequestFailure, ResilienceConfig,
-                                Scheduler, Server, SpecConfig,
-                                SpecEngine, SpecPagedEngine,
-                                ngram_propose)
+from paddle_tpu.serving import (ContinuousBatchingEngine,
+                                PrefillPagedEngine, RequestFailure,
+                                ResilienceConfig, Scheduler, Server,
+                                SpecConfig, SpecPagedEngine, ngram_propose)
 from paddle_tpu.utils import faults
 
 
 @pytest.fixture(scope="module")
 def spec_setup():
-    """One model + one dense and one paged speculative engine for the
-    whole file (reset() frees slots/blocks, never the compiled verify/
-    chunk programs). Constructed through the ContinuousBatchingEngine
-    factory so the spec= routing is on the tested path."""
+    """One model + two speculative engines for the whole file: one at
+    the default geometry (16-token blocks, a whole prompt in one chunk)
+    and one at 8-token blocks and chunks (reset() frees slots/blocks,
+    never the compiled verify/chunk programs). Constructed through the
+    ContinuousBatchingEngine factory so the spec= routing is on the
+    tested path."""
     paddle.seed(0)
     cfg = llama_tiny_config(tensor_parallel=False)
     model = LlamaForCausalLM(cfg)
-    dense = ContinuousBatchingEngine(
+    spec16 = ContinuousBatchingEngine(
         model, num_slots=2, max_len=96, decode_block=4,
-        prompt_buckets=(8, 16), spec=SpecConfig(k=4))
+        spec=SpecConfig(k=4))
     paged = ContinuousBatchingEngine(
         model, num_slots=2, max_len=96, decode_block=4, paged=True,
         block_size=8, prefill_chunk=8, spec=SpecConfig(k=4))
-    assert isinstance(dense, SpecEngine)
+    assert isinstance(spec16, SpecPagedEngine)
     assert isinstance(paged, SpecPagedEngine)
-    return model, cfg, dense, paged
+    return model, cfg, spec16, paged
 
 
 @pytest.fixture(autouse=True)
 def _paged_invariants(spec_setup):
     """Arena accounting must hold after every test in this file."""
     yield
+    spec_setup[2].manager.assert_consistent()
     spec_setup[3].manager.assert_consistent()
 
 
@@ -100,21 +102,21 @@ class TestSpecBitExactness:
         """5 ragged greedy requests through 2 speculative slots: every
         output bit-identical to standalone generate(), ONE verify
         program compiled across all admissions/retirements."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         prompts = _prompts(cfg, 0, (5, 9, 12, 5, 9))
         news = [12, 8, 10, 9, 12]
-        srv = Server(dense)
+        srv = Server(spec16)
         rids = [srv.submit(p, max_new_tokens=mn)
                 for p, mn in zip(prompts, news)]
         res = srv.run_until_idle()
         for rid, p, mn in zip(rids, prompts, news):
             np.testing.assert_array_equal(
                 res[rid], _ref(model, p, mn, temperature=0.0))
-        assert dense.decode_compile_count() == 1
+        assert spec16.decode_compile_count() == 1
         st = srv.stats()
         assert st["spec_k"] == 4
-        assert st["spec_verify_steps"] == dense.verify_steps > 0
+        assert st["spec_verify_steps"] == spec16.verify_steps > 0
 
     def test_paged_chunked_stream_bit_exact_one_compile(self,
                                                         spec_setup):
@@ -139,23 +141,22 @@ class TestSpecBitExactness:
         assert paged.prefill_compile_count() == 1
 
     def test_spec_stream_equals_plain_engine_stream(self, spec_setup):
-        """The spec engine's results also equal the plain slot-pool
-        engine's on the same stream (the bit-identity is engine-level,
-        not just per-request)."""
-        model, cfg, dense, _ = spec_setup
+        """The spec engine's results also equal the plain engine's on
+        the same stream (the bit-identity is engine-level, not just
+        per-request)."""
+        model, cfg, spec16, _ = spec_setup
         plain = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=96, decode_block=4,
-            prompt_buckets=(8, 16))
-        assert not isinstance(plain, SpecEngine)
+            model, num_slots=2, max_len=96, decode_block=4)
+        assert not isinstance(plain, SpecPagedEngine)
         prompts = _prompts(cfg, 3, (5, 9, 12))
         outs = {}
-        for eng in (dense, plain):
+        for eng in (spec16, plain):
             eng.reset()
             srv = Server(eng)
             rids = [srv.submit(p, max_new_tokens=9, arrival_step=i)
                     for i, p in enumerate(prompts)]
             res = srv.run_until_idle()
-            outs[eng is dense] = [res[r] for r in rids]
+            outs[eng is spec16] = [res[r] for r in rids]
         for a, b in zip(outs[True], outs[False]):
             np.testing.assert_array_equal(a, b)
 
@@ -166,10 +167,10 @@ class TestSpecBitExactness:
         matches generate(seed) token-for-token — the per-request key
         schedule survives because its verify steps emit exactly one
         token through the same split+sample sequence."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         pg, pk = _prompts(cfg, 2, (5, 9))
-        srv = Server(dense)
+        srv = Server(spec16)
         rg = srv.submit(pg, max_new_tokens=8)
         rk = srv.submit(pk, max_new_tokens=8, temperature=1.0, top_k=50,
                         seed=7)
@@ -189,39 +190,39 @@ class TestAcceptance:
         verify step (ragged at the budget tail), and the output stays
         bit-identical. max_new=14 at k=4: steps emit 5/5/4 after the
         prefill token -> exactly 3 verify steps."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         p = _prompts(cfg, 5, (6,))[0]
         ref = _ref(model, p, 14, temperature=0.0)
         cont = ref[len(p):].astype(np.int32)    # [tok0, tail...]
-        srv = Server(dense)
+        srv = Server(spec16)
         rid = srv.submit(p, max_new_tokens=14)
-        monkeypatch.setattr(dense, "_propose", _oracle(dense, {rid: cont}))
+        monkeypatch.setattr(spec16, "_propose", _oracle(spec16, {rid: cont}))
         res = srv.run_until_idle()
         np.testing.assert_array_equal(res[rid], ref)
-        assert dense.acceptance_rate() == 1.0
-        assert dense.verify_steps == 3
-        assert dense.draft_accepted == 10       # 4 + 4 + 2
+        assert spec16.acceptance_rate() == 1.0
+        assert spec16.verify_steps == 3
+        assert spec16.draft_accepted == 10       # 4 + 4 + 2
 
     def test_eos_inside_accepted_span(self, spec_setup, monkeypatch):
         """An eos landing mid-span cuts the ragged advance at the eos
         (one verify step retires the slot) and the result equals
         generate(eos_token_id=...) including its eos padding."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         p = _prompts(cfg, 6, (7,))[0]
         free = _ref(model, p, 14, temperature=0.0)
         cont = free[len(p):].astype(np.int32)
         eos = int(cont[3])          # 4th generated token: mid first span
         assert eos not in cont[:3]  # genuinely mid-span, not at an edge
         ref = _ref(model, p, 14, temperature=0.0, eos_token_id=eos)
-        srv = Server(dense)
+        srv = Server(spec16)
         rid = srv.submit(p, max_new_tokens=14, eos_token_id=eos)
-        monkeypatch.setattr(dense, "_propose", _oracle(dense, {rid: cont}))
+        monkeypatch.setattr(spec16, "_propose", _oracle(spec16, {rid: cont}))
         res = srv.run_until_idle()
         np.testing.assert_array_equal(res[rid], ref)
         assert (res[rid][len(p) + 4:] == eos).all()
-        assert dense.verify_steps == 1          # retired inside span 1
+        assert spec16.verify_steps == 1          # retired inside span 1
 
     def test_k0_degenerates_to_plain_decode(self, spec_setup):
         """k=0: the (S, 1) verify window emits exactly one token per
@@ -229,8 +230,8 @@ class TestAcceptance:
         model, cfg, _, _ = spec_setup
         eng = ContinuousBatchingEngine(
             model, num_slots=2, max_len=64, decode_block=4,
-            prompt_buckets=(8, 16), spec=SpecConfig(k=0))
-        assert isinstance(eng, SpecEngine)
+            spec=SpecConfig(k=0))
+        assert isinstance(eng, SpecPagedEngine)
         prompts = _prompts(cfg, 8, (5, 9, 12))
         srv = Server(eng)
         rids = [srv.submit(p, max_new_tokens=6) for p in prompts]
@@ -245,16 +246,16 @@ class TestAcceptance:
         """The real n-gram drafter on a repetitive continuation: some
         drafts must be accepted (the speculation path actually fires —
         bit-identity alone would also pass with a dead drafter)."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         p = np.full((16,), 7, np.int32)     # heavy-repetition prompt
-        srv = Server(dense)
+        srv = Server(spec16)
         rid = srv.submit(p, max_new_tokens=40)
         res = srv.run_until_idle()
         np.testing.assert_array_equal(
             res[rid], _ref(model, p, 40, temperature=0.0))
-        assert dense.draft_proposed > 0
-        assert dense.verify_steps < 39      # strictly fewer steps than
+        assert spec16.draft_proposed > 0
+        assert spec16.verify_steps < 39      # strictly fewer steps than
         #                                     tokens -> multi-token steps
 
 
@@ -282,28 +283,26 @@ class TestRouting:
         model = spec_setup[0]
         monkeypatch.setenv("PT_SERVING_SPEC", "3")
         eng = ContinuousBatchingEngine(model, num_slots=2, max_len=32,
-                                       decode_block=4,
-                                       prompt_buckets=(8,))
-        assert isinstance(eng, SpecEngine) and eng.spec_k == 3
+                                       decode_block=4)
+        assert isinstance(eng, SpecPagedEngine) and eng.spec_k == 3
 
     def test_env_never_reroutes_explicit_backend(self, spec_setup,
                                                  monkeypatch):
         """An explicitly passed NON-spec backend stays non-spec even
-        with PT_SERVING_SPEC armed (same contract as paged/tp)."""
-        model, cfg, dense, paged = spec_setup
+        with PT_SERVING_SPEC armed (same contract as quant/tp)."""
+        model, cfg, spec16, paged = spec_setup
         plain = ContinuousBatchingEngine(
-            model, num_slots=2, max_len=96, decode_block=4,
-            prompt_buckets=(8, 16))
+            model, num_slots=2, max_len=96, decode_block=4)
         monkeypatch.setenv("PT_SERVING_SPEC", "4")
         again = ContinuousBatchingEngine(backend=plain.backend)
-        assert not isinstance(again, SpecEngine)
+        assert not isinstance(again, SpecPagedEngine)
 
     def test_spec_backend_is_the_decision(self, spec_setup):
         """A spec backend routes without the keyword (backend carries
-        the config), dense AND paged."""
-        model, cfg, dense, paged = spec_setup
-        d2 = ContinuousBatchingEngine(backend=dense.backend)
-        assert isinstance(d2, SpecEngine) and d2.spec_k == 4
+        the config), at either geometry."""
+        model, cfg, spec16, paged = spec_setup
+        d2 = ContinuousBatchingEngine(backend=spec16.backend)
+        assert isinstance(d2, SpecPagedEngine) and d2.spec_k == 4
         p2 = ContinuousBatchingEngine(backend=paged.backend)
         assert isinstance(p2, SpecPagedEngine) and p2.spec_k == 4
 
@@ -312,19 +311,16 @@ class TestRouting:
         not silently ignored."""
         model = spec_setup[0]
         with pytest.raises(ValueError, match="factory"):
-            PagedEngine(model, num_slots=2, max_len=64, decode_block=4,
-                        block_size=8, spec=SpecConfig(k=2))
+            PrefillPagedEngine(model, num_slots=2, max_len=64,
+                               decode_block=4, block_size=8,
+                               spec=SpecConfig(k=2))
 
     def test_direct_ctor_paged_mismatch_refused(self, spec_setup):
-        """SpecEngine(paged=True) / SpecPagedEngine(paged=False) are
-        hard errors, not silently-ignored kwargs — same contract as
-        spec= on a direct non-factory constructor."""
+        """SpecPagedEngine(paged=False) is a hard error, not a
+        silently-ignored kwarg: the dense slot-pool engine was removed —
+        same contract as spec= on a direct non-factory constructor."""
         model = spec_setup[0]
-        with pytest.raises(ValueError, match="dense speculative"):
-            SpecEngine(model, num_slots=2, max_len=64, decode_block=4,
-                       prompt_buckets=(8,), paged=True,
-                       spec=SpecConfig(k=2))
-        with pytest.raises(ValueError, match="paged speculative"):
+        with pytest.raises(ValueError, match="dense slot-pool engine"):
             SpecPagedEngine(model, num_slots=2, max_len=64,
                             decode_block=4, block_size=8, paged=False,
                             spec=SpecConfig(k=2))
@@ -334,7 +330,7 @@ class TestRouting:
         model = spec_setup[0]
         with pytest.raises(NotImplementedError, match="tensor-parallel"):
             ContinuousBatchingEngine(model, num_slots=2, max_len=32,
-                                     decode_block=4, prompt_buckets=(8,),
+                                     decode_block=4,
                                      spec=SpecConfig(k=2),
                                      tp=TPConfig(axes=("mp",)))
 
@@ -346,12 +342,11 @@ class TestSpecPreemption:
     have, so preempted spec streams stay bit-identical to generate()
     (greedy) / generate(seed) (sampled, which never speculates)."""
 
-    @pytest.mark.parametrize("which", ["dense", "paged"])
+    @pytest.mark.parametrize("which", ["paged"])
     def test_greedy_preempt_resume_bit_identical(self, spec_setup,
                                                  which):
         from paddle_tpu.serving import Frontend
-        model, cfg, dense, paged = spec_setup
-        engine = dense if which == "dense" else paged
+        model, cfg, _, engine = spec_setup
         engine.reset()
         prompts = _prompts(cfg, 30, (5, 9, 12))
         fe = Frontend(engine, preemption=True)
@@ -375,10 +370,10 @@ class TestSpecPreemption:
         carries its rng key through the eviction — the resumed stream
         follows the exact generate(seed) key schedule."""
         from paddle_tpu.serving import Frontend
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         prompts = _prompts(cfg, 31, (5, 9, 12))
-        fe = Frontend(dense, preemption=True)
+        fe = Frontend(spec16, preemption=True)
         rs_ = fe.submit(prompts[0], max_new_tokens=20, priority=0,
                         temperature=0.9, top_k=40, seed=11)
         rg = fe.submit(prompts[1], max_new_tokens=20, priority=0)
@@ -394,16 +389,16 @@ class TestSpecPreemption:
             res[rg], _ref(model, prompts[1], 20, temperature=0.0))
         np.testing.assert_array_equal(
             res[hi], _ref(model, prompts[2], 4, temperature=0.0))
-        assert dense.decode_compile_count() == 1
+        assert spec16.decode_compile_count() == 1
 
     def test_explicit_preemption_no_longer_refused(self, spec_setup):
         """The PR 13 NotImplementedError guard is gone: explicit
         preemption=True on a spec engine constructs (TP engines are
         still refused — see test_frontend.py)."""
         from paddle_tpu.serving import FairScheduler, Server
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
-        srv = Server(dense, FairScheduler(), preemption=True)
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
+        srv = Server(spec16, FairScheduler(), preemption=True)
         assert srv.preemption
 
 
@@ -455,10 +450,10 @@ class TestSpecResilience:
     def test_poison_quarantines_only_that_slot(self, spec_setup):
         """The NaN sentinel rides the verify block: the poisoned slot
         fails as 'poisoned', its neighbour's stream is untouched."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         p0, p1 = _prompts(cfg, 9, (5, 9))
-        srv = Server(dense)
+        srv = Server(spec16)
         r0 = srv.submit(p0, max_new_tokens=10)
         r1 = srv.submit(p1, max_new_tokens=10, arrival_step=1)
         with faults.injected("serving.poison:at=2,times=1", seed=0):
@@ -479,7 +474,7 @@ class TestSpecResilience:
         """Mid-stream snapshot/restore of the speculative engine into a
         fresh process simulation: every stream finishes bit-identical
         and the spec counters survive the round trip."""
-        model, cfg, dense, _ = spec_setup
+        model, cfg, spec16, _ = spec_setup
         prompts = _prompts(cfg, 11, (5, 9, 12))
         news = [10, 8, 9]
 
@@ -487,17 +482,17 @@ class TestSpecResilience:
             return [srv.submit(p, max_new_tokens=mn, arrival_step=i)
                     for i, (p, mn) in enumerate(zip(prompts, news))]
 
-        dense.reset()
-        srv_ref = Server(dense)
+        spec16.reset()
+        srv_ref = Server(spec16)
         rids = submit_all(srv_ref)
         ref = srv_ref.run_until_idle()
 
-        dense.reset()
-        srv_kill = Server(dense)
+        spec16.reset()
+        srv_kill = Server(spec16)
         assert submit_all(srv_kill) == rids
         srv_kill.run_until_idle(max_ticks=3)
-        assert dense.has_live()
-        steps_at_kill = dense.verify_steps
+        assert spec16.has_live()
+        steps_at_kill = spec16.verify_steps
         path = str(tmp_path / "spec.npz")
         srv_kill.snapshot(path)
 
@@ -505,7 +500,7 @@ class TestSpecResilience:
         model2 = LlamaForCausalLM(cfg)
         engine2 = ContinuousBatchingEngine(
             model2, num_slots=2, max_len=96, decode_block=4,
-            prompt_buckets=(8, 16), spec=SpecConfig(k=4))
+            spec=SpecConfig(k=4))
         srv_new = Server.restore(path, engine2)
         assert engine2.verify_steps == steps_at_kill
         res = srv_new.run_until_idle()
@@ -516,12 +511,12 @@ class TestSpecResilience:
     def test_restore_refuses_mismatched_k(self, spec_setup, tmp_path):
         """A snapshot taken at k=4 cannot restore into a k=2 engine
         (different verify window) — loud error, not silent resume."""
-        model, cfg, dense, _ = spec_setup
-        dense.reset()
+        model, cfg, spec16, _ = spec_setup
+        spec16.reset()
         path = str(tmp_path / "k4.npz")
-        dense.snapshot(path)
+        spec16.snapshot(path)
         engine2 = ContinuousBatchingEngine(
             model, num_slots=2, max_len=96, decode_block=4,
-            prompt_buckets=(8, 16), spec=SpecConfig(k=2))
+            spec=SpecConfig(k=2))
         with pytest.raises(ValueError, match="k=4"):
             engine2.restore(path)

@@ -2,7 +2,7 @@
 decode block sharded over a simulated 2x4 device mesh.
 
 The defining contract: exact-mode sharded streams — greedy AND seeded
-sampling, dense AND paged, under staggered arrivals — are BIT-IDENTICAL
+sampling, 8- and 16-token blocks, under staggered arrivals — are BIT-IDENTICAL
 to the 1-chip engine, with decode/prefill compile counts still pinned
 at 1. Plus: the KV cache really shards its kv-head dim (the per-chip
 HBM win), the psum-mode int8 hidden-state all-reduce exposes its
@@ -21,9 +21,7 @@ from paddle_tpu.distributed.mesh import (build_device_mesh,
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.serving import (ContinuousBatchingEngine, Scheduler,
                                 Server, TPConfig)
-from paddle_tpu.serving.tp import (ShardedModelStepBackend,
-                                   ShardedPagedStepBackend,
-                                   resolve_tp_config)
+from paddle_tpu.serving.tp import ShardedPagedStepBackend, resolve_tp_config
 
 pytestmark = pytest.mark.skipif(
     jax.device_count() < 8,
@@ -37,8 +35,9 @@ def mesh():
 
 @pytest.fixture(scope="module")
 def setup(mesh):
-    """One model + the 1-chip and sharded engines for the whole file
-    (compiled programs persist across reset())."""
+    """One model + the 1-chip and sharded engines, at 8-token blocks
+    and chunks, for the whole file (compiled programs persist across
+    reset())."""
     paddle.seed(0)
     # 8 kv heads: divisible by the full 2x4 degree so the KV arena
     # shards whole heads per device
@@ -46,12 +45,11 @@ def setup(mesh):
                             num_key_value_heads=8)
     model = LlamaForCausalLM(cfg)
     ref = ContinuousBatchingEngine(model, num_slots=2, max_len=64,
-                                   decode_block=4,
-                                   prompt_buckets=(8, 16))
+                                   decode_block=4, block_size=8,
+                                   prefill_chunk=8)
     tp = ContinuousBatchingEngine(
-        model, num_slots=2, max_len=64, decode_block=4,
-        prompt_buckets=(8, 16),
-        tp=TPConfig(axes=("dp", "mp"), mesh=mesh))
+        model, num_slots=2, max_len=64, decode_block=4, block_size=8,
+        prefill_chunk=8, tp=TPConfig(axes=("dp", "mp"), mesh=mesh))
     return model, cfg, ref, tp
 
 
@@ -238,7 +236,6 @@ class TestPsumInt8:
         model, cfg, _, _ = setup
         return ContinuousBatchingEngine(
             model, num_slots=2, max_len=64, decode_block=4,
-            prompt_buckets=(8, 16),
             tp=TPConfig(axes=("mp",), mode="psum", int8=True,
                         mesh=mesh))
 
@@ -383,7 +380,7 @@ class TestEnvFlagsAndValidation:
         try:
             eng = ContinuousBatchingEngine(model, num_slots=1,
                                            max_len=32, decode_block=2)
-            assert isinstance(eng.backend, ShardedModelStepBackend)
+            assert isinstance(eng.backend, ShardedPagedStepBackend)
             assert eng.tp_degree() == 4
         finally:
             set_current_mesh(None)
@@ -392,7 +389,7 @@ class TestEnvFlagsAndValidation:
         model, cfg, ref, _ = setup
         monkeypatch.setenv("PT_SERVING_TP", "1")
         eng = ContinuousBatchingEngine(backend=ref.backend)
-        assert not isinstance(eng.backend, ShardedModelStepBackend)
+        assert not isinstance(eng.backend, ShardedPagedStepBackend)
         assert eng.tp_degree() == 1
 
     def test_config_validation(self, setup, mesh):

@@ -19,7 +19,8 @@ import paddle_tpu as paddle
 from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
 from paddle_tpu.observability import tracing
 from paddle_tpu.serving import ContinuousBatchingEngine, Server
-from paddle_tpu.serving.engine import (build_slot_block_fn, init_slot_state,
+from paddle_tpu.serving.engine import (build_paged_chunk_fn,
+                                       build_slot_block_fn, init_slot_state,
                                        slot_sample_logits)
 from paddle_tpu.serving.spec import build_spec_block_fn
 
@@ -175,15 +176,18 @@ def _toy_pure(all_positions=False):
 def _case_sort_only_under_cond(program):
     S, V = 3, 24
     pv = jnp.ones((V, V), jnp.float32)
-    state = init_slot_state(S)
+    state = dict(init_slot_state(S), table=jnp.zeros((S, 2), jnp.int32))
     cache = (jnp.zeros((S, 4), jnp.float32),)
-    if program == "block":
+    if program == "chunk":
+        fn = build_paged_chunk_fn(_toy_pure(), 8)
+        closed = jax.make_jaxpr(fn)(
+            pv, (), jnp.zeros((1, 8), jnp.int32), cache,
+            jnp.zeros((1, 2), jnp.int32), jnp.int32(0), jnp.int32(8),
+            jax.random.PRNGKey(0), jnp.float32(0), jnp.int32(0),
+            jnp.float32(1))
+    elif program == "paged_block":
         fn = build_slot_block_fn(_toy_pure(), 4)
         closed = jax.make_jaxpr(fn)(pv, (), cache, state)
-    elif program == "paged_block":
-        fn = build_slot_block_fn(_toy_pure(), 4, paged=True)
-        closed = jax.make_jaxpr(fn)(
-            pv, (), cache, dict(state, table=jnp.zeros((S, 2), jnp.int32)))
     else:
         fn = build_spec_block_fn(_toy_pure(all_positions=True), 2)
         closed = jax.make_jaxpr(fn)(
@@ -267,7 +271,7 @@ CASES = {
         live=[True, True, False, True]),
     "c-dead-sampled-row-masked": lambda: _case_dead_sampled_row(True),
     "c-dead-sampled-row-no-mask": lambda: _case_dead_sampled_row(False),
-    "d-sort-under-cond-block": lambda: _case_sort_only_under_cond("block"),
+    "d-sort-under-cond-chunk": lambda: _case_sort_only_under_cond("chunk"),
     "d-sort-under-cond-paged-block": lambda: _case_sort_only_under_cond(
         "paged_block"),
     "d-sort-under-cond-spec-block": lambda: _case_sort_only_under_cond(

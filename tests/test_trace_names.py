@@ -233,11 +233,6 @@ def _lower_chunk(be):
         jnp.float32(0), jnp.int32(0), jnp.float32(1))
 
 
-def _dense(model):
-    from paddle_tpu.serving.engine import ModelStepBackend
-    return _lower_block(ModelStepBackend(model, 2, 64, decode_block=4)), None
-
-
 def _paged_programs(**kw):
     def build(model):
         be = _paged_backend(model, **kw)
@@ -272,7 +267,6 @@ def _tp_sharded(model):
 
 
 DECODE_BUILDERS = {
-    "dense": _dense,
     "paged": _paged_programs(),
     "paged-kv_int8": _paged_programs(kv_int8=True),
     "weight-quant": _paged_programs(quant=QuantConfig(weights="int8")),
@@ -293,8 +287,7 @@ def test_decode_and_prefill_programs_are_named_on_purpose(tiny, builder):
         == ("jit_block_fn", "jit_chunk_fn")
     block, chunk = DECODE_BUILDERS[builder](tiny)
     assert _module_name(block) == engine.DECODE_PROGRAM
-    if chunk is not None:
-        assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM
 
 
 @pytest.fixture(scope="module")
@@ -644,9 +637,6 @@ def test_decode_block_span_counter_names(tiny):
             "kv_pages_in_runs": 0, "arm_ns": 0}
     assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied,
             eng.kv_pages_in_runs) == (0, 8, 8, 0)
-    dense = ContinuousBatchingEngine(tiny, num_slots=2, max_len=64,
-                                     decode_block=4)
-    assert dense._decode_block_counters() == {"sampled_steps": 0}
 
 
 def test_admit_span_counts_the_blocks_it_took_by_eviction(tiny):
