@@ -8,6 +8,9 @@ and a shared expert),
 ouro (one stack of layers run several times, a cache for every pass),
 dots3_note (latent attention at two geometries, a learned indexer that
 picks the full layers' tokens, windowed latent layers, a headwise gate),
+lfm2_moe (gated short-convolution layers whose state the paged cache
+keeps a block at a time beside QK-normed grouped-query attention layers,
+sigmoid-routed experts with a selection bias),
 resnet (vision re-export), diffusion (SDXL-style UNet)."""
 from . import llama      # noqa: F401
 from . import bert       # noqa: F401
@@ -27,5 +30,7 @@ from .ouro import OuroConfig, OuroForCausalLM         # noqa: F401
 from . import dots3_note  # noqa: F401
 from .dots3_note import (Dots3NoteConfig,             # noqa: F401
                          Dots3NoteForCausalLM)
+from . import lfm2_moe   # noqa: F401
+from .lfm2_moe import Lfm2MoeConfig, Lfm2MoeForCausalLM  # noqa: F401
 
 from ..vision.models import resnet50, resnet18, ResNet  # noqa: F401

@@ -26,6 +26,7 @@ requests is bit-identical to per-request ``generate()`` calls.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, field
@@ -239,6 +240,17 @@ def build_paged_chunk_fn(pure, chunk: int, trace_counter=None):
     return named_program(chunk_fn, PREFILL_CHUNK_PROGRAM)
 
 
+@functools.lru_cache(maxsize=1024)
+def _key_schedule(seed: int):
+    """``generate()``'s key schedule for a request's seed: ``(key, sub)``,
+    the key the slot is armed with and the prefill chunk's draw. Cached:
+    made eagerly, PRNGKey, split and the unpacking are three dispatches an
+    admission, about 1.6 ms of host time on a TPU host while the chip
+    waits (the arrays are never donated)."""
+    key, sub = jax.random.split(jax.random.PRNGKey(seed))
+    return key, sub
+
+
 def _cancel_fn(state, slot):
     """Kill one slot in-graph (deadline/poison cancellation): ``live``
     drops and ``remaining`` zeroes, so the next decode block treats the
@@ -434,6 +446,9 @@ class ContinuousBatchingEngine:
         # and the cached-attention sites a decode step holds: the page
         # counters below stay ONE site's
         self.cache_passes = getattr(backend, "cache_passes", 1)
+        # conv layers whose state the cache carries under the block ids:
+        # an admission then starts from a shared block's state or cold
+        self.conv_state_layers = getattr(backend, "conv_state_layers", 0)
         self.attn_sites = getattr(backend, "attn_sites", 0)
         self.manager = BlockManager(self.num_kv_blocks,
                                     self.kv_block_size, hash_fn)
@@ -540,11 +555,18 @@ class ContinuousBatchingEngine:
         self.kv_pages_live = 0         # pages holding a slot's live KV
         self.kv_pages_copied = 0       # pages the kernel copied for them
         self.kv_pages_in_runs = 0      # of the live, pages moved in runs
+        # rows a layer's decode read MUST see, summed over the decoding
+        # slots and steps: every live token
+        self.kv_rows_live = 0
         # each slot's table row as the walk's run copies see it
         # (``paged_attention.run_groups``), from the row it was armed with
         self._run_groups = self._table_run_groups(
             np.zeros((self.num_slots, self.max_blocks), np.int32))
         self.ut_steps = 0              # looped model: passes run by decode
+        # conv-state model: admissions whose conv state starts from a
+        # shared prefix block's entry, and those that start from zeros
+        self.conv_warm_admissions = 0
+        self.conv_cold_admissions = 0
         self._pos_host = np.zeros((self.num_slots,), np.int64)
         # what the model's programs count into the cache's last leaf
         # (``cache_counters``: name -> "sum" | "max"; a (2, n) int32 array,
@@ -825,8 +847,7 @@ class ContinuousBatchingEngine:
                                  fresh_blocks=held.span_ids["fresh_blocks"],
                                  resumed=resume is not None)
         if resume is None:
-            key = jax.random.PRNGKey(request.seed)
-            key, sub = jax.random.split(key)  # generate()'s key schedule
+            key, sub = _key_schedule(int(request.seed))
             run = _SlotRun(request, block_ids=block_ids)
             resume_tok = None
         else:
@@ -843,14 +864,19 @@ class ContinuousBatchingEngine:
         self._slots[slot] = run
         self._prefill_slots.add(slot)
         n_shared = held.shared_blocks * self.kv_block_size
+        if self.conv_state_layers:
+            if held.shared_blocks:
+                self.conv_warm_admissions += 1
+            else:
+                self.conv_cold_admissions += 1
         self.prompt_tokens += L
         self.shared_tokens += n_shared
         self._jobs.append(_PrefillJob(
             run=run, slot=slot, prompt=full, done=n_shared,
             table_row=table_row, key=key, sub=sub,
-            temp=jnp.float32(request.temperature),
-            topk=jnp.int32(request.top_k),
-            topp=jnp.float32(request.top_p), resume_tok=resume_tok))
+            temp=np.float32(request.temperature),
+            topk=np.int32(request.top_k),
+            topp=np.float32(request.top_p), resume_tok=resume_tok))
         return held.span_ids
 
     def _match_prefix_for_admission(self, full, chain: list) -> List[int]:
@@ -892,11 +918,12 @@ class ContinuousBatchingEngine:
             with _span("serving.prefill_chunk",
                        rid=job.run.request.request_id,
                        tokens=n) as self._chunk_span:
+                # host values go to the program as they are: it moves
+                # them in one call, where an eager jnp conversion is a
+                # dispatch of its own (0.1-0.5 ms each)
                 tok0_dev, self._cache = self.backend.prefill_chunk(
-                    jnp.asarray(ids), self._cache,
-                    jnp.asarray(job.table_row[None]),
-                    jnp.asarray(job.done, jnp.int32),
-                    jnp.asarray(n, jnp.int32),
+                    ids, self._cache, np.asarray(job.table_row[None]),
+                    np.int32(job.done), np.int32(n),
                     job.sub, job.temp, job.topk, job.topp)
                 self._enqueued(self._chunk_span)
             job.done += n
@@ -977,9 +1004,9 @@ class ContinuousBatchingEngine:
         of its remaining count and position (the one arm site: local
         prefill completion and the fleet's adopted hand-offs)."""
         self._state = self._arm_jit(
-            self._state, jnp.int32(slot), jnp.asarray(table_row),
-            jnp.int32(tok0), jnp.int32(pos0), jnp.int32(rem0),
-            jnp.int32(-1 if eos is None else eos), temp, topk, topp, key)
+            self._state, np.int32(slot), np.asarray(table_row, np.int32),
+            np.int32(tok0), np.int32(pos0), np.int32(rem0),
+            np.int32(-1 if eos is None else eos), temp, topk, topp, key)
         self._remaining_host[slot] = rem0
         self._pos_host[slot] = pos0
         self._run_groups[slot] = self._table_run_groups(
@@ -1123,10 +1150,12 @@ class ContinuousBatchingEngine:
         row. ``ops.pallas.paged_attention.walk_counts`` mirrors the
         kernel's loop bound. A slot that meets its EOS inside the block
         is counted as if it ran to the block's end (at most one page a
-        block over). ``kv_pages_in_runs``: of the live pages, those the
-        walk moves 8 to a copy (``paged_attention.walk_run_pages``), from
-        each decoding slot's row as it was armed; a dead slot reads
-        through a zeroed row, page by page."""
+        block over). ``kv_rows_live``: the tokens those reads must see,
+        over the decoding slots. ``kv_pages_in_runs``: of the live pages,
+        those the walk moves 8 to a copy
+        (``paged_attention.walk_run_pages``), from each decoding slot's row
+        as it was armed; a dead slot reads through a zeroed row, page by
+        page."""
         from ..ops.pallas.paged_attention import walk_counts, walk_run_pages
         sampled = self._count_sampled_steps(self.decode_block)
         lengths, decoding = self._block_read_lengths()
@@ -1135,12 +1164,14 @@ class ContinuousBatchingEngine:
         in_runs = walk_run_pages(self._run_groups,
                                  np.where(decoding, lengths, 0),
                                  self.kv_block_size)
+        rows = int((lengths * decoding).sum())
         self.kv_pages_live += live
         self.kv_pages_copied += copied
         self.kv_pages_in_runs += in_runs
+        self.kv_rows_live += rows
         ids = dict(sampled_steps=sampled, kv_pages_live=live,
                    kv_pages_copied=copied, kv_pages_in_runs=in_runs,
-                   arm_ns=self._arm_ns)
+                   kv_rows_live=rows, arm_ns=self._arm_ns)
         self._arm_ns = 0
         if self.cache_passes > 1:
             ids["ut_steps"] = self.decode_block * self.cache_passes

@@ -734,9 +734,9 @@ class DecodeWorker:
                        t_admit=meta["t_admit"], block_ids=blocks)
         eng._slots[slot] = run
         eng._arm(slot, table_row, meta["tok0"], meta["pos0"], meta["rem0"],
-                 req.eos_token_id, jnp.float32(req.temperature),
-                 jnp.int32(req.top_k), jnp.float32(req.top_p),
-                 jnp.asarray(np.asarray(h.arrays["key"], np.uint32)))
+                 req.eos_token_id, np.float32(req.temperature),
+                 np.int32(req.top_k), np.float32(req.top_p),
+                 np.asarray(h.arrays["key"], np.uint32))
         self._commit()
         return True
 

@@ -164,10 +164,9 @@ class HybridPagedEngine(ContinuousBatchingEngine):
         # full layers' ``kv_pages_live`` / ``kv_pages_copied``
         self.window_kv_pages_live = 0
         self.window_kv_pages_copied = 0
-        # rows a layer's decode reads MUST see, summed over live slots and
-        # decode steps: every live token (a full layer), those inside the
-        # window (a window layer)
-        self.kv_rows_live = 0
+        # rows a window layer's decode read MUST see, summed over live
+        # slots and decode steps: those inside the window (a full layer's
+        # are the base engine's ``kv_rows_live``)
         self.window_kv_rows_live = 0
 
     def validate_request(self, prompt_len: int, max_new_tokens: int):
@@ -292,13 +291,11 @@ class HybridPagedEngine(ContinuousBatchingEngine):
                                       self.kv_block_size, window=self.window)
         self.window_kv_pages_live += live
         self.window_kv_pages_copied += copied
-        rows = int((lengths * decoding).sum())
         window_rows = int((np.minimum(lengths, self.window) * decoding).sum())
-        self.kv_rows_live += rows
         self.window_kv_rows_live += window_rows
         return dict(super()._decode_block_counters(),
                     window_kv_pages_live=live, window_kv_pages_copied=copied,
-                    kv_rows_live=rows, window_kv_rows_live=window_rows)
+                    window_kv_rows_live=window_rows)
 
     # -- what is not carried over ------------------------------------------
     def can_resume(self, run) -> bool:

@@ -544,6 +544,19 @@ def refuse_looped_cache(holder, what: str):
             "one a pass, and this path moves or shapes only one")
 
 
+def refuse_conv_state(holder, what: str):
+    """What cannot carry a model's conv state yet (a leaf indexed by the
+    block ids beside the arenas: ``conv_state_layers``, e.g.
+    ``models.lfm2_moe``) refuses by name. ``holder`` is the model or a
+    backend built on it."""
+    layers = int(getattr(holder, "conv_state_layers", 0))
+    if layers:
+        raise NotImplementedError(
+            f"{what} over a conv state ({layers} conv layers keep theirs a "
+            "block at a time beside the arenas): this path has no rule for "
+            "it yet")
+
+
 class _StepBackendCommon:
     """What the in-process and the exported step backends share: the
     slot state with its block tables, a zeroed arena, and the byte
@@ -631,6 +644,9 @@ class PagedModelStepBackend(_StepBackendCommon):
         # a looped model runs each weight layer ``kv_cache_passes`` times a
         # step, each pass over a slice of the layer's arenas of its own
         self.cache_passes = int(getattr(model, "kv_cache_passes", 1))
+        # layers whose state the cache carries a block at a time beside
+        # the arenas (their own leaf, under the same block ids)
+        self.conv_state_layers = int(getattr(model, "conv_state_layers", 0))
         self.attn_sites = self.cache_passes * int(getattr(
             getattr(model, "config", None), "num_hidden_layers", 0))
         self._pv = [p._value for _, p in model.named_parameters()]

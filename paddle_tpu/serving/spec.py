@@ -70,7 +70,8 @@ from ..utils.flags import env_int
 from .engine import (DECODE_PROGRAM, ContinuousBatchingEngine,
                      _M_COMPILES, _M_DECODE_TOKENS, _M_STEPS, _M_TOKENS,
                      slot_sample_logits)
-from .paging import PagedModelStepBackend, refuse_looped_cache
+from .paging import (PagedModelStepBackend, refuse_conv_state,
+                     refuse_looped_cache)
 
 __all__ = ["SpecConfig", "resolve_spec_config", "ngram_propose",
            "build_spec_block_fn", "SpecPagedStepBackend",
@@ -295,6 +296,9 @@ class SpecPagedStepBackend(PagedModelStepBackend):
                  quant=None):
         from ..models.generation import build_decode_step
         refuse_looped_cache(model, "speculative decoding")
+        # a rejected draft would leave a block's conv entry past the
+        # accepted token
+        refuse_conv_state(model, "speculative decoding")
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
                          quant=quant)

@@ -68,7 +68,8 @@ from ..utils.tp_hooks import (current_tp, maybe_gather,  # noqa: F401
                               maybe_gather_logits, maybe_reduce)
 from ..utils.flags import env_bool, env_str
 from .engine import build_paged_chunk_fn, build_slot_block_fn
-from .paging import PagedModelStepBackend, refuse_looped_cache
+from .paging import (PagedModelStepBackend, refuse_conv_state,
+                     refuse_looped_cache)
 
 __all__ = ["TPConfig", "resolve_tp_config", "ShardedPagedStepBackend"]
 
@@ -192,6 +193,8 @@ class ShardedPagedStepBackend(PagedModelStepBackend):
                  kv_int8: bool, prefill_chunk: int, tp: TPConfig,
                  quant=None):
         refuse_looped_cache(model, "tensor-parallel serving")
+        # the sharding rules split kv heads, and have none for conv channels
+        refuse_conv_state(model, "tensor-parallel serving")
         super().__init__(model, num_slots, max_len, decode_block,
                          block_size, num_blocks, kv_int8, prefill_chunk,
                          quant=quant)

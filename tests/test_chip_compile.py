@@ -783,3 +783,82 @@ def test_gated_hybrid_programs_fit_the_chip_and_copy_no_arena(
                             ("swa_paged_attention_decode", 3)):
             assert len(set(re.findall(rf"%((?<![a-z_]){name}\.\d+) = ",
                                       text))) == sites, name
+
+
+# -- a conv state beside packed arenas at head_dim 64 (lfm2-tool-agent-decode)
+
+def test_conv_moe_packed_read_compiles_for_v5e(one_chip, on_tpu_gates):
+    """The packed read at the shape lfm2-tool-agent-decode serves: 128
+    slots, a 384-entry table, 32 query heads over 8 kv heads of 64, ``[v
+    64 | k 64]`` rows (one whole lane tile), 16,385 blocks; V is the first
+    half of the tile."""
+    arena = ((16385, KV_BLOCK * 8, 128), BF16)
+    pages = [(arena[0][1:], BF16)]
+    assert pa._tiles(*arena)
+    assert pa._walk_vmem_bytes(pa._pages_per_chunk(384, pages), pages) \
+        <= pa._VMEM_BUDGET
+    specs = [((128, 32, 128), BF16), arena, ((128, 384), jnp.int32),
+             ((128,), jnp.int32)]
+    text = _compile(functools.partial(
+        pa.packed_paged_attention_decode, scale=64 ** -0.5, kvh=8, dv=64),
+        one_chip, *specs)
+    # the page is viewed, not copied (the (128, 32, 64) output's re-layout
+    # is a copy of 512 KiB, which the generic check would flag)
+    assert not re.search(r"bf16\[16385,128,128\]\S* copy\(", text)
+    assert "%paged_attention_decode" in text and "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("program", ["block", "chunk"])
+def test_conv_moe_programs_fit_the_chip_and_copy_no_arena(
+        program, one_chip, on_tpu_gates):
+    """lfm2-tool-agent-decode's two programs at the served size (9 layers,
+    all 64 experts, the whole vocabulary, 128 slots of 6,144, the pool of
+    16,385 blocks with its conv state), lowered on abstract weights:
+    weights + pool = 11.77 GiB, and the program's temporaries keep the
+    whole under 13 GiB; the decode block reads its 2 attention layers
+    through the packed walk and its 8 expert layers through the hit-experts
+    kernel, and copies neither an arena nor the conv state."""
+    import json
+    from benchmark import weights_by_class
+    from paddle_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
+    from paddle_tpu.serving.paging import PagedModelStepBackend
+    from paddle_tpu.utils.scale import abstract_init
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "LFM2-24B-A2B.json")) as f:
+        c = json.load(f)
+    with abstract_init("bfloat16"):
+        model = Lfm2MoeForCausalLM(weights_by_class.model_config(c))
+    dep = c["deployment"]
+    be = PagedModelStepBackend(model, dep["num_slots"], dep["max_len"], 8,
+                               KV_BLOCK, dep["num_blocks"], False, 512)
+
+    def spec(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def scalar(dtype, *shape):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    cache = tuple(scalar(dtype, *shape) for shape, dtype in be.pool_specs)
+    pv, bv = [spec(v) for v in be._pv], [spec(v) for v in be._bv]
+    if program == "block":
+        lowered = be._block_jit.lower(pv, bv, cache,
+                                      jax.tree.map(spec, be.init_state()))
+    else:
+        lowered = be._chunk_jit.lower(
+            pv, bv, scalar(jnp.int32, 1, 512), cache,
+            scalar(jnp.int32, 1, be.table_width), scalar(jnp.int32),
+            scalar(jnp.int32), scalar(jnp.uint32, 2), scalar(jnp.float32),
+            scalar(jnp.int32), scalar(jnp.float32))
+    compiled = lowered.compile()
+    text = compiled.as_text()
+    ma = compiled.memory_analysis()
+    assert 11.7 * 2 ** 30 < ma.argument_size_in_bytes < 11.8 * 2 ** 30
+    assert hbm_bytes(compiled) < 13 * 2 ** 30, hbm_bytes(compiled) / 2 ** 30
+    assert ma.temp_size_in_bytes < 2 ** 30
+    assert not re.search(r"bf16\[16385,(128,128|7,2,2048)\]\S* copy\(", text)
+    if program == "block":
+        for name, sites in (("paged_attention_decode", 2),
+                            ("moe_hit_experts_decode", 8)):
+            assert len(set(re.findall(rf"%((?<![a-z_]){name}\.\d+) = ",
+                                      text))) == sites, name

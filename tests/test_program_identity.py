@@ -6,7 +6,8 @@ they were, as PR 33 did (a looped model's two programs are pinned beside
 them), and PR 37 (``DeepseekV3Attention`` takes its sizes as arguments:
 kanana's two programs are byte for byte what they were; the new model's two
 are pinned beside them; PR 38 changed those two: the selection kernel; PR 39 pins MiMo's two, which its generalized attention
-class left as they were, and Laguna's). The three models whose cells hold
+class left as they were, and Laguna's; LFM2's two beside them). The three
+models whose cells hold
 a share of their experts are fingerprinted holding a share; kanana's two
 programs run the expert kernel. A change that means to alter one of them updates its pin here
 and says so in CHANGES.md; one that does not has changed what
@@ -50,6 +51,12 @@ PINNED = {
     "mimo_v2.chunk": "bcd4088c52475b3c2faefc7d0be78b9f98d8248c",
     "laguna.block": "90924f5b8b30359e2e337d6055f37a88d1d357fa",
     "laguna.chunk": "8c8ec8c793d0ea5b798dfdd5ac9da1c340e49c47",
+    # LFM2's two (a conv state under the block ids beside packed
+    # arenas, all experts held); the fourteen above are as they were with
+    # the conv leaf, the short-conv mixer and the engine's counters beside
+    # them
+    "lfm2_moe.block": "b145cc3fb9e276d2ce858b4a7c6ee6d380ec59d4",
+    "lfm2_moe.chunk": "9f70bbacd5046938c7aa61f4bbd804e9d12e48a2",
 }
 
 

@@ -763,8 +763,8 @@ class TestKvWalkCounters:
             engine, cfg, ((5, 7), (19, 12), (9, 5)))
         assert spans and want[0] > 0
         assert set(spans[0].ids) == {"kv_pages_live", "kv_pages_copied",
-                                     "kv_pages_in_runs", "sampled_steps",
-                                     "arm_ns", "starved_ns"}
+                                     "kv_pages_in_runs", "kv_rows_live",
+                                     "sampled_steps", "arm_ns", "starved_ns"}
         assert [engine.kv_pages_live, engine.kv_pages_copied,
                 engine.kv_pages_in_runs] == want
         assert [sum(sp.ids[k] for sp in spans)

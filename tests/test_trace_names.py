@@ -389,6 +389,41 @@ def test_gated_hybrid_model_keeps_the_program_names_and_names_both_reads(
     assert "attn_gate/" in chunk.as_text(debug_info=True)
 
 
+def test_conv_moe_model_keeps_the_program_names_and_names_its_mixers(
+        interpret):
+    """LFM2's layers through the plain paged backend: the SAME two
+    programs; its attention layer reads through ``paged_attention_decode``
+    (the packed arena), a decode step's experts through
+    ``moe_hit_experts_decode``; the conv mixers and the conv state's read
+    and write sit under ``short_conv``, beside ``attn``, ``mlp`` (the dense
+    layer), ``moe_router`` and ``moe_experts``."""
+    from paddle_tpu.models.lfm2_moe import (Lfm2MoeForCausalLM,
+                                            lfm2_moe_tiny_config)
+    from paddle_tpu.serving import engine
+    from paddle_tpu.serving.paging import PagedModelStepBackend
+    paddle.seed(0)
+    be = PagedModelStepBackend(Lfm2MoeForCausalLM(lfm2_moe_tiny_config()),
+                               2, 64, decode_block=4, block_size=8,
+                               num_blocks=17, kv_int8=False, prefill_chunk=8)
+    block, chunk = _lower_block(be), be._chunk_jit.lower(
+        be._pv, be._bv, i32(1, 8), be.pool_cache(), i32(1, be.table_width),
+        jnp.int32(0), jnp.int32(8), jax.random.PRNGKey(0),
+        jnp.float32(0), jnp.int32(0), jnp.float32(1))
+    assert _module_name(block) == engine.DECODE_PROGRAM == "jit_block_fn"
+    assert _module_name(chunk) == engine.PREFILL_CHUNK_PROGRAM == "jit_chunk_fn"
+    cache = tuple(jnp.zeros(s, d) for s, d in be.pool_specs)
+    names = pallas_names(jax.make_jaxpr(be._block_jit)(
+        be._pv, be._bv, cache, be.init_state()))
+    assert names.count("paged_attention_decode") == 1
+    assert names.count("moe_hit_experts_decode") == 4
+    for text in (block.as_text(debug_info=True),
+                 chunk.as_text(debug_info=True)):
+        for scope in ("short_conv", "attn", "mlp", "moe_router",
+                      "moe_experts", "lm_head", "sample"):
+            assert f"{scope}/" in text, scope
+        assert "moe_shared/" not in text        # this family has none
+
+
 def test_sparse_latent_model_keeps_the_program_names_and_names_its_reads(
         interpret):
     """A model with learned sparse attention over a latent cache through
@@ -574,7 +609,8 @@ def test_looped_engine_span_and_counter_names():
     # at ONE site, and four passes a step
     assert eng._decode_block_counters() == {
         "sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-        "kv_pages_in_runs": 0, "arm_ns": 0, "ut_steps": 16}
+        "kv_pages_in_runs": 0, "kv_rows_live": 0, "arm_ns": 0,
+        "ut_steps": 16}
     assert (eng.ut_steps, eng.ut_exit_step_milli,
             eng.prefill_ut_exit_step_milli) == (16, 0, 0)
     srv = Server(eng, Scheduler())
@@ -634,7 +670,7 @@ def test_decode_block_span_counter_names(tiny):
                                    block_size=8, prefill_chunk=8)
     assert eng._decode_block_counters() \
         == {"sampled_steps": 0, "kv_pages_live": 8, "kv_pages_copied": 8,
-            "kv_pages_in_runs": 0, "arm_ns": 0}
+            "kv_pages_in_runs": 0, "kv_rows_live": 0, "arm_ns": 0}
     assert (eng.sampled_steps, eng.kv_pages_live, eng.kv_pages_copied,
             eng.kv_pages_in_runs) == (0, 8, 8, 0)
 

@@ -1,10 +1,11 @@
 #!/usr/bin/env python3
-"""On-chip readings for the limits of ``correct`` in a cell whose model
-keeps two cache groups (full and sliding-window layers) and whose reference
+"""On-chip readings for the limits of ``correct`` in a cell whose reference
 has ``model_outputs``, ``cached_outputs``, ``compare``, ``forward`` and
-``MUTATIONS`` (``reference/mimo_v2.py``, ``reference/laguna.py``), at the
-published widths. The cell's workload file names the configuration and the
-reference. Needs a TPU, unless ``--config`` gives a toy configuration.
+``MUTATIONS`` (``reference/mimo_v2.py``, ``reference/laguna.py``: a model
+that keeps two cache groups; ``reference/lfm2_moe.py``: a conv state under
+the block ids, with its own ``greedy``), at the published widths. The
+cell's workload file names the configuration and the reference. Needs a
+TPU, unless ``--config`` gives a toy configuration.
 
     python3 tools/limits_probe.py --workload <cell> [--paths ...] [--seeds N ...]
 
@@ -17,14 +18,17 @@ prefilled in the deployment's chunks and decoded through both groups'
 caches (``cached_outputs``, what check (d) reads) against the same
 references (a broken term held to the model's picks alone); and the SYSTEM
 broken: a window ring two blocks short, so that
-a chunk's last block lands on the start of its first token's window.
+a chunk's last block lands on the start of its first token's window (a
+two-group cache), or the conv state zeroed at the prefix cut that the
+configuration's ``check_cut`` puts in the context (a conv state).
 ``timed``: the timed path's statistic (``serve_latent_moe``'s
 ``emitted_vs_reference``: each emitted token's reference logit below the
 reference's largest, in standard deviations) on ``--emit`` tokens the
 model emits greedily through both groups' caches after a seeded prompt of
 ``check_context`` tokens, against the reference as it is, in bf16 and in
-8-bit floats; and on random tokens after the same prompt. One JSON line a
-seed and path.
+8-bit floats (and, for a conv state, broken term by term, and on the
+tokens emitted with the conv state zeroed at the cut); and on random tokens
+after the same prompt. One JSON line a seed and path.
 """
 import argparse
 import json
@@ -39,6 +43,7 @@ import jax.numpy as jnp                                       # noqa: E402
 import numpy as np                                            # noqa: E402
 
 from benchmark import common, weights_by_class               # noqa: E402
+from benchmark import weights_quantiles                       # noqa: E402
 from paddle_tpu.utils.compile_cache import (                  # noqa: E402
     configure_compile_cache)
 
@@ -77,15 +82,28 @@ def nocache(ref, model, params, c, seed, args):
                     lambda r, *a, **kw: r.compare(*a, **kw))
 
 
+def cut_of(c) -> dict:
+    """The prefix hit a conv-state cell's check puts in its context."""
+    cut = c["deployment"].get("check_cut")
+    return {"cut": int(cut)} if cut else {}
+
+
 def cached(ref, model, params, c, seed, args):
     n = int(c["deployment"]["check_context"])
     chunk = int(c["overrides"]["prefill_chunk"]["value"])
     ids = np.random.default_rng([seed & 0xFFFFFFFF, 29]).integers(
         0, c["vocab_size"], n, np.int32)
-    rows, got, picks = ref.cached_outputs(model, ids, chunk=chunk, decode=8)
+    rows, got, picks = ref.cached_outputs(model, ids, chunk=chunk, decode=8,
+                                          **cut_of(c))
     kw = dict(logits_at=rows, block=256)        # as check (d) reads it
     out = {"rows": len(rows)}
     out.update(variants(ref, got, picks, params, c, ids, held_to, **kw))
+    if cut_of(c):
+        rows, got, picks = ref.cached_outputs(
+            model, ids, chunk=chunk, decode=8, zero_at_cut=True, **cut_of(c))
+        out["state_zeroed_at_cut"] = ref.compare(got, picks, params, c, ids,
+                                                 **kw)
+        return out
     # cached_outputs reads ring_tables from its own module's namespace
     names = ref.cached_outputs.__globals__
     real = names["ring_tables"]
@@ -149,7 +167,11 @@ def timed(ref, model, params, c, seed, args):
     rng = np.random.default_rng([seed & 0xFFFFFFFF, 31])
     prompt = rng.integers(0, c["vocab_size"], n, np.int32)
     t = time.time()
-    emitted = greedy(ref, model, prompt, chunk=chunk, steps=args.emit)
+    if hasattr(ref, "greedy"):
+        emitted = ref.greedy(model, prompt, chunk=chunk, steps=args.emit,
+                             **cut_of(c))
+    else:
+        emitted = greedy(ref, model, prompt, chunk=chunk, steps=args.emit)
     latent = common.load_module("kinds", "serve_latent_moe.py")
     out = {"prompt": n, "emitted": len(emitted),
            "greedy_s": time.time() - t}
@@ -158,6 +180,16 @@ def timed(ref, model, params, c, seed, args):
             ref.forward, matmul_dtype=dt))
         out[name] = latent.emitted_vs_reference(r, params, c, prompt,
                                                 emitted, print)
+    if hasattr(ref, "greedy") and cut_of(c):
+        for m in ref.MUTATIONS:
+            r = types.SimpleNamespace(forward=functools.partial(
+                ref.forward, mutate=(m,)))
+            out[m] = latent.emitted_vs_reference(r, params, c, prompt,
+                                                 emitted, print)
+        zeroed = ref.greedy(model, prompt, chunk=chunk, steps=args.emit,
+                            zero_at_cut=True, **cut_of(c))
+        out["state_zeroed_at_cut"] = latent.emitted_vs_reference(
+            ref, params, c, prompt, zeroed, print)
     out["random_stream"] = latent.emitted_vs_reference(
         ref, params, c, prompt,
         rng.integers(0, c["vocab_size"], args.emit, np.int32), print)
@@ -194,8 +226,11 @@ def main():
                if k.endswith("experts_published")}
     for seed in args.seeds:
         t = time.time()
+        held = {"experts_held": tuple(c["experts_held"])} \
+            if "experts_held" in c else {}
         model = weights_by_class.build_lazy(weights_by_class.model_config(
-            c, experts_held=tuple(c["experts_held"]), **routers), seed)
+            c, **held, **routers), seed)
+        weights_quantiles.apply(model, c, seed)     # as the cell's kind
         params = {k: p._value for k, p in model.named_parameters()}
         for path in args.paths:
             out = PATHS[path](ref, model, params, c, seed, args)
