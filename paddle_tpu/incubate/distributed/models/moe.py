@@ -337,7 +337,8 @@ def route_topk(logits, bias, *, top_k, scoring="sigmoid", n_group=1,
 # 128-row matrix unit: it costs its weights' bytes whether 1 row is the
 # expert's or all of them. Such a step runs every row through each expert it
 # reads: every expert held, where a share is held; the experts hit, where all
-# are (``ops/pallas/moe_experts.py``).
+# are (``ops/pallas/moe_experts.py``). More rows (a prefill chunk) run each
+# expert hit on the rows that picked it alone.
 FEW_ROWS = 128
 
 
@@ -372,7 +373,8 @@ def _every_row_on_every_expert_held(x, idx, weights, w_gate, w_up, w_down,
 
 def _grouped_expert_mix(x, idx, weights, w_gate, w_up, w_down, first,
                         partial):
-    """``dropless_expert_mix`` for any number of tokens: the ``T * k`` picks
+    """``dropless_expert_mix`` for any number of tokens off the TPU (and
+    the kernels' reference in the tests): the ``T * k`` picks
     are sorted by expert, each expert's rows form one group of three
     grouped matmuls (``jax.lax.ragged_dot``: rows past the last group come
     out zero), and the rows return to token order by the inverse
@@ -415,14 +417,18 @@ def dropless_expert_mix(x, idx, weights, w_gate, w_up, w_down, first=0,
     largest count on one expert]``. The form follows from what the call
     observes:
 
-    - more than ``FEW_ROWS`` tokens (a prefill chunk), or all the experts
-      off the TPU: ``_grouped_expert_mix``;
+    - more than ``FEW_ROWS`` tokens (a prefill chunk), on a TPU: the kernel
+      ``moe_grouped_experts_prefill`` (``ops/pallas/moe_experts.py``),
+      which reads each expert hit once and runs only the rows that picked
+      it, whole holds and shares alike;
     - at most ``FEW_ROWS`` tokens (a decode step) on a share:
       ``_every_row_on_every_expert_held`` (in an expert-parallel deployment
       every rank's rows reach its experts, so all of them are hit);
     - at most ``FEW_ROWS`` tokens on all the experts, on a TPU: the kernel
-      ``moe_hit_experts_decode`` (``ops/pallas/moe_experts.py``), which
-      streams only the experts the step's own rows picked."""
+      ``moe_hit_experts_decode``, which streams only the experts the
+      step's own rows picked;
+    - off the TPU, any rows but a share's decode step:
+      ``_grouped_expert_mix``."""
     if idx.shape[0] <= FEW_ROWS:
         if partial:
             return _every_row_on_every_expert_held(x, idx, weights, w_gate,
@@ -430,6 +436,9 @@ def dropless_expert_mix(x, idx, weights, w_gate, w_up, w_down, first=0,
         if moe_experts.kernel_ok():
             return moe_experts.moe_hit_experts_decode(
                 x, idx - first, weights, w_gate, w_up, w_down)
+    elif moe_experts.kernel_ok():
+        return moe_experts.moe_grouped_experts_prefill(
+            x, idx - first, weights, w_gate, w_up, w_down)
     return _grouped_expert_mix(x, idx, weights, w_gate, w_up, w_down, first,
                                partial)
 

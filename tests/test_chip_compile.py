@@ -192,6 +192,32 @@ def _case_moe_hit_experts(one_chip, rows=64, tile=768):
     return text
 
 
+# a prefill chunk's expert layer at each MoE cell's shape: (experts held,
+# top-k, hidden, ff) over 512 rows
+PREFILL_EXPERTS = {
+    "lfm2": (64, 4, 2048, 1536),
+    "kanana": (128, 6, 2048, 768),
+    "laguna": (128, 10, 3072, 1024),
+    "mimo": (16, 8, 4096, 2048),
+    "dots3": (16, 8, 5120, 1536),
+}
+
+
+def _case_moe_grouped_experts(one_chip, shape):
+    """The routed experts of a 512-row prefill chunk through
+    ``moe_grouped_experts_prefill`` at a served shape: the weight blocks,
+    the row and output tiles and the scratch a group's rows wait in over
+    the ff tiles fit the chip's VMEM."""
+    from paddle_tpu.ops.pallas import moe_experts as me
+    e, k, h, ff = PREFILL_EXPERTS[shape]
+    text = _compile(lambda *a: me.moe_grouped_experts_prefill(*a), one_chip,
+                    ((512, h), BF16), ((512, k), jnp.int32),
+                    ((512, k), jnp.float32), ((e, h, ff), BF16),
+                    ((e, h, ff), BF16), ((e, ff, h), BF16))
+    assert "%moe_grouped_experts_prefill" in text
+    return text
+
+
 def _case_packed_decode(one_chip, window=None):
     """The packed reads at the shapes the benchmark's mimo-v2-agent-decode
     cell serves: 64 slots, 64 query heads, a 512-entry table, K 192 and V
@@ -295,6 +321,8 @@ CASES = {
                                                  rows=1),
     "moe_hit_experts_half_expert_tiles":
         functools.partial(_case_moe_hit_experts, tile=384),
+    **{f"moe_grouped_experts_prefill_{shape}": functools.partial(
+        _case_moe_grouped_experts, shape=shape) for shape in PREFILL_EXPERTS},
     "packed_decode_served_shape": _case_packed_decode,
     "swa_packed_decode_served_shape":
         functools.partial(_case_packed_decode, window=128),
@@ -817,7 +845,8 @@ def test_conv_moe_programs_fit_the_chip_and_copy_no_arena(
     weights + pool = 11.77 GiB, and the program's temporaries keep the
     whole under 13 GiB; the decode block reads its 2 attention layers
     through the packed walk and its 8 expert layers through the hit-experts
-    kernel, and copies neither an arena nor the conv state."""
+    kernel, the 512-token chunk its 8 expert layers through the prefill
+    kernel, and neither copies an arena or the conv state."""
     import json
     from benchmark import weights_by_class
     from paddle_tpu.models.lfm2_moe import Lfm2MoeForCausalLM
@@ -857,8 +886,9 @@ def test_conv_moe_programs_fit_the_chip_and_copy_no_arena(
     assert hbm_bytes(compiled) < 13 * 2 ** 30, hbm_bytes(compiled) / 2 ** 30
     assert ma.temp_size_in_bytes < 2 ** 30
     assert not re.search(r"bf16\[16385,(128,128|7,2,2048)\]\S* copy\(", text)
-    if program == "block":
-        for name, sites in (("paged_attention_decode", 2),
-                            ("moe_hit_experts_decode", 8)):
-            assert len(set(re.findall(rf"%((?<![a-z_]){name}\.\d+) = ",
-                                      text))) == sites, name
+    kernels = ((("paged_attention_decode", 2), ("moe_hit_experts_decode", 8))
+               if program == "block" else
+               (("moe_grouped_experts_prefill", 8),))
+    for name, sites in kernels:
+        assert len(set(re.findall(rf"%((?<![a-z_]){name}\.\d+) = ",
+                                  text))) == sites, name

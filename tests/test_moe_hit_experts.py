@@ -135,24 +135,27 @@ def test_bf16_is_no_further_from_float32_than_the_grouped_form(interpret):
 @pytest.mark.parametrize("partial,rows,forced,form", [
     (True, moe.FEW_ROWS, True, "every_row_on_every_expert_held"),
     (False, moe.FEW_ROWS, True, "kernel"),
-    (False, moe.FEW_ROWS + 1, True, "grouped"),
-    (True, moe.FEW_ROWS + 1, True, "grouped"),
+    (False, moe.FEW_ROWS + 1, True, "prefill_kernel"),
+    (True, moe.FEW_ROWS + 1, True, "prefill_kernel"),
     (False, moe.FEW_ROWS, False, "grouped"),
+    (False, moe.FEW_ROWS + 1, False, "grouped"),
+    (True, moe.FEW_ROWS + 1, False, "grouped"),
 ], ids=["share_decode", "whole_decode", "whole_chunk", "share_chunk",
-        "whole_decode_cpu"])
+        "whole_decode_cpu", "whole_chunk_cpu", "share_chunk_cpu"])
 def test_the_form_follows_the_share_the_rows_and_the_backend(
         monkeypatch, partial, rows, forced, form):
     """A share at a decode step's rows: every row on every expert held;
     all the experts at those rows, on a TPU (here: forced interpret): the
-    kernel; more rows (a prefill chunk), or the CPU lane: the grouped
-    form."""
+    kernel; more rows (a prefill chunk) on a TPU: the prefill kernel,
+    whole hold or share; the CPU lane: the grouped form."""
     monkeypatch.setattr(fused, "_FORCE_INTERPRET", forced)
     taken = []
     for name, attr, owner in (
             ("every_row_on_every_expert_held",
              "_every_row_on_every_expert_held", moe),
             ("grouped", "_grouped_expert_mix", moe),
-            ("kernel", "moe_hit_experts_decode", me)):
+            ("kernel", "moe_hit_experts_decode", me),
+            ("prefill_kernel", "moe_grouped_experts_prefill", me)):
         real = getattr(owner, attr)
         monkeypatch.setattr(owner, attr, lambda *a, _n=name, _r=real: (
             taken.append(_n), _r(*a))[1])

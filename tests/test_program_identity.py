@@ -9,7 +9,10 @@ are pinned beside them; PR 38 changed those two: the selection kernel; PR 39 pin
 class left as they were, and Laguna's; LFM2's two beside them). The three
 models whose cells hold
 a share of their experts are fingerprinted holding a share; kanana's two
-programs run the expert kernel. A change that means to alter one of them updates its pin here
+programs run the expert kernel. The five MoE models' decode blocks are also
+pinned at ``moe.FEW_ROWS`` slots, the most rows a decode form of the
+routed experts takes, as they were before a prefill chunk had a kernel of
+its own. A change that means to alter one of them updates its pin here
 and says so in CHANGES.md; one that does not has changed what
 ``mistral7b-decode-sat``, ``kanana2-docqa-decode``, ``ouro-reason-decode``,
 ``mimo-v2-agent-decode`` or ``dots3-longdoc-decode`` runs."""
@@ -57,6 +60,15 @@ PINNED = {
     # them
     "lfm2_moe.block": "b145cc3fb9e276d2ce858b4a7c6ee6d380ec59d4",
     "lfm2_moe.chunk": "9f70bbacd5046938c7aa61f4bbd804e9d12e48a2",
+    # each MoE model's decode block at moe.FEW_ROWS slots, the most rows a
+    # decode form of its experts takes, as the parent of the chunk's
+    # expert kernel (moe_grouped_experts_prefill, past FEW_ROWS rows) made
+    # them: a chunk's kernel leaves every decode block as it was
+    "deepseek_v3.block_few_rows": "2ddb05ba274052c9d9acf27fa3eea65756e52bb3",
+    "dots3_note.block_few_rows": "aab01ac6ffd4d65c65a548b42af6d7908386c8ed",
+    "mimo_v2.block_few_rows": "bd2abd533846e0f34be3976d756872b31579da5a",
+    "laguna.block_few_rows": "5c3579ce07e48db233dbb9610f30e085bac81482",
+    "lfm2_moe.block_few_rows": "9d9de179c2eac8e4e75cfbea0e78e0a100de937e",
 }
 
 

@@ -113,6 +113,13 @@ def _moe_hit_experts():
         f32(3, 8, 16))
 
 
+def _moe_grouped_experts():
+    from paddle_tpu.ops.pallas.moe_experts import moe_grouped_experts_prefill
+    return jax.make_jaxpr(moe_grouped_experts_prefill)(
+        f32(130, 16), i32(130, 2), f32(130, 2), f32(3, 16, 8),
+        f32(3, 16, 8), f32(3, 8, 16))
+
+
 def _paged_int8():
     codes = jnp.zeros((5, 8, 2, 32), jnp.int8)
     return jax.make_jaxpr(lambda *a: pa.paged_attention_decode_int8(
@@ -147,6 +154,10 @@ KERNELS = {
     # the routed experts of a decode step on all the experts held, under
     # the scope moe_experts
     "moe_hit_experts_decode": (_moe_hit_experts, ["moe_hit_experts_decode"]),
+    # the routed experts of a prefill chunk, whole holds and shares alike,
+    # under the same scope
+    "moe_grouped_experts_prefill":
+        (_moe_grouped_experts, ["moe_grouped_experts_prefill"]),
     "fused_rms_norm": (lambda: jax.make_jaxpr(
         lambda x, w: fused.fused_rms_norm(x, w, 1e-5))(
             f32(4, 128), f32(128)), ["fused_rms_norm"]),

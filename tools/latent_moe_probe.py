@@ -4,7 +4,8 @@ model, each alone, at the shape the ``kanana2-docqa-decode`` cell serves.
 Needs a TPU.
 
     python3 tools/latent_moe_probe.py [--chunk-rows N ...]
-        [--orders shuffled falling rising] [--experts-only | --latent-only]
+        [--orders shuffled falling rising]
+        [--experts-only | --latent-only | --chunks-only]
 
 ``latent``: the s = 1 read ``mla_paged_attention_decode`` (64 slots, 32
 heads against one shared 640-wide row, KV block 16, a 320-entry table,
@@ -20,9 +21,15 @@ a decode step's 64 tokens through all 128 experts (2048 x 768 SwiGLU,
 top-6) in three forms: the kernel ``moe_hit_experts_decode``, XLA's grouped
 matmuls and every row on every expert, ms a layer and the share of the hit
 experts' bytes, on the cell's router and on pools of 64, 100 and 128
-experts; the grouped form at a chunk's 512 rows. ``--experts-only`` skips
-the latent read, ``--latent-only`` the experts. The last line is one JSON
-object.
+experts. ``chunks``: a prefill chunk's 512 rows through the expert layer of
+each MoE configuration the benchmark serves (its experts held, top-k,
+hidden and ff widths), in two forms: the kernel
+``moe_grouped_experts_prefill`` and XLA's grouped matmuls
+(``ragged-dot``), ms a layer, the share of the hit experts' bytes and the
+largest difference between the two; and the LFM2 shape with 412 pad rows
+that all pick the same 4 experts. ``--experts-only`` skips the latent
+read, ``--latent-only`` the experts and the chunks, ``--chunks-only``
+runs the chunks alone. The last line is one JSON object.
 """
 import argparse
 import functools
@@ -45,6 +52,15 @@ REPS = 32
 HBM = 819e9
 # the cell's expert layer: 128 experts of 2048 x 768, top-6; a chunk's rows
 EXPERTS, HIDDEN, FF, TOP_K, CHUNK = 128, 2048, 768, 6, 512
+# each MoE configuration's expert layer as its cell holds it: (experts
+# held, the router's experts, top-k, hidden, ff)
+CHUNK_SHAPES = {
+    "LFM2-24B-A2B": (64, 64, 4, 2048, 1536),
+    "kanana-2-30b-a3b-instruct-2601": (128, 128, 6, 2048, 768),
+    "Laguna-S-2.1": (128, 256, 10, 3072, 1024),
+    "MiMo-V2-Flash": (16, 256, 8, 4096, 2048),
+    "dots3-note-prev": (16, 256, 8, 5120, 1536),
+}
 
 
 def median_ms(j, *args, runs=10, reps=1):
@@ -64,6 +80,17 @@ def chained(fn, rank):
         def body(_, q):
             return q.at[..., :rank].set(fn(q, *r).astype(q.dtype))
         return jax.lax.fori_loop(0, REPS, body, q0)
+    return jax.jit(prog)
+
+
+def chained_mix(fn):
+    """8 calls of an expert mix in one program: each call's output is added
+    into the next call's rows, so no call can be dropped."""
+    def prog(x0, idx, w, *ws):
+        def body(_, xx):
+            y, _ = fn(xx, idx, w, *ws)
+            return (xx + 1e-3 * y).astype(xx.dtype)
+        return jax.lax.fori_loop(0, 8, body, x0)
     return jax.jit(prog)
 
 
@@ -161,14 +188,6 @@ def experts(a, out):
         routings[f"pool_{pool}"] = (jnp.asarray(np.stack(
             [rs.permutation(held)[:k] for _ in range(t)]), jnp.int32), w)
 
-    def chained(fn):
-        def prog(x0, idx, w, *ws):
-            def body(_, xx):
-                y, _ = fn(xx, idx, w, *ws)
-                return (xx + 1e-3 * y).astype(xx.dtype)
-            return jax.lax.fori_loop(0, 8, body, x0)
-        return jax.jit(prog)
-
     forms = {
         "kernel": me.moe_hit_experts_decode,
         "grouped": lambda *v: moe._grouped_expert_mix(*v, 0, False),
@@ -182,7 +201,7 @@ def experts(a, out):
         row["kernel_vs_grouped_max_abs"] = float(np.abs(got - want).max())
         row["grouped_max_abs"] = float(np.abs(want).max())
         for form, fn in forms.items():
-            ms = median_ms(chained(fn), x, idx, w, *ws, reps=8)
+            ms = median_ms(chained_mix(fn), x, idx, w, *ws, reps=8)
             row[f"{form}_ms"] = ms
             row[f"{form}_hit_bytes_roofline_pct"] = \
                 100 * hit * expert_bytes / HBM / (ms / 1e3)
@@ -192,22 +211,58 @@ def experts(a, out):
         keep, me._WEIGHT_VMEM = me._WEIGHT_VMEM, 6 * h * (ff // 2) * 2
         row["half_expert_tile"] = me._ff_tile(h, ff, 2)
         row["kernel_ms.half_expert_tiles"] = median_ms(
-            chained(me.moe_hit_experts_decode), x, idx, w, *ws, reps=8)
+            chained_mix(me.moe_hit_experts_decode), x, idx, w, *ws, reps=8)
         me._WEIGHT_VMEM = keep
         out["experts." + name] = row
         print("experts", name, json.dumps(row), flush=True)
+
+
+def chunks(a, out):
+    """A prefill chunk's expert layer at each configuration's shape, two
+    forms on the same routing (random distinct picks over the router's
+    experts; a share holds the first of them): the kernel
+    ``moe_grouped_experts_prefill`` (what a chunk takes on a TPU) and XLA's
+    grouped matmuls (the form before it, ``_grouped_expert_mix``)."""
     t = CHUNK
-    x = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.bfloat16)
-    idx = jnp.asarray(np.stack([np.random.RandomState(5 + i).permutation(e)[:k]
-                                for i in range(t)]), jnp.int32)
-    w = jnp.full((t, k), 0.4, jnp.float32)
-    hit = len(np.unique(np.asarray(idx)))
-    ms = median_ms(chained(forms["grouped"]), x, idx, w, *ws, reps=8)
-    row = {"tokens": t, "experts_hit": hit, "grouped_ms": ms,
-           "grouped_hit_bytes_roofline_pct":
-           100 * hit * expert_bytes / HBM / (ms / 1e3)}
-    out[f"experts.rows_{t}"] = row
-    print(f"experts rows_{t}", json.dumps(row), flush=True)
+    cases = [(name, shape, None) for name, shape in CHUNK_SHAPES.items()]
+    # pad columns: all the rows past a short prompt's pick the same experts
+    cases.append(("LFM2-24B-A2B.pad_412", CHUNK_SHAPES["LFM2-24B-A2B"], 100))
+    for name, (e, routed, k, h, ff), real in cases:
+        ws = [jax.random.normal(jax.random.PRNGKey(10 + i), s, jnp.bfloat16)
+              * 0.03 for i, s in enumerate(((e, h, ff), (e, h, ff),
+                                             (e, ff, h)))]
+        x = jax.random.normal(jax.random.PRNGKey(3), (t, h), jnp.bfloat16)
+        rs = np.random.RandomState(5)
+        idx = np.stack([rs.permutation(routed)[:k] for _ in range(t)])
+        if real is not None:
+            idx[real:] = np.arange(k)
+            x = x.at[real:].set(x[real])
+        idx = jnp.asarray(idx, jnp.int32)
+        w = jnp.asarray(rs.uniform(0.1, 0.5, (t, k)), jnp.float32)
+        partial = e < routed
+        forms = {
+            "kernel": me.moe_grouped_experts_prefill,
+            "grouped": lambda *v, _p=partial: moe._grouped_expert_mix(
+                *v, 0, _p)}
+        held = np.asarray(idx)[np.asarray(idx) < e]
+        hit = len(np.unique(held))
+        expert_bytes = 3 * h * ff * 2
+        want = np.asarray(forms["grouped"](x, idx, w, *ws)[0], np.float32)
+        got = np.asarray(forms["kernel"](x, idx, w, *ws)[0], np.float32)
+        row = {"tokens": t, "experts_held": e, "top_k": k, "hidden": h,
+               "ff": ff, "picks_held": int(held.size), "experts_hit": hit,
+               "largest_group": int(np.bincount(held).max()),
+               "ff_tile": me._ff_tile(h, ff, 2),
+               "kernel_vs_grouped_max_abs": float(np.abs(got - want).max()),
+               "grouped_max_abs": float(np.abs(want).max())}
+        for form, fn in forms.items():
+            ms = median_ms(chained_mix(fn), x, idx, w, *ws, reps=8)
+            row[f"{form}_ms"] = ms
+            row[f"{form}_hit_bytes_roofline_pct"] = \
+                100 * hit * expert_bytes / HBM / (ms / 1e3)
+        out["chunks." + name] = row
+        print("chunks", name, json.dumps(row), flush=True)
+        del ws
 
 
 def main():
@@ -225,6 +280,7 @@ def main():
     only = ap.add_mutually_exclusive_group()
     only.add_argument("--experts-only", action="store_true")
     only.add_argument("--latent-only", action="store_true")
+    only.add_argument("--chunks-only", action="store_true")
     a = ap.parse_args()
     dev = jax.devices()[0]
     if dev.platform != "tpu":
@@ -232,10 +288,12 @@ def main():
                  f"{dev.platform}); nothing measured")
     out = {"device": dev.device_kind, "reps": REPS,
            "chunk_rows": pa._CHUNK_ROWS}
-    if not a.experts_only:
+    if not (a.experts_only or a.chunks_only):
         latent(a, out)
-    if not a.latent_only:
+    if not (a.latent_only or a.chunks_only):
         experts(a, out)
+    if not a.latent_only:
+        chunks(a, out)
     print(json.dumps(out))
 
 
